@@ -81,8 +81,8 @@ from omnia_tpu.models import llama
 from omnia_tpu.models import quant
 from omnia_tpu.models.kv_quant import cache_bytes, validate_kv_quant
 from omnia_tpu.ops.sampling import make_slot_key_data
-from omnia_tpu.parallel import make_mesh, shard_pytree
-from omnia_tpu.parallel.sharding import named_sharding_tree
+from omnia_tpu.ops.attention import check_decode_kernel, pallas_decode_mode
+from omnia_tpu.parallel import init_sharded, make_mesh, shard_pytree
 from omnia_tpu.utils.compile_cache import enable_compilation_cache, enabled_dir
 
 logger = logging.getLogger(__name__)
@@ -142,6 +142,12 @@ class InferenceEngine(
         self._mesh = None
         use_mesh = engine_cfg.dp * engine_cfg.tp * engine_cfg.sp > 1
         validate_paged_config(engine_cfg, use_mesh)
+        # A cache the decode kernel refuses is an error here, not a
+        # quiet switch of implementation at the first decode trace.
+        check_decode_kernel(
+            engine_cfg.max_seq, model_cfg.num_kv_heads,
+            engine_cfg.kv_pages > 0, engine_cfg.dp, engine_cfg.tp,
+        )
         if use_mesh:
             self._mesh = make_mesh(
                 engine_cfg.dp, engine_cfg.tp, sp=engine_cfg.sp, devices=devices
@@ -251,27 +257,31 @@ class InferenceEngine(
                     f"EngineConfig.quant={qmode!r} but supplied params are "
                     f"{detected!r}-quantized"
                 )
-        if params is None:
-            if qmode:
-                # Born quantized: for flagship sizes the full-precision
-                # tree would not fit in HBM beside the int8 one.
-                params = quant.init_params_quantized(
-                    model_cfg, jax.random.key(seed), qmode, dtype=self._dtype
-                )
-            else:
-                params = llama.init_params(
-                    model_cfg, jax.random.key(seed), dtype=self._dtype
-                )
-        elif qmode and not quant.params_quantized(params):
-            # Caller-supplied full-precision params (small models / tests).
-            # Checkpoint-loaded flagships should quantize in the loader
-            # (load_params(quant=...)) so this on-device pass is skipped.
-            params = quant.quantize_params(params, model_cfg, qmode)
         specs = llama.param_specs(model_cfg)
         if qmode:
             specs = quant.quantize_param_specs(specs, model_cfg, qmode)
-        if self._mesh is not None:
-            params = shard_pytree(params, specs, self._mesh)
+        if params is None:
+            key = jax.random.key(seed)
+            if qmode:
+                # Born quantized: for flagship sizes the full-precision
+                # tree would not fit in HBM beside the int8 one.
+                def init():
+                    return quant.init_params_quantized(
+                        model_cfg, key, qmode, dtype=self._dtype
+                    )
+            else:
+                def init():
+                    return llama.init_params(model_cfg, key, dtype=self._dtype)
+            params = init_sharded(init, specs, self._mesh)
+        else:
+            if qmode and not quant.params_quantized(params):
+                # Caller-supplied full-precision params (small models /
+                # tests). Checkpoint-loaded flagships should quantize in
+                # the loader (load_params(quant=...)) so this on-device
+                # pass is skipped.
+                params = quant.quantize_params(params, model_cfg, qmode)
+            if self._mesh is not None:
+                params = shard_pytree(params, specs, self._mesh)
         self.params = params
         self._init_device_state()
 
@@ -465,8 +475,6 @@ class InferenceEngine(
         # A callable-params construction streamed weights before the
         # metrics dict existed — fold the tracker's view in now.
         self._sync_coldstart_metrics()
-        from omnia_tpu.ops.attention import pallas_decode_mode
-
         logger.info(
             "engine built: backend=%s pallas_decode=%s slots=%d max_seq=%d "
             "chunks=%s quant=%s kv_quant=%s",
@@ -492,26 +500,32 @@ class InferenceEngine(
         if self.cfg.kv_pages > 0:
             ck, cv = self._alloc_paged_kv()
             return ck, cv, None, None
-        ck, cv = llama.init_kv_cache(
-            self.model_cfg, B, S, dtype=self._dtype, kv_quant=self._kv_quant
-        )
-        tree = None
-        if self._mesh is not None:
-            kspec, vspec = llama.kv_cache_specs(self._kv_quant)
-            tree = named_sharding_tree((kspec, vspec), self._mesh)
-            ck = jax.device_put(ck, tree[0])
-            cv = jax.device_put(cv, tree[1])
+        ck, cv = self._zero_kv(B, S)
         pk = pv = None
         if self._prefix_pool is not None:
-            R = self.cfg.prefix_buckets()[-1]
-            pk, pv = llama.init_kv_cache(
-                self.model_cfg, self.cfg.prefix_cache_slots, R,
-                dtype=self._dtype, kv_quant=self._kv_quant,
+            pk, pv = self._zero_kv(
+                self.cfg.prefix_cache_slots, self.cfg.prefix_buckets()[-1]
             )
-            if self._mesh is not None:
-                pk = jax.device_put(pk, tree[0])
-                pv = jax.device_put(pv, tree[1])
         return ck, cv, pk, pv
+
+    def _born_sharded(self, init, specs):
+        """``init()`` at the engine's placement: plain on one device,
+        every leaf born on its shards under a mesh (never whole on one
+        device and moved)."""
+        if self._mesh is None:
+            return init()
+        return init_sharded(init, specs, self._mesh)
+
+    def _zero_kv(self, batch: int, rows: int):
+        """Zeroed (k, v) [L, batch, rows, Hkv, D] at the engine's KV
+        representation and placement."""
+        return self._born_sharded(
+            lambda: llama.init_kv_cache(
+                self.model_cfg, batch, rows, dtype=self._dtype,
+                kv_quant=self._kv_quant,
+            ),
+            llama.kv_cache_specs(self._kv_quant),
+        )
 
     def _init_device_state(self):
         """(Re)allocate KV caches and per-slot device state. Called at

@@ -98,7 +98,7 @@ INIT_EVENTS = frozenset({
 })
 
 # Microsecond-scale buckets for the per-dispatch histograms (host
-# dispatch/sync of one compiled step — µs on-box, ms over a tunnel).
+# dispatch/sync of one compiled step, µs to ms).
 _US_BUCKETS = (50, 100, 250, 500, 1000, 2500, 5000, 10000, 25000,
                50000, 100000, 250000, 1000000)
 _S_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,

@@ -29,14 +29,12 @@ from __future__ import annotations
 
 import logging
 
-import jax
 import jax.numpy as jnp
 
 from omnia_tpu.engine.kv_pages import TRASH, PageAllocator, PoolExhausted
 from omnia_tpu.models import llama
 from omnia_tpu.models.kv_quant import is_quant_kv, kv_device, kv_host
 from omnia_tpu.models.paged_kv import PagedKV
-from omnia_tpu.parallel.sharding import named_sharding_tree
 
 logger = logging.getLogger(__name__)
 
@@ -97,22 +95,22 @@ class _PagedKVMixin:
         worker states (engine/warmup.py), which chain donated paged
         operands through their own pool copy."""
         cfg = self.cfg
-        pool_k, pool_v = llama.init_kv_cache(
-            self.model_cfg, cfg.kv_pages, cfg.kv_page_tokens,
-            dtype=self._dtype, kv_quant=self._kv_quant,
-        )
         np_pos = cfg.num_page_positions()
-        # Two table copies (one per cache) so donation never sees the
-        # same buffer twice; _sync_table_row updates them in lockstep.
-        tk = jnp.zeros((cfg.num_slots, np_pos), jnp.int32)
-        tv = jnp.zeros((cfg.num_slots, np_pos), jnp.int32)
-        ck, cv = PagedKV(pool_k, tk), PagedKV(pool_v, tv)
-        if self._mesh is not None:
-            kspec, vspec = llama.paged_kv_specs(self._kv_quant)
-            tree = named_sharding_tree((kspec, vspec), self._mesh)
-            ck = jax.device_put(ck, tree[0])
-            cv = jax.device_put(cv, tree[1])
-        return ck, cv
+
+        def init():
+            pool_k, pool_v = llama.init_kv_cache(
+                self.model_cfg, cfg.kv_pages, cfg.kv_page_tokens,
+                dtype=self._dtype, kv_quant=self._kv_quant,
+            )
+            # Two table copies (one per cache) so donation never sees the
+            # same buffer twice; _sync_table_row updates them in lockstep.
+            tk = jnp.zeros((cfg.num_slots, np_pos), jnp.int32)
+            tv = jnp.zeros((cfg.num_slots, np_pos), jnp.int32)
+            return PagedKV(pool_k, tk), PagedKV(pool_v, tv)
+
+        return self._born_sharded(
+            init, llama.paged_kv_specs(self._kv_quant)
+        )
 
     def _init_paged_state(self) -> None:
         """(Re)allocate the page pool, tables, and allocator books —

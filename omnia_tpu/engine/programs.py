@@ -8,9 +8,8 @@ Program inventory (all static-shaped, KV caches donated where they flow
 through):
 
 - ``prefill_insert`` — fused fresh-prefill: forward + cache insert +
-  first-token sample in ONE dispatch. TTFT pays per-dispatch round trips
-  (tens of ms each on a remote-device link), so folding the old
-  prefill→insert pair into one program halves the prefill RTT bill.
+  first-token sample in ONE dispatch (every dispatch is a host↔device
+  round trip that TTFT pays for; one program, one round trip).
 - ``prefill_ring`` — long-context prefill (sp > 1): ring attention
   splits the O(T²) attention of buckets ≥ long_prefill_threshold across
   the sp mesh axis (SURVEY §5.7).
@@ -263,7 +262,7 @@ def build_programs(
                 ck, cv, tokens, positions, active, budget, key_data = carry
             logits, ck, cv = llama.forward(
                 params, cfg, tokens[:, None], positions[:, None], ck, cv,
-                positions
+                positions, mesh=mesh,
             )
             if grammar_on:
                 row = _grammar_rows(gtable, gstate)
@@ -352,7 +351,7 @@ def build_programs(
         decode body's shared ``_grammar_rows`` helper — one idiom, one
         mask source for sampler and oracle alike."""
         logits, ck, cv = llama.forward(
-            params, cfg, vtoks, vpos, ck, cv, vwstart
+            params, cfg, vtoks, vpos, ck, cv, vwstart, mesh=mesh
         )
         if gstate is None:
             return ck, cv, jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -505,7 +504,8 @@ def build_programs(
         k_slot = _take_slot(ck, slot)
         v_slot = _take_slot(cv, slot)
         logits, k_slot, v_slot = llama.forward(
-            params, cfg, tokens, positions, k_slot, v_slot, write_start[None]
+            params, cfg, tokens, positions, k_slot, v_slot, write_start[None],
+            mesh=mesh,
         )
         # forward kept the slice in cache representation (suffix rows
         # quantized inside _write_kv when kv_quant is on) — write back
@@ -530,7 +530,8 @@ def build_programs(
         k_slot = _take_slot(ck, slot)
         v_slot = _take_slot(cv, slot)
         _, k_slot, v_slot = llama.forward(
-            params, cfg, tokens, positions, k_slot, v_slot, write_start[None]
+            params, cfg, tokens, positions, k_slot, v_slot, write_start[None],
+            mesh=mesh,
         )
         t = tokens.shape[1]
         ck = _put_back(ck, k_slot, slot, write_start, t)
@@ -581,7 +582,8 @@ def build_programs(
                 k_slot = _take_slot(ck, pslot)
                 v_slot = _take_slot(cv, pslot)
                 plogits, k_slot, v_slot = llama.forward(
-                    params, cfg, ptoks, ppos, k_slot, v_slot, pwrite[None]
+                    params, cfg, ptoks, ppos, k_slot, v_slot, pwrite[None],
+                    mesh=mesh,
                 )
                 pt = ptoks.shape[1]
                 ck = _put_back(ck, k_slot, pslot, pwrite, pt)

@@ -507,8 +507,7 @@ class _SchedulerMixin:
         work exceeds it, else the SMALLEST variant covering the remainder.
         Overshoot is preferred to undershoot — the on-device finish mask
         makes overshot steps cheap garbage (~one model step each), while
-        an extra dispatch costs a full host round trip (the dominant cost
-        on a remote-device link)."""
+        an extra dispatch costs a full host round trip."""
         need = max(self._remaining_work(), 1)
         best = max(self._decode_fns)
         for k in sorted(self._decode_fns):

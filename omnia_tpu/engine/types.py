@@ -218,8 +218,8 @@ class EngineConfig:
     sp: int = 1
     long_prefill_threshold: int = 2048
     # Decode steps per device dispatch (lax.scan inside one compiled
-    # program). Each dispatch costs a host↔device round trip — ruinous
-    # through a tunnel/remote device — so K tokens per sync amortizes it.
+    # program). Each dispatch costs a host↔device round trip, so K
+    # tokens per sync amortizes it.
     # Trade-offs: streaming granularity becomes K tokens, a queued prefill
     # waits up to one chunk, and a slot finishing mid-chunk wastes ≤K-1
     # slot-steps (bounded by on-device stop/length masking: a finished

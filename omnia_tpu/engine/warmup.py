@@ -232,9 +232,14 @@ class _WarmupMixin:
         if sessions:
             def session_task(r):
                 def run(st):
-                    zero = jnp.int32(0)
-                    k, v = self._offload_fn(st.ck, st.cv, zero, r)
-                    st.ck, st.cv = self._restore_fn(st.ck, st.cv, k, v, zero)
+                    # The operands eviction and restore dispatch with
+                    # (sessions.py): a python-int slot, rows that have
+                    # been to the host and back — except across
+                    # processes, where no one host can read the rows.
+                    k, v = self._offload_fn(st.ck, st.cv, 0, r)
+                    if jax.tree.leaves(k)[0].is_fully_addressable:
+                        k, v = kv_device(kv_host(k)), kv_device(kv_host(v))
+                    st.ck, st.cv = self._restore_fn(st.ck, st.cv, k, v, 0)
                 return run
 
             for r in cfg.restore_buckets():
@@ -460,9 +465,16 @@ class _WarmupMixin:
 
         cs = self._coldstart
         cs.begin_phase("weights_load")
+        failed: list[Exception] = []
+
+        def overlap():
+            try:
+                self._warmup_paramfree()
+            except Exception as e:  # re-raised on the caller below
+                failed.append(e)
+
         t = threading.Thread(
-            target=self._overlap_guarded, name="omnia-warmup-overlap",
-            daemon=True,
+            target=overlap, name="omnia-warmup-overlap", daemon=True,
         )
         t.start()
         try:
@@ -475,6 +487,10 @@ class _WarmupMixin:
             params = loader(**kwargs)
         finally:
             t.join()
+        if failed:
+            # A program that does not compile here will not compile in
+            # warmup() either; fail bring-up where the cause is.
+            raise failed[0]
         seconds = cs.end_phase("weights_load")
         if self._flight is not None:
             snap = cs.snapshot()
@@ -483,17 +499,6 @@ class _WarmupMixin:
                 "bytes": snap["weights_bytes_loaded"],
             })
         return params
-
-    def _overlap_guarded(self) -> None:
-        try:
-            self._warmup_paramfree()
-        except Exception:
-            # The overlap is an optimization: a failure here only means
-            # the full warmup pays these compiles serially later.
-            logger.warning(
-                "param-free warmup overlap failed; warmup() will compile "
-                "those families serially", exc_info=True,
-            )
 
     # -- orchestrator ----------------------------------------------------
 

@@ -229,7 +229,7 @@ def _write_kv(cache, new, start):
 
 
 def _layer(x, p, cfg: ModelConfig, cos, sin, q_positions, ck, cv, write_start,
-           attn_fn=None):
+           attn_fn=None, mesh=None):
     B, T, D = x.shape
     h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
     q = qdot(h, p["attn"]["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
@@ -253,7 +253,7 @@ def _layer(x, p, cfg: ModelConfig, cos, sin, q_positions, ck, cv, write_start,
     if attn_fn is not None:
         attn = attn_fn(q, ck_eff, cv_eff, q_positions)
     else:
-        attn = gqa_attention(q, ck_eff, cv_eff, q_positions)
+        attn = gqa_attention(q, ck_eff, cv_eff, q_positions, mesh=mesh)
     x = x + qdot(attn.reshape(B, T, -1), p["attn"]["wo"])
 
     h2 = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
@@ -319,11 +319,14 @@ def _logits(params, cfg: ModelConfig, x):
     return qdot(x, params["lm_head"]).astype(jnp.float32)
 
 
-def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v, write_start):
+def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
+            write_start, mesh=None):
     """Serving forward (prefill or decode — same code, different T).
 
     tokens, q_positions: int32 [B, T]; cache_k/v: [L, B, S, Hkv, D];
     write_start: int32 [B] row offset where this chunk's KV lands.
+    mesh: the mesh params and caches are sharded over, if any — the
+    decode kernel needs it named (ops/attention.py).
     Returns (logits [B, T, V] f32, new_cache_k, new_cache_v).
     """
     x = params["embed"][tokens]  # [B,T,D]
@@ -340,7 +343,7 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v, wri
             p, pk, pv = scanned
             x, ck, cv = _layer(
                 x, p, cfg, cos, sin, q_positions,
-                PagedKV(pk, tk), PagedKV(pv, tv), write_start,
+                PagedKV(pk, tk), PagedKV(pv, tv), write_start, mesh=mesh,
             )
             return x, (ck.pool, cv.pool)
 
@@ -352,7 +355,9 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v, wri
     def body(carry, scanned):
         x = carry
         p, ck, cv = scanned
-        x, ck, cv = _layer(x, p, cfg, cos, sin, q_positions, ck, cv, write_start)
+        x, ck, cv = _layer(
+            x, p, cfg, cos, sin, q_positions, ck, cv, write_start, mesh=mesh
+        )
         return x, (ck, cv)
 
     x, (new_k, new_v) = jax.lax.scan(

@@ -335,6 +335,7 @@ def _skill_source_schema() -> dict:
             "type": _str(enum=("dir", "configmap", "git", "oci")),
             "path": _str(),
             "ref": _str(),
+            "data": _obj(open_=True, desc="configmap payload {filename: text}"),
         }, required=["type"]),
         "interval_s": _NUM,
     }, required=["source"])

@@ -1,11 +1,5 @@
-"""jax API compatibility: shard_map across jax versions.
-
-Newer jax exposes `jax.shard_map(..., check_vma=, axis_names=)`; older
-releases (≤0.4.x, still common in hermetic containers) only have
-`jax.experimental.shard_map.shard_map(..., check_rep=, auto=)`. The two
-spell "manual over these axes, skip the replication check" differently —
-this is the one place that knows both spellings.
-"""
+"""The one spelling of ``jax.shard_map`` this repo uses: manual over the
+named axes, replication (VMA) check off."""
 
 from __future__ import annotations
 
@@ -16,20 +10,12 @@ import jax
 
 def shard_map(f, mesh, in_specs, out_specs,
               manual_axes: Optional[set] = None):
-    """Version-portable shard_map with the replication/VMA check off.
-    `manual_axes=None` = manual over every mesh axis; a set = manual over
+    """`manual_axes=None` = manual over every mesh axis; a set = manual over
     exactly those axes (the rest stay auto-sharded)."""
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": False}
-        if manual_axes is not None:
-            kw["axis_names"] = set(manual_axes)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    kw = {"check_rep": False}
+    kw = {}
     if manual_axes is not None:
-        # Old spelling inverts it: `auto` lists the NON-manual axes.
-        kw["auto"] = frozenset(mesh.axis_names) - set(manual_axes)
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = set(manual_axes)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False, **kw
+    )

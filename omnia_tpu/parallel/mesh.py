@@ -19,7 +19,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 
@@ -56,12 +56,9 @@ def make_mesh(
     ]
     shape = tuple(size for _, size in keep)
     names = tuple(name for name, _ in keep)
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices[:n])
-    except Exception:
-        dev_array = np.array(devices[:n]).reshape(shape)
+    # Raises on a TPU topology the shape does not fit — a plain reshape
+    # there would put "tp" neighbours on chips that are not.
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices[:n])
     return Mesh(dev_array, names)
 
 

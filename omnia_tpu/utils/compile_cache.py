@@ -10,62 +10,48 @@ useless. Persisting compiled executables across process starts turns every
 restart after the first into a cache hit: warmup becomes deserialize +
 load, not compile.
 
+Where the cache lives is decided outside the program: JAX's own
+``JAX_COMPILATION_CACHE_DIR`` when set (this module then sets no directory
+at all), else the fixed ``<checkout>/.jax_cache``. The path is part of the
+cache key, so it never depends on a temporary directory, a pid or a time;
+a directory that cannot be written is an error, not a reason to move.
+
 One call, idempotent, safe before or after backend init. Used by the
-engine itself (so every serving path benefits), bench, and the dryrun
-entry.
+engine itself, so every serving path benefits.
 """
 
 from __future__ import annotations
 
-import logging
 import os
-import tempfile
 
-logger = logging.getLogger(__name__)
-
-_enabled = False
 _enabled_dir: str | None = None
 
 
-def _writable_dir(path: str) -> bool:
-    """True when `path` exists (or can be created) and accepts writes —
-    the probe actually creates and removes a file, because os.access
-    lies under containers' overlayfs/read-only mounts."""
-    try:
-        os.makedirs(path, exist_ok=True)
-        probe = os.path.join(path, f".write_probe_{os.getpid()}")
-        with open(probe, "w") as f:
-            f.write("")
-        os.remove(probe)
-        return True
-    except OSError:
-        return False
-
-
 def default_cache_dir() -> str:
-    """OMNIA_JAX_CACHE_DIR wins; otherwise a dot-dir next to the package
-    (the repo root in dev, the install prefix in a pod image) — and when
-    THAT is unwritable (read-only container images mount the install
-    prefix ro), a per-user tmpdir with a logged warning. A tmpdir cache
-    only survives the pod, not the node — but a silent failure used to
-    disable caching entirely, which is strictly worse."""
-    env = os.environ.get("OMNIA_JAX_CACHE_DIR")
+    """``JAX_COMPILATION_CACHE_DIR`` when set, else ``.jax_cache`` beside
+    the package (the repo root in a checkout, the install prefix in a
+    pod image)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    preferred = os.path.join(pkg_root, ".jax_cache")
-    if _writable_dir(preferred):
-        return preferred
-    fallback = os.path.join(
-        tempfile.gettempdir(), f"omnia_jax_cache_{os.getuid()}"
-    )
-    logger.warning(
-        "compile cache dir %s is unwritable (read-only image?); falling "
-        "back to %s — set OMNIA_JAX_CACHE_DIR to a persistent volume so "
-        "restarts keep their compile cache",
-        preferred, fallback,
-    )
-    return fallback
+    return os.path.join(pkg_root, ".jax_cache")
+
+
+def _require_writable(path: str) -> None:
+    """Create `path` and write a file there. os.access lies under
+    containers' overlayfs/read-only mounts, so the probe really writes."""
+    probe = os.path.join(path, ".write_probe")
+    try:
+        os.makedirs(path, exist_ok=True)
+        with open(probe, "w"):
+            pass
+        os.remove(probe)
+    except OSError as e:
+        raise RuntimeError(
+            f"compile cache dir {path} is not writable ({e}); point "
+            "JAX_COMPILATION_CACHE_DIR at a persistent volume"
+        ) from e
 
 
 def enabled_dir() -> str | None:
@@ -76,36 +62,31 @@ def enabled_dir() -> str | None:
 
 
 def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at `cache_dir` and drop the
-    entry-size/compile-time floors so *every* serving program is cached
-    (the defaults skip fast compiles — but through a remote-device tunnel
-    even a 1 s compile is worth skipping). Returns the dir, or None if the
-    cache could not be enabled (old jax) — serving still works, cold starts
-    just stay slow."""
-    global _enabled, _enabled_dir
-    if _enabled:
+    """Turn JAX's persistent compilation cache on at `cache_dir` (default:
+    :func:`default_cache_dir`) and drop the entry-size/compile-time floors
+    so *every* serving program is cached — the defaults skip fast
+    compiles, and warmup is many of them. Returns the directory, or None
+    on a CPU backend that nobody placed a cache for. Raises when the
+    directory cannot be written."""
+    global _enabled_dir
+    if _enabled_dir is not None:
         return _enabled_dir
-    explicit = cache_dir is not None or "OMNIA_JAX_CACHE_DIR" in os.environ
-    try:
-        import jax
+    import jax
 
-        if not explicit and jax.default_backend() == "cpu":
-            # CPU runs (tests, dev) don't pay a meaningful compile bill,
-            # and XLA:CPU AOT cache entries are machine-feature-pinned —
-            # reloading them across feature-detection differences risks
-            # SIGILL. Opt in explicitly to cache on CPU. Decided BEFORE
-            # resolving the default dir: the resolution write-probes the
-            # filesystem and may log the read-only-image fallback
-            # warning, which would be noise for a cache never enabled.
-            return None
-        cache_dir = cache_dir or default_cache_dir()
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _enabled = True
-        _enabled_dir = cache_dir
-        return cache_dir
-    except Exception:  # pragma: no cover - depends on jax version
-        logger.exception("persistent compilation cache unavailable")
+    from_env = cache_dir is None and "JAX_COMPILATION_CACHE_DIR" in os.environ
+    if cache_dir is None and not from_env and jax.default_backend() == "cpu":
+        # CPU runs (tests, dev) don't pay a meaningful compile bill, and
+        # XLA:CPU AOT cache entries are machine-feature-pinned — reloading
+        # them across feature-detection differences risks SIGILL. Name a
+        # directory to cache on CPU.
         return None
+    cache_dir = cache_dir or default_cache_dir()
+    _require_writable(cache_dir)
+    if not from_env:
+        # With the standard variable set JAX already reads it; setting a
+        # directory here would only be a second source of truth.
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _enabled_dir = cache_dir
+    return cache_dir

@@ -176,38 +176,60 @@ class TestWarmupManifest:
 
 
 # ---------------------------------------------------------------------------
-# compile_cache fallback (jax-free satellite)
+# compile_cache placement (jax-free satellite)
 # ---------------------------------------------------------------------------
 
 
 class TestCompileCacheDir:
-    def test_env_override_wins(self, monkeypatch):
+    def test_standard_variable_wins(self, monkeypatch):
         from omnia_tpu.utils import compile_cache
 
-        monkeypatch.setenv("OMNIA_JAX_CACHE_DIR", "/somewhere/persistent")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/persistent")
         assert compile_cache.default_cache_dir() == "/somewhere/persistent"
 
-    def test_unwritable_default_falls_back_to_tmpdir(self, monkeypatch, caplog):
-        """The dot-dir next to the package is unwritable in read-only
-        container images — the cache must fall back to a tmpdir with a
-        logged warning instead of failing enablement silently."""
-        import logging
+    def test_unwritable_dir_is_an_error_not_a_move(self, monkeypatch, tmp_path):
+        """A cache directory that cannot be written fails loudly with
+        the path in the message; the cache is never moved somewhere
+        else (the path is part of the cache key)."""
+        from omnia_tpu.utils import compile_cache
+
+        target = tmp_path / "ro" / ".jax_cache"
+
+        def refuse(path, exist_ok=False):
+            raise OSError(30, "Read-only file system", path)
+
+        monkeypatch.setattr(compile_cache.os, "makedirs", refuse)
+        with pytest.raises(RuntimeError, match="not writable") as exc:
+            compile_cache._require_writable(str(target))
+        assert str(target) in str(exc.value)
+        assert not target.exists()
+
+    def test_default_is_fixed_dot_dir_in_checkout(self, monkeypatch):
+        from omnia_tpu.utils import compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert compile_cache.default_cache_dir() == os.path.join(
+            REPO, ".jax_cache"
+        )
+
+    def test_standard_variable_means_no_directory_set_in_code(
+        self, monkeypatch, tmp_path
+    ):
+        """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself: the
+        module lowers the floors and sets no directory of its own."""
+        import jax
 
         from omnia_tpu.utils import compile_cache
 
-        monkeypatch.delenv("OMNIA_JAX_CACHE_DIR", raising=False)
-        monkeypatch.setattr(compile_cache, "_writable_dir", lambda p: False)
-        with caplog.at_level(logging.WARNING, logger=compile_cache.__name__):
-            d = compile_cache.default_cache_dir()
-        assert d.startswith(__import__("tempfile").gettempdir())
-        assert any("unwritable" in r.message for r in caplog.records)
-
-    def test_writable_default_keeps_repo_dot_dir(self, monkeypatch):
-        from omnia_tpu.utils import compile_cache
-
-        monkeypatch.delenv("OMNIA_JAX_CACHE_DIR", raising=False)
-        monkeypatch.setattr(compile_cache, "_writable_dir", lambda p: True)
-        assert compile_cache.default_cache_dir().endswith(".jax_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+        updates = []
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: updates.append(k)
+        )
+        assert compile_cache.enable_compilation_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+        assert "jax_persistent_cache_min_compile_time_secs" in updates
 
 
 # ---------------------------------------------------------------------------

@@ -280,3 +280,147 @@ class TestDecodeAttention:
             )
         finally:
             attn._pallas_decode_mode.cache_clear()
+
+
+# ---------------------------------------------------------------------------
+# The kernel under a device mesh (shard_map over "dp" × "tp"), and the
+# layouts it refuses — ops/attention.py::_decode_path / check_decode_kernel
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def route(monkeypatch):
+    """Set the decode-kernel route for the rest of the test."""
+    import omnia_tpu.ops.attention as attn
+
+    def set_route(mode):
+        monkeypatch.setenv("OMNIA_PALLAS_DECODE", mode)
+        attn._pallas_decode_mode.cache_clear()
+
+    yield set_route
+    attn._pallas_decode_mode.cache_clear()  # monkeypatch restores the env
+
+
+class TestKernelUnderMesh:
+    @pytest.mark.parametrize(
+        "dp,tp,layout",
+        [(dp, tp, layout)
+         for dp, tp in [(1, 2), (2, 2), (1, 4)]
+         for layout in ["plain", "int8", "paged"]
+         if not (layout == "paged" and dp > 1)],  # refused: TestKernelRefusals
+    )
+    def test_sharded_kernel_matches_einsum(self, devices8, route, dp, tp, layout):
+        """Operands sharded the way the engine shards them (slots over
+        dp, KV heads over tp): the shard_mapped kernel must give what the
+        GSPMD-partitioned einsum path gives."""
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from omnia_tpu.models import kv_quant as kvq
+        from omnia_tpu.models.paged_kv import PagedKV
+        from omnia_tpu.parallel import make_mesh
+
+        mesh = make_mesh(dp, tp, devices=devices8)
+        q, k, v = _setup(B=4, S=512, H=8, Hkv=4, D=64)
+        pos = jnp.asarray([3, 255, 256, 510], dtype=jnp.int32)
+
+        def put(x, *spec):
+            return jax.device_put(x, NamedSharding(mesh, P(*spec)))
+
+        q = put(q, "dp", None, "tp", None)
+        if layout == "paged":
+            pool_k, pool_v, table = _paginate(k, v, page_s=64)
+            kc = PagedKV(put(pool_k, None, None, "tp", None), put(table))
+            vc = PagedKV(put(pool_v, None, None, "tp", None), put(table))
+        elif layout == "int8":
+            def quant(x):
+                c = kvq.quantize_rows(x)
+                return kvq.QuantKV(put(c.q, "dp", None, "tp", None),
+                                   put(c.s, "dp", None, "tp"))
+            kc, vc = quant(k), quant(v)
+        else:
+            kc, vc = (put(x, "dp", None, "tp", None) for x in (k, v))
+
+        def run(mode):
+            route(mode)
+            return jax.jit(
+                lambda q, kc, vc, p: gqa_attention(q, kc, vc, p[:, None],
+                                                   mesh=mesh)
+            )(q, kc, vc, pos)
+
+        out, ref = run("interpret"), run("0")
+        assert out.sharding.spec == P("dp", None, "tp", None) or tp == 1
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
+        )
+
+    def test_single_slot_view_is_replicated_over_dp(self, devices8, route):
+        """extend runs T=1 on one slot's view: B=1 does not divide over
+        dp=2, so the slot axis stays whole while heads still shard."""
+        from omnia_tpu.parallel import make_mesh
+
+        mesh = make_mesh(2, 2, devices=devices8)
+        q, k, v = _setup(B=1, S=256, H=4, Hkv=2, D=64)
+        pos = jnp.asarray([[100]], dtype=jnp.int32)
+        route("interpret")
+        out = jax.jit(lambda *a: gqa_attention(*a, mesh=mesh))(q, k, v, pos)
+        route("0")
+        ref = gqa_attention(q, k, v, pos)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
+        )
+
+
+class TestKernelRefusals:
+    """With the kernel routed on, a layout it cannot serve is an error —
+    at engine construction — never a quiet switch to the einsum path."""
+
+    @pytest.mark.parametrize(
+        "kwargs,exc,match",
+        [
+            (dict(cache_len=300, num_kv_heads=8, paged=False),
+             ValueError, "multiple"),
+            (dict(cache_len=1024, num_kv_heads=2, paged=False, tp=4),
+             ValueError, "KV heads"),
+            (dict(cache_len=1024, num_kv_heads=8, paged=True, dp=2),
+             NotImplementedError, "page pool"),
+        ],
+        ids=["cache-not-multiple-of-block", "heads-not-divisible", "paged-dp"],
+    )
+    def test_refusals(self, route, kwargs, exc, match):
+        from omnia_tpu.ops.attention import check_decode_kernel
+
+        route("interpret")
+        with pytest.raises(exc, match=match):
+            check_decode_kernel(**kwargs)
+        route("0")  # routed off: the einsum path takes any layout
+        check_decode_kernel(**kwargs)
+
+    def test_short_and_block_multiple_caches_pass(self, route):
+        from omnia_tpu.ops.attention import check_decode_kernel
+
+        route("interpret")
+        for cache_len in (64, 200, 256, 1024):  # ≤ one block, or multiples
+            check_decode_kernel(cache_len, 8, paged=False, tp=4)
+
+    def test_engine_refuses_at_construction(self, route):
+        from omnia_tpu.engine import EngineConfig, InferenceEngine
+        from omnia_tpu.models import get_config
+
+        cfg = get_config("test-tiny", max_seq_len=512)
+        ecfg = EngineConfig(num_slots=2, max_seq=300, prefill_buckets=(16,),
+                            dtype="float32")
+        route("interpret")
+        with pytest.raises(ValueError, match="cache length 300"):
+            InferenceEngine(cfg, ecfg)
+        route("0")
+        InferenceEngine(cfg, ecfg)
+
+    def test_trace_time_recheck_raises_not_falls_back(self, route):
+        """gqa_attention called directly with a cache the block does not
+        divide: an error, where it used to return the einsum result."""
+        q, k, v = _setup(B=2, S=300, H=4, Hkv=2, D=64)
+        pos = jnp.asarray([[10], [200]], dtype=jnp.int32)
+        route("interpret")
+        with pytest.raises(ValueError, match="cache length 300"):
+            gqa_attention(q, k, v, pos)

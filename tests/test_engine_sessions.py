@@ -247,7 +247,10 @@ class TestWarmupCoversSessionPrograms:
 
             stream = io.StringIO()
             handler = _logging.StreamHandler(stream)
-            logger = _logging.getLogger("jax._src.dispatch")
+            # The whole "jax" tree: this JAX logs "Compiling jit(...)"
+            # from interpreters.pxla and "Finished XLA compilation" from
+            # dispatch; listening on one module alone hears nothing.
+            logger = _logging.getLogger("jax")
             logger.addHandler(handler)
             try:
                 p1 = [1, 2, 3, 4, 5]
@@ -262,6 +265,7 @@ class TestWarmupCoversSessionPrograms:
                 logger.removeHandler(handler)
             logged = stream.getvalue()
         assert "Compiling" not in logged, logged
+        assert "Finished XLA compilation" not in logged, logged
 
 
 class TestLongContextServing:

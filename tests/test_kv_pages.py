@@ -143,17 +143,19 @@ BASE = dict(num_slots=2, max_seq=64, prefill_buckets=(8, 16, 32),
             dtype="float32", max_sessions=6)
 
 
-def _engines(seed=3, pages=20, page_tokens=16, **kw):
+def _engines(seed=3, pages=20, page_tokens=16, params=None, **kw):
     pytest.importorskip("jax")
     from omnia_tpu.engine import EngineConfig, InferenceEngine
     from omnia_tpu.models import get_config
 
     cfg = dict(BASE, **kw)
-    cont = InferenceEngine(get_config("test-tiny"), EngineConfig(**cfg), seed=seed)
+    cont = InferenceEngine(
+        get_config("test-tiny"), EngineConfig(**cfg), params=params, seed=seed
+    )
     paged = InferenceEngine(
         get_config("test-tiny"),
         EngineConfig(**cfg, kv_pages=pages, kv_page_tokens=page_tokens),
-        seed=seed,
+        params=params, seed=seed,
     )
     return cont, paged
 
@@ -271,11 +273,18 @@ class TestPagedEquivalence:
         assert isinstance(paged._ck.pool, QuantKV)
 
     def test_spec_decode_bit_identical(self):
-        cont, paged = _engines(spec_decode=3)
-        tc, _ = _turn(cont, [3, 1, 4, 1, 5, 9, 2, 6], max_tokens=12)
-        tp, _ = _turn(paged, [3, 1, 4, 1, 5, 9, 2, 6], max_tokens=12)
-        assert tc == tp
+        # The echo model + echo prompt (tests/echomodel.py): the lookup
+        # proposes the model's next tokens by construction, so verify
+        # steps run and accept on both layouts.
+        pytest.importorskip("jax")
+        from echomodel import ECHO, echo_params
+
+        cont, paged = _engines(spec_decode=3, params=echo_params())
+        tc, _ = _turn(cont, ECHO, max_tokens=12)
+        tp, _ = _turn(paged, ECHO, max_tokens=12)
+        assert tc == tp == [(3 + i) % 8 for i in range(12)]
         assert paged.metrics["spec_steps"] > 0
+        assert paged.metrics["spec_accepted"] > 0
 
 
 class TestPagedPoolBehavior:

@@ -215,7 +215,7 @@ class TestMockMirror:
 # ---------------------------------------------------------------------------
 
 
-def _engine(spec: int, **over):
+def _engine(spec: int, params=None, **over):
     pytest.importorskip("jax")
     from omnia_tpu.engine import EngineConfig, InferenceEngine
     from omnia_tpu.models import get_config
@@ -224,7 +224,9 @@ def _engine(spec: int, **over):
               dtype="float32", decode_chunk=4, max_sessions=4,
               spec_decode=spec)
     kw.update(over)
-    eng = InferenceEngine(get_config("test-tiny"), EngineConfig(**kw), seed=0)
+    eng = InferenceEngine(
+        get_config("test-tiny"), EngineConfig(**kw), params=params, seed=0
+    )
     eng.warmup()
     return eng
 
@@ -283,15 +285,31 @@ def test_spec_spends_fewer_weight_streams_on_repetition():
 def test_adaptive_depth_stays_identical_and_accepts():
     """spec_decode_max lets depth follow the accept EMA; output must
     stay token-identical to vanilla while the ledger shows adaptation
-    (accepts observed, engine-wide EMA moved, index bounded)."""
-    ref, _ = _engine(0).generate(REPETITIVE, _sp(temperature=0.0,
-                                                 max_tokens=100))
-    eng = _engine(2, spec_decode_max=8)
-    toks, _ = eng.generate(REPETITIVE, _sp(temperature=0.0, max_tokens=100))
+    (accepts observed, engine-wide EMA moved, index bounded). The echo
+    model + echo prompt make every proposal right by construction."""
+    pytest.importorskip("jax")
+    from echomodel import ECHO, echo_params
+
+    params = echo_params()
+    sp = _sp(temperature=0.0, max_tokens=100)
+    ref, _ = _engine(0, params=params).generate(ECHO, sp)
+    assert ref[:4] == [3, 4, 5, 6]  # the cycle, continued from the prompt
+    eng = _engine(2, params=params, spec_decode_max=8)
+    handle = eng.submit(ECHO, sp)
+    index_bytes_peak = 0
+    while eng.step():
+        # A gauge over LIVE slots: a stream that finishes inside a
+        # verify step leaves it at 0, so watch it while the stream runs.
+        index_bytes_peak = max(
+            index_bytes_peak, eng.metrics["spec_index_bytes"]
+        )
+    toks, _ = handle.collect_tokens(timeout=60)
     assert toks == ref
     assert eng.metrics["spec_accepted"] > 0
     assert eng.metrics["spec_accept_ema"] > 0.0
-    assert eng.metrics["spec_index_bytes"] > 0
+    assert 0 < index_bytes_peak <= (
+        sd._ENTRY_BYTES * sd._NGRAM_CAP * sd._NGRAM_MAX
+    )
     # Deep windows engaged: some step accepted more than the base depth
     # would ever allow (depth grew past spec_decode=2).
     assert eng.metrics["spec_proposed"] > 2 * eng.metrics["spec_steps"] or (

@@ -1,0 +1,195 @@
+"""Ask the TPU's compiler, without a TPU.
+
+The chip's compiler is installed here and compiles for a described,
+unattached ``v5e:2x2``: it refuses what interpret mode and the CPU backend
+let through (misaligned kernel slices, too much VMEM, a Mosaic kernel
+inside a GSPMD-partitioned jit). A compile that passes is not a chip run;
+these tests guard that the main path still *compiles* at real widths.
+
+The topology is described inside a module-scoped fixture — never at
+import — because only one process may load the TPU library and every
+xdist worker imports this file. All of these compiles live in this one
+file for the same reason. Nothing here starts a child process.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from chip_smoke import collective_lines, kv_rows_moved
+from omnia_tpu.engine.programs import build_programs
+from omnia_tpu.engine.types import MAX_DEVICE_STOP_IDS, EngineConfig
+from omnia_tpu.models import get_config, llama
+from omnia_tpu.ops import attention as attn
+from omnia_tpu.ops import decode_attention as dk
+from omnia_tpu.parallel import make_mesh
+
+B, S, PAGE_S = 16, 1024, 64
+WIDTHS = {"llama3-1b": (32, 8, 64), "llama3-8b": (32, 8, 128)}  # H, Hkv, D
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4_mesh(topo):
+    return make_mesh(dp=1, tp=4, devices=topo.devices)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def kernel_route_on(monkeypatch):
+    """Steer ops/attention.py onto the route a TPU backend resolves to
+    (``auto`` reads ``jax.default_backend()``, which is the CPU here)."""
+    monkeypatch.setenv("OMNIA_PALLAS_DECODE", "1")
+    attn._pallas_decode_mode.cache_clear()
+    yield
+    attn._pallas_decode_mode.cache_clear()
+
+
+def _is_spec(x):
+    return isinstance(x, P)
+
+
+def _model_operands(cfg, sharding_for):
+    """(params, ck, cv) ShapeDtypeStruct trees for one decode batch of
+    B × S; ``sharding_for(spec)`` places each leaf."""
+    def shapes(make, specs):
+        return jax.tree.map(
+            lambda spec, x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=sharding_for(spec)
+            ),
+            specs, jax.eval_shape(make), is_leaf=_is_spec,
+        )
+
+    params = shapes(
+        lambda: llama.init_params(cfg, jax.random.key(0), jnp.bfloat16),
+        llama.param_specs(cfg),
+    )
+    ck, cv = shapes(
+        lambda: llama.init_kv_cache(cfg, B, S, dtype=jnp.bfloat16),
+        llama.kv_cache_specs(),
+    )
+    return params, ck, cv
+
+
+def _slot_vec(dtype, sharding, *tail):
+    return jax.ShapeDtypeStruct((B, *tail), dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("model", sorted(WIDTHS))
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_decode_kernel_compiles(one_chip, paged, model, kv_int8):
+    H, Hkv, D = WIDTHS[model]
+    kv_dtype = jnp.int8 if kv_int8 else jnp.bfloat16
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, pos = arr((B, H, D), jnp.bfloat16), arr((B,), jnp.int32)
+    if paged:
+        pages = B * S // PAGE_S
+        kv = arr((pages, PAGE_S, Hkv, D), kv_dtype)
+        scale = arr((pages, PAGE_S, Hkv), jnp.float32)
+        args = (q, kv, kv, arr((B, S // PAGE_S), jnp.int32), pos)
+        fn = dk.decode_gqa_attention_paged
+    else:
+        kv = arr((B, S, Hkv, D), kv_dtype)
+        scale = arr((B, S, Hkv), jnp.float32)
+        args = (q, kv, kv, pos)
+        fn = dk.decode_gqa_attention
+    if kv_int8:
+        args += (scale, scale)
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_one_chip_decode_step_holds_the_mosaic_call(one_chip, kernel_route_on):
+    cfg = get_config("llama3-1b")
+    params, ck, cv = _model_operands(cfg, lambda _spec: one_chip)
+    toks = _slot_vec(jnp.int32, one_chip)
+
+    def step(params, ck, cv, toks, pos):
+        logits, ck, cv = llama.forward(
+            params, cfg, toks[:, None], pos[:, None], ck, cv, pos
+        )
+        return jnp.argmax(logits[:, 0], -1), ck, cv
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, ck, cv, toks, toks
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+def test_tp4_engine_decode_chunk_compiles_sharded(tp4_mesh, kernel_route_on):
+    """The engine's real decode program (the scan of decode_chunk steps)
+    on a dp=1 × tp=4 mesh of described devices, operands sharded by the
+    repo's own specs. Before the shard_map around the kernel this raised
+    ``NotImplementedError: Mosaic kernels cannot be automatically
+    partitioned``."""
+    cfg = get_config("llama3-1b")
+    ecfg = EngineConfig(num_slots=B, max_seq=S, tp=4, decode_chunk=8)
+    rep = NamedSharding(tp4_mesh, P())
+    params, ck, cv = _model_operands(
+        cfg, lambda spec: NamedSharding(tp4_mesh, spec)
+    )
+    i32, f32 = (lambda *t: _slot_vec(jnp.int32, rep, *t)), _slot_vec(
+        jnp.float32, rep
+    )
+    decode = build_programs(cfg, ecfg, tp4_mesh).decode_fns[8]
+    compiled = decode.lower(
+        params, ck, cv, i32(), i32(), _slot_vec(jnp.bool_, rep), i32(),
+        i32(MAX_DEVICE_STOP_IDS), _slot_vec(jnp.uint32, rep, 2), f32, f32,
+        i32(),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # Megatron tensor parallelism: all-reduces after the attention and
+    # MLP output projections. The sampler sorts vocab-sharded logits,
+    # which brings all-gathers and all-to-alls of [B, V]-sized arrays;
+    # nothing may move cache rows ([.., S, Hkv, D]) between chips.
+    found = collective_lines(text)
+    assert len(found.get("all-reduce", [])) >= 2, sorted(found)
+    assert "collective-permute" not in found, sorted(found)
+    assert not kv_rows_moved(text, S, cfg.head_dim)
+    # Each chip holds a quarter of the weights and the cache.
+    mem = compiled.memory_analysis()
+    whole = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize
+        for x in jax.tree.leaves((params, ck, cv))
+    )
+    assert mem.argument_size_in_bytes < 0.3 * whole
