@@ -1,0 +1,53 @@
+"""Every cell's readers run on a context made from the recorded trace and
+hand-made records: each returns a number or None, never raises, and the
+shares stay under 100 %."""
+import gzip
+import json
+import os
+
+import pytest
+
+from harness import roofline, trace as tr
+from harness.load import Record
+from harness.manifest import Cell, benchmark_json
+
+
+@pytest.fixture(scope="module")
+def ctx():
+    path = os.path.join(os.path.dirname(__file__), "trace_sample.json.gz")
+    with gzip.open(path, "rt") as f:
+        reduced = tr.reduce(json.load(f))
+    records = [
+        Record(i, "window", 400 + 10 * i, 64, due=10.0 + i, sent=10.001 + i,
+               first=10.3 + i, last=12.8 + i, done=12.8 + i, tokens=64,
+               finish="length", request_id=f"req-{i}")
+        for i in range(20)
+    ]
+    return {
+        "seconds": 20.0, "records": records, "all_records": records, "chips": 1,
+        "peaks": roofline.peaks("TPU v5 lite"), "engine": {"num_slots": 32},
+        "counters_window": {"decode_steps": 500, "tokens_generated": 15000,
+                            "prefill_steps": 100, "prefill_tokens": 40000},
+        "setup": {"setup_s": 30.0, "phases": {"warmup_compile": 5.0, "warmup_restore": 0.5}},
+        "flight": {f"req-{i}": {"queue_s": 0.05 * i} for i in range(20)},
+        "trace": reduced,
+        "traced": {"t": (14.0, 14.25), "counters": {"decode_steps": 4, "prefill_tokens": 500}},
+    }
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in benchmark_json()["workloads"]])
+def test_cell_readers(name, ctx):
+    cell = Cell(name)
+    ctx = {**ctx, "model": cell.model, "chips": cell.chips}
+    values = {metric: mod.read(ctx) for metric, mod in cell.layer_metrics}
+    assert all(v is None or isinstance(v, (int, float)) for v in values.values()), values
+    assert values["programs.warmup_s"] == pytest.approx(5.5)
+    if cell.chips == 1:  # the recorded trace is a one-chip, 14-layer run
+        assert values["step.decode_ms"] == pytest.approx(49.3, rel=0.02)
+        assert 0 < values["decode_step_roofline"] < 100
+        assert 0 < values["decode_gqa_attention_roofline"] < 100
+    for k, v in values.items():
+        if k.startswith("device.idle_share"):
+            assert 0 <= v < 100
+    if "engine.batch_occupancy" in values:
+        assert values["engine.batch_occupancy"] == pytest.approx(100 * 14900 / 16000)
