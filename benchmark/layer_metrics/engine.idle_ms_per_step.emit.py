@@ -1,0 +1,10 @@
+"""Device idle time per decode step that falls under `omnia.engine.emit`:
+the per-step, per-slot emit loop after a chunk is read."""
+from harness import spans
+
+LAYER, UNIT, BETTER = "engine scheduler", "ms", "lower"
+SOURCE, MOVES = "program_span", "gap_p95_ms"
+
+
+def read(ctx):
+    return spans.idle_ms_per_step(ctx, "omnia.engine.emit")

@@ -58,6 +58,11 @@ PURITY_FILES_PREFIXES: tuple[str, ...] = (
     # threads + gate arithmetic); a traced body here would be the same
     # bug class.
     "omnia_tpu/engine/devloop.py",
+    # The engine-loop phase spans are host annotations on the profiler's
+    # clock; one opened inside a traced body would run once, at trace
+    # time, and name nothing.
+    "omnia_tpu/engine/phases.py",
+    "omnia_tpu/engine/lifecycle.py",
 )
 
 #: Call heads that trace their function argument(s).

@@ -29,6 +29,7 @@ outside it (the repo's lock-scope rule, omnia_tpu/analysis/locks.py).
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -105,9 +106,16 @@ class ChunkDrainer:
 
     Replaces the old per-chunk ``omnia-chunk-sync`` daemon threads the
     watchdog path used to spawn: same timeout semantics, zero thread
-    churn on the hot path."""
+    churn on the hot path.
 
-    def __init__(self, name: str = "omnia-chunk-drainer"):
+    ``span`` opens the context each readback runs in: the engine passes
+    its ``omnia.engine.ring_drain`` profiler phase (engine/phases.py —
+    this module stays jax-free), and what it yields is told the tokens
+    read when it is truthy."""
+
+    def __init__(self, name: str = "omnia-chunk-drainer",
+                 span: Callable[[], Any] = contextlib.nullcontext):
+        self._span = span
         self._queue: queue.Queue = queue.Queue()
         self._lock = threading.Lock()
         self.drains = 0         # guarded-by: _lock
@@ -132,7 +140,10 @@ class ChunkDrainer:
 
                 if entry.pre_sleep_s > 0.0:
                     time.sleep(entry.pre_sleep_s)
-                arr = np.asarray(entry.toks)
+                with self._span() as sp:
+                    arr = np.asarray(entry.toks)
+                    if sp:
+                        sp.set_metadata(tokens=int(arr.size))
                 entry.result = arr
             except Exception as exc:  # noqa: BLE001 - parked for the engine thread
                 # A readback can die mid-recovery (the engine freed the
@@ -295,8 +306,10 @@ class DevLoopState:
     watchdog threads either way); ``decode_ring=0`` with no watchdog
     builds nothing at all."""
 
-    def __init__(self, ring: int, gate: bool = True):
+    def __init__(self, ring: int, gate: bool = True,
+                 drain_span: Callable[[], Any] = contextlib.nullcontext):
         self.ring = ring
+        self._drain_span = drain_span
         # Undrained-chunk capacity: the pipeline may hold this many
         # dispatched-but-unprocessed chunks before dispatch must stall
         # (ring_full_stalls). Watchdog-only engines (ring=0) keep the
@@ -319,7 +332,7 @@ class DevLoopState:
         if d is None or d.poisoned:
             if d is not None:
                 d.stop()
-            d = ChunkDrainer()
+            d = ChunkDrainer(span=self._drain_span)
             self._drainer = d
         return d
 

@@ -39,6 +39,7 @@ construction, submission, warmup, and the thread lifecycle.
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import logging
 import threading
@@ -48,12 +49,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from omnia_tpu.engine import phases
 from omnia_tpu.engine.coldstart import PHASE_CODES, ColdStartTracker
 from omnia_tpu.engine.devloop import DevLoopState, validate_decode_ring
 from omnia_tpu.engine.faults import FaultPlan
 from omnia_tpu.engine.flight import FlightRecorder
 from omnia_tpu.engine.interleave import _InflightPrefill, _InterleaveMixin
 from omnia_tpu.engine.lifecycle import _LifecycleMixin
+from omnia_tpu.engine.phases import phase
 from omnia_tpu.engine.paged import (
     _PagedKVMixin,
     dp_divisibility_error,
@@ -313,7 +316,10 @@ class InferenceEngine(
         # with decode_ring=0 and no watchdog (the guarded no-op: no
         # thread, no state, no extra attribute reads on the hot path).
         self._devloop: Optional[DevLoopState] = (
-            DevLoopState(engine_cfg.decode_ring)
+            DevLoopState(
+                engine_cfg.decode_ring,
+                drain_span=functools.partial(phase, phases.RING_DRAIN),
+            )
             if engine_cfg.decode_ring > 0 or engine_cfg.watchdog_s is not None
             else None
         )
@@ -344,6 +350,20 @@ class InferenceEngine(
             "tokens_generated": 0,
             "prefill_steps": 0,
             "decode_steps": 0,
+            # Where the scheduler decides (scheduler.py
+            # _count_decode_dispatch): one dispatch per program call that
+            # decodes, so decode_steps / decode_dispatches is the
+            # realised chunk; _single counts calls of the one-step decode
+            # program; decode_slot_steps sums live slots x steps at
+            # dispatch (occupancy where the batch is formed);
+            # pipeline_flushes counts the flushes a waiting request
+            # forced; programs_compiled_serving the programs asked of the
+            # compiler after warmup() returned (engine/warmup.py).
+            "decode_dispatches": 0,
+            "decode_dispatches_single": 0,
+            "decode_slot_steps": 0,
+            "pipeline_flushes": 0,
+            "programs_compiled_serving": 0,
             "extend_steps": 0,
             "prefill_tokens": 0,
             "prefix_reuse_tokens": 0,
@@ -647,6 +667,19 @@ class InferenceEngine(
         flight recording on, the request's lifecycle is recorded and an
         `omnia.engine.request` child span is emitted into self.tracer —
         trace continuity from the facade down to TPU dispatch."""
+        with phase(phases.SUBMIT) as sp:
+            handle = self._submit(
+                prompt_tokens, params, session_id, grammar, deadline_s,
+                trace_ctx,
+            )
+            if sp:
+                sp.set_metadata(
+                    request_id=handle.request_id, n_prompt=len(prompt_tokens)
+                )
+            return handle
+
+    def _submit(self, prompt_tokens, params, session_id, grammar, deadline_s,
+                trace_ctx) -> RequestHandle:
         if self._fault_plan is not None and self._fault_plan.take_submit_fault():
             raise RuntimeError("injected flaky submit (FaultPlan)")
         rid = f"req-{next(self._req_counter)}"

@@ -522,9 +522,17 @@ def load_jsonl(path: str) -> list[dict]:
     return out
 
 
-def to_chrome_trace(events: list) -> dict:
+def to_chrome_trace(events: list,
+                    profiler_offset_s: Optional[float] = None) -> dict:
     """Convert flight events (dicts or :class:`FlightEvent`) into the
     Chrome trace event format (loadable in Perfetto / chrome://tracing).
+
+    ``profiler_offset_s`` lays the dump on a ``jax.profiler`` trace of the
+    same run: it is what to add to ``mono`` to land on the profiler's time
+    axis, i.e. the profiler's start of an ``omnia.engine.step`` span less
+    that span's ``mono_ns`` (engine/phases.py; ``python3
+    benchmark/harness/spans.py <trace dir>`` prints it). Without it the
+    timeline starts at the dump's earliest event.
 
     Layout: tid 0 is the engine's step row (decode chunks, mixed steps,
     prefill pieces, offload/restore, failover/resubmit markers); each
@@ -551,7 +559,10 @@ def to_chrome_trace(events: list) -> dict:
             return e["mono"] - attrs.get("seconds", 0.0)
         return e["mono"] - attrs.get("dispatch_s", 0.0) - attrs.get("sync_s", 0.0)
 
-    base = min(start_of(e) for e in evs)
+    if profiler_offset_s is None:
+        base = min(start_of(e) for e in evs)
+    else:
+        base = -profiler_offset_s
 
     def us(mono: float) -> float:
         return round((mono - base) * 1e6, 1)
@@ -646,9 +657,13 @@ def main(argv: Optional[list] = None) -> int:
     parser.add_argument("dump", help="jsonl dump (FlightRecorder.dump_jsonl)")
     parser.add_argument("-o", "--out", default=None,
                         help="output path (default: <dump>.trace.json)")
+    parser.add_argument("--profiler-offset-s", type=float, default=None,
+                        help="seconds to add to `mono` to land on a "
+                        "jax.profiler trace of the same run (printed by "
+                        "benchmark/harness/spans.py)")
     args = parser.parse_args(argv)
     events = load_jsonl(args.dump)
-    trace = to_chrome_trace(events)
+    trace = to_chrome_trace(events, args.profiler_offset_s)
     out_path = args.out or (args.dump + ".trace.json")
     with open(out_path, "w", encoding="utf-8") as f:
         json.dump(trace, f)

@@ -135,7 +135,7 @@ class _InterleaveMixin:
                 # surface in-flight finishes promptly — but keep
                 # dispatching FULL chunks: prefill waits never degrade
                 # the chunk pipeline under the token-budget policy.
-                self._flush_pipeline()
+                self._flush_for_waiting()
             if self._inflight and not self._dispatch_ahead_useful():
                 self._process_oldest_chunk()
             else:
@@ -312,7 +312,7 @@ class _InterleaveMixin:
              self._budget, self._key_data, dtoks) = out
         dispatch_s = time.monotonic() - t_dispatch
         self.metrics["decode_dispatch_s"] += dispatch_s
-        self.metrics["decode_steps"] += 1
+        self._count_decode_dispatch(1, len(active))
         self.metrics["mixed_steps"] += 1
         self.metrics["interleaved_prefill_tokens"] += take
         self.metrics["prefill_tokens"] += take

@@ -2,8 +2,9 @@
 
 The request-lifecycle robustness seam of :class:`InferenceEngine` (same
 seam-per-concern layout as the scheduler/session/placement mixins):
-starting/stopping the step loop, the graceful drain that stops admission
-and pages sessions out before shutdown, and the recovery path that turns
+starting/stopping the step loop, the housekeeping every step starts with
+(cancelled and expired requests reaped), the graceful drain that stops
+admission and pages sessions out before shutdown, and the recovery path that turns
 a failed (or watchdog-tripped) device step into failed handles plus a
 fresh device-state allocation instead of a silently dead engine.
 """
@@ -14,6 +15,7 @@ import logging
 import threading
 import time
 
+from omnia_tpu.engine.phases import IDLE_SLEEP, phase
 from omnia_tpu.engine.types import FinishReason, StreamEvent
 
 logger = logging.getLogger(__name__)
@@ -128,11 +130,104 @@ class _LifecycleMixin:
         while not self._stop_event.is_set():
             try:
                 if not self.step():
-                    time.sleep(0.001)
+                    with phase(IDLE_SLEEP):
+                        time.sleep(0.001)
             except Exception:  # pragma: no cover - engine must not die silently
                 logger.exception("engine step failed")
                 self._recover("engine step failed")
                 time.sleep(0.1)
+
+    def _housekeeping(self) -> None:
+        """What every step does before it schedules: cross-thread queues
+        drained, cancelled and expired requests reaped."""
+        self._drain_releases()
+        self._drain_imports()
+        self._drain_prefix_regs()
+        self._reap_cancelled()
+        self._reap_deadlines()
+
+    def _reap_cancelled(self):
+        for i, slot in enumerate(self._slots):
+            if slot.active and slot.handle.cancelled:
+                self._finish_slot(i, FinishReason.CANCELLED)
+        pf = self._prefilling
+        if pf is not None and pf.handle.cancelled:
+            # Half-prefilled slot (token-budget interleaving): consumed
+            # rows stay valid for the session, books are already exact.
+            self._abort_prefilling(FinishReason.CANCELLED)
+        reaped = []
+        with self._lock:
+            still = []
+            for req, handle in self._waiting:
+                if handle.cancelled:
+                    handle._push(
+                        StreamEvent(req.request_id, finish_reason=FinishReason.CANCELLED)
+                    )
+                    # A queue-cancelled request is as finished as a slot-
+                    # cancelled one: every submit reaches exactly one
+                    # terminal event AND one finished count.
+                    self.metrics["requests_finished"] += 1
+                    reaped.append(req.request_id)
+                else:
+                    still.append((req, handle))
+            self._waiting = still
+        if self._flight is not None:
+            # Terminal recording ends the request span (tracer export
+            # I/O) — never under the engine lock.
+            for rid in reaped:
+                self._flight.note_terminal(rid, FinishReason.CANCELLED.value)
+
+    def _reap_deadlines(self):
+        """Deadline enforcement at the step boundary: queued requests
+        past their TTL shed with DEADLINE before placement (they would
+        only add latency), and an active slot past its TTL finishes
+        early with its partial output (chunk granularity — the boundary
+        is checked between dispatches, not inside a compiled chunk).
+        Requests without a deadline cost one attribute check here —
+        deadline_s=None traffic takes the pre-existing path exactly."""
+        now = None
+        for i, slot in enumerate(self._slots):
+            if slot.active and slot.request.deadline_at is not None:
+                now = self.clock() if now is None else now
+                if now >= slot.request.deadline_at:
+                    self.metrics["deadline_exceeded"] += 1
+                    self._finish_slot(i, FinishReason.DEADLINE)
+        pf = self._prefilling
+        if pf is not None and pf.request.deadline_at is not None:
+            now = self.clock() if now is None else now
+            if now >= pf.request.deadline_at:
+                # Deadline landed mid-prefill (token-budget
+                # interleaving): shed with exact partial counts — the
+                # pieces consumed so far were metered per dispatch and
+                # their rows stay valid for the session.
+                self.metrics["deadline_exceeded"] += 1
+                self._abort_prefilling(FinishReason.DEADLINE)
+        reaped = []
+        with self._lock:
+            if not any(r.deadline_at is not None for r, _h in self._waiting):
+                return
+            now = self.clock() if now is None else now
+            still = []
+            for req, handle in self._waiting:
+                if req.deadline_at is not None and now >= req.deadline_at:
+                    handle._push(
+                        StreamEvent(
+                            req.request_id,
+                            finish_reason=FinishReason.DEADLINE,
+                            num_prompt_tokens=len(req.prompt_tokens),
+                        )
+                    )
+                    # Shed-from-queue is still a terminal: every submit
+                    # reaches exactly one final event and one finish.
+                    self.metrics["deadline_exceeded"] += 1
+                    self.metrics["requests_finished"] += 1
+                    reaped.append(req.request_id)
+                else:
+                    still.append((req, handle))
+            self._waiting = still
+        if self._flight is not None:
+            for rid in reaped:  # span end = I/O, never under the lock
+                self._flight.note_terminal(rid, FinishReason.DEADLINE.value)
 
     def _recover(self, msg: str):
         """Fail in-flight requests and rebuild device state. A raise after

@@ -19,6 +19,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from omnia_tpu.engine.phases import PREFILL_DISPATCH, phase
 from omnia_tpu.engine.sessions import _SessionKV
 from omnia_tpu.engine.types import (
     MAX_DEVICE_STOP_IDS,
@@ -191,10 +192,13 @@ class _PlacementMixin:
                 sess.token_ids = []
         return slot_idx, sess, reuse
 
-    def _place_request(self, slot_idx: int, request: Request, handle: RequestHandle):
+    def _place_request(self, slot_idx: int, request: Request,
+                       handle: RequestHandle, span=None):
         """Prefill a request into a slot: fresh single-bucket prefill when
         there is no reusable prefix and the prompt fits one bucket,
-        otherwise chunked incremental extend from the reuse frontier."""
+        otherwise chunked incremental extend from the reuse frontier.
+        ``span`` is the caller's open ``omnia.engine.place`` phase, if a
+        profiler session is on: it is told the slot and the reuse."""
         prompt = request.prompt_tokens
         n = len(prompt)
         slot_idx, sess, reuse = self._prepare_session_slot(slot_idx, request)
@@ -296,6 +300,8 @@ class _PlacementMixin:
             self._geos = self._geos.at[slot_idx].set(
                 request.grammar.eos_id if request.grammar is not None else -1
             )
+        if span:
+            span.set_metadata(slot=slot_idx, reuse=reuse, seeded=seeded)
         first = int(first_tok)
         self._attach_grammar(slot_idx, request, first)
         if self._flight is not None:
@@ -340,14 +346,20 @@ class _PlacementMixin:
             return first_tok
         kd = self._sampling_key(slot_idx, sp)
         t0 = time.monotonic()
-        self._ck, self._cv, first_tok, new_kd = self._prefill_insert_fn(
-            self.params, self._ck, self._cv,
-            jnp.asarray(toks), jnp.asarray(pos),
-            jnp.int32(slot_idx), jnp.int32(n - 1), kd,
-            jnp.float32(sp.temperature), jnp.float32(sp.top_p),
-            jnp.int32(sp.top_k),
-            *self._grammar_args(request, sp),
-        )
+        with phase(PREFILL_DISPATCH) as span:
+            if span:
+                span.set_metadata(
+                    request_id=request.request_id if request else "",
+                    take=n, bucket=bucket,
+                )
+            self._ck, self._cv, first_tok, new_kd = self._prefill_insert_fn(
+                self.params, self._ck, self._cv,
+                jnp.asarray(toks), jnp.asarray(pos),
+                jnp.int32(slot_idx), jnp.int32(n - 1), kd,
+                jnp.float32(sp.temperature), jnp.float32(sp.top_p),
+                jnp.int32(sp.top_k),
+                *self._grammar_args(request, sp),
+            )
         if self._flight is not None and request is not None:
             self._flight.note_prefill_piece(
                 request.request_id, n, bucket, time.monotonic() - t0
@@ -397,9 +409,13 @@ class _PlacementMixin:
             # copy-on-writes the shared boundary page here.
             self._prepare_slot_write(slot_idx, off, off + b)
             t0 = time.monotonic()
-            self._ck, self._cv = self._extend_nosample_fn(
-                self.params, self._ck, self._cv, toks, pos, slot_arr, jnp.int32(off)
-            )
+            with phase(PREFILL_DISPATCH) as span:
+                if span:
+                    span.set_metadata(request_id=rid, take=take, bucket=b)
+                self._ck, self._cv = self._extend_nosample_fn(
+                    self.params, self._ck, self._cv, toks, pos, slot_arr,
+                    jnp.int32(off),
+                )
             if self._flight is not None and rid:
                 self._flight.note_prefill_piece(
                     rid, take, b, time.monotonic() - t0
@@ -409,12 +425,16 @@ class _PlacementMixin:
         self._prepare_slot_write(slot_idx, off, off + b)
         kd = self._sampling_key(slot_idx, sp)
         t0 = time.monotonic()
-        self._ck, self._cv, first_tok, new_kd = self._extend_fn(
-            self.params, self._ck, self._cv, toks, pos, slot_arr, jnp.int32(off),
-            jnp.int32(take - 1), kd,
-            jnp.float32(sp.temperature), jnp.float32(sp.top_p), jnp.int32(sp.top_k),
-            *self._grammar_args(request, sp),
-        )
+        with phase(PREFILL_DISPATCH) as span:
+            if span:
+                span.set_metadata(request_id=rid, take=take, bucket=b)
+            self._ck, self._cv, first_tok, new_kd = self._extend_fn(
+                self.params, self._ck, self._cv, toks, pos, slot_arr,
+                jnp.int32(off), jnp.int32(take - 1), kd,
+                jnp.float32(sp.temperature), jnp.float32(sp.top_p),
+                jnp.int32(sp.top_k),
+                *self._grammar_args(request, sp),
+            )
         if self._flight is not None and rid:
             self._flight.note_prefill_piece(rid, take, b, time.monotonic() - t0)
         self._key_data = self._key_data.at[slot_idx].set(new_kd)

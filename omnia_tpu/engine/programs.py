@@ -139,12 +139,14 @@ def build_programs(
     # no-op contract).
     paged = ecfg.kv_pages > 0
 
+    @jax.named_scope("insert")
     def _put(c, chunk, slot, start):
         """Write a slot-row chunk [L, 1, T, H, D] at rows [start, …)."""
         if paged:
             return pkv.put_chunk(c, chunk, slot, start)
         return cache_put(c, chunk, (0, slot, start))
 
+    @jax.named_scope("insert")
     def _take_slot(c, slot):
         """One slot's contiguous [L, 1, S, H, D] view, either layout."""
         if paged:
@@ -152,6 +154,7 @@ def build_programs(
         L, B, S, H, D = c.shape
         return cache_take(c, (0, slot, 0), (L, 1, S))
 
+    @jax.named_scope("insert")
     def _put_back(c, view, slot, write_start, t):
         """Write a slot view back after forward wrote rows
         [write_start, write_start + t): contiguous puts the whole view
@@ -163,6 +166,17 @@ def build_programs(
             return pkv.put_chunk(c, new, slot, write_start)
         return cache_put(c, view, (0, slot, 0))
 
+    def _sample_first(logits, last_idx, key_data, temp, top_p, top_k, g):
+        """A placed request's first token, sampled at its last prompt row."""
+        last = jax.lax.dynamic_slice(
+            logits, (0, last_idx, 0), (1, 1, logits.shape[-1])
+        )[:, 0]
+        tok, new_kd = sample_tokens_per_slot(
+            last, key_data[None], temp[None], top_p[None], top_k[None],
+            mask_bias=_first_bias(g),
+        )
+        return tok[0], new_kd[0]
+
     def prefill_insert(params, ck, cv, tokens, positions, slot, last_idx,
                        key_data, temp, top_p, top_k, *g):
         logits, k_chunk, v_chunk = llama.forward_prefill(
@@ -173,14 +187,9 @@ def build_programs(
         # quantizes the fresh rows inside cache_put (kv_quant mode).
         ck = _put(ck, k_chunk, slot, 0)
         cv = _put(cv, v_chunk, slot, 0)
-        last = jax.lax.dynamic_slice(
-            logits, (0, last_idx, 0), (1, 1, logits.shape[-1])
-        )[:, 0]
-        tok, new_kd = sample_tokens_per_slot(
-            last, key_data[None], temp[None], top_p[None], top_k[None],
-            mask_bias=_first_bias(g),
-        )
-        return ck, cv, tok[0], new_kd[0]
+        tok, new_kd = _sample_first(logits, last_idx, key_data, temp, top_p,
+                                    top_k, g)
+        return ck, cv, tok, new_kd
 
     prefill_insert_fn = jax.jit(prefill_insert, donate_argnums=(1, 2))
 
@@ -207,6 +216,7 @@ def build_programs(
 
     max_seq = ecfg.max_seq
 
+    @jax.named_scope("sample")
     def _grammar_rows(gtable, state):
         """Each slot's current [V] transition row, gathered with one
         dynamic_slice per slot unrolled over the static batch dim: XLA
@@ -286,28 +296,29 @@ def build_programs(
                 tok, key_data = sample_tokens_per_slot(
                     logits[:, 0], key_data, temp, top_p, top_k
                 )
-            # Position advances for the row just written (gated on
-            # active at step START); deactivation applies from the
-            # NEXT step on, mirroring the host's finish bookkeeping.
-            positions = jnp.where(
-                active, jnp.minimum(positions + 1, max_seq - 1), positions
-            )
-            budget = budget - active.astype(jnp.int32)
-            if ring:
-                # Deadline-step budget: decremented like the emission
-                # budget (on active at step START); exhaustion masks
-                # the slot from the NEXT step on, and the host mirror
-                # finishes it with DEADLINE at the same step index.
-                dl = dl - active.astype(jnp.int32)
-            hit_stop = (tok[:, None] == stop_ids).any(axis=1)
-            if ring and grammar_on:
-                # Per-slot grammar EOS (geos, -1 = none): token ids are
-                # >= 0, so non-grammar slots never match.
-                hit_stop = hit_stop | (tok == geos)
-            active = active & ~hit_stop & (budget > 0)
-            if ring:
-                active = active & (dl > 0)
-            tokens = jnp.where(active | hit_stop, tok, tokens)
+            with jax.named_scope("finish_mask"):
+                # Position advances for the row just written (gated on
+                # active at step START); deactivation applies from the
+                # NEXT step on, mirroring the host's finish bookkeeping.
+                positions = jnp.where(
+                    active, jnp.minimum(positions + 1, max_seq - 1), positions
+                )
+                budget = budget - active.astype(jnp.int32)
+                if ring:
+                    # Deadline-step budget: decremented like the emission
+                    # budget (on active at step START); exhaustion masks
+                    # the slot from the NEXT step on, and the host mirror
+                    # finishes it with DEADLINE at the same step index.
+                    dl = dl - active.astype(jnp.int32)
+                hit_stop = (tok[:, None] == stop_ids).any(axis=1)
+                if ring and grammar_on:
+                    # Per-slot grammar EOS (geos, -1 = none): token ids
+                    # are >= 0, so non-grammar slots never match.
+                    hit_stop = hit_stop | (tok == geos)
+                active = active & ~hit_stop & (budget > 0)
+                if ring:
+                    active = active & (dl > 0)
+                tokens = jnp.where(active | hit_stop, tok, tokens)
             out = (ck, cv, tokens, positions, active, budget, key_data)
             if grammar_on:
                 out += (gstate,)
@@ -513,14 +524,9 @@ def build_programs(
         t = tokens.shape[1]
         ck = _put_back(ck, k_slot, slot, write_start, t)
         cv = _put_back(cv, v_slot, slot, write_start, t)
-        last = jax.lax.dynamic_slice(
-            logits, (0, last_idx, 0), (1, 1, logits.shape[-1])
-        )[:, 0]
-        tok, new_kd = sample_tokens_per_slot(
-            last, key_data[None], temp[None], top_p[None], top_k[None],
-            mask_bias=_first_bias(g),
-        )
-        return ck, cv, tok[0], new_kd[0]
+        tok, new_kd = _sample_first(logits, last_idx, key_data, temp, top_p,
+                                    top_k, g)
+        return ck, cv, tok, new_kd
 
     extend_fn = jax.jit(extend, donate_argnums=(1, 2))
 
@@ -594,15 +600,10 @@ def build_programs(
                     # token (grammar start-state bias rides *pg, the
                     # extend signature exactly).
                     plast, pkd, ptemp, ptop_p, ptop_k = rest[:5]
-                    pg = tuple(rest[5:])
-                    last = jax.lax.dynamic_slice(
-                        plogits, (0, plast, 0), (1, 1, plogits.shape[-1])
-                    )[:, 0]
-                    ptok, new_pkd = sample_tokens_per_slot(
-                        last, pkd[None], ptemp[None], ptop_p[None],
-                        ptop_k[None], mask_bias=_first_bias(pg),
+                    extra = _sample_first(
+                        plogits, plast, pkd, ptemp, ptop_p, ptop_k,
+                        tuple(rest[5:]),
                     )
-                    extra = (ptok[0], new_pkd[0])
                 if spec:
                     # Verify window AFTER the piece (its garbage rows
                     # for the placing slot park at the piece frontier,

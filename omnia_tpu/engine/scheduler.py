@@ -34,8 +34,10 @@ from typing import Optional
 
 import numpy as np
 
+from omnia_tpu.engine import phases
 from omnia_tpu.engine.devloop import _InflightChunk
 from omnia_tpu.engine.faults import WatchdogTimeout
+from omnia_tpu.engine.phases import phase
 from omnia_tpu.engine.types import FinishReason, SamplingParams, StreamEvent
 
 
@@ -82,11 +84,32 @@ class _SchedulerMixin:
 
     def step(self) -> bool:
         """One scheduling step. Returns True if any work was done."""
-        self._drain_releases()
-        self._drain_imports()
-        self._drain_prefix_regs()
-        self._reap_cancelled()
-        self._reap_deadlines()
+        if not (phases.enabled() and self._has_work()):
+            self._housekeeping()
+            return self._schedule()
+        # A profiler session is on and there is work to look at: the step
+        # is a span on the profiler's clock, with every phase below nested
+        # in it. An idle poll writes none.
+        with phase(phases.STEP) as sp:
+            if sp:
+                sp.set_metadata(
+                    mono_ns=time.monotonic_ns(), queued=self.queue_depth(),
+                    inflight=len(self._inflight),
+                )
+            with phase(phases.HOUSEKEEPING):
+                self._housekeeping()
+            return self._schedule()
+
+    def _has_work(self) -> bool:
+        with self._lock:
+            if self._waiting or self._pending_releases or self._pending_imports:
+                return True
+        return (
+            bool(self._inflight) or self._prefilling is not None
+            or any(s.active for s in self._slots)
+        )
+
+    def _schedule(self) -> bool:
         if self._mixed_enabled():
             # Token-budget policy (engine/interleave.py): prefills split
             # into pieces fused with decode steps.
@@ -97,7 +120,7 @@ class _SchedulerMixin:
         if queued and self._inflight:
             # Requests are waiting: surface any in-flight finishes now so
             # their slots free up this step (TTFT over pipeline depth).
-            self._flush_pipeline()
+            self._flush_for_waiting()
             did = True
         pending, slot_idx = self._claim_pending()
         if pending is not None:
@@ -145,29 +168,41 @@ class _SchedulerMixin:
         incremented); returns ``(pending, slot_idx)`` or ``(None, None)``."""
         with self._lock:
             waiting = list(self._waiting)
-        pending = None
-        slot_idx = None
-        for cand in self._admission_order(waiting):
-            idx = self._slot_for(cand[0])
-            if idx is not None:
-                pending, slot_idx = cand, idx
-                break
-        if pending is not None:
-            with self._lock:
-                try:
-                    self._waiting.remove(pending)
-                    self._placing += 1
-                except ValueError:
-                    pending = None  # reaped concurrently
-        if pending is not None and self._flight is not None:
-            self._flight.note_claim(pending[0].request_id)
+        if not waiting:
+            return None, None
+        with phase(phases.CLAIM) as sp:
+            pending = None
+            slot_idx = None
+            for cand in self._admission_order(waiting):
+                idx = self._slot_for(cand[0])
+                if idx is not None:
+                    pending, slot_idx = cand, idx
+                    break
+            if pending is not None:
+                with self._lock:
+                    try:
+                        self._waiting.remove(pending)
+                        self._placing += 1
+                    except ValueError:
+                        pending = None  # reaped concurrently
+            if pending is not None:
+                if self._flight is not None:
+                    self._flight.note_claim(pending[0].request_id)
+                if sp:
+                    sp.set_metadata(request_id=pending[0].request_id)
         return pending, slot_idx
 
     def _place_pending(self, slot_idx, request, handle):
         """Monolithic placement with the prefill-failure error surface;
         balances the ``_placing`` claim taken by ``_claim_pending``."""
         try:
-            self._place_request(slot_idx, request, handle)
+            with phase(phases.PLACE) as sp:
+                if sp:
+                    sp.set_metadata(
+                        request_id=request.request_id,
+                        n_prompt=len(request.prompt_tokens),
+                    )
+                self._place_request(slot_idx, request, handle, span=sp)
         except Exception:
             # The request may not be attached to a slot yet, so
             # recovery's _fail_all would never reach its handle —
@@ -266,89 +301,6 @@ class _SchedulerMixin:
         chunk, the cost of pessimism would be no pipelining for any request
         that carries an EOS id (all real chat traffic)."""
         return self._remaining_work() > 0
-
-    def _reap_cancelled(self):
-        for i, slot in enumerate(self._slots):
-            if slot.active and slot.handle.cancelled:
-                self._finish_slot(i, FinishReason.CANCELLED)
-        pf = self._prefilling
-        if pf is not None and pf.handle.cancelled:
-            # Half-prefilled slot (token-budget interleaving): consumed
-            # rows stay valid for the session, books are already exact.
-            self._abort_prefilling(FinishReason.CANCELLED)
-        reaped = []
-        with self._lock:
-            still = []
-            for req, handle in self._waiting:
-                if handle.cancelled:
-                    handle._push(
-                        StreamEvent(req.request_id, finish_reason=FinishReason.CANCELLED)
-                    )
-                    # A queue-cancelled request is as finished as a slot-
-                    # cancelled one: every submit reaches exactly one
-                    # terminal event AND one finished count.
-                    self.metrics["requests_finished"] += 1
-                    reaped.append(req.request_id)
-                else:
-                    still.append((req, handle))
-            self._waiting = still
-        if self._flight is not None:
-            # Terminal recording ends the request span (tracer export
-            # I/O) — never under the engine lock.
-            for rid in reaped:
-                self._flight.note_terminal(rid, FinishReason.CANCELLED.value)
-
-    def _reap_deadlines(self):
-        """Deadline enforcement at the step boundary: queued requests
-        past their TTL shed with DEADLINE before placement (they would
-        only add latency), and an active slot past its TTL finishes
-        early with its partial output (chunk granularity — the boundary
-        is checked between dispatches, not inside a compiled chunk).
-        Requests without a deadline cost one attribute check here —
-        deadline_s=None traffic takes the pre-existing path exactly."""
-        now = None
-        for i, slot in enumerate(self._slots):
-            if slot.active and slot.request.deadline_at is not None:
-                now = self.clock() if now is None else now
-                if now >= slot.request.deadline_at:
-                    self.metrics["deadline_exceeded"] += 1
-                    self._finish_slot(i, FinishReason.DEADLINE)
-        pf = self._prefilling
-        if pf is not None and pf.request.deadline_at is not None:
-            now = self.clock() if now is None else now
-            if now >= pf.request.deadline_at:
-                # Deadline landed mid-prefill (token-budget
-                # interleaving): shed with exact partial counts — the
-                # pieces consumed so far were metered per dispatch and
-                # their rows stay valid for the session.
-                self.metrics["deadline_exceeded"] += 1
-                self._abort_prefilling(FinishReason.DEADLINE)
-        reaped = []
-        with self._lock:
-            if not any(r.deadline_at is not None for r, _h in self._waiting):
-                return
-            now = self.clock() if now is None else now
-            still = []
-            for req, handle in self._waiting:
-                if req.deadline_at is not None and now >= req.deadline_at:
-                    handle._push(
-                        StreamEvent(
-                            req.request_id,
-                            finish_reason=FinishReason.DEADLINE,
-                            num_prompt_tokens=len(req.prompt_tokens),
-                        )
-                    )
-                    # Shed-from-queue is still a terminal: every submit
-                    # reaches exactly one final event and one finish.
-                    self.metrics["deadline_exceeded"] += 1
-                    self.metrics["requests_finished"] += 1
-                    reaped.append(req.request_id)
-                else:
-                    still.append((req, handle))
-            self._waiting = still
-        if self._flight is not None:
-            for rid in reaped:  # span end = I/O, never under the lock
-                self._flight.note_terminal(rid, FinishReason.DEADLINE.value)
 
     def _fault_sleep_s(self) -> float:
         """Injected hang/slow-sync seconds for the next chunk readback
@@ -479,8 +431,23 @@ class _SchedulerMixin:
                 toks,
             ) = fn(*args)
         self.metrics["decode_dispatch_s"] += time.monotonic() - t_dispatch
-        self.metrics["decode_steps"] += int(toks.shape[0])
         return toks
+
+    def _count_decode_dispatch(self, steps: int, live: int,
+                               single: bool = False) -> None:
+        """One program call that decodes, counted where the batch is
+        formed: ``decode_steps / decode_dispatches`` is the realised
+        chunk, ``decode_dispatches_single`` the calls of the one-step
+        decode program, and ``decode_slot_steps`` the slots live at
+        dispatch times the steps asked, so ``decode_slot_steps /
+        (decode_steps * num_slots)`` is occupancy without reckoning it
+        from tokens."""
+        m = self.metrics
+        m["decode_steps"] += steps
+        m["decode_dispatches"] += 1
+        m["decode_slot_steps"] += live * steps
+        if single:
+            m["decode_dispatches_single"] += 1
 
     def _remaining_work(self) -> int:
         """Max over active slots of tokens still to emit beyond steps
@@ -524,23 +491,30 @@ class _SchedulerMixin:
         is deactivated on-device the same step, so it stops writing rows;
         any rows it DID write past its valid frontier are tolerated by the
         sessionful bookkeeping (garbage only at rows ≥ session length)."""
-        active = [
-            (i, s.request.request_id) for i, s in enumerate(self._slots) if s.active
-        ]
-        chunk = 1 if single else self._pick_chunk()
-        # Paged pool: extend every active slot's pages past its write
-        # frontier BEFORE the chunk dispatches (engine/paged.py) — a
-        # decode write must never land through a trash table entry.
-        self._prealloc_decode_pages(chunk)
-        dl_steps = (
-            self._deadline_steps() if self.cfg.decode_ring > 0 else None
-        )
-        t_dispatch = time.monotonic()
-        toks = self._run_decode_step(chunk=chunk, dl_steps=dl_steps)
-        # The dispatch wall rides the in-flight entry so the flight
-        # recorder can pair it with the (deferred) sync wall into one
-        # per-chunk dispatch-vs-sync event.
-        self._push_inflight(toks, active, time.monotonic() - t_dispatch, dl_steps)
+        with phase(phases.DECODE_DISPATCH) as sp:
+            active = [
+                (i, s.request.request_id)
+                for i, s in enumerate(self._slots) if s.active
+            ]
+            chunk = 1 if single else self._pick_chunk()
+            if sp:
+                sp.set_metadata(chunk=chunk, active=len(active), single=single)
+            # Paged pool: extend every active slot's pages past its write
+            # frontier BEFORE the chunk dispatches (engine/paged.py) — a
+            # decode write must never land through a trash table entry.
+            self._prealloc_decode_pages(chunk)
+            dl_steps = (
+                self._deadline_steps() if self.cfg.decode_ring > 0 else None
+            )
+            t_dispatch = time.monotonic()
+            toks = self._run_decode_step(chunk=chunk, dl_steps=dl_steps)
+            self._count_decode_dispatch(chunk, len(active), single=chunk == 1)
+            # The dispatch wall rides the in-flight entry so the flight
+            # recorder can pair it with the (deferred) sync wall into one
+            # per-chunk dispatch-vs-sync event.
+            self._push_inflight(
+                toks, active, time.monotonic() - t_dispatch, dl_steps
+            )
 
     def _deadline_steps(self) -> np.ndarray:
         """Per-slot deadline budget in decode STEPS for the next ring
@@ -596,13 +570,16 @@ class _SchedulerMixin:
 
     def _process_oldest_chunk(self):
         ch = self._inflight.popleft()
+        drained = ch.entry is not None
         t_sync = time.monotonic()
-        # [K, B] — ONE sync per chunk; with a drain entry this only
-        # blocks for whatever the drainer hasn't finished yet.
-        host_tokens = self._sync_chunk_host(ch.toks, ch.entry)
+        with phase(phases.CHUNK_SYNC) as sp:
+            if sp:
+                sp.set_metadata(chunk=int(ch.toks.shape[0]), drained=drained)
+            # [K, B] — ONE sync per chunk; with a drain entry this only
+            # blocks for whatever the drainer hasn't finished yet.
+            host_tokens = self._sync_chunk_host(ch.toks, ch.entry)
         sync_s = time.monotonic() - t_sync
         self.metrics["decode_sync_s"] += sync_s
-        drained = ch.entry is not None
         if drained:
             self.metrics["ring_drains"] += 1
         dv = self._devloop
@@ -614,6 +591,32 @@ class _SchedulerMixin:
             self._flight.note_decode_chunk(
                 K, ch.dispatch_s, sync_s, len(ch.active), drained=drained
             )
+        with phase(phases.EMIT) as sp:
+            if sp:
+                m = self.metrics
+                tok0, fin0 = m["tokens_generated"], m["requests_finished"]
+            self._emit_chunk(ch, host_tokens)
+            if sp:
+                sp.set_metadata(
+                    tokens=m["tokens_generated"] - tok0,
+                    finished=m["requests_finished"] - fin0,
+                )
+        if (
+            dv is not None and dv.gate is not None
+            and self.clock is time.monotonic
+        ):
+            # One gate tick per processed chunk (the spec-gate idiom):
+            # realized tok/s with async drain permitted vs suppressed
+            # decides whether the NEXT dispatch hands its readback to
+            # the drainer. Skipped under an injected logical clock
+            # (lockstep), where a wall-clock decision could diverge
+            # the replicated step streams.
+            dv.gate.tick(time.monotonic(), self.metrics["tokens_generated"])
+            self.metrics["decode_ring_gate_state"] = dv.gate.state_code()
+
+    def _emit_chunk(self, ch, host_tokens) -> None:
+        """The per-step, per-slot emission of one synced chunk [K, B]."""
+        K = int(host_tokens.shape[0])
         for k in range(K):
             stepped = False
             for i, rid in ch.active:
@@ -644,22 +647,19 @@ class _SchedulerMixin:
                 if ch.dl_steps is not None:
                     self.metrics["early_exit_steps"] += K - k
                 break
-        if (
-            dv is not None and dv.gate is not None
-            and self.clock is time.monotonic
-        ):
-            # One gate tick per processed chunk (the spec-gate idiom):
-            # realized tok/s with async drain permitted vs suppressed
-            # decides whether the NEXT dispatch hands its readback to
-            # the drainer. Skipped under an injected logical clock
-            # (lockstep), where a wall-clock decision could diverge
-            # the replicated step streams.
-            dv.gate.tick(time.monotonic(), self.metrics["tokens_generated"])
-            self.metrics["decode_ring_gate_state"] = dv.gate.state_code()
 
     def _flush_pipeline(self):
         while self._inflight:
             self._process_oldest_chunk()
+
+    def _flush_for_waiting(self):
+        """The flush a waiting request forces: every chunk in flight is
+        read now, so that finished slots free up for it."""
+        self.metrics["pipeline_flushes"] += 1
+        with phase(phases.FLUSH_PIPELINE) as sp:
+            if sp:
+                sp.set_metadata(chunks=len(self._inflight))
+            self._flush_pipeline()
 
     def _emit_token(self, slot_idx: int, token: int):
         slot = self._slots[slot_idx]

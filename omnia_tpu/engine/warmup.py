@@ -47,6 +47,7 @@ import dataclasses
 import logging
 import threading
 import time
+import weakref
 from typing import Callable, Optional
 
 import jax
@@ -62,6 +63,43 @@ from omnia_tpu.engine.types import MAX_DEVICE_STOP_IDS, SamplingParams
 from omnia_tpu.models.kv_quant import kv_device, kv_host
 
 logger = logging.getLogger(__name__)
+
+#: What JAX records for every program asked of the compiler while its
+#: persistent cache is on, hit or miss (benchmark/harness/compiles.py
+#: counts the same event): after warmup() a request is a shape that
+#: warm-up did not cover.
+_COMPILE_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+# JAX's listeners are process-wide and cannot be taken off again, so the
+# process registers ONE, the first time an engine finishes warming, and
+# the engines it serves are held weakly.
+_warmed: "weakref.WeakSet" = weakref.WeakSet()  # guarded-by: _warmed_lock
+_warmed_lock = threading.Lock()
+_listening = False  # guarded-by: _warmed_lock
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    with _warmed_lock:
+        engines = list(_warmed)
+    me = threading.current_thread()
+    for eng in engines:
+        # The event does not say who asked. An engine's request path
+        # compiles on its own loop thread; an engine stepped inline has
+        # none and counts every program the process asks for.
+        if eng._thread is None or eng._thread is me:
+            eng.metrics["programs_compiled_serving"] += 1
+
+
+def _watch_serving_compiles(engine) -> None:
+    """From now on ``programs_compiled_serving`` counts for ``engine``."""
+    global _listening
+    with _warmed_lock:
+        if not _listening:
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _listening = True
+        _warmed.add(engine)
+
 
 #: Families whose programs take no model params — compilable while the
 #: checkpoint is still streaming (the weight/compile overlap set).
@@ -577,6 +615,7 @@ class _WarmupMixin:
             time.monotonic() - t0, len(tasks), len(self._decode_fns),
             threads, hits, misses, sessions,
         )
+        _watch_serving_compiles(self)
 
     def _warmup_scatters(self) -> None:
         """Placement bookkeeping runs a handful of tiny scatter programs

@@ -197,7 +197,8 @@ def _moe_mlp(h, p, cfg: ModelConfig):
     """Mixtral MoE — routing + dispatch live in ops/moe.py. Decode-sized
     token counts take the exact all-expert path; prefill/train token counts
     take GShard-style capacity dispatch (experts sharded over "tp")."""
-    return moe_mlp(h, p, cfg.num_experts_per_tok)
+    with jax.named_scope("moe.experts"):  # the router inside is moe.route
+        return moe_mlp(h, p, cfg.num_experts_per_tok)
 
 
 def _write_kv(cache, new, start):
@@ -230,13 +231,20 @@ def _write_kv(cache, new, start):
 
 def _layer(x, p, cfg: ModelConfig, cos, sin, q_positions, ck, cv, write_start,
            attn_fn=None, mesh=None):
+    """One block. Every op sits in a named scope (metadata only), so a
+    device trace names it whatever number XLA gives its fusion; what the
+    layer scan itself emits (its slice of this layer's weights, K and V
+    out of the stacked arrays, and the write-back) carries only the
+    scan's own ``layers``."""
     B, T, D = x.shape
-    h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
-    q = qdot(h, p["attn"]["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-    k = qdot(h, p["attn"]["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    v = qdot(h, p["attn"]["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn.qkv"):
+        h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
+        q = qdot(h, p["attn"]["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
+        k = qdot(h, p["attn"]["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+        v = qdot(h, p["attn"]["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    with jax.named_scope("attn.rope"):
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     if ck is None:
         # Self-contained path (training, or fresh prefill): attend over this
@@ -245,23 +253,37 @@ def _layer(x, p, cfg: ModelConfig, cos, sin, q_positions, ck, cv, write_start,
         ck_eff, cv_eff = k, v
         out_pair = (k, v)
     else:
-        ck = _write_kv(ck, k, write_start)
-        cv = _write_kv(cv, v, write_start)
+        with jax.named_scope("kv.update"):
+            ck = _write_kv(ck, k, write_start)
+            cv = _write_kv(cv, v, write_start)
         ck_eff, cv_eff = ck, cv
         out_pair = (ck, cv)
 
-    if attn_fn is not None:
-        attn = attn_fn(q, ck_eff, cv_eff, q_positions)
-    else:
-        attn = gqa_attention(q, ck_eff, cv_eff, q_positions, mesh=mesh)
-    x = x + qdot(attn.reshape(B, T, -1), p["attn"]["wo"])
+    with jax.named_scope("attn.decode" if T == 1 else "attn.prefill"):
+        if attn_fn is not None:
+            attn = attn_fn(q, ck_eff, cv_eff, q_positions)
+        else:
+            attn = gqa_attention(q, ck_eff, cv_eff, q_positions, mesh=mesh)
+    with jax.named_scope("attn.out"):
+        x = x + qdot(attn.reshape(B, T, -1), p["attn"]["wo"])
 
-    h2 = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
-    if cfg.is_moe:
-        x = x + _moe_mlp(h2, p["mlp"], cfg)
-    else:
-        x = x + _dense_mlp(h2, p["mlp"])
+    with jax.named_scope("mlp"):
+        h2 = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
+        if cfg.is_moe:
+            x = x + _moe_mlp(h2, p["mlp"], cfg)
+        else:
+            x = x + _dense_mlp(h2, p["mlp"])
     return x, out_pair[0], out_pair[1]
+
+
+def _embed(params, cfg: ModelConfig, tokens, q_positions):
+    """Token embeddings and the rotary tables of their positions."""
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        cos, sin = rope_cos_sin(
+            q_positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        )
+    return x, cos, sin
 
 
 def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None):
@@ -273,8 +295,7 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None)
     Returns (logits [B, T, V] f32, k_chunk, v_chunk [L, B, T, Hkv, D]).
     attn_fn overrides the attention op (the ring-prefill path).
     """
-    x = params["embed"][tokens]
-    cos, sin = rope_cos_sin(q_positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    x, cos, sin = _embed(params, cfg, tokens, q_positions)
 
     def body(x, p):
         x, k, v = _layer(
@@ -282,7 +303,8 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None)
         )
         return x, (k, v)
 
-    x, (k_chunk, v_chunk) = jax.lax.scan(body, x, params["layers"])
+    with jax.named_scope("layers"):
+        x, (k_chunk, v_chunk) = jax.lax.scan(body, x, params["layers"])
     return _logits(params, cfg, x), k_chunk, v_chunk
 
 
@@ -313,10 +335,11 @@ def forward_prefill_ring(params, cfg: ModelConfig, tokens, q_positions, mesh):
 
 
 def _logits(params, cfg: ModelConfig, x):
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    if cfg.tie_embeddings:
-        return jnp.dot(x, params["embed"].T).astype(jnp.float32)
-    return qdot(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        if cfg.tie_embeddings:
+            return jnp.dot(x, params["embed"].T).astype(jnp.float32)
+        return qdot(x, params["lm_head"]).astype(jnp.float32)
 
 
 def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
@@ -329,8 +352,7 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
     decode kernel needs it named (ops/attention.py).
     Returns (logits [B, T, V] f32, new_cache_k, new_cache_v).
     """
-    x = params["embed"][tokens]  # [B,T,D]
-    cos, sin = rope_cos_sin(q_positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    x, cos, sin = _embed(params, cfg, tokens, q_positions)  # x [B,T,D]
 
     if is_paged(cache_k):
         # Paged caches: the pool's [L] axis scans with the layers; the
@@ -347,9 +369,10 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
             )
             return x, (ck.pool, cv.pool)
 
-        x, (new_k, new_v) = jax.lax.scan(
-            pbody, x, (params["layers"], cache_k.pool, cache_v.pool)
-        )
+        with jax.named_scope("layers"):
+            x, (new_k, new_v) = jax.lax.scan(
+                pbody, x, (params["layers"], cache_k.pool, cache_v.pool)
+            )
         return _logits(params, cfg, x), PagedKV(new_k, tk), PagedKV(new_v, tv)
 
     def body(carry, scanned):
@@ -360,9 +383,10 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
         )
         return x, (ck, cv)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache_k, cache_v)
-    )
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = jax.lax.scan(
+            body, x, (params["layers"], cache_k, cache_v)
+        )
     return _logits(params, cfg, x), new_k, new_v
 
 
@@ -374,9 +398,8 @@ def forward_embed(params, cfg: ModelConfig, tokens, mask):
     tokens: int32 [B, T]; mask: [B, T] (1 = real token, 0 = pad).
     """
     B, T = tokens.shape
-    x = params["embed"][tokens]
     q_positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-    cos, sin = rope_cos_sin(q_positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    x, cos, sin = _embed(params, cfg, tokens, q_positions)
 
     def body(x, p):
         x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None)
@@ -395,9 +418,8 @@ def forward_train(params, cfg: ModelConfig, tokens):
     tokens: int32 [B, T] → logits [B, T, V] f32.
     """
     B, T = tokens.shape
-    x = params["embed"][tokens]
     q_positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-    cos, sin = rope_cos_sin(q_positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling)
+    x, cos, sin = _embed(params, cfg, tokens, q_positions)
 
     def body(x, p):
         x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None)

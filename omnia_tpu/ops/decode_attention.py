@@ -204,6 +204,7 @@ def decode_gqa_attention_paged(
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="decode_gqa_attention_paged",
     )(*operands)
     return out.reshape(B, H, D)
 
@@ -285,5 +286,6 @@ def decode_gqa_attention(
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="decode_gqa_attention",
     )(*operands)
     return out.reshape(B, H, D)

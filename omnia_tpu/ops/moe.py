@@ -39,10 +39,11 @@ def route_sparse(h, router_w, num_experts_per_tok: int):
     renormalize kept weights to sum 1. The single source of routing truth —
     both MoE implementations derive from it so they can never diverge.
     """
-    logits = jnp.dot(h, router_w).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, num_experts_per_tok)
-    top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(h, router_w).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_i = jax.lax.top_k(probs, num_experts_per_tok)
+        top_w = top_w / top_w.sum(axis=-1, keepdims=True)
     return top_w, top_i
 
 

@@ -182,6 +182,7 @@ def sample_tokens(
     return jnp.where(temperature <= 0.0, greedy_tok, sampled_tok)
 
 
+@jax.named_scope("sample")
 def sample_tokens_per_slot(
     logits: jnp.ndarray,
     key_data: jnp.ndarray,

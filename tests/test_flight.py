@@ -149,6 +149,29 @@ class TestRecorderUnit:
                      if e["name"] == "decode_chunk")
         assert chunk["ts"] == 0.0  # the dump's origin is its true start
 
+    def test_chrome_trace_on_the_profilers_time_axis(self, tmp_path):
+        """With the offset an ``omnia.engine.step`` span gives (its start
+        on the profiler's clock less its ``mono_ns``), every event lands
+        at mono + offset instead of at the dump's own origin — from the
+        function and from the CLI alike."""
+        rec, clock = self._clocked()
+        clock[0] = 100.0
+        rec.note_submit("r", 4)
+        clock[0] = 100.5
+        rec.note_claim("r")
+        offset = 2.25 - 100.0  # the profiler's 2.25 s is this clock's 100 s
+        doc = to_chrome_trace(rec.events(), profiler_offset_s=offset)
+        queue = next(e for e in doc["traceEvents"] if e["name"] == "queue")
+        assert queue["ts"] == pytest.approx(2.25e6)
+        assert queue["dur"] == pytest.approx(0.5e6)
+        assert to_chrome_trace(rec.events())["traceEvents"][-1]["ts"] == 0.0
+        dump, out = str(tmp_path / "f.jsonl"), str(tmp_path / "t.json")
+        rec.dump_jsonl(dump)
+        assert flight_main([dump, "-o", out, "--profiler-offset-s", str(offset)]) == 0
+        cli = json.load(open(out))
+        assert next(e for e in cli["traceEvents"]
+                    if e["name"] == "queue")["ts"] == pytest.approx(2.25e6)
+
     def test_terminal_without_submit_is_tolerated(self):
         """A terminal for a request the recorder never saw (ring
         recycled mid-incident) records an empty breakdown, not a crash."""

@@ -1,0 +1,352 @@
+"""Engine-loop phase spans on the profiler's clock (engine/phases.py), the
+named scopes inside the step programs, and the counters where the
+scheduler decides.
+
+The span tests run a tiny engine under ``jax.profiler`` on the CPU and read
+the ``.xplane.pb`` back the way ``benchmark/harness/spans.py`` does; the
+off-path test shows what a process nobody profiles pays; the lowering test
+shows that the scopes are metadata and nothing else.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import glob
+import os
+import re
+import time
+import tracemalloc
+
+import jax
+import pytest
+
+from omnia_tpu.engine import EngineConfig, InferenceEngine, phases
+from omnia_tpu.engine.types import SamplingParams
+from omnia_tpu.models import get_config
+
+GREEDY = SamplingParams(temperature=0.0, max_tokens=8)
+
+
+def _tiny_engine(**over) -> InferenceEngine:
+    base = dict(num_slots=2, max_seq=64, prefill_buckets=(8,), dtype="float32",
+                max_sessions=0, decode_chunk=4, decode_pipeline=2)
+    base.update(over)
+    return InferenceEngine(get_config("test-tiny"), EngineConfig(**base), seed=3)
+
+
+def _drain(eng: InferenceEngine) -> None:
+    while eng.step():
+        pass
+
+
+@contextlib.contextmanager
+def _profiled(tmp_path):
+    """A profiler session with the host tracer on; yields a function that
+    returns the ``omnia.*`` spans per thread line once the session ended."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    stopped = False
+
+    def spans() -> dict:
+        assert stopped, "read the spans after the with block"
+        from jax.profiler import ProfileData
+
+        path = sorted(glob.glob(
+            os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+        out: dict = {}  # threads share a line name: keyed by position
+        for plane in ProfileData.from_file(path).planes:
+            for i, line in enumerate(plane.lines):
+                evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                       for e in line.events if e.name.startswith("omnia.")]
+                if evs:
+                    out[plane.name, i] = evs
+        return out
+
+    try:
+        yield spans
+    finally:
+        jax.profiler.stop_trace()
+        stopped = True
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def test_phase_spans_nest_under_step_and_carry_request_ids(tmp_path):
+    eng = _tiny_engine(num_slots=1)
+    eng.generate([1, 2, 3], GREEDY)  # compile outside the session
+    with _profiled(tmp_path) as spans:
+        t_lo = time.monotonic_ns()
+        first = eng.submit([1, 2, 3], GREEDY)
+        eng.step()  # places `first`, leaves one chunk in flight
+        second = eng.submit([4, 5, 6, 7], GREEDY)  # waits for the one slot
+        _drain(eng)
+        t_hi = time.monotonic_ns()
+    assert first.collect_tokens(timeout=60)[0] and second.collect_tokens(timeout=60)[0]
+    (events,) = spans().values()  # one thread did everything
+
+    steps = _named(events, phases.STEP)
+    assert steps
+    for name in (phases.HOUSEKEEPING, phases.FLUSH_PIPELINE, phases.CLAIM,
+                 phases.PLACE, phases.PREFILL_DISPATCH, phases.DECODE_DISPATCH,
+                 phases.CHUNK_SYNC, phases.EMIT):
+        found = _named(events, name)
+        assert found, name
+        for _n, start, end, _attrs in found:
+            assert any(s <= start and end <= e for _sn, s, e, _a in steps), name
+    # The caller's submit is a span of its own, outside every step.
+    submits = _named(events, phases.SUBMIT)
+    assert [s[3]["request_id"] for s in submits] == [first.request_id, second.request_id]
+    assert submits[1][3]["n_prompt"] == 4
+    for _n, start, end, _a in submits:
+        assert not any(s < end and start < e for _sn, s, e, _a2 in steps)
+
+    # A prefill program call nests in its placement, and both name the
+    # request the claim named.
+    claimed = [c[3]["request_id"] for c in _named(events, phases.CLAIM) if c[3]]
+    assert claimed == [first.request_id, second.request_id]
+    places = _named(events, phases.PLACE)
+    assert [p[3]["request_id"] for p in places] == claimed
+    assert places[1][3]["n_prompt"] == 4 and places[1][3]["slot"] == 0
+    for piece in _named(events, phases.PREFILL_DISPATCH):
+        owner = [p for p in places if p[1] <= piece[1] and piece[2] <= p[2]]
+        assert len(owner) == 1
+        assert piece[3]["request_id"] == owner[0][3]["request_id"]
+        assert piece[3]["bucket"] == 8
+
+    # The step's mono_ns is the flight recorder's clock at its entry.
+    assert all(t_lo <= s[3]["mono_ns"] <= t_hi for s in steps)
+    assert {"queued", "inflight"} <= set(steps[0][3])
+    # While `second` waited, every dispatch asked for the one-step program.
+    waiting = [d[3] for d in _named(events, phases.DECODE_DISPATCH)
+               if places[0][2] <= d[1] and d[2] <= places[1][1]]
+    assert waiting[0]["chunk"] == 4 and not waiting[0]["single"]
+    assert all(d["chunk"] == 1 and d["single"] and d["active"] == 1
+               for d in waiting[1:])
+    flush = _named(events, phases.FLUSH_PIPELINE)
+    assert len(flush) == 1 and flush[0][3]["chunks"] == 1
+    emitted = sum(e[3]["tokens"] for e in _named(events, phases.EMIT))
+    assert emitted == 2 * (GREEDY.max_tokens - 1)  # the first comes from prefill
+    assert sum(e[3]["finished"] for e in _named(events, phases.EMIT)) == 2
+
+
+def test_engine_thread_sleep_and_ring_drain_spans(tmp_path):
+    eng = _tiny_engine(decode_ring=2)
+    eng.generate([1, 2, 3], GREEDY)
+    eng.start()
+    try:
+        with _profiled(tmp_path) as spans:
+            handle = eng.submit([1, 2, 3], GREEDY)
+            handle.collect_tokens(timeout=60)
+            time.sleep(0.02)  # an idle engine sleeps between polls
+    finally:
+        eng.stop()
+    by_thread = spans()
+    loop = next(evs for evs in by_thread.values() if _named(evs, phases.STEP))
+    sleeps = _named(loop, phases.IDLE_SLEEP)
+    steps = _named(loop, phases.STEP)
+    assert sleeps
+    # An idle poll writes no step span: sleeps outnumber steps once idle,
+    # and no sleep lies inside a step.
+    assert not any(s <= sl[1] and sl[2] <= e for sl in sleeps for _n, s, e, _a in steps)
+    drains = [e for evs in by_thread.values() for e in _named(evs, phases.RING_DRAIN)]
+    assert drains and all(d[3]["tokens"] > 0 for d in drains)
+    assert not _named(loop, phases.RING_DRAIN)  # the drainer's own thread
+
+
+def test_counters_against_a_scripted_schedule():
+    """One slot, chunk of 4, pipeline of 2. `a` is placed and decodes alone;
+    `b` arrives while a chunk of `a` is in flight and waits for the slot."""
+    eng = _tiny_engine(num_slots=1)
+    m = eng.metrics
+    a = eng.submit([1, 2, 3], GREEDY)
+    eng.step()
+    # Placed (first token from the prefill), one chunk of 4 dispatched and
+    # left in flight: nobody waits, so the pipeline may run two deep.
+    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (1, 0)
+    assert (m["decode_steps"], m["decode_slot_steps"]) == (4, 4)
+    assert m["pipeline_flushes"] == 0
+    b = eng.submit([4, 5, 6], GREEDY)
+    eng.step()
+    # `b` waits: the chunk in flight is flushed for it (4 of a's 7 decode
+    # tokens), no slot is free, and the next dispatch is one step, read back
+    # at once.
+    assert m["pipeline_flushes"] == 1
+    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 1)
+    assert (m["decode_steps"], m["decode_slot_steps"]) == (5, 5)
+    _drain(eng)
+    assert len(a.collect_tokens(timeout=60)[0]) == 8
+    assert len(b.collect_tokens(timeout=60)[0]) == 8
+    # a: 4 + 1 + 1 + 1 steps, the last three one-step calls while b waited
+    # with nothing in flight (so no further flush). b decodes alone: 4 + 3,
+    # where the tail picks the smallest variant that covers it (the chunk
+    # of 4, one step of it garbage).
+    assert m["pipeline_flushes"] == 1
+    assert m["decode_dispatches_single"] == 3
+    assert m["decode_dispatches"] == 4 + 2
+    assert m["decode_steps"] == 7 + 8
+    assert m["decode_slot_steps"] == m["decode_steps"]  # one slot, always live
+    assert m["tokens_generated"] == 16
+
+
+def test_live_slots_are_counted_where_the_batch_is_formed():
+    eng = _tiny_engine(num_slots=2, decode_chunk=1, decode_pipeline=1)
+    m = eng.metrics
+    eng.submit([1, 2, 3], SamplingParams(temperature=0.0, max_tokens=3))
+    eng.submit([4, 5, 6], SamplingParams(temperature=0.0, max_tokens=5))
+    _drain(eng)
+    # Step 1 places the first request and decodes it alone (1 live slot);
+    # from then on both are live until the short one ends.
+    assert m["decode_dispatches"] == m["decode_steps"] == m["decode_dispatches_single"]
+    assert m["decode_steps"] < m["decode_slot_steps"] < 2 * m["decode_steps"]
+
+
+def test_programs_compiled_after_warmup_are_counted(tmp_path, monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # The event is recorded only while the persistent cache is on, as it
+    # is wherever the engine serves.
+    prev_dir = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    cc.reset_cache()
+    try:
+        eng = _tiny_engine()
+        assert eng.metrics["programs_compiled_serving"] == 0
+        eng.warmup(sessions=False)
+        assert eng.metrics["programs_compiled_serving"] == 0
+        eng.generate([1, 2, 3], GREEDY)
+        assert eng.metrics["programs_compiled_serving"] == 0  # all warmed
+        import numpy as np
+
+        # A program warm-up never saw (numpy in: nothing else to compile).
+        jax.jit(lambda x: x * 3 + 1)(np.ones((7, 3), np.float32))
+        assert eng.metrics["programs_compiled_serving"] == 1
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        cc.reset_cache()
+
+
+def test_off_path_allocates_nothing_and_records_nothing():
+    """No profiler session, flight_events=0: no recorder, and the helper
+    hands out one shared falsy object without allocating."""
+    eng = _tiny_engine()
+    assert eng._flight is None
+    assert not phases.enabled()
+    eng.generate([1, 2, 3], GREEDY)
+    assert phases.phase(phases.STEP) is phases.phase(phases.EMIT) is phases._OFF
+    with phases.phase(phases.CLAIM) as sp:
+        assert not sp
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        for _ in range(2000):
+            with phases.phase(phases.STEP) as sp:
+                if sp:
+                    sp.set_metadata(mono_ns=time.monotonic_ns())
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    here = [tracemalloc.Filter(True, phases.__file__)]
+    grown = sum(s.size_diff for s in
+                after.filter_traces(here).compare_to(before.filter_traces(here), "filename"))
+    assert grown == 0
+    # An idle engine's step takes the same path as before: no span object.
+    assert eng.step() is False
+
+
+def _decode_args(eng):
+    return (eng.params, eng._ck, eng._cv, eng._tokens, eng._positions,
+            eng._active, eng._budget, eng._stop_ids, eng._key_data,
+            eng._temp, eng._top_p, eng._top_k)
+
+
+def _prefill_args(eng):
+    import jax.numpy as jnp
+
+    toks = jnp.zeros((1, 8), jnp.int32)
+    return (eng.params, eng._ck, eng._cv, toks, toks, jnp.int32(0), jnp.int32(2),
+            eng._key_data[0], jnp.float32(0.0), jnp.float32(1.0), jnp.int32(0))
+
+
+def _extend_args(eng):
+    import jax.numpy as jnp
+
+    toks = jnp.zeros((1, 8), jnp.int32)
+    return (eng.params, eng._ck, eng._cv, toks, toks, jnp.int32(0), jnp.int32(0),
+            jnp.int32(2), eng._key_data[0], jnp.float32(0.0), jnp.float32(1.0),
+            jnp.int32(0))
+
+
+class _NoScope(contextlib.ContextDecorator):
+    """`jax.named_scope` taken out: a context manager and a decorator that
+    names nothing. (The sampler's own `sample` scope is applied when its
+    module is imported and stays.)"""
+
+    def __init__(self, _name: str) -> None:
+        pass
+
+    def __enter__(self) -> "_NoScope":
+        return self
+
+    def __exit__(self, *_exc) -> bool:
+        return False
+
+
+def _in_op_names(scope_path: str, lowered_text: str) -> bool:
+    """Whether some op's name path (`loc("jit(f)/layers/mlp/dot_general"...)`
+    in the lowered text; relative inside a called body) runs through it."""
+    return re.search(r'["/]' + re.escape(scope_path) + "/", lowered_text) is not None
+
+
+@pytest.mark.parametrize("program,args,scopes", [
+    ("_decode_fn", _decode_args,
+     ("embed", "layers", "attn.qkv", "attn.rope", "kv.update", "attn.decode",
+      "attn.out", "mlp", "lm_head", "sample", "finish_mask")),
+    ("_prefill_insert_fn", _prefill_args,
+     ("embed", "layers", "attn.qkv", "attn.prefill", "attn.out", "mlp",
+      "lm_head", "insert", "sample")),
+    ("_extend_fn", _extend_args,
+     ("kv.update", "attn.prefill", "mlp", "insert", "sample")),
+])
+def test_named_scopes_are_in_the_op_metadata_and_change_nothing_else(
+        monkeypatch, program, args, scopes):
+    scoped = _tiny_engine()
+    lowered = getattr(scoped, program).lower(*args(scoped))
+    with_names = lowered.as_text(debug_info=True)
+    for scope in scopes:
+        assert _in_op_names(scope, with_names), scope
+    # The same program traced with the scopes taken out, as the parent
+    # traced it: the computation is the same text, only locations differ.
+    monkeypatch.setattr(jax, "named_scope", _NoScope)
+    bare = _tiny_engine()
+    assert not _in_op_names(scopes[0], getattr(bare, program).lower(
+        *args(bare)).as_text(debug_info=True))
+    assert getattr(bare, program).lower(*args(bare)).as_text() == lowered.as_text()
+
+
+def test_greedy_tokens_equal_the_unscoped_programs(monkeypatch):
+    prompts = ([1, 2, 3], [9, 8, 7, 6, 5], list(range(20, 40)))
+    scoped = _tiny_engine()
+    want = [scoped.generate(p, GREEDY)[0] for p in prompts]
+    monkeypatch.setattr(jax, "named_scope", _NoScope)
+    bare = _tiny_engine()
+    assert [bare.generate(p, GREEDY)[0] for p in prompts] == want
+
+
+def test_moe_scopes():
+    import jax.numpy as jnp
+
+    from omnia_tpu.models import llama
+
+    cfg = get_config("test-tiny-moe")
+    params = llama.init_params(cfg, jax.random.key(0), jnp.float32)
+    ck, cv = llama.init_kv_cache(cfg, 2, 16, dtype=jnp.float32)
+    toks = jnp.zeros((2, 1), jnp.int32)
+    text = jax.jit(
+        lambda p, ck, cv: llama.forward(p, cfg, toks, toks, ck, cv, toks[:, 0])
+    ).lower(params, ck, cv).as_text(debug_info=True)
+    assert _in_op_names("mlp/moe.experts", text)
+    assert _in_op_names("moe.experts/moe.route", text)
