@@ -157,42 +157,39 @@ def gather_pages(pool: Any, idx: Any) -> Any:
 # ---------------------------------------------------------------------------
 
 
-def _flat_scatter(arr: Any, flat_idx: Any, vals: Any, lead: int) -> Any:
-    """Scatter rows into pool ``arr`` with page axes flattened:
-    ``arr [*lead, P, PS, rest]``, ``flat_idx [...]`` into the P*PS row
-    axis, ``vals [*lead, *idx_shape, rest]``."""
+def _flat_scatter(arr: Any, flat_idx: Any, vals: Any, layer: Any = None) -> Any:
+    """Scatter rows into the whole pool ``arr [L, P, PS, rest]`` with the
+    page axes flattened: ``flat_idx [...]`` indexes the P*PS row axis.
+    With ``layer`` the rows ``vals [*idx_shape, rest]`` land in that one
+    layer; without, ``vals [L, *idx_shape, rest]`` land in every layer."""
     s = arr.shape
-    a2 = arr.reshape(s[:lead] + (s[lead] * s[lead + 1],) + s[lead + 2:])
-    if lead == 0:
-        a2 = a2.at[flat_idx].set(vals)
-    else:
-        a2 = a2.at[:, flat_idx].set(vals)
-    return a2.reshape(s)
+    a2 = arr.reshape((s[0], s[1] * s[2]) + s[3:])
+    where = slice(None) if layer is None else layer
+    return a2.at[where, flat_idx].set(vals).reshape(s)
 
 
-def write_rows(cache: PagedKV, new: Any, start: Any) -> PagedKV:
-    """The paged edition of llama._write_kv: per-layer pool ``[P, PS,
-    Hkv, D]`` ← new rows ``[B, T, Hkv, D]`` at per-slot row offsets
-    ``start [B]``, routed through the page table. Fresh rows quantize
-    through the SAME ``quantize_rows`` as the contiguous write seam, so
-    stored int8 rows are bit-identical across layouts."""
+def write_rows(cache: PagedKV, new: Any, start: Any, layer: Any) -> PagedKV:
+    """The paged edition of llama._write_kv: layer ``layer`` of the whole
+    pool ``[L, P, PS, Hkv, D]`` ← new rows ``[B, T, Hkv, D]`` at per-slot
+    row offsets ``start [B]``, routed through the page table, in place.
+    Fresh rows quantize through the SAME ``quantize_rows`` as the
+    contiguous write seam, so stored int8 rows are bit-identical across
+    layouts."""
     table, pool = cache.table, cache.pool
     ps = cache.page_tokens
     np_ = table.shape[1]
-    t = new.q.shape[1] if is_quant_kv(new) else new.shape[1]
+    t = new.shape[1]
     r = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # [B, T]
     r = jnp.minimum(r, np_ * ps - 1)
     page = jnp.take_along_axis(table, r // ps, axis=1)  # [B, T]
     flat = page * ps + (r % ps)
 
     if is_quant_kv(pool):
-        qn = new if is_quant_kv(new) else quantize_rows(new)
-        pool = QuantKV(
-            _flat_scatter(pool.q, flat, qn.q.astype(pool.q.dtype), 0),
-            _flat_scatter(pool.s, flat, qn.s.astype(pool.s.dtype), 0),
-        )
-    else:
-        pool = _flat_scatter(pool, flat, new.astype(pool.dtype), 0)
+        new = quantize_rows(new)
+    pool = kv_map(
+        lambda arr, n: _flat_scatter(arr, flat, n.astype(arr.dtype), layer),
+        pool, new,
+    )
     return PagedKV(pool, table)
 
 
@@ -212,13 +209,13 @@ def put_chunk(cache: PagedKV, chunk: Any, slot: Any, start: Any) -> PagedKV:
     if is_quant_kv(pool):
         qc = chunk if is_quant_kv(chunk) else quantize_rows(chunk)
         pool = QuantKV(
-            _flat_scatter(pool.q, flat, qc.q[:, 0].astype(pool.q.dtype), 1),
-            _flat_scatter(pool.s, flat, qc.s[:, 0].astype(pool.s.dtype), 1),
+            _flat_scatter(pool.q, flat, qc.q[:, 0].astype(pool.q.dtype)),
+            _flat_scatter(pool.s, flat, qc.s[:, 0].astype(pool.s.dtype)),
         )
     else:
         if is_quant_kv(chunk):
             raise TypeError("quantized chunk written into an unquantized pool")
-        pool = _flat_scatter(pool, flat, chunk[:, 0].astype(pool.dtype), 1)
+        pool = _flat_scatter(pool, flat, chunk[:, 0].astype(pool.dtype))
     return PagedKV(pool, table)
 
 

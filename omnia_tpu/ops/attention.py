@@ -28,8 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from omnia_tpu.models.kv_quant import is_quant_kv
-from omnia_tpu.models.paged_kv import gather_view, is_paged
+from omnia_tpu.models.kv_quant import is_quant_kv, kv_map
+from omnia_tpu.models.paged_kv import PagedKV, gather_view, is_paged
 
 _NEG_INF = -1e30
 
@@ -84,20 +84,34 @@ def check_decode_kernel(cache_len: int, num_kv_heads: int, paged: bool,
         )
 
 
-def _decode_path(q, k_cache, v_cache, q_positions, mesh):
-    """The Pallas decode kernel, or None when it is routed off. Under a
-    mesh the call is wrapped in a shard_map: slots over "dp" (when they
+def _map_rows(fn, cache):
+    """``fn`` over the row arrays of a cache of any layout (plain,
+    QuantKV, or a PagedKV's pool; the page table passes through)."""
+    if is_paged(cache):
+        return PagedKV(kv_map(fn, cache.pool), cache.table)
+    return kv_map(fn, cache)
+
+
+def _decode_path(q, k_cache, v_cache, q_positions, mesh, layer):
+    """The Pallas decode kernel over layer ``layer`` of the whole cache,
+    or None when it is routed off. Under a mesh the call is wrapped in a
+    shard_map: the layer axis unsharded, slots over "dp" (when they
     divide; a single-slot view is replicated), heads over "tp",
     positions and the page table sliced with the slots."""
     if not _kernel_on():
         return None
     from omnia_tpu.ops import decode_attention as dk
 
+    if layer is None:
+        # A per-layer cache is a whole cache of one layer (a reshape).
+        k_cache, v_cache = (_map_rows(lambda a: a[None], c)
+                            for c in (k_cache, v_cache))
+        layer = 0
     interpret = _pallas_decode_mode() == "interpret"
     paged = is_paged(k_cache)
-    B, Hkv = q.shape[0], k_cache.shape[2]
+    B, (S, Hkv) = q.shape[0], k_cache.shape[-3:-1]
     dp, tp = (mesh.shape["dp"], mesh.shape["tp"]) if mesh is not None else (1, 1)
-    check_decode_kernel(k_cache.shape[1], Hkv, paged, dp, tp)
+    check_decode_kernel(S, Hkv, paged, dp, tp)
     b = "dp" if B % dp == 0 else None
     if paged:
         # The kernel gathers K/V blocks through the scalar-prefetched
@@ -107,29 +121,30 @@ def _decode_path(q, k_cache, v_cache, q_positions, mesh):
                                    interpret=interpret)
         k, v = k_cache.pool, v_cache.pool
         mid, mid_specs = (k_cache.table,), (P(b, None),)
-        kv_spec, scale_spec = P(None, None, "tp", None), P(None, None, "tp")
+        kv_spec = P(None, None, None, "tp", None)
     else:
         kernel = functools.partial(
             dk.decode_gqa_attention, interpret=interpret,
-            block_s=min(_DECODE_BLOCK_S, k_cache.shape[1]),
+            block_s=min(_DECODE_BLOCK_S, S),
         )
         k, v = k_cache, v_cache
         mid, mid_specs = (), ()
-        kv_spec, scale_spec = P(b, None, "tp", None), P(b, None, "tp")
+        kv_spec = P(None, b, None, "tp", None)
     scales, scale_specs = (), ()
     if is_quant_kv(k):
         # int8 KV: the kernel streams the int8 rows + scale rows and
         # applies the scales in VMEM (half the HBM KV traffic).
-        scales, scale_specs = (k.s, v.s), (scale_spec, scale_spec)
+        scales, scale_specs = (k.s, v.s), (P(*kv_spec[:-1]),) * 2
         k, v = k.q, v.q
-    operands = (q[:, 0], k, v, *mid, q_positions[:, 0], *scales)
+    operands = (q[:, 0], k, v, *mid, q_positions[:, 0],
+                jnp.asarray(layer, jnp.int32), *scales)
     if mesh is not None:
         from omnia_tpu.parallel.compat import shard_map
 
         head_spec = P(b, "tp", None)
         kernel = shard_map(
             kernel, mesh,
-            in_specs=(head_spec, kv_spec, kv_spec, *mid_specs, P(b),
+            in_specs=(head_spec, kv_spec, kv_spec, *mid_specs, P(b), P(),
                       *scale_specs),
             out_specs=head_spec,
         )
@@ -142,6 +157,7 @@ def gqa_attention(
     v_cache: jnp.ndarray,
     q_positions: jnp.ndarray,
     mesh=None,
+    layer=None,
 ) -> jnp.ndarray:
     """Attention of queries against a slot-contiguous KV cache.
 
@@ -155,14 +171,26 @@ def gqa_attention(
     q_positions: int [B, T] absolute position of each query token.
     mesh: the engine's device mesh when operands are sharded over it
         (only the decode kernel needs it; the einsum path is GSPMD's).
+    layer: when given, k_cache/v_cache are the WHOLE caches
+        [L, B, S, Hkv, D] (or the whole paged pool) and attention runs
+        over layer ``layer`` of them. The decode kernel indexes the layer
+        in its block index map, so no layer is sliced out in front of
+        it; the einsum path reads that one layer.
     Returns [B, T, H, D].
     """
     B, T, H, D = q.shape
 
     if T == 1:
-        fused = _decode_path(q, k_cache, v_cache, q_positions, mesh)
+        fused = _decode_path(q, k_cache, v_cache, q_positions, mesh, layer)
         if fused is not None:
             return fused
+
+    if layer is not None:
+        # One read of the layer; nothing is written back.
+        def take(arr):
+            return jax.lax.dynamic_index_in_dim(arr, layer, 0, keepdims=False)
+
+        k_cache, v_cache = _map_rows(take, k_cache), _map_rows(take, v_cache)
 
     if is_paged(k_cache):
         # XLA `take` fallback (prefill/extend/verify, and decode off

@@ -5,6 +5,10 @@ The XLA path (ops/attention.py gqa_attention) always reads all S rows —
 a slot at position 500 in an 8192-row cache pays 16× the necessary HBM
 traffic. This kernel makes traffic proportional to the ACTUAL context:
 
+- the kernel takes the WHOLE cache ``[L, B, S, Hkv, D]`` and the layer
+  index as a scalar-prefetch operand: the kv BlockSpec squeezes the layer
+  axis and its index map starts with ``layer``, so the layer scan in
+  models/llama.py carries one buffer and never slices a layer out of it.
 - grid = (B, S // BLOCK_S); the kv BlockSpec index_map CLAMPS the block
   index to the slot's last needed block (scalar-prefetched positions).
   Pallas skips the DMA when consecutive grid steps map to the same
@@ -33,6 +37,7 @@ _NEG_INF = -1e30
 
 
 def _decode_kernel(
+    layer_ref,      # SMEM [1] (scalar prefetch; the index maps consume it)
     positions_ref,  # SMEM [B] (scalar prefetch)
     q_ref,          # VMEM [1, Hkv, G, D]
     k_ref,          # VMEM [1, BLOCK_S, Hkv, D] (bf16, or int8 when quantized)
@@ -50,6 +55,7 @@ def _decode_kernel(
         ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
     else:
         out_ref, m_ref, l_ref, acc_ref = rest
+    del layer_ref
     b = pl.program_id(0)
     s = pl.program_id(1)
     num_s = pl.num_programs(1)
@@ -113,8 +119,8 @@ def _decode_kernel(
         ).astype(out_ref.dtype)
 
 
-def _decode_kernel_paged(positions_ref, table_ref, *rest, block_s, scale,
-                         quantized=False):
+def _decode_kernel_paged(layer_ref, positions_ref, table_ref, *rest, block_s,
+                         scale, quantized=False):
     """Paged edition (EngineConfig.kv_pages): identical online-softmax
     body — the page table acts entirely through the BlockSpec index
     maps, which resolve logical block ``s`` of slot ``b`` to pool page
@@ -122,73 +128,73 @@ def _decode_kernel_paged(positions_ref, table_ref, *rest, block_s, scale,
     ids, so the math is the contiguous kernel's, block for block."""
     del table_ref  # consumed by the index maps only
     return _decode_kernel(
-        positions_ref, *rest, block_s=block_s, scale=scale,
+        layer_ref, positions_ref, *rest, block_s=block_s, scale=scale,
         quantized=quantized,
     )
+
+
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index as the [1] int32 array scalar prefetch takes."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_gqa_attention_paged(
     q: jnp.ndarray,          # [B, H, D] (rotary already applied)
-    pool_k: jnp.ndarray,     # [P, PAGE_S, Hkv, D] (int8 when scales given)
-    pool_v: jnp.ndarray,     # [P, PAGE_S, Hkv, D]
+    pool_k: jnp.ndarray,     # [L, P, PAGE_S, Hkv, D] (int8 when scales given)
+    pool_v: jnp.ndarray,     # [L, P, PAGE_S, Hkv, D]
     table: jnp.ndarray,      # int32 [B, NP] — per-slot page table
     positions: jnp.ndarray,  # int32 [B] — current decode position per slot
-    k_scale: jnp.ndarray = None,  # f32 [P, PAGE_S, Hkv] (int8-KV mode)
+    layer: jnp.ndarray,      # int32 [] — the layer of the pool to attend over
+    k_scale: jnp.ndarray = None,  # f32 [L, P, PAGE_S, Hkv] (int8-KV mode)
     v_scale: jnp.ndarray = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """→ [B, H, D]. Paged-attention decode: one kernel block per KV
-    page (``block_s == PAGE_S``), gathered from the pool through the
-    scalar-prefetched page table. Blocks past a slot's position re-map
+    """→ [B, H, D]. Paged-attention decode over layer ``layer`` of the
+    whole pool: one kernel block per KV page (``block_s == PAGE_S``),
+    gathered from the pool through the scalar-prefetched page table
+    and layer index. Blocks past a slot's position re-map
     to its last needed page (DMA dedup) and skip compute, so HBM
     traffic stays proportional to actual context length — and free/dead
     pages are simply never addressed (tests poison them to prove it)."""
     B, H, D = q.shape
-    P, page_s, Hkv = pool_k.shape[0], pool_k.shape[1], pool_k.shape[2]
+    page_s, Hkv = pool_k.shape[2], pool_k.shape[3]
     G = H // Hkv
     num_s = table.shape[1]
     quantized = k_scale is not None
     positions = positions.astype(jnp.int32)
     table = table.astype(jnp.int32)
 
-    def kv_index(b, s, pos_ref, tbl_ref):
+    def kv_index(b, s, layer_ref, pos_ref, tbl_ref):
         # Clamp to the last needed LOGICAL block, then translate through
         # the page table: repeated steps re-map to the same pool page,
         # which Pallas recognizes as resident and skips the DMA.
-        return (tbl_ref[b, jnp.minimum(s, pos_ref[b] // page_s)], 0, 0)
+        page = tbl_ref[b, jnp.minimum(s, pos_ref[b] // page_s)]
+        return (layer_ref[0], page, 0, 0)
+
+    def q_index(b, s, *_):
+        return (b, 0, 0, 0)
 
     kv_spec = pl.BlockSpec(
-        (1, page_s, Hkv, D),
-        lambda b, s, pos_ref, tbl_ref: kv_index(b, s, pos_ref, tbl_ref) + (0,),
+        (None, 1, page_s, Hkv, D), lambda *a: kv_index(*a) + (0,),
         memory_space=pltpu.VMEM,
     )
-    in_specs = [
-        pl.BlockSpec(
-            (1, Hkv, G, D), lambda b, s, pos_ref, tbl_ref: (b, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        kv_spec,
-        kv_spec,
-    ]
-    operands = [positions, table, q.reshape(B, Hkv, G, D), pool_k, pool_v]
+    q_spec = pl.BlockSpec((1, Hkv, G, D), q_index, memory_space=pltpu.VMEM)
+    in_specs = [q_spec, kv_spec, kv_spec]
+    operands = [_layer_operand(layer), positions, table,
+                q.reshape(B, Hkv, G, D), pool_k, pool_v]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (1, page_s, Hkv),
-            lambda b, s, pos_ref, tbl_ref: kv_index(b, s, pos_ref, tbl_ref),
-            memory_space=pltpu.VMEM,
+            (None, 1, page_s, Hkv), kv_index, memory_space=pltpu.VMEM,
         )
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, num_s),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, Hkv, G, D), lambda b, s, pos_ref, tbl_ref: (b, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Hkv, G), jnp.float32),
             pltpu.VMEM((Hkv, G), jnp.float32),
@@ -212,21 +218,25 @@ def decode_gqa_attention_paged(
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_gqa_attention(
     q: jnp.ndarray,          # [B, H, D] (rotary already applied)
-    k_cache: jnp.ndarray,    # [B, S, Hkv, D] (int8 when scales given)
-    v_cache: jnp.ndarray,    # [B, S, Hkv, D]
+    k_cache: jnp.ndarray,    # [L, B, S, Hkv, D] (int8 when scales given)
+    v_cache: jnp.ndarray,    # [L, B, S, Hkv, D]
     positions: jnp.ndarray,  # int32 [B] — current decode position per slot
-    k_scale: jnp.ndarray = None,  # f32 [B, S, Hkv] (int8-KV mode)
+    layer: jnp.ndarray,      # int32 [] — the layer of the cache to attend over
+    k_scale: jnp.ndarray = None,  # f32 [L, B, S, Hkv] (int8-KV mode)
     v_scale: jnp.ndarray = None,
     block_s: int = DEFAULT_BLOCK_S,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """→ [B, H, D]. Requires S % block_s == 0 (engine sizes caches so).
+    """→ [B, H, D], attention over layer ``layer`` of the whole cache: only
+    that layer's blocks are ever addressed, and nothing is sliced out of
+    the cache before the call. Requires S % block_s == 0 (engine sizes
+    caches so).
 
     With k_scale/v_scale the caches are rowwise-int8 (models/kv_quant):
     the kernel streams half the KV bytes from HBM and applies the scales
     in VMEM on the score/prob matrices."""
     B, H, D = q.shape
-    S, Hkv = k_cache.shape[1], k_cache.shape[2]
+    S, Hkv = k_cache.shape[2], k_cache.shape[3]
     G = H // Hkv
     if S % block_s != 0:
         raise ValueError(f"cache length {S} not divisible by block {block_s}")
@@ -234,43 +244,35 @@ def decode_gqa_attention(
     num_s = S // block_s
     positions = positions.astype(jnp.int32)
 
-    def kv_index(b, s, pos_ref):
+    def kv_index(b, s, layer_ref, pos_ref):
         # Clamp to the last needed block: steps past the position re-map
         # to the same block, which Pallas recognizes as "already resident"
         # and skips the HBM→VMEM DMA.
-        return (b, jnp.minimum(s, pos_ref[b] // block_s), 0, 0)
+        return (layer_ref[0], b, jnp.minimum(s, pos_ref[b] // block_s), 0)
+
+    def q_index(b, s, *_):
+        return (b, 0, 0, 0)
 
     kv_spec = pl.BlockSpec(
-        (1, block_s, Hkv, D),
-        lambda b, s, pos_ref: kv_index(b, s, pos_ref),
+        (None, 1, block_s, Hkv, D), lambda *a: kv_index(*a) + (0,),
         memory_space=pltpu.VMEM,
     )
-    in_specs = [
-        pl.BlockSpec(
-            (1, Hkv, G, D), lambda b, s, pos_ref: (b, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        kv_spec,
-        kv_spec,
-    ]
-    operands = [positions, q.reshape(B, Hkv, G, D), k_cache, v_cache]
+    q_spec = pl.BlockSpec((1, Hkv, G, D), q_index, memory_space=pltpu.VMEM)
+    in_specs = [q_spec, kv_spec, kv_spec]
+    operands = [_layer_operand(layer), positions, q.reshape(B, Hkv, G, D),
+                k_cache, v_cache]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (1, block_s, Hkv),
-            lambda b, s, pos_ref: kv_index(b, s, pos_ref)[:3],
-            memory_space=pltpu.VMEM,
+            (None, 1, block_s, Hkv), kv_index, memory_space=pltpu.VMEM,
         )
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, num_s),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, Hkv, G, D), lambda b, s, pos_ref: (b, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Hkv, G), jnp.float32),
             pltpu.VMEM((Hkv, G), jnp.float32),

@@ -23,6 +23,41 @@ def _setup(B=4, S=512, H=8, Hkv=2, D=128, seed=0, dtype=jnp.float32):
     return q, k, v
 
 
+L = 3  # layers of the whole caches the kernel is handed
+LAYERS = pytest.mark.parametrize("layer", [0, L - 1])
+
+
+def _whole(x, layer):
+    """x as layer ``layer`` of an L-layer cache whose every other layer
+    is poison (NaN, or ±127 for int8 rows): the kernel takes the whole
+    cache and may address only the layer it is told."""
+    x = np.asarray(x)
+    poison = 127 if x.dtype == np.int8 else np.nan
+    out = np.full((L, *x.shape), poison, x.dtype)
+    out[layer] = x
+    return jnp.asarray(out)
+
+
+def _kernel(q, k, v, pos, layer=1, k_scale=None, v_scale=None, **kw):
+    """decode_gqa_attention on per-layer operands placed at ``layer``."""
+    if k_scale is not None:
+        kw.update(k_scale=_whole(k_scale, layer), v_scale=_whole(v_scale, layer))
+    return decode_gqa_attention(
+        q, _whole(k, layer), _whole(v, layer), pos, layer, interpret=True, **kw
+    )
+
+
+def _kernel_paged(q, pool_k, pool_v, table, pos, layer=1, k_scale=None,
+                  v_scale=None):
+    kw = {}
+    if k_scale is not None:
+        kw.update(k_scale=_whole(k_scale, layer), v_scale=_whole(v_scale, layer))
+    return decode_gqa_attention_paged(
+        q, _whole(pool_k, layer), _whole(pool_v, layer), table, pos, layer,
+        interpret=True, **kw
+    )
+
+
 def _paginate(k, v, page_s, free_pages=3, seed=7):
     """Scatter contiguous caches into a scrambled page pool + table
     (the first `free_pages` pool pages stay unreferenced — 'free')."""
@@ -42,12 +77,16 @@ def _paginate(k, v, page_s, free_pages=3, seed=7):
 
 
 class TestDecodeAttention:
+    @LAYERS
     @pytest.mark.parametrize("positions", [[0, 5, 255, 511], [37, 499, 256, 128]])
-    def test_matches_xla_reference(self, positions):
+    def test_matches_xla_reference(self, positions, layer):
+        """The kernel on layer ``layer`` of a 5-D cache equals the einsum
+        on that layer's slice; every other layer is NaN and changes
+        nothing."""
         q, k, v = _setup()
         pos = jnp.asarray(positions, dtype=jnp.int32)
         ref = gqa_attention(q, k, v, pos[:, None])[:, 0]
-        out = decode_gqa_attention(q[:, 0], k, v, pos, block_s=128, interpret=True)
+        out = _kernel(q[:, 0], k, v, pos, layer, block_s=128)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
     def test_rows_past_position_do_not_influence(self):
@@ -55,22 +94,19 @@ class TestDecodeAttention:
         kernel must produce identical output (those blocks are skipped)."""
         q, k, v = _setup(B=2, S=256, H=4, Hkv=2, D=128)
         pos = jnp.asarray([63, 190], dtype=jnp.int32)
-        out_clean = decode_gqa_attention(q[:, 0], k, v, pos, block_s=64, interpret=True)
+        out_clean = _kernel(q[:, 0], k, v, pos, block_s=64)
         k_poison, v_poison = np.asarray(k).copy(), np.asarray(v).copy()
         for b, p in enumerate([63, 190]):
             k_poison[b, p + 1:] = 1e9
             v_poison[b, p + 1:] = -1e9
-        out_poison = decode_gqa_attention(
-            q[:, 0], jnp.asarray(k_poison), jnp.asarray(v_poison), pos,
-            block_s=64, interpret=True,
-        )
+        out_poison = _kernel(q[:, 0], k_poison, v_poison, pos, block_s=64)
         np.testing.assert_allclose(np.asarray(out_clean), np.asarray(out_poison))
 
     def test_bf16_inputs(self):
         q, k, v = _setup(B=2, S=256, H=8, Hkv=4, D=128, dtype=jnp.bfloat16)
         pos = jnp.asarray([100, 200], dtype=jnp.int32)
         ref = gqa_attention(q, k, v, pos[:, None])[:, 0]
-        out = decode_gqa_attention(q[:, 0], k, v, pos, block_s=128, interpret=True)
+        out = _kernel(q[:, 0], k, v, pos, block_s=128)
         np.testing.assert_allclose(
             np.asarray(out, dtype=np.float32), np.asarray(ref, dtype=np.float32),
             atol=3e-2, rtol=3e-2,
@@ -79,11 +115,11 @@ class TestDecodeAttention:
     def test_indivisible_cache_rejected(self):
         q, k, v = _setup(B=1, S=100, H=2, Hkv=1, D=128)
         with pytest.raises(ValueError, match="divisible"):
-            decode_gqa_attention(q[:, 0], k, v, jnp.zeros((1,), jnp.int32),
-                                 block_s=64, interpret=True)
+            _kernel(q[:, 0], k, v, jnp.zeros((1,), jnp.int32), block_s=64)
 
+    @LAYERS
     @pytest.mark.parametrize("positions", [[0, 5, 255, 511], [37, 499, 256, 128]])
-    def test_quantized_matches_dequantized_reference(self, positions):
+    def test_quantized_matches_dequantized_reference(self, positions, layer):
         """int8-KV edition (models/kv_quant.py): the kernel streaming
         int8 rows + scale blocks must equal the XLA reference over the
         DEQUANTIZED cache to float epsilon — the scale application in
@@ -96,9 +132,9 @@ class TestDecodeAttention:
         ref = gqa_attention(
             q, kvq.dequantize_rows(qk), kvq.dequantize_rows(qv), pos[:, None]
         )[:, 0]
-        out = decode_gqa_attention(
-            q[:, 0], qk.q, qv.q, pos, k_scale=qk.s, v_scale=qv.s,
-            block_s=128, interpret=True,
+        out = _kernel(
+            q[:, 0], qk.q, qv.q, pos, layer, k_scale=qk.s, v_scale=qv.s,
+            block_s=128,
         )
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
@@ -111,9 +147,8 @@ class TestDecodeAttention:
         q, k, v = _setup(B=2, S=256, H=4, Hkv=2, D=128)
         pos = jnp.asarray([63, 190], dtype=jnp.int32)
         qk, qv = kvq.quantize_rows(k), kvq.quantize_rows(v)
-        clean = decode_gqa_attention(
-            q[:, 0], qk.q, qv.q, pos, k_scale=qk.s, v_scale=qv.s,
-            block_s=64, interpret=True,
+        clean = _kernel(
+            q[:, 0], qk.q, qv.q, pos, k_scale=qk.s, v_scale=qv.s, block_s=64,
         )
         ks_p, vs_p = np.asarray(qk.s).copy(), np.asarray(qv.s).copy()
         kq_p, vq_p = np.asarray(qk.q).copy(), np.asarray(qv.q).copy()
@@ -122,10 +157,8 @@ class TestDecodeAttention:
             vq_p[b, p + 1:] = -127
             ks_p[b, p + 1:] = 1e9
             vs_p[b, p + 1:] = 1e9
-        poisoned = decode_gqa_attention(
-            q[:, 0], jnp.asarray(kq_p), jnp.asarray(vq_p), pos,
-            k_scale=jnp.asarray(ks_p), v_scale=jnp.asarray(vs_p),
-            block_s=64, interpret=True,
+        poisoned = _kernel(
+            q[:, 0], kq_p, vq_p, pos, k_scale=ks_p, v_scale=vs_p, block_s=64,
         )
         np.testing.assert_allclose(np.asarray(clean), np.asarray(poisoned))
 
@@ -151,6 +184,7 @@ class TestDecodeAttention:
         finally:
             attn._pallas_decode_mode.cache_clear()
 
+    @LAYERS
     @pytest.mark.parametrize(
         "positions",
         [
@@ -159,18 +193,16 @@ class TestDecodeAttention:
             [63, 64, 127, 510],   # last row of a page / first of the next
         ],
     )
-    def test_paged_matches_contiguous_kernel(self, positions):
+    def test_paged_matches_contiguous_kernel(self, positions, layer):
         """Paged edition vs the contiguous kernel at the SAME block size
         over a scrambled page pool: the table only reorders DMAs, so the
         outputs must be bit-identical — including partial last pages and
         single-page sequences (within-block iota masking)."""
         q, k, v = _setup()
         pos = jnp.asarray(positions, dtype=jnp.int32)
-        ref = decode_gqa_attention(q[:, 0], k, v, pos, block_s=64, interpret=True)
+        ref = _kernel(q[:, 0], k, v, pos, L - 1 - layer, block_s=64)
         pool_k, pool_v, table = _paginate(k, v, page_s=64)
-        out = decode_gqa_attention_paged(
-            q[:, 0], pool_k, pool_v, table, pos, interpret=True
-        )
+        out = _kernel_paged(q[:, 0], pool_k, pool_v, table, pos, layer)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     def test_paged_free_and_dead_pages_never_contribute(self):
@@ -181,9 +213,7 @@ class TestDecodeAttention:
         q, k, v = _setup(B=2, S=256, H=4, Hkv=2, D=128)
         pos = jnp.asarray([63, 190], dtype=jnp.int32)
         pool_k, pool_v, table = _paginate(k, v, page_s=64)
-        clean = decode_gqa_attention_paged(
-            q[:, 0], pool_k, pool_v, table, pos, interpret=True
-        )
+        clean = _kernel_paged(q[:, 0], pool_k, pool_v, table, pos)
         kp, vp = np.asarray(pool_k).copy(), np.asarray(pool_v).copy()
         referenced = set(np.asarray(table).ravel().tolist())
         for pid in range(kp.shape[0]):
@@ -200,12 +230,11 @@ class TestDecodeAttention:
                 elif lo <= p < lo + 64:
                     kp[pid, p - lo + 1:] = 1e9  # partial-page tail
                     vp[pid, p - lo + 1:] = -1e9
-        poisoned = decode_gqa_attention_paged(
-            q[:, 0], jnp.asarray(kp), jnp.asarray(vp), table, pos, interpret=True
-        )
+        poisoned = _kernel_paged(q[:, 0], kp, vp, table, pos)
         np.testing.assert_array_equal(np.asarray(clean), np.asarray(poisoned))
 
-    def test_paged_quantized_matches_dequantized_reference(self):
+    @LAYERS
+    def test_paged_quantized_matches_dequantized_reference(self, layer):
         """int8 scale-block path: the paged kernel streaming int8 pool
         pages + scale pages through the table must equal the XLA
         reference over the dequantized contiguous cache."""
@@ -221,10 +250,9 @@ class TestDecodeAttention:
         pool_ks, pool_vs, _t = _paginate(
             qk.s[..., None], qv.s[..., None], page_s=64
         )
-        out = decode_gqa_attention_paged(
-            q[:, 0], pool_kq, pool_vq, table, pos,
+        out = _kernel_paged(
+            q[:, 0], pool_kq, pool_vq, table, pos, layer,
             k_scale=pool_ks[..., 0], v_scale=pool_vs[..., 0],
-            interpret=True,
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
@@ -302,6 +330,7 @@ def route(monkeypatch):
 
 
 class TestKernelUnderMesh:
+    @LAYERS
     @pytest.mark.parametrize(
         "dp,tp,layout",
         [(dp, tp, layout)
@@ -309,10 +338,12 @@ class TestKernelUnderMesh:
          for layout in ["plain", "int8", "paged"]
          if not (layout == "paged" and dp > 1)],  # refused: TestKernelRefusals
     )
-    def test_sharded_kernel_matches_einsum(self, devices8, route, dp, tp, layout):
-        """Operands sharded the way the engine shards them (slots over
-        dp, KV heads over tp): the shard_mapped kernel must give what the
-        GSPMD-partitioned einsum path gives."""
+    def test_sharded_kernel_matches_einsum(self, devices8, route, dp, tp,
+                                           layout, layer):
+        """Whole caches sharded the way the engine shards them (layers
+        whole, slots over dp, KV heads over tp): the shard_mapped kernel
+        on layer ``layer`` must give what the GSPMD-partitioned einsum
+        path gives on that layer's slice (the other layers are NaN)."""
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
@@ -327,25 +358,28 @@ class TestKernelUnderMesh:
         def put(x, *spec):
             return jax.device_put(x, NamedSharding(mesh, P(*spec)))
 
+        def put_whole(x, *spec):
+            return put(_whole(x, layer), None, *spec)
+
         q = put(q, "dp", None, "tp", None)
         if layout == "paged":
             pool_k, pool_v, table = _paginate(k, v, page_s=64)
-            kc = PagedKV(put(pool_k, None, None, "tp", None), put(table))
-            vc = PagedKV(put(pool_v, None, None, "tp", None), put(table))
+            kc = PagedKV(put_whole(pool_k, None, None, "tp", None), put(table))
+            vc = PagedKV(put_whole(pool_v, None, None, "tp", None), put(table))
         elif layout == "int8":
             def quant(x):
                 c = kvq.quantize_rows(x)
-                return kvq.QuantKV(put(c.q, "dp", None, "tp", None),
-                                   put(c.s, "dp", None, "tp"))
+                return kvq.QuantKV(put_whole(c.q, "dp", None, "tp", None),
+                                   put_whole(c.s, "dp", None, "tp"))
             kc, vc = quant(k), quant(v)
         else:
-            kc, vc = (put(x, "dp", None, "tp", None) for x in (k, v))
+            kc, vc = (put_whole(x, "dp", None, "tp", None) for x in (k, v))
 
         def run(mode):
             route(mode)
             return jax.jit(
                 lambda q, kc, vc, p: gqa_attention(q, kc, vc, p[:, None],
-                                                   mesh=mesh)
+                                                   mesh=mesh, layer=layer)
             )(q, kc, vc, pos)
 
         out, ref = run("interpret"), run("0")

@@ -12,6 +12,8 @@ xdist worker imports this file. All of these compiles live in this one
 file for the same reason. Nothing here starts a child process.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,7 +21,7 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from chip_smoke import collective_lines, kv_rows_moved
+from chip_smoke import collective_lines, kv_rows_moved, result_dims
 from omnia_tpu.engine.programs import build_programs
 from omnia_tpu.engine.types import MAX_DEVICE_STOP_IDS, EngineConfig
 from omnia_tpu.models import get_config, llama
@@ -84,9 +86,9 @@ def _is_spec(x):
     return isinstance(x, P)
 
 
-def _model_operands(cfg, sharding_for):
+def _model_operands(cfg, sharding_for, batch=B, seq=S):
     """(params, ck, cv) ShapeDtypeStruct trees for one decode batch of
-    B × S; ``sharding_for(spec)`` places each leaf."""
+    batch × seq; ``sharding_for(spec)`` places each leaf."""
     def shapes(make, specs):
         return jax.tree.map(
             lambda spec, x: jax.ShapeDtypeStruct(
@@ -100,7 +102,7 @@ def _model_operands(cfg, sharding_for):
         llama.param_specs(cfg),
     )
     ck, cv = shapes(
-        lambda: llama.init_kv_cache(cfg, B, S, dtype=jnp.bfloat16),
+        lambda: llama.init_kv_cache(cfg, batch, seq, dtype=jnp.bfloat16),
         llama.kv_cache_specs(),
     )
     return params, ck, cv
@@ -121,16 +123,17 @@ def test_decode_kernel_compiles(one_chip, paged, model, kv_int8):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     q, pos = arr((B, H, D), jnp.bfloat16), arr((B,), jnp.int32)
+    layer, layers = arr((), jnp.int32), 4  # the whole cache and which layer
     if paged:
         pages = B * S // PAGE_S
-        kv = arr((pages, PAGE_S, Hkv, D), kv_dtype)
-        scale = arr((pages, PAGE_S, Hkv), jnp.float32)
-        args = (q, kv, kv, arr((B, S // PAGE_S), jnp.int32), pos)
+        kv = arr((layers, pages, PAGE_S, Hkv, D), kv_dtype)
+        scale = arr((layers, pages, PAGE_S, Hkv), jnp.float32)
+        args = (q, kv, kv, arr((B, S // PAGE_S), jnp.int32), pos, layer)
         fn = dk.decode_gqa_attention_paged
     else:
-        kv = arr((B, S, Hkv, D), kv_dtype)
-        scale = arr((B, S, Hkv), jnp.float32)
-        args = (q, kv, kv, pos)
+        kv = arr((layers, B, S, Hkv, D), kv_dtype)
+        scale = arr((layers, B, S, Hkv), jnp.float32)
+        args = (q, kv, kv, pos, layer)
         fn = dk.decode_gqa_attention
     if kv_int8:
         args += (scale, scale)
@@ -153,6 +156,89 @@ def test_one_chip_decode_step_holds_the_mosaic_call(one_chip, kernel_route_on):
         params, ck, cv, toks, toks
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# The eval-batch cell's shape (benchmark/cells/mistral-7b.eval-batch.json over
+# benchmark/configs/mistral-7b.json): Mistral-7B widths, 14 layers, 32 × 2048.
+CELL_SLOTS, CELL_SEQ = 32, 2048
+
+
+def _cell_model():
+    from omnia_tpu.models.config import ModelConfig
+
+    return ModelConfig(
+        name="mistral-7b", vocab_size=32768, hidden_size=4096, num_layers=14,
+        num_heads=32, num_kv_heads=8, head_dim=128, ffn_hidden_size=14336,
+        rope_theta=1e6, rms_norm_eps=1e-5, tie_embeddings=False,
+        max_seq_len=32768,
+    )
+
+
+def _computation_roots(text: str) -> dict[str, str]:
+    """Each HLO computation's ROOT instruction line, by computation name."""
+    roots, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            name = head.group(1)
+        elif ln.lstrip().startswith("ROOT ") and name:
+            roots[name] = ln.strip()
+    return roots
+
+
+@pytest.mark.parametrize("chunk", [1, 8])
+def test_cell_decode_programs_keep_the_cache_in_place(one_chip, kernel_route_on,
+                                                      chunk):
+    """The engine's one-step and chunk-of-8 decode programs at the
+    eval-batch cell's shape: the donated cache is the only copy of the
+    cache. Until the cache rode the layer scan as its carry the one-step
+    program held 4.3 GB of temporaries (a second whole cache) and every
+    layer sliced 2 × 134 MB out of it and wrote them back."""
+    cfg = _cell_model()
+    ecfg = EngineConfig(
+        num_slots=CELL_SLOTS, max_seq=CELL_SEQ, decode_chunk=8,
+        decode_pipeline=2, max_sessions=0,
+        prefill_buckets=(384, 512, 640, 768, 896, 1024),
+    )
+
+    params, ck, cv = _model_operands(
+        cfg, lambda _spec: one_chip, CELL_SLOTS, CELL_SEQ
+    )
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((CELL_SLOTS, *tail), dtype, sharding=one_chip)
+
+    i32, f32 = (lambda *t: vec(jnp.int32, *t)), vec(jnp.float32)
+    compiled = build_programs(cfg, ecfg, None).decode_fns[chunk].lower(
+        params, ck, cv, i32(), i32(), vec(jnp.bool_), i32(),
+        i32(MAX_DEVICE_STOP_IDS), vec(jnp.uint32, 2), f32, f32, i32(),
+    ).compile()
+    text = compiled.as_text()
+    # One layer body, one Mosaic call in it.
+    assert text.count("tpu_custom_call") == 1
+    # Nothing produces a second cache, a layer of it, or a re-laid-out one:
+    # the only instructions as large as a layer of K are the row writes,
+    # fusions whose root updates the carried buffer in place.
+    layer_elems = CELL_SLOTS * CELL_SEQ * cfg.num_kv_heads * cfg.head_dim
+    roots = _computation_roots(text)
+    for ln in text.splitlines():
+        m = re.search(r"= \w+\[[\d,]+\]\S* (copy|copy-start|dynamic-slice|"
+                      r"transpose|fusion)\(", ln)
+        dims = result_dims(ln) if m else []
+        if not dims or int(np.prod(dims)) < layer_elems or dims[-1] != cfg.head_dim:
+            continue
+        assert m.group(1) == "fusion", ln.strip()[:200]
+        called = re.search(r"calls=%([\w.\-]+)", ln).group(1)
+        assert " dynamic-update-slice(" in roots[called], roots[called][:200]
+    # What is left of the temporaries: wq / wk / wv re-laid out once a call
+    # ahead of the step loop (chunk of 8 only; the parent did the same).
+    relaid = sum(
+        2 * int(np.prod(result_dims(ln))) for ln in text.splitlines()
+        if re.search(r"= bf16\[14,4096,(1024|4096)\]\S* copy\(", ln)
+    )
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp - relaid < 0.5e9, (temp, relaid)
+    assert temp < 0.5e9 or chunk == 8, temp
 
 
 def test_tp4_engine_decode_chunk_compiles_sharded(tp4_mesh, kernel_route_on):
