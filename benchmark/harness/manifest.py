@@ -3,7 +3,7 @@ hang together. Jax-free except `model_config`/`engine_config`, which import
 the program's config types.
 
     BENCHMARK.json workloads[name] -> cells/<name>.json
-    cell.config  -> configs/<config>.json
+    cell.config  -> configs/<config>.json    (its `reference` -> reference/<r>.py)
     cell.traffic -> traffic/<traffic>.json   (its `generator` -> generators/<g>.py)
     cell.per_layer[] -> layer_metrics/<metric>.py
 """
@@ -26,7 +26,7 @@ def _load_json(*parts) -> dict:
 def _load_module(kind: str, name: str):
     path = os.path.join(BENCH_DIR, kind, name + ".py")
     if not os.path.exists(path):
-        raise FileNotFoundError(f"no {kind[:-1]} {name!r}: {path} does not exist")
+        raise FileNotFoundError(f"no {kind.rstrip('s')} {name!r}: {path} does not exist")
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -40,6 +40,19 @@ def benchmark_json() -> dict:
 
 def load_generator(name: str):
     return _load_module("generators", name).schedule
+
+
+DEFAULT_REFERENCE = "llama_ref"
+
+
+def load_reference(name: str = DEFAULT_REFERENCE):
+    """The plain reference a configuration names (`"reference"` in its file).
+    `forward(params, sizes, tokens)` for every model, `forward_routed` as well
+    for one with a router: see README, "Adding things"."""
+    mod = _load_module("reference", name)
+    if not hasattr(mod, "forward"):
+        raise AttributeError(f"reference {name!r} has no forward(params, sizes, tokens)")
+    return mod
 
 
 def load_layer_metric(name: str):
@@ -70,6 +83,7 @@ class Cell:
         cfg_entry = next(c for c in bench["configs"] if c["name"] == self.spec["config"])
         self.model = _load_json(ROOT, cfg_entry["file"])
         self.model.setdefault("head_dim", self.model["assumed"]["head_dim"])
+        self.reference = self.model.get("reference", DEFAULT_REFERENCE)
         self.traffic = _load_json(BENCH_DIR, "traffic", self.spec["traffic"] + ".json")
         if "rate" in self.spec:
             self.traffic = {**self.traffic, "rate_rps": self.spec["rate"]}

@@ -34,8 +34,7 @@ if __package__ in (None, ""):  # run as a script: this directory is sys.path[0]
 from harness import trace as tr
 
 HOST_PREFIX = "omnia."
-STEP = "omnia.engine.step"
-IDLE_SLEEP = "omnia.engine.idle_sleep"
+STEP = tr.ENGINE_STEP
 UNATTRIBUTED = "unattributed"
 # The stat that holds a device op's HLO op_name, the path of jit names and
 # named scopes it was traced under. The profiler keeps it once an op, on the
@@ -186,33 +185,6 @@ def load(trace_dir: str) -> dict:
     return {"planes": planes}
 
 
-def _innermost_segments(events: list) -> list:
-    """One thread's nested spans as a flat, sorted list of [start, end,
-    name]: at every instant the innermost open span. What is left of a span
-    after its children are cut out is its self time."""
-    out: list = []
-    stack: list = []  # [name, end, cursor]
-
-    def close(upto: float) -> None:
-        while stack and stack[-1][1] <= upto:
-            name, end, cursor = stack.pop()
-            if end > cursor:
-                out.append([cursor, end, name])
-            if stack:
-                stack[-1][2] = max(stack[-1][2], end)
-
-    for name, start, dur, _x in sorted(events, key=lambda e: (e[1], -e[2])):
-        close(start)
-        if stack and start > stack[-1][2]:
-            out.append([stack[-1][2], start, stack[-1][0]])
-        if stack:
-            stack[-1][2] = max(stack[-1][2], start)
-        stack.append([name, start + dur, start])
-    close(float("inf"))
-    out.sort()
-    return out
-
-
 def reduce(raw: dict) -> dict:
     """Seconds are means over the device planes, as in `trace.reduce`."""
     devices = [p for p in raw["planes"] if tr.DEVICE_PLANE.match(p["name"])]
@@ -228,8 +200,7 @@ def reduce(raw: dict) -> dict:
         if tr.DEVICE_PLANE.match(plane["name"]):
             continue
         for line in plane["lines"]:
-            names = {e[0] for e in line["events"]}
-            flat = _innermost_segments(line["events"])
+            flat = tr.innermost_segments(line["events"])
             for name, start, dur, attrs in line["events"]:
                 p = phases.setdefault(name, {"count": 0, "seconds": 0.0, "self_s": 0.0})
                 p["count"] += 1
@@ -240,10 +211,9 @@ def reduce(raw: dict) -> dict:
                     step_clock = (start, int(attrs["mono_ns"]))
             for s, e, name in flat:
                 phases[name]["self_s"] += (e - s) / 1e9
-            if STEP in names or IDLE_SLEEP in names:
+            if tr.is_engine_thread(line["events"]):
                 segments += flat
-    segments.sort()
-    seg_starts = [s for s, _e, _n in segments]
+    engine = tr.segment_table(segments)
 
     idle_by_phase: dict = {}
     idle_s = 0.0
@@ -257,16 +227,10 @@ def reduce(raw: dict) -> dict:
             if g1 <= g0:
                 continue
             idle_s += (g1 - g0) / 1e9 / n_dev
-            covered = 0.0
-            i = max(bisect.bisect_right(seg_starts, g0) - 1, 0)
-            while i < len(segments) and segments[i][0] < g1:
-                s, e, name = segments[i]
-                overlap = min(e, g1) - max(s, g0)
-                if overlap > 0:
-                    covered += overlap
-                    idle_by_phase[name] = idle_by_phase.get(name, 0.0) + overlap / 1e9 / n_dev
-                i += 1
-            rest = (g1 - g0) - covered
+            shares = tr.phase_shares(*engine, g0, g1)
+            for name, ns in shares.items():
+                idle_by_phase[name] = idle_by_phase.get(name, 0.0) + ns / 1e9 / n_dev
+            rest = (g1 - g0) - sum(shares.values())
             if rest > 0:
                 idle_by_phase[UNATTRIBUTED] = (
                     idle_by_phase.get(UNATTRIBUTED, 0.0) + rest / 1e9 / n_dev)

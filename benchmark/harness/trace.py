@@ -6,9 +6,12 @@ Two steps, so the arithmetic can be tested without a chip:
 seconds, device seconds per XLA module and per op, the collectives' share
 and the longest idle gaps. `reduce` is pure Python.
 
-Names are the ones XLA prints: the program puts no named scopes on its
-steps yet, so a gap inside the engine loop is "engine loop, not
-attributed" plus the module that ended it.
+Device names are the ones XLA prints. An idle gap is split among the
+engine phases (`omnia.engine.*`, `omnia_tpu/engine/phases.py`) whose self
+time on the engine thread covers it (`phase_shares`, the one attribution:
+`harness/spans.py` sums the same shares into its table), and named by the
+phase with the largest share, else by this benchmark's own spans that
+overlap it, else "engine loop, not attributed"; then the module that ended it.
 """
 
 from __future__ import annotations
@@ -20,7 +23,8 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 MODULE_LINE, OPS_LINE = "XLA Modules", "XLA Ops"
-HOST_PREFIX = "bench."
+HOST_PREFIX = ("bench.", "omnia.")
+ENGINE_STEP, ENGINE_IDLE_SLEEP = "omnia.engine.step", "omnia.engine.idle_sleep"
 COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute|"
     r"collective-broadcast)")
@@ -117,6 +121,63 @@ def _union(intervals: list) -> list:
     return merged
 
 
+def innermost_segments(events: list) -> list:
+    """One thread's nested spans as a flat, sorted list of [start, end,
+    name]: at every instant the innermost open span. What is left of a span
+    after its children are cut out is its self time. Events are [name,
+    start_ns, duration_ns, ...]."""
+    out: list = []
+    stack: list = []  # [name, end, cursor]
+
+    def close(upto: float) -> None:
+        while stack and stack[-1][1] <= upto:
+            name, end, cursor = stack.pop()
+            if end > cursor:
+                out.append([cursor, end, name])
+            if stack:
+                stack[-1][2] = max(stack[-1][2], end)
+
+    for name, start, dur, *_x in sorted(events, key=lambda e: (e[1], -e[2])):
+        close(start)
+        if stack and start > stack[-1][2]:
+            out.append([stack[-1][2], start, stack[-1][0]])
+        if stack:
+            stack[-1][2] = max(stack[-1][2], start)
+        stack.append([name, start + dur, start])
+    close(float("inf"))
+    out.sort()
+    return out
+
+
+def is_engine_thread(events: list) -> bool:
+    """Whether one host line's events are an engine thread's: its phases can
+    own an idle gap."""
+    names = {e[0] for e in events}
+    return ENGINE_STEP in names or ENGINE_IDLE_SLEEP in names
+
+
+def segment_table(segments: list) -> tuple:
+    """(segments sorted, their starts, the longest one's length): what
+    `phase_shares` bisects. Segments are [start, end, name]."""
+    segments = sorted(segments)
+    return (segments, [s for s, _e, _n in segments],
+            max((e - s for s, e, _n in segments), default=0.0))
+
+
+def phase_shares(segments: list, starts: list, longest: float, lo: float, hi: float) -> dict:
+    """{name: ns} of [lo, hi] under each of the sorted [start, end, name]
+    segments: who owns how much of one idle gap."""
+    out: dict = {}
+    i = bisect.bisect_left(starts, lo - longest)
+    while i < len(segments) and segments[i][0] < hi:
+        s, e, name = segments[i]
+        overlap = min(e, hi) - max(s, lo)
+        if overlap > 0:
+            out[name] = out.get(name, 0.0) + overlap
+        i += 1
+    return out
+
+
 def module_base(name: str) -> str:
     """`jit_decode_chunk(1234)` -> `jit_decode_chunk`."""
     return name.split("(", 1)[0]
@@ -134,10 +195,13 @@ def reduce(trace: dict) -> dict:
     devices = [p for p in trace["planes"] if DEVICE_PLANE.match(p["name"])]
     if not devices:
         raise ValueError("the trace holds no /device:TPU:N plane")
-    host_spans = [
-        (s, s + d, n) for p in trace["planes"] if not DEVICE_PLANE.match(p["name"])
-        for line in p["lines"] for n, s, d in line["events"]
-    ]
+    host_lines = [line["events"] for p in trace["planes"]
+                  if not DEVICE_PLANE.match(p["name"]) for line in p["lines"]]
+    engine = segment_table([seg for events in host_lines if is_engine_thread(events)
+                            for seg in innermost_segments(events)])
+    # Every other host span kept, e.g. `bench.submit`.
+    others = segment_table([[s, s + d, n] for events in host_lines
+                            if not is_engine_thread(events) for n, s, d in events])
     n_dev = len(devices)
     busy = window = 0.0
     modules: dict = {}        # base name -> {"count", "seconds", "by_id": {full: [count, s]}}
@@ -181,8 +245,12 @@ def reduce(trace: dict) -> dict:
             while i < len(mods) and mods[i][2] < BOOKKEEPING_NS:
                 i += 1  # scatters and converts of a few microseconds
             nxt = module_base(mods[i][0]) if i < len(mods) else "end of trace"
-            host = sorted({n for hs, he, n in host_spans if hs < s1 and he > e0})
-            what = "+".join(host) if host else "engine loop, not attributed"
+            phases = phase_shares(*engine, e0, s1)
+            if phases:
+                what = max(phases.items(), key=lambda kv: (kv[1], kv[0]))[0]
+            else:
+                what = ("+".join(sorted(phase_shares(*others, e0, s1)))
+                        or "engine loop, not attributed")
             label = f"{what}; before {nxt}"
             gaps[label] = gaps.get(label, 0.0) + gap / n_dev
     if window <= 0:

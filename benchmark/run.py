@@ -103,7 +103,8 @@ def main(argv=None) -> int:
     log(f"engine built in {build_s:.1f} s on {platform} {kind!r} x{len(devices)}")
 
     t = time.monotonic()
-    ref = correct.check(engine, mc, reference_sizes(mc), args.seed)
+    ref = correct.check(engine, mc, reference_sizes(mc), args.seed,
+                        reference=cell.reference)
     reference_s = time.monotonic() - t
     log(f"reference check in {reference_s:.1f} s: {json.dumps(ref)}")
 

@@ -9,6 +9,7 @@ import os
 import pytest
 
 from harness import spans
+from harness import trace as tr
 from harness.manifest import load_layer_metric
 
 MS = 1e6  # ns
@@ -126,7 +127,7 @@ def test_op_names_reads_the_event_metadata_table(tmp_path):
 
 def test_innermost_segments_tile_a_thread_without_overlap():
     events = hand_made()["planes"][1]["lines"][0]["events"]
-    segs = spans._innermost_segments(events)
+    segs = tr.innermost_segments(events)
     assert all(a[1] <= b[0] for a, b in zip(segs, segs[1:]))
     total = {}
     for s, e, name in segs:

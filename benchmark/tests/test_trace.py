@@ -1,13 +1,16 @@
 """The reduction from a trace to busy/idle, modules, ops and gaps: on a
 hand-made trace whose answers are known, and on a small trace recorded on
 the chip (0.25 s of `mistral-7b.chat-steady`, PR 24, TPU v5 lite) kept
-beside this file."""
+beside this file. Since PR 27 a gap is named by the engine phase that owns
+it: on the hand-made trace with an engine thread, and on PR 25's recorded
+sample of `mistral-7b.eval-batch`, which holds `omnia.engine.*` spans."""
 import gzip
 import json
 import os
 
 import pytest
 
+from harness import spans as sp
 from harness import trace as tr
 from harness.layer_common import decode_steps_in_trace, kernel_in_decode
 
@@ -72,6 +75,60 @@ def test_reduce_hand_made_trace():
     ctx = {"trace": r, "model": {"num_hidden_layers": 1}}
     assert kernel_in_decode(ctx) == (3, pytest.approx(0.015))
     assert decode_steps_in_trace(ctx) == 3
+
+
+def test_a_gap_is_named_by_the_engine_phase_that_owns_most_of_it():
+    """The same device lines with an engine thread beside them: the first
+    gap [40,50] lies under `emit` [41,47] (6 ms of self time) inside a step
+    [0,48] whose own time is [40,41] and [47,48]; the second [70,80] under
+    `place` [60,79] less its `prefill_dispatch` child [60,72]."""
+    raw = hand_made()
+    e = "omnia.engine."
+    raw["planes"][1]["lines"].append({"name": "engine", "events": [
+        [e + "step", 0 * MS, 48 * MS],
+        [e + "chunk_sync", 1 * MS, 39 * MS],
+        [e + "emit", 41 * MS, 6 * MS],
+        [e + "step", 49 * MS, 50 * MS],
+        [e + "place", 60 * MS, 19 * MS],
+        [e + "prefill_dispatch", 60 * MS, 12 * MS],
+    ]})
+    r = tr.reduce(raw)
+    assert r["idle_gaps"] == {
+        "omnia.engine.emit; before jit_prefill_insert": pytest.approx(0.010),
+        "omnia.engine.place; before jit_decode_chunk": pytest.approx(0.010),
+    }
+    # A thread without a step span is not an engine thread: its spans name a
+    # gap only where no phase does, as `bench.submit` did above.
+    assert r["busy_s"] == pytest.approx(0.080)
+
+
+def test_recorded_sample_with_engine_spans_names_its_gaps():
+    """PR 25's sample is in `harness/spans.py`'s scheme, a fourth element
+    on every event; `trace.py`'s has three."""
+    path = os.path.join(os.path.dirname(__file__), "spans_sample.json.gz")
+    with gzip.open(path, "rt") as f:
+        raw = json.load(f)
+    by_spans = sp.reduce(raw)
+    for plane in raw["planes"]:
+        for line in plane["lines"]:
+            line["events"] = [ev[:3] for ev in line["events"]]
+    r = tr.reduce(raw)
+    # One attribution (`tr.phase_shares`): the seconds named here are the
+    # seconds `spans.reduce` split among the phases, and the phase that owns
+    # most idle time there names a gap here.
+    assert sum(r["idle_gaps"].values()) == pytest.approx(by_spans["idle_s"])
+    biggest = max(by_spans["idle_by_phase"].items(), key=lambda kv: kv[1])[0]
+    assert any(label.startswith(biggest + ";") for label in r["idle_gaps"])
+    owners = {}
+    for label, seconds in r["idle_gaps"].items():
+        owner = label.split("; before ")[0]
+        owners[owner] = owners.get(owner, 0.0) + seconds
+    named = sum(s for o, s in owners.items() if o.startswith("omnia.engine."))
+    assert named / sum(owners.values()) > 0.75, owners
+    # 0.15 s, two steps; the steps that straddle its edges were cut away.
+    assert {"omnia.engine.chunk_sync", "omnia.engine.decode_dispatch"} <= set(owners)
+    top = tr.breakdown(r)["idle_gaps"][0][0]
+    assert top.startswith("omnia.engine."), top
 
 
 def test_no_device_plane_is_an_error():
