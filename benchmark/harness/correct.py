@@ -2,13 +2,39 @@
 through the cache against the plain reference, on logits.
 
 In set-up, outside the window: a seeded sequence of prefill + decode
-tokens. The program side is `models/llama.py::forward` with the engine's
-own parameters, its mesh and the kernel route as served: one prefill of
-the first tokens into a cache, then single-token decode steps through that
-cache (teacher-forced with the seeded tokens, because with random weights
-the largest logit changes on rounding). The reference side is one
-whole-sequence float32 forward of the module the configuration names
-(`"reference"` in its file, default `reference/llama_ref.py`).
+tokens. The program side is `forward` of the model module the
+configuration names (`program.module` in its file; the defaults are
+`harness/manifest.py`'s) with the engine's own parameters, its mesh and
+the kernel route as served: one prefill of the first tokens into a cache,
+then single-token decode steps through that cache (teacher-forced with
+the seeded tokens, because with random weights the largest logit changes
+on rounding). The reference side is one whole-sequence float32 forward of
+the reference module the configuration names (`"reference"` in its file).
+
+What the harness asks of the program's model module, and no more:
+
+    init_params(cfg, key, dtype=) -> params     (harness/weights.py)
+    param_specs(cfg) -> PartitionSpecs like params
+    init_kv_cache(cfg, batch, rows, dtype=) -> cache, a tuple of arrays
+    kv_cache_specs(kv_quant) -> PartitionSpecs like cache
+    forward(params, cfg, tokens [B, T], positions [B, T], *cache,
+            start [B], mesh=) -> (logits [B, T, V], *cache)
+
+`cfg` is the program's ModelConfig, of which the harness itself reads
+`vocab_size` and replaces `num_layers` and `tie_embeddings`. The cache is
+opaque: a tuple of any length (a pair of K and V, one latent array, a pair
+and a state), made by the module, handed back to it whole and never
+indexed here. Of `params` the harness knows `embed` [V, D] (also the head,
+transposed, where the table is tied), `layers`, a tree whose every leaf is
+led by the layer axis, and `lm_head` [D, V] where untied: that is what
+`_sub_model` cuts a one- or two-layer model from. So every layer must be
+alike; a stack of unlike layers (leading dense layers, a period of kinds)
+has no single layer axis to cut, and is not judged here yet (README).
+The reference is handed those cut trees too, with `sizes` as they are: it
+takes its depth from the tree, never from `sizes["config"]`, which is the
+file at full depth. The module named is the one the engine dispatches to
+(`manifest.served_by`; `run.py` refuses a configuration that names
+another), so what is judged here is what the window times.
 
 Tolerance, as shares of the reference's logit range (max - min): max
 |diff| <= 5e-2 and mean |diff| <= 1e-2. PR 22 measured one bf16 run at
@@ -86,7 +112,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.manifest import DEFAULT_REFERENCE, load_reference
+from harness.manifest import (DEFAULT_MODEL_MODULE, DEFAULT_REFERENCE, load_model_module,
+                              load_reference)
 
 PREFILL, DECODE, CACHE_ROWS = 128, 8, 256
 MAX_TOL, MEAN_TOL = 5e-2, 1e-2
@@ -120,26 +147,28 @@ def _sub_model(params, stream, first, count: int, dtype):
 
 
 def _served_logits(engine, model_cfg, tokens, prefill: int, cache_rows: int,
-                   layer_inputs=None, depth: int = 1):
+                   layer_inputs=None, depth: int = 1,
+                   model_module: str = DEFAULT_MODEL_MODULE):
     """The program's logits as float32 for `tokens` through a fresh cache.
     Whole depth: [T, V]. With `layer_inputs` [N, T, D], the stream the
     reference saw enter layer n: [N, T, V], each the `depth` layers from n
     alone (`_sub_model`; `tokens` is then arange(T))."""
-    from omnia_tpu.models import llama
     from omnia_tpu.parallel import init_sharded
 
+    model = load_model_module(model_module)
     mesh = engine._mesh  # the mesh the engine's parameters live on
     dtype = engine.params["embed"].dtype
     if layer_inputs is not None:
         model_cfg = dataclasses.replace(model_cfg, num_layers=depth, tie_embeddings=False)
-    fresh = init_sharded(
-        lambda: llama.init_kv_cache(model_cfg, 1, cache_rows, dtype=dtype),
-        llama.kv_cache_specs(None), mesh)
+    fresh = tuple(init_sharded(
+        lambda: model.init_kv_cache(model_cfg, 1, cache_rows, dtype=dtype),
+        model.kv_cache_specs(None), mesh))
 
-    def step(params, ck, cv, toks, start):
+    def step(params, cache, toks, start):
         pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None, :]
-        return llama.forward(params, model_cfg, toks, pos, ck, cv,
-                             jnp.reshape(start, (1,)), mesh=mesh)
+        logits, *cache = model.forward(params, model_cfg, toks, pos, *cache,
+                                       jnp.reshape(start, (1,)), mesh=mesh)
+        return logits, tuple(cache)
 
     def sub_step(params, stream, first, *rest):
         return step(_sub_model(params, stream, first, depth, dtype), *rest)
@@ -148,11 +177,11 @@ def _served_logits(engine, model_cfg, tokens, prefill: int, cache_rows: int,
 
     def through_cache(*lead):
         """One prefill of `prefill` tokens, then one token a step."""
-        ck, cv = fresh
+        cache = fresh
         served = []
         for lo, hi in [(0, prefill)] + [(i, i + 1) for i in range(prefill, len(tokens))]:
-            logits, ck, cv = jitted(*lead, ck, cv, jnp.asarray(tokens[None, lo:hi]),
-                                    jnp.int32(lo))
+            logits, cache = jitted(*lead, cache, jnp.asarray(tokens[None, lo:hi]),
+                                   jnp.int32(lo))
             served.append(np.asarray(logits[0], np.float32))
         return np.concatenate(served, axis=0)
 
@@ -168,13 +197,15 @@ def _seeded_tokens(model_cfg, seed: int, n: int):
 
 
 def check(engine, model_cfg, sizes: dict, seed: int,
-          reference: str = DEFAULT_REFERENCE) -> dict:
+          reference: str = DEFAULT_REFERENCE,
+          model_module: str = DEFAULT_MODEL_MODULE) -> dict:
     ref_mod = load_reference(reference)
     if sizes["num_experts"]:
-        return _check_sparse(engine, model_cfg, sizes, seed, ref_mod)
+        return _check_sparse(engine, model_cfg, sizes, seed, ref_mod, model_module)
 
     tokens = _seeded_tokens(model_cfg, seed, PREFILL + DECODE)
-    served = _served_logits(engine, model_cfg, tokens, PREFILL, CACHE_ROWS)
+    served = _served_logits(engine, model_cfg, tokens, PREFILL, CACHE_ROWS,
+                            model_module=model_module)
     ref = jax.jit(lambda params, toks: ref_mod.forward(params, sizes, toks))(
         engine.params, jnp.asarray(tokens))
     ref = np.asarray(ref, np.float32)
@@ -275,7 +306,8 @@ def reference_layers(ref_mod, params, sizes: dict, residual):
     return tuple(np.stack([np.asarray(x[i], np.float32) for x in per]) for i in range(4))
 
 
-def _check_sparse(engine, model_cfg, sizes: dict, seed: int, ref_mod) -> dict:
+def _check_sparse(engine, model_cfg, sizes: dict, seed: int, ref_mod,
+                  model_module: str = DEFAULT_MODEL_MODULE) -> dict:
     if not hasattr(ref_mod, "forward_routed"):
         raise AttributeError(
             f"reference {ref_mod.__name__!r} has no forward_routed, which a "
@@ -289,11 +321,12 @@ def _check_sparse(engine, model_cfg, sizes: dict, seed: int, ref_mod) -> dict:
     layers_ref, layers_plain, margin, sigma = reference_layers(
         ref_mod, engine.params, sizes, residual)
     layers = _served_logits(engine, model_cfg, positions, PREFILL, CACHE_ROWS,
-                            layer_inputs=residual[:-1])
+                            layer_inputs=residual[:-1], model_module=model_module)
     pair = pair_ref = None
     if model_cfg.num_layers >= PAIR:
         pair = _served_logits(engine, model_cfg, positions, PREFILL, CACHE_ROWS,
-                              layer_inputs=residual[:1], depth=PAIR)[0]
+                              layer_inputs=residual[:1], depth=PAIR,
+                              model_module=model_module)[0]
         pair_ref = np.asarray(jax.jit(lambda params, stream: ref_mod.forward(
             _sub_model(params, stream, 0, PAIR, dtype), _untied(sizes), jnp.asarray(positions)))(
                 engine.params, residual[0]), np.float32)

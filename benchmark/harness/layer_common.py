@@ -4,12 +4,20 @@ context was live while it was taken."""
 
 from __future__ import annotations
 
+from harness.manifest import decode_kernel, load_layer_metric
 from harness.metrics import late_ms
 from harness.stats import percentile
 
 DECODE_MODULE = "jit_decode_chunk"
-DECODE_KERNEL = "decode_gqa_attention"  # ops/decode_attention.py, as XLA prints it
 PREFILL_MODULES = ("jit_prefill_insert",)
+
+
+def variant_of(base: str) -> tuple:
+    """(LAYER, UNIT, BETTER, SOURCE, read) of the per-layer metric `base`,
+    for a file that reports the same quantity under another name because its
+    cells judge another end-to-end metric: it states its own MOVES."""
+    mod = load_layer_metric(base)
+    return mod.LAYER, mod.UNIT, mod.BETTER, mod.SOURCE, mod.read
 
 
 def p95_ms(values_s) -> float | None:
@@ -63,14 +71,16 @@ def idle_share(ctx) -> float | None:
 
 
 def kernel_in_decode(ctx):
-    """(calls, seconds) of the Pallas decode-attention kernel inside the
-    decode module, or None where the trace shows none."""
+    """(calls, seconds) inside the decode module of the kernel the
+    configuration names as the one that counts its decode steps
+    (`manifest.decode_kernel`), or None where the trace shows none."""
     tr = ctx["trace"]
     if not tr:
         return None
+    kernel = decode_kernel(ctx["model"])
     calls = seconds = 0.0
     for name, (n, s) in tr["ops_in_module"].get(DECODE_MODULE, {}).items():
-        if name.split(".")[0] == DECODE_KERNEL:
+        if name.split(".")[0] == kernel:
             calls, seconds = calls + n, seconds + s
     return (calls, seconds) if calls else None
 

@@ -1,14 +1,18 @@
-"""Published peaks and the bytes a decode step must move. Jax-free.
+"""Published peaks and the least time of a decode step. Jax-free.
 
 The least time of one decode step is bound by HBM: every weight the step
-reads once, plus the K and V rows of the live contexts once. The bytes come
-from shapes here, never from the program.
+reads once, plus the cached rows of the live contexts once. The bytes come
+from shapes, never from the program: by the module under `decode_bytes/`
+that the configuration names (`"decode_bytes"` in its file; the default
+is `harness/manifest.py`'s), where `m` is that file's keys.
 """
 
 from __future__ import annotations
 
 import json
 import os
+
+from harness.manifest import load_decode_bytes
 
 _HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -24,33 +28,16 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def decode_weight_bytes(m: dict, itemsize: int = 2) -> int:
-    """Weight bytes one decode step reads, over all chips. `m` holds the
-    model's sizes under the configuration file's own keys. The embedding
-    table is gathered (a row per slot), not streamed, so it is left out;
-    the output head is read whole. A Mixtral decode step through
-    `moe_dense` reads every expert, and with 32 slots x top-2 of 8 experts
-    nearly every expert is needed anyway, so all experts count."""
-    d, f = m["hidden_size"], m["intermediate_size"]
-    hd = m["head_dim"]
-    q, kv = m["num_attention_heads"] * hd, m["num_key_value_heads"] * hd
-    attn = d * q + 2 * d * kv + q * d
-    experts = m.get("num_local_experts", 0)
-    mlp = (experts or 1) * 3 * d * f + d * experts
-    per_layer = attn + mlp + 2 * d
-    head = d * m["vocab_size"]  # tied or not, one [D, V] table is read whole
-    return (m["num_hidden_layers"] * per_layer + head + d) * itemsize
-
-
-def kv_bytes_per_token(m: dict, itemsize: int = 2) -> int:
-    """K and V bytes of one cached token over all layers."""
-    return (m["num_hidden_layers"] * m["num_key_value_heads"] * m["head_dim"]
-            * 2 * itemsize)
+def kv_bytes_per_token(m: dict) -> int:
+    """Cache bytes of one live token over all layers, by the byte-count
+    module the configuration names."""
+    return load_decode_bytes(m).kv_bytes_per_token(m)
 
 
 def decode_step_floor_s(m: dict, live_context_tokens: float, chips: int,
                         hbm_bytes_per_s: float) -> float:
-    """Least seconds for one decode step: (weights once + live K/V rows
-    once) / (chips x HBM bandwidth). Bound by HBM."""
-    total = decode_weight_bytes(m) + live_context_tokens * kv_bytes_per_token(m)
+    """Least seconds for one decode step: (weights once + the live tokens'
+    cached rows once) / (chips x HBM bandwidth). Bound by HBM."""
+    counts = load_decode_bytes(m)
+    total = counts.decode_weight_bytes(m) + live_context_tokens * counts.kv_bytes_per_token(m)
     return total / (chips * hbm_bytes_per_s)

@@ -4,7 +4,7 @@
 The program writes its engine-loop phases into the profiler's own trace
 (`omnia_tpu/engine/phases.py`: `omnia.engine.*` spans on the engine
 thread, nested under `omnia.engine.step`) and puts named scopes on the ops
-of its step programs (`models/llama.py`, `engine/programs.py`). Two steps,
+of its step programs (the model module, `engine/programs.py`). Two steps,
 as in `trace.py`: `load` turns the `.xplane.pb` into plain lists, keeping
 the host spans named `omnia.*` with their attributes and each device op
 with its scope, and `reduce` (pure Python) gives

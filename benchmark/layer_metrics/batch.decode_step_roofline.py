@@ -1,0 +1,5 @@
+"""`decode_step_roofline` in a closed loop, where the cell judges tokens/s/chip and not the gap's tail."""
+from harness.layer_common import variant_of
+
+LAYER, UNIT, BETTER, SOURCE, read = variant_of("decode_step_roofline")
+MOVES = "out_tokens_per_s_chip"

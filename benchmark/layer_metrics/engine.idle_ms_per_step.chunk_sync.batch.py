@@ -1,0 +1,5 @@
+"""`engine.idle_ms_per_step.chunk_sync` in a closed loop, where the cell judges tokens/s/chip and not the gap's tail."""
+from harness.layer_common import variant_of
+
+LAYER, UNIT, BETTER, SOURCE, read = variant_of("engine.idle_ms_per_step.chunk_sync")
+MOVES = "out_tokens_per_s_chip"

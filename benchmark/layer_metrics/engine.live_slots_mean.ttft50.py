@@ -1,0 +1,5 @@
+"""`engine.live_slots_mean` where the cell judges the median first token and not the gap's tail."""
+from harness.layer_common import variant_of
+
+LAYER, UNIT, BETTER, SOURCE, read = variant_of("engine.live_slots_mean")
+MOVES = "ttft_p50_ms"

@@ -1,0 +1,5 @@
+"""`engine.single_step_share` where the cell judges the median first token and not the gap's tail."""
+from harness.layer_common import variant_of
+
+LAYER, UNIT, BETTER, SOURCE, read = variant_of("engine.single_step_share")
+MOVES = "ttft_p50_ms"

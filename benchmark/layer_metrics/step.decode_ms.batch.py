@@ -1,0 +1,5 @@
+"""`step.decode_ms` in a closed loop, where the cell judges tokens/s/chip and not the gap's tail."""
+from harness.layer_common import variant_of
+
+LAYER, UNIT, BETTER, SOURCE, read = variant_of("step.decode_ms")
+MOVES = "out_tokens_per_s_chip"

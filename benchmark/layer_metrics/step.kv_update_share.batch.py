@@ -1,0 +1,5 @@
+"""`step.kv_update_share` in a closed loop, where the cell judges tokens/s/chip and not the gap's tail."""
+from harness.layer_common import variant_of
+
+LAYER, UNIT, BETTER, SOURCE, read = variant_of("step.kv_update_share")
+MOVES = "out_tokens_per_s_chip"

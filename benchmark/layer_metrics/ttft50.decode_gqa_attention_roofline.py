@@ -1,0 +1,5 @@
+"""`decode_gqa_attention_roofline` where the cell judges the median first token and not the gap's tail."""
+from harness.layer_common import variant_of
+
+LAYER, UNIT, BETTER, SOURCE, read = variant_of("decode_gqa_attention_roofline")
+MOVES = "ttft_p50_ms"

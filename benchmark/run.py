@@ -49,7 +49,7 @@ def main(argv=None) -> int:
                     help="write the reduced trace and its description here")
     args = ap.parse_args(argv)
 
-    from harness.manifest import Cell, load_generator, reference_sizes
+    from harness.manifest import Cell, load_generator, reference_sizes, served_by
 
     cell = Cell(args.workload)
     if args.rate is not None:
@@ -95,16 +95,23 @@ def main(argv=None) -> int:
     coldstart.begin_phase("backend_init")
     t = time.monotonic()
     mesh_devices = devices if cell.chips > 1 else None
-    params = seeded_params(mc, ecfg, mesh_devices, args.seed, resolve_dtype(ecfg.dtype))
+    params = seeded_params(mc, ecfg, mesh_devices, args.seed, resolve_dtype(ecfg.dtype),
+                           model_module=cell.model_module)
     engine = InferenceEngine(mc, ecfg, params=params, seed=args.seed & 0x7FFFFFFF,
                              devices=mesh_devices, coldstart=coldstart)
+    if served_by(engine) != cell.model_module:
+        # `correct` would judge one module's forward while the window times another's.
+        raise SystemExit(
+            f"configuration {cell.spec['config']} names the model module "
+            f"{cell.model_module!r} (program.module in its file), and the engine "
+            f"dispatches to {served_by(engine)!r}: the key follows the program, never leads it")
     jax.block_until_ready(engine.params)
     build_s = time.monotonic() - t
     log(f"engine built in {build_s:.1f} s on {platform} {kind!r} x{len(devices)}")
 
     t = time.monotonic()
-    ref = correct.check(engine, mc, reference_sizes(mc), args.seed,
-                        reference=cell.reference)
+    ref = correct.check(engine, mc, reference_sizes(mc, cell.config_as_run(args.rehearse_cpu)),
+                        args.seed, reference=cell.reference, model_module=cell.model_module)
     reference_s = time.monotonic() - t
     log(f"reference check in {reference_s:.1f} s: {json.dumps(ref)}")
 
