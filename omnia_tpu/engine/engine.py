@@ -354,13 +354,17 @@ class InferenceEngine(
             # _count_decode_dispatch): one dispatch per program call that
             # decodes, so decode_steps / decode_dispatches is the
             # realised chunk; _single counts calls of the one-step decode
-            # program; decode_slot_steps sums live slots x steps at
-            # dispatch (occupancy where the batch is formed);
-            # pipeline_flushes counts the flushes a waiting request
-            # forced; programs_compiled_serving the programs asked of the
-            # compiler after warmup() returned (engine/warmup.py).
+            # program; _blocked the dispatches made while requests waited
+            # and none had a slot (pipelined, so its share of
+            # decode_dispatches is how much of a full engine's queueing
+            # ran a step ahead); decode_slot_steps sums live slots x
+            # steps at dispatch (occupancy where the batch is formed);
+            # pipeline_flushes counts the flushes forced by a waiting
+            # request that had a slot to go to; programs_compiled_serving
+            # the programs asked of the compiler after warmup() returned.
             "decode_dispatches": 0,
             "decode_dispatches_single": 0,
+            "decode_dispatches_blocked": 0,
             "decode_slot_steps": 0,
             "pipeline_flushes": 0,
             "programs_compiled_serving": 0,

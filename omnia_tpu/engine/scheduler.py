@@ -6,8 +6,8 @@ Scheduling policy, two editions selected by
 - **0 (default): prefill-first** — one monolithic prefill per step,
   then a decode step for all active slots. Favors TTFT, but every
   arriving prompt stalls ALL active decode slots for its full prefill,
-  and while requests queue the pipeline degrades to synchronous single
-  steps.
+  and while a waiting request has a slot to go to the pipeline degrades
+  to synchronous single steps.
 - **> 0: token-budget mixed steps** (engine/interleave.py) — prefills
   split into budget-sized pieces and every piece FUSES into the same
   dispatch as a one-token decode step for all active slots, so decode
@@ -19,11 +19,18 @@ Steady state keeps up to ``decode_pipeline`` chunks in flight: chunk
 N+1 is dispatched on chunk N's output *futures* before N's tokens are
 read, so the device never idles through the host's read-RTT +
 bookkeeping gap (the dominant per-chunk cost on a remote-dispatch
-link). While requests queue under prefill-first, the pipeline degrades
-to synchronous single steps so a waiting prefill never sits out a full
-chunk; under the token-budget policy a waiting prefill piggybacks on
-the next mixed step instead — requests waiting on a SLOT get a
-pipeline flush per step (finish surfacing) but chunks stay full-size.
+link). Under prefill-first a non-empty queue has two regimes, told
+apart by ``_queued_placeable``. *Placeable* — a waiting request would
+get a slot now: everything in flight is flushed, it is placed, and
+decode runs as synchronous single steps so a waiting prefill never sits
+out a full chunk. *Blocked* — requests wait and none has a slot (a full
+engine): still single steps, so a slot's end is seen one step late and
+never a chunk late, but the pipeline keeps its depth — step N+1 is
+dispatched before N is read, and the read-back, the emit and the loop
+run under it. Under the token-budget policy a waiting prefill
+piggybacks on the next mixed step instead — requests waiting on a SLOT
+get a pipeline flush per step (finish surfacing) but chunks stay
+full-size.
 """
 
 from __future__ import annotations
@@ -115,25 +122,25 @@ class _SchedulerMixin:
             # into pieces fused with decode steps.
             return self._step_mixed()
         did = False
-        with self._lock:
-            queued = bool(self._waiting)
-        if queued and self._inflight:
-            # Requests are waiting: surface any in-flight finishes now so
-            # their slots free up this step (TTFT over pipeline depth).
-            self._flush_for_waiting()
-            did = True
-        pending, slot_idx = self._claim_pending()
-        if pending is not None:
+        queued, placeable = self._queued_placeable()
+        if placeable:
+            if self._inflight:
+                # A waiting request has a slot to go to: surface every
+                # in-flight finish now and place it this step (TTFT over
+                # pipeline depth).
+                self._flush_for_waiting()
+                did = True
             # Prefill/extend programs consume self._ck/_cv, which may be
             # futures from in-flight decode chunks — XLA sequences the
             # dependency, but host slot state must be current before
-            # placement decisions stick, so the pipeline is already flushed
-            # (the queued branch above ran whenever _waiting was non-empty).
-            self._place_pending(slot_idx, *pending)
-            did = True
+            # placement decisions stick (and an eviction is a device
+            # program of its own), so the claim comes after the flush.
+            pending, slot_idx = self._claim_pending()
+            if pending is not None:
+                self._place_pending(slot_idx, *pending)
+                did = True
+            queued, placeable = self._queued_placeable()
         if any(s.active for s in self._slots):
-            with self._lock:
-                queued = bool(self._waiting)
             # Per-slot speculation (spec_decode.py): greedy slots —
             # grammar-constrained ones included — verify up to W
             # proposals per weight stream while sampled slots ride the
@@ -150,8 +157,15 @@ class _SchedulerMixin:
             if self._inflight and not self._dispatch_ahead_useful():
                 self._process_oldest_chunk()
             else:
-                self._dispatch_decode(single=queued)
-                depth = 1 if queued else max(1, self.cfg.decode_pipeline)
+                # Nobody waits: full chunks, pipelined. A waiting request
+                # can be placed: one step, read back at once, placement
+                # next. Blocked (requests wait, none has a slot): one
+                # step, but pipelined, so the read-back and emit of step
+                # N run while the device computes step N+1.
+                self._dispatch_decode(
+                    single=queued, blocked=queued and not placeable
+                )
+                depth = 1 if placeable else max(1, self.cfg.decode_pipeline)
                 while len(self._inflight) >= depth:
                     self._process_oldest_chunk()
             did = True
@@ -159,6 +173,20 @@ class _SchedulerMixin:
             self._process_oldest_chunk()
             did = True
         return did
+
+    def _queued_placeable(self) -> tuple[bool, bool]:
+        """``(queued, placeable)``: whether requests wait, and whether
+        one of them would get a slot now — the test ``_claim_pending``
+        makes, without its side effects, so it may run with chunks in
+        flight. It answers from host slot state, which lags the device
+        by those chunks: an end not yet read reads as blocked, and the
+        blocked regime's own read of the oldest chunk is what finds it.
+        No clock and nothing rank-local is read, so lockstep ranks agree."""
+        with self._lock:
+            waiting = list(self._waiting)
+        return bool(waiting), any(
+            self._choose_slot(req)[0] is not None for req, _h in waiting
+        )
 
     def _claim_pending(self):
         """First PLACEABLE waiting request — not just the head: a
@@ -434,20 +462,24 @@ class _SchedulerMixin:
         return toks
 
     def _count_decode_dispatch(self, steps: int, live: int,
-                               single: bool = False) -> None:
+                               single: bool = False,
+                               blocked: bool = False) -> None:
         """One program call that decodes, counted where the batch is
         formed: ``decode_steps / decode_dispatches`` is the realised
         chunk, ``decode_dispatches_single`` the calls of the one-step
-        decode program, and ``decode_slot_steps`` the slots live at
-        dispatch times the steps asked, so ``decode_slot_steps /
-        (decode_steps * num_slots)`` is occupancy without reckoning it
-        from tokens."""
+        decode program, ``decode_dispatches_blocked`` those made while
+        requests waited and none had a slot, and ``decode_slot_steps``
+        the slots live at dispatch times the steps asked, so
+        ``decode_slot_steps / (decode_steps * num_slots)`` is occupancy
+        without reckoning it from tokens."""
         m = self.metrics
         m["decode_steps"] += steps
         m["decode_dispatches"] += 1
         m["decode_slot_steps"] += live * steps
         if single:
             m["decode_dispatches_single"] += 1
+        if blocked:
+            m["decode_dispatches_blocked"] += 1
 
     def _remaining_work(self) -> int:
         """Max over active slots of tokens still to emit beyond steps
@@ -483,7 +515,7 @@ class _SchedulerMixin:
                 break
         return best
 
-    def _dispatch_decode(self, single: bool = False):
+    def _dispatch_decode(self, single: bool = False, blocked: bool = False):
         """Dispatch one decode chunk asynchronously: device state advances
         to output futures immediately; the token read is deferred to
         _process_oldest_chunk. The active-slot list is snapshotted at
@@ -498,7 +530,10 @@ class _SchedulerMixin:
             ]
             chunk = 1 if single else self._pick_chunk()
             if sp:
-                sp.set_metadata(chunk=chunk, active=len(active), single=single)
+                sp.set_metadata(
+                    chunk=chunk, active=len(active), single=single,
+                    blocked=blocked, inflight=len(self._inflight),
+                )
             # Paged pool: extend every active slot's pages past its write
             # frontier BEFORE the chunk dispatches (engine/paged.py) — a
             # decode write must never land through a trash table entry.
@@ -508,7 +543,9 @@ class _SchedulerMixin:
             )
             t_dispatch = time.monotonic()
             toks = self._run_decode_step(chunk=chunk, dl_steps=dl_steps)
-            self._count_decode_dispatch(chunk, len(active), single=chunk == 1)
+            self._count_decode_dispatch(
+                chunk, len(active), single=chunk == 1, blocked=blocked
+            )
             # The dispatch wall rides the in-flight entry so the flight
             # recorder can pair it with the (deferred) sync wall into one
             # per-chunk dispatch-vs-sync event.

@@ -149,23 +149,26 @@ class _SessionMixin:
     as one unit apart from the decode scheduler.
     """
 
-    def _slot_for(self, request: Request) -> Optional[int]:
-        """Pick the slot for a request, or None if it must wait.
+    def _choose_slot(self, request: Request) -> tuple[Optional[int], bool]:
+        """The slot a request would get now, and whether taking it evicts
+        an idle session: ``(None, False)`` if it must wait. Pure — it
+        reads host slot state only, so the scheduler may ask it with
+        chunks in flight (``_queued_placeable``).
 
         Priority: the session's own resident slot (but never while a
         previous request on the same session is still decoding there) →
-        a free unpinned slot → evict the least-recently-used idle session
-        to host and take its slot."""
+        a free unpinned slot → the slot of the least-recently-used idle
+        session, which the claim pages out to host (``_slot_for``)."""
         sid = request.session_id if self.cfg.max_sessions > 0 else None
         if sid is not None:
             sess = self._sessions.get(sid)
             if sess is not None and sess.slot is not None:
                 if self._slots[sess.slot].active:
-                    return None  # same-session turn still in flight
-                return sess.slot
+                    return None, False  # same-session turn still in flight
+                return sess.slot, False
         for i, s in enumerate(self._slots):
             if not s.active and s.session_id is None:
-                return i
+                return i, False
         idle_pinned = [
             (self._sessions[s.session_id].last_used, i)
             for i, s in enumerate(self._slots)
@@ -173,10 +176,17 @@ class _SessionMixin:
             and s.session_id in self._sessions
         ]
         if idle_pinned:
-            _, i = min(idle_pinned)
+            return min(idle_pinned)[1], True
+        return None, False  # every slot is decoding
+
+    def _slot_for(self, request: Request) -> Optional[int]:
+        """Claim the slot ``_choose_slot`` picks, evicting the idle
+        session pinned there to host if that is the price. Runs a device
+        program then, so only with the pipeline flushed."""
+        i, evict = self._choose_slot(request)
+        if evict:
             self._offload_session(self._sessions[self._slots[i].session_id])
-            return i
-        return None  # every slot is decoding
+        return i
 
     def _offload_session(self, sess: _SessionKV) -> None:
         """Page an idle session's valid KV rows to host RAM and unpin its

@@ -384,3 +384,134 @@ class TestDecodePipeline:
             got, fin = h.collect_tokens(timeout=5)
             assert fin.finish_reason == FinishReason.LENGTH
             assert got == w
+
+    # More requests than slots, so most decode steps run with requests
+    # waiting and no slot for them (the scheduler's blocked regime: the
+    # next step is dispatched before the one in flight is read).
+    _SATURATED = ((3, 9), (5, 5), (7, 12), (9, 7), (11, 3), (13, 10), (15, 6))
+
+    def _run_saturated(self, eng, stop_id):
+        """Seven requests on two slots: mixed ``max_tokens``, the third
+        ends on a stop id mid-run, the last is cancelled while it waits.
+        Returns ``[(tokens, reason, num_generated)]`` in submit order."""
+        handles = []
+        for i, (first, n) in enumerate(self._SATURATED):
+            stops = (stop_id,) if i == 2 else ()
+            handles.append(eng.submit(
+                [first, first + 1, first + 2],
+                SamplingParams(temperature=0.0, max_tokens=n,
+                               stop_token_ids=stops),
+            ))
+        for _ in range(3):
+            eng.step()
+        handles[-1].cancel()  # still queued: five requests are ahead of it
+        while eng.step():
+            pass
+        out = []
+        for h in handles:
+            toks, fin = h.collect_tokens(timeout=5)
+            out.append((toks, fin.finish_reason, fin.num_generated_tokens))
+        return out
+
+    @pytest.mark.parametrize("kw", [
+        pytest.param({}, id="inline-sync"),
+        pytest.param({"decode_ring": 2}, id="ring-drain"),
+    ])
+    def test_saturated_pipelined_matches_sync(self, kw):
+        # Each request alone on a fresh engine: what its stream must be,
+        # whoever held its slot before it.
+        alone = [
+            self._mk(1).generate(
+                [first, first + 1, first + 2],
+                SamplingParams(temperature=0.0, max_tokens=n))[0]
+            for first, n in self._SATURATED
+        ]
+        stop_id = alone[2][4]
+        assert stop_id not in alone[2][:4]
+        want = [(t, FinishReason.LENGTH, len(t)) for t in alone]
+        want[2] = (alone[2][:4], FinishReason.STOP, 4)
+        want[-1] = ([], FinishReason.CANCELLED, 0)
+        for pipeline in (1, 2):
+            eng = self._mk(pipeline, **kw)
+            try:
+                assert self._run_saturated(eng, stop_id) == want, pipeline
+                m = eng.metrics
+                # The mechanism engaged, and only ever with one-step programs.
+                assert 0 < m["decode_dispatches_blocked"] <= m["decode_dispatches_single"]
+                assert m["requests_finished"] == len(want)
+            finally:
+                eng.stop()
+
+    def test_blocked_turn_waits_for_its_own_slot_and_evicts_nobody(self):
+        """A session's next turn arrives while its previous turn still
+        decodes: its own slot is the only one it may take, so it is blocked
+        even though an idle session's slot could be had by eviction. The
+        predicate runs every step with a chunk in flight and offloads
+        nothing; the turn lands on its own slot and reuses its rows."""
+        sp1 = SamplingParams(temperature=0.0, max_tokens=12)
+        sp2 = SamplingParams(temperature=0.0, max_tokens=5)
+        p1 = [1, 2, 3, 4, 5]
+        t1, _ = self._mk(1).generate(p1, sp1)
+        p2 = p1 + t1 + [9]
+        want2, _ = self._mk(1).generate(p2, sp2)
+
+        eng = self._mk(2, max_sessions=4)
+        m = eng.metrics
+        idle = eng.submit([6, 7, 8], sp2, session_id="idle")
+        while eng.step():
+            pass
+        assert idle.collect_tokens(timeout=5)[1].finish_reason == FinishReason.LENGTH
+        idle_slot = eng._sessions["idle"].slot
+        assert idle_slot is not None and not eng._slots[idle_slot].active
+
+        h1 = eng.submit(p1, sp1, session_id="s")
+        eng.step()
+        own = eng._sessions["s"].slot
+        assert own is not None and own != idle_slot
+        h2 = eng.submit(p2, sp2, session_id="s")
+        blocked_steps = 0
+        while eng._slots[own].active and eng._slots[own].request.request_id == h1.request_id:
+            assert eng._choose_slot(eng._waiting[0][0]) == (None, False)
+            eng.step()
+            blocked_steps += bool(eng._inflight)
+            assert m["session_offloads"] == 0
+            assert eng._sessions["idle"].slot == idle_slot
+        assert blocked_steps > 2 and m["decode_dispatches_blocked"] > 2
+        reuse0 = m["prefix_reuse_tokens"]
+        while eng.step():
+            pass
+        got1, _ = h1.collect_tokens(timeout=5)
+        got2, fin2 = h2.collect_tokens(timeout=5)
+        assert got1 == t1 and got2 == want2
+        assert fin2.finish_reason == FinishReason.LENGTH
+        assert eng._sessions["s"].slot == own
+        # Every valid row of turn 1 (its last token's row is not trusted).
+        assert m["prefix_reuse_tokens"] - reuse0 == len(p1) + len(t1) - 1
+        assert m["session_offloads"] == 0
+
+    def test_choosing_a_slot_is_pure_and_the_claim_evicts(self):
+        """``_choose_slot`` names the idle session's slot and says that it
+        costs an eviction; only ``_slot_for`` pays it."""
+        sp = SamplingParams(temperature=0.0, max_tokens=20)
+        eng = self._mk(2, max_sessions=4)
+        m = eng.metrics
+        eng.submit([6, 7, 8], SamplingParams(temperature=0.0, max_tokens=3),
+                   session_id="idle")
+        while eng.step():
+            pass
+        idle_slot = eng._sessions["idle"].slot
+        busy = eng.submit([1, 2, 3], sp)
+        eng.step()
+        waiter = eng.submit([4, 5, 6], SamplingParams(temperature=0.0, max_tokens=3))
+        request = eng._waiting[0][0]
+        assert eng._choose_slot(request) == (idle_slot, True)
+        assert eng._queued_placeable() == (True, True)
+        assert m["session_offloads"] == 0 and eng._sessions["idle"].slot == idle_slot
+        eng.step()  # placeable: flush, claim (evicts), place
+        assert m["session_offloads"] == 1 and eng._sessions["idle"].slot is None
+        assert eng._slots[idle_slot].request.request_id == waiter.request_id
+        assert m["pipeline_flushes"] == 1 and m["decode_dispatches_blocked"] == 0
+        busy.cancel()
+        while eng.step():
+            pass
+        assert len(waiter.collect_tokens(timeout=5)[0]) == 3

@@ -77,15 +77,20 @@ def _named(events, name):
 
 def test_phase_spans_nest_under_step_and_carry_request_ids(tmp_path):
     eng = _tiny_engine(num_slots=1)
-    eng.generate([1, 2, 3], GREEDY)  # compile outside the session
+    want, _fin = eng.generate([1, 2, 3], GREEDY)  # compile outside the session
+    # `first` ends on a stop id, which the host cannot foresee: the step
+    # ahead is in flight when the end is read, and is flushed for `second`.
+    stopping = SamplingParams(temperature=0.0, max_tokens=8,
+                              stop_token_ids=(want[5],))
     with _profiled(tmp_path) as spans:
         t_lo = time.monotonic_ns()
-        first = eng.submit([1, 2, 3], GREEDY)
+        first = eng.submit([1, 2, 3], stopping)
         eng.step()  # places `first`, leaves one chunk in flight
         second = eng.submit([4, 5, 6, 7], GREEDY)  # waits for the one slot
         _drain(eng)
         t_hi = time.monotonic_ns()
-    assert first.collect_tokens(timeout=60)[0] and second.collect_tokens(timeout=60)[0]
+    got_first, got_second = (h.collect_tokens(timeout=60)[0] for h in (first, second))
+    assert got_first == want[:5] and len(got_second) == GREEDY.max_tokens
     (events,) = spans().values()  # one thread did everything
 
     steps = _named(events, phases.STEP)
@@ -120,16 +125,20 @@ def test_phase_spans_nest_under_step_and_carry_request_ids(tmp_path):
     # The step's mono_ns is the flight recorder's clock at its entry.
     assert all(t_lo <= s[3]["mono_ns"] <= t_hi for s in steps)
     assert {"queued", "inflight"} <= set(steps[0][3])
-    # While `second` waited, every dispatch asked for the one-step program.
+    # While `second` waited with no slot to go to, every dispatch asked for
+    # the one-step program, ahead of the step being read.
     waiting = [d[3] for d in _named(events, phases.DECODE_DISPATCH)
                if places[0][2] <= d[1] and d[2] <= places[1][1]]
     assert waiting[0]["chunk"] == 4 and not waiting[0]["single"]
+    assert not waiting[0]["blocked"] and waiting[0]["inflight"] == 0
+    assert len(waiting) == 3
     assert all(d["chunk"] == 1 and d["single"] and d["active"] == 1
-               for d in waiting[1:])
+               and d["blocked"] and d["inflight"] == 1 for d in waiting[1:])
     flush = _named(events, phases.FLUSH_PIPELINE)
     assert len(flush) == 1 and flush[0][3]["chunks"] == 1
     emitted = sum(e[3]["tokens"] for e in _named(events, phases.EMIT))
-    assert emitted == 2 * (GREEDY.max_tokens - 1)  # the first comes from prefill
+    # The first token of each comes from its prefill.
+    assert emitted == (len(got_first) - 1) + (len(got_second) - 1)
     assert sum(e[3]["finished"] for e in _named(events, phases.EMIT)) == 2
 
 
@@ -159,7 +168,7 @@ def test_engine_thread_sleep_and_ring_drain_spans(tmp_path):
 
 def test_counters_against_a_scripted_schedule():
     """One slot, chunk of 4, pipeline of 2. `a` is placed and decodes alone;
-    `b` arrives while a chunk of `a` is in flight and waits for the slot."""
+    `b` arrives while a chunk of `a` is in flight and has no slot to go to."""
     eng = _tiny_engine(num_slots=1)
     m = eng.metrics
     a = eng.submit([1, 2, 3], GREEDY)
@@ -171,24 +180,114 @@ def test_counters_against_a_scripted_schedule():
     assert m["pipeline_flushes"] == 0
     b = eng.submit([4, 5, 6], GREEDY)
     eng.step()
-    # `b` waits: the chunk in flight is flushed for it (4 of a's 7 decode
-    # tokens), no slot is free, and the next dispatch is one step, read back
-    # at once.
-    assert m["pipeline_flushes"] == 1
+    # `b` waits and is blocked: nothing is flushed for it. One step is
+    # dispatched ahead of the chunk in flight, then the chunk is read (4 of
+    # a's 7 decode tokens) and the step stays in flight.
+    assert m["pipeline_flushes"] == 0
     assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 1)
+    assert m["decode_dispatches_blocked"] == 1
     assert (m["decode_steps"], m["decode_slot_steps"]) == (5, 5)
+    assert (len(eng._inflight), m["tokens_generated"]) == (1, 5)
     _drain(eng)
     assert len(a.collect_tokens(timeout=60)[0]) == 8
     assert len(b.collect_tokens(timeout=60)[0]) == 8
-    # a: 4 + 1 + 1 + 1 steps, the last three one-step calls while b waited
-    # with nothing in flight (so no further flush). b decodes alone: 4 + 3,
-    # where the tail picks the smallest variant that covers it (the chunk
-    # of 4, one step of it garbage).
-    assert m["pipeline_flushes"] == 1
-    assert m["decode_dispatches_single"] == 3
+    # a: 4 + 1 + 1 + 1 steps, the last three one-step calls while b was
+    # blocked, each dispatched with its predecessor in flight. a's end is
+    # foreseeable from its budget, so no step is dispatched past it and
+    # nothing is in flight when b becomes placeable: no flush at all. b
+    # decodes alone: 4 + 3, where the tail picks the smallest variant that
+    # covers it (the chunk of 4, one step of it garbage).
+    assert m["pipeline_flushes"] == 0
+    assert (m["decode_dispatches_single"], m["decode_dispatches_blocked"]) == (3, 3)
     assert m["decode_dispatches"] == 4 + 2
     assert m["decode_steps"] == 7 + 8
     assert m["decode_slot_steps"] == m["decode_steps"]  # one slot, always live
+    assert m["tokens_generated"] == 16
+
+
+def test_an_unforeseen_end_is_found_a_step_late_and_flushed_once():
+    """As above, but `a` ends on a stop id at its sixth token: the host
+    learns of it from the read-back with the step ahead in flight. That
+    step is flushed (it wrote nothing for `a`: the device deactivated the
+    slot in the step that sampled the stop id) and `b` is placed."""
+    eng = _tiny_engine(num_slots=1)
+    m = eng.metrics
+    want_a, _ = eng.generate([1, 2, 3], GREEDY)
+    want_b, _ = eng.generate([4, 5, 6], GREEDY)
+    base = dict(m)
+    a = eng.submit([1, 2, 3], SamplingParams(
+        temperature=0.0, max_tokens=8, stop_token_ids=(want_a[5],)))
+    eng.step()
+    b = eng.submit([4, 5, 6], GREEDY)
+    eng.step()  # single ahead; reads the chunk of 4
+    eng.step()  # single ahead; reads the stop id: a ends, one step in flight
+    assert a.collect_tokens(timeout=60)[0] == want_a[:5]
+    assert m["pipeline_flushes"] == base["pipeline_flushes"]
+    assert len(eng._inflight) == 1 and not eng._slots[0].active
+    eng.step()  # b is placeable: flush the step ahead, place, decode
+    assert m["pipeline_flushes"] - base["pipeline_flushes"] == 1
+    assert eng._slots[0].request.request_id == b.request_id
+    _drain(eng)
+    got_b, fin_b = b.collect_tokens(timeout=60)
+    assert got_b == want_b and fin_b.num_generated_tokens == 8
+    delta = {k: m[k] - base[k] for k in (
+        "decode_dispatches", "decode_dispatches_single",
+        "decode_dispatches_blocked", "decode_steps", "tokens_generated")}
+    # a: chunk of 4 + two blocked single steps (the second one garbage);
+    # b: 4 + 4. Tokens: a 5, b 8.
+    assert delta == {
+        "decode_dispatches": 3 + 2, "decode_dispatches_single": 2,
+        "decode_dispatches_blocked": 2, "decode_steps": 6 + 8,
+        "tokens_generated": 13,
+    }
+
+
+def test_a_placeable_waiting_request_keeps_the_synchronous_schedule():
+    """Two slots, one free: `b` arrives while a chunk of `a` is in flight
+    and can be placed at once: the chunk is flushed for it and it is
+    placed in the same step; nothing is counted as blocked."""
+    eng = _tiny_engine(num_slots=2)
+    m = eng.metrics
+    a = eng.submit([1, 2, 3], GREEDY)
+    eng.step()
+    assert (m["decode_dispatches"], len(eng._inflight)) == (1, 1)
+    b = eng.submit([4, 5, 6], GREEDY)
+    c = eng.submit([7, 8, 9], GREEDY)
+    eng.step()
+    # Flushed for b (4 of a's tokens), b placed; c still waits and has no
+    # slot: the next dispatch is one step for both live slots, and since c
+    # is blocked it stays in flight.
+    assert m["pipeline_flushes"] == 1 and m["prefill_steps"] == 2
+    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 1)
+    assert m["decode_dispatches_blocked"] == 1
+    assert (m["decode_steps"], m["decode_slot_steps"]) == (5, 4 + 2)
+    assert (len(eng._inflight), m["tokens_generated"]) == (1, 1 + 4 + 1)
+    _drain(eng)
+    for h in (a, b, c):
+        toks, fin = h.collect_tokens(timeout=60)
+        assert len(toks) == 8 and fin.num_generated_tokens == 8
+    assert m["tokens_generated"] == 24
+
+
+def test_nobody_blocked_counts_nothing_blocked():
+    """Two slots, two requests, one free slot each time: the waiting
+    request is always placeable, so the schedule and its counters are the
+    ones before the blocked regime existed."""
+    eng = _tiny_engine(num_slots=2)
+    m = eng.metrics
+    a = eng.submit([1, 2, 3], GREEDY)
+    eng.step()
+    b = eng.submit([4, 5, 6], GREEDY)
+    eng.step()
+    # Flushed for b, b placed, nobody waits any more: a full chunk.
+    assert m["pipeline_flushes"] == 1
+    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 0)
+    assert (m["decode_steps"], m["decode_slot_steps"]) == (8, 4 + 8)
+    _drain(eng)
+    assert len(a.collect_tokens(timeout=60)[0]) == 8
+    assert len(b.collect_tokens(timeout=60)[0]) == 8
+    assert m["pipeline_flushes"] == 1
+    assert m["decode_dispatches_single"] == m["decode_dispatches_blocked"] == 0
     assert m["tokens_generated"] == 16
 
 
