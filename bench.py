@@ -476,20 +476,6 @@ def child_main() -> None:
             _log(f"greedy_spec bench failed: {exc!r}")
             greedy_spec = {"error": repr(exc)}
 
-    # --- device-resident decode ring A/B (engine/devloop.py) ----------
-    # The same greedy decode-heavy traffic ring-off vs ring-on
-    # (`decode_ring=2`): dispatch-path sync share must shrink and tok/s
-    # must hold, or the self-gate reports the disable with its measured
-    # rates — a silent regression is a failure either way.
-    devloop = None
-    if remaining() > (120 if on_accel else 50):
-        try:
-            devloop = _bench_devloop(cfg, remaining, on_accel)
-            _log(f"devloop bench done: {devloop}")
-        except Exception as exc:  # noqa: BLE001 - aux evidence only
-            _log(f"devloop bench failed: {exc!r}")
-            devloop = {"error": repr(exc)}
-
     # --- paged KV pool A/B (engine/kv_pages.py) -----------------------
     # Sessions-per-chip at equal pool bytes, occupancy/fragmentation
     # over a churny multi-session run, and decode tok/s paged vs
@@ -634,7 +620,6 @@ def child_main() -> None:
                 "interleave": interleave,
                 "kv_paged": kv_paged,
                 "latency": latency,
-                "devloop": devloop,
                 "trafficsim": trafficsim,
                 "fleet": fleet,
                 "disagg": disagg,
@@ -744,11 +729,6 @@ def child_main() -> None:
         result["aux"]["kv_paged"] = kv_paged
     if latency is not None:
         result["aux"]["latency"] = latency
-    if devloop is not None:
-        # Device-resident decode ring (engine/devloop.py): ring-on must
-        # hold tok/s with the link wall moved off the dispatch path, or
-        # aux.devloop.gate must report the self-disable.
-        result["aux"]["devloop"] = devloop
     if trafficsim is not None:
         # Traffic simulator (ROADMAP item 5): per-class SLO attainment
         # clean-vs-chaos with exact ledger reconciliation.
@@ -1784,104 +1764,6 @@ def _bench_greedy_spec(cfg, remaining, on_accel):
         # The acceptance bar: speculation pays, or the gate disabled it
         # and says so — never a silent regression.
         "paying": ratio >= 1.0 or gate_disabled,
-    }
-
-
-def _bench_devloop(cfg, remaining, on_accel):
-    """Device-resident decode ring A/B (engine/devloop.py): the SAME
-    greedy decode-heavy traffic through a ring-off engine (one blocking
-    device→host sync per chunk on the dispatch path) and a ring-on
-    engine (`decode_ring=2`, chunks dispatched ahead, readbacks on the
-    long-lived drainer thread, in-scan early exit armed).
-
-    The honest contract mirrors aux.greedy_spec: ring-on decode tok/s
-    >= ring-off, or `gate` reports the self-disable with the measured
-    rates. `sync_share` (decode_sync_s over dispatch+sync) is the
-    overlap evidence — with the ring on it is only the residual wait
-    the dispatch path still paid; the real link wall moved to the
-    drainer (`drainer_drain_s`)."""
-    import gc
-
-    from omnia_tpu.engine import EngineConfig, InferenceEngine, SamplingParams
-
-    base = dict(
-        num_slots=4,
-        max_seq=512 if on_accel else 128,
-        prefill_buckets=(64,),
-        dtype="bfloat16" if on_accel else "float32",
-        decode_chunk=8,
-        max_sessions=0,
-    )
-    max_tokens = 128 if on_accel else 64
-    waves = 4 if on_accel else 3  # long enough for >=1 full gate decision
-    prompt = list(range(11, 27)) * 3                   # 48-token prompt
-    arms = {"off": dict(base), "on": dict(base, decode_ring=2)}
-    out = {}
-    gate_report = None
-    for tag in ("off", "on"):
-        engine = InferenceEngine(cfg, EngineConfig(**arms[tag]), seed=0)
-        try:
-            if tag == "on":
-                # Bench-scale gate window (the spec_gate_window=8 idiom):
-                # the default 32-chunk phases need a longer run than the
-                # arm budget to reach a decision, and the contract below
-                # leans on the gate having actually decided.
-                from omnia_tpu.engine.devloop import RingGate
-
-                engine._devloop.gate = RingGate(8)
-            engine.warmup(sessions=False)
-            engine.start()
-            sp = SamplingParams(temperature=0.0, max_tokens=max_tokens)
-            m0 = dict(engine.metrics)
-            t0 = time.monotonic()
-            tokens = 0
-            for _ in range(waves):
-                handles = [
-                    engine.submit(prompt, sp)
-                    for _ in range(base["num_slots"])
-                ]
-                tokens += sum(
-                    len(h.collect_tokens(timeout=300)[0]) for h in handles
-                )
-            wall = time.monotonic() - t0
-            dispatch = engine.metrics["decode_dispatch_s"] - m0["decode_dispatch_s"]
-            sync = engine.metrics["decode_sync_s"] - m0["decode_sync_s"]
-            arm = {
-                "tok_s": round(tokens / wall, 1),
-                "tokens": tokens,
-                "sync_share": round(sync / max(dispatch + sync, 1e-9), 3),
-            }
-            if tag == "on":
-                arm["ring_drains"] = engine.metrics["ring_drains"]
-                arm["ring_full_stalls"] = engine.metrics["ring_full_stalls"]
-                arm["early_exit_steps"] = engine.metrics["early_exit_steps"]
-                arm["gate_state"] = engine.metrics["decode_ring_gate_state"]
-                dl = engine._devloop
-                d = dl.drainer_if_live() if dl is not None else None
-                if d is not None:
-                    drains, drain_s = d.stats()
-                    arm["drainer_drains"] = drains
-                    arm["drainer_drain_s"] = round(drain_s, 4)
-                gate_report = (
-                    dl.gate.report()
-                    if dl is not None and dl.gate is not None else None
-                )
-            out[tag] = arm
-        finally:
-            engine.stop()
-            del engine
-            gc.collect()
-    ratio = out["on"]["tok_s"] / max(out["off"]["tok_s"], 1e-9)
-    gate_disabled = bool(gate_report and gate_report["state"] == "off")
-    return {
-        "on": out["on"],
-        "off": out["off"],
-        "ratio_on_vs_off": round(ratio, 3),
-        "gate": gate_report,
-        # The acceptance bar: overlap pays, or the gate disabled it and
-        # says so — never a silent regression.
-        "paying": ratio >= 1.0 or gate_disabled,
-        "regression": bool(ratio < 0.95 and not gate_disabled),
     }
 
 

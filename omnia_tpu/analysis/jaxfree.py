@@ -39,11 +39,10 @@ JAX_FREE_PACKAGES: tuple[str, ...] = (
     # the CI poisoned-jax subset proves the routing/handoff plane
     # without a device stack.
     "omnia_tpu/engine/disagg.py",
-    # Device-resident decode loop host half: the chunk drainer, ring
-    # self-gate, and deadline-step state are host-side by contract —
-    # the CI poisoned-jax subset proves the drain/gate plane without a
-    # device stack (the readback's numpy import is lazy for the same
-    # reason).
+    # The pipeline entry and the watchdog's chunk drainer are host-side
+    # by contract — the CI poisoned-jax subset proves the drain plane
+    # without a device stack (the readback's numpy import is lazy for
+    # the same reason).
     "omnia_tpu/engine/devloop.py",
 )
 

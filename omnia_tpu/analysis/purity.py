@@ -54,9 +54,8 @@ PURITY_FILES_PREFIXES: tuple[str, ...] = (
     # Role routing and the handoff plane are stats arithmetic + worker
     # RPCs; a traced body here would be the same bug class.
     "omnia_tpu/engine/disagg.py",
-    # The decode-ring host half is host-side by contract (drainer
-    # threads + gate arithmetic); a traced body here would be the same
-    # bug class.
+    # The watchdog's chunk drainer is host-side by contract (a thread
+    # and a queue); a traced body here would be the same bug class.
     "omnia_tpu/engine/devloop.py",
     # The engine-loop phase spans are host annotations on the profiler's
     # clock; one opened inside a traced body would run once, at trace

@@ -39,7 +39,6 @@ construction, submission, warmup, and the thread lifecycle.
 from __future__ import annotations
 
 import collections
-import functools
 import itertools
 import logging
 import threading
@@ -51,7 +50,7 @@ import jax.numpy as jnp
 
 from omnia_tpu.engine import phases
 from omnia_tpu.engine.coldstart import PHASE_CODES, ColdStartTracker
-from omnia_tpu.engine.devloop import DevLoopState, validate_decode_ring
+from omnia_tpu.engine.devloop import DevLoopState
 from omnia_tpu.engine.faults import FaultPlan
 from omnia_tpu.engine.flight import FlightRecorder
 from omnia_tpu.engine.interleave import _InflightPrefill, _InterleaveMixin
@@ -126,7 +125,6 @@ class InferenceEngine(
         if engine_cfg.warmup_threads < 0:
             raise ValueError("warmup_threads must be >= 0")
         validate_spec_config(engine_cfg)
-        validate_decode_ring(engine_cfg)
 
         # Grammar-constrained decoding (engine/grammar/): gated ONCE here;
         # every grammar code path below checks this flag, so grammar=False
@@ -309,19 +307,11 @@ class InferenceEngine(
         # Dispatched-but-unread decode chunks (_InflightChunk entries,
         # engine/devloop.py). Engine-thread-owned.
         self._inflight: collections.deque = collections.deque()
-        # Device-resident decode loop (engine/devloop.py): the drainer
-        # thread, the async A/B gate, and the deadline-step EMA. Also
-        # built for watchdog-only engines — the ONE long-lived drainer
-        # replaces the old per-chunk omnia-chunk-sync threads. None
-        # with decode_ring=0 and no watchdog (the guarded no-op: no
-        # thread, no state, no extra attribute reads on the hot path).
+        # The watchdog's read-back lane (engine/devloop.py): ONE
+        # long-lived drainer thread, started on the first chunk read.
+        # None without a watchdog (no thread, no state).
         self._devloop: Optional[DevLoopState] = (
-            DevLoopState(
-                engine_cfg.decode_ring,
-                drain_span=functools.partial(phase, phases.RING_DRAIN),
-            )
-            if engine_cfg.decode_ring > 0 or engine_cfg.watchdog_s is not None
-            else None
+            DevLoopState() if engine_cfg.watchdog_s is not None else None
         )
         # Token-budget interleaving (engine/interleave.py): the at-most-
         # one placement currently mid-interleave. Always None with
@@ -416,19 +406,6 @@ class InferenceEngine(
             # stable key set is the same on a healthy engine — a
             # dashboard querying it pre-incident reads 0, not KeyError.
             "recoveries": 0,
-            # Device-resident decode loop (engine/devloop.py):
-            # ring_drains = chunks whose device→host token readback ran
-            # on the drainer thread (async), ring_full_stalls = dispatches
-            # that had to process a chunk first because the undrained
-            # ring was at capacity, early_exit_steps = scan steps the
-            # all-slots-done early-out skipped the forward for,
-            # gate_state the async-vs-sync self-gate's decision
-            # (0 probing / 1 on / 2 off — the spec_gate_state encoding).
-            "decode_ring_enabled": 1 if engine_cfg.decode_ring > 0 else 0,
-            "ring_drains": 0,
-            "ring_full_stalls": 0,
-            "early_exit_steps": 0,
-            "decode_ring_gate_state": 0,
             # Stall-free batching (engine/interleave.py): mixed_steps =
             # fused prefill+decode dispatches, interleaved_prefill_tokens
             # = prompt tokens consumed by them (metered per piece — exact
@@ -620,13 +597,6 @@ class InferenceEngine(
         # handles, the device mask just stops wasted work.
         self._budget = jnp.zeros((B,), jnp.int32)
         self._stop_ids = jnp.full((B, MAX_DEVICE_STOP_IDS), -1, jnp.int32)
-        # Ring decode's per-slot grammar EOS (-1 = none): lets the scan
-        # stop a grammar slot whose eos id was truncated off the 8-wide
-        # stop-id set. Only the ring+grammar program family carries the
-        # operand — everything else leaves it unallocated.
-        self._geos = None
-        if self._gr_on and self.cfg.decode_ring > 0:
-            self._geos = jnp.full((B,), -1, jnp.int32)
         self._key_data = jnp.stack(
             [make_slot_key_data(self._seed + 1 + i) for i in range(B)]
         )

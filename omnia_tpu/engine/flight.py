@@ -77,9 +77,6 @@ EVENTS = frozenset({
                        # on the counted fresh-prefill fallback)
     "drain",           # one worker's graceful drain finished (attrs:
                        # worker, seconds — slow-drain attribution)
-    "ring_drain",      # token-ring buffer(s) drained on the drainer
-                       # thread (engine/devloop.py; attrs: buffers,
-                       # tokens, seconds — async readback attribution)
     "terminal",        # request finished (attrs carry the breakdown)
     # Cold-start phases (engine/coldstart.py): the submit-to-ready
     # bring-up seams, so an accelerator hang is attributed to a PHASE
@@ -312,35 +309,16 @@ class FlightRecorder:
         })
 
     def note_decode_chunk(self, chunk: int, dispatch_s: float,
-                          sync_s: float, active: int,
-                          drained: bool = False) -> None:
+                          sync_s: float, active: int) -> None:
         """One decode chunk fully processed: the host wall split between
         DISPATCH (async program submit) and SYNC (waiting on outputs) —
-        the roofline evidence, now per chunk instead of only cumulative.
-        ``drained=True`` means the readback ran on the drainer thread
-        (engine/devloop.py): sync_s is then only the residual wait the
-        dispatch path paid, and the real link time was already observed
-        into sync_us by ``note_ring_drain`` — skipping the observation
-        here keeps the dispatch/sync split honest under async drain."""
+        the roofline evidence, now per chunk instead of only cumulative."""
         self._record("decode_chunk", "", {
             "chunk": chunk, "dispatch_s": dispatch_s,
-            "sync_s": sync_s, "active": active, "drained": drained,
+            "sync_s": sync_s, "active": active,
         })
         self.hist["dispatch_us"].observe(dispatch_s * 1e6)
-        if not drained:
-            self.hist["sync_us"].observe(sync_s * 1e6)
-
-    def note_ring_drain(self, buffers: int, tokens: int,
-                        drain_s: float) -> None:
-        """Token-ring buffer(s) drained (engine/devloop.py): recorded
-        FROM the drainer thread — the thread that actually blocked on
-        the device→host link — so sync_us attribution follows the
-        blocking, not the dispatch path. ``seconds`` makes it a
-        duration row in the Chrome export."""
-        self._record("ring_drain", "", {
-            "buffers": buffers, "tokens": tokens, "seconds": drain_s,
-        })
-        self.hist["sync_us"].observe(drain_s * 1e6)
+        self.hist["sync_us"].observe(sync_s * 1e6)
 
     def note_spec_verify(self, proposed: int, accepted: int,
                          dispatch_s: float, sync_s: float,
@@ -549,13 +527,11 @@ def to_chrome_trace(events: list,
     # land at a negative ts. Base on the earliest computed start.
     def start_of(e: dict) -> float:
         attrs = e.get("attrs", {})
-        if e["kind"] in INIT_EVENTS or e["kind"] in (
-            "drain", "handoff", "ring_drain"
-        ):
-            # Init-phase, drain, handoff, and ring-drain events are
-            # recorded at their END with the wall in `seconds` — the
-            # longest durations in any cold-start or scale-down dump,
-            # so the base must account for them.
+        if e["kind"] in INIT_EVENTS or e["kind"] in ("drain", "handoff"):
+            # Init-phase, drain, and handoff events are recorded at
+            # their END with the wall in `seconds` — the longest
+            # durations in any cold-start or scale-down dump, so the
+            # base must account for them.
             return e["mono"] - attrs.get("seconds", 0.0)
         return e["mono"] - attrs.get("dispatch_s", 0.0) - attrs.get("sync_s", 0.0)
 
@@ -593,13 +569,10 @@ def to_chrome_trace(events: list,
                 "ts": us(e["mono"] - dur), "dur": round(dur * 1e6, 1),
                 "args": attrs,
             })
-        elif kind in INIT_EVENTS or kind in ("drain", "handoff", "ring_drain"):
+        elif kind in INIT_EVENTS or kind in ("drain", "handoff"):
             dur = attrs.get("seconds", 0.0)
             out.append({
-                # ring_drain renders on its own lane (tid 1): the work
-                # happened on the drainer thread, not the dispatch path.
-                "ph": "X", "pid": 1, "tid": 1 if kind == "ring_drain" else 0,
-                "name": kind,
+                "ph": "X", "pid": 1, "tid": 0, "name": kind,
                 "ts": us(e["mono"] - dur), "dur": round(dur * 1e6, 1),
                 "args": attrs,
             })

@@ -320,11 +320,8 @@ class _InterleaveMixin:
             self._flight.note_mixed_step(
                 pf.request.request_id, take, bucket, dispatch_s
             )
-        # Mixed steps ride the same pipeline AND the same token ring as
-        # plain decode chunks (shared seam: _push_inflight hands the
-        # [1, B] token read to the drainer when async drain is engaged).
-        # No dl_steps: the mixed program family is non-ring — deadline
-        # masking only lives in the chunked ring scan.
+        # Mixed steps ride the same pipeline as plain decode chunks
+        # (shared seam: _push_inflight).
         self._push_inflight(dtoks, active, dispatch_s)
         if plan is not None:
             # Acceptance decides the verify slots' next inputs — sync
@@ -399,13 +396,6 @@ class _InterleaveMixin:
         self._stop_ids = self._stop_ids.at[slot_idx].set(
             jnp.asarray(ids, jnp.int32)
         )
-        if self._geos is not None:
-            # Ring scan's per-slot grammar EOS (-1 = none): set at every
-            # placement so a slot's previous occupant can never leak its
-            # eos id into the next request's stop mask.
-            self._geos = self._geos.at[slot_idx].set(
-                request.grammar.eos_id if request.grammar is not None else -1
-            )
         self._prefilling = None
         with self._lock:
             self._placing -= 1

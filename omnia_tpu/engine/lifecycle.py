@@ -108,7 +108,7 @@ class _LifecycleMixin:
             # device-state ownership has passed back to this caller.
             self._offload_idle_sessions()
         if self._devloop is not None:
-            # Join the long-lived chunk drainer (engine/devloop.py) —
+            # Join the watchdog's chunk drainer (engine/devloop.py) —
             # stop() skips a poisoned drainer's thread (it is wedged in
             # the hung readback that tripped the watchdog). A later
             # start() lazily builds a fresh one on first use.

@@ -20,7 +20,6 @@ import time
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
-from omnia_tpu.engine.devloop import validate_decode_ring
 from omnia_tpu.engine.disagg import validate_role
 from omnia_tpu.engine.faults import FaultPlan
 from omnia_tpu.engine.flight import FlightRecorder
@@ -90,8 +89,7 @@ class MockEngine(_MockMirrorsMixin, _MockSessionsMixin):
                  prefill_chunk_tokens: int = 0, flight_events: int = 0,
                  kv_pages: int = 0, kv_page_tokens: int = 64,
                  spec_decode: int = 0, spec_decode_max: int = 0,
-                 spec_gate_window: int = 0, decode_ring: int = 0,
-                 warmup_threads: int = 0,
+                 spec_gate_window: int = 0, warmup_threads: int = 0,
                  coldstart=None, name: str = "mock", role: str = "pooled"):
         from omnia_tpu.engine.coldstart import ColdStartTracker
 
@@ -195,13 +193,6 @@ class MockEngine(_MockMirrorsMixin, _MockSessionsMixin):
             from omnia_tpu.engine.spec_decode import _SpecGate
 
             self._spec_gate = _SpecGate(spec_gate_window)
-        # Device-resident decode-loop parity (engine/devloop.py): the
-        # mock streams host-side (nothing to buffer), but with
-        # decode_ring set each playback books the identical drain/gate
-        # ledger (mock_mirrors._ring_mirror). Same validation as the
-        # engine: 1 is rejected, 0 is the guarded no-op.
-        self.decode_ring = decode_ring
-        validate_decode_ring(self)
         # Session-migration parity (engine/sessions.py export/import):
         # the mock keeps no KV, but it DOES remember which sessions are
         # resident — token streams keyed by session_id — so the
@@ -257,15 +248,6 @@ class MockEngine(_MockMirrorsMixin, _MockSessionsMixin):
             "spec_gate_state": 0,
             "spec_accept_ema": 0.0,
             "spec_index_bytes": 0,
-            # Device-resident decode-loop parity (engine/devloop.py):
-            # _ring_mirror books drains per chunk-stride of each reply;
-            # the mock never stalls (host playback) and mirrors no
-            # in-scan deadline mask, so stalls/early-exits stay 0.
-            "decode_ring_enabled": 1 if decode_ring > 0 else 0,
-            "ring_drains": 0,
-            "ring_full_stalls": 0,
-            "early_exit_steps": 0,
-            "decode_ring_gate_state": 0,
             # Paged-KV parity (engine/kv_pages.py): live playbacks hold
             # pages in a real allocator, so these mirror the engine's
             # pool gauges; all zero with kv_pages=0.
@@ -640,7 +622,6 @@ class MockEngine(_MockMirrorsMixin, _MockSessionsMixin):
         # decoded token) round-trips through the int8 scheme host-side.
         self._kv_roundtrip(prompt_tokens + reply_ids)
         self._spec_mirror(prompt_tokens, reply_ids, params)
-        self._ring_mirror(reply_ids)
         generated = 0
         if die_after == 0:
             self._finish(

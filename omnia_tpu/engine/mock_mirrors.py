@@ -148,23 +148,3 @@ class _MockMirrorsMixin:
             self.metrics["kv_pages_free"] = a.free_count
             self.metrics["kv_page_fragmentation"] = a.fragmentation()
             self.metrics["kv_page_cow_copies"] = a.cow_copies
-
-    def _ring_mirror(self, reply_ids: list) -> None:
-        """Device-resident decode-loop parity (engine/devloop.py): the
-        mock streams host-side, so the ring has nothing to buffer — but
-        with decode_ring set each playback books the IDENTICAL ledger
-        the real engine's drainer produces: one drain per chunk-sized
-        stride of the reply (ceil(len/ring) buffers for a ring of depth
-        `ring` standing in for the engine's chunk size), and the gate
-        state pinned to its async-engaged code. Scripted token output
-        is EXACTLY unchanged; decode_ring=0 books nothing (the guarded
-        no-op, zero-valued keys)."""
-        if self.decode_ring <= 0 or not reply_ids:
-            return
-        drains = -(-len(reply_ids) // self.decode_ring)
-        with self._lock:
-            self.metrics["ring_drains"] += drains
-            # The mock never measures a slower async arm, so its gate
-            # mirror reports the engaged code (RingGate.state_code()
-            # HOLD_ON encoding: 1 = on).
-            self.metrics["decode_ring_gate_state"] = 1

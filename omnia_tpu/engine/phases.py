@@ -20,8 +20,8 @@ is on::
             sp.set_metadata(request_id=rid)
 
 All engine-thread phases nest under ``STEP`` (a phase's self time is its
-duration less its children); ``SUBMIT`` runs on the caller's thread and
-``RING_DRAIN`` on the drainer's. ``STEP`` carries ``mono_ns``
+duration less its children); ``SUBMIT`` runs on the caller's thread.
+``STEP`` carries ``mono_ns``
 (``time.monotonic_ns()`` at entry): the offset between the flight
 recorder's clock and the profiler's, which ``flight.to_chrome_trace``
 takes to lay a flight dump on the profiler's time axis.
@@ -41,7 +41,6 @@ DECODE_DISPATCH = "omnia.engine.decode_dispatch"
 CHUNK_SYNC = "omnia.engine.chunk_sync"
 EMIT = "omnia.engine.emit"
 IDLE_SLEEP = "omnia.engine.idle_sleep"
-RING_DRAIN = "omnia.engine.ring_drain"
 SUBMIT = "omnia.engine.submit"
 
 

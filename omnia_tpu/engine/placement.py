@@ -292,14 +292,6 @@ class _PlacementMixin:
         self._stop_ids = self._stop_ids.at[slot_idx].set(
             jnp.asarray(ids, jnp.int32)
         )
-        if self._geos is not None:
-            # Ring scan's per-slot grammar EOS (-1 = none): unlike the
-            # 8-wide stop-id row it can never truncate away, so a
-            # grammar slot's EOS always masks in-scan. Set at every
-            # placement — a previous occupant's id must never leak.
-            self._geos = self._geos.at[slot_idx].set(
-                request.grammar.eos_id if request.grammar is not None else -1
-            )
         if span:
             span.set_metadata(slot=slot_idx, reuse=reuse, seeded=seeded)
         first = int(first_tok)

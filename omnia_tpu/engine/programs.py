@@ -234,37 +234,15 @@ def build_programs(
             for b in range(gtable.shape[0])
         ])  # [B, V]
 
-    def _dead_step(carry):
-        """Early-out dead branch (ring scan only): identity carry, the
-        frozen token vector replayed as the step output — the host
-        emission loop never reads a token for a slot it already saw
-        finish, so the replayed values are dead data."""
-        return carry, carry[2]
-
     def _mk_step_body(params, stop_ids, temp, top_p, top_k,
-                      gtable=None, gactive=None, grammar_on=False,
-                      geos=None, ring=False):
+                      gtable=None, gactive=None, grammar_on=False):
         """One decode step as a ``lax.scan`` body — the SINGLE source of
         the decode-step math, shared by the chunked decode programs and
         the fused mixed prefill+decode programs (interleaved and
         monolithic serving must stay bit-identical, so there is exactly
-        one place the step semantics live).
+        one place the step semantics live)."""
 
-        ``ring=True`` is the device-resident-loop edition
-        (EngineConfig.decode_ring, engine/devloop.py): the carry gains a
-        per-slot deadline-step budget as its LAST element (decremented
-        and masked exactly like the emission budget, so a deadline
-        finishes mid-scan instead of at the chunk boundary), grammar
-        slots additionally deactivate on their per-slot EOS id
-        (``geos``, -1 = none — covers an eos truncated off the 8-wide
-        stop-id set), and the whole step is ``lax.cond``-guarded on any
-        slot being live, so a chunk whose batch finishes at step k
-        stops paying forwards for steps k+1..N. ``ring=False`` traces
-        the exact pre-ring ops (the guarded no-op contract)."""
-
-        def step(carry):
-            if ring:
-                carry, dl = carry[:-1], carry[-1]
+        def body(carry, _):
             if grammar_on:
                 (ck, cv, tokens, positions, active, budget, key_data,
                  gstate) = carry
@@ -304,39 +282,13 @@ def build_programs(
                     active, jnp.minimum(positions + 1, max_seq - 1), positions
                 )
                 budget = budget - active.astype(jnp.int32)
-                if ring:
-                    # Deadline-step budget: decremented like the emission
-                    # budget (on active at step START); exhaustion masks
-                    # the slot from the NEXT step on, and the host mirror
-                    # finishes it with DEADLINE at the same step index.
-                    dl = dl - active.astype(jnp.int32)
                 hit_stop = (tok[:, None] == stop_ids).any(axis=1)
-                if ring and grammar_on:
-                    # Per-slot grammar EOS (geos, -1 = none): token ids
-                    # are >= 0, so non-grammar slots never match.
-                    hit_stop = hit_stop | (tok == geos)
                 active = active & ~hit_stop & (budget > 0)
-                if ring:
-                    active = active & (dl > 0)
                 tokens = jnp.where(active | hit_stop, tok, tokens)
             out = (ck, cv, tokens, positions, active, budget, key_data)
             if grammar_on:
                 out += (gstate,)
-            if ring:
-                out += (dl,)
             return out, tok
-
-        if not ring:
-            def body(carry, _):
-                return step(carry)
-            return body
-
-        def body(carry, _):
-            # All-slots-done early-out: once the batch is fully masked
-            # (stop/budget/deadline), remaining scan steps skip the
-            # forward entirely — the dead branch passes the carry
-            # through and replays the frozen token vector.
-            return jax.lax.cond(carry[4].any(), step, _dead_step, carry)
 
         return body
 
@@ -417,11 +369,10 @@ def build_programs(
             out += (carry[7],)
         return out, toks
 
-    def make_decode(chunk: int, ring: bool = False):
+    def make_decode(chunk: int):
         def decode_impl(params, ck, cv, tokens, positions, active, budget,
                         stop_ids, key_data, temp, top_p, top_k,
-                        gstate=None, gtable=None, gactive=None,
-                        geos=None, dl_budget=None):
+                        gstate=None, gtable=None, gactive=None):
             """`chunk` decode steps in ONE compiled program (lax.scan):
             one host↔device round trip per K tokens instead of per
             token. Stop-token/length finishes are masked ON DEVICE:
@@ -447,38 +398,16 @@ def build_programs(
             grammar_on = gstate is not None
             body = _mk_step_body(
                 params, stop_ids, temp, top_p, top_k, gtable, gactive,
-                grammar_on, geos=geos, ring=ring,
+                grammar_on,
             )
             init = (ck, cv, tokens, positions, active, budget, key_data)
             if grammar_on:
                 init += (gstate,)
-            if ring:
-                init += (dl_budget,)
             carry, toks = jax.lax.scan(body, init, None, length=chunk)
             # toks [K, B]
             return carry + (toks,)
 
-        if ring and ecfg.grammar:
-            def decode_chunk_ring_grammar(params, ck, cv, tokens, positions,
-                                          active, budget, stop_ids, key_data,
-                                          temp, top_p, top_k, gstate, gtable,
-                                          gactive, geos, dl_budget):
-                return decode_impl(params, ck, cv, tokens, positions, active,
-                                   budget, stop_ids, key_data, temp, top_p,
-                                   top_k, gstate, gtable, gactive, geos,
-                                   dl_budget)
-
-            fn = decode_chunk_ring_grammar
-        elif ring:
-            def decode_chunk_ring(params, ck, cv, tokens, positions, active,
-                                  budget, stop_ids, key_data, temp, top_p,
-                                  top_k, dl_budget):
-                return decode_impl(params, ck, cv, tokens, positions, active,
-                                   budget, stop_ids, key_data, temp, top_p,
-                                   top_k, dl_budget=dl_budget)
-
-            fn = decode_chunk_ring
-        elif ecfg.grammar:
+        if ecfg.grammar:
             def decode_chunk_grammar(params, ck, cv, tokens, positions,
                                      active, budget, stop_ids, key_data,
                                      temp, top_p, top_k, gstate, gtable,
@@ -502,13 +431,7 @@ def build_programs(
     # throughput, smaller ones so the tail of a generation (or a step
     # taken while requests queue — TTFT discipline) doesn't pay for a
     # full chunk. The scheduler's _pick_chunk chooses per dispatch.
-    # decode_ring > 0 swaps the WHOLE decode family for the ring
-    # edition (extra deadline/geos operands, early-out scan) — there is
-    # exactly one decode program set per engine, so ring on/off can
-    # never mix mid-pipeline. Ring off builds the exact pre-ring
-    # programs (the guarded no-op contract, tests/test_devloop.py).
-    _ring = ecfg.decode_ring > 0
-    decode_fns = {k: make_decode(k, ring=_ring) for k in ecfg.chunk_variants()}
+    decode_fns = {k: make_decode(k) for k in ecfg.chunk_variants()}
 
     def extend(params, ck, cv, tokens, positions, slot, write_start, last_idx,
                key_data, temp, top_p, top_k, *g):

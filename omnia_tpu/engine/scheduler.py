@@ -333,37 +333,32 @@ class _SchedulerMixin:
     def _fault_sleep_s(self) -> float:
         """Injected hang/slow-sync seconds for the next chunk readback
         (engine/faults.py): consumed at the point the readback STARTS —
-        inline, or on the drainer thread, where an injected hang must
-        look exactly like a hung device sync to the watchdog."""
+        inline, or on the watchdog's drainer thread, where an injected
+        hang must look exactly like a hung device sync."""
         fault = self._fault_plan
         if fault is None:
             return 0.0
         return fault.take_hang_s() + fault.slow_sync_s
 
-    def _sync_chunk_host(self, toks, entry=None) -> np.ndarray:
+    def _sync_chunk_host(self, toks) -> np.ndarray:
         """Device→host read of a decode chunk's tokens, optionally under
-        the hung-dispatch watchdog. watchdog_s=None without a drain
-        entry is the direct pre-existing sync (no thread); everything
-        else rides the engine's ONE long-lived drainer thread
-        (engine/devloop.py ChunkDrainer — it replaced the short-lived
-        per-chunk omnia-chunk-sync threads the watchdog used to spawn):
-        a readback already started at dispatch (``entry``) is awaited,
-        a watchdog-only readback is handed over now. A read that
-        outlives watchdog_s raises WatchdogTimeout — the loop's
-        recovery path fails in-flight handles and reallocates device
-        state, so a hung device bounds client latency instead of
-        freezing the engine silently."""
+        the hung-dispatch watchdog. watchdog_s=None is the direct sync
+        (no thread); with a watchdog the read rides the engine's ONE
+        long-lived drainer thread (engine/devloop.py ChunkDrainer) and
+        is awaited with a timeout. A read that outlives watchdog_s
+        raises WatchdogTimeout — the loop's recovery path fails
+        in-flight handles and reallocates device state, so a hung
+        device bounds client latency instead of freezing the engine
+        silently."""
         wd = self.cfg.watchdog_s
-        if entry is None:
-            if wd is None:
-                sleep_s = self._fault_sleep_s()
-                if sleep_s > 0.0:
-                    time.sleep(sleep_s)
-                return np.asarray(toks)
-            entry = self._devloop.get_drainer().submit(
-                toks, pre_sleep_s=self._fault_sleep_s()
-            )
-        host = self._devloop.get_drainer().wait(entry, timeout=wd)
+        if wd is None:
+            sleep_s = self._fault_sleep_s()
+            if sleep_s > 0.0:
+                time.sleep(sleep_s)
+            return np.asarray(toks)
+        drainer = self._devloop.get_drainer()
+        entry = drainer.submit(toks, pre_sleep_s=self._fault_sleep_s())
+        host = drainer.wait(entry, timeout=wd)
         if host is None:
             self.metrics["watchdog_trips"] += 1
             self._healthy = False  # readiness flips for the incident;
@@ -373,19 +368,12 @@ class _SchedulerMixin:
             )
         return host
 
-    def _run_decode_step(self, single: bool = False, chunk: Optional[int] = None,
-                         dl_steps=None):
-        """One chunked decode dispatch → host tokens [K, B]. Position
+    def _run_decode_step(self, chunk: Optional[int] = None):
+        """One chunked decode dispatch → device tokens [K, B]. Position
         advancement AND stop/length deactivation happen on-device inside
-        the scan. `single` picks the 1-step variant (used while work is
-        queued so a waiting prefill doesn't sit out a full chunk); `chunk`
-        picks an explicit compiled variant."""
-        if single:
-            fn = self._decode_fn_single
-        elif chunk is not None:
-            fn = self._decode_fns[chunk]
-        else:
-            fn = self._decode_fn
+        the scan. `chunk` picks an explicit compiled variant (1 is the
+        one-step program used while requests wait)."""
+        fn = self._decode_fn if chunk is None else self._decode_fns[chunk]
         t_dispatch = time.monotonic()
         args = (
             self.params,
@@ -401,27 +389,7 @@ class _SchedulerMixin:
             self._top_p,
             self._top_k,
         )
-        ring = self.cfg.decode_ring > 0
-        if ring and dl_steps is None:
-            dl_steps = self._deadline_steps()
-        if self._gr_on and ring:
-            # Ring grammar edition: the per-slot EOS ids and the
-            # deadline-step budget ride the dispatch; the returned
-            # deadline carry is discarded (recomputed per dispatch).
-            (
-                self._ck,
-                self._cv,
-                self._tokens,
-                self._positions,
-                self._active,
-                self._budget,
-                self._key_data,
-                self._gstate,
-                _dl,
-                toks,
-            ) = fn(*args, self._gstate, self._gtable, self._gactive,
-                   self._geos, dl_steps)
-        elif self._gr_on:
+        if self._gr_on:
             # Grammar edition: per-slot FSM state rides the dispatch and
             # advances on device (programs.decode_chunk_grammar).
             (
@@ -435,18 +403,6 @@ class _SchedulerMixin:
                 self._gstate,
                 toks,
             ) = fn(*args, self._gstate, self._gtable, self._gactive)
-        elif ring:
-            (
-                self._ck,
-                self._cv,
-                self._tokens,
-                self._positions,
-                self._active,
-                self._budget,
-                self._key_data,
-                _dl,
-                toks,
-            ) = fn(*args, dl_steps)
         else:
             (
                 self._ck,
@@ -538,95 +494,35 @@ class _SchedulerMixin:
             # frontier BEFORE the chunk dispatches (engine/paged.py) — a
             # decode write must never land through a trash table entry.
             self._prealloc_decode_pages(chunk)
-            dl_steps = (
-                self._deadline_steps() if self.cfg.decode_ring > 0 else None
-            )
             t_dispatch = time.monotonic()
-            toks = self._run_decode_step(chunk=chunk, dl_steps=dl_steps)
+            toks = self._run_decode_step(chunk=chunk)
             self._count_decode_dispatch(
                 chunk, len(active), single=chunk == 1, blocked=blocked
             )
             # The dispatch wall rides the in-flight entry so the flight
             # recorder can pair it with the (deferred) sync wall into one
             # per-chunk dispatch-vs-sync event.
-            self._push_inflight(
-                toks, active, time.monotonic() - t_dispatch, dl_steps
-            )
+            self._push_inflight(toks, active, time.monotonic() - t_dispatch)
 
-    def _deadline_steps(self) -> np.ndarray:
-        """Per-slot deadline budget in decode STEPS for the next ring
-        dispatch: remaining wall time to each slot's deadline divided by
-        the realized per-step EMA (engine/devloop.py), clamped to ≥ 1 —
-        a deadline already past belongs to the step-boundary reap, not
-        the scan. Slots without a deadline (and every slot under an
-        injected logical clock, where a wall-based conversion would
-        diverge lockstep ranks) get an effectively-infinite budget, so
-        the in-scan mask can only ever fire for real wall deadlines."""
-        dl = np.full((self.cfg.num_slots,), 1 << 30, np.int32)
-        if self.clock is not time.monotonic:
-            return dl
-        ema = max(self._devloop.step_ema_s, 1e-6)
-        now = time.monotonic()
-        for i, s in enumerate(self._slots):
-            if s.active and s.request.deadline_at is not None:
-                steps = int((s.request.deadline_at - now) / ema)
-                dl[i] = max(1, min(1 << 30, steps))
-        return dl
-
-    def _push_inflight(self, toks, active, dispatch_s, dl_steps=None):
+    def _push_inflight(self, toks, active, dispatch_s):
         """Append one dispatched chunk to the pipeline — the shared seam
-        for plain decode chunks and mixed interleave steps (both ride
-        the same ring). With async drain engaged, the device→host
-        readback starts NOW on the drainer thread (the dispatch path
-        never blocks on it); a ring already holding ``capacity``
-        undrained chunks processes its oldest first (ring_full_stalls —
-        the drain fell behind dispatch)."""
-        ch = _InflightChunk(toks, active, dispatch_s, dl_steps)
-        dv = self._devloop
-        if dv is not None and dv.async_engaged(self.clock is time.monotonic):
-            if len(self._inflight) >= dv.capacity:
-                self.metrics["ring_full_stalls"] += 1
-                self._process_oldest_chunk()
-            ch.entry = dv.get_drainer().submit(
-                toks, pre_sleep_s=self._fault_sleep_s(),
-                on_drained=self._note_ring_drain,
-            )
-        self._inflight.append(ch)
-
-    def _note_ring_drain(self, host_tokens, drain_s: float) -> None:
-        """Drainer-thread callback: record the drain as ITS OWN flight
-        event so sync time is attributed to the thread that actually
-        blocked on the link, keeping the dispatch/sync split honest
-        under async drain. Runs on the drainer thread — the recorder is
-        lock-protected, and None (a failed readback) records nothing
-        (the engine thread re-raises and recovers)."""
-        if self._flight is not None and host_tokens is not None:
-            self._flight.note_ring_drain(
-                1, int(host_tokens.size), drain_s
-            )
+        for plain decode chunks and mixed interleave steps."""
+        self._inflight.append(_InflightChunk(toks, active, dispatch_s))
 
     def _process_oldest_chunk(self):
         ch = self._inflight.popleft()
-        drained = ch.entry is not None
         t_sync = time.monotonic()
         with phase(phases.CHUNK_SYNC) as sp:
             if sp:
-                sp.set_metadata(chunk=int(ch.toks.shape[0]), drained=drained)
-            # [K, B] — ONE sync per chunk; with a drain entry this only
-            # blocks for whatever the drainer hasn't finished yet.
-            host_tokens = self._sync_chunk_host(ch.toks, ch.entry)
+                sp.set_metadata(chunk=int(ch.toks.shape[0]))
+            # [K, B] — ONE sync per chunk.
+            host_tokens = self._sync_chunk_host(ch.toks)
         sync_s = time.monotonic() - t_sync
         self.metrics["decode_sync_s"] += sync_s
-        if drained:
-            self.metrics["ring_drains"] += 1
-        dv = self._devloop
-        K = int(host_tokens.shape[0])
-        if dv is not None and K > 0:
-            # Realized per-step wall time feeds the deadline→steps EMA.
-            dv.observe_step_time((ch.dispatch_s + sync_s) / K)
         if self._flight is not None:
             self._flight.note_decode_chunk(
-                K, ch.dispatch_s, sync_s, len(ch.active), drained=drained
+                int(host_tokens.shape[0]), ch.dispatch_s, sync_s,
+                len(ch.active),
             )
         with phase(phases.EMIT) as sp:
             if sp:
@@ -638,18 +534,6 @@ class _SchedulerMixin:
                     tokens=m["tokens_generated"] - tok0,
                     finished=m["requests_finished"] - fin0,
                 )
-        if (
-            dv is not None and dv.gate is not None
-            and self.clock is time.monotonic
-        ):
-            # One gate tick per processed chunk (the spec-gate idiom):
-            # realized tok/s with async drain permitted vs suppressed
-            # decides whether the NEXT dispatch hands its readback to
-            # the drainer. Skipped under an injected logical clock
-            # (lockstep), where a wall-clock decision could diverge
-            # the replicated step streams.
-            dv.gate.tick(time.monotonic(), self.metrics["tokens_generated"])
-            self.metrics["decode_ring_gate_state"] = dv.gate.state_code()
 
     def _emit_chunk(self, ch, host_tokens) -> None:
         """The per-step, per-slot emission of one synced chunk [K, B]."""
@@ -664,25 +548,12 @@ class _SchedulerMixin:
                     # flight, in which case these tokens belong to the old
                     # request, never the slot's new occupant.
                     continue
-                if ch.dl_steps is not None and k >= int(ch.dl_steps[i]):
-                    # The scan masked this slot at exactly this step
-                    # (deadline-step budget): finish with the partial
-                    # output — streamed tokens == num_generated, and
-                    # the frozen device rows past here are garbage.
-                    self.metrics["deadline_exceeded"] += 1
-                    self._finish_slot(i, FinishReason.DEADLINE)
-                    continue
                 stepped = True
                 slot.length += 1
                 self._emit_token(i, int(host_tokens[k, i]))
             if not stepped:
                 # Every snapshot slot is finished: the remaining steps'
-                # tokens are frozen garbage for all of them — and with
-                # the ring scan (dl_steps rides exactly the ring decode
-                # chunks, never mixed steps), the device skipped those
-                # forwards too (the lax.cond early-out).
-                if ch.dl_steps is not None:
-                    self.metrics["early_exit_steps"] += K - k
+                # tokens are frozen garbage for all of them.
                 break
 
     def _flush_pipeline(self):

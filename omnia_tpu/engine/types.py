@@ -404,23 +404,6 @@ class EngineConfig:
     # exists, no span is ever opened, every seam is one `is not None`
     # check (tests/test_flight.py).
     flight_events: int = 0
-    # Device-resident decode loop (engine/devloop.py): >= 2 turns the
-    # decode dispatch path fully asynchronous — each dispatched chunk's
-    # token buffer is handed to ONE long-lived drainer thread that
-    # starts the device→host readback immediately, the pipeline holds
-    # up to this many undrained chunks (the token ring), and the chunk
-    # scan gains an all-slots-done early-out plus in-scan grammar-EOS
-    # and deadline-step masking. An online A/B gate (the spec-decode
-    # self-gate idiom) probes async-drain vs inline-sync tok/s and
-    # disables the ring per engine if it does not pay — never a silent
-    # regression. 0 (default) is a guarded true no-op: no drainer
-    # thread, no gate, no extra device operands — the decode programs
-    # lower byte-identical to the pre-ring engine
-    # (tests/test_devloop.py::test_decode_ring_off_is_true_noop).
-    # 1 is rejected (a one-deep ring cannot overlap drain with
-    # dispatch). Ring values > 0 change the traced decode programs, so
-    # they participate in the warmup manifest key.
-    decode_ring: int = 0
 
     def spec_window(self) -> int:
         """Speculative verify window W — the most proposals any slot

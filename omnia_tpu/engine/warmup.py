@@ -167,19 +167,7 @@ class _WarmupMixin:
                     self._stop_ids, self._key_data, self._temp,
                     self._top_p, self._top_k,
                 )
-                ring_args = ()
-                if cfg.decode_ring > 0:
-                    # Ring decode family (engine/devloop.py): per-slot
-                    # grammar EOS rides between gactive and the
-                    # deadline-step budget; the request path always
-                    # passes both, so warmup must too. np.int32 matches
-                    # _deadline_steps' dispatch-time operand dtype.
-                    if self._gr_on:
-                        ring_args = (self._geos,)
-                    ring_args = ring_args + (
-                        jnp.full((cfg.num_slots,), 1 << 30, jnp.int32),
-                    )
-                out = fn(*args, *gargs(), *ring_args)
+                out = fn(*args, *gargs())
                 st.ck, st.cv = out[0], out[1]
             return run
 
@@ -459,9 +447,7 @@ class _WarmupMixin:
         shape, the bucket sets, and the KV knobs. Host-side-only knobs
         (thread counts, event-ring capacities, admission bounds) are
         excluded — they change no traced program, so a restart that
-        only tunes them still reads the same manifest. decode_ring is
-        deliberately NOT excluded: the token ring swaps the whole
-        decode family for the ring-operand edition."""
+        only tunes them still reads the same manifest."""
         ecfg = dataclasses.asdict(self.cfg)
         for host_only in (
             "warmup_threads", "flight_events", "max_queue", "watchdog_s",

@@ -114,7 +114,6 @@ class Aggregator:
     def __init__(self) -> None:
         self._cells: dict[tuple, CellStats] = {}
         self._seen: set[str] = set()
-        self._devloop: list[dict] = []
 
     def add(self, r: WorkResult) -> bool:
         """Fold one result; returns False for a duplicate work_id (the
@@ -170,28 +169,6 @@ class Aggregator:
             folded += 1
         return folded
 
-    def add_devloop(self, devloop: dict, provider: str = "bench") -> bool:
-        """Fold a bench ``aux.devloop`` A/B block (ring-on vs ring-off
-        decode, engine/devloop.py) so one ArenaJob verdict can gate the
-        serving-perf evidence beside the check/SLO planes. Keeps only
-        the verdict surface: the tok/s ratio, whether the ring's
-        self-gate disabled it (a reported disable is NOT a silent
-        regression), and bench's own paying/regression flags. Returns
-        False for blocks with no ratio (an errored bench phase folds
-        nothing)."""
-        if not isinstance(devloop, dict) or "ratio_on_vs_off" not in devloop:
-            return False
-        self._devloop.append({
-            "provider": provider,
-            "ratio_on_vs_off": float(devloop["ratio_on_vs_off"]),
-            "gate_disabled": bool(
-                (devloop.get("gate") or {}).get("state") == "off"
-            ),
-            "paying": bool(devloop.get("paying")),
-            "regression": bool(devloop.get("regression")),
-        })
-        return True
-
     def cells(self) -> list[CellStats]:
         return [self._cells[k] for k in sorted(self._cells)]
 
@@ -246,25 +223,8 @@ class Aggregator:
                         f"{cell.scenario}/{cell.provider}: inter-token p95 "
                         f"{i95:.1f}ms > {threshold.max_p95_itl_ms:.1f}ms"
                     )
-        # Decode-ring bench gate: engages only on folded aux.devloop
-        # blocks. The no-silent-regression contract — the ring clears
-        # the ratio floor OR its self-gate disabled it and said so.
-        if threshold.min_devloop_ratio is not None:
-            for blk in self._devloop:
-                if blk["gate_disabled"]:
-                    continue
-                if blk["ratio_on_vs_off"] < threshold.min_devloop_ratio:
-                    failures.append(
-                        f"devloop/{blk['provider']}: ring-on/off tok/s "
-                        f"ratio {blk['ratio_on_vs_off']:.3f} < "
-                        f"{threshold.min_devloop_ratio:.3f} and the "
-                        "self-gate did not disable"
-                    )
-        verdict = {
+        return {
             "passed": not failures,
             "failures": failures,
             "cells": [c.to_dict() for c in self.cells()],
         }
-        if self._devloop:
-            verdict["devloop"] = list(self._devloop)
-        return verdict

@@ -95,11 +95,6 @@ class Threshold:
     min_slo_attainment: Optional[float] = None
     max_p95_ttft_ms: Optional[float] = None
     max_p95_itl_ms: Optional[float] = None
-    # Decode-ring bench gate (bench aux.devloop → Aggregator
-    # add_devloop): ring-on/ring-off tok/s ratio floor; a block whose
-    # self-gate disabled the ring (and reported its measured rates)
-    # clears the gate — the bound catches only SILENT regressions.
-    min_devloop_ratio: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -127,7 +122,6 @@ class ArenaJobSpec:
                 min_slo_attainment=th.get("min_slo_attainment"),
                 max_p95_ttft_ms=th.get("max_p95_ttft_ms"),
                 max_p95_itl_ms=th.get("max_p95_itl_ms"),
-                min_devloop_ratio=th.get("min_devloop_ratio"),
             ),
         )
 

@@ -283,9 +283,6 @@ def _arena_job_schema() -> dict:
             "min_slo_attainment": _NUM,
             "max_p95_ttft_ms": _NUM,
             "max_p95_itl_ms": _NUM,
-            # Decode-ring bench gate (bench aux.devloop → Aggregator
-            # add_devloop): tok/s ratio floor on non-self-disabled runs.
-            "min_devloop_ratio": _NUM,
         }),
     }, required=["providers"])
 

@@ -1,16 +1,16 @@
-"""Device-resident decode loop suite (ISSUE 17): the chunk drainer,
-the ring self-gate, the deadline-step conversion, the mock's ring
-mirror, and the ring-on-vs-off equivalence battery.
+"""Decode-pipeline suite: the watchdog's chunk drainer, the schedule's
+one rule as a table, the chunk-size arithmetic, and the pipeline-depth
+equivalence battery (``decode_pipeline`` 2 against 1).
 
-Module top is jax-free by design: the validate/drainer/gate/state
-units and the MockEngine ring-mirror battery all run under the CI
-analysis job's poisoned jax stub (``pytest -m devloop --noconftest``);
-the engine-backed equivalence battery importorskips jax.
+Module top is jax-free by design: the drainer/state units run under the
+CI analysis job's poisoned jax stub (``pytest -m devloop
+--noconftest``); the scheduler tables and the engine-backed battery
+importorskip jax.
 """
 
 from __future__ import annotations
 
-import threading
+import collections
 import time
 from types import SimpleNamespace
 
@@ -21,41 +21,11 @@ try:  # the CI analysis job runs the jax-free subset on a bare venv
 except ImportError:  # pragma: no cover - CI analysis job only
     np = None
 
-from omnia_tpu.engine.devloop import (
-    ChunkDrainer,
-    DevLoopState,
-    RingGate,
-    _InflightChunk,
-    validate_decode_ring,
-)
-from omnia_tpu.engine.mock import MockEngine, Scenario
+from omnia_tpu.engine.devloop import ChunkDrainer, DevLoopState, _InflightChunk
+from omnia_tpu.engine.mock import MockEngine
 from omnia_tpu.engine.types import FinishReason, SamplingParams
 
 pytestmark = pytest.mark.devloop
-
-
-# ---------------------------------------------------------------------------
-# validate_decode_ring (jax-free)
-# ---------------------------------------------------------------------------
-
-
-class TestValidate:
-    @pytest.mark.parametrize("ring", [0, 2, 3, 8])
-    def test_servable_values_pass(self, ring):
-        validate_decode_ring(SimpleNamespace(decode_ring=ring))
-
-    def test_negative_rejected(self):
-        with pytest.raises(ValueError, match="must be >= 0"):
-            validate_decode_ring(SimpleNamespace(decode_ring=-1))
-
-    def test_one_deep_ring_rejected(self):
-        """ring=1 can never overlap a drain with the next dispatch —
-        a misconfiguration, not a degraded mode."""
-        with pytest.raises(ValueError, match="one-deep ring"):
-            validate_decode_ring(SimpleNamespace(decode_ring=1))
-
-    def test_knobless_config_is_off(self):
-        validate_decode_ring(SimpleNamespace())  # duck-typed: absent = 0
 
 
 # ---------------------------------------------------------------------------
@@ -75,7 +45,7 @@ class TestChunkDrainer:
     @pytest.fixture(autouse=True)
     def _needs_numpy(self):
         # The drain IS the numpy readback; on the bare CI venv these
-        # skip while the gate/state/mock units still run.
+        # skip while the state units still run.
         pytest.importorskip("numpy")
 
     def test_drain_returns_host_array_fifo(self):
@@ -86,8 +56,6 @@ class TestChunkDrainer:
             for i, out in enumerate(outs):
                 assert isinstance(out, np.ndarray)
                 assert out.tolist() == [i, i + 1]
-            drains, drain_s = d.stats()
-            assert drains == 3 and drain_s >= 0.0
             assert not d.poisoned
         finally:
             d.stop()
@@ -115,95 +83,35 @@ class TestChunkDrainer:
         d.stop()
         assert time.monotonic() - t0 < 0.4
 
-    def test_on_drained_runs_on_drainer_thread(self):
+    def test_waits_may_come_in_any_order(self):
+        """The watchdog waits for the chunk it is processing, whatever
+        else was handed over since: entries are boxes, not a stream."""
         d = ChunkDrainer()
-        seen = {}
-        fired = threading.Event()
-
-        def cb(arr, took):
-            seen["arr"] = arr
-            seen["took"] = took
-            seen["thread"] = threading.current_thread().name
-            fired.set()
-
         try:
-            d.wait(d.submit([1, 2], on_drained=cb), timeout=5)
-            assert fired.wait(5)
-            assert seen["arr"].tolist() == [1, 2]
-            assert seen["took"] >= 0.0
-            assert seen["thread"] == "omnia-chunk-drainer"
+            first, second = d.submit([1]), d.submit([2, 3])
+            assert d.wait(second, timeout=5).tolist() == [2, 3]
+            assert d.wait(first, timeout=5).tolist() == [1]
         finally:
             d.stop()
 
-    def test_callback_exception_does_not_kill_drainer(self):
-        d = ChunkDrainer()
-        try:
-            d.wait(d.submit([1], on_drained=lambda a, t: 1 / 0), timeout=5)
-            assert d.wait(d.submit([2]), timeout=5).tolist() == [2]
-        finally:
-            d.stop()
+    def test_stop_joins_an_idle_thread_and_is_final(self):
+        d = ChunkDrainer(name="omnia-chunk-drainer-test")
+        assert d._thread.name == "omnia-chunk-drainer-test" and d._thread.daemon
+        d.stop()
+        assert not d._thread.is_alive()
+        # Nothing reads an entry handed over after the stop.
+        late = d.submit([1])
+        assert d.wait(late, timeout=0.05) is None
 
     def test_fault_pre_sleep_is_timed(self):
         """Injected hang rides the drain wall (watchdog/chaos parity)."""
         d = ChunkDrainer()
         try:
+            t0 = time.monotonic()
             d.wait(d.submit([1], pre_sleep_s=0.05), timeout=5)
-            _, drain_s = d.stats()
-            assert drain_s >= 0.05
+            assert time.monotonic() - t0 >= 0.05
         finally:
             d.stop()
-
-
-# ---------------------------------------------------------------------------
-# RingGate (jax-free) — the spec-decode _SpecGate state machine
-# ---------------------------------------------------------------------------
-
-
-class TestRingGate:
-    def test_probe_cycle_keeps_faster_async(self):
-        g = RingGate(window=2, hold_factor=2)
-        assert g.state == RingGate.PROBE_ASYNC and g.allows_async()
-        # Async probe: 100 tok/s realized.
-        g.tick(0.0, 0)
-        g.tick(1.0, 100)
-        assert g.state == RingGate.PROBE_SYNC and not g.allows_async()
-        # Sync probe: 10 tok/s — async wins, hold on.
-        g.tick(2.0, 110)
-        g.tick(3.0, 120)
-        assert g.state == RingGate.HOLD_ON and g.allows_async()
-        assert g.state_code() == 1
-        assert g.decisions == 1 and g.disables == 0
-        rep = g.report()
-        assert rep["state"] == "on"
-        assert rep["rate_async_tok_s"] == 100.0
-        assert rep["rate_sync_tok_s"] == 10.0
-
-    def test_slower_async_is_disabled(self):
-        g = RingGate(window=2, hold_factor=2)
-        g.tick(0.0, 0)
-        g.tick(1.0, 10)     # async: 10 tok/s
-        g.tick(2.0, 60)
-        g.tick(3.0, 160)    # sync: 100 tok/s — ring does not pay
-        assert g.state == RingGate.HOLD_OFF and not g.allows_async()
-        assert g.state_code() == 2
-        assert g.disables == 1
-        assert g.report()["state"] == "off"
-
-    def test_hold_expiry_reprobes(self):
-        g = RingGate(window=1, hold_factor=2)
-        g.tick(0.0, 0)      # async probe ends (rate 0 over zero time)
-        g.tick(1.0, 0)      # sync probe: rate 0 — tie keeps async on
-        assert g.state == RingGate.HOLD_ON
-        g.tick(2.0, 50)
-        g.tick(3.0, 100)    # hold (window*factor=2 ticks) expires
-        assert g.state == RingGate.PROBE_ASYNC
-        assert g.rate_async == 50.0  # hold refreshed the async rate
-
-    def test_window_zero_always_allows(self):
-        g = RingGate(window=0)
-        for i in range(10):
-            assert g.tick(float(i), i * 5)
-        assert g.state_code() == 0
 
 
 # ---------------------------------------------------------------------------
@@ -213,155 +121,205 @@ class TestRingGate:
 
 class TestDevLoopState:
     def test_ring_off_builds_nothing(self):
-        st = DevLoopState(0)
-        assert st.capacity == 0 and st.gate is None
-        assert not st.async_engaged(wall_clock=True)
-        assert not st.async_engaged(wall_clock=False)
-        assert st.drainer_if_live() is None
+        st = DevLoopState()
+        assert st._drainer is None  # no thread until a chunk is read
         st.stop()  # no drainer ever built — a no-op
-
-    def test_ring_on_capacity_and_gate(self):
-        st = DevLoopState(3)
-        assert st.capacity == 3 and isinstance(st.gate, RingGate)
-        assert st.async_engaged(wall_clock=True)
-        # Lockstep engines (injected logical clock) keep async drain
-        # unconditionally — the gate's wall-clock decision never binds.
-        st.gate.state = RingGate.HOLD_OFF
-        assert not st.async_engaged(wall_clock=True)
-        assert st.async_engaged(wall_clock=False)
-        st.stop()
-
-    def test_gateless_ring(self):
-        st = DevLoopState(2, gate=False)
-        assert st.gate is None and st.async_engaged(wall_clock=True)
-        st.stop()
+        assert st._drainer is None
 
     def test_drainer_lazy_and_poison_replacement(self):
-        st = DevLoopState(2)
-        assert st.drainer_if_live() is None  # lazy: nothing until first use
+        st = DevLoopState()
         d1 = st.get_drainer()
         assert st.get_drainer() is d1
         d1.poisoned = True
-        assert st.drainer_if_live() is None
         d2 = st.get_drainer()  # recovery lane: fresh thread
         assert d2 is not d1 and not d2.poisoned
         st.stop()
         assert st._drainer is None
 
-    def test_step_ema(self):
-        st = DevLoopState(2)
-        before = st.step_ema_s
-        for _ in range(50):
-            st.observe_step_time(1.0)
-        assert abs(st.step_ema_s - 1.0) < 1e-3 and st.step_ema_s != before
-        st.stop()
-
     def test_inflight_chunk_fields(self):
         ch = _InflightChunk("toks", [(0, "r0")], 0.25)
-        assert ch.dl_steps is None and ch.entry is None
         assert ch.toks == "toks" and ch.dispatch_s == 0.25
+        assert ch.active == [(0, "r0")]
         assert not hasattr(ch, "__dict__")  # __slots__: pipeline entry
+        assert _InflightChunk.__slots__ == ("toks", "active", "dispatch_s")
 
 
 # ---------------------------------------------------------------------------
-# MockEngine ring mirror (jax-free)
+# The schedule's one rule, engine-free: _SchedulerMixin._schedule on a stub
+# that records what it was asked to do (needs jax only to import the mixin)
 # ---------------------------------------------------------------------------
 
 
-REPLY = "devloop-reply!"  # 14 tokens under the byte tokenizer
+def _stub_scheduler(waiting, inflight, useful, active=True, pipeline=2):
+    """``waiting`` is the queue as a list of booleans (has this request a
+    slot to go to); ``inflight`` the chunks already dispatched."""
+    pytest.importorskip("jax")
+    from omnia_tpu.engine.scheduler import _SchedulerMixin
+
+    class Stub(_SchedulerMixin):
+        def __init__(self):
+            self.cfg = SimpleNamespace(decode_pipeline=pipeline)
+            self._waiting = list(waiting)
+            self._inflight = collections.deque(f"c{i}" for i in range(inflight))
+            self._slots = [SimpleNamespace(active=active)]
+            self.calls = []
+
+        def _mixed_enabled(self):
+            return False
+
+        def _spec_step(self):
+            return False
+
+        def _queued_placeable(self):
+            return bool(self._waiting), any(self._waiting)
+
+        def _dispatch_ahead_useful(self):
+            return useful
+
+        def _flush_for_waiting(self):
+            self.calls.append(("flush", len(self._inflight)))
+            self._inflight.clear()
+
+        def _claim_pending(self):
+            self.calls.append(("claim",))
+            if True not in self._waiting:
+                return None, None
+            self._waiting.remove(True)
+            return ("request", "handle"), 0
+
+        def _place_pending(self, slot_idx, request, handle):
+            self.calls.append(("place", slot_idx))
+
+        def _dispatch_decode(self, single=False, blocked=False):
+            self.calls.append((
+                "dispatch", "single" if single else "chunk",
+                "blocked" if blocked else "free", len(self._inflight),
+            ))
+            self._inflight.append("new")
+
+        def _process_oldest_chunk(self):
+            self.calls.append(("process", self._inflight.popleft()))
+
+    return Stub()
 
 
-class TestMockRingMirror:
-    def test_mock_rejects_one_deep_ring(self):
-        with pytest.raises(ValueError, match="one-deep ring"):
-            MockEngine(decode_ring=1)
+_NOBODY, _PLACEABLE, _BLOCKED = (), (True, True), (False,)
+# A full chunk on the futures of what is in flight, read `decode_pipeline`
+# dispatches later; one step read at once; one step ahead of the one read.
+_CHUNK = ("dispatch", "chunk", "free")
+_STEP_SYNC = ("dispatch", "single", "free")
+_STEP_AHEAD = ("dispatch", "single", "blocked")
+_PLACED = [("claim",), ("place", 0)]
 
-    def test_mock_ring_ledger(self):
-        m = MockEngine([Scenario(".", REPLY)], decode_ring=4)
-        toks, fin = m.generate(m.tokenizer.encode("hi"))
-        assert m.tokenizer.decode(toks) == REPLY
-        assert fin.finish_reason is FinishReason.STOP
-        assert m.metrics["decode_ring_enabled"] == 1
-        # ceil(14 / 4) chunk-strides drained, gate engaged, no stalls.
-        assert m.metrics["ring_drains"] == 4
-        assert m.metrics["decode_ring_gate_state"] == 1
-        assert m.metrics["ring_full_stalls"] == 0
-        assert m.metrics["early_exit_steps"] == 0
 
-    def test_mock_decode_ring_off_is_true_noop(self):
-        """KNOB_GUARDS target (MockEngine.decode_ring): the default books
-        zero ring state and playback is byte-identical to a ring mock."""
-        off = MockEngine([Scenario(".", REPLY)])
-        on = MockEngine([Scenario(".", REPLY)], decode_ring=2)
-        prompt = off.tokenizer.encode("hi")
-        t_off, _ = off.generate(prompt)
-        t_on, _ = on.generate(prompt)
-        assert t_off == t_on
-        assert off.decode_ring == 0
-        for key in ("decode_ring_enabled", "ring_drains",
-                    "ring_full_stalls", "early_exit_steps",
-                    "decode_ring_gate_state"):
-            assert off.metrics[key] == 0, (key, off.metrics[key])
+@pytest.mark.parametrize("waiting,inflight,useful,calls,left", [
+    # Nobody waits: full chunks, `decode_pipeline` deep; a chunk nobody's
+    # budget can still need is not dispatched, the oldest is read instead.
+    (_NOBODY, 0, True, [_CHUNK + (0,)], 1),
+    (_NOBODY, 0, False, [_CHUNK + (0,)], 1),
+    (_NOBODY, 1, True, [_CHUNK + (1,), ("process", "c0")], 1),
+    (_NOBODY, 1, False, [("process", "c0")], 0),
+    (_NOBODY, 2, True, [_CHUNK + (2,), ("process", "c0"), ("process", "c1")], 1),
+    (_NOBODY, 2, False, [("process", "c0")], 1),
+    # A waiting request has a slot to go to: flush whatever flies, claim,
+    # place, and (another still waits for its slot's placement next step)
+    # one step, read back before anything else is enqueued.
+    (_PLACEABLE, 0, True, _PLACED + [_STEP_SYNC + (0,), ("process", "new")], 0),
+    (_PLACEABLE, 0, False, _PLACED + [_STEP_SYNC + (0,), ("process", "new")], 0),
+    (_PLACEABLE, 1, True,
+     [("flush", 1)] + _PLACED + [_STEP_SYNC + (0,), ("process", "new")], 0),
+    (_PLACEABLE, 1, False,
+     [("flush", 1)] + _PLACED + [_STEP_SYNC + (0,), ("process", "new")], 0),
+    (_PLACEABLE, 2, True,
+     [("flush", 2)] + _PLACED + [_STEP_SYNC + (0,), ("process", "new")], 0),
+    (_PLACEABLE, 2, False,
+     [("flush", 2)] + _PLACED + [_STEP_SYNC + (0,), ("process", "new")], 0),
+    # Requests wait and none has a slot: nothing is flushed or claimed; one
+    # step goes out ahead of the one in flight and the OLDEST is read.
+    (_BLOCKED, 0, True, [_STEP_AHEAD + (0,)], 1),
+    (_BLOCKED, 0, False, [_STEP_AHEAD + (0,)], 1),
+    (_BLOCKED, 1, True, [_STEP_AHEAD + (1,), ("process", "c0")], 1),
+    (_BLOCKED, 1, False, [("process", "c0")], 0),
+    (_BLOCKED, 2, True,
+     [_STEP_AHEAD + (2,), ("process", "c0"), ("process", "c1")], 1),
+    (_BLOCKED, 2, False, [("process", "c0")], 1),
+])
+def test_schedule_rule_table(waiting, inflight, useful, calls, left):
+    stub = _stub_scheduler(waiting, inflight, useful)
+    assert stub._schedule() is True
+    assert stub.calls == calls
+    assert len(stub._inflight) == left
+
+
+@pytest.mark.parametrize("waiting,inflight,calls,did", [
+    # No slot is live: what flies is read, one chunk a step; nothing to
+    # read and nobody to place is an idle poll.
+    (_NOBODY, 1, [("process", "c0")], True),
+    (_NOBODY, 0, [], False),
+    # The last placeable request of the queue: after its placement nobody
+    # waits, so decode goes back to full chunks in the same step.
+    ((True,), 1, [("flush", 1)] + _PLACED, True),
+])
+def test_schedule_rule_with_no_live_slot(waiting, inflight, calls, did):
+    stub = _stub_scheduler(waiting, inflight, useful=True, active=False)
+    assert stub._schedule() is did
+    assert stub.calls == calls
+
+
+def test_schedule_rule_depth_one_reads_every_dispatch_back():
+    """decode_pipeline=1: blocked or not, a dispatch is read at once."""
+    for waiting in (_NOBODY, _BLOCKED):
+        stub = _stub_scheduler(waiting, 0, useful=True, pipeline=1)
+        stub._schedule()
+        assert [c[0] for c in stub.calls] == ["dispatch", "process"]
+        assert not stub._inflight
+
+
+def _stub_budget(slots, inflight=(), max_seq=64, variants=(1, 4, 8)):
+    """``slots``: (max_total, generated, length) or None for an idle slot;
+    ``inflight``: (steps, slot indices) of each chunk already dispatched."""
+    pytest.importorskip("jax")
+    from omnia_tpu.engine.scheduler import _SchedulerMixin
+
+    stub = _SchedulerMixin()
+    stub.cfg = SimpleNamespace(max_seq=max_seq)
+    stub._decode_fns = dict.fromkeys(variants)
+    stub._slots = [
+        SimpleNamespace(active=False) if s is None else SimpleNamespace(
+            active=True, max_total=s[0], generated=s[1], length=s[2])
+        for s in slots
+    ]
+    stub._inflight = collections.deque(
+        _InflightChunk(SimpleNamespace(shape=(k, len(slots))),
+                       [(i, f"r{i}") for i in idx], 0.0)
+        for k, idx in inflight
+    )
+    return stub
+
+
+@pytest.mark.parametrize("slots,inflight,remaining,chunk", [
+    # The budget ends inside the chunk in flight: nothing more to dispatch.
+    ([(12, 7, 10)], [(8, [0])], 0, 1),
+    # The cache's end comes before the budget's: max_seq - 2 - length.
+    ([(100, 1, 59)], [], 3, 4),
+    # One slot live among idle ones, far from its end: the full chunk.
+    ([None, (40, 20, 23), None], [], 20, 8),
+    # None live.
+    ([None, None], [], 0, 1),
+    # Two live; what flies covers one of them and not the other.
+    ([(9, 1, 4), (30, 1, 4)], [(8, [0, 1])], 21, 8),
+    # Chunks in flight add up per slot: 8 + 1 of 10 left one step.
+    ([(11, 1, 4)], [(8, [0]), (1, [0])], 1, 1),
+])
+def test_chunk_size_follows_the_work_left(slots, inflight, remaining, chunk):
+    stub = _stub_budget(slots, inflight)
+    assert stub._remaining_work() == remaining
+    assert stub._dispatch_ahead_useful() is (remaining > 0)
+    assert stub._pick_chunk() == chunk
 
 
 # ---------------------------------------------------------------------------
-# Aggregator devloop gate (jax-free) — bench aux.devloop → ArenaJob verdict
-# ---------------------------------------------------------------------------
-
-
-class TestAggregatorDevloopGate:
-    def _agg(self):
-        from omnia_tpu.evals.aggregator import Aggregator
-
-        return Aggregator()
-
-    def test_silent_regression_fails_the_bound(self):
-        from omnia_tpu.evals.defs import Threshold
-
-        agg = self._agg()
-        assert not agg.add_devloop({"error": "boom"})  # errored phase folds nothing
-        assert agg.add_devloop({
-            "ratio_on_vs_off": 0.9, "gate": {"state": "on"},
-            "paying": False, "regression": True,
-        })
-        verdict = agg.evaluate(Threshold(min_devloop_ratio=0.95))
-        assert not verdict["passed"]
-        assert "devloop/bench" in verdict["failures"][0]
-        assert "0.900" in verdict["failures"][0]
-        assert verdict["devloop"][0]["regression"] is True
-
-    def test_reported_gate_disable_clears_the_bound(self):
-        from omnia_tpu.evals.defs import Threshold
-
-        agg = self._agg()
-        assert agg.add_devloop({
-            "ratio_on_vs_off": 0.7, "gate": {"state": "off"},
-            "paying": True, "regression": False,
-        })
-        verdict = agg.evaluate(Threshold(min_devloop_ratio=0.95))
-        assert verdict["passed"] and verdict["devloop"][0]["gate_disabled"]
-
-    def test_unset_bound_and_unfolded_jobs_never_engage(self):
-        from omnia_tpu.evals.defs import Threshold
-
-        agg = self._agg()
-        agg.add_devloop({"ratio_on_vs_off": 0.5, "gate": None})
-        assert agg.evaluate(Threshold())["passed"]  # no bound set
-        clean = self._agg().evaluate(Threshold(min_devloop_ratio=0.95))
-        assert clean["passed"] and "devloop" not in clean  # nothing folded
-
-    def test_threshold_schema_row(self):
-        from omnia_tpu.evals.defs import ArenaJobSpec
-
-        spec = ArenaJobSpec.from_dict({
-            "name": "perf", "providers": ["p"],
-            "threshold": {"min_devloop_ratio": 0.97},
-        })
-        assert spec.threshold.min_devloop_ratio == 0.97
-
-
-# ---------------------------------------------------------------------------
-# Engine-backed equivalence battery (skips without jax)
+# Engine-backed battery: decode_pipeline 2 against 1 (skips without jax)
 # ---------------------------------------------------------------------------
 
 
@@ -392,95 +350,33 @@ def _drive(eng, *handles, timeout=60):
     return out
 
 
-def test_decode_ring_off_is_true_noop():
-    """KNOB_GUARDS target (EngineConfig.decode_ring): decode_ring=0
-    allocates ZERO ring state — no devloop container, no drainer
-    thread, no per-slot grammar-EOS array — and the compiled decode
-    program carries the exact pre-ring operands (the 12-argument
-    signature lowers; byte-identical whether or not the host-side
-    watchdog, which shares the drainer implementation, is on)."""
-    off = _engine()
-    wd = _engine(watchdog_s=30.0)
-    assert off._devloop is None and off._geos is None
-    assert off.cfg.decode_ring == 0
-
-    def lowered(eng):
-        return eng._decode_fn_single.lower(
-            eng.params, eng._ck, eng._cv, eng._tokens, eng._positions,
-            eng._active, eng._budget, eng._stop_ids, eng._key_data,
-            eng._temp, eng._top_p, eng._top_k,
-        ).as_text()
-
-    # The watchdog engine owns devloop state (its drainer) but traces
-    # the identical ring-free program.
-    assert wd._devloop is not None and wd._devloop.ring == 0
-    assert lowered(off) == lowered(wd)
-
-    toks, fin = off.generate([1, 2, 3], GREEDY)
-    assert toks and fin.finish_reason is not None
-    for key in ("ring_drains", "ring_full_stalls", "early_exit_steps",
-                "decode_ring_gate_state", "decode_ring_enabled"):
-        assert off.metrics[key] == 0, (key, off.metrics[key])
-    wd.stop()
-
-
-def test_ring_one_rejected_at_construction():
-    with pytest.raises(ValueError, match="one-deep ring"):
-        _engine(decode_ring=1)
-
-
-def test_ring_greedy_equivalence_and_resident_kv():
-    """Ring on vs off: bit-identical greedy streams AND bit-identical
-    valid resident KV rows for a sessionful turn (the ring early-out
-    may skip frozen-slot garbage writes, so only rows below the
-    session's valid frontier are comparable — exactly the rows any
-    later turn can read)."""
-    prompt = [1, 2, 3, 4]
-    results = []
-    for ring in (0, 2):
-        eng = _engine(decode_ring=ring, max_sessions=4)
-        h = eng.submit(prompt, GREEDY, session_id="s")
-        (res,) = _drive(eng, h)
-        rows = len(eng._sessions["s"].token_ids)
-        assert rows > 0
-        ck = np.asarray(eng._ck)[:, 0, :rows]
-        cv = np.asarray(eng._cv)[:, 0, :rows]
-        results.append((res, rows, ck, cv))
-        if ring:
-            assert eng.metrics["decode_ring_enabled"] == 1
-            assert eng.metrics["ring_drains"] > 0
-            eng.stop()
-    (t0, r0, ck0, cv0), (t1, r1, ck1, cv1) = results
-    assert t0 == t1 and r0 == r1
-    np.testing.assert_array_equal(ck0, ck1)
-    np.testing.assert_array_equal(cv0, cv1)
-
-
 @pytest.mark.parametrize("extra", [
     pytest.param({"kv_quant": "int8"}, id="int8-kv"),
     pytest.param({"kv_pages": 9, "kv_page_tokens": 8}, id="paged"),
     pytest.param({"spec_decode": 2}, id="spec"),
     pytest.param({"prefill_chunk_tokens": 4}, id="interleave"),
 ])
-def test_ring_equivalence_with_cotenant(extra):
-    """Ring on vs off under each major engine feature, with TWO live
-    requests so chunks carry multi-slot snapshots (spec-decode and
-    mixed interleave steps must ride the same ring unchanged)."""
+def test_pipeline_depth_equivalence_with_cotenant(extra):
+    """Two chunks in flight against one under each major engine feature,
+    with TWO live requests so chunks carry multi-slot snapshots (verify
+    steps and mixed interleave steps ride the same pipeline)."""
     pa, pb = [1, 2, 3], [9, 8, 7, 6]
     streams = []
-    for ring in (0, 2):
-        eng = _engine(decode_ring=ring, **extra)
+    for pipeline in (1, 2):
+        eng = _engine(decode_pipeline=pipeline, **extra)
         ha = eng.submit(pa, GREEDY)
         hb = eng.submit(pb, GREEDY)
         streams.append([t for t, _ in _drive(eng, ha, hb)])
         eng.stop()
     assert streams[0] == streams[1]
+    assert all(len(t) == GREEDY.max_tokens for t in streams[0])
 
 
-def test_ring_grammar_equivalence_and_inscan_eos():
-    """Grammar-constrained ring decode: identical constrained streams,
-    and the ring engine carries the per-slot grammar-EOS ids so the
-    scan can freeze a completed grammar slot in-scan."""
+def test_pipeline_depth_equivalence_grammar_beside_free_slot():
+    """A grammar-constrained slot and an unconstrained one share every
+    chunk: identical streams at either depth, the constrained one
+    admissible token by token, the free one what it is without a
+    grammar engine's mask."""
     pytest.importorskip("jax")
     from omnia_tpu.engine.grammar import compile_json_schema
     from omnia_tpu.engine.tokenizer import ByteTokenizer
@@ -492,55 +388,55 @@ def test_ring_grammar_equivalence_and_inscan_eos():
     g = compile_json_schema(schema, ByteTokenizer())
     sp = SamplingParams(temperature=0.0, max_tokens=40, stop_token_ids=(0,))
     streams = []
-    for ring in (0, 2):
-        eng = _engine(decode_ring=ring, num_slots=4, max_seq=128,
+    for pipeline in (1, 2):
+        eng = _engine(decode_pipeline=pipeline, num_slots=4, max_seq=128,
                       prefill_buckets=(8, 16, 32), grammar=True,
                       grammar_max_states=512)
-        if ring:
-            assert eng._geos is not None
-        else:
-            assert eng._geos is None
-        h = eng.submit(list(b"make json"), sp, grammar=g)
-        streams.append(_drive(eng, h)[0][0])
+        hg = eng.submit(list(b"make json"), sp, grammar=g)
+        hf = eng.submit([5, 6, 7], GREEDY)
+        streams.append([t for t, _ in _drive(eng, hg, hf)])
         eng.stop()
     assert streams[0] == streams[1]
+    constrained, free = streams[0]
     v = g.view(get_config("test-tiny").vocab_size, (0,))
     s = v.start
-    for t in streams[0]:
+    for t in constrained:
         assert v.allowed(s)[t]
         s = v.advance(s, t)
+    assert len(free) == GREEDY.max_tokens
 
 
-def test_mid_scan_deadline_exact_partial_counts():
-    """The in-scan deadline-step budget: a slot whose wall budget
-    converts to 1 step emits exactly one in-chunk token and finishes
-    DEADLINE at the same step the device masked it — streamed tokens
-    == num_generated, and the chunk's remaining steps are booked as
-    early-exit savings."""
-    eng = _engine(decode_ring=2)
-    # Force the deadline→steps conversion to 1 step without the
-    # boundary reap ever firing: a far-future wall deadline against a
-    # huge per-step EMA.
-    eng._devloop.step_ema_s = 1e4
-    h = eng.submit([1, 2, 3], SamplingParams(temperature=0.0, max_tokens=32),
-                   deadline_s=60.0)
+@pytest.mark.parametrize("pipeline", [1, 2])
+def test_deadline_inside_a_chunk_ends_at_the_next_boundary(pipeline):
+    """A wall deadline that passes while the device is inside a chunk is
+    found at the next step boundary (lifecycle._reap_deadlines): DEADLINE
+    with streamed == num_generated_tokens, and no token of a chunk still
+    in flight leaks past the terminal."""
+    eng = _engine(decode_pipeline=pipeline)
+    h = eng.submit([1, 2, 3], SamplingParams(temperature=0.0, max_tokens=48),
+                   deadline_s=600.0)
+    for _ in range(3):
+        eng.step()
+    assert bool(eng._inflight) == (pipeline == 2)
+    (slot,) = [s for s in eng._slots if s.active]
+    streamed_before = slot.generated
+    slot.request.deadline_at = eng.clock() - 1.0
     ((toks, fin),) = _drive(eng, h)
     assert fin.finish_reason is FinishReason.DEADLINE
-    assert len(toks) == fin.num_generated_tokens
-    # Prefill's first token + exactly one in-scan step before the mask.
-    assert fin.num_generated_tokens == 2
+    assert len(toks) == fin.num_generated_tokens == streamed_before
     assert eng.metrics["deadline_exceeded"] == 1
-    assert eng.metrics["early_exit_steps"] > 0
+    assert not eng._inflight
 
 
-def test_cancel_mid_ring_exact_partial_counts():
-    """A cancel landing while ring chunks are in flight: the terminal
-    carries exactly the streamed token count (no token from a stale
-    drained chunk leaks past the terminal)."""
-    eng = _engine(decode_ring=2)
+def test_cancel_with_chunks_in_flight_exact_partial_counts():
+    """A cancel landing while chunks are in flight: the terminal carries
+    exactly the streamed token count (no token from a chunk read after
+    the cancel leaks past the terminal)."""
+    eng = _engine(decode_pipeline=2)
     h = eng.submit([1, 2, 3], SamplingParams(temperature=0.0, max_tokens=48))
     for _ in range(3):
         eng.step()
+    assert eng._inflight
     h.cancel()
     while eng.step():
         pass
@@ -549,18 +445,16 @@ def test_cancel_mid_ring_exact_partial_counts():
     assert len(toks) == fin.num_generated_tokens
 
 
-def test_ring_watchdog_trip_poisons_drainer_and_recovers():
+def test_watchdog_trip_poisons_drainer_and_recovers():
     """An injected hang on the drainer thread trips the watchdog at
     the bound, poisons the drainer, and recovery rebuilds device state
     plus a FRESH drainer lane — the engine serves again."""
-    from omnia_tpu.engine.faults import FaultPlan
+    from omnia_tpu.engine.faults import FaultPlan, WatchdogTimeout
 
     plan = FaultPlan(hang_dispatch_s=30.0, hang_count=1)
-    eng = _engine(decode_ring=2, watchdog_s=0.2)
+    eng = _engine(decode_pipeline=2, watchdog_s=0.2)
     eng._fault_plan = plan
     h = eng.submit([1, 2, 3], GREEDY)
-    from omnia_tpu.engine.faults import WatchdogTimeout
-
     with pytest.raises(WatchdogTimeout):
         while eng.step():
             pass
@@ -578,33 +472,44 @@ def test_ring_watchdog_trip_poisons_drainer_and_recovers():
     eng.stop()
 
 
-def test_ring_drain_stop_with_inflight_chunks():
-    """stop(drain=True) with a half-drained ring: every in-flight
+def test_drain_stop_with_inflight_chunks():
+    """stop(drain=True) with chunks in flight under a watchdog: every
     chunk's tokens are surfaced (the stream terminal arrives), and the
     drainer thread is joined."""
-    eng = _engine(decode_ring=2)
+    eng = _engine(decode_pipeline=2, watchdog_s=30.0)
     h = eng.submit([1, 2, 3], SamplingParams(temperature=0.0, max_tokens=48))
     for _ in range(4):
         eng.step()
-    assert eng._inflight  # chunks genuinely in flight mid-drain
+    assert eng._inflight  # chunks genuinely in flight at the stop
+    assert eng._devloop._drainer is not None
     eng.stop(drain=True)
-    d = eng._devloop._drainer
-    assert d is None  # stop() joined and cleared the drainer
+    assert eng._devloop._drainer is None  # stop() joined and cleared it
     toks, fin = h.collect_tokens(timeout=5)
     assert fin.finish_reason is not None
     assert len(toks) == fin.num_generated_tokens
 
 
-def test_ring_full_stall_books_and_preserves_stream():
-    """A pipeline held past the ring's undrained-chunk capacity books
-    ring_full_stalls and processes the oldest chunk first — tokens
-    still arrive exactly once, in order."""
-    eng = _engine(decode_ring=2, decode_pipeline=4)
-    off = _engine(decode_pipeline=4)
-    sp = SamplingParams(temperature=0.0, max_tokens=24)
-    (t_on,) = _drive(eng, eng.submit([5, 6, 7], sp))
-    (t_off,) = _drive(off, off.submit([5, 6, 7], sp))
-    assert t_on[0] == t_off[0]
-    # decode_pipeline=4 wants 4 undrained chunks; capacity 2 stalls it.
-    assert eng.metrics["ring_full_stalls"] > 0
-    eng.stop()
+# ---------------------------------------------------------------------------
+# What went with the token ring stays gone
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("owner", ["EngineConfig", "MockEngine"])
+def test_decode_ring_is_not_an_option(owner):
+    from omnia_tpu.engine.types import EngineConfig
+
+    with pytest.raises(TypeError, match="decode_ring"):
+        {"EngineConfig": EngineConfig, "MockEngine": MockEngine}[owner](decode_ring=2)
+
+
+def test_arenajob_threshold_has_no_devloop_ratio():
+    from omnia_tpu.evals.defs import ArenaJobSpec, Threshold
+    from omnia_tpu.operator.crds import render_crd
+
+    assert not hasattr(Threshold(), "min_devloop_ratio")
+    spec = ArenaJobSpec.from_dict({
+        "name": "perf", "providers": ["p"],
+        "threshold": {"min_devloop_ratio": 0.97, "min_pass_rate": 0.5},
+    })
+    assert spec.threshold == Threshold(min_pass_rate=0.5)
+    assert "min_devloop_ratio" not in str(render_crd("ArenaJob"))

@@ -413,11 +413,7 @@ class TestDecodePipeline:
             out.append((toks, fin.finish_reason, fin.num_generated_tokens))
         return out
 
-    @pytest.mark.parametrize("kw", [
-        pytest.param({}, id="inline-sync"),
-        pytest.param({"decode_ring": 2}, id="ring-drain"),
-    ])
-    def test_saturated_pipelined_matches_sync(self, kw):
+    def test_saturated_pipelined_matches_sync(self):
         # Each request alone on a fresh engine: what its stream must be,
         # whoever held its slot before it.
         alone = [
@@ -432,7 +428,7 @@ class TestDecodePipeline:
         want[2] = (alone[2][:4], FinishReason.STOP, 4)
         want[-1] = ([], FinishReason.CANCELLED, 0)
         for pipeline in (1, 2):
-            eng = self._mk(pipeline, **kw)
+            eng = self._mk(pipeline)
             try:
                 assert self._run_saturated(eng, stop_id) == want, pipeline
                 m = eng.metrics

@@ -78,8 +78,6 @@ KNOB_GUARDS = {
         "test_flight.py::test_flight_off_is_true_noop",
     "EngineConfig.warmup_threads":
         "test_coldstart.py::test_warmup_threads_zero_is_true_noop",
-    "EngineConfig.decode_ring":
-        "test_devloop.py::test_decode_ring_off_is_true_noop",
     "MockEngine.kv_quant":
         "test_guards.py::test_mock_knobs_off_are_true_noop",
     "MockEngine.fault_plan":
@@ -102,8 +100,6 @@ KNOB_GUARDS = {
         "structural: mirror depth cap; dead while spec_decode=0",
     "MockEngine.spec_gate_window":
         "structural: mirror gate window; dead while spec_decode=0",
-    "MockEngine.decode_ring":
-        "test_devloop.py::test_mock_decode_ring_off_is_true_noop",
     "MockEngine.warmup_threads":
         "test_coldstart.py::test_mock_warmup_threads_zero_is_true_noop",
     "MockEngine.coldstart":
@@ -435,8 +431,8 @@ def test_lifecycle_knobs_off_are_true_noop():
     # engine's watchdog runs through its single long-lived drainer, not
     # per-chunk thread churn (one ChunkDrainer, reused across chunks).
     assert off._devloop is None
-    d = on._devloop.drainer_if_live()
-    assert d is not None and d.drains > 0
+    d = on._devloop._drainer  # built on the first chunk read, never before
+    assert d is not None and not d.poisoned and d._thread.is_alive()
     on.stop()
     assert not d._thread.is_alive()
     # The always-present counters exist and stayed zero on both engines.

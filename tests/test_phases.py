@@ -142,8 +142,8 @@ def test_phase_spans_nest_under_step_and_carry_request_ids(tmp_path):
     assert sum(e[3]["finished"] for e in _named(events, phases.EMIT)) == 2
 
 
-def test_engine_thread_sleep_and_ring_drain_spans(tmp_path):
-    eng = _tiny_engine(decode_ring=2)
+def test_engine_thread_sleep_spans(tmp_path):
+    eng = _tiny_engine()
     eng.generate([1, 2, 3], GREEDY)
     eng.start()
     try:
@@ -161,9 +161,6 @@ def test_engine_thread_sleep_and_ring_drain_spans(tmp_path):
     # An idle poll writes no step span: sleeps outnumber steps once idle,
     # and no sleep lies inside a step.
     assert not any(s <= sl[1] and sl[2] <= e for sl in sleeps for _n, s, e, _a in steps)
-    drains = [e for evs in by_thread.values() for e in _named(evs, phases.RING_DRAIN)]
-    assert drains and all(d[3]["tokens"] > 0 for d in drains)
-    assert not _named(loop, phases.RING_DRAIN)  # the drainer's own thread
 
 
 def test_counters_against_a_scripted_schedule():

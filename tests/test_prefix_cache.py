@@ -482,8 +482,6 @@ class TestMetricsKeyStability:
         "kv_page_cow_copies",
         "requests_shed", "deadline_exceeded", "watchdog_trips",
         "recoveries",
-        "decode_ring_enabled", "ring_drains", "ring_full_stalls",
-        "early_exit_steps", "decode_ring_gate_state",
         "mixed_steps", "interleaved_prefill_tokens", "decode_stall_steps",
         "flight_enabled",
         "compile_cache_enabled", "warmup_phase",
