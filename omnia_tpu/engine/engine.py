@@ -348,7 +348,9 @@ class InferenceEngine(
             # and none had a slot (pipelined, so its share of
             # decode_dispatches is how much of a full engine's queueing
             # ran a step ahead); decode_slot_steps sums live slots x
-            # steps at dispatch (occupancy where the batch is formed);
+            # steps at dispatch (occupancy where the batch is formed) and
+            # decode_kv_blocks the decode kernel's blocks their contexts
+            # span x steps (the share of the cache's blocks it visits);
             # pipeline_flushes counts the flushes forced by a waiting
             # request that had a slot to go to; programs_compiled_serving
             # the programs asked of the compiler after warmup() returned.
@@ -356,6 +358,7 @@ class InferenceEngine(
             "decode_dispatches_single": 0,
             "decode_dispatches_blocked": 0,
             "decode_slot_steps": 0,
+            "decode_kv_blocks": 0,
             "pipeline_flushes": 0,
             "programs_compiled_serving": 0,
             "extend_steps": 0,

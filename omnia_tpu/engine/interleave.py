@@ -312,7 +312,7 @@ class _InterleaveMixin:
              self._budget, self._key_data, dtoks) = out
         dispatch_s = time.monotonic() - t_dispatch
         self.metrics["decode_dispatch_s"] += dispatch_s
-        self._count_decode_dispatch(1, len(active))
+        self._count_decode_dispatch(1, active)
         self.metrics["mixed_steps"] += 1
         self.metrics["interleaved_prefill_tokens"] += take
         self.metrics["prefill_tokens"] += take

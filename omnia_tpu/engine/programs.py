@@ -248,9 +248,11 @@ def build_programs(
                  gstate) = carry
             else:
                 ck, cv, tokens, positions, active, budget, key_data = carry
+            # The decode kernel reads no cache for a slot that is not
+            # active: its sample is discarded below.
             logits, ck, cv = llama.forward(
                 params, cfg, tokens[:, None], positions[:, None], ck, cv,
-                positions, mesh=mesh,
+                positions, mesh=mesh, live=active,
             )
             if grammar_on:
                 row = _grammar_rows(gtable, gstate)

@@ -46,6 +46,7 @@ from omnia_tpu.engine.devloop import _InflightChunk
 from omnia_tpu.engine.faults import WatchdogTimeout
 from omnia_tpu.engine.phases import phase
 from omnia_tpu.engine.types import FinishReason, SamplingParams, StreamEvent
+from omnia_tpu.ops.attention import decode_block_rows
 
 
 class _SchedulerMixin:
@@ -417,21 +418,36 @@ class _SchedulerMixin:
         self.metrics["decode_dispatch_s"] += time.monotonic() - t_dispatch
         return toks
 
-    def _count_decode_dispatch(self, steps: int, live: int,
+    def _live_kv_blocks(self, live) -> int:
+        """Blocks of the decode kernel that the contexts of the ``live``
+        slots ``[(slot, request_id)]`` span, by the host's lengths: what
+        one step's attention kernel visits, a layer."""
+        rows = decode_block_rows(
+            self.cfg.max_seq,
+            self.cfg.kv_page_tokens if self.cfg.kv_pages > 0 else 0,
+        )
+        return sum(self._slots[i].length // rows + 1 for i, _rid in live)
+
+    def _count_decode_dispatch(self, steps: int, live,
                                single: bool = False,
                                blocked: bool = False) -> None:
-        """One program call that decodes, counted where the batch is
+        """One program call that decodes over the ``live`` slots
+        ``[(slot, request_id)]``, counted where the batch is
         formed: ``decode_steps / decode_dispatches`` is the realised
         chunk, ``decode_dispatches_single`` the calls of the one-step
         decode program, ``decode_dispatches_blocked`` those made while
         requests waited and none had a slot, and ``decode_slot_steps``
         the slots live at dispatch times the steps asked, so
         ``decode_slot_steps / (decode_steps * num_slots)`` is occupancy
-        without reckoning it from tokens."""
+        without reckoning it from tokens. ``decode_kv_blocks`` is
+        ``_live_kv_blocks`` at dispatch times the steps asked, so over
+        ``decode_steps * num_slots * max_seq / block`` it is the share
+        of all (slot, block) pairs the decode kernel visits."""
         m = self.metrics
         m["decode_steps"] += steps
         m["decode_dispatches"] += 1
-        m["decode_slot_steps"] += live * steps
+        m["decode_slot_steps"] += len(live) * steps
+        m["decode_kv_blocks"] += self._live_kv_blocks(live) * steps
         if single:
             m["decode_dispatches_single"] += 1
         if blocked:
@@ -489,6 +505,7 @@ class _SchedulerMixin:
                 sp.set_metadata(
                     chunk=chunk, active=len(active), single=single,
                     blocked=blocked, inflight=len(self._inflight),
+                    kv_blocks=self._live_kv_blocks(active),
                 )
             # Paged pool: extend every active slot's pages past its write
             # frontier BEFORE the chunk dispatches (engine/paged.py) — a
@@ -497,7 +514,7 @@ class _SchedulerMixin:
             t_dispatch = time.monotonic()
             toks = self._run_decode_step(chunk=chunk)
             self._count_decode_dispatch(
-                chunk, len(active), single=chunk == 1, blocked=blocked
+                chunk, active, single=chunk == 1, blocked=blocked
             )
             # The dispatch wall rides the in-flight entry so the flight
             # recorder can pair it with the (deferred) sync wall into one

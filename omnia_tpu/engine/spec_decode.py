@@ -524,7 +524,7 @@ class _SpecDecodeMixin:
         self.metrics["decode_sync_s"] += sync_s
         self.metrics["spec_steps"] += 1
         if dtoks is not None:
-            self._count_decode_dispatch(1, len(plan.scan))
+            self._count_decode_dispatch(1, plan.scan)
         self._spec_accept(plan, g, dispatch_s, sync_s)
         if host_toks is not None:
             # Scan-lane emission: the exact chunk-processing loop at

@@ -260,7 +260,7 @@ def _write_kv(cache, new, start, layer, slots_sharded=False):
 
 
 def _layer(x, p, cfg: ModelConfig, cos, sin, q_positions, ck, cv, write_start,
-           attn_fn=None, mesh=None, layer=None):
+           attn_fn=None, mesh=None, layer=None, live=None):
     """One block. With a cache, ``ck``/``cv`` are the WHOLE caches
     [L, B, S, Hkv, D] (or paged pools) and ``layer`` this block's index
     into them: the new rows are written into layer ``layer`` in place,
@@ -297,7 +297,8 @@ def _layer(x, p, cfg: ModelConfig, cos, sin, q_positions, ck, cv, write_start,
         if attn_fn is not None:
             attn = attn_fn(q, ck, cv, q_positions)
         else:
-            attn = gqa_attention(q, ck, cv, q_positions, mesh=mesh, layer=layer)
+            attn = gqa_attention(q, ck, cv, q_positions, mesh=mesh, layer=layer,
+                                 live=live)
     with jax.named_scope("attn.out"):
         x = x + qdot(attn.reshape(B, T, -1), p["attn"]["wo"])
 
@@ -377,13 +378,17 @@ def _logits(params, cfg: ModelConfig, x):
 
 
 def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
-            write_start, mesh=None):
+            write_start, mesh=None, live=None):
     """Serving forward (prefill or decode — same code, different T).
 
     tokens, q_positions: int32 [B, T]; cache_k/v: [L, B, S, Hkv, D];
     write_start: int32 [B] row offset where this chunk's KV lands.
     mesh: the mesh params and caches are sharded over, if any — the
     decode kernel needs it named (ops/attention.py).
+    live: bool [B], or None for "every slot live": the slots whose
+    logits the caller will use. The decode kernel skips the others
+    (no cache read, zero attention output); their rows are still
+    written and their logits are garbage, as the caller expects.
     Returns (logits [B, T, V] f32, new_cache_k, new_cache_v).
 
     The caches (plain, QuantKV or PagedKV alike) ride the layer scan
@@ -400,7 +405,7 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
         p, layer = scanned
         return _layer(
             x, p, cfg, cos, sin, q_positions, ck, cv, write_start,
-            mesh=mesh, layer=layer,
+            mesh=mesh, layer=layer, live=live,
         ), None
 
     layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)

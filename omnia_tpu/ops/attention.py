@@ -59,6 +59,14 @@ def _kernel_on() -> bool:
     return _pallas_decode_mode() in ("1", "interpret")
 
 
+def decode_block_rows(cache_len: int, page_tokens: int = 0) -> int:
+    """Cache rows in one block of the decode kernel: a page of a paged
+    pool, else ``_DECODE_BLOCK_S`` (the whole cache when it is shorter).
+    A live slot at position ``p`` costs the kernel ``p // rows + 1``
+    blocks; a dead slot none."""
+    return page_tokens or min(_DECODE_BLOCK_S, cache_len)
+
+
 def check_decode_kernel(cache_len: int, num_kv_heads: int, paged: bool,
                         dp: int = 1, tp: int = 1) -> None:
     """Raise when the decode kernel is routed on and cannot serve this
@@ -67,7 +75,7 @@ def check_decode_kernel(cache_len: int, num_kv_heads: int, paged: bool,
     path mid-serving; ``_decode_path`` re-checks at trace time."""
     if not _kernel_on():
         return
-    if not paged and cache_len % min(_DECODE_BLOCK_S, cache_len):
+    if not paged and cache_len % decode_block_rows(cache_len):
         raise ValueError(
             f"decode kernel: cache length {cache_len} is not a multiple of "
             f"its {_DECODE_BLOCK_S}-row block; size max_seq to a multiple"
@@ -92,12 +100,12 @@ def _map_rows(fn, cache):
     return kv_map(fn, cache)
 
 
-def _decode_path(q, k_cache, v_cache, q_positions, mesh, layer):
+def _decode_path(q, k_cache, v_cache, q_positions, mesh, layer, live):
     """The Pallas decode kernel over layer ``layer`` of the whole cache,
     or None when it is routed off. Under a mesh the call is wrapped in a
     shard_map: the layer axis unsharded, slots over "dp" (when they
     divide; a single-slot view is replicated), heads over "tp",
-    positions and the page table sliced with the slots."""
+    positions, the live mask and the page table sliced with the slots."""
     if not _kernel_on():
         return None
     from omnia_tpu.ops import decode_attention as dk
@@ -110,6 +118,8 @@ def _decode_path(q, k_cache, v_cache, q_positions, mesh, layer):
     interpret = _pallas_decode_mode() == "interpret"
     paged = is_paged(k_cache)
     B, (S, Hkv) = q.shape[0], k_cache.shape[-3:-1]
+    if live is None:
+        live = jnp.ones((B,), jnp.int32)
     dp, tp = (mesh.shape["dp"], mesh.shape["tp"]) if mesh is not None else (1, 1)
     check_decode_kernel(S, Hkv, paged, dp, tp)
     b = "dp" if B % dp == 0 else None
@@ -125,7 +135,7 @@ def _decode_path(q, k_cache, v_cache, q_positions, mesh, layer):
     else:
         kernel = functools.partial(
             dk.decode_gqa_attention, interpret=interpret,
-            block_s=min(_DECODE_BLOCK_S, S),
+            block_s=decode_block_rows(S),
         )
         k, v = k_cache, v_cache
         mid, mid_specs = (), ()
@@ -136,19 +146,22 @@ def _decode_path(q, k_cache, v_cache, q_positions, mesh, layer):
         # applies the scales in VMEM (half the HBM KV traffic).
         scales, scale_specs = (k.s, v.s), (P(*kv_spec[:-1]),) * 2
         k, v = k.q, v.q
-    operands = (q[:, 0], k, v, *mid, q_positions[:, 0],
+    def run(live, *operands):  # the scales are optional and positional
+        return kernel(*operands, live=live)
+
+    operands = (live.astype(jnp.int32), q[:, 0], k, v, *mid, q_positions[:, 0],
                 jnp.asarray(layer, jnp.int32), *scales)
     if mesh is not None:
         from omnia_tpu.parallel.compat import shard_map
 
         head_spec = P(b, "tp", None)
-        kernel = shard_map(
-            kernel, mesh,
-            in_specs=(head_spec, kv_spec, kv_spec, *mid_specs, P(b), P(),
+        run = shard_map(
+            run, mesh,
+            in_specs=(P(b), head_spec, kv_spec, kv_spec, *mid_specs, P(b), P(),
                       *scale_specs),
             out_specs=head_spec,
         )
-    return kernel(*operands)[:, None]
+    return run(*operands)[:, None]
 
 
 def gqa_attention(
@@ -158,6 +171,7 @@ def gqa_attention(
     q_positions: jnp.ndarray,
     mesh=None,
     layer=None,
+    live=None,
 ) -> jnp.ndarray:
     """Attention of queries against a slot-contiguous KV cache.
 
@@ -176,12 +190,17 @@ def gqa_attention(
         over layer ``layer`` of them. The decode kernel indexes the layer
         in its block index map, so no layer is sliced out in front of
         it; the einsum path reads that one layer.
+    live: bool/int [B], or None for "every slot live". The decode kernel
+        reads nothing for a slot marked dead and returns zeros for it;
+        the einsum path (and any T > 1) ignores it — a dead slot's
+        output is discarded by whoever marked it dead.
     Returns [B, T, H, D].
     """
     B, T, H, D = q.shape
 
     if T == 1:
-        fused = _decode_path(q, k_cache, v_cache, q_positions, mesh, layer)
+        fused = _decode_path(q, k_cache, v_cache, q_positions, mesh, layer,
+                             live)
         if fused is not None:
             return fused
 

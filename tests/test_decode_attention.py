@@ -48,8 +48,7 @@ def _kernel(q, k, v, pos, layer=1, k_scale=None, v_scale=None, **kw):
 
 
 def _kernel_paged(q, pool_k, pool_v, table, pos, layer=1, k_scale=None,
-                  v_scale=None):
-    kw = {}
+                  v_scale=None, **kw):
     if k_scale is not None:
         kw.update(k_scale=_whole(k_scale, layer), v_scale=_whole(v_scale, layer))
     return decode_gqa_attention_paged(
@@ -310,6 +309,109 @@ class TestDecodeAttention:
             attn._pallas_decode_mode.cache_clear()
 
 
+EDITIONS = pytest.mark.parametrize("edition", ["plain", "int8", "paged"])
+_BLOCK = 256  # rows a block in the cases below (the page size when paged)
+
+
+def _edition_run(edition, q, k, v, pos, live=None, dead=()):
+    """The kernel of one edition over caches k, v [B, S, Hkv, D] at 256
+    rows a block, with every cache row (and scale) of the slots in
+    ``dead`` poisoned with NaN after the layout is made."""
+    from omnia_tpu.models import kv_quant as kvq
+
+    def poison(x, rows):
+        x = np.asarray(x).copy()
+        x[rows] = 127 if x.dtype == np.int8 else np.nan
+        return x
+
+    kw = {} if live is None else {"live": jnp.asarray(live)}
+    dead = list(dead)
+    if edition == "paged":
+        pool_k, pool_v, table = _paginate(k, v, page_s=_BLOCK)
+        pages = np.asarray(table)[dead].ravel()
+        return _kernel_paged(q, poison(pool_k, pages), poison(pool_v, pages),
+                             table, pos, **kw)
+    if edition == "int8":
+        qk, qv = kvq.quantize_rows(k), kvq.quantize_rows(v)
+        return _kernel(
+            q, poison(qk.q, dead), poison(qv.q, dead), pos,
+            k_scale=poison(qk.s, dead), v_scale=poison(qv.s, dead),
+            block_s=_BLOCK, **kw,
+        )
+    return _kernel(q, poison(k, dead), poison(v, dead), pos, block_s=_BLOCK,
+                   **kw)
+
+
+def _edition_ref(edition, q, k, v, pos):
+    """The einsum path over the values the edition's cache holds."""
+    from omnia_tpu.models import kv_quant as kvq
+
+    if edition == "int8":
+        k = kvq.dequantize_rows(kvq.quantize_rows(k))
+        v = kvq.dequantize_rows(kvq.quantize_rows(v))
+    return gqa_attention(q, k, v, pos[:, None])[:, 0]
+
+
+class TestLiveSlotsOnly:
+    """The kernel's work follows the live (slot, block) pairs: a live
+    slot takes ``pos // block + 1`` grid steps, a dead one none — it
+    reads nothing and its output row is zeros."""
+
+    @EDITIONS
+    def test_dead_slot_reads_nothing_and_returns_zeros(self, edition):
+        q, k, v = _setup(B=4, S=512, H=4, Hkv=2, D=128)
+        pos = jnp.asarray([300, 0, 17, 511], jnp.int32)
+        live = [1, 0, 1, 0]
+        clean = _edition_run(edition, q[:, 0], k, v, pos)
+        out = _edition_run(edition, q[:, 0], k, v, pos, live=live, dead=[1, 3])
+        out = np.asarray(out)
+        np.testing.assert_array_equal(out[[0, 2]], np.asarray(clean)[[0, 2]])
+        np.testing.assert_array_equal(out[[1, 3]], 0.0)
+        assert np.isfinite(out).all()
+
+    @EDITIONS
+    @pytest.mark.parametrize(
+        "live", [[1, 1, 1, 1], [0, 0, 0, 0], [0, 0, 1, 0], [1, 0, 0, 1]],
+        ids=["all-live", "all-dead", "one-live", "ends-live"],
+    )
+    def test_trip_count_edges(self, edition, live):
+        """Positions on both sides of a block edge and at the cache's
+        last row, under every pattern of live and dead neighbours: a
+        slot's first block follows another slot's last, or nothing."""
+        S = 512
+        q, k, v = _setup(B=4, S=S, H=4, Hkv=2, D=128)
+        pos = jnp.asarray([0, 255, 256, S - 1], jnp.int32)
+        ref = np.array(_edition_ref(edition, q, k, v, pos))
+        ref[~np.asarray(live, bool)] = 0.0
+        dead = [b for b, on in enumerate(live) if not on]
+        out = _edition_run(edition, q[:, 0], k, v, pos, live=live, dead=dead)
+        np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
+
+    @EDITIONS
+    def test_live_none_is_every_slot_live(self, edition):
+        q, k, v = _setup(B=3, S=512, H=4, Hkv=2, D=128, seed=5)
+        pos = jnp.asarray([5, 400, 256], jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(_edition_run(edition, q[:, 0], k, v, pos)),
+            np.asarray(_edition_run(edition, q[:, 0], k, v, pos, live=[1, 1, 1])),
+        )
+
+    @pytest.mark.parametrize("mask", [[True, False], [1, 0]], ids=["bool", "int"])
+    def test_gqa_attention_hands_the_mask_to_the_kernel(self, route, mask):
+        """``gqa_attention(..., live=)`` on the kernel route; the einsum
+        route takes the argument and ignores it."""
+        q, k, v = _setup(B=2, S=256, H=4, Hkv=2, D=128)
+        pos = jnp.asarray([[10], [200]], jnp.int32)
+        live = jnp.asarray(mask)
+        route("0")
+        ref = np.asarray(gqa_attention(q, k, v, pos, live=live))
+        np.testing.assert_array_equal(ref, np.asarray(gqa_attention(q, k, v, pos)))
+        route("interpret")
+        out = np.asarray(gqa_attention(q, k, v, pos, live=live))
+        np.testing.assert_allclose(out[0], ref[0], atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(out[1], 0.0)
+
+
 # ---------------------------------------------------------------------------
 # The kernel under a device mesh (shard_map over "dp" × "tp"), and the
 # layouts it refuses — ops/attention.py::_decode_path / check_decode_kernel
@@ -387,6 +489,68 @@ class TestKernelUnderMesh:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
         )
+
+    @pytest.mark.parametrize(
+        "dp,tp,layout",
+        [(2, 2, "plain"), (2, 2, "int8"), (1, 2, "paged")],
+    )
+    def test_live_mask_is_sliced_with_the_slots(self, devices8, route, dp, tp,
+                                                layout):
+        """The mask goes into the shard_map sliced over "dp" like the
+        positions: each shard skips its own dead slots (their rows and
+        scales are NaN) and gives the einsum's answer for its live ones."""
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from omnia_tpu.models import kv_quant as kvq
+        from omnia_tpu.models.paged_kv import PagedKV
+        from omnia_tpu.parallel import make_mesh
+
+        mesh = make_mesh(dp, tp, devices=devices8)
+        q, k, v = _setup(B=4, S=512, H=8, Hkv=4, D=64)
+        pos = jnp.asarray([3, 255, 256, 510], dtype=jnp.int32)
+        live = np.asarray([True, False, False, True])  # one a dp shard
+        route("0")
+        ref = np.asarray(gqa_attention(q, k, v, pos[:, None]))
+
+        def put(x, *spec):
+            return jax.device_put(x, NamedSharding(mesh, P(*spec)))
+
+        def dead_rows(x):
+            x = np.asarray(x).copy()
+            x[~live] = 127 if x.dtype == np.int8 else np.nan
+            return x
+
+        if layout == "paged":
+            pool_k, pool_v, table = _paginate(k, v, page_s=64)
+            dead_pages = np.asarray(table)[~live].ravel()
+            caches = []
+            for pool in (pool_k, pool_v):
+                pool = np.asarray(pool).copy()
+                pool[dead_pages] = np.nan
+                caches.append(PagedKV(put(pool, None, None, "tp", None),
+                                      put(table)))
+            kc, vc = caches
+        elif layout == "int8":
+            def quant(x):
+                c = kvq.quantize_rows(x)
+                return kvq.QuantKV(put(dead_rows(c.q), "dp", None, "tp", None),
+                                   put(dead_rows(c.s), "dp", None, "tp"))
+            kc, vc = quant(k), quant(v)
+            ref = np.asarray(gqa_attention(
+                q, kvq.dequantize_rows(kvq.quantize_rows(k)),
+                kvq.dequantize_rows(kvq.quantize_rows(v)), pos[:, None],
+            ))
+        else:
+            kc, vc = (put(dead_rows(x), "dp", None, "tp", None) for x in (k, v))
+
+        route("interpret")
+        out = np.asarray(jax.jit(
+            lambda q, kc, vc, p, live: gqa_attention(
+                q, kc, vc, p[:, None], mesh=mesh, live=live)
+        )(put(q, "dp", None, "tp", None), kc, vc, pos, put(live, "dp")))
+        np.testing.assert_allclose(out[live], ref[live], atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(out[~live], 0.0)
 
     def test_single_slot_view_is_replicated_over_dp(self, devices8, route):
         """extend runs T=1 on one slot's view: B=1 does not divide over

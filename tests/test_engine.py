@@ -511,3 +511,71 @@ class TestDecodePipeline:
         while eng.step():
             pass
         assert len(waiter.collect_tokens(timeout=5)[0]) == 3
+
+
+def test_decode_kernel_skips_freed_slots_and_counts_its_blocks(monkeypatch):
+    """Through the (interpreted) decode kernel: a request's greedy tokens
+    are the same when every row of the slots freed (or never used) around
+    it is NaN while it decodes — the kernel reads a slot only while it is
+    live — and ``decode_kv_blocks`` advances by the 256-row blocks the
+    live contexts span, a step."""
+    import jax.numpy as jnp
+
+    from omnia_tpu.ops import attention as attn
+
+    monkeypatch.setenv("OMNIA_PALLAS_DECODE", "interpret")
+    attn._pallas_decode_mode.cache_clear()
+    try:
+        eng = InferenceEngine(
+            get_config("test-tiny", max_seq_len=512),
+            EngineConfig(
+                num_slots=3, max_seq=512, prefill_buckets=(8, 264),
+                dtype="float32", decode_chunk=4,
+            ),
+            seed=3,
+        )
+        m = eng.metrics
+        long_prompt = [1 + i % 50 for i in range(260)]  # two blocks of 256
+        sp_long = SamplingParams(temperature=0.0, max_tokens=13)
+        sp_short = SamplingParams(temperature=0.0, max_tokens=3)
+
+        def delta(run):
+            before = {k: m[k] for k in ("decode_steps", "decode_slot_steps",
+                                        "decode_kv_blocks")}
+            out = run()
+            return out, {k: m[k] - v for k, v in before.items()}
+
+        (alone, _), d = delta(lambda: eng.generate(long_prompt, sp_long))
+        assert d["decode_steps"] >= 12
+        assert d["decode_kv_blocks"] == 2 * d["decode_steps"]
+        _, d = delta(lambda: eng.generate([7, 8, 9], sp_short))
+        assert 0 < d["decode_kv_blocks"] == d["decode_steps"]
+
+        def poisoned():
+            h_long = eng.submit(long_prompt, sp_long)
+            h_short = eng.submit([7, 8, 9], sp_short)
+            poisoned_slots = 0
+            while eng.step():
+                free = [i for i, s in enumerate(eng._slots) if not s.active]
+                if len(free) == 2 and not poisoned_slots:
+                    # The short request has ended: its slot and the one
+                    # never used are dead to the kernel from here on.
+                    eng._flush_pipeline()
+                    for name in ("_ck", "_cv"):
+                        cache = getattr(eng, name)
+                        setattr(eng, name,
+                                cache.at[:, jnp.asarray(free)].set(jnp.nan))
+                    poisoned_slots = len(free)
+            assert poisoned_slots == 2
+            assert h_short.collect_tokens(timeout=5)[1].finish_reason == \
+                FinishReason.LENGTH
+            return h_long.collect_tokens(timeout=5)[0]
+
+        together, d = delta(poisoned)
+        assert together == alone
+        # Two blocks a step for the long request, live at every dispatch,
+        # and one for the short one while it lived.
+        assert d["decode_slot_steps"] > d["decode_steps"]
+        assert d["decode_kv_blocks"] == d["decode_slot_steps"] + d["decode_steps"]
+    finally:
+        attn._pallas_decode_mode.cache_clear()

@@ -464,7 +464,7 @@ class TestMetricsKeyStability:
         "requests_submitted", "requests_finished", "tokens_generated",
         "prefill_steps", "decode_steps", "extend_steps", "prefill_tokens",
         "decode_dispatches", "decode_dispatches_single",
-        "decode_dispatches_blocked", "decode_slot_steps",
+        "decode_dispatches_blocked", "decode_slot_steps", "decode_kv_blocks",
         "pipeline_flushes", "programs_compiled_serving",
         "prefix_reuse_tokens", "session_offloads", "session_restores",
         "session_exports", "session_imports",
