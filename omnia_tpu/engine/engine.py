@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from omnia_tpu.engine import phases
 from omnia_tpu.engine.coldstart import PHASE_CODES, ColdStartTracker
 from omnia_tpu.engine.devloop import DevLoopState
+from omnia_tpu.engine.family import _PairCacheMixin, refuse_unported
 from omnia_tpu.engine.faults import FaultPlan
 from omnia_tpu.engine.flight import FlightRecorder
 from omnia_tpu.engine.interleave import _InflightPrefill, _InterleaveMixin
@@ -78,8 +79,7 @@ from omnia_tpu.engine.types import (
     StreamEvent,
     resolve_dtype,
 )
-from omnia_tpu.models import ModelConfig
-from omnia_tpu.models import llama
+from omnia_tpu.models import ModelConfig, model_module
 from omnia_tpu.models import quant
 from omnia_tpu.models.kv_quant import cache_bytes, validate_kv_quant
 from omnia_tpu.ops.sampling import make_slot_key_data
@@ -93,7 +93,7 @@ logger = logging.getLogger(__name__)
 class InferenceEngine(
     _SchedulerMixin, _SessionMixin, _SpecDecodeMixin, _PrefixCacheMixin,
     _PlacementMixin, _InterleaveMixin, _LifecycleMixin, _PagedKVMixin,
-    _WarmupMixin,
+    _WarmupMixin, _PairCacheMixin,
 ):
     """Slot-based continuous-batching engine over one model."""
 
@@ -108,6 +108,11 @@ class InferenceEngine(
     ):
         self.model_cfg = model_cfg
         self.cfg = engine_cfg
+        # The module every program, the weights and the cache go through
+        # (models/__init__.py::model_module); the benchmark reads it
+        # (benchmark/harness/manifest.py::served_by).
+        self.model_module = model_module(model_cfg)
+        refuse_unported(model_cfg, engine_cfg)
         # Cold-start tracker (engine/coldstart.py): phase spans + weight
         # streaming + warmup progress, mirrored into the stable metrics.
         # Callers that measure backend bring-up (bench, the runtime
@@ -258,7 +263,7 @@ class InferenceEngine(
                     f"EngineConfig.quant={qmode!r} but supplied params are "
                     f"{detected!r}-quantized"
                 )
-        specs = llama.param_specs(model_cfg)
+        specs = self.model_module.param_specs(model_cfg)
         if qmode:
             specs = quant.quantize_param_specs(specs, model_cfg, qmode)
         if params is None:
@@ -272,7 +277,9 @@ class InferenceEngine(
                     )
             else:
                 def init():
-                    return llama.init_params(model_cfg, key, dtype=self._dtype)
+                    return self.model_module.init_params(
+                        model_cfg, key, dtype=self._dtype
+                    )
             params = init_sharded(init, specs, self._mesh)
         else:
             if qmode and not quant.params_quantized(params):
@@ -440,8 +447,17 @@ class InferenceEngine(
             "kv_quant_enabled": 1 if self._kv_quant else 0,
             "kv_quant_bytes_per_token": self.kv_bytes_per_token(),
             "kv_quant_device_bytes": cache_bytes(
-                self._ck, self._cv, self._pk, self._pv
+                *self._cache, self._pk, self._pv
             ),
+            # The expert layer of a chip that holds a share of the
+            # experts (ops/moe.py::moe_dropless), summed on the device
+            # over a decode chunk's steps and layers and read back with
+            # its tokens: the (token, expert) assignments that landed on
+            # an expert held here, and the held experts that got at least
+            # one token, a layer a step. Over every row of the batch a
+            # step computes, live or not. Zero for a model without one.
+            "moe_assignments_held": 0,
+            "moe_experts_hit": 0,
             # Paged KV cache (engine/kv_pages.py) — pool gauges, live
             # while kv_pages > 0 and zero otherwise: usable pages total/
             # free, internal fragmentation of slot-referenced pages
@@ -502,15 +518,13 @@ class InferenceEngine(
         (engine/paged.py), pk/pv None."""
         B, S = self.cfg.num_slots, self.cfg.max_seq
         if self.cfg.kv_pages > 0:
-            ck, cv = self._alloc_paged_kv()
-            return ck, cv, None, None
-        ck, cv = self._zero_kv(B, S)
+            return tuple(self._alloc_paged_kv()), None, None
         pk = pv = None
         if self._prefix_pool is not None:
             pk, pv = self._zero_kv(
                 self.cfg.prefix_cache_slots, self.cfg.prefix_buckets()[-1]
             )
-        return ck, cv, pk, pv
+        return self._zero_kv(B, S), pk, pv
 
     def _born_sharded(self, init, specs):
         """``init()`` at the engine's placement: plain on one device,
@@ -521,15 +535,16 @@ class InferenceEngine(
         return init_sharded(init, specs, self._mesh)
 
     def _zero_kv(self, batch: int, rows: int):
-        """Zeroed (k, v) [L, batch, rows, Hkv, D] at the engine's KV
-        representation and placement."""
-        return self._born_sharded(
-            lambda: llama.init_kv_cache(
+        """The family's zeroed cache tuple for ``batch`` slots of ``rows``
+        rows ((k, v) [L, batch, rows, Hkv, D] for Llama's), at the engine's
+        KV representation and placement."""
+        return tuple(self._born_sharded(
+            lambda: self.model_module.init_kv_cache(
                 self.model_cfg, batch, rows, dtype=self._dtype,
                 kv_quant=self._kv_quant,
             ),
-            llama.kv_cache_specs(self._kv_quant),
-        )
+            self.model_module.kv_cache_specs(self._kv_quant),
+        ))
 
     def _init_device_state(self):
         """(Re)allocate KV caches and per-slot device state. Called at
@@ -544,7 +559,7 @@ class InferenceEngine(
             # list — the dedicated _pk/_pv prefix arrays do not exist.
             self._init_paged_state()
         else:
-            self._ck, self._cv, self._pk, self._pv = self._alloc_kv_state()
+            self._cache, self._pk, self._pv = self._alloc_kv_state()
             if self._prefix_pool is not None:
                 # A reallocation means any device-resident pool entries
                 # died with the caches; host-paged entries survive in
@@ -556,7 +571,7 @@ class InferenceEngine(
                     )
         if hasattr(self, "metrics"):
             self.metrics["kv_quant_device_bytes"] = cache_bytes(
-                self._ck, self._cv, self._pk, self._pv
+                *self._cache, self._pk, self._pv
             )
 
         # Grammar-constrained decoding state: per-slot FSM state beside
@@ -610,6 +625,8 @@ class InferenceEngine(
         roofline at THIS engine's configured precision."""
         mc = self.model_cfg
         itemsize = 1 if self._kv_quant else jnp.dtype(self._dtype).itemsize
+        if mc.is_latent:  # one row a token a layer, as allocated
+            return mc.num_layers * self.model_module.row_width(mc) * itemsize
         scale_bytes = 4 if self._kv_quant else 0
         return (
             mc.num_layers * mc.num_kv_heads

@@ -118,7 +118,7 @@ class _PagedKVMixin:
         it too: device pages died, host-paged sessions/prefixes keep
         their rows)."""
         cfg = self.cfg
-        self._ck, self._cv = self._alloc_paged_kv()
+        self._cache = tuple(self._alloc_paged_kv())
         self._pk = self._pv = None  # the prefix cache shares THIS pool
         self._pages = PageAllocator(cfg.kv_pages, cfg.kv_page_tokens, cfg.num_slots)
         if self._prefix_pool is not None:

@@ -134,15 +134,14 @@ class _PlacementMixin:
                 request.request_id, view.num_states
             )
 
-    def _run_insert(self, k_chunk, v_chunk, slot_idx, last_logits, sp=None,
+    def _run_insert(self, chunks, slot_idx, last_logits, sp=None,
                     request=None):
+        """``chunks``: one prefill chunk for each array of the cache."""
         sp = sp or SamplingParams()
         kd = self._sampling_key(slot_idx, sp)
-        ck, cv, tok, new_kd = self._insert_fn(
-            self._ck,
-            self._cv,
-            k_chunk,
-            v_chunk,
+        *cache, tok, new_kd = self._insert_fn(
+            *self._cache,
+            *chunks,
             slot_idx,
             last_logits,
             kd,
@@ -152,7 +151,7 @@ class _PlacementMixin:
             *self._grammar_args(request, sp),
         )
         key_data = self._key_data.at[slot_idx].set(new_kd)
-        return ck, cv, tok, key_data
+        return tuple(cache), tok, key_data
 
     def _prepare_session_slot(
         self, slot_idx: int, request: Request
@@ -328,12 +327,11 @@ class _PlacementMixin:
         ):
             # Ring path: the sp-sharded prefill stays its own program;
             # its KV chunk gathers into the slot via the insert step.
-            logits, k_chunk, v_chunk = self._prefill_ring_fn(
+            logits, *chunks = self._prefill_ring_fn(
                 self.params, jnp.asarray(toks), jnp.asarray(pos)
             )
-            self._ck, self._cv, first_tok, self._key_data = self._run_insert(
-                k_chunk, v_chunk, slot_idx, logits[:, n - 1], sp,
-                request=request,
+            self._cache, first_tok, self._key_data = self._run_insert(
+                chunks, slot_idx, logits[:, n - 1], sp, request=request,
             )
             return first_tok
         kd = self._sampling_key(slot_idx, sp)
@@ -344,14 +342,15 @@ class _PlacementMixin:
                     request_id=request.request_id if request else "",
                     take=n, bucket=bucket,
                 )
-            self._ck, self._cv, first_tok, new_kd = self._prefill_insert_fn(
-                self.params, self._ck, self._cv,
+            *cache, first_tok, new_kd = self._prefill_insert_fn(
+                self.params, *self._cache,
                 jnp.asarray(toks), jnp.asarray(pos),
                 jnp.int32(slot_idx), jnp.int32(n - 1), kd,
                 jnp.float32(sp.temperature), jnp.float32(sp.top_p),
                 jnp.int32(sp.top_k),
                 *self._grammar_args(request, sp),
             )
+            self._cache = tuple(cache)
         if self._flight is not None and request is not None:
             self._flight.note_prefill_piece(
                 request.request_id, n, bucket, time.monotonic() - t0
@@ -404,8 +403,8 @@ class _PlacementMixin:
             with phase(PREFILL_DISPATCH) as span:
                 if span:
                     span.set_metadata(request_id=rid, take=take, bucket=b)
-                self._ck, self._cv = self._extend_nosample_fn(
-                    self.params, self._ck, self._cv, toks, pos, slot_arr,
+                self._cache = self._extend_nosample_fn(
+                    self.params, *self._cache, toks, pos, slot_arr,
                     jnp.int32(off),
                 )
             if self._flight is not None and rid:
@@ -420,13 +419,14 @@ class _PlacementMixin:
         with phase(PREFILL_DISPATCH) as span:
             if span:
                 span.set_metadata(request_id=rid, take=take, bucket=b)
-            self._ck, self._cv, first_tok, new_kd = self._extend_fn(
-                self.params, self._ck, self._cv, toks, pos, slot_arr,
+            *cache, first_tok, new_kd = self._extend_fn(
+                self.params, *self._cache, toks, pos, slot_arr,
                 jnp.int32(off), jnp.int32(take - 1), kd,
                 jnp.float32(sp.temperature), jnp.float32(sp.top_p),
                 jnp.int32(sp.top_k),
                 *self._grammar_args(request, sp),
             )
+            self._cache = tuple(cache)
         if self._flight is not None and rid:
             self._flight.note_prefill_piece(rid, take, b, time.monotonic() - t0)
         self._key_data = self._key_data.at[slot_idx].set(new_kd)

@@ -45,6 +45,18 @@ through):
   a pool entry to host RAM for the paged tier. All device↔device (store
   and seed never cross the host link) in fixed prefix-bucket shapes.
 
+Model family and cache: the programs reach the model through
+``models.model_module(cfg)`` and through nothing else, and a family's cache
+is an opaque TUPLE of arrays (Llama's K and V, the latent family's one
+array) that ``prefill_insert``, ``insert``, ``extend`` and the decode
+programs take and return whole, right behind ``params``: each array of it
+is donated and moved by the same row helpers. A module that names
+``DECODE_COUNTERS`` has them summed on the device over a chunk's steps and
+layers and appended to the chunk's token buffer, one row a counter, so
+they are read back with the tokens and by nothing else. What is not
+ported to a family keeps the pair's signature below and is refused for
+the others at engine construction (family.py::refuse_unported).
+
 KV representation: every program moves cache rows through the
 cache-agnostic helpers in ``models/kv_quant.py``, so one program source
 serves both KV precisions — with ``EngineConfig.kv_quant`` the caches
@@ -68,7 +80,7 @@ import jax
 import jax.numpy as jnp
 
 from omnia_tpu.engine.types import EngineConfig
-from omnia_tpu.models import ModelConfig, llama
+from omnia_tpu.models import ModelConfig, model_module
 from omnia_tpu.models.kv_quant import cache_put, cache_take, kv_map
 from omnia_tpu.models import paged_kv as pkv
 from omnia_tpu.ops.sampling import _NEG_INF, sample_tokens_per_slot
@@ -120,6 +132,13 @@ def build_programs(
     owns no state, and is safe to call before any device state exists.
     """
 
+    model = model_module(cfg)
+    # Arrays of the family's cache tuple: the operands right behind
+    # ``params`` of every program that takes the cache whole.
+    n_cache = len(model.kv_cache_specs(ecfg.kv_quant))
+    cache_args = tuple(range(1, 1 + n_cache))
+    counters = len(getattr(model, "DECODE_COUNTERS", ()))
+
     # Grammar-constrained decoding: when the engine is built with
     # ecfg.grammar, every first-token sampler (prefill_insert / insert /
     # extend) takes ONE extra ``*g`` operand — the start-state mask bias
@@ -151,8 +170,7 @@ def build_programs(
         """One slot's contiguous [L, 1, S, H, D] view, either layout."""
         if paged:
             return pkv.gather_slot(c, slot)
-        L, B, S, H, D = c.shape
-        return cache_take(c, (0, slot, 0), (L, 1, S))
+        return cache_take(c, (0, slot, 0), (c.shape[0], 1, c.shape[2]))
 
     @jax.named_scope("insert")
     def _put_back(c, view, slot, write_start, t):
@@ -177,42 +195,44 @@ def build_programs(
         )
         return tok[0], new_kd[0]
 
-    def prefill_insert(params, ck, cv, tokens, positions, slot, last_idx,
-                       key_data, temp, top_p, top_k, *g):
-        logits, k_chunk, v_chunk = llama.forward_prefill(
-            params, cfg, tokens, positions
-        )
+    def prefill_insert(params, *args):
+        """(params, *cache, tokens, positions, slot, last_idx, key_data,
+        temp, top_p, top_k, *g) -> (*cache, tok, new_key_data)."""
+        cache, (tokens, positions, slot, last_idx, key_data, temp, top_p,
+                top_k, *g) = args[:n_cache], args[n_cache:]
+        logits, *chunks = model.forward_prefill(params, cfg, tokens, positions)
 
-        # c: [L,B,S,H,D]; chunk: [L,1,T,H,D] — a quantized cache
+        # c: [L,B,S,...]; chunk: [L,1,T,...] — a quantized cache
         # quantizes the fresh rows inside cache_put (kv_quant mode).
-        ck = _put(ck, k_chunk, slot, 0)
-        cv = _put(cv, v_chunk, slot, 0)
+        cache = tuple(_put(c, chunk, slot, 0) for c, chunk in zip(cache, chunks))
         tok, new_kd = _sample_first(logits, last_idx, key_data, temp, top_p,
                                     top_k, g)
-        return ck, cv, tok, new_kd
+        return (*cache, tok, new_kd)
 
-    prefill_insert_fn = jax.jit(prefill_insert, donate_argnums=(1, 2))
+    prefill_insert_fn = jax.jit(prefill_insert, donate_argnums=cache_args)
 
     prefill_ring_fn = None
     if ecfg.sp > 1:
         def prefill_ring(params, tokens, positions):
-            return llama.forward_prefill_ring(params, cfg, tokens, positions, mesh)
+            return model.forward_prefill_ring(params, cfg, tokens, positions, mesh)
 
         prefill_ring_fn = jax.jit(prefill_ring)
 
-    def insert(ck, cv, k_chunk, v_chunk, slot, last_logits, key_data, temp,
-               top_p, top_k, *g):
+    def insert(*args):
+        """(*cache, *chunks, slot, last_logits, key_data, temp, top_p,
+        top_k, *g) -> (*cache, tok, new_key_data)."""
+        cache, chunks = args[:n_cache], args[n_cache:2 * n_cache]
+        slot, last_logits, key_data, temp, top_p, top_k, *g = args[2 * n_cache:]
         # Place the prefill chunk into the slot's rows [slot, 0:T]
-        # (chunk [L,1,T,H,D] floats — quantized on write in kv mode).
-        ck = _put(ck, k_chunk, slot, 0)
-        cv = _put(cv, v_chunk, slot, 0)
+        # (chunk [L,1,T,...] floats — quantized on write in kv mode).
+        cache = tuple(_put(c, chunk, slot, 0) for c, chunk in zip(cache, chunks))
         tok, new_kd = sample_tokens_per_slot(
             last_logits, key_data[None], temp[None], top_p[None], top_k[None],
             mask_bias=_first_bias(g),
         )
-        return ck, cv, tok[0], new_kd[0]
+        return (*cache, tok[0], new_kd[0])
 
-    insert_fn = jax.jit(insert, donate_argnums=(0, 1))
+    insert_fn = jax.jit(insert, donate_argnums=tuple(range(n_cache)))
 
     max_seq = ecfg.max_seq
 
@@ -243,17 +263,20 @@ def build_programs(
         one place the step semantics live)."""
 
         def body(carry, _):
+            cache, rest = carry[:n_cache], carry[n_cache:]
             if grammar_on:
-                (ck, cv, tokens, positions, active, budget, key_data,
-                 gstate) = carry
+                tokens, positions, active, budget, key_data, gstate = rest
             else:
-                ck, cv, tokens, positions, active, budget, key_data = carry
+                tokens, positions, active, budget, key_data = rest
             # The decode kernel reads no cache for a slot that is not
             # active: its sample is discarded below.
-            logits, ck, cv = llama.forward(
-                params, cfg, tokens[:, None], positions[:, None], ck, cv,
+            logits, *cache = model.forward(
+                params, cfg, tokens[:, None], positions[:, None], *cache,
                 positions, mesh=mesh, live=active,
+                **({"counters": True} if counters else {}),
             )
+            if counters:
+                counts = cache.pop()
             if grammar_on:
                 row = _grammar_rows(gtable, gstate)
                 bias = jnp.where(
@@ -287,10 +310,10 @@ def build_programs(
                 hit_stop = (tok[:, None] == stop_ids).any(axis=1)
                 active = active & ~hit_stop & (budget > 0)
                 tokens = jnp.where(active | hit_stop, tok, tokens)
-            out = (ck, cv, tokens, positions, active, budget, key_data)
+            out = (*cache, tokens, positions, active, budget, key_data)
             if grammar_on:
                 out += (gstate,)
-            return out, tok
+            return out, ((tok, counts) if counters else tok)
 
         return body
 
@@ -315,7 +338,7 @@ def build_programs(
         acceptance never trusts anything past it. The row gather is the
         decode body's shared ``_grammar_rows`` helper — one idiom, one
         mask source for sampler and oracle alike."""
-        logits, ck, cv = llama.forward(
+        logits, ck, cv = model.forward(
             params, cfg, vtoks, vpos, ck, cv, vwstart, mesh=mesh
         )
         if gstate is None:
@@ -372,7 +395,7 @@ def build_programs(
         return out, toks
 
     def make_decode(chunk: int):
-        def decode_impl(params, ck, cv, tokens, positions, active, budget,
+        def decode_impl(params, cache, tokens, positions, active, budget,
                         stop_ids, key_data, temp, top_p, top_k,
                         gstate=None, gtable=None, gactive=None):
             """`chunk` decode steps in ONE compiled program (lax.scan):
@@ -402,32 +425,30 @@ def build_programs(
                 params, stop_ids, temp, top_p, top_k, gtable, gactive,
                 grammar_on,
             )
-            init = (ck, cv, tokens, positions, active, budget, key_data)
+            init = (*cache, tokens, positions, active, budget, key_data)
             if grammar_on:
                 init += (gstate,)
             carry, toks = jax.lax.scan(body, init, None, length=chunk)
             # toks [K, B]
+            if counters:
+                # The chunk's counters ride its token buffer, one row a
+                # counter (the sum over its steps, in every column): one
+                # read-back, [K + counters, B].
+                toks, counts = toks
+                toks = jnp.concatenate([toks, jnp.broadcast_to(
+                    counts.sum(axis=0)[:, None], (counters, toks.shape[1]))])
             return carry + (toks,)
 
+        def decode_chunk(params, *args):
+            """(params, *cache, tokens, positions, active, budget,
+            stop_ids, key_data, temp, top_p, top_k[, gstate, gtable,
+            gactive]) -> (*cache, tokens, positions, active, budget,
+            key_data[, gstate], toks)."""
+            return decode_impl(params, args[:n_cache], *args[n_cache:])
+
         if ecfg.grammar:
-            def decode_chunk_grammar(params, ck, cv, tokens, positions,
-                                     active, budget, stop_ids, key_data,
-                                     temp, top_p, top_k, gstate, gtable,
-                                     gactive):
-                return decode_impl(params, ck, cv, tokens, positions, active,
-                                   budget, stop_ids, key_data, temp, top_p,
-                                   top_k, gstate, gtable, gactive)
-
-            fn = decode_chunk_grammar
-        else:
-            def decode_chunk(params, ck, cv, tokens, positions, active,
-                             budget, stop_ids, key_data, temp, top_p, top_k):
-                return decode_impl(params, ck, cv, tokens, positions, active,
-                                   budget, stop_ids, key_data, temp, top_p,
-                                   top_k)
-
-            fn = decode_chunk
-        return jax.jit(fn, donate_argnums=(1, 2))
+            decode_chunk.__name__ = "decode_chunk_grammar"
+        return jax.jit(decode_chunk, donate_argnums=cache_args)
 
     # Compiled chunk-size variants: the big chunk for steady-state
     # throughput, smaller ones so the tail of a generation (or a step
@@ -435,41 +456,41 @@ def build_programs(
     # full chunk. The scheduler's _pick_chunk chooses per dispatch.
     decode_fns = {k: make_decode(k) for k in ecfg.chunk_variants()}
 
-    def extend(params, ck, cv, tokens, positions, slot, write_start, last_idx,
-               key_data, temp, top_p, top_k, *g):
-        k_slot = _take_slot(ck, slot)
-        v_slot = _take_slot(cv, slot)
-        logits, k_slot, v_slot = llama.forward(
-            params, cfg, tokens, positions, k_slot, v_slot, write_start[None],
+    def _extend_slot(params, cache, tokens, positions, slot, write_start):
+        """The extend seam: one slot's view of every cache array, forward
+        over it with the slot's write offset, the view written back."""
+        views = [_take_slot(c, slot) for c in cache]
+        logits, *views = model.forward(
+            params, cfg, tokens, positions, *views, write_start[None],
             mesh=mesh,
         )
         # forward kept the slice in cache representation (suffix rows
         # quantized inside _write_kv when kv_quant is on) — write back
         # verbatim, no requantization of resident rows.
         t = tokens.shape[1]
-        ck = _put_back(ck, k_slot, slot, write_start, t)
-        cv = _put_back(cv, v_slot, slot, write_start, t)
+        return logits, tuple(_put_back(c, view, slot, write_start, t)
+                             for c, view in zip(cache, views))
+
+    def extend(params, *args):
+        """(params, *cache, tokens, positions, slot, write_start, last_idx,
+        key_data, temp, top_p, top_k, *g) -> (*cache, tok, new_key_data)."""
+        cache, (tokens, positions, slot, write_start, last_idx, key_data,
+                temp, top_p, top_k, *g) = args[:n_cache], args[n_cache:]
+        logits, cache = _extend_slot(params, cache, tokens, positions, slot,
+                                     write_start)
         tok, new_kd = _sample_first(logits, last_idx, key_data, temp, top_p,
                                     top_k, g)
-        return ck, cv, tok, new_kd
+        return (*cache, tok, new_kd)
 
-    extend_fn = jax.jit(extend, donate_argnums=(1, 2))
+    extend_fn = jax.jit(extend, donate_argnums=cache_args)
 
     # Mid-extend chunk: writes rows, no sampling (sampling happens only
     # on the final chunk of a multi-chunk extend).
-    def extend_nosample(params, ck, cv, tokens, positions, slot, write_start):
-        k_slot = _take_slot(ck, slot)
-        v_slot = _take_slot(cv, slot)
-        _, k_slot, v_slot = llama.forward(
-            params, cfg, tokens, positions, k_slot, v_slot, write_start[None],
-            mesh=mesh,
-        )
-        t = tokens.shape[1]
-        ck = _put_back(ck, k_slot, slot, write_start, t)
-        cv = _put_back(cv, v_slot, slot, write_start, t)
-        return ck, cv
+    def extend_nosample(params, *args):
+        cache, (tokens, positions, slot, write_start) = args[:n_cache], args[n_cache:]
+        return _extend_slot(params, cache, tokens, positions, slot, write_start)[1]
 
-    extend_nosample_fn = jax.jit(extend_nosample, donate_argnums=(1, 2))
+    extend_nosample_fn = jax.jit(extend_nosample, donate_argnums=cache_args)
 
     # Stall-free batching: fused mixed prefill+decode steps. One program
     # per prefill-piece bucket (and a *_sample twin for the final piece)
@@ -512,7 +533,7 @@ def build_programs(
                 # -- prefill piece via the extend seam ------------------
                 k_slot = _take_slot(ck, pslot)
                 v_slot = _take_slot(cv, pslot)
-                plogits, k_slot, v_slot = llama.forward(
+                plogits, k_slot, v_slot = model.forward(
                     params, cfg, ptoks, ppos, k_slot, v_slot, pwrite[None],
                     mesh=mesh,
                 )

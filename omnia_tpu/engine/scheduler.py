@@ -378,8 +378,7 @@ class _SchedulerMixin:
         t_dispatch = time.monotonic()
         args = (
             self.params,
-            self._ck,
-            self._cv,
+            *self._cache,
             self._tokens,
             self._positions,
             self._active,
@@ -390,31 +389,23 @@ class _SchedulerMixin:
             self._top_p,
             self._top_k,
         )
+        n = len(self._cache)
         if self._gr_on:
             # Grammar edition: per-slot FSM state rides the dispatch and
             # advances on device (programs.decode_chunk_grammar).
-            (
-                self._ck,
-                self._cv,
-                self._tokens,
-                self._positions,
-                self._active,
-                self._budget,
-                self._key_data,
-                self._gstate,
-                toks,
-            ) = fn(*args, self._gstate, self._gtable, self._gactive)
+            out = fn(*args, self._gstate, self._gtable, self._gactive)
+            self._gstate = out[n + 5]
         else:
-            (
-                self._ck,
-                self._cv,
-                self._tokens,
-                self._positions,
-                self._active,
-                self._budget,
-                self._key_data,
-                toks,
-            ) = fn(*args)
+            out = fn(*args)
+        self._cache = tuple(out[:n])
+        (
+            self._tokens,
+            self._positions,
+            self._active,
+            self._budget,
+            self._key_data,
+        ) = out[n:n + 5]
+        toks = out[-1]
         self.metrics["decode_dispatch_s"] += time.monotonic() - t_dispatch
         return toks
 
@@ -422,7 +413,8 @@ class _SchedulerMixin:
         """Blocks of the decode kernel that the contexts of the ``live``
         slots ``[(slot, request_id)]`` span, by the host's lengths: what
         one step's attention kernel visits, a layer."""
-        rows = decode_block_rows(
+        own = getattr(self.model_module, "decode_block_rows", None)
+        rows = own(self.cfg.max_seq) if own else decode_block_rows(
             self.cfg.max_seq,
             self.cfg.kv_page_tokens if self.cfg.kv_pages > 0 else 0,
         )
@@ -529,11 +521,18 @@ class _SchedulerMixin:
     def _process_oldest_chunk(self):
         ch = self._inflight.popleft()
         t_sync = time.monotonic()
+        counters = getattr(self.model_module, "DECODE_COUNTERS", ())
         with phase(phases.CHUNK_SYNC) as sp:
             if sp:
-                sp.set_metadata(chunk=int(ch.toks.shape[0]))
+                sp.set_metadata(chunk=int(ch.toks.shape[0]) - len(counters))
             # [K, B] — ONE sync per chunk.
             host_tokens = self._sync_chunk_host(ch.toks)
+        if counters:
+            # The model's device counters are the buffer's last rows
+            # (programs.py::decode_impl): read with the tokens.
+            for name, row in zip(counters, host_tokens[-len(counters):]):
+                self.metrics[name] += int(row[0])
+            host_tokens = host_tokens[:-len(counters)]
         sync_s = time.monotonic() - t_sync
         self.metrics["decode_sync_s"] += sync_s
         if self._flight is not None:

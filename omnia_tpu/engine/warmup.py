@@ -108,13 +108,31 @@ PARAMFREE_FAMILIES = frozenset({"session", "prefix", "pages"})
 
 class _WarmupState:
     """The donated operands one warmup worker chains its calls through:
-    the slot KV pair and (when the pool exists) the prefix-pool pair.
-    Everything else a warmup call takes is shared read-only self state."""
+    the slots' cache (the family's tuple of arrays) and, when the pool
+    exists, the prefix-pool pair. Everything else a warmup call takes is
+    shared read-only self state. ``ck`` / ``cv`` name the pair family's
+    two arrays, for the tasks only that family has."""
 
-    __slots__ = ("ck", "cv", "pk", "pv")
+    __slots__ = ("cache", "pk", "pv")
 
-    def __init__(self, ck, cv, pk=None, pv=None):
-        self.ck, self.cv, self.pk, self.pv = ck, cv, pk, pv
+    def __init__(self, cache, pk=None, pv=None):
+        self.cache, self.pk, self.pv = tuple(cache), pk, pv
+
+    @property
+    def ck(self):
+        return self.cache[0]
+
+    @ck.setter
+    def ck(self, value):
+        self.cache = (value,) + self.cache[1:]
+
+    @property
+    def cv(self):
+        return self.cache[1]
+
+    @cv.setter
+    def cv(self, value):
+        self.cache = (self.cache[0], value)
 
 
 class _WarmupMixin:
@@ -162,13 +180,13 @@ class _WarmupMixin:
             def run(st):
                 fn = self._decode_fns[k]
                 args = (
-                    self.params, st.ck, st.cv, self._tokens,
+                    self.params, *st.cache, self._tokens,
                     self._positions, self._active, self._budget,
                     self._stop_ids, self._key_data, self._temp,
                     self._top_p, self._top_k,
                 )
                 out = fn(*args, *gargs())
-                st.ck, st.cv = out[0], out[1]
+                st.cache = tuple(out[:len(st.cache)])
             return run
 
         for k in sorted(self._decode_fns, reverse=True):
@@ -190,35 +208,37 @@ class _WarmupMixin:
                 toks = jnp.zeros((1, b), jnp.int32)
                 pos = jnp.arange(b, dtype=jnp.int32)[None, :]
                 if b in usable:
-                    st.ck, st.cv, _, _ = self._prefill_insert_fn(
-                        self.params, st.ck, st.cv, toks, pos, zero,
+                    *cache, _, _ = self._prefill_insert_fn(
+                        self.params, *st.cache, toks, pos, zero,
                         jnp.int32(b - 1), *sargs()
                     )
+                    st.cache = tuple(cache)
                     if (
                         self._prefill_ring_fn is not None
                         and b >= cfg.long_prefill_threshold
                         and b % cfg.sp == 0
                     ):
-                        logits, k_chunk, v_chunk = self._prefill_ring_fn(
+                        logits, *chunks = self._prefill_ring_fn(
                             self.params, toks, pos
                         )
                         sp = SamplingParams()
                         out = self._insert_fn(
-                            st.ck, st.cv, k_chunk, v_chunk, 0,
+                            *st.cache, *chunks, 0,
                             logits[:, -1], self._sampling_key(0, sp),
                             jnp.float32(sp.temperature),
                             jnp.float32(sp.top_p), jnp.int32(sp.top_k),
                             *self._grammar_args(None, sp),
                         )
-                        st.ck, st.cv = out[0], out[1]
+                        st.cache = tuple(out[:len(st.cache)])
                 if b in extend_shapes:
-                    st.ck, st.cv = self._extend_nosample_fn(
-                        self.params, st.ck, st.cv, toks, pos, zero, zero
-                    )
-                    st.ck, st.cv, _, _ = self._extend_fn(
-                        self.params, st.ck, st.cv, toks, pos, zero, zero,
+                    st.cache = tuple(self._extend_nosample_fn(
+                        self.params, *st.cache, toks, pos, zero, zero
+                    ))
+                    *cache, _, _ = self._extend_fn(
+                        self.params, *st.cache, toks, pos, zero, zero,
                         zero, *sargs()
                     )
+                    st.cache = tuple(cache)
             return run
 
         for b in sorted(usable | extend_shapes):
@@ -392,11 +412,10 @@ class _WarmupMixin:
         what each ADDITIONAL parallel warmup worker chains its donated
         operands through (worker 0 steals the engine's own arrays; the
         closing restore reallocates them regardless)."""
-        ck, cv, pk, pv = self._alloc_kv_state()
-        return _WarmupState(ck, cv, pk, pv)
+        return _WarmupState(*self._alloc_kv_state())
 
     def _run_warmup_serial(self, tasks) -> list[_WarmupState]:
-        st = _WarmupState(self._ck, self._cv, self._pk, self._pv)
+        st = _WarmupState(self._cache, self._pk, self._pv)
         for _family, _key, fn in tasks:
             fn(st)
             self.metrics["warmup_programs_done"] = self._coldstart.note_program()
@@ -411,7 +430,7 @@ class _WarmupMixin:
         from concurrent.futures import ThreadPoolExecutor
 
         states: list[_WarmupState] = [
-            _WarmupState(self._ck, self._cv, self._pk, self._pv)
+            _WarmupState(self._cache, self._pk, self._pv)
         ]
         idle: "queue_mod.SimpleQueue[_WarmupState]" = queue_mod.SimpleQueue()
         idle.put(states[0])
@@ -476,7 +495,7 @@ class _WarmupMixin:
         st = self._alloc_warmup_state()
         for _family, _key, fn in tasks:
             fn(st)
-        jax.block_until_ready((st.ck, st.cv))
+        jax.block_until_ready(st.cache)
 
     def _load_params_overlapped(self, loader: Callable):
         """Run the params loader with weight-streaming progress tracked,
@@ -571,7 +590,7 @@ class _WarmupMixin:
         for st in states:
             # Donated chains may still be executing asynchronously;
             # the compile phase ends when the device is quiesced.
-            jax.block_until_ready((st.ck, st.cv))
+            jax.block_until_ready(st.cache)
         compile_s = cs.end_phase("warmup_compile")
         if self._flight is not None:
             self._flight.note_init_phase("warmup_compile", {

@@ -35,6 +35,34 @@ class ModelConfig:
     num_experts_per_tok: int = 2
     # Maximum sequence length the serving engine sizes KV caches for.
     max_seq_len: int = 8192
+    # Latent attention (MLA, models/mla.py), served when kv_rank > 0 (the
+    # sources' kv_lora_rank; q_rank is their q_lora_rank): the cached row
+    # of a token is [c | k_rope], kv_rank + qk_rope_head_dim values shared
+    # by every head. The query passes through a rank of its own; a head's
+    # query and key are qk_nope_head_dim values without position beside
+    # qk_rope_head_dim rotated ones, its value v_head_dim.
+    kv_rank: int = 0
+    q_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling as a hashable tuple (factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim); None = none. Rotary pairs are dims (2i, 2i+1)
+    # when rope_interleave, else the two halves.
+    rope_yarn: Optional[tuple] = None
+    rope_interleave: bool = False
+    # q ← q·(1 + β·ln(1 + ⌊pos / original context⌋)); 0 = none.
+    q_scaling_beta: float = 0.0
+    # The expert layer of that family (ops/moe.py::moe_dropless): the
+    # router scores all num_experts; this chip holds num_experts_held of
+    # them (0 = all), those of expert_rank's share, each moe_ffn_hidden_size
+    # wide, beside num_shared_experts experts that every token takes.
+    moe_ffn_hidden_size: int = 0
+    num_shared_experts: int = 0
+    num_experts_held: int = 0
+    expert_rank: int = 0
+    routed_scaling_factor: float = 1.0
 
     @property
     def q_dim(self) -> int:
@@ -47,6 +75,14 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts_held or self.num_experts
 
     def num_params(self) -> int:
         """Approximate parameter count (for memory planning)."""
@@ -140,6 +176,35 @@ PRESETS: dict[str, ModelConfig] = {
         ffn_hidden_size=128,
         rope_theta=10000.0,
         max_seq_len=128,
+    ),
+    # Latent attention + dropless experts at test widths: rank 1 of 2
+    # holds experts 4..7 of 8.
+    "test-tiny-mla": ModelConfig(
+        name="test-tiny-mla",
+        vocab_size=256,
+        hidden_size=64,
+        num_layers=2,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        ffn_hidden_size=128,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        num_experts=8,
+        num_experts_per_tok=2,
+        max_seq_len=512,
+        kv_rank=32,
+        q_rank=48,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        rope_yarn=(16.0, 64, 32.0, 1.0, 1.0, 1.0),
+        rope_interleave=True,
+        q_scaling_beta=0.1,
+        moe_ffn_hidden_size=32,
+        num_shared_experts=1,
+        num_experts_held=4,
+        expert_rank=1,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

@@ -18,6 +18,13 @@ on the tokens they serve:
   Tokens past an expert's capacity contribute zero (standard capacity-drop
   semantics); use capacity_factor ≥ ~2 at small batch.
 
+A third, ``moe_dropless``, is the expert layer of a chip that holds a
+SHARE of the experts (models/mla.py): it routes over all of them, sorts the
+(token, expert) assignments, and runs one grouped matmul over the
+assignments that landed on the experts it holds. No capacity, so nothing
+drops, and no token count at which the implementation changes. Mixtral's
+``moe_mlp`` moves onto it, and the two above go, in a later PR (ROADMAP).
+
 Sharding: expert-leading weights [E, d, f] shard E over the "tp" axis
 (expert parallelism). The [E, C, d] buffer shards over E, each device runs
 its experts' FFNs, and the scatter-add back to tokens reduces over E with
@@ -124,3 +131,66 @@ def moe_mlp(h, p, num_experts_per_tok: int, capacity_factor: float = 2.0):
     if B * T < DISPATCH_MIN_TOKENS:
         return moe_dense(h, p, num_experts_per_tok)
     return moe_dispatch(h, p, num_experts_per_tok, capacity_factor)
+
+
+def _grouped_matmul(xs, w, sizes, layer):
+    """Rows of ``xs`` in runs of ``sizes`` against each run's own matrix of
+    ``w`` [Eh, k, n]. With ``layer``, ``w`` is the whole stack [L, Eh, k, n]
+    and the runs meet layer ``layer``'s matrices where they lie: the stack
+    is one group axis of L·Eh (a reshape) in which every other layer's
+    groups are empty. Slicing the layer out in front of ``ragged_dot``
+    instead copies all its experts, hit or not, once a call (1.6 GB a layer
+    a step at Mistral-Small-4's widths: 62 % of a decode step, PERF.md
+    section 6, PR 32)."""
+    if layer is None:
+        return jax.lax.ragged_dot(xs, w, sizes)
+    L, Eh = w.shape[:2]
+    stack_sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((L * Eh,), sizes.dtype), sizes, (layer * Eh,))
+    return jax.lax.ragged_dot(xs, w.reshape(L * Eh, *w.shape[2:]), stack_sizes)
+
+
+def moe_dropless(h, p, num_experts_per_tok: int, first_expert: int = 0,
+                 routed_scaling_factor: float = 1.0, layer=None):
+    """Dropless expert layer over the experts this chip holds.
+
+    h [N, d]; ``p["router"]`` [d, E] scores all E experts; ``p["wg"]``,
+    ``p["wu"]`` [Eh, d, f] and ``p["wd"]`` [Eh, f, d] are experts
+    ``first_expert … first_expert + Eh - 1``, the only ones here (with
+    ``layer``, an index, the three are the layers' stacks [L, Eh, …] and
+    the layer's are used in place: ``_grouped_matmul``). Router
+    logits in float32, softmax over all E, the k largest renormalised to
+    sum 1, times ``routed_scaling_factor``. Returns ``(out [N, d],
+    held, hit)``: the weighted sum, a token, of its assignments' outputs
+    on held experts (what an absent expert would add is left out: its
+    chip adds it), how many of the N·k assignments landed on a held
+    expert, and how many held experts got at least one.
+
+    The N·k assignments are sorted by expert with those on absent
+    experts last; ``ragged_dot`` multiplies each held expert's run of
+    rows by that expert's weights and visits no expert whose run is
+    empty; the rows return to token order by the inverse permutation
+    and sum over k. Every shape is static; the group sizes are data."""
+    N, d = h.shape
+    Eh, K = p["wg"].shape[-3], num_experts_per_tok
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(h, p["router"], preferred_element_type=jnp.float32)
+        top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        top_w = top_w / top_w.sum(axis=-1, keepdims=True) * routed_scaling_factor
+    with jax.named_scope("moe.sort"):
+        local = top_i.reshape(N * K) - first_expert      # token-major
+        held = (local >= 0) & (local < Eh)
+        group = jnp.where(held, local, Eh)               # absent experts sort last
+        order = jnp.argsort(group)                       # stable
+        sizes = jnp.bincount(group, length=Eh + 1)[:Eh].astype(jnp.int32)
+        xs = h[order // K]                               # [N·K, d]
+    with jax.named_scope("moe.experts"):
+        gate = _grouped_matmul(xs, p["wg"], sizes, layer)
+        up = _grouped_matmul(xs, p["wu"], sizes, layer)
+        ys = _grouped_matmul(jax.nn.silu(gate) * up, p["wd"], sizes, layer)
+    with jax.named_scope("moe.combine"):
+        # Rows past the held runs are whatever ragged_dot left there.
+        ys = jnp.where(held[order][:, None], ys, 0).astype(jnp.float32)
+        ys = ys[jnp.argsort(order)].reshape(N, K, d)     # back to token order
+        out = jnp.sum(ys * top_w[:, :, None], axis=1).astype(h.dtype)
+    return out, held.sum(dtype=jnp.int32), (sizes > 0).sum(dtype=jnp.int32)

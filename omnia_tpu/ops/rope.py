@@ -1,4 +1,6 @@
-"""Rotary position embeddings (half-split / "rotate-half" convention).
+"""Rotary position embeddings: half-split ("rotate-half") pairs with the
+Llama-3 long-context remap, and interleaved pairs with YaRN frequencies
+(models/mla.py).
 
 Angles are computed in float32 from integer positions (not accumulated), so
 decode steps at large positions stay exact. Cos/sin are computed on the fly —
@@ -7,6 +9,8 @@ materializing a [max_seq, head_dim] table in HBM.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax.numpy as jnp
 
@@ -70,3 +74,66 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     sin = sin[..., None, :]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def _yarn_mscale(factor: float, m: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * m * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max_position: float, beta_fast: float,
+                  beta_slow: float) -> jnp.ndarray:
+    """YaRN frequencies [head_dim // 2] in float32: a pair that turns
+    more than ``beta_fast`` times over the original context keeps its
+    frequency, one that turns fewer than ``beta_slow`` times is slowed by
+    ``factor`` (interpolated), and a linear ramp over the pair index
+    joins the two."""
+
+    def pair_that_turns(rotations: float) -> float:
+        return (head_dim * math.log(original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    inv_freq = 1.0 / (
+        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    )
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0
+    )
+    return inv_freq / factor * ramp + inv_freq * (1.0 - ramp)
+
+
+def yarn_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float, yarn: tuple):
+    """Cos/sin [..., head_dim // 2] in float32 under YaRN; ``yarn`` is
+    ``ModelConfig.rope_yarn``. Both are scaled by mscale's ratio, the
+    convention of the DeepSeek-V3 code the ``mscale_all_dim`` key comes
+    from (1 when the two are equal)."""
+    factor, original_max, beta_fast, beta_slow, mscale, mscale_all_dim = yarn
+    inv_freq = yarn_inv_freq(head_dim, theta, factor, original_max, beta_fast, beta_slow)
+    ratio = _yarn_mscale(factor, mscale) / _yarn_mscale(factor, mscale_all_dim)
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    return jnp.cos(angles) * ratio, jnp.sin(angles) * ratio
+
+
+def yarn_softmax_scale(qk_head_dim: int, yarn: tuple | None) -> float:
+    """The factor on q·k: ``qk_head_dim ** -0.5``, times YaRN's
+    ``mscale_all_dim`` magnitude squared (once for q, once for k)."""
+    scale = qk_head_dim ** -0.5
+    if yarn is not None and yarn[5]:
+        scale *= _yarn_mscale(yarn[0], yarn[5]) ** 2
+    return scale
+
+
+def apply_rope_interleaved(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """Rotary embedding over the pairs (2i, 2i+1) of the last axis, in
+    place: x [..., H, head_dim]; cos/sin [..., head_dim//2] (broadcast
+    over H)."""
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    cos = cos[..., None, :]
+    sin = sin[..., None, :]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -141,6 +141,29 @@ def test_decode_kernel_compiles(one_chip, paged, model, kv_int8):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("slots,rows", [(96, 3072), (1, 256)],
+                         ids=["reason-batch", "the-check"])
+def test_latent_decode_kernel_compiles(one_chip, slots, rows):
+    """`decode_mla_attention` at Mistral-Small-4's published widths (32
+    heads against one latent head, rows of 256 + 64 values padded to 384
+    lanes): the cell's 96 slots x 3072 rows, and the one slot x 256 rows
+    that benchmark/harness/correct.py runs."""
+    from omnia_tpu.ops.decode_mla_attention import decode_mla_attention
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda q, cache, pos, layer, live: decode_mla_attention(
+            q, cache, pos, layer, live, rank=256, scale=0.2)
+    ).lower(
+        arr((slots, 32, 384), jnp.bfloat16), arr((5, slots, rows, 384), jnp.bfloat16),
+        arr((slots,), jnp.int32), arr((), jnp.int32), arr((slots,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_one_chip_decode_step_holds_the_mosaic_call(one_chip, kernel_route_on):
     cfg = get_config("llama3-1b")
     params, ck, cv = _model_operands(cfg, lambda _spec: one_chip)
