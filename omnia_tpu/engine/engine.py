@@ -358,6 +358,8 @@ class InferenceEngine(
             # steps at dispatch (occupancy where the batch is formed) and
             # decode_kv_blocks the decode kernel's blocks their contexts
             # span x steps (the share of the cache's blocks it visits);
+            # decode_steps_sampling / _filtering the steps in which a live
+            # request sampled / also filtered (ops/sampling.py's gates);
             # pipeline_flushes counts the flushes forced by a waiting
             # request that had a slot to go to; programs_compiled_serving
             # the programs asked of the compiler after warmup() returned.
@@ -366,6 +368,8 @@ class InferenceEngine(
             "decode_dispatches_blocked": 0,
             "decode_slot_steps": 0,
             "decode_kv_blocks": 0,
+            "decode_steps_sampling": 0,
+            "decode_steps_filtering": 0,
             "pipeline_flushes": 0,
             "programs_compiled_serving": 0,
             "extend_steps": 0,

@@ -15,6 +15,8 @@ import logging
 import threading
 import time
 
+import jax.numpy as jnp
+
 from omnia_tpu.engine.phases import IDLE_SLEEP, phase
 from omnia_tpu.engine.types import FinishReason, StreamEvent
 
@@ -294,3 +296,8 @@ class _LifecycleMixin:
                     )
                 self._release_slot_seed(slot)
                 slot.clear()
+        # No request is live any more. One stale positive temperature
+        # would hold the sampler's gate open (ops/sampling.py) for every
+        # later step of an engine that is started again; written without
+        # reading the old array, which recovery may find dead.
+        self._temp = jnp.zeros_like(self._temp)

@@ -420,6 +420,26 @@ class _SchedulerMixin:
         )
         return sum(self._slots[i].length // rows + 1 for i, _rid in live)
 
+    def _live_sampling(self, live) -> tuple[bool, bool]:
+        """(some ``live`` slot's request samples, some sampling one asks
+        for a threshold): the gates the sampler takes on the device for a
+        dispatch over these slots (ops/sampling.py ``_gated_sample``), by
+        the host's own slot records: a slot's ``_temp`` is its request's
+        temperature from placement until its slot is released. A slot
+        released between the snapshot and the program call (the paged
+        pool ran out, engine/paged.py) holds 0.0 again by then."""
+        sampling = False
+        for i, _rid in live:
+            request = self._slots[i].request
+            if request is None:
+                continue
+            p = request.params
+            if p.temperature > 0:
+                if p.top_p < 1 or p.top_k > 0:
+                    return True, True
+                sampling = True
+        return sampling, False
+
     def _count_decode_dispatch(self, steps: int, live,
                                single: bool = False,
                                blocked: bool = False) -> None:
@@ -434,12 +454,19 @@ class _SchedulerMixin:
         without reckoning it from tokens. ``decode_kv_blocks`` is
         ``_live_kv_blocks`` at dispatch times the steps asked, so over
         ``decode_steps * num_slots * max_seq / block`` it is the share
-        of all (slot, block) pairs the decode kernel visits."""
+        of all (slot, block) pairs the decode kernel visits.
+        ``decode_steps_sampling`` / ``decode_steps_filtering`` are the
+        steps asked times ``_live_sampling``: over ``decode_steps``, the
+        shares of steps in which the sampler did more than the argmax,
+        and in which it also computed thresholds."""
         m = self.metrics
         m["decode_steps"] += steps
         m["decode_dispatches"] += 1
         m["decode_slot_steps"] += len(live) * steps
         m["decode_kv_blocks"] += self._live_kv_blocks(live) * steps
+        sampling, filtering = self._live_sampling(live)
+        m["decode_steps_sampling"] += steps * sampling
+        m["decode_steps_filtering"] += steps * filtering
         if single:
             m["decode_dispatches_single"] += 1
         if blocked:
@@ -494,10 +521,12 @@ class _SchedulerMixin:
             ]
             chunk = 1 if single else self._pick_chunk()
             if sp:
+                sampling, filtering = self._live_sampling(active)
                 sp.set_metadata(
                     chunk=chunk, active=len(active), single=single,
                     blocked=blocked, inflight=len(self._inflight),
                     kv_blocks=self._live_kv_blocks(active),
+                    sampling=sampling, filtering=filtering,
                 )
             # Paged pool: extend every active slot's pages past its write
             # frontier BEFORE the chunk dispatches (engine/paged.py) — a

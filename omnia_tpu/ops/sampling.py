@@ -7,8 +7,12 @@ specialization). top_k is implemented as a threshold gathered from the
 descending sort that top_p already pays for, which keeps it dynamic without
 a second sort or a static lax.top_k shape.
 
-Greedy is expressed as temperature <= 0 and resolved with jnp.where, not
-Python branching, to keep the step traceable.
+Greedy is expressed as temperature <= 0 and resolved per row with
+jnp.where; what the whole batch pays for is decided on the device by
+``lax.cond`` over the same per-row values (``_gated_sample``): a batch in
+which no row samples takes the argmax and nothing else, one in which no
+sampling row filters skips the thresholds. No Python branching, so the
+step stays one traceable program.
 """
 
 from __future__ import annotations
@@ -144,7 +148,24 @@ def fast_path_feasible(scaled, top_p, top_k) -> bool:
     ))
 
 
-def _prepare(logits, temperature, top_p, top_k, mask_bias=None):
+def _gated_sample(logits, temperature, top_p, top_k, mask_bias, draw):
+    """The sampler's one piece of mathematics, gated on what some row of
+    the batch asks for. Two nested ``lax.cond``s in front of the
+    fast/slow one in ``_filter_thresholds``, decided on the device from
+    the per-row parameters alone:
+
+    1. no row samples (every ``temperature <= 0``, which is also what a
+       slot that never ran or has ended holds) → the argmax, nothing else;
+    2. some row samples but no SAMPLING row filters (the SamplingParams
+       defaults; a greedy row's ``top_p`` asks for nothing) → Gumbel
+       argmax with the threshold open: no top_k, no prefix arithmetic;
+    3. else the thresholds.
+
+    A row's token does not depend on the branch its batch takes: a
+    greedy row always gets ``argmax(logits + mask_bias)``, and a sampling
+    row holds the outer gate open in every step in which it is live.
+    ``draw(filtered [B, V]) -> int32 [B]`` is the Gumbel argmax with the
+    caller's keys. Returns int32 [B]."""
     B, V = logits.shape
     logits = logits.astype(jnp.float32)
     if mask_bias is not None:
@@ -153,13 +174,25 @@ def _prepare(logits, temperature, top_p, top_k, mask_bias=None):
         # greedy argmax and the filter thresholds so every path —
         # greedy, top-k, top-p — samples inside the grammar.
         logits = logits + mask_bias
-    if isinstance(top_k, int):
-        top_k = jnp.full((B,), top_k, dtype=jnp.int32)
-    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    thresh = _filter_thresholds(scaled, top_p, jnp.asarray(top_k, jnp.int32))
-    filtered = jnp.where(scaled < thresh, _NEG_INF, scaled)
-    return filtered, greedy_tok
+    top_k = jnp.broadcast_to(jnp.asarray(top_k, jnp.int32), (B,))
+    sampling = temperature > 0.0
+    filtering = sampling & ((top_p < 1.0) | (top_k > 0))
+
+    def greedy(_):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def sample(_):
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        thresh = jax.lax.cond(
+            jnp.any(filtering),
+            lambda _: _filter_thresholds(scaled, top_p, top_k),
+            lambda _: jnp.full((B, 1), _NEG_INF, jnp.float32),
+            None,
+        )
+        filtered = jnp.where(scaled < thresh, _NEG_INF, scaled)
+        return jnp.where(temperature <= 0.0, greedy(None), draw(filtered))
+
+    return jax.lax.cond(jnp.any(sampling), sample, greedy, None)
 
 
 def sample_tokens(
@@ -176,10 +209,11 @@ def sample_tokens(
     top_k: int or [B] int32; mask_bias: optional additive [B, V] grammar
     mask (0 / -inf). Returns int32 [B].
     """
-    filtered, greedy_tok = _prepare(logits, temperature, top_p, top_k, mask_bias)
-    gumbel = jax.random.gumbel(key, filtered.shape, dtype=jnp.float32)
-    sampled_tok = jnp.argmax(filtered + gumbel, axis=-1).astype(jnp.int32)
-    return jnp.where(temperature <= 0.0, greedy_tok, sampled_tok)
+    def draw(filtered):
+        gumbel = jax.random.gumbel(key, filtered.shape, dtype=jnp.float32)
+        return jnp.argmax(filtered + gumbel, axis=-1).astype(jnp.int32)
+
+    return _gated_sample(logits, temperature, top_p, top_k, mask_bias, draw)
 
 
 @jax.named_scope("sample")
@@ -199,16 +233,25 @@ def sample_tokens_per_slot(
     keys); mask_bias: optional additive [B, V] grammar mask (0 / -inf).
     Returns (tokens int32 [B], new_key_data [B, 2]).
     """
-    filtered, greedy_tok = _prepare(logits, temperature, top_p, top_k, mask_bias)
+    # Every row's key advances in every step, whatever branch the batch
+    # takes: a [B, 2] split, so a request without a seed (it inherits
+    # its slot's key) sees one stream however many greedy steps ran
+    # before it. The [B, V] noise is drawn only where a row samples.
+    def split(kd):
+        k, sub = jax.random.split(jax.random.wrap_key_data(kd))
+        return jax.random.key_data(k), jax.random.key_data(sub)
 
-    def one(row, kd):
-        k = jax.random.wrap_key_data(kd)
-        k, sub = jax.random.split(k)
+    new_key_data, sub_data = jax.vmap(split)(key_data)
+
+    def one(row, sd):
+        sub = jax.random.wrap_key_data(sd)
         g = jax.random.gumbel(sub, row.shape, dtype=jnp.float32)
-        return jnp.argmax(row + g).astype(jnp.int32), jax.random.key_data(k)
+        return jnp.argmax(row + g).astype(jnp.int32)
 
-    sampled_tok, new_key_data = jax.vmap(one)(filtered, key_data)
-    tok = jnp.where(temperature <= 0.0, greedy_tok, sampled_tok)
+    tok = _gated_sample(
+        logits, temperature, top_p, top_k, mask_bias,
+        lambda filtered: jax.vmap(one)(filtered, sub_data),
+    )
     return tok, new_key_data
 
 

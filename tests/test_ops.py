@@ -249,3 +249,166 @@ def test_fast_prefix_threshold_matches_full_sort():
         np.testing.assert_array_equal(
             np.asarray(scaled >= got), np.asarray(scaled >= want))
     assert fast_seen and slow_seen
+
+
+# ---------------------------------------------------------------------------
+# The gated sampler (PR 33) against a frozen copy of its parent's arithmetic
+# ---------------------------------------------------------------------------
+
+def _parent_sample_per_slot(logits, key_data, temperature, top_p, top_k,
+                            mask_bias=None):
+    """Commit 773850e's ``sample_tokens_per_slot``, kept verbatim: every row
+    pays the thresholds, the [B, V] noise and a second argmax, and a greedy
+    row's result is thrown away by the last ``where``. The thresholds
+    function is the module's own (the gates sit in front of it, unchanged)."""
+    from omnia_tpu.ops import sampling as S
+
+    logits = logits.astype(jnp.float32)
+    if mask_bias is not None:
+        logits = logits + mask_bias
+    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    thresh = S._filter_thresholds(scaled, top_p, jnp.asarray(top_k, jnp.int32))
+    filtered = jnp.where(scaled < thresh, S._NEG_INF, scaled)
+
+    def one(row, kd):
+        k = jax.random.wrap_key_data(kd)
+        k, sub = jax.random.split(k)
+        g = jax.random.gumbel(sub, row.shape, dtype=jnp.float32)
+        return jnp.argmax(row + g).astype(jnp.int32), jax.random.key_data(k)
+
+    sampled_tok, new_key_data = jax.vmap(one)(filtered, key_data)
+    return jnp.where(temperature <= 0.0, greedy_tok, sampled_tok), new_key_data
+
+
+# name: (V, logit scale, temperature, top_p, top_k, mask, branch) where branch
+# is the gate the batch must take: 1 argmax only, 2 no thresholds, 3 thresholds.
+_GATE_CASES = {
+    "all_greedy": (1024, 3.0, [0.0] * 4, [1.0] * 4, [0] * 4, False, 1),
+    "all_sampling_no_filter": (1024, 3.0, [0.7, 1.0, 0.3, 1.5], [1.0] * 4, [0] * 4, False, 2),
+    "greedy_and_sampling_mixed": (1024, 3.0, [0.0, 0.7, 0.0, 1.0], [1.0] * 4, [0] * 4, False, 2),
+    "top_p_only": (1024, 3.0, [0.7, 0.0, 1.0, 0.9], [0.9, 1.0, 0.5, 1.0], [0] * 4, False, 3),
+    "top_k_only": (1024, 3.0, [0.7, 0.0, 1.0, 0.9], [1.0] * 4, [40, 0, 1, 0], False, 3),
+    "top_p_and_top_k": (1024, 3.0, [0.7, 0.0, 1.0, 0.9], [0.9, 0.5, 0.95, 1.0], [40, 8, 0, 0], False, 3),
+    "greedy_row_with_top_p_takes_argmax": (1024, 3.0, [0.0] * 4, [0.9, 1.0, 0.5, 1.0], [0, 0, 7, 0], False, 1),
+    "greedy_filter_row_beside_plain_sampling": (1024, 3.0, [0.0, 0.7, 0.0, 1.0], [0.9, 1.0, 1.0, 1.0], [0, 0, 5, 0], False, 2),
+    "mask_bias_greedy": (1024, 3.0, [0.0] * 4, [1.0] * 4, [0] * 4, True, 1),
+    "mask_bias_sampling": (1024, 3.0, [0.7, 0.0, 1.0, 0.9], [0.9, 1.0, 1.0, 0.8], [0, 0, 3, 0], True, 3),
+    "vocab_is_the_prefix": (256, 3.0, [0.7, 0.0, 1.0, 0.9], [0.9, 1.0, 0.5, 1.0], [0, 0, 300, 0], False, 3),
+    "infeasible_prefix_slow_sort": (4096, 0.01, [1.0, 0.0, 0.8, 1.0], [0.99, 1.0, 0.9, 1.0], [0, 0, 2000, 0], False, 3),
+    "one_row_greedy": (1024, 3.0, [0.0], [1.0], [0], False, 1),
+    "one_row_sampling": (1024, 3.0, [0.7], [0.9], [50], False, 3),
+    "one_row_default_params": (1024, 3.0, [0.7], [1.0], [0], False, 2),
+}
+
+
+@pytest.mark.parametrize("name", list(_GATE_CASES))
+def test_gated_sampler_bit_equal_to_parent(name):
+    """Tokens AND key data of the gated sampler equal the parent's, bit for
+    bit, over three steps of each stream (the key advances in every branch),
+    jitted as the engine calls it; and the batch takes the gate it should."""
+    from omnia_tpu.ops import sampling as S
+
+    V, scale, temp, top_p, top_k, masked, branch = _GATE_CASES[name]
+    B = len(temp)
+    rng = np.random.default_rng(sum(map(ord, name)))
+    temp = jnp.asarray(temp, jnp.float32)
+    top_p = jnp.asarray(top_p, jnp.float32)
+    top_k = jnp.asarray(top_k, jnp.int32)
+    sampling = np.asarray(temp) > 0
+    filtering = sampling & ((np.asarray(top_p) < 1) | (np.asarray(top_k) > 0))
+    assert branch == (3 if filtering.any() else 2 if sampling.any() else 1)
+    if name == "infeasible_prefix_slow_sort":
+        probe = jnp.asarray(rng.normal(0, scale, (B, V)), jnp.float32)
+        assert not S.fast_path_feasible(
+            probe / jnp.maximum(temp, 1e-6)[:, None], top_p, top_k)
+        rng = np.random.default_rng(sum(map(ord, name)))
+
+    new = jax.jit(S.sample_tokens_per_slot)
+    old = jax.jit(_parent_sample_per_slot)
+    kd_new = kd_old = jnp.stack(
+        [S.make_slot_key_data(1000 + i) for i in range(B)])
+    for _step in range(3):
+        logits = jnp.asarray(rng.normal(0, scale, (B, V)), jnp.float32)
+        bias = None
+        if masked:
+            bias = jnp.where(
+                jnp.asarray(rng.random((B, V)) < 0.5), S._NEG_INF, 0.0
+            ).astype(jnp.float32)
+        tok_new, kd_new = new(logits, kd_new, temp, top_p, top_k, bias)
+        tok_old, kd_old = old(logits, kd_old, temp, top_p, top_k, bias)
+        np.testing.assert_array_equal(np.asarray(tok_new), np.asarray(tok_old))
+        np.testing.assert_array_equal(np.asarray(kd_new), np.asarray(kd_old))
+        if masked:
+            picked = np.take_along_axis(
+                np.asarray(bias), np.asarray(tok_new)[:, None], axis=1)
+            assert (picked == 0.0).all()
+
+
+def _eqn_names(jaxpr, into_cond=True):
+    """Primitive names (with the output shapes) of a jaxpr, descending into
+    every sub-jaxpr except, when ``into_cond`` is false, a ``cond``'s."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name,
+                    tuple(getattr(v.aval, "shape", ()) for v in eqn.outvars)))
+        if eqn.primitive.name == "cond" and not into_cond:
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_eqn_names(sub, into_cond))
+    return out
+
+
+def test_sampler_gates_hold_the_vocabulary_wide_work():
+    """Structure: ``top_k``, ``sort``, ``cumsum`` and the [B, V] random bits
+    occur only inside ``cond`` branches; the branch taken when every
+    temperature is zero holds none of them and nothing vocabulary-wide but
+    the argmax; the branch of a batch whose sampling rows do not filter
+    draws the noise but holds no ``top_k`` / ``sort`` / ``cumsum``."""
+    from omnia_tpu.ops import sampling as S
+
+    B, V = 4, 1024
+    closed = jax.make_jaxpr(S.sample_tokens_per_slot)(
+        jnp.zeros((B, V), jnp.float32), jnp.zeros((B, 2), jnp.uint32),
+        jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
+        jnp.zeros((B,), jnp.int32), jnp.zeros((B, V), jnp.float32),
+    )
+
+    def heavy(names):
+        return [
+            (n, shapes) for n, shapes in names
+            if n in ("top_k", "sort", "cumsum", "exp", "div")
+            or n.startswith("cum")
+            or (n in ("random_bits", "threefry2x32")
+                and any(V in s for s in shapes))
+        ]
+
+    assert heavy(_eqn_names(closed.jaxpr, into_cond=False)) == []
+    everything = {n for n, _ in _eqn_names(closed.jaxpr)}
+    assert {"top_k", "sort", "cumsum", "random_bits"} <= everything
+
+    (outer,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "cond"]
+    # lax.cond(pred, true_fn, false_fn): branches[int(pred)].
+    greedy, sample = outer.params["branches"]
+    greedy_names = _eqn_names(greedy.jaxpr)
+    assert heavy(greedy_names) == []
+    assert "cond" not in {n for n, _ in greedy_names}
+    assert {n for n, _ in greedy_names} <= {
+        "argmax", "convert_element_type", "add", "pjit"}
+
+    (inner,) = [e for e in sample.jaxpr.eqns if e.primitive.name == "cond"]
+    outside_inner = {n for n, _ in _eqn_names(sample.jaxpr, into_cond=False)}
+    assert not outside_inner & {"top_k", "sort", "cumsum"}
+    assert "random_bits" in outside_inner
+    open_thresh, thresholds = inner.params["branches"]
+    assert heavy(_eqn_names(open_thresh.jaxpr)) == []
+    assert {"top_k", "sort", "cumsum"} <= {
+        n for n, _ in _eqn_names(thresholds.jaxpr)}
+
+    # The single-key sampler goes through the same gates.
+    closed1 = jax.make_jaxpr(S.sample_tokens)(
+        jnp.zeros((B, V), jnp.float32), jax.random.key(0),
+        jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
+        jnp.zeros((B,), jnp.int32),
+    )
+    assert heavy(_eqn_names(closed1.jaxpr, into_cond=False)) == []

@@ -300,6 +300,36 @@ def test_live_slots_are_counted_where_the_batch_is_formed():
     assert m["decode_steps"] < m["decode_slot_steps"] < 2 * m["decode_steps"]
 
 
+@pytest.mark.parametrize("params,sampling,filtering", [
+    (SamplingParams(temperature=0.0, top_p=0.5, max_tokens=6), False, False),
+    (SamplingParams(temperature=0.7, max_tokens=6, seed=1), True, False),
+    (SamplingParams(temperature=0.7, top_p=0.9, max_tokens=6, seed=1), True, True),
+], ids=["greedy", "plain", "filtered"])
+def test_decode_dispatch_span_says_what_the_sampler_pays_for(
+        tmp_path, params, sampling, filtering):
+    """``sampling`` / ``filtering`` on a ``.decode_dispatch`` span are the
+    gates the sampler takes on the device for that dispatch, and summed
+    over the spans' steps they are the two counters."""
+    eng = _tiny_engine(num_slots=2)
+    eng.generate([1, 2, 3], params)  # compile outside the session
+    before = dict(eng.metrics)
+    with _profiled(tmp_path) as spans:
+        eng.submit([1, 2, 3], SamplingParams(temperature=0.0, max_tokens=20))
+        eng.submit([4, 5, 6], params)
+        _drain(eng)
+    (events,) = spans().values()
+    dispatches = [d[3] for d in _named(events, phases.DECODE_DISPATCH)]
+    assert dispatches and all(d["filtering"] <= d["sampling"] for d in dispatches)
+    assert any(d["sampling"] for d in dispatches) == sampling
+    assert any(d["filtering"] for d in dispatches) == filtering
+    # The greedy request outlives the six tokens of the other one.
+    assert not dispatches[-1]["sampling"] and dispatches[-1]["active"] == 1
+    for key, attr in (("decode_steps_sampling", "sampling"),
+                      ("decode_steps_filtering", "filtering")):
+        assert eng.metrics[key] - before[key] == sum(
+            d["chunk"] for d in dispatches if d[attr])
+
+
 def test_programs_compiled_after_warmup_are_counted(tmp_path, monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache as cc
 

@@ -209,6 +209,39 @@ def _computation_roots(text: str) -> dict[str, str]:
     return roots
 
 
+def _sorts_outside_conditionals(text: str) -> list[str]:
+    """``sort`` instructions (the sampler's top-k prefix and its full
+    sort) that the compiled program would run whatever the batch asks
+    for: those in a computation that no ``conditional`` branch reaches.
+    Empty means the chip's compiler kept the sampler's gates as real
+    conditionals instead of flattening them into selects."""
+    comps, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name:
+            comps[name].append(ln)
+    gated, todo = set(), []
+    for body in comps.values():
+        for ln in body:
+            if " conditional(" in ln:
+                m = re.search(r"branch_computations=\{([^}]*)\}", ln)
+                todo += re.findall(r"%([\w.\-]+)", m.group(1))
+    while todo:
+        c = todo.pop()
+        if c in gated or c not in comps:
+            continue
+        gated.add(c)
+        for ln in comps[c]:
+            todo += re.findall(r"%([\w.\-]+)", ln.split("=", 1)[-1])
+    return [
+        f"{c}: {ln.strip()[:120]}" for c, body in comps.items()
+        if c not in gated for ln in body if re.search(r"\bsort\(", ln)
+    ]
+
+
 @pytest.mark.parametrize("chunk", [1, 8])
 def test_cell_decode_programs_keep_the_cache_in_place(one_chip, kernel_route_on,
                                                       chunk):
@@ -237,6 +270,10 @@ def test_cell_decode_programs_keep_the_cache_in_place(one_chip, kernel_route_on,
         i32(MAX_DEVICE_STOP_IDS), vec(jnp.uint32, 2), f32, f32, i32(),
     ).compile()
     text = compiled.as_text()
+    # The sampler's gates are conditionals on the chip too: a greedy
+    # batch runs no sort over the vocabulary (PR 33).
+    assert " conditional(" in text and re.search(r"\bsort\(", text)
+    assert _sorts_outside_conditionals(text) == []
     # One layer body, one Mosaic call in it.
     assert text.count("tpu_custom_call") == 1
     # Nothing produces a second cache, a layer of it, or a re-laid-out one:
@@ -287,9 +324,15 @@ def test_tp4_engine_decode_chunk_compiles_sharded(tp4_mesh, kernel_route_on):
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    # The sampler's predicates are replicated scalars, so every chip
+    # takes the same branch; the vocabulary-sharded sort and its
+    # collectives live inside the branches.
+    assert " conditional(" in text and re.search(r"\bsort\(", text)
+    assert _sorts_outside_conditionals(text) == []
     # Megatron tensor parallelism: all-reduces after the attention and
-    # MLP output projections. The sampler sorts vocab-sharded logits,
-    # which brings all-gathers and all-to-alls of [B, V]-sized arrays;
+    # MLP output projections. The sampler, where a row asks for it, sorts
+    # vocab-sharded logits, which brings all-gathers and all-to-alls of
+    # [B, V]-sized arrays;
     # nothing may move cache rows ([.., S, Hkv, D]) between chips.
     found = collective_lines(text)
     assert len(found.get("all-reduce", [])) >= 2, sorted(found)
