@@ -21,20 +21,48 @@ What the harness asks of the program's model module, and no more:
             start [B], mesh=) -> (logits [B, T, V], *cache)
 
 `cfg` is the program's ModelConfig, of which the harness itself reads
-`vocab_size` and replaces `num_layers` and `tie_embeddings`. The cache is
-opaque: a tuple of any length (a pair of K and V, one latent array, a pair
-and a state), made by the module, handed back to it whole and never
-indexed here. Of `params` the harness knows `embed` [V, D] (also the head,
-transposed, where the table is tied), `layers`, a tree whose every leaf is
-led by the layer axis, and `lm_head` [D, V] where untied: that is what
-`_sub_model` cuts a one- or two-layer model from. So every layer must be
-alike; a stack of unlike layers (leading dense layers, a period of kinds)
-has no single layer axis to cut, and is not judged here yet (README).
-The reference is handed those cut trees too, with `sizes` as they are: it
-takes its depth from the tree, never from `sizes["config"]`, which is the
-file at full depth. The module named is the one the engine dispatches to
-(`manifest.served_by`; `run.py` refuses a configuration that names
-another), so what is judged here is what the window times.
+`vocab_size` and replaces `tie_embeddings` and the layers (below). The cache
+is opaque: a tuple of any length (a pair of K and V, one latent array, a
+pair and a state), made by the module, handed back to it whole and never
+indexed here. Of `params` the harness knows `embed` [V, W] (also the head,
+transposed, where the table is tied), `layers`, and `lm_head` [D, V] where
+untied: that is what `_sub_model` cuts a one- or two-layer model from.
+
+`layers` is either ONE tree whose every leaf is led by the layer axis (every
+layer alike), or a SEQUENCE (list or tuple) of such trees, a *stack* for each
+kind of layer, each with its own leading axis. A module of the second kind
+says two things more:
+
+    layer_order(cfg) -> ((stack, index), ...)   for model layer 0, 1, ...
+    with_layer_order(cfg, order) -> cfg         the same model with those layers
+
+`layer_order` is the order in which the model runs its layers: layer l is
+`index` of `params["layers"][stack]`; a stack's layers run in the order of
+its axis. "One dense layer, then five sparse" is ((0, 0), (1, 0), ... (1,
+4)); "two of one kind, one of the other, twice" ((0, 0), (0, 1), (1, 0), (0,
+2), (0, 3), (1, 1)). `with_layer_order` makes the ModelConfig of the model
+that `_sub_model` cuts: `order` is that model's own order, over the cut
+stacks, and the counts of every stack follow from it (a stack may be left
+with no layer: its leaves then have a leading axis of 0). A module that
+says neither has one tree, and `dataclasses.replace(cfg, num_layers=depth)`
+is how its cut model's config is made, as before. The reference of such a
+family states the same order from the configuration's file, on its own
+(`layer_order(sizes)`), and `check` refuses a run in which the two differ.
+
+The stream may be wider than the model. `_sub_model` makes the reference's
+stream into layer l the cut model's `embed` table, whatever its width W: a
+family that carries n copies of the residual a token gives `residual` as
+[L + 1, T, n * D], and its module takes a table of that width as the
+expanded stream itself (a table [V, D] it expands as it always does).
+Nothing here reads a width.
+
+The reference is handed those cut trees too, with `sizes` as they are but
+for `layer_order`, the cut model's own order, where `layers` is a sequence:
+it takes its depth from the tree (and its order from that key), never from
+`sizes["config"]`, which is the file at full depth. The module named is the
+one the engine dispatches to (`manifest.served_by`; `run.py` refuses a
+configuration that names another), so what is judged here is what the
+window times.
 
 Tolerance, as shares of the reference's logit range (max - min): max
 |diff| <= 5e-2 and mean |diff| <= 1e-2. PR 22 measured one bf16 run at
@@ -82,7 +110,9 @@ depth:
   distance from float32 may be at most NOISE_FACTOR times that
   evaluation's. The ratio does not know the depth, the width or the range.
 - *The first two layers together.* What a layer alone cannot show is
-  what joins layers: the order of the scan and the cache's layer index.
+  what joins layers: the order of the scan and the cache's layer index
+  (with one leading layer of another kind: the join between two stacks and
+  the cache's index across them).
   Layers 0 and 1 are therefore run once more as a two-layer model on the
   embedded tokens, through a two-layer cache, against the same two layers
   in float32. Two layers is the depth at which this can be judged at all:
@@ -94,6 +124,11 @@ depth:
   median position's worst logit, prefill and decode each, is held to
   PAIR_TOL. Layers in the wrong order or a cache read at the wrong layer
   move every position by half the range.
+
+Every model layer of every stack is judged so, in the model's order. A
+layer without a router has nothing to flip: its reference reports an
+infinite margin (every position decided) and it is held to the same three
+limits as any decided pair.
 
 Nothing is fed from the program into the reference: no routing is forced
 and no expert choice is read from the served side.
@@ -107,6 +142,7 @@ largest and the controls' smallest on both sides of each (PERF.md section
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -135,22 +171,84 @@ NOISE_FACTOR = 1.8
 PAIR, PAIR_TOL = 2, 0.1
 
 
-def _sub_model(params, stream, first, count: int, dtype):
+def _stacked(layers) -> bool:
+    return isinstance(layers, (list, tuple))
+
+
+def layer_order(model, cfg, layers):
+    """((stack, index), ...) for model layer 0, 1, ... where `layers` is a
+    sequence of stacks (the module's `layer_order(cfg)`, held to what the
+    stacks hold); None for one tree, whose layers are its axis."""
+    if not _stacked(layers):
+        return None
+    order = tuple((int(s), int(i)) for s, i in model.layer_order(cfg))
+    held = [jax.tree_util.tree_leaves(stack)[0].shape[0] for stack in layers]
+    named = [[i for s, i in order if s == k] for k in range(len(layers))]
+    if named != [list(range(n)) for n in held] or not all(held):
+        raise ValueError(
+            f"{model.__name__}.layer_order names {named} of stacks that hold {held} "
+            f"layers: every layer once, a stack's layers in the order of its axis, "
+            f"and no stack without a layer")
+    return order
+
+
+def cut_layers(order, first: int, count: int):
+    """Where model layers `first` to `first + count - 1` lie: (each stack's
+    first layer among them, each stack's number of them, the cut model's own
+    order over the cut stacks). A stack's layers run in the order of its
+    axis, so those of one stack are a slice of it, possibly empty."""
+    taken = order[first:first + count]
+    stacks = 1 + max(s for s, _ in order)
+    los = tuple(min((i for s, i in taken if s == k), default=0) for k in range(stacks))
+    counts = tuple(sum(s == k for s, _ in taken) for k in range(stacks))
+    return los, counts, tuple((s, i - los[s]) for s, i in taken)
+
+
+def cut_config(model, cfg, layers):
+    """The ModelConfig of a model `_sub_model` cuts: `layers` is its depth
+    (one tree) or its own order over the cut stacks (`cut_layers`)."""
+    if isinstance(layers, int):
+        return dataclasses.replace(cfg, num_layers=layers, tie_embeddings=False)
+    return dataclasses.replace(model.with_layer_order(cfg, layers), tie_embeddings=False)
+
+
+def _sub_model(params, stream, first, count, dtype):
     """The parameters of the model that is layers `first` to `first + count
-    - 1` alone on `stream` [T, D]: the stream is its embedding table
-    (position t is token t), the final norm and the head stay. Traced
-    inside a jit."""
-    layers = jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, first, count, axis=0), params["layers"])
+    - 1` alone on `stream` [T, W]: the stream is its embedding table
+    (position t is token t), the final norm and the head stay. Where
+    `layers` is a sequence of stacks, `first` and `count` are sequences
+    too, a stack's first layer and number of layers kept (`cut_layers`).
+    Traced inside a jit; `first` may be traced, `count` is static."""
+    def cut(stack, lo, n):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, lo, n, axis=0), stack)
+
+    if _stacked(params["layers"]):
+        layers = [cut(*each) for each in zip(params["layers"], first, count, strict=True)]
+    else:
+        layers = cut(params["layers"], first, count)
     head = params["lm_head"] if "lm_head" in params else params["embed"].T
     return {**params, "embed": stream.astype(dtype), "layers": layers, "lm_head": head}
+
+
+def _cut(order, n: int, depth: int):
+    """The model of `depth` layers from model layer n, as (first, count) for
+    `_sub_model` and what `cut_config` takes; `order` None is one tree."""
+    return (n, depth, depth) if order is None else cut_layers(order, n, depth)
+
+
+def _cuts(order, layers: int, depth: int):
+    """`_cut` for every n < `layers`, with `first` as arrays, so that cuts
+    alike but for where they start share one compiled program."""
+    return [(jax.tree_util.tree_map(jnp.int32, first), count, cut)
+            for first, count, cut in (_cut(order, n, depth) for n in range(layers))]
 
 
 def _served_logits(engine, model_cfg, tokens, prefill: int, cache_rows: int,
                    layer_inputs=None, depth: int = 1,
                    model_module: str = DEFAULT_MODEL_MODULE):
     """The program's logits as float32 for `tokens` through a fresh cache.
-    Whole depth: [T, V]. With `layer_inputs` [N, T, D], the stream the
+    Whole depth: [T, V]. With `layer_inputs` [N, T, W], the stream the
     reference saw enter layer n: [N, T, V], each the `depth` layers from n
     alone (`_sub_model`; `tokens` is then arange(T))."""
     from omnia_tpu.parallel import init_sharded
@@ -158,26 +256,22 @@ def _served_logits(engine, model_cfg, tokens, prefill: int, cache_rows: int,
     model = load_model_module(model_module)
     mesh = engine._mesh  # the mesh the engine's parameters live on
     dtype = engine.params["embed"].dtype
-    if layer_inputs is not None:
-        model_cfg = dataclasses.replace(model_cfg, num_layers=depth, tie_embeddings=False)
-    fresh = tuple(init_sharded(
-        lambda: model.init_kv_cache(model_cfg, 1, cache_rows, dtype=dtype),
-        model.kv_cache_specs(None), mesh))
 
-    def step(params, cache, toks, start):
-        pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None, :]
-        logits, *cache = model.forward(params, model_cfg, toks, pos, *cache,
-                                       jnp.reshape(start, (1,)), mesh=mesh)
-        return logits, tuple(cache)
+    def fresh(cfg):
+        return tuple(init_sharded(
+            lambda: model.init_kv_cache(cfg, 1, cache_rows, dtype=dtype),
+            model.kv_cache_specs(None), mesh))
 
-    def sub_step(params, stream, first, *rest):
-        return step(_sub_model(params, stream, first, depth, dtype), *rest)
+    def stepper(cfg):
+        def step(params, cache, toks, start):
+            pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None, :]
+            logits, *cache = model.forward(params, cfg, toks, pos, *cache,
+                                           jnp.reshape(start, (1,)), mesh=mesh)
+            return logits, tuple(cache)
+        return step
 
-    jitted = jax.jit(step if layer_inputs is None else sub_step)
-
-    def through_cache(*lead):
+    def through_cache(jitted, cache, *lead):
         """One prefill of `prefill` tokens, then one token a step."""
-        cache = fresh
         served = []
         for lo, hi in [(0, prefill)] + [(i, i + 1) for i in range(prefill, len(tokens))]:
             logits, cache = jitted(*lead, cache, jnp.asarray(tokens[None, lo:hi]),
@@ -186,9 +280,24 @@ def _served_logits(engine, model_cfg, tokens, prefill: int, cache_rows: int,
         return np.concatenate(served, axis=0)
 
     if layer_inputs is None:
-        return through_cache(engine.params)
-    return np.stack([through_cache(engine.params, layer_inputs[n], jnp.int32(n))
-                     for n in range(layer_inputs.shape[0])])
+        return through_cache(jax.jit(stepper(model_cfg)), fresh(model_cfg), engine.params)
+
+    @functools.cache
+    def program(count, layers):
+        """The compiled program of one kind of cut, and its fresh cache."""
+        cfg = cut_config(model, model_cfg, layers)
+        step = stepper(cfg)
+
+        def sub_step(params, stream, first, *rest):
+            return step(_sub_model(params, stream, first, count, dtype), *rest)
+
+        return jax.jit(sub_step), fresh(cfg)
+
+    order = layer_order(model, model_cfg, engine.params["layers"])
+    return np.stack([
+        through_cache(*program(count, layers), engine.params, layer_inputs[n], first)
+        for n, (first, count, layers) in enumerate(
+            _cuts(order, layer_inputs.shape[0], depth))])
 
 
 def _seeded_tokens(model_cfg, seed: int, n: int):
@@ -222,6 +331,19 @@ def check(engine, model_cfg, sizes: dict, seed: int,
         and max(out["prefill_mean_over_range"], out["decode_mean_over_range"]) <= MEAN_TOL
     )
     return out
+
+
+def compared(out: dict) -> dict:
+    """name -> [number, limit] for every number of `check`'s result that was
+    held to a limit (`decided_positions` to a least, the others to a most)."""
+    limits = out.get("limits") or {"max_over_range": MAX_TOL, "mean_over_range": MEAN_TOL}
+    pairs = {}
+    for name, value in out.items():
+        key = name.replace("prefill_", "").replace("decode_", "")
+        limit = limits.get(key, limits.get(key + "_min"))
+        if limit is not None:
+            pairs[name] = [value, limit]
+    return pairs
 
 
 def decided_pairs(margin, sigma, tau: float = TAU_SIGMA):
@@ -284,25 +406,32 @@ def judge_sparse(layers_served, layers_ref, layers_plain, decided, prefill: int,
     return out
 
 
-def _untied(sizes: dict) -> dict:
-    return {**sizes, "tie_embeddings": False}  # `_sub_model` names the head
+def _cut_sizes(sizes: dict, layers) -> dict:
+    """`sizes` for a model `_sub_model` cuts (`layers` as `cut_config` takes
+    it): the head is named, and stacks come with the cut model's own order."""
+    sizes = {**sizes, "tie_embeddings": False}
+    return sizes if isinstance(layers, int) else {**sizes, "layer_order": layers}
 
 
-def reference_layers(ref_mod, params, sizes: dict, residual):
-    """Each layer alone on the stream the reference saw enter it, by the
-    reference: (float32 logits [L, T, V], served-type logits [L, T, V],
-    margin [L, T], sigma [L]) as numpy."""
+def reference_layers(ref_mod, params, sizes: dict, residual, order=None):
+    """Each model layer alone on the stream the reference saw enter it, by
+    the reference: (float32 logits [L, T, V], served-type logits [L, T, V],
+    margin [L, T], sigma [L]) as numpy. `order` as `layer_order` gives it."""
     dtype = params["embed"].dtype
     positions = jnp.arange(residual.shape[1], dtype=jnp.int32)
-    sizes = _untied(sizes)
+    @functools.cache
+    def program(count, layers):
+        cut = _cut_sizes(sizes, layers)
 
-    @jax.jit
-    def one(params, stream, layer):
-        p = _sub_model(params, stream, layer, 1, dtype)
-        logits, margin, sigma, _ = ref_mod.forward_routed(p, sizes, positions)
-        return logits, ref_mod.forward(p, sizes, positions, compute=dtype), margin[0], sigma[0]
+        def one(params, stream, first):
+            p = _sub_model(params, stream, first, count, dtype)
+            logits, margin, sigma, _ = ref_mod.forward_routed(p, cut, positions)
+            return logits, ref_mod.forward(p, cut, positions, compute=dtype), margin[0], sigma[0]
 
-    per = [one(params, residual[l], jnp.int32(l)) for l in range(residual.shape[0] - 1)]
+        return jax.jit(one)
+
+    per = [program(count, layers)(params, residual[n], first)
+           for n, (first, count, layers) in enumerate(_cuts(order, residual.shape[0] - 1, 1))]
     return tuple(np.stack([np.asarray(x[i], np.float32) for x in per]) for i in range(4))
 
 
@@ -312,6 +441,12 @@ def _check_sparse(engine, model_cfg, sizes: dict, seed: int, ref_mod,
         raise AttributeError(
             f"reference {ref_mod.__name__!r} has no forward_routed, which a "
             f"configuration with a router needs (benchmark/README.md)")
+    order = layer_order(load_model_module(model_module), model_cfg, engine.params["layers"])
+    stated = None if order is None else tuple(map(tuple, ref_mod.layer_order(sizes)))
+    if stated != order:
+        raise ValueError(
+            f"the model module {model_module!r} runs its layers as {order}, and the "
+            f"reference {ref_mod.__name__!r} reads {stated} from the configuration's file")
     tokens = _seeded_tokens(model_cfg, seed, PREFILL + DECODE)
     positions = np.arange(len(tokens), dtype=np.int32)
     dtype = engine.params["embed"].dtype
@@ -319,17 +454,18 @@ def _check_sparse(engine, model_cfg, sizes: dict, seed: int, ref_mod,
         lambda params, toks: ref_mod.forward_routed(params, sizes, toks))(
             engine.params, jnp.asarray(tokens))
     layers_ref, layers_plain, margin, sigma = reference_layers(
-        ref_mod, engine.params, sizes, residual)
+        ref_mod, engine.params, sizes, residual, order)
     layers = _served_logits(engine, model_cfg, positions, PREFILL, CACHE_ROWS,
                             layer_inputs=residual[:-1], model_module=model_module)
     pair = pair_ref = None
-    if model_cfg.num_layers >= PAIR:
+    if residual.shape[0] - 1 >= PAIR:  # the model's layers, of every stack
         pair = _served_logits(engine, model_cfg, positions, PREFILL, CACHE_ROWS,
                               layer_inputs=residual[:1], depth=PAIR,
                               model_module=model_module)[0]
+        first, count, cut = _cut(order, 0, PAIR)
         pair_ref = np.asarray(jax.jit(lambda params, stream: ref_mod.forward(
-            _sub_model(params, stream, 0, PAIR, dtype), _untied(sizes), jnp.asarray(positions)))(
-                engine.params, residual[0]), np.float32)
+            _sub_model(params, stream, first, count, dtype), _cut_sizes(sizes, cut),
+            jnp.asarray(positions)))(engine.params, residual[0]), np.float32)
     out = {"logit_range": float(whole_ref.max() - whole_ref.min())}
     out.update(judge_sparse(layers, layers_ref, layers_plain, decided_pairs(margin, sigma),
                             PREFILL, pair, pair_ref))

@@ -4,7 +4,7 @@ context was live while it was taken."""
 
 from __future__ import annotations
 
-from harness.manifest import decode_kernel, load_layer_metric
+from harness.manifest import decode_kernel, decode_kernel_layers, load_layer_metric
 from harness.metrics import late_ms
 from harness.stats import percentile
 
@@ -87,13 +87,15 @@ def kernel_in_decode(ctx):
 
 def decode_steps_in_trace(ctx) -> float | None:
     """Decode steps the traced decode modules ran: the kernel runs once a
-    layer a step, so its calls over the number of layers. (The module's
-    name does not say its chunk size, and the engine's `decode_steps`
-    counter runs ahead of the device by the chunks in flight.)"""
+    step in each layer that calls it, so its calls over the number of those
+    layers (`manifest.decode_kernel_layers`: every layer, unless the
+    configuration says which). (The module's name does not say its chunk
+    size, and the engine's `decode_steps` counter runs ahead of the device
+    by the chunks in flight.)"""
     kernel = kernel_in_decode(ctx)
     if kernel is None:
         return None
-    return kernel[0] / ctx["model"]["num_hidden_layers"]
+    return kernel[0] / decode_kernel_layers(ctx["model"])
 
 
 def decode_step_s(ctx) -> float | None:

@@ -9,6 +9,7 @@ program's model.
                     the program's model module, ModelConfig fields and decode kernel)
     cell.traffic -> traffic/<traffic>.json   (its `generator` -> generators/<g>.py)
     cell.per_layer[] -> layer_metrics/<metric>.py
+    cell name    -> rehearsal/<name>.json    (optional; `--rehearse-cpu` only)
 
 Whatever of the program or of a model family the harness has to name is
 named here, once, as the default of a key that a configuration file may
@@ -32,6 +33,9 @@ DEFAULT_REFERENCE = "llama_ref"                 # reference/<name>.py
 DEFAULT_DECODE_BYTES = "llama_bytes"            # decode_bytes/<name>.py
 DEFAULT_MODEL_MODULE = "omnia_tpu.models.llama"
 DEFAULT_DECODE_KERNEL = "decode_gqa_attention"  # ops/decode_attention.py, as XLA prints it
+# The file's key that counts the layers which call that kernel once a step:
+# every layer, unless the model's layers are of several kinds.
+DEFAULT_DECODE_KERNEL_LAYERS = "num_hidden_layers"
 # ModelConfig field -> (the file's key, cast[, default where the key may be absent]).
 LLAMA_KEYS = {
     "vocab_size": ("vocab_size", int),
@@ -135,6 +139,31 @@ def decode_kernel(model: dict) -> str:
     return model.get("program", {}).get("decode_kernel", DEFAULT_DECODE_KERNEL)
 
 
+def decode_kernel_layers(model: dict) -> int:
+    """How many layers of the configuration whose file `model` is call
+    `decode_kernel(model)` once a step: the file's number under the key that
+    `program.decode_kernel_layers` names (default `num_hidden_layers`: every
+    layer does). A model whose layers are of several kinds names the count
+    of the kind that calls it."""
+    key = model.get("program", {}).get("decode_kernel_layers", DEFAULT_DECODE_KERNEL_LAYERS)
+    if key not in model:
+        raise KeyError(f"program.decode_kernel_layers names {key!r}, which the "
+                       f"configuration's file does not hold")
+    return int(model[key])
+
+
+def program_scopes(model: dict) -> dict:
+    """The named scopes the configuration whose file `model` is adds to
+    `spans.SCOPES` for its cells (`program.scopes`, default none): name ->
+    "ops", or "scan" for one that wraps a `lax.scan`, so that an op directly
+    under it reads as `<name>.scan_io`."""
+    scopes = model.get("program", {}).get("scopes", {})
+    wrong = {k: v for k, v in scopes.items() if v not in ("ops", "scan")}
+    if wrong:
+        raise ValueError(f"program.scopes: each name says \"ops\" or \"scan\", not {wrong}")
+    return dict(scopes)
+
+
 def load_layer_metric(name: str):
     mod = _load_module("layer_metrics", name)
     for attr in ("LAYER", "UNIT", "MOVES", "SOURCE", "BETTER", "read"):
@@ -199,6 +228,18 @@ class Cell:
                     f"layer metric {metric}: its file says {mine}, "
                     f"BENCHMARK.json says {theirs}")
             self.layer_metrics.append((metric, mod))
+
+    def rehearse(self) -> None:
+        """Sandbox rehearsal only: lay `rehearsal/<cell>.json`'s `engine` and
+        `traffic` over the cell's, where that file exists (a cell whose slots
+        x rows the CPU cannot hold names smaller ones there, and lengths that
+        fit them). Without the file the cell's own are run."""
+        try:
+            over = _load_json(_path("rehearsal", self.name + ".json"))
+        except FileNotFoundError:
+            return
+        self.engine = {**self.engine, **over.get("engine", {})}
+        self.traffic = {**self.traffic, **over.get("traffic", {})}
 
     def config_as_run(self, rehearse: bool = False) -> dict:
         """The configuration file's keys as run: under `rehearse` with the

@@ -30,10 +30,6 @@ def ttft_p50_ms(ctx):
     return _pct(_ttft_ms(ctx["records"]), 50)
 
 
-def ttft_p95_ms(ctx):
-    return _pct(_ttft_ms(ctx["records"]), 95)
-
-
 def gap_p95_ms(ctx):
     return _pct(_gaps_ms(ctx["records"]), 95)
 
@@ -52,7 +48,7 @@ def setup_s(ctx):
 
 
 END_TO_END = {f.__name__: f for f in
-              (ttft_p50_ms, ttft_p95_ms, gap_p95_ms, out_tokens_per_s_chip, setup_s)}
+              (ttft_p50_ms, gap_p95_ms, out_tokens_per_s_chip, setup_s)}
 
 
 def describe_lengths(records) -> dict:

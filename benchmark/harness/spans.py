@@ -17,7 +17,8 @@ with its scope, and `reduce` (pure Python) gives
 A trace of a program without the spans or the scopes reduces to empty
 tables, and every reader then returns None.
 
-By hand, for a directory that `run.py --trace 1` left behind:
+By hand, for a directory that `run.py --trace 1` left behind (the scopes
+are those of the cell the directory is named after, else the default set):
 
     python3 benchmark/harness/spans.py .bench_trace/<cell>
 """
@@ -32,6 +33,7 @@ if __package__ in (None, ""):  # run as a script: this directory is sys.path[0]
     sys.path[0] = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from harness import trace as tr
+from harness.manifest import Cell, program_scopes
 
 HOST_PREFIX = "omnia."
 STEP = tr.ENGINE_STEP
@@ -45,9 +47,11 @@ SCOPES = frozenset({
     "attn.qkv", "attn.rope", "kv.update", "attn.decode", "attn.prefill",
     "attn.out", "mlp", "moe.route", "moe.experts",
 })
-# An op directly under `layers` is one that `lax.scan` emitted itself: its
-# slice of the layer's operands out of the stacked arrays and the write-back
-# of the layer's results. No named scope can be put around those.
+# An op directly under a scope that wraps a `lax.scan` (`layers`, and what a
+# configuration's `program.scopes` calls "scan") is one that the scan emitted
+# itself: its slice of the layer's operands out of the stacked arrays and the
+# write-back of the layer's results. No named scope can be put around those.
+SCANS = frozenset({"layers"})
 SCAN_IO = "layers.scan_io"
 UNSCOPED = "unscoped"
 # An op without an op name is one the compiler put in itself. Its copies
@@ -56,13 +60,21 @@ UNSCOPED = "unscoped"
 XLA_COPY = "xla.copy"
 
 
-def scope_of(op_name: str) -> str:
+def scopes_of(model: dict | None = None) -> tuple:
+    """(names, scans): the program's scopes as the cells of the configuration
+    whose file `model` is read them: the default set and what the file's
+    `program.scopes` adds to it; `scans` are those that wrap a `lax.scan`."""
+    own = program_scopes(model or {})
+    return SCOPES | set(own), SCANS | {n for n, kind in own.items() if kind == "scan"}
+
+
+def scope_of(op_name: str, scopes=SCOPES, scans=SCANS) -> str:
     """`jit(decode_chunk)/while/body/closed_call/layers/while/body/
     closed_call/kv.update/scatter` -> `kv.update`: the innermost of the
-    program's scopes on the path."""
+    program's scopes on the path; `<name>.scan_io` where that wraps a scan."""
     for part in reversed(op_name.rstrip(":").split("/")):
-        if part in SCOPES:
-            return SCAN_IO if part == "layers" else part
+        if part in scopes:
+            return part + ".scan_io" if part in scans else part
     return UNSCOPED
 
 
@@ -147,14 +159,16 @@ def op_names(xplane_path: str) -> dict:
     return out
 
 
-def load(trace_dir: str) -> dict:
+def load(trace_dir: str, model: dict | None = None) -> dict:
     """`trace.load_xplane`'s scheme with a fourth element on each event:
     {"planes": [{"name", "lines": [{"name", "events": [[name, start_ns,
     duration_ns, extra], ...]}]}]}. `extra` is the span's attributes on a
     host line (only `omnia.*` spans are kept) and the op's scope on a
-    device line (None on the modules' line)."""
+    device line (None on the modules' line), read with the scopes of the
+    configuration whose file `model` is (`scopes_of`)."""
     from jax.profiler import ProfileData
 
+    known = scopes_of(model)
     path = tr.find_xplane(trace_dir)
     names = op_names(path)
     data = ProfileData.from_file(path)
@@ -164,7 +178,7 @@ def load(trace_dir: str) -> dict:
         lines = []
         for line in plane.lines:
             if device and line.name == tr.OPS_LINE:
-                scopes = {n: scope_of(o) for n, o in names.get(plane.name, {}).items()}
+                scopes = {n: scope_of(o, *known) for n, o in names.get(plane.name, {}).items()}
                 evs = []
                 for e in line.events:
                     short = tr.short_name(e.name)
@@ -260,7 +274,7 @@ def reduced(ctx: dict):
     directory, made once a run; None where the run was not traced."""
     if "spans" not in ctx:
         traced = ctx.get("traced")
-        ctx["spans"] = reduce(load(traced["dir"])) if traced else None
+        ctx["spans"] = reduce(load(traced["dir"], ctx.get("model"))) if traced else None
     return ctx["spans"]
 
 
@@ -325,7 +339,11 @@ def main(argv=None) -> int:
     if len(argv) != 1:
         print(__doc__)
         return 2
-    print(tables(reduce(load(argv[0]))))
+    try:
+        model = Cell(os.path.basename(os.path.normpath(argv[0]))).model
+    except KeyError:  # not a cell's directory: the default scopes
+        model = None
+    print(tables(reduce(load(argv[0], model))))
     return 0
 
 

@@ -52,6 +52,8 @@ def main(argv=None) -> int:
     from harness.manifest import Cell, load_generator, reference_sizes, served_by
 
     cell = Cell(args.workload)
+    if args.rehearse_cpu:
+        cell.rehearse()  # rehearsal/<cell>.json, where a cell's slots x rows need it
     if args.rate is not None:
         cell.traffic["rate_rps"] = args.rate
 
@@ -234,6 +236,12 @@ def main(argv=None) -> int:
             value = mt.END_TO_END[m["name"]](ctx)
             if value is not None:
                 result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
+    # Each number `correct` compared, beside its limit: last on stderr, and
+    # last in the line.
+    result["compared"] = {**correct.compared(ref), "failed": [len(failed), 0],
+                          "compiled_in_window": [compiled_in_window, 0]}
+    for name, (value, limit) in result["compared"].items():
+        print(f"[bench] compared {name}: {value!r} (limit {limit!r})", file=sys.stderr, flush=True)
     if args.rehearse_cpu:
         log("REHEARSAL line (not a result): " + json.dumps(result))
         return 3

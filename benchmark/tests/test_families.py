@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 import harness.manifest as mf
+from fixture_family import as_a_model_config_pr
 from harness import correct, roofline, trace as tr
 from harness.layer_common import DECODE_MODULE, decode_steps_in_trace, kernel_in_decode
 from harness.load import Record
@@ -31,37 +32,13 @@ if HERE not in sys.path:  # the fixture's model module is `latent_family.model`
 GQA_ONLY = "batch.decode_gqa_attention_roofline"  # a kernel's own roofline lists its configurations' cells
 
 
-def _batch_lists(bench) -> set:
-    """The metrics that list the closed loop's cell: a new closed-loop cell
-    adds its name to each but the GQA kernel's own."""
-    return {m["name"] for m in bench["end_to_end"] + bench["per_layer"]
-            if "mistral-7b.eval-batch" in m.get("workloads", ())} - {GQA_ONLY}
-
-
 @pytest.fixture
 def family(monkeypatch):
     """BENCHMARK.json as a `model_config` PR for the fixture family would
     leave it: two `configs` entries, two `workloads` entries and the cells'
-    names on the closed loop's lists. Nothing under harness/ is touched."""
-    bench = mf.benchmark_json()
-    for name in ("latent-tiny", "latent-sparse"):
-        bench["configs"].append({
-            "name": name, "source": "fixture", "reduced": [], "why": "fixture",
-            "file": os.path.relpath(os.path.join(FIXTURE, "configs", name + ".json"), mf.ROOT)})
-        bench["workloads"].append({"name": name + ".eval-batch", "config": name,
-                                   "traffic": "eval-batch", "chips": 1, "why": "fixture"})
-        for m in bench["end_to_end"] + bench["per_layer"]:
-            if m["name"] in _batch_lists(bench):
-                m["workloads"].append(name + ".eval-batch")
-    monkeypatch.setattr(mf, "benchmark_json", lambda: json.loads(json.dumps(bench)))
-    own = mf._path
-
-    def path(kind, filename):
-        fixture = os.path.join(FIXTURE, kind, filename)
-        return fixture if os.path.exists(fixture) else own(kind, filename)
-
-    monkeypatch.setattr(mf, "_path", path)
-    return bench
+    names on the closed loop's lists but the GQA kernel's own."""
+    return as_a_model_config_pr(monkeypatch, FIXTURE, ("latent-tiny", "latent-sparse"),
+                                like="mistral-7b.eval-batch", but=(GQA_ONLY,))
 
 
 def _with_config(monkeypatch, tmp_path, change):

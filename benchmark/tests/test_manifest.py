@@ -86,11 +86,11 @@ def test_a_cell_that_lists_a_metric_must_report_what_it_moves(bench, monkeypatch
 
     broken = json.loads(json.dumps(bench))
     for m in broken["end_to_end"]:
-        if m["name"] == "ttft_p95_ms":  # every metric that moves it lists its cells
+        if m["name"] == "ttft_p50_ms":  # every metric that moves it lists its cells
             m["workloads"] = ["mistral-7b.eval-batch"]
     monkeypatch.setattr(mf, "benchmark_json", lambda: broken)
-    with pytest.raises(ValueError, match="does not report ttft_p95_ms"):
-        Cell("mistral-7b.chat-steady")
+    with pytest.raises(ValueError, match="does not report ttft_p50_ms"):
+        Cell("mistral-7b.longprompt-steady")
 
 
 def test_a_metric_without_a_list_is_due_where_what_it_moves_is_reported(bench):
