@@ -63,6 +63,26 @@ class ModelConfig:
     num_experts_held: int = 0
     expert_rank: int = 0
     routed_scaling_factor: float = 1.0
+    # The router's score of an expert, "softmax" over all of them or
+    # "sigmoid" each on its own, and how the k are picked: "greedy" by the
+    # score, "noaux_tc" by the score plus a selection bias an expert (the
+    # weights are the scores themselves either way).
+    router_scoring: str = "softmax"
+    router_topk_method: str = "greedy"
+    # Of num_layers, the leading ones whose FFN is dense (ffn_hidden_size
+    # wide, no router); the rest hold experts. With any, the latent
+    # family's params["layers"] is two stacks, (dense, sparse).
+    num_dense_layers: int = 0
+    # Hyper-connections (ops/hyper_connections.py): a token's residual is
+    # residual_copies copies of hidden_size, 1 = the plain residual; the
+    # mixing matrix is exp(clip(·, hc_res_clamp_min, hc_res_clamp_max))
+    # made doubly stochastic by hc_sinkhorn_iters Sinkhorn iterations, each
+    # sum plus hc_eps.
+    residual_copies: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp_min: float = -30.0
+    hc_res_clamp_max: float = 30.0
 
     @property
     def q_dim(self) -> int:
@@ -83,6 +103,10 @@ class ModelConfig:
     @property
     def experts_held(self) -> int:
         return self.num_experts_held or self.num_experts
+
+    @property
+    def router_bias(self) -> bool:
+        return self.router_topk_method == "noaux_tc"
 
     def num_params(self) -> int:
         """Approximate parameter count (for memory planning)."""
@@ -205,6 +229,38 @@ PRESETS: dict[str, ModelConfig] = {
         num_shared_experts=1,
         num_experts_held=4,
         expert_rank=1,
+    ),
+    # test-tiny-mla's attention behind a residual of four copies: one leading
+    # dense layer, then two sparse ones that hold all 8 experts, a sigmoid
+    # router with a selection bias.
+    "test-tiny-hc": ModelConfig(
+        name="test-tiny-hc",
+        vocab_size=256,
+        hidden_size=64,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        ffn_hidden_size=128,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        num_experts=8,
+        num_experts_per_tok=2,
+        max_seq_len=512,
+        kv_rank=32,
+        q_rank=48,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        rope_yarn=(16.0, 64, 32.0, 1.0, 1.0, 1.0),
+        rope_interleave=True,
+        moe_ffn_hidden_size=32,
+        num_shared_experts=1,
+        routed_scaling_factor=2.0,
+        router_scoring="sigmoid",
+        router_topk_method="noaux_tc",
+        num_dense_layers=1,
+        residual_copies=4,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

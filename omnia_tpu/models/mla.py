@@ -1,5 +1,8 @@
 """Latent-attention (MLA) transformer with a dropless expert layer that
-holds a share of the routed experts beside a shared expert, functional JAX.
+holds a share of the routed experts beside a shared expert, functional JAX;
+optionally with leading dense layers (two stacks), a residual stream of
+several copies mixed by hyper-connections, and a sigmoid router with a
+selection bias.
 
 The block, for a layer with input ``x`` [T, D] (benchmark/reference/
 mla_moe_ref.py is the same mathematics in plain float32):
@@ -13,6 +16,34 @@ mla_moe_ref.py is the same mathematics in plain float32):
   causal softmax, ``o = Σ p·v``; ``x ← x + o·Wo``.
 - ``h2 = rms(x; ln2)``; router logits in float32, softmax over all E, the k
   largest renormalised; ``x ← x + Σ_{e ∈ top-k ∩ held} w_e·ffn_e(h2) + ffn_shared(h2)``.
+
+Three things a model of this family may have besides, each off by default
+and then tracing none of its code (benchmark/reference/xing4_ref.py is their
+mathematics in plain float32):
+
+- **Stacks of unlike layers** (``cfg.num_dense_layers`` > 0): the leading
+  layers have a dense SwiGLU of ``ffn_hidden_size`` where the rest have the
+  router and the experts. ``params["layers"]`` is then the sequence ``[dense,
+  sparse]``, a tree each with its own leading layer axis, and ``layer_order`` /
+  ``with_layer_order`` state and cut the order as benchmark/README.md ("`layers`:
+  one tree, or stacks") sets out. A forward pass is two scans in that order
+  under the scopes ``stack.dense`` and ``stack.sparse`` (one tree: one scan under
+  ``layers``), the cache carried through both; a sparse layer's cache index is
+  its index in the stack plus the dense count, its experts' index the stack's
+  own. A stack that a cut model is left with none of is skipped.
+- **A stream of n copies** (``cfg.residual_copies`` = n > 1): a token's residual
+  is ``X`` [n, D], carried as ``[B, T, n·D]``. An embedding row is copied n times
+  (a table already n·D wide is the stream itself), each sublayer f sits
+  between ``hc.pre`` and ``hc.post`` (ops/hyper_connections.py: ``u = Σ h_pre[i]·X[i]``,
+  ``X'[i] = Σ_j H_res[i, j]·X[j] + h_post[i]·f(rms(u))`` with ``H_res`` made doubly
+  stochastic by Sinkhorn) instead of ``x + f(rms(x))``, and after the last layer
+  the copies are summed before the final norm. Scopes ``hc.mix``, ``hc.maps``,
+  ``hc.sinkhorn``.
+- **A router that scores by sigmoid and picks with a bias**
+  (``cfg.router_scoring``, ``cfg.router_topk_method`` "noaux_tc"): ``s = σ(h2·Wr)``,
+  the k experts with the largest ``s + b_sel`` (``mlp/bias`` [E], float32), weights
+  ``s`` at those k renormalised, times ``routed_scaling_factor``
+  (ops/moe.py::top_k_weights).
 
 **The cache is one array** ``[L, B, S, W]``: a token's row is ``[c | k_rope |
 0]``, W the next multiple of 128 (ops/decode_mla_attention.py says why).
@@ -47,11 +78,14 @@ step, sp, tp/dp > 1.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from omnia_tpu.models.config import ModelConfig
+from omnia_tpu.ops import hyper_connections as hc
 from omnia_tpu.ops.attention import _kernel_on, _pallas_decode_mode
 from omnia_tpu.ops.decode_mla_attention import block_rows, decode_mla_attention
 from omnia_tpu.ops.moe import moe_dropless
@@ -86,54 +120,108 @@ def row_width(cfg: ModelConfig) -> int:
 # ---------------------------------------------------------------------------
 
 
+def layer_order(cfg: ModelConfig) -> tuple:
+    """((stack, index), ...) for model layer 0, 1, ... of a model whose
+    ``params["layers"]`` is two stacks: the leading dense layers are stack 0,
+    the sparse ones behind them stack 1 (benchmark/README.md, "`layers`: one
+    tree, or stacks")."""
+    dense = cfg.num_dense_layers
+    return (tuple((0, i) for i in range(dense))
+            + tuple((1, i) for i in range(cfg.num_layers - dense)))
+
+
+def with_layer_order(cfg: ModelConfig, order) -> ModelConfig:
+    """The same model with the layers ``order`` names: its own order over
+    the cut stacks, either of which may be left with none."""
+    dense = sum(stack == 0 for stack, _ in order)
+    cut = dataclasses.replace(cfg, num_layers=len(order), num_dense_layers=dense)
+    if tuple(map(tuple, order)) != layer_order(cut):
+        raise ValueError(f"{order}: this family runs its dense layers first, then its sparse")
+    return cut
+
+
+def _hc_constants(cfg: ModelConfig) -> dict:
+    return {"iters": cfg.hc_sinkhorn_iters, "eps": cfg.hc_eps,
+            "clamp": (cfg.hc_res_clamp_min, cfg.hc_res_clamp_max),
+            "norm_eps": cfg.rms_norm_eps}
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
     """Random-initialized parameter pytree (layers stacked on axis 0); of
-    the routed experts only the held share exists."""
+    the routed experts only the held share exists. ``layers`` is one tree
+    of sparse layers, or with leading dense layers the two stacks ``[dense,
+    sparse]`` (``layer_order``). A model with a selection bias has it as
+    ``mlp/bias`` [E] float32 beside the router; one with several residual
+    copies has ``hc/{attn, mlp}/{phi, bias, alpha}`` in every layer
+    (ops/hyper_connections.py): Φ ~ N(0, 1/(n·D)), so that x̄·Φ has unit
+    variance at any width, α_pre = α_post = 0.5, α_res = 0.3, b_pre, b_post
+    ~ N(0, 0.5), B_res = 1.5·I + N(0, 0.3): H_res leans to the identity
+    (diagonal about 0.6 of 4 copies) without being it, and 20 Sinkhorn
+    iterations bring its columns within 1e-4 of 1 (a sharper diagonal does
+    not converge that far)."""
     L, D, V, H = cfg.num_layers, cfg.hidden_size, cfg.vocab_size, cfg.num_heads
     R, Rq = cfg.kv_rank, cfg.q_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    F, Eh = cfg.moe_ffn_hidden_size, cfg.experts_held
+    F, Eh, n = cfg.moe_ffn_hidden_size, cfg.experts_held, cfg.residual_copies
     Fs = cfg.num_shared_experts * F
+    dense, sparse = cfg.num_dense_layers, cfg.num_layers - cfg.num_dense_layers
     keys = iter(jax.random.split(key, 16))
+    # What a model of one tree, one copy and no bias has no use for draws
+    # from keys of its own, so that model's weights are what they were.
+    more = iter(jax.random.split(jax.random.fold_in(key, 1), 24))
     out_std = 0.02 / (2 * L) ** 0.5
 
-    def normal(shape, std=0.02):
-        return (jax.random.normal(next(keys), shape, dtype=jnp.float32) * std).astype(dtype)
+    def normal(shape, std=0.02, keys=keys, dtype=dtype, mean=0.0):
+        return (mean + jax.random.normal(next(keys), shape, dtype=jnp.float32) * std).astype(dtype)
 
     def ones(*shape):
         return jnp.ones(shape, dtype=dtype)
 
-    mlp = {
-        "router": normal((L, D, cfg.num_experts)),
-        "wg": normal((L, Eh, D, F)),
-        "wu": normal((L, Eh, D, F)),
-        "wd": normal((L, Eh, F, D), std=out_std),
-    }
-    if Fs:
-        mlp["shared"] = {
-            "wg": normal((L, D, Fs)),
-            "wu": normal((L, D, Fs)),
-            "wd": normal((L, Fs, D), std=out_std),
+    def swiglu(lead, width, keys):
+        return {
+            "wg": normal((*lead, D, width), keys=keys),
+            "wu": normal((*lead, D, width), keys=keys),
+            "wd": normal((*lead, width, D), std=out_std, keys=keys),
         }
-    return {
-        "embed": normal((V, D)),
-        "layers": {
-            "ln1": ones(L, D),
-            "ln2": ones(L, D),
-            "attn": {
-                "wqa": normal((L, D, Rq)),
-                "qn": ones(L, Rq),
-                "wqb": normal((L, Rq, H * (dn + dr))),
-                "wkva": normal((L, D, R + dr)),
-                "kvn": ones(L, R),
-                "wkvb": normal((L, R, H * (dn + dv))),
-                "wo": normal((L, H * dv, D), std=out_std),
-            },
-            "mlp": mlp,
-        },
-        "final_norm": ones(D),
-        "lm_head": normal((D, V)),
-    }
+
+    def attention(c, keys):
+        return {
+            "wqa": normal((c, D, Rq), keys=keys),
+            "qn": ones(c, Rq),
+            "wqb": normal((c, Rq, H * (dn + dr)), keys=keys),
+            "wkva": normal((c, D, R + dr), keys=keys),
+            "kvn": ones(c, R),
+            "wkvb": normal((c, R, H * (dn + dv)), keys=keys),
+            "wo": normal((c, H * dv, D), std=out_std, keys=keys),
+        }
+
+    def hyper(c):
+        if n == 1:
+            return {}
+
+        def maps():
+            f32 = {"keys": more, "dtype": jnp.float32}
+            b_res = 1.5 * jnp.eye(n).reshape(n * n) + normal((c, n * n), 0.3, **f32)
+            return {"phi": normal((c, n * D, 2 * n + n * n), (n * D) ** -0.5, keys=more),
+                    "bias": jnp.concatenate([normal((c, 2 * n), 0.5, **f32), b_res], axis=-1),
+                    "alpha": jnp.tile(jnp.asarray([0.5, 0.5, 0.3], jnp.float32), (c, 1))}
+
+        return {"hc": {"attn": maps(), "mlp": maps()}}
+
+    mlp = {"router": normal((sparse, D, cfg.num_experts)), **swiglu((sparse, Eh), F, keys)}
+    if Fs:
+        mlp["shared"] = swiglu((sparse,), Fs, keys)
+    embed = normal((V, D))
+    stack = {"ln1": ones(sparse, D), "ln2": ones(sparse, D), "attn": attention(sparse, keys),
+             "mlp": mlp}
+    lm_head = normal((D, V))
+    if cfg.router_bias:
+        mlp["bias"] = normal((sparse, cfg.num_experts), 0.05, keys=more, dtype=jnp.float32)
+    stack.update(hyper(sparse))
+    if dense:
+        stack = [{"ln1": ones(dense, D), "ln2": ones(dense, D), "attn": attention(dense, more),
+                  "mlp": swiglu((dense,), cfg.ffn_hidden_size, more), **hyper(dense)}, stack]
+    return {"embed": embed, "layers": stack, "final_norm": ones(D), "lm_head": lm_head}
 
 
 def param_specs(cfg: ModelConfig):
@@ -260,41 +348,57 @@ def _unstack_experts(layers):
     """(what the layer scan slices a layer at a time, the routed experts'
     three stacks [L, Eh, …] whole): the experts are nine tenths of a
     layer's bytes and a step needs only those a token chose, so the scan
-    must not slice a layer's out (ops/moe.py::_grouped_matmul)."""
+    must not slice a layer's out (ops/moe.py::_grouped_matmul). Of two
+    stacks this is the sparse one's; a dense layer's FFN is read whole."""
     mlp = layers["mlp"]
     scanned = {**layers, "mlp": {k: v for k, v in mlp.items() if k not in _EXPERT_STACKS}}
     return scanned, {k: mlp[k] for k in _EXPERT_STACKS}
 
 
+def _swiglu(h, p):
+    return jnp.dot(jax.nn.silu(jnp.dot(h, p["wg"])) * jnp.dot(h, p["wu"]), p["wd"])
+
+
 def _experts(h2, p, experts, layer, cfg: ModelConfig):
-    """The routed experts held here (``experts``: their stacks over all
-    layers, of which ``layer``'s are used) and the shared expert. h2
-    [B, T, D] → (y [B, T, D], counts int32 [2] as DECODE_COUNTERS)."""
+    """The routed experts held here (``experts``: their stacks over the
+    sparse layers, of which ``layer``'s are used) and the shared expert. h2
+    [B, T, D] → (y [B, T, D], counts int32 [2] as DECODE_COUNTERS). A dense
+    layer (``experts`` None) is one SwiGLU and counts nothing."""
+    if experts is None:
+        return _swiglu(h2, p), jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
     B, T, D = h2.shape
+    router = {k: p[k] for k in ("router", "bias") if k in p}
     y, held, hit = moe_dropless(
-        h2.reshape(B * T, D), {"router": p["router"], **experts},
+        h2.reshape(B * T, D), {**router, **experts},
         cfg.num_experts_per_tok,
         first_expert=cfg.expert_rank * cfg.experts_held,
         routed_scaling_factor=cfg.routed_scaling_factor, layer=layer,
+        scoring=cfg.router_scoring,
     )
     y = y.reshape(B, T, D)
     if "shared" in p:
         with jax.named_scope("moe.shared"):
-            s = p["shared"]
-            y = y + jnp.dot(jax.nn.silu(jnp.dot(h2, s["wg"])) * jnp.dot(h2, s["wu"]), s["wd"])
+            y = y + _swiglu(h2, p["shared"])
     return y, jnp.stack([held, hit])
 
 
-def _layer(x, p, experts, layer, cfg: ModelConfig, cos, sin, q_scale, q_positions,
-           cache, write_start, live=None):
-    """One block, ``layer`` its index. With a cache, ``cache`` is the
-    WHOLE [L, B, S, W]: the new rows are written in place and attention
+def _layer(x, p, experts, at, cfg: ModelConfig, cos, sin, q_scale, q_positions,
+           cache, write_start, live=None, first=0):
+    """One block, ``at`` its index in its stack and ``first + at`` in the
+    model and the cache (one tree: the same). With a cache, ``cache`` is
+    the WHOLE [L, B, S, W]: the new rows are written in place and attention
     reads that layer where it lies. Without one (fresh prefill) attention
-    runs over the chunk's own rows, which are returned. ``experts`` are
-    the routed experts' stacks over all layers (``_unstack_experts``)."""
+    runs over the chunk's own rows, which are returned. ``experts`` are the
+    routed experts' stacks over the stack's layers (``_unstack_experts``),
+    None for a dense layer. With several residual copies x is the stream
+    [B, T, n·D], and each sublayer sits between ``hc.pre`` and ``hc.post``
+    instead of ``x + f(x)``."""
     B, T, _ = x.shape
+    n = cfg.residual_copies
+    layer = first + at if first else at
+    u, mixes = hc.pre(x, p["hc"]["attn"], n, **_hc_constants(cfg)) if n > 1 else (x, None)
     with jax.named_scope("attn.qkv"):  # attn.q_lora and attn.kv_latent inside
-        h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
+        h = rms_norm(u, p["ln1"], cfg.rms_norm_eps)
         q_nope, q_rope, row = _queries_and_row(h, p["attn"], cfg, cos, sin, q_scale)
     if cache is None:
         rows, kept = row, row
@@ -312,18 +416,23 @@ def _layer(x, p, experts, layer, cfg: ModelConfig, cos, sin, q_scale, q_position
             attn = _expanded_attention(q_nope, q_rope, rows, p["attn"]["wkvb"], cfg,
                                        q_positions)
     with jax.named_scope("attn.out"):
-        x = x + jnp.dot(attn, p["attn"]["wo"])
+        out = jnp.dot(attn, p["attn"]["wo"])
+        x = x + out if mixes is None else hc.post(x, out, mixes)
+    u, mixes = hc.pre(x, p["hc"]["mlp"], n, **_hc_constants(cfg)) if n > 1 else (x, None)
     with jax.named_scope("mlp"):  # moe.route/sort/experts/combine/shared inside
-        h2 = rms_norm(x, p["ln2"], cfg.rms_norm_eps)
-        y, counts = _experts(h2, p["mlp"], experts, layer, cfg)
-    return x + y, kept, counts
+        h2 = rms_norm(u, p["ln2"], cfg.rms_norm_eps)
+        y, counts = _experts(h2, p["mlp"], experts, at, cfg)
+    return (x + y if mixes is None else hc.post(x, y, mixes)), kept, counts
 
 
 def _embed(params, cfg: ModelConfig, tokens, q_positions):
-    """Token embeddings, the rotary tables of their positions and the
+    """Token embeddings (with several residual copies: the stream they
+    start, ``hc.expand``), the rotary tables of their positions and the
     position-dependent query scale (None where the model has none)."""
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
+        if cfg.residual_copies > 1:
+            x = hc.expand(x, cfg.residual_copies, cfg.hidden_size)
         cos, sin = _rotary(cfg, q_positions)
         q_scale = None
         if cfg.q_scaling_beta:
@@ -336,8 +445,31 @@ def _embed(params, cfg: ModelConfig, tokens, q_positions):
 
 def _logits(params, cfg: ModelConfig, x):
     with jax.named_scope("lm_head"):
+        if cfg.residual_copies > 1:
+            x = hc.fold(x, cfg.residual_copies)
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         return jnp.dot(x, params["lm_head"]).astype(jnp.float32)
+
+
+def _scans(params, cfg: ModelConfig):
+    """The layer scans a forward pass makes, in the model's order: (scope,
+    what the scan runs over: the layers' parameters, sliced a layer at a time,
+    and their indices in the stack; the routed experts' stacks or None; the
+    stack's first layer in the model). One tree is one scan under ``layers``;
+    two stacks are ``stack.dense`` and ``stack.sparse``, and a stack a cut
+    model is left with none of is skipped."""
+    def scan(scope, scanned, experts, first):
+        count = scanned["ln1"].shape[0]
+        return scope, (scanned, jnp.arange(count, dtype=jnp.int32)), experts, first
+
+    layers = params["layers"]
+    if not isinstance(layers, (list, tuple)):
+        return [scan("layers", *_unstack_experts(layers), 0)]
+    dense, sparse = layers
+    scans = [scan("stack.dense", dense, None, 0)] if cfg.num_dense_layers else []
+    if cfg.num_layers > cfg.num_dense_layers:
+        scans.append(scan("stack.sparse", *_unstack_experts(sparse), cfg.num_dense_layers))
+    return scans
 
 
 # ---------------------------------------------------------------------------
@@ -352,17 +484,19 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions):
     tokens, q_positions: int32 [B, T]. Returns (logits [B, T, V] f32,
     chunk [L, B, T, W])."""
     x, cos, sin, q_scale = _embed(params, cfg, tokens, q_positions)
-    scanned, experts = _unstack_experts(params["layers"])
+    chunks = []
+    for scope, layers, experts, first in _scans(params, cfg):
 
-    def body(x, scanned):
-        p, layer = scanned
-        x, row, _ = _layer(x, p, experts, layer, cfg, cos, sin, q_scale, q_positions,
-                           None, None)
-        return x, row
+        def body(x, scanned, experts=experts, first=first):
+            p, at = scanned
+            x, row, _ = _layer(x, p, experts, at, cfg, cos, sin, q_scale, q_positions,
+                               None, None, first=first)
+            return x, row
 
-    layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    with jax.named_scope("layers"):
-        x, chunk = jax.lax.scan(body, x, (scanned, layers))
+        with jax.named_scope(scope):
+            x, chunk = jax.lax.scan(body, x, layers)
+        chunks.append(chunk)
+    chunk = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=0)
     return _logits(params, cfg, x), chunk
 
 
@@ -375,24 +509,22 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache, write_start,
     or None, the slots whose logits the caller will use; the decode
     kernel skips the others. Returns (logits [B, T, V] f32, cache), and
     with ``counters`` a third: int32 [len(DECODE_COUNTERS)], summed over
-    the layers.
+    the layers that have a router.
     """
     del mesh  # one chip a replica: nothing here is sharded
     x, cos, sin, q_scale = _embed(params, cfg, tokens, q_positions)
-    scanned, experts = _unstack_experts(params["layers"])
+    scans = _scans(params, cfg)
+    counts = jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
+    for scope, layers, experts, first in scans:
 
-    def body(carry, scanned):
-        x, cache, counts = carry
-        p, layer = scanned
-        x, cache, c = _layer(x, p, experts, layer, cfg, cos, sin, q_scale, q_positions,
-                             cache, write_start, live=live)
-        return (x, cache, counts + c), None
+        def body(carry, scanned, experts=experts, first=first):
+            x, cache, counts = carry
+            p, at = scanned
+            x, cache, c = _layer(x, p, experts, at, cfg, cos, sin, q_scale, q_positions,
+                                 cache, write_start, live=live, first=first)
+            return (x, cache, counts + c), None
 
-    layers = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    zero = jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
-    with jax.named_scope("layers"):
-        (x, cache, counts), _ = jax.lax.scan(
-            body, (x, cache, zero), (scanned, layers)
-        )
+        with jax.named_scope(scope):
+            (x, cache, counts), _ = jax.lax.scan(body, (x, cache, counts), layers)
     logits = _logits(params, cfg, x)
     return (logits, cache, counts) if counters else (logits, cache)

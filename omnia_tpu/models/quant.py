@@ -56,14 +56,22 @@ def is_quantized(w) -> bool:
     return isinstance(w, dict) and ("w8" in w or "w8d" in w)
 
 
+def _probe(params):
+    """The query projection the int8 path would have quantized, or None:
+    ``params["layers"]`` is one tree or a sequence of stacks (models/mla.py)."""
+    layers = params.get("layers", {})
+    stacks = layers if isinstance(layers, (list, tuple)) else (layers,)
+    return next((s["attn"]["wq"] for s in stacks if "wq" in s.get("attn", {})), None)
+
+
 def params_quantized(params) -> bool:
     """True if the param pytree already carries quantized matmul weights."""
-    return is_quantized(params.get("layers", {}).get("attn", {}).get("wq"))
+    return is_quantized(_probe(params))
 
 
 def detect_mode(params) -> Optional[str]:
     """The quant mode a pre-quantized tree was built with (None if dense)."""
-    wq = params.get("layers", {}).get("attn", {}).get("wq")
+    wq = _probe(params)
     if not is_quantized(wq):
         return None
     return "int8" if "w8" in wq else "int8-dynamic"

@@ -39,19 +39,36 @@ import jax
 import jax.numpy as jnp
 
 
-def route_sparse(h, router_w, num_experts_per_tok: int):
+def top_k_weights(logits, k: int, scoring: str = "softmax", bias=None):
+    """Router logits float32 [..., E] → (top_w, top_i), each [..., k]: an
+    expert's score is the softmax over all E or, ``scoring`` "sigmoid", the
+    sigmoid of its own logit; the k with the largest score are kept, with a
+    selection ``bias`` [E] the k with the largest score + bias; the weights
+    are the kept scores themselves (never score + bias), renormalised to
+    sum 1."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"router scoring {scoring!r}: softmax or sigmoid")
+    scores = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
+    if bias is None:
+        top_w, top_i = jax.lax.top_k(scores, k)
+    else:
+        top_i = jax.lax.top_k(scores + bias.astype(scores.dtype), k)[1]
+        top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+    return top_w / top_w.sum(axis=-1, keepdims=True), top_i
+
+
+def route_sparse(h, router_w, num_experts_per_tok: int, scoring: str = "softmax",
+                 bias=None):
     """Router: h [..., d] × router_w [d, E] → (top_w, top_i), each [..., K].
 
-    Mixtral semantics: float32 softmax over all experts, keep the top-k,
-    renormalize kept weights to sum 1. The single source of routing truth —
-    both MoE implementations derive from it so they can never diverge.
+    Mixtral semantics by default: float32 softmax over all experts, keep the
+    top-k, renormalize kept weights to sum 1 (``top_k_weights`` says what
+    ``scoring`` and ``bias`` change). The single source of routing truth —
+    every MoE implementation derives from it so they can never diverge.
     """
     with jax.named_scope("moe.route"):
         logits = jnp.dot(h, router_w).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_i = jax.lax.top_k(probs, num_experts_per_tok)
-        top_w = top_w / top_w.sum(axis=-1, keepdims=True)
-    return top_w, top_i
+        return top_k_weights(logits, num_experts_per_tok, scoring, bias)
 
 
 def route_topk(h, router_w, num_experts_per_tok: int):
@@ -151,7 +168,8 @@ def _grouped_matmul(xs, w, sizes, layer):
 
 
 def moe_dropless(h, p, num_experts_per_tok: int, first_expert: int = 0,
-                 routed_scaling_factor: float = 1.0, layer=None):
+                 routed_scaling_factor: float = 1.0, layer=None,
+                 scoring: str = "softmax"):
     """Dropless expert layer over the experts this chip holds.
 
     h [N, d]; ``p["router"]`` [d, E] scores all E experts; ``p["wg"]``,
@@ -159,8 +177,10 @@ def moe_dropless(h, p, num_experts_per_tok: int, first_expert: int = 0,
     ``first_expert … first_expert + Eh - 1``, the only ones here (with
     ``layer``, an index, the three are the layers' stacks [L, Eh, …] and
     the layer's are used in place: ``_grouped_matmul``). Router
-    logits in float32, softmax over all E, the k largest renormalised to
-    sum 1, times ``routed_scaling_factor``. Returns ``(out [N, d],
+    logits in float32, softmax over all E (``scoring`` "sigmoid": each
+    expert's own), the k largest (with ``p["bias"]`` [E], a selection bias:
+    the k largest of score + bias) renormalised to sum 1
+    (``top_k_weights``), times ``routed_scaling_factor``. Returns ``(out [N, d],
     held, hit)``: the weighted sum, a token, of its assignments' outputs
     on held experts (what an absent expert would add is left out: its
     chip adds it), how many of the N·k assignments landed on a held
@@ -175,8 +195,8 @@ def moe_dropless(h, p, num_experts_per_tok: int, first_expert: int = 0,
     Eh, K = p["wg"].shape[-3], num_experts_per_tok
     with jax.named_scope("moe.route"):
         logits = jnp.dot(h, p["router"], preferred_element_type=jnp.float32)
-        top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
-        top_w = top_w / top_w.sum(axis=-1, keepdims=True) * routed_scaling_factor
+        top_w, top_i = top_k_weights(logits, K, scoring, p.get("bias"))
+        top_w = top_w * routed_scaling_factor
     with jax.named_scope("moe.sort"):
         local = top_i.reshape(N * K) - first_expert      # token-major
         held = (local >= 0) & (local < Eh)

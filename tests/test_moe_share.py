@@ -12,17 +12,29 @@ import pytest
 
 from omnia_tpu.ops.moe import moe_dropless
 
-_REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                    "benchmark", "reference", "mla_moe_ref.py")
-_spec = importlib.util.spec_from_file_location("mla_moe_ref", _REF)
-ref = importlib.util.module_from_spec(_spec)  # the benchmark's plain reference
-_spec.loader.exec_module(ref)
+_REFS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                     "benchmark", "reference")
+
+
+def _reference(name):
+    spec = importlib.util.spec_from_file_location(name, os.path.join(_REFS, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)  # the benchmark's plain reference
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _reference("mla_moe_ref")
+# The router by which a family picks its experts, and the reference that states it:
+# softmax over all of them (mla_moe_ref), or each expert's own sigmoid with a
+# selection bias that picks and does not weigh (xing4_ref).
+ROUTERS = {"softmax": ("softmax", False, ref), "sigmoid-with-a-selection-bias": (
+    "sigmoid", True, _reference("xing4_ref"))}
 
 D, F, E, K, N = 32, 48, 8, 2, 40
 
 
-def layer_params(seed=0, experts=E, shared=True):
-    ks = jax.random.split(jax.random.key(seed), 7)
+def layer_params(seed=0, experts=E, shared=True, bias=False):
+    ks = jax.random.split(jax.random.key(seed), 8)
     p = {"router": jax.random.normal(ks[0], (D, experts)),
          "wg": jax.random.normal(ks[1], (experts, D, F)) * 0.2,
          "wu": jax.random.normal(ks[2], (experts, D, F)) * 0.2,
@@ -31,22 +43,27 @@ def layer_params(seed=0, experts=E, shared=True):
         p["shared"] = {"wg": jax.random.normal(ks[4], (D, F)) * 0.2,
                        "wu": jax.random.normal(ks[5], (D, F)) * 0.2,
                        "wd": jax.random.normal(ks[6], (F, D)) * 0.2}
+    if bias:  # as large as the scores' own spread: it changes which experts are kept
+        p["bias"] = jax.random.normal(ks[7], (experts,)) * 0.3
     return p
 
 
 def share_of(p, rank, ranks):
     held = p["wg"].shape[0] // ranks
     cut = {k: p[k][rank * held:(rank + 1) * held] for k in ("wg", "wu", "wd")}
-    return {"router": p["router"], **cut}, rank * held
+    whole = {k: p[k] for k in ("router", "bias") if k in p}  # the router keeps its width
+    return {**whole, **cut}, rank * held
 
 
-def uncut_reference(h, p, k=K, scaling=1.0):
-    """The whole layer by the benchmark's plain reference: every expert
-    held (rank 0 of 1), the shared expert once."""
+def uncut_reference(h, p, k=K, scaling=1.0, router="softmax"):
+    """The whole layer by the benchmark's plain reference of that router:
+    every expert held (rank 0 of 1), the shared expert once."""
+    scoring, _, stated_by = ROUTERS[router]
     sizes = {"num_experts_per_tok": k,
-             "config": {"expert_rank": 0, "routed_scaling_factor": scaling}}
+             "config": {"expert_rank": 0, "routed_scaling_factor": scaling,
+                        "scoring_func": scoring, "norm_topk_prob": True}}
     with jax.default_matmul_precision("highest"):
-        return np.asarray(ref._experts(h, p, sizes, jnp.float32)[0])
+        return np.asarray(stated_by._experts(h, p, sizes, jnp.float32)[0])
 
 
 def shared_expert(h, p):
@@ -56,24 +73,51 @@ def shared_expert(h, p):
 
 @pytest.mark.parametrize("ranks", [1, 2, 4])
 @pytest.mark.parametrize("scaling", [1.0, 2.5])
-def test_the_ranks_parts_and_the_shared_expert_once_equal_the_uncut_layer(ranks, scaling):
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_the_ranks_parts_and_the_shared_expert_once_equal_the_uncut_layer(router, ranks, scaling):
     """The `model-configs` guide's test of the cut (section 4): what each
     rank computes for its own experts, summed over the ranks, plus what
-    every chip computes alike counted once, is the uncut layer."""
-    p = layer_params()
+    every chip computes alike counted once, is the uncut layer, under either
+    router."""
+    scoring, bias, _ = ROUTERS[router]
+    p = layer_params(bias=bias)
     h = jax.random.normal(jax.random.key(9), (N, D))
     total = shared_expert(h, p)
     held_total = 0
     for rank in range(ranks):
         mine, first = share_of(p, rank, ranks)
         out, held, hit = moe_dropless(h, mine, K, first_expert=first,
-                                      routed_scaling_factor=scaling)
+                                      routed_scaling_factor=scaling, scoring=scoring)
         total = total + out
         held_total += int(held)
         assert 0 < int(hit) <= E // ranks
     assert held_total == N * K  # every assignment lands on exactly one rank
-    np.testing.assert_allclose(np.asarray(total), uncut_reference(h, p, scaling=scaling),
+    np.testing.assert_allclose(np.asarray(total),
+                               uncut_reference(h, p, scaling=scaling, router=router),
                                atol=2e-5, rtol=1e-4)
+
+
+def test_the_selection_bias_picks_experts_and_does_not_weigh_them():
+    """`top_k_weights`: with a bias the kept experts are those of score +
+    bias, their weights the scores themselves renormalised; the bias changes
+    the choice here, and a zero bias changes nothing."""
+    from omnia_tpu.ops.moe import top_k_weights
+
+    logits = jax.random.normal(jax.random.key(1), (N, E))
+    bias = jax.random.normal(jax.random.key(2), (E,)) * 0.3
+    scores = np.asarray(jax.nn.sigmoid(logits))
+    top_w, top_i = top_k_weights(logits, K, "sigmoid", bias)
+    want_i = np.argsort(-(scores + np.asarray(bias)), axis=-1)[:, :K]
+    np.testing.assert_array_equal(np.asarray(top_i), want_i)
+    kept = np.take_along_axis(scores, want_i, axis=-1)
+    np.testing.assert_allclose(np.asarray(top_w), kept / kept.sum(-1, keepdims=True), rtol=1e-6)
+    plain_w, plain_i = top_k_weights(logits, K, "sigmoid")
+    assert (np.asarray(plain_i) != want_i).any()
+    zero_w, zero_i = top_k_weights(logits, K, "sigmoid", jnp.zeros((E,)))
+    np.testing.assert_array_equal(np.asarray(zero_i), np.asarray(plain_i))
+    np.testing.assert_allclose(np.asarray(zero_w), np.asarray(plain_w), rtol=1e-6)
+    with pytest.raises(ValueError, match="softmax or sigmoid"):
+        top_k_weights(logits, K, "tanh")
 
 
 @pytest.mark.parametrize("tokens", [7, 64, 300])

@@ -141,13 +141,16 @@ def test_decode_kernel_compiles(one_chip, paged, model, kv_int8):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("slots,rows", [(96, 3072), (1, 256)],
-                         ids=["reason-batch", "the-check"])
-def test_latent_decode_kernel_compiles(one_chip, slots, rows):
-    """`decode_mla_attention` at Mistral-Small-4's published widths (32
-    heads against one latent head, rows of 256 + 64 values padded to 384
-    lanes): the cell's 96 slots x 3072 rows, and the one slot x 256 rows
-    that benchmark/harness/correct.py runs."""
+@pytest.mark.parametrize("slots,rows,rank,lanes", [
+    (96, 3072, 256, 384), (1, 256, 256, 384), (48, 2304, 512, 640), (1, 256, 512, 640),
+], ids=["reason-batch", "the-check", "judge-batch", "the-check-at-640-lanes"])
+def test_latent_decode_kernel_compiles(one_chip, slots, rows, rank, lanes):
+    """`decode_mla_attention` at the published widths of the two latent
+    configurations (32 heads against one latent head): Mistral-Small-4's rows
+    of 256 + 64 values padded to 384 lanes, Xing4.0's of 512 + 64 padded to
+    640; each cell's slots x rows (2304 rows divide by 256 alone, so that
+    cell's blocks are the smallest), and the one slot x 256 rows that
+    benchmark/harness/correct.py runs."""
     from omnia_tpu.ops.decode_mla_attention import decode_mla_attention
 
     def arr(shape, dtype):
@@ -155,9 +158,9 @@ def test_latent_decode_kernel_compiles(one_chip, slots, rows):
 
     compiled = jax.jit(
         lambda q, cache, pos, layer, live: decode_mla_attention(
-            q, cache, pos, layer, live, rank=256, scale=0.2)
+            q, cache, pos, layer, live, rank=rank, scale=0.2)
     ).lower(
-        arr((slots, 32, 384), jnp.bfloat16), arr((5, slots, rows, 384), jnp.bfloat16),
+        arr((slots, 32, lanes), jnp.bfloat16), arr((5, slots, rows, lanes), jnp.bfloat16),
         arr((slots,), jnp.int32), arr((), jnp.int32), arr((slots,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
