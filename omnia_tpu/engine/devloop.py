@@ -32,14 +32,26 @@ class _InflightChunk:
 
     ``toks`` is the device [K, B] token buffer, ``active`` the (slot,
     request_id) snapshot at dispatch, ``dispatch_s`` the host dispatch
-    wall time."""
+    wall time.
 
-    __slots__ = ("toks", "active", "dispatch_s")
+    A placement's first token rides the same pipeline, in order: ``toks``
+    is then the prefill's scalar, ``active`` the one (slot, request_id)
+    placed, and ``placement`` (``None`` on a decode chunk) the flight
+    recorder's placement note, written when the token is read."""
 
-    def __init__(self, toks, active, dispatch_s):
+    __slots__ = ("toks", "active", "dispatch_s", "placement")
+
+    def __init__(self, toks, active, dispatch_s, placement=None):
         self.toks = toks
         self.active = active
         self.dispatch_s = dispatch_s
+        self.placement = placement
+
+    @property
+    def steps(self) -> int:
+        """Decode steps this entry holds for each slot of ``active``: a
+        first token stands for one emission, like a step."""
+        return 1 if self.placement is not None else int(self.toks.shape[0])
 
 
 class DrainEntry:

@@ -232,6 +232,8 @@ class InferenceEngine(
         self._prefix_offload_fn = progs.prefix_offload
         self._mixed_fns = progs.mixed
         self._mixed_sample_fns = progs.mixed_sample
+        self._activate_slot_fn = progs.activate_slot
+        self._release_slot_fn = progs.release_slot
         self._page_copy_fn = progs.page_copy
         self._gather_pages_fn = progs.gather_pages
         self._scatter_pages_fn = progs.scatter_pages
@@ -362,7 +364,10 @@ class InferenceEngine(
             # request sampled / also filtered (ops/sampling.py's gates);
             # pipeline_flushes counts the flushes forced by a waiting
             # request that had a slot to go to; programs_compiled_serving
-            # the programs asked of the compiler after warmup() returned.
+            # the programs asked of the compiler after warmup() returned;
+            # placements_deferred the placements whose first token went on
+            # the pipeline unread (placement.py _defers_first_token): over
+            # prefill_steps, the share that left the thread without a read.
             "decode_dispatches": 0,
             "decode_dispatches_single": 0,
             "decode_dispatches_blocked": 0,
@@ -371,6 +376,7 @@ class InferenceEngine(
             "decode_steps_sampling": 0,
             "decode_steps_filtering": 0,
             "pipeline_flushes": 0,
+            "placements_deferred": 0,
             "programs_compiled_serving": 0,
             "extend_steps": 0,
             "prefill_tokens": 0,

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from omnia_tpu.engine.types import (
-    MAX_DEVICE_STOP_IDS,
     FinishReason,
     Request,
     RequestHandle,
@@ -350,67 +349,26 @@ class _InterleaveMixin:
         """The final piece sampled the first token: activate the slot —
         the back half of ``_place_request``, against the mixed program's
         already-advanced decode state."""
-        slot_idx, request, handle = pf.slot_idx, pf.request, pf.handle
-        sp = request.params
-        prompt = pf.prompt
-        n = len(prompt)
-        slot = self._slots[slot_idx]
-        slot.request = request
-        slot.handle = handle
-        slot.length = n
-        slot.generated = 0
-        slot.emitted = []
-        slot.max_total = sp.max_tokens
-        if self.cfg.spec_decode:
-            slot.spec_reset(self.cfg.spec_decode, self.cfg.spec_decode_max)
-        stop_ids = frozenset(sp.stop_token_ids)
-        if request.grammar is not None:
-            # Same rule as monolithic placement: the grammar's eos id
-            # must finish the slot even when the caller's stop set
-            # omits it (see _place_request).
-            stop_ids |= {request.grammar.eos_id}
-        slot.stop_ids = stop_ids
+        slot_idx, prompt = pf.slot_idx, pf.prompt
         if pf.sess is not None:
             pf.sess.token_ids = list(prompt)
         self._maybe_publish_prefix(slot_idx, prompt)
         # Paged pool: drop the final piece's bucket-padding slack (after
         # publish shared the prefix pages).
-        self._trim_slot_pages(slot_idx, n)
+        self._trim_slot_pages(slot_idx, len(prompt))
         self.metrics["prefill_steps"] += 1
-
-        self._tokens = self._tokens.at[slot_idx].set(first_tok)
-        self._key_data = self._key_data.at[slot_idx].set(new_pkd)
-        # positions[slot_idx] already sits at n — the final piece's
-        # frontier, where the first real decode write lands.
-        self._active = self._active.at[slot_idx].set(True)
-        self._temp = self._temp.at[slot_idx].set(sp.temperature)
-        self._top_p = self._top_p.at[slot_idx].set(sp.top_p)
-        self._top_k = self._top_k.at[slot_idx].set(sp.top_k)
-        budget = min(sp.max_tokens - 1, self.cfg.max_seq - 2 - n)
-        self._budget = self._budget.at[slot_idx].set(max(budget, 0))
-        ids = list(sp.stop_token_ids)
-        if request.grammar is not None and request.grammar.eos_id not in ids:
-            ids.append(request.grammar.eos_id)
-        ids = ids[:MAX_DEVICE_STOP_IDS]
-        ids += [-1] * (MAX_DEVICE_STOP_IDS - len(ids))
-        self._stop_ids = self._stop_ids.at[slot_idx].set(
-            jnp.asarray(ids, jnp.int32)
-        )
         self._prefilling = None
         with self._lock:
             self._placing -= 1
-        first = int(first_tok)
-        self._attach_grammar(slot_idx, request, first)
-        if self._flight is not None:
-            # Same stage-tiling rule as monolithic placement: recorded
-            # just before the first token emits. prefill_s=0 here — the
-            # per-piece mixed-step dispatches already accumulated it.
-            self._flight.note_placement(
-                request.request_id, slot_idx, n,
-                reuse=pf.reuse, seeded=pf.seeded,
-                prefill_s=0.0, stalled=False,
-            )
-        self._emit_token(slot_idx, first)
+        # positions[slot_idx] already sits at n — the final piece's
+        # frontier, where the first real decode write lands — and is
+        # written there again. prefill_s=0: the per-piece mixed-step
+        # dispatches already accumulated it.
+        self._activate_slot(
+            slot_idx, pf.request, pf.handle, first_tok, new_pkd,
+            dict(reuse=pf.reuse, seeded=pf.seeded, prefill_s=0.0,
+                 stalled=False),
+        )
 
     # -- abort / failure ------------------------------------------------
 

@@ -30,6 +30,13 @@ from omnia_tpu.engine.types import (
 from omnia_tpu.ops.sampling import _NEG_INF, make_slot_key_data
 
 
+#: The per-slot device vectors a placement writes (``activate_slot``'s
+#: leading operands, in order) and those a finish does (``release_slot``'s).
+ACTIVATED = ("_tokens", "_positions", "_active", "_temp", "_top_p", "_top_k",
+             "_budget", "_stop_ids", "_key_data")
+RELEASED = ("_positions", "_tokens", "_temp", "_active")
+
+
 class _PlacementMixin:
     """Placement methods of :class:`InferenceEngine`."""
 
@@ -89,11 +96,8 @@ class _PlacementMixin:
         slot (metrics + mock parity)."""
         slot = self._slots[slot_idx]
         g = request.grammar
-        if not self._gr_on:
-            return
-        if g is None:
-            self._gactive = self._gactive.at[slot_idx].set(False)
-            return
+        if not self._gr_on or g is None:
+            return  # the gate was closed by the slot's activation
         sp = request.params
         view = g.view(self.model_cfg.vocab_size, sp.stop_token_ids)
         state0 = view.advance(view.start, first_tok)
@@ -150,8 +154,7 @@ class _PlacementMixin:
             jnp.int32(sp.top_k),
             *self._grammar_args(request, sp),
         )
-        key_data = self._key_data.at[slot_idx].set(new_kd)
-        return tuple(cache), tok, key_data
+        return tuple(cache), tok, new_kd
 
     def _prepare_session_slot(
         self, slot_idx: int, request: Request
@@ -225,9 +228,9 @@ class _PlacementMixin:
         stalled = any(s.active for s in self._slots)
         ext0 = self.metrics["extend_steps"]
         if frontier == 0 and n <= max(usable):
-            first_tok = self._fresh_prefill(slot_idx, prompt, sp, request)
+            first_tok, new_kd = self._fresh_prefill(slot_idx, prompt, sp, request)
         else:
-            first_tok = self._chunked_extend(
+            first_tok, new_kd = self._chunked_extend(
                 slot_idx, prompt, frontier, sp, request
             )
         if stalled:
@@ -246,7 +249,41 @@ class _PlacementMixin:
         self.metrics["prefix_reuse_tokens"] += reuse
         self.metrics["prefill_tokens"] += n - frontier
         self.metrics["prefill_steps"] += 1
+        if sess is not None:
+            sess.token_ids = list(prompt)
+        deferred = self._activate_slot(
+            slot_idx, request, handle, first_tok, new_kd,
+            dict(reuse=reuse, seeded=seeded, prefill_s=prefill_s,
+                 stalled=stalled),
+        )
+        if span:
+            span.set_metadata(
+                slot=slot_idx, reuse=reuse, seeded=seeded, deferred=deferred
+            )
 
+    def _defers_first_token(self, request: Request) -> bool:
+        """Whether a placement may end with its first token unread. The
+        host needs the token at placement only to advance a grammar's
+        start state (``_attach_grammar``) and to propose from
+        ``slot.emitted`` (per-slot speculation); everything else needs the
+        prompt and the slot. Reads the request and the configuration
+        alone, so lockstep ranks agree."""
+        return request.grammar is None and not self.cfg.spec_decode
+
+    def _activate_slot(self, slot_idx: int, request: Request,
+                       handle: RequestHandle, first_tok, new_kd,
+                       note: dict) -> bool:
+        """The tail every placement path shares, once the prefill that
+        sampled ``first_tok`` is on the device's queue: the host's slot
+        record, the slot's device state in ONE program call, and the
+        first token — as an entry of the pipeline, ahead of any decode
+        chunk dispatched after it, so that the thread enqueues the step
+        that follows the prefill before it reads anything back; read at
+        once where the host needs it (``_defers_first_token``). ``note``
+        is the rest of the flight recorder's placement note. Returns
+        whether the token was deferred."""
+        sp = request.params
+        n = len(request.prompt_tokens)
         slot = self._slots[slot_idx]
         slot.request = request
         slot.handle = handle
@@ -256,24 +293,15 @@ class _PlacementMixin:
         slot.max_total = sp.max_tokens
         if self.cfg.spec_decode:
             slot.spec_reset(self.cfg.spec_decode, self.cfg.spec_decode_max)
-        stop_ids = frozenset(sp.stop_token_ids)
-        if request.grammar is not None:
+        ids = list(sp.stop_token_ids)
+        if request.grammar is not None and request.grammar.eos_id not in ids:
             # In terminal accepting states the grammar view unmasks ONLY
             # its eos id — the engine must finish on it even when the
             # caller's stop set omits it, or the slot streams raw EOS
             # tokens until the budget runs out (valid JSON + EOS spam,
             # finish_reason LENGTH, and mock/compiled parity broken).
-            stop_ids |= {request.grammar.eos_id}
-        slot.stop_ids = stop_ids
-        if sess is not None:
-            sess.token_ids = list(prompt)
-
-        self._tokens = self._tokens.at[slot_idx].set(first_tok)
-        self._positions = self._positions.at[slot_idx].set(n)
-        self._active = self._active.at[slot_idx].set(True)
-        self._temp = self._temp.at[slot_idx].set(sp.temperature)
-        self._top_p = self._top_p.at[slot_idx].set(sp.top_p)
-        self._top_k = self._top_k.at[slot_idx].set(sp.top_k)
+            ids.append(request.grammar.eos_id)
+        slot.stop_ids = frozenset(ids)
         # Device-side finish state: decode emissions still allowed after
         # the first token. MUST equal the host's finish schedule exactly
         # (generated >= max_tokens OR length >= max_seq - 2, checked after
@@ -281,33 +309,56 @@ class _PlacementMixin:
         # would freeze the slot while the host keeps consuming its chunk
         # rows as real tokens. Stop-id row is -1 padded; ids past
         # MAX_DEVICE_STOP_IDS are host-checked only (host-early is safe).
-        budget = min(sp.max_tokens - 1, self.cfg.max_seq - 2 - n)
-        self._budget = self._budget.at[slot_idx].set(max(budget, 0))
-        ids = list(sp.stop_token_ids)
-        if request.grammar is not None and request.grammar.eos_id not in ids:
-            ids.append(request.grammar.eos_id)  # device mirror of slot.stop_ids
+        budget = max(min(sp.max_tokens - 1, self.cfg.max_seq - 2 - n), 0)
         ids = ids[:MAX_DEVICE_STOP_IDS]
         ids += [-1] * (MAX_DEVICE_STOP_IDS - len(ids))
-        self._stop_ids = self._stop_ids.at[slot_idx].set(
-            jnp.asarray(ids, jnp.int32)
+        self._run_slot_program(
+            self._activate_slot_fn, ACTIVATED, first_tok, new_kd,
+            np.asarray([slot_idx, n, sp.top_k, budget, *ids], np.int32),
+            np.asarray([sp.temperature, sp.top_p], np.float32),
         )
-        if span:
-            span.set_metadata(slot=slot_idx, reuse=reuse, seeded=seeded)
+        note = dict(note, slot=slot_idx, n_prompt=n)
+        rid = request.request_id
+        if self._defers_first_token(request):
+            self.metrics["placements_deferred"] += 1
+            self._push_inflight(first_tok, [(slot_idx, rid)], 0.0, note)
+            return True
         first = int(first_tok)
         self._attach_grammar(slot_idx, request, first)
+        self._emit_first_token(slot_idx, rid, first, note)
+        return False
+
+    def _run_slot_program(self, fn, names, *operands) -> None:
+        """Call ``activate_slot`` / ``release_slot`` (programs.py): the
+        per-slot vectors ``names`` in, donated, and out, the host's
+        ``operands`` between them and a grammar engine's gate."""
+        gate = ("_gactive",) if self._gr_on else ()
+        out = fn(
+            *(getattr(self, name) for name in names), *operands,
+            *(getattr(self, name) for name in gate),
+        )
+        for name, vector in zip(names + gate, out):
+            setattr(self, name, vector)
+
+    def _emit_first_token(self, slot_idx: int, rid: str, token: int,
+                          note: dict) -> None:
+        """A placed request's first token, on the host: its event, under
+        the guard ``_emit_chunk`` applies (the slot still holds that
+        request)."""
+        slot = self._slots[slot_idx]
+        if not slot.active or slot.request.request_id != rid:
+            return
         if self._flight is not None:
             # Recorded just BEFORE the first token emits so the
             # breakdown's stages tile the wall: queue (submit→claim) +
             # placement (claim→here, prefill included) + decode (first
             # token→terminal).
-            self._flight.note_placement(
-                request.request_id, slot_idx, n, reuse=reuse, seeded=seeded,
-                prefill_s=prefill_s, stalled=stalled,
-            )
-        self._emit_token(slot_idx, first)
+            self._flight.note_placement(rid, **note)
+        self._emit_token(slot_idx, token)
 
     def _fresh_prefill(self, slot_idx: int, prompt: list[int],
                        sp: SamplingParams, request: Optional[Request] = None):
+        """``(first_tok, new_key_data)``, both still on the device."""
         n = len(prompt)
         bucket = self.cfg.bucket_for(n)
         toks = np.zeros((1, bucket), np.int32)
@@ -327,13 +378,11 @@ class _PlacementMixin:
         ):
             # Ring path: the sp-sharded prefill stays its own program;
             # its KV chunk gathers into the slot via the insert step.
-            logits, *chunks = self._prefill_ring_fn(
-                self.params, jnp.asarray(toks), jnp.asarray(pos)
-            )
-            self._cache, first_tok, self._key_data = self._run_insert(
+            logits, *chunks = self._prefill_ring_fn(self.params, toks, pos)
+            self._cache, first_tok, new_kd = self._run_insert(
                 chunks, slot_idx, logits[:, n - 1], sp, request=request,
             )
-            return first_tok
+            return first_tok, new_kd
         kd = self._sampling_key(slot_idx, sp)
         t0 = time.monotonic()
         with phase(PREFILL_DISPATCH) as span:
@@ -342,12 +391,14 @@ class _PlacementMixin:
                     request_id=request.request_id if request else "",
                     take=n, bucket=bucket,
                 )
+            # Host operands go in as numpy: the call transfers them in
+            # one batch, where a ``jnp`` constructor each is a device put
+            # of its own on the thread the device is waiting for.
             *cache, first_tok, new_kd = self._prefill_insert_fn(
-                self.params, *self._cache,
-                jnp.asarray(toks), jnp.asarray(pos),
-                jnp.int32(slot_idx), jnp.int32(n - 1), kd,
-                jnp.float32(sp.temperature), jnp.float32(sp.top_p),
-                jnp.int32(sp.top_k),
+                self.params, *self._cache, toks, pos,
+                np.int32(slot_idx), np.int32(n - 1), kd,
+                np.float32(sp.temperature), np.float32(sp.top_p),
+                np.int32(sp.top_k),
                 *self._grammar_args(request, sp),
             )
             self._cache = tuple(cache)
@@ -355,8 +406,7 @@ class _PlacementMixin:
             self._flight.note_prefill_piece(
                 request.request_id, n, bucket, time.monotonic() - t0
             )
-        self._key_data = self._key_data.at[slot_idx].set(new_kd)
-        return first_tok
+        return first_tok, new_kd
 
     def _extend_pieces(self, start: int, count: int) -> list[tuple[int, int, int]]:
         """Plan (offset, real_len, bucket) chunks covering prompt[start:
@@ -382,15 +432,16 @@ class _PlacementMixin:
         sp: SamplingParams, request: Optional[Request] = None,
     ):
         """Incremental prefill of prompt[reuse:] against the slot's resident
-        rows; only the final chunk samples."""
+        rows; only the final chunk samples: ``(first_tok, new_key_data)``,
+        both still on the device."""
         pieces = self._extend_pieces(reuse, len(prompt) - reuse)
-        slot_arr = jnp.int32(slot_idx)
+        slot_arr = np.int32(slot_idx)
 
         def chunk_arrays(off, take, b):
+            # numpy, like every host operand below (see _fresh_prefill)
             toks = np.zeros((1, b), np.int32)
             toks[0, :take] = prompt[off:off + take]
-            pos = (off + np.arange(b, dtype=np.int32))[None, :]
-            return jnp.asarray(toks), jnp.asarray(pos)
+            return toks, (off + np.arange(b, dtype=np.int32))[None, :]
 
         rid = request.request_id if request is not None else ""
         for off, take, b in pieces[:-1]:
@@ -405,7 +456,7 @@ class _PlacementMixin:
                     span.set_metadata(request_id=rid, take=take, bucket=b)
                 self._cache = self._extend_nosample_fn(
                     self.params, *self._cache, toks, pos, slot_arr,
-                    jnp.int32(off),
+                    np.int32(off),
                 )
             if self._flight is not None and rid:
                 self._flight.note_prefill_piece(
@@ -421,14 +472,13 @@ class _PlacementMixin:
                 span.set_metadata(request_id=rid, take=take, bucket=b)
             *cache, first_tok, new_kd = self._extend_fn(
                 self.params, *self._cache, toks, pos, slot_arr,
-                jnp.int32(off), jnp.int32(take - 1), kd,
-                jnp.float32(sp.temperature), jnp.float32(sp.top_p),
-                jnp.int32(sp.top_k),
+                np.int32(off), np.int32(take - 1), kd,
+                np.float32(sp.temperature), np.float32(sp.top_p),
+                np.int32(sp.top_k),
                 *self._grammar_args(request, sp),
             )
             self._cache = tuple(cache)
         if self._flight is not None and rid:
             self._flight.note_prefill_piece(rid, take, b, time.monotonic() - t0)
-        self._key_data = self._key_data.at[slot_idx].set(new_kd)
         self.metrics["extend_steps"] += len(pieces)
-        return first_tok
+        return first_tok, new_kd

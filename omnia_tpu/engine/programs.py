@@ -36,6 +36,10 @@ through):
   run a prompt suffix through ``forward`` against the slot's EXISTING
   rows (cross-attention to history) from the reuse frontier; batch-1 on
   a sliced slot cache so one slot's cache moves, not B× suffix FLOPs.
+- ``activate_slot`` / ``release_slot`` — a slot's per-slot device state
+  (token, position, active flag, sampling parameters, budget, stop row,
+  sampler key) written by ONE dispatch at placement and at a finish, so
+  that the engine thread never updates it op by op.
 - ``offload`` / ``restore`` — session paging: pull/push one slot's
   leading KV rows in fixed restore-bucket shapes (device↔host transfers
   stay compile-stable).
@@ -107,6 +111,10 @@ class EnginePrograms:
     # (prefill_chunk_tokens > 0, else both dicts are empty).
     mixed: dict[int, Callable]
     mixed_sample: dict[int, Callable]
+    # A slot's per-slot device state, written whole by one call each
+    # (placement's tail, and a finish).
+    activate_slot: Callable
+    release_slot: Callable
     # Paged-pool programs (kv_pages > 0, else all None): copy-on-write
     # page duplication and the prefix host-tier page-run transfers.
     page_copy: Optional[Callable] = None
@@ -721,7 +729,52 @@ def build_programs(
 
         verify_decode_fn = jax.jit(verify_decode, donate_argnums=(1, 2))
 
+    def _slot_put(ints):
+        """``(vector, value) -> vector`` written at the slot ``ints[0]``."""
+        return lambda v, x: v.at[ints[0]].set(x)
+
+    def activate_slot(tokens, positions, active, temp, top_p, top_k, budget,
+                      stop_ids, key_data, first_tok, new_kd, ints, floats,
+                      *g):
+        """(the nine per-slot vectors, first_tok, new_key_data, ints,
+        floats[, gactive]) -> the vectors[, gactive]: all a placement
+        writes for its slot. ``first_tok`` and ``new_key_data`` are the
+        prefill's outputs, still on the device; ``ints`` is the host's
+        ``[slot, n_prompt, top_k, budget, *stop_row]``, ``floats`` its
+        ``[temperature, top_p]``. A grammar engine's gate is closed here;
+        a grammar request's attach opens it again."""
+        put = _slot_put(ints)
+        return (
+            put(tokens, first_tok), put(positions, ints[1]),
+            put(active, True), put(temp, floats[0]), put(top_p, floats[1]),
+            put(top_k, ints[2]), put(budget, ints[3]),
+            put(stop_ids, ints[4:]), put(key_data, new_kd),
+            *(put(ga, False) for ga in g),
+        )
+
+    def release_slot(positions, tokens, temp, active, ints, *g):
+        """(positions, tokens, temp, active, ints[, gactive]) -> the same:
+        a finished slot quiesced. ``ints`` is the host's ``[slot,
+        quiesce_row]``: decode keeps running over the slot (static shape)
+        and rewrites that one row."""
+        put = _slot_put(ints)
+        return (
+            put(positions, ints[1]), put(tokens, 0), put(temp, 0.0),
+            put(active, False), *(put(ga, False) for ga in g),
+        )
+
+    # Every vector is donated; the gate only where grammar support is on.
+    gate = bool(ecfg.grammar)
+    activate_slot_fn = jax.jit(
+        activate_slot, donate_argnums=(*range(9), *((13,) * gate))
+    )
+    release_slot_fn = jax.jit(
+        release_slot, donate_argnums=(*range(4), *((5,) * gate))
+    )
+
     return EnginePrograms(
+        activate_slot=activate_slot_fn,
+        release_slot=release_slot_fn,
         prefill_insert=prefill_insert_fn,
         prefill_ring=prefill_ring_fn,
         insert=insert_fn,

@@ -35,6 +35,7 @@ full-size.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import time
 from typing import Optional
@@ -45,6 +46,7 @@ from omnia_tpu.engine import phases
 from omnia_tpu.engine.devloop import _InflightChunk
 from omnia_tpu.engine.faults import WatchdogTimeout
 from omnia_tpu.engine.phases import phase
+from omnia_tpu.engine.placement import RELEASED
 from omnia_tpu.engine.types import FinishReason, SamplingParams, StreamEvent
 from omnia_tpu.ops.attention import decode_block_rows
 
@@ -162,9 +164,13 @@ class _SchedulerMixin:
                 # can be placed: one step, read back at once, placement
                 # next. Blocked (requests wait, none has a slot): one
                 # step, but pipelined, so the read-back and emit of step
-                # N run while the device computes step N+1.
+                # N run while the device computes step N+1. Behind a
+                # prefill whose first token is still unread: one step
+                # too, since who arrives during that prefill is not known
+                # yet, and would wait out a whole chunk before its own.
                 self._dispatch_decode(
-                    single=queued, blocked=queued and not placeable
+                    single=queued or self._first_token_unread(),
+                    blocked=queued and not placeable,
                 )
                 depth = 1 if placeable else max(1, self.cfg.decode_pipeline)
                 while len(self._inflight) >= depth:
@@ -478,9 +484,8 @@ class _SchedulerMixin:
         for SOMEONE."""
         inflight_steps: dict[int, int] = {}
         for ch in self._inflight:
-            k = int(ch.toks.shape[0])
             for i, _rid in ch.active:
-                inflight_steps[i] = inflight_steps.get(i, 0) + k
+                inflight_steps[i] = inflight_steps.get(i, 0) + ch.steps
         need = 0
         for i, s in enumerate(self._slots):
             if not s.active:
@@ -542,13 +547,18 @@ class _SchedulerMixin:
             # per-chunk dispatch-vs-sync event.
             self._push_inflight(toks, active, time.monotonic() - t_dispatch)
 
-    def _push_inflight(self, toks, active, dispatch_s):
+    def _push_inflight(self, toks, active, dispatch_s, placement=None):
         """Append one dispatched chunk to the pipeline — the shared seam
-        for plain decode chunks and mixed interleave steps."""
-        self._inflight.append(_InflightChunk(toks, active, dispatch_s))
+        for plain decode chunks, mixed interleave steps and a
+        placement's first token (``placement``, devloop.py)."""
+        self._inflight.append(
+            _InflightChunk(toks, active, dispatch_s, placement)
+        )
 
     def _process_oldest_chunk(self):
         ch = self._inflight.popleft()
+        if ch.placement is not None:
+            return self._process_first_token(ch)
         t_sync = time.monotonic()
         counters = getattr(self.model_module, "DECODE_COUNTERS", ())
         with phase(phases.CHUNK_SYNC) as sp:
@@ -569,16 +579,50 @@ class _SchedulerMixin:
                 int(host_tokens.shape[0]), ch.dispatch_s, sync_s,
                 len(ch.active),
             )
-        with phase(phases.EMIT) as sp:
-            if sp:
-                m = self.metrics
-                tok0, fin0 = m["tokens_generated"], m["requests_finished"]
+        with self._emit_phase():
             self._emit_chunk(ch, host_tokens)
+
+    @contextlib.contextmanager
+    def _emit_phase(self):
+        """The ``emit`` span around what it covers, with the tokens and
+        the terminals that went out under it."""
+        with phase(phases.EMIT) as sp:
+            m = self.metrics
+            tok0, fin0 = m["tokens_generated"], m["requests_finished"]
+            yield
             if sp:
                 sp.set_metadata(
                     tokens=m["tokens_generated"] - tok0,
                     finished=m["requests_finished"] - fin0,
                 )
+
+    def _process_first_token(self, ch) -> None:
+        """Read and emit a placement's deferred first token: the read
+        waits for the prefill alone, whatever is queued behind it, under
+        the watchdog like any chunk's (``chunk=0``: no decode step)."""
+        (slot_idx, rid), = ch.active
+        with phase(phases.CHUNK_SYNC) as sp:
+            if sp:
+                sp.set_metadata(chunk=0)
+            token = int(self._sync_chunk_host(ch.toks))
+        with self._emit_phase():
+            self._emit_first_token(slot_idx, rid, token, ch.placement)
+
+    def _first_token_unread(self) -> bool:
+        """A placement's first token is in the pipeline: its prefill may
+        still be running."""
+        return any(ch.placement is not None for ch in self._inflight)
+
+    def _settle_first_token(self, slot_idx: int) -> None:
+        """Read the pipeline through ``slot_idx``'s first token, if it is
+        still in flight: a slot ended from outside the emit loop (cancel,
+        deadline, the paged pool run dry) has its first token emitted
+        first, as when placement read it."""
+        while any(
+            ch.placement is not None and ch.active[0][0] == slot_idx
+            for ch in self._inflight
+        ):
+            self._process_oldest_chunk()
 
     def _emit_chunk(self, ch, host_tokens) -> None:
         """The per-step, per-slot emission of one synced chunk [K, B]."""
@@ -649,19 +693,21 @@ class _SchedulerMixin:
 
     def _finish_slot(self, slot_idx: int, reason: FinishReason):
         slot = self._slots[slot_idx]
+        self._settle_first_token(slot_idx)
+        if not slot.active:
+            return  # its first token, read just now, ended it
         rid = slot.request.request_id
         handle = slot.handle
         n_prompt = len(slot.request.prompt_tokens)
         generated = slot.generated
-        if slot.gr_view is not None:
+        if (
+            slot.gr_view is not None and reason is FinishReason.STOP
+            and slot.gr_view.is_accepting(slot.gr_state)
+        ):
             # A constrained generation brought to a valid stop: without
             # the grammar this request could have burned a whole decode
             # on unparseable output and retried (bad_response_format).
-            if reason is FinishReason.STOP and slot.gr_view.is_accepting(
-                slot.gr_state
-            ):
-                self.metrics["grammar_rejections_avoided"] += 1
-            self._gactive = self._gactive.at[slot_idx].set(False)
+            self.metrics["grammar_rejections_avoided"] += 1
         # Sessionful: record which rows are valid for the next turn's
         # prefix reuse. The last emitted token's row write is not
         # guaranteed (a slot can finish mid-decode-chunk), so it is
@@ -695,10 +741,10 @@ class _SchedulerMixin:
         # with active=False its position is frozen, so it only ever rewrites
         # one row — row 0 for unpinned slots (the next prefill's insert
         # overwrites it) or the session's length frontier for pinned ones.
-        self._positions = self._positions.at[slot_idx].set(quiesce_row)
-        self._tokens = self._tokens.at[slot_idx].set(0)
-        self._temp = self._temp.at[slot_idx].set(0.0)
-        self._active = self._active.at[slot_idx].set(False)
+        self._run_slot_program(
+            self._release_slot_fn, RELEASED,
+            np.asarray([slot_idx, quiesce_row], np.int32),
+        )
         handle._push(
             StreamEvent(
                 rid,

@@ -52,6 +52,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from omnia_tpu.engine.coldstart import (
     PHASE_CODES,
@@ -59,6 +60,7 @@ from omnia_tpu.engine.coldstart import (
     manifest_bookkeeping,
     manifest_dir,
 )
+from omnia_tpu.engine.placement import ACTIVATED, RELEASED
 from omnia_tpu.engine.types import MAX_DEVICE_STOP_IDS, SamplingParams
 from omnia_tpu.models.kv_quant import kv_device, kv_host
 
@@ -157,14 +159,16 @@ class _WarmupMixin:
             if families is None or family in families:
                 tasks.append((family, key, fn))
 
-        def sargs():
+        def sargs(xp=jnp):
             # First-token sampling operands (the prefill/extend/mixed
             # `*sargs` tail): per-slot key data + greedy scalars, plus
             # the zero grammar bias when support is on (the request
-            # path ALWAYS passes the bias operand then).
+            # path ALWAYS passes the bias operand then). ``xp``: where
+            # the caller's host scalars come from (placement.py hands
+            # numpy to the prefill and extend programs).
             out = (
-                self._key_data[0], jnp.float32(0.0), jnp.float32(1.0),
-                jnp.int32(0),
+                self._key_data[0], xp.float32(0.0), xp.float32(1.0),
+                xp.int32(0),
             )
             if self._gr_on:
                 out = out + (self._gbias_zero,)
@@ -204,13 +208,13 @@ class _WarmupMixin:
 
         def bucket_task(b):
             def run(st):
-                zero = jnp.int32(0)
-                toks = jnp.zeros((1, b), jnp.int32)
-                pos = jnp.arange(b, dtype=jnp.int32)[None, :]
+                zero = np.int32(0)
+                toks = np.zeros((1, b), np.int32)
+                pos = np.arange(b, dtype=np.int32)[None, :]
                 if b in usable:
                     *cache, _, _ = self._prefill_insert_fn(
                         self.params, *st.cache, toks, pos, zero,
-                        jnp.int32(b - 1), *sargs()
+                        np.int32(b - 1), *sargs(np)
                     )
                     st.cache = tuple(cache)
                     if (
@@ -236,7 +240,7 @@ class _WarmupMixin:
                     ))
                     *cache, _, _ = self._extend_fn(
                         self.params, *st.cache, toks, pos, zero, zero,
-                        zero, *sargs()
+                        zero, *sargs(np)
                     )
                     st.cache = tuple(cache)
             return run
@@ -599,7 +603,7 @@ class _WarmupMixin:
                 "manifest_misses": misses,
             })
 
-        self._warmup_scatters()
+        self._warmup_slot_programs()
 
         cs.begin_phase("warmup_restore")
         self.metrics["warmup_phase"] = PHASE_CODES["warmup_restore"]
@@ -622,28 +626,28 @@ class _WarmupMixin:
         )
         _watch_serving_compiles(self)
 
-    def _warmup_scatters(self) -> None:
-        """Placement bookkeeping runs a handful of tiny scatter programs
-        (at[slot].set on tokens/positions/active/budget/stop_ids/keys);
-        un-warmed, each costs a first-request compile round trip —
-        directly inflating the FIRST measured TTFT. Touch them all.
-        Scalar types must MATCH the request path exactly (weak-typed
-        Python scalars for positions/temp/top_p/top_k/budget, a strong
-        device int32 for tokens) — jit caches key on weak_type, so a
-        jnp.int32 here would warm a different program than the one
-        placement dispatches."""
-        kd = self._key_data[0]
+    def _warmup_slot_programs(self) -> None:
+        """The two programs that write a slot's device state
+        (programs.py ``activate_slot`` / ``release_slot``), called as
+        placement and a finish call them: un-warmed, the first request
+        would pay their compile in its TTFT. They donate the per-slot
+        vectors every warm-up worker reads, so they run here, after the
+        workers, and not as tasks. Operand types MATCH the request path
+        (numpy rows of the host's scalars, the prefill's device scalars
+        for the token and the key) — jit caches key on them. What else
+        still updates a vector op by op is touched too: the mixed step's
+        parked position (interleave.py), speculation's re-sync
+        (spec_decode.py) and a grammar's attach."""
+        self._run_slot_program(
+            self._activate_slot_fn, ACTIVATED, jnp.int32(0), self._key_data[0],
+            np.asarray([0, 0, 0, 1] + [-1] * MAX_DEVICE_STOP_IDS, np.int32),
+            np.asarray([0.0, 1.0], np.float32),
+        )
+        self._run_slot_program(
+            self._release_slot_fn, RELEASED, np.asarray([0, 0], np.int32)
+        )
         self._tokens = self._tokens.at[0].set(jnp.int32(0))
         self._positions = self._positions.at[0].set(0)
-        self._active = self._active.at[0].set(True)
-        self._temp = self._temp.at[0].set(0.0)
-        self._top_p = self._top_p.at[0].set(1.0)
-        self._top_k = self._top_k.at[0].set(0)
-        self._budget = self._budget.at[0].set(1)
-        self._stop_ids = self._stop_ids.at[0].set(
-            jnp.asarray([-1] * MAX_DEVICE_STOP_IDS, jnp.int32)
-        )
-        self._key_data = self._key_data.at[0].set(kd)
         if self._gr_on:
             # Grammar placement scatters: FSM state + gate (the exact
             # scalar-set programs placement dispatches). The table
@@ -654,7 +658,7 @@ class _WarmupMixin:
             # building a multi-GB host array at large vocabularies.
             self._gstate = self._gstate.at[0].set(0)
             self._gactive = self._gactive.at[0].set(True)
-        jax.block_until_ready(self._key_data)
+        jax.block_until_ready(self._active)
 
     def _sync_coldstart_metrics(self) -> None:
         """Mirror the tracker into the stable metrics keys (the warmup

@@ -358,6 +358,11 @@ class TestGracefulDrain:
         strand clients: queued requests shed (OVERLOADED), the active
         slot fails with its partial count, books balance."""
         eng = _tiny_engine(num_slots=1, decode_chunk=1)
+        # Every read-back a little slow: the 55 steps to the cache's end
+        # outlast the window by far, whatever the first token waited for
+        # (it is read after the first decode step is dispatched, so no
+        # compile stands between it and the drain any more).
+        eng._fault_plan = FaultPlan(slow_sync_s=0.01)
         eng.start()
         sp_long = SamplingParams(temperature=0.0, max_tokens=100_000)
         h_active = eng.submit(list(range(1, 9)), sp_long)

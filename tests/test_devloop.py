@@ -141,7 +141,13 @@ class TestDevLoopState:
         assert ch.toks == "toks" and ch.dispatch_s == 0.25
         assert ch.active == [(0, "r0")]
         assert not hasattr(ch, "__dict__")  # __slots__: pipeline entry
-        assert _InflightChunk.__slots__ == ("toks", "active", "dispatch_s")
+        assert _InflightChunk.__slots__ == (
+            "toks", "active", "dispatch_s", "placement")
+        # A decode chunk holds its buffer's rows as steps; a placement's
+        # first token (any placement note) stands for one.
+        assert ch.placement is None
+        first = _InflightChunk("tok", [(0, "r0")], 0.0, {"slot": 0})
+        assert first.steps == 1 and first.placement == {"slot": 0}
 
 
 # ---------------------------------------------------------------------------
@@ -150,9 +156,11 @@ class TestDevLoopState:
 # ---------------------------------------------------------------------------
 
 
-def _stub_scheduler(waiting, inflight, useful, active=True, pipeline=2):
+def _stub_scheduler(waiting, inflight, useful, active=True, pipeline=2,
+                    first_unread=False):
     """``waiting`` is the queue as a list of booleans (has this request a
-    slot to go to); ``inflight`` the chunks already dispatched."""
+    slot to go to); ``inflight`` the chunks already dispatched;
+    ``first_unread`` whether one of them is a placement's first token."""
     pytest.importorskip("jax")
     from omnia_tpu.engine.scheduler import _SchedulerMixin
 
@@ -169,6 +177,9 @@ def _stub_scheduler(waiting, inflight, useful, active=True, pipeline=2):
 
         def _spec_step(self):
             return False
+
+        def _first_token_unread(self):
+            return first_unread
 
         def _queued_placeable(self):
             return bool(self._waiting), any(self._waiting)
@@ -246,6 +257,21 @@ _PLACED = [("claim",), ("place", 0)]
 ])
 def test_schedule_rule_table(waiting, inflight, useful, calls, left):
     stub = _stub_scheduler(waiting, inflight, useful)
+    assert stub._schedule() is True
+    assert stub.calls == calls
+    assert len(stub._inflight) == left
+
+
+@pytest.mark.parametrize("inflight,calls,left", [
+    (1, [_STEP_SYNC + (1,), ("process", "c0")], 1),
+    (2, [_STEP_SYNC + (2,), ("process", "c0"), ("process", "c1")], 1),
+])
+def test_behind_an_unread_first_token_nobody_waiting_still_gets_one_step(
+        inflight, calls, left):
+    """The prefill may still be running, and who arrives during it is not
+    known yet: no full chunk is committed behind it. The pipeline keeps
+    its depth (the step is not read at once)."""
+    stub = _stub_scheduler(_NOBODY, inflight, True, first_unread=True)
     assert stub._schedule() is True
     assert stub.calls == calls
     assert len(stub._inflight) == left

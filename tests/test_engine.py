@@ -579,3 +579,281 @@ def test_decode_kernel_skips_freed_slots_and_counts_its_blocks(monkeypatch):
         assert d["decode_kv_blocks"] == d["decode_slot_steps"] + d["decode_steps"]
     finally:
         attn._pallas_decode_mode.cache_clear()
+
+
+# ---------------------------------------------------------------------------
+# A placement is enqueues only: the slot's device state is one program call,
+# and the first token is an entry of the pipeline, read after the decode step
+# that follows the prefill has been dispatched.
+# ---------------------------------------------------------------------------
+
+
+def _placement_engine(eager: bool = False, **over) -> InferenceEngine:
+    base = dict(num_slots=3, max_seq=64, prefill_buckets=(8, 16),
+                dtype="float32", max_sessions=0, decode_chunk=4,
+                decode_pipeline=2)
+    base.update(over)
+    eng = InferenceEngine(get_config("test-tiny"), EngineConfig(**base), seed=5)
+    if eager:
+        # The same engine made to read at once: the branch a grammar or
+        # speculation takes, for every request.
+        eng._defers_first_token = lambda request: False
+    return eng
+
+
+def _events(handle):
+    """A finished request's events, in order: (token, reason, generated)."""
+    out = []
+    while True:
+        ev = handle.get_event(timeout=5)
+        out.append((ev.token_id, ev.finish_reason, ev.num_generated_tokens))
+        if ev.is_final:
+            return out
+
+
+def _drive(eng):
+    while eng.step():
+        pass
+
+
+def _script_mix(eng):
+    """Eleven requests over three slots, lengths and parameters drawn from
+    a fixed seed: greedy and seeded-sampling, stop ids, two arrivals."""
+    rng = np.random.RandomState(20260929)
+    free_run, _ = eng.generate([3, 1, 4], SamplingParams(temperature=0.0, max_tokens=6))
+    handles = []
+
+    def submit():
+        n = int(rng.randint(2, 15))
+        prompt = [int(t) for t in rng.randint(1, 200, size=n)]
+        if rng.rand() < 0.4:
+            sp = SamplingParams(temperature=0.8, top_p=0.9, top_k=20,
+                                max_tokens=int(rng.randint(1, 9)),
+                                seed=int(rng.randint(1, 1000)))
+        else:
+            stops = (free_run[int(rng.randint(0, 6))],) if rng.rand() < 0.5 else ()
+            sp = SamplingParams(temperature=0.0, stop_token_ids=stops,
+                                max_tokens=int(rng.randint(1, 9)))
+        handles.append(eng.submit(prompt, sp))
+
+    for _ in range(7):
+        submit()
+    for _ in range(3):
+        eng.step()
+    for _ in range(4):
+        submit()
+    _drive(eng)
+    return handles
+
+
+def _script_first_token_is_a_stop_id(eng):
+    prompt = [3, 1, 4, 1, 5]
+    first = eng.generate(prompt, SamplingParams(temperature=0.0, max_tokens=1))[0][0]
+    live = eng.submit([9, 9, 8], SamplingParams(temperature=0.0, max_tokens=6))
+    eng.step()
+    ends_at_once = eng.submit(prompt, SamplingParams(
+        temperature=0.0, max_tokens=5, stop_token_ids=(first,)))
+    _drive(eng)
+    return [live, ends_at_once]
+
+
+def _script_one_token(eng):
+    live = eng.submit([9, 9, 8], SamplingParams(temperature=0.0, max_tokens=6))
+    eng.step()
+    one = eng.submit([2, 7, 1, 8], SamplingParams(temperature=0.0, max_tokens=1))
+    alone = eng.submit([6, 6], SamplingParams(temperature=0.0, max_tokens=1))
+    _drive(eng)
+    return [live, one, alone]
+
+
+def _script_cancel_before_the_read(eng):
+    h = eng.submit([2, 4, 6], SamplingParams(temperature=0.0, max_tokens=20))
+    eng.step()  # placed; a pipeline of three leaves the first token unread
+    h.cancel()
+    _drive(eng)
+    return [h]
+
+
+def _script_back_to_back(eng):
+    sp = SamplingParams(temperature=0.0, max_tokens=7)
+    handles = [eng.submit([i + 1, i + 2, i + 3], sp) for i in range(3)]
+    _drive(eng)
+    return handles
+
+
+@pytest.mark.parametrize("script, over", [
+    (_script_mix, {}),
+    (_script_mix, {"decode_pipeline": 1}),
+    (_script_mix, {"prefill_chunk_tokens": 8}),
+    (_script_first_token_is_a_stop_id, {}),
+    (_script_one_token, {}),
+    (_script_cancel_before_the_read, {"decode_pipeline": 3}),
+    (_script_back_to_back, {}),
+], ids=["mix", "mix-unpipelined", "mix-interleaved", "first-is-stop",
+        "one-token", "cancel-before-read", "back-to-back"])
+def test_deferred_first_token_streams_equal_the_eager_ones(script, over):
+    """Token for token and terminal for terminal, a request's events are
+    those of the same engine made to read every first token at once."""
+    deferred = _placement_engine(**over)
+    eager = _placement_engine(eager=True, **over)
+    got = [_events(h) for h in script(deferred)]
+    want = [_events(h) for h in script(eager)]
+    assert got == want
+    assert all(evs[-1][1] is not None for evs in got)
+    m = deferred.metrics
+    assert m["placements_deferred"] == m["prefill_steps"] > 0
+    assert eager.metrics["placements_deferred"] == 0
+    assert m["requests_finished"] == m["requests_submitted"]
+    assert not deferred._inflight and not any(s.active for s in deferred._slots)
+
+
+def test_a_cancel_between_placement_and_read_still_gets_its_first_token():
+    eng = _placement_engine(decode_pipeline=3)
+    h = eng.submit([2, 4, 6], SamplingParams(temperature=0.0, max_tokens=20))
+    eng.step()
+    # Placed, one chunk dispatched behind the first token, nothing read.
+    assert [ch.placement is not None for ch in eng._inflight] == [True, False]
+    assert h._queue.empty() and eng._slots[0].active
+    h.cancel()
+    eng.step()
+    evs = _events(h)
+    assert [e[0] is not None for e in evs] == [True, False]
+    assert evs[-1][1:] == (FinishReason.CANCELLED, 1)
+    _drive(eng)
+    assert not eng._inflight
+
+
+def _grammar_request(eng):
+    from omnia_tpu.engine.grammar import compile_json_schema
+
+    g = compile_json_schema(
+        {"type": "object", "properties": {"a": {"type": "integer"}},
+         "required": ["a"]}, ByteTokenizer())
+    sp = SamplingParams(temperature=0.0, max_tokens=24, stop_token_ids=(0,))
+    return eng.submit(ByteTokenizer().encode("json"), sp, grammar=g)
+
+
+@pytest.mark.parametrize("over, submit, deferred", [
+    ({}, None, 2),
+    ({"grammar": True, "grammar_max_states": 256}, None, 2),
+    ({"grammar": True, "grammar_max_states": 256}, _grammar_request, 0),
+    ({"spec_decode": 3}, None, 0),
+], ids=["plain", "grammar-engine-plain-request", "grammar-request", "spec-decode"])
+def test_placements_deferred_counts_the_placements_that_read_nothing(
+        over, submit, deferred):
+    """One a placement whose first token went on the pipeline: all of
+    them for plain requests, none under a grammar or speculation."""
+    eng = _placement_engine(prefill_buckets=(8, 16, 32), **over)
+    sp = SamplingParams(temperature=0.0, max_tokens=5)
+    handles = [
+        submit(eng) if submit else eng.submit([i + 1, i + 2, i + 3], sp)
+        for i in range(2)
+    ]
+    _drive(eng)
+    for h in handles:
+        toks, fin = h.collect_tokens(timeout=5)
+        assert toks and fin.finish_reason in (FinishReason.LENGTH, FinishReason.STOP)
+    m = eng.metrics
+    assert m["prefill_steps"] == 2
+    assert m["placements_deferred"] == deferred
+
+
+@pytest.mark.parametrize("path", ["fresh", "extend", "interleaved"])
+def test_a_placement_and_a_finish_are_one_program_call_each(path, monkeypatch):
+    """The slot's device state is written by ``activate_slot`` at
+    placement and ``release_slot`` at the finish, once each, and by no
+    ``.at[].set`` from the engine thread (the interleaved path parks the
+    placing slot's position once a piece, before its dispatch: not part
+    of the activation)."""
+    from jax._src.numpy import array_methods
+
+    over = {"prefill_chunk_tokens": 8} if path == "interleaved" else {}
+    eng = _placement_engine(num_slots=2, **over)
+    sp = SamplingParams(temperature=0.0, max_tokens=6)
+    prompt = list(range(1, 21)) if path != "fresh" else [5, 6, 7]
+    eng.generate([1, 2, 3], sp)  # every program compiled
+    calls = {"activate": 0, "release": 0, "at": 0}
+
+    def counted(name, fn):
+        def call(*a, **k):
+            calls[name] += 1
+            return fn(*a, **k)
+        return call
+
+    eng._activate_slot_fn = counted("activate", eng._activate_slot_fn)
+    eng._release_slot_fn = counted("release", eng._release_slot_fn)
+    for method in ("set", "add", "get"):
+        monkeypatch.setattr(
+            array_methods._IndexUpdateRef, method,
+            counted("at", getattr(array_methods._IndexUpdateRef, method)))
+    if path == "interleaved":
+        # A live slot, so that the placement is split into mixed steps.
+        eng.submit([9, 9], SamplingParams(temperature=0.0, max_tokens=40))
+        eng.step()
+        calls.update(activate=0, release=0, at=0)
+    h = eng.submit(prompt, sp)
+    while not h._queue.qsize():
+        eng.step()
+    pieces = eng.metrics["mixed_steps"] if path == "interleaved" else 0
+    assert (calls["activate"], calls["release"]) == (1, 0)
+    assert calls["at"] == pieces
+    while any(s.active and s.request.request_id == h.request_id
+              for s in eng._slots):
+        eng.step()
+    assert h.collect_tokens(timeout=5)[1].finish_reason == FinishReason.LENGTH
+    assert (calls["activate"], calls["release"]) == (1, 1)
+    assert calls["at"] == pieces
+
+
+def test_the_first_token_read_is_under_the_watchdog():
+    """The read goes through ``_sync_chunk_host``: a hang injected there
+    (engine/faults.py) trips the watchdog at the first token, which a
+    read inside the placement never could."""
+    from omnia_tpu.engine.faults import FaultPlan, WatchdogTimeout
+
+    eng = _placement_engine(watchdog_s=0.1)
+    eng.generate([1, 2, 3], SamplingParams(temperature=0.0, max_tokens=2))
+    eng._fault_plan = FaultPlan(hang_dispatch_s=0.6, hang_count=1)
+    try:
+        h = eng.submit([4, 5, 6], SamplingParams(temperature=0.0, max_tokens=4))
+        tokens0 = eng.metrics["tokens_generated"]
+        with pytest.raises(WatchdogTimeout):
+            eng.step()
+        # Tripped at the first entry of the pipeline: placed, the step
+        # behind it dispatched and still in flight, no token out.
+        assert eng._fault_plan.fired["hangs"] == 1
+        assert eng.metrics["watchdog_trips"] == 1
+        assert eng.metrics["tokens_generated"] == tokens0
+        assert [ch.placement for ch in eng._inflight] == [None]
+        assert eng._slots[0].active and h._queue.empty()
+        eng._recover("hung first-token read")
+        evs = _events(h)
+        assert evs == [(None, FinishReason.ERROR, 0)]
+        toks, fin = eng.generate([4, 5, 6], SamplingParams(temperature=0.0, max_tokens=4))
+        assert len(toks) == 4 and fin.finish_reason == FinishReason.LENGTH
+    finally:
+        eng.stop()
+
+
+def test_a_prefill_error_surfaces_at_the_read_and_reaches_the_handle():
+    """Outside the placement's own error surface the request sits in its
+    slot, so recovery's ``_fail_all`` ends it."""
+    eng = _placement_engine()
+    sp = SamplingParams(temperature=0.0, max_tokens=4)
+    eng.generate([1, 2, 3], sp)
+    orig = eng._sync_chunk_host
+
+    def failing(toks):
+        eng._sync_chunk_host = orig
+        raise RuntimeError("device error in the prefill")
+
+    eng._sync_chunk_host = failing
+    h = eng.submit([4, 5, 6], sp)
+    with pytest.raises(RuntimeError, match="device error"):
+        eng.step()
+    assert eng._slots[0].active and h._queue.empty()
+    eng._recover("engine step failed")
+    (ev,) = _events(h)
+    assert ev[1] == FinishReason.ERROR
+    assert eng.metrics["requests_finished"] == eng.metrics["requests_submitted"]
+    assert len(eng.generate([4, 5, 6], sp)[0]) == 4

@@ -129,16 +129,19 @@ def test_phase_spans_nest_under_step_and_carry_request_ids(tmp_path):
     # the one-step program, ahead of the step being read.
     waiting = [d[3] for d in _named(events, phases.DECODE_DISPATCH)
                if places[0][2] <= d[1] and d[2] <= places[1][1]]
-    assert waiting[0]["chunk"] == 4 and not waiting[0]["single"]
-    assert not waiting[0]["blocked"] and waiting[0]["inflight"] == 0
-    assert len(waiting) == 3
+    # Behind the prefill, whose first token is still unread in the
+    # pipeline: one step, though nobody waits yet.
+    assert waiting[0]["chunk"] == 1 and waiting[0]["single"]
+    assert not waiting[0]["blocked"] and waiting[0]["inflight"] == 1
+    assert len(waiting) == 6
     assert all(d["chunk"] == 1 and d["single"] and d["active"] == 1
                and d["blocked"] and d["inflight"] == 1 for d in waiting[1:])
     flush = _named(events, phases.FLUSH_PIPELINE)
     assert len(flush) == 1 and flush[0][3]["chunks"] == 1
     emitted = sum(e[3]["tokens"] for e in _named(events, phases.EMIT))
-    # The first token of each comes from its prefill.
-    assert emitted == (len(got_first) - 1) + (len(got_second) - 1)
+    # The first token of each comes from its prefill, and is read and
+    # emitted as an entry of the pipeline.
+    assert emitted == len(got_first) + len(got_second)
     assert sum(e[3]["finished"] for e in _named(events, phases.EMIT)) == 2
 
 
@@ -170,34 +173,36 @@ def test_counters_against_a_scripted_schedule():
     m = eng.metrics
     a = eng.submit([1, 2, 3], GREEDY)
     eng.step()
-    # Placed (first token from the prefill), one chunk of 4 dispatched and
-    # left in flight: nobody waits, so the pipeline may run two deep.
-    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (1, 0)
-    assert (m["decode_steps"], m["decode_slot_steps"]) == (4, 4)
+    # Placed; one step dispatched behind the prefill (its first token is
+    # unread, so no chunk is committed yet), the first token read, the step
+    # left in flight: the pipeline may run two deep.
+    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (1, 1)
+    assert (m["decode_steps"], m["decode_slot_steps"]) == (1, 1)
     assert m["pipeline_flushes"] == 0
+    assert (len(eng._inflight), m["tokens_generated"]) == (1, 1)
     b = eng.submit([4, 5, 6], GREEDY)
     eng.step()
     # `b` waits and is blocked: nothing is flushed for it. One step is
-    # dispatched ahead of the chunk in flight, then the chunk is read (4 of
-    # a's 7 decode tokens) and the step stays in flight.
+    # dispatched ahead of the step in flight, which is then read, and the
+    # new one stays in flight.
     assert m["pipeline_flushes"] == 0
-    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 1)
+    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 2)
     assert m["decode_dispatches_blocked"] == 1
-    assert (m["decode_steps"], m["decode_slot_steps"]) == (5, 5)
-    assert (len(eng._inflight), m["tokens_generated"]) == (1, 5)
+    assert (m["decode_steps"], m["decode_slot_steps"]) == (2, 2)
+    assert (len(eng._inflight), m["tokens_generated"]) == (1, 2)
     _drain(eng)
     assert len(a.collect_tokens(timeout=60)[0]) == 8
     assert len(b.collect_tokens(timeout=60)[0]) == 8
-    # a: 4 + 1 + 1 + 1 steps, the last three one-step calls while b was
-    # blocked, each dispatched with its predecessor in flight. a's end is
-    # foreseeable from its budget, so no step is dispatched past it and
-    # nothing is in flight when b becomes placeable: no flush at all. b
-    # decodes alone: 4 + 3, where the tail picks the smallest variant that
-    # covers it (the chunk of 4, one step of it garbage).
+    # a: 1 + 6 one-step calls, the six while b was blocked, each dispatched
+    # with its predecessor in flight. a's end is foreseeable from its
+    # budget, so no step is dispatched past it and nothing is in flight
+    # when b becomes placeable: no flush at all. b decodes alone: one step
+    # behind its prefill, then 4 + 2, where the tail picks the smallest
+    # variant that covers it (the chunk of 4, two steps of it garbage).
     assert m["pipeline_flushes"] == 0
-    assert (m["decode_dispatches_single"], m["decode_dispatches_blocked"]) == (3, 3)
-    assert m["decode_dispatches"] == 4 + 2
-    assert m["decode_steps"] == 7 + 8
+    assert (m["decode_dispatches_single"], m["decode_dispatches_blocked"]) == (8, 6)
+    assert m["decode_dispatches"] == 7 + 3
+    assert m["decode_steps"] == 7 + 9
     assert m["decode_slot_steps"] == m["decode_steps"]  # one slot, always live
     assert m["tokens_generated"] == 16
 
@@ -214,9 +219,10 @@ def test_an_unforeseen_end_is_found_a_step_late_and_flushed_once():
     base = dict(m)
     a = eng.submit([1, 2, 3], SamplingParams(
         temperature=0.0, max_tokens=8, stop_token_ids=(want_a[5],)))
-    eng.step()
+    eng.step()  # placed, one step behind the prefill, first token read
     b = eng.submit([4, 5, 6], GREEDY)
-    eng.step()  # single ahead; reads the chunk of 4
+    for _ in range(4):
+        eng.step()  # single ahead; reads the step before it
     eng.step()  # single ahead; reads the stop id: a ends, one step in flight
     assert a.collect_tokens(timeout=60)[0] == want_a[:5]
     assert m["pipeline_flushes"] == base["pipeline_flushes"]
@@ -230,11 +236,12 @@ def test_an_unforeseen_end_is_found_a_step_late_and_flushed_once():
     delta = {k: m[k] - base[k] for k in (
         "decode_dispatches", "decode_dispatches_single",
         "decode_dispatches_blocked", "decode_steps", "tokens_generated")}
-    # a: chunk of 4 + two blocked single steps (the second one garbage);
-    # b: 4 + 4. Tokens: a 5, b 8.
+    # a: one step behind its prefill + five blocked single steps (the last
+    # one garbage); b: one step behind its prefill, then 4 + 4. Tokens: a 5,
+    # b 8.
     assert delta == {
-        "decode_dispatches": 3 + 2, "decode_dispatches_single": 2,
-        "decode_dispatches_blocked": 2, "decode_steps": 6 + 8,
+        "decode_dispatches": 6 + 3, "decode_dispatches_single": 6 + 1,
+        "decode_dispatches_blocked": 5, "decode_steps": 6 + 9,
         "tokens_generated": 13,
     }
 
@@ -251,14 +258,15 @@ def test_a_placeable_waiting_request_keeps_the_synchronous_schedule():
     b = eng.submit([4, 5, 6], GREEDY)
     c = eng.submit([7, 8, 9], GREEDY)
     eng.step()
-    # Flushed for b (4 of a's tokens), b placed; c still waits and has no
-    # slot: the next dispatch is one step for both live slots, and since c
-    # is blocked it stays in flight.
+    # Flushed for b (the step behind a's prefill: one of a's tokens), b
+    # placed; c still waits and has no slot: the next dispatch is one step
+    # for both live slots, and since c is blocked it stays in flight while
+    # b's first token is read.
     assert m["pipeline_flushes"] == 1 and m["prefill_steps"] == 2
-    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 1)
+    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 2)
     assert m["decode_dispatches_blocked"] == 1
-    assert (m["decode_steps"], m["decode_slot_steps"]) == (5, 4 + 2)
-    assert (len(eng._inflight), m["tokens_generated"]) == (1, 1 + 4 + 1)
+    assert (m["decode_steps"], m["decode_slot_steps"]) == (2, 1 + 2)
+    assert (len(eng._inflight), m["tokens_generated"]) == (1, 1 + 1 + 1)
     _drain(eng)
     for h in (a, b, c):
         toks, fin = h.collect_tokens(timeout=60)
@@ -276,15 +284,17 @@ def test_nobody_blocked_counts_nothing_blocked():
     eng.step()
     b = eng.submit([4, 5, 6], GREEDY)
     eng.step()
-    # Flushed for b, b placed, nobody waits any more: a full chunk.
+    # Flushed for b, b placed, nobody waits any more: one step behind b's
+    # prefill (its first token is unread), full chunks from the next step on.
     assert m["pipeline_flushes"] == 1
-    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 0)
-    assert (m["decode_steps"], m["decode_slot_steps"]) == (8, 4 + 8)
+    assert (m["decode_dispatches"], m["decode_dispatches_single"]) == (2, 2)
+    assert (m["decode_steps"], m["decode_slot_steps"]) == (2, 1 + 2)
     _drain(eng)
     assert len(a.collect_tokens(timeout=60)[0]) == 8
     assert len(b.collect_tokens(timeout=60)[0]) == 8
     assert m["pipeline_flushes"] == 1
-    assert m["decode_dispatches_single"] == m["decode_dispatches_blocked"] == 0
+    # The two one-step calls are those behind the two prefills.
+    assert (m["decode_dispatches_single"], m["decode_dispatches_blocked"]) == (2, 0)
     assert m["tokens_generated"] == 16
 
 
@@ -345,6 +355,13 @@ def test_programs_compiled_after_warmup_are_counted(tmp_path, monkeypatch):
         assert eng.metrics["programs_compiled_serving"] == 0
         eng.generate([1, 2, 3], GREEDY)
         assert eng.metrics["programs_compiled_serving"] == 0  # all warmed
+        # Placements and finishes with other slots live: the two programs
+        # that write a slot's state meet vectors that came out of a decode
+        # step, of each other and of a fresh allocation.
+        handles = [eng.submit([i + 1, i + 2, i + 3], GREEDY) for i in range(5)]
+        _drain(eng)
+        assert all(len(h.collect_tokens(timeout=60)[0]) == 8 for h in handles)
+        assert eng.metrics["programs_compiled_serving"] == 0
         import numpy as np
 
         # A program warm-up never saw (numpy in: nothing else to compile).
@@ -476,3 +493,35 @@ def test_moe_scopes():
     ).lower(params, ck, cv).as_text(debug_info=True)
     assert _in_op_names("mlp/moe.experts", text)
     assert _in_op_names("moe.experts/moe.route", text)
+
+
+def test_place_span_says_deferred_and_the_first_token_read_is_chunk_zero(tmp_path):
+    """A plain request's placement ends with its first token unread
+    (`deferred` on the span); the token is then read as an entry of the
+    pipeline: a `chunk_sync` of no decode step, and an `emit` of one
+    token. Made to read at once, the same engine says so and writes no
+    such entry."""
+    eng = _tiny_engine(num_slots=1)
+    eng.generate([1, 2, 3], GREEDY)  # compile outside the session
+    with _profiled(tmp_path) as spans:
+        eng.generate([1, 2, 3], GREEDY)
+        eng._defers_first_token = lambda request: False
+        eng.generate([4, 5, 6], GREEDY)
+    (events,) = spans().values()
+    places = _named(events, phases.PLACE)
+    assert [p[3]["deferred"] for p in places] == [1, 0]
+    assert all({"slot", "reuse", "seeded"} <= set(p[3]) for p in places)
+    syncs = _named(events, phases.CHUNK_SYNC)
+    first_reads = [s for s in syncs if s[3]["chunk"] == 0]
+    assert len(first_reads) == 1
+    # After its placement, before any chunk of that request is read, and
+    # before the second placement.
+    (read,) = first_reads
+    assert places[0][2] <= read[1] and read[2] <= places[1][1]
+    assert not any(s[1] < read[1] and places[0][2] <= s[1] for s in syncs)
+    emits = [e for e in _named(events, phases.EMIT) if read[2] <= e[1]]
+    assert emits[0][3]["tokens"] == 1 and emits[0][3]["finished"] == 0
+    # The decode step that follows the prefill was dispatched before the read.
+    ahead = [d for d in _named(events, phases.DECODE_DISPATCH)
+             if places[0][2] <= d[1] and d[2] <= read[1]]
+    assert len(ahead) == 1 and ahead[0][3]["inflight"] == 1

@@ -467,7 +467,8 @@ class TestMetricsKeyStability:
         "decode_dispatches_blocked", "decode_slot_steps", "decode_kv_blocks",
         "decode_steps_sampling", "decode_steps_filtering",
         "moe_assignments_held", "moe_experts_hit",
-        "pipeline_flushes", "programs_compiled_serving",
+        "pipeline_flushes", "placements_deferred",
+        "programs_compiled_serving",
         "prefix_reuse_tokens", "session_offloads", "session_restores",
         "session_exports", "session_imports",
         "decode_dispatch_s", "decode_sync_s", "prefill_dispatch_s",
