@@ -1,0 +1,8 @@
+"""The scheduling pass that claimed the request, up to the claim: the pipeline read out for it (`LatencyBreakdown.flush_s`); the pipeline's depth bounds it.
+Mean over the band of the requests around the median first token (40th-60th percentile of first - due) (`harness/first_token.py`), so that the band's stages add up to its mean first token."""
+from harness.first_token import stage_ms
+
+read = stage_ms("flush", "ttft50")
+
+LAYER, UNIT, BETTER = "engine scheduler", "ms", "lower"
+SOURCE, MOVES = "program_span", "ttft_p50_ms"

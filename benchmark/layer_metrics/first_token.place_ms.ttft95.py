@@ -1,0 +1,8 @@
+"""Claim -> the placement's last prefill piece on the device's queue (`LatencyBreakdown.place_s`): the host before the prefill.
+Mean over the band of the requests of the first-token tail (90th percentile of first - due and above; the cell judges `gap_p95_ms` since PR 34) (`harness/first_token.py`), so that the band's stages add up to its mean first token."""
+from harness.first_token import stage_ms
+
+read = stage_ms("place", "ttft95")
+
+LAYER, UNIT, BETTER = "engine scheduler", "ms", "lower"
+SOURCE, MOVES = "program_span", "gap_p95_ms"

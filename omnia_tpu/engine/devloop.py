@@ -37,15 +37,25 @@ class _InflightChunk:
     A placement's first token rides the same pipeline, in order: ``toks``
     is then the prefill's scalar, ``active`` the one (slot, request_id)
     placed, and ``placement`` (``None`` on a decode chunk) the flight
-    recorder's placement note, written when the token is read."""
+    recorder's placement note, written when the token is read.
 
-    __slots__ = ("toks", "active", "dispatch_s", "placement")
+    ``seq`` is the ``seq`` of the ``omnia.engine.decode_dispatch`` span
+    the chunk was dispatched under, for the ``.chunk_sync`` and ``.emit``
+    that read it (engine/phases.py); ``None`` with no profiler session."""
 
-    def __init__(self, toks, active, dispatch_s, placement=None):
+    __slots__ = ("toks", "active", "dispatch_s", "placement", "seq")
+
+    def __init__(self, toks, active, dispatch_s, placement=None, seq=None):
         self.toks = toks
         self.active = active
         self.dispatch_s = dispatch_s
         self.placement = placement
+        self.seq = seq
+
+    @property
+    def seq_attr(self) -> dict:
+        """``seq`` as an attribute of the spans that read this chunk back."""
+        return {} if self.seq is None else {"seq": self.seq}
 
     @property
     def steps(self) -> int:

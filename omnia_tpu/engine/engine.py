@@ -302,6 +302,7 @@ class InferenceEngine(
         # placement): invisible to queue_depth AND active_slots, so the
         # graceful-drain wait must count them explicitly.
         self._placing = 0  # guarded-by: _lock
+        self._pass_at, self._prefill_seq = 0.0, itertools.count()  # flight's t_pass; spans' seq
         self._lock = threading.Lock()
         self._req_counter = itertools.count()
         # Sessionful KV registry — engine-thread-owned: only step() and the

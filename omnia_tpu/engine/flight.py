@@ -8,8 +8,10 @@ seeding), each prefill piece / mixed step / decode chunk with the
 host-side dispatch-vs-sync wall split, each speculative verify step
 with its proposed/accepted counts, grammar attach, session
 offload/restore, coordinator failover/resubmit/shed, terminal — plus a
-per-request :class:`LatencyBreakdown` (queue_s, placement_s, prefill_s,
-ttft_s, per-token decode_s, stall_steps) attached to terminal events.
+per-request :class:`LatencyBreakdown` (the first token's life in stages
+that tile it: slot_wait_s + loop_wait_s + flush_s = queue_s, then
+place_s, then prefill_s, to ttft_s; per-token decode_s, stall_steps)
+attached to terminal events.
 
 Design constraints, in order:
 
@@ -136,19 +138,49 @@ class FlightEvent:
 class LatencyBreakdown:
     """Where one request's wall time went, stage by stage.
 
-    ``queue_s`` (submit→claim) + ``placement_s`` (claim→slot active,
-    prefill included) + ``decode_s`` (first token→terminal) sum to the
-    request's wall time up to the tiny claim/activate bookkeeping gaps
-    (tests pin the sum within 5%). ``prefill_s`` is the host dispatch
-    wall spent inside placement on prefill/extend/seed programs (a
-    subset of ``placement_s``); ``ttft_s`` is submit→first token;
+    The first token's life is tiled by three stages, each cut at a
+    ``time.monotonic()`` read taken where the work happens:
+    ``queue_s + place_s + prefill_s == ttft_s``.
+
+    ``queue_s`` (submit→claim) is itself tiled by three parts, cut at the
+    time from which the host's books showed a slot the request could take
+    (sessions.py ``_free_since``) and at the start of the scheduling pass
+    that claimed it, both clipped into
+    [submit, claim] in that order: ``slot_wait_s`` (no slot was free:
+    admission and slots), ``loop_wait_s`` (a slot was free and the engine
+    thread had not come round: it was reading or emitting a chunk, so the
+    chunk's length bounds it) and ``flush_s`` (the pass that claimed it:
+    the pipeline read out for it, so its depth bounds it, and the claim).
+    A request never claimed has all of its life in ``queue_s`` and the
+    parts zero.
+
+    ``place_s`` is claim→the placement's last prefill / extend / mixed
+    piece on the device's queue: the host before the prefill (session
+    restore, pool seed, the bucket's copy, the enqueue). ``prefill_s`` is
+    from there to the first token's stamp (``handle.first_token_at``): the
+    device's prefill as the host sees it, through the read-back and that
+    token's emit; ``read_blocked_s`` is the part of it the engine thread
+    spent blocked in the read (near all of it: the host added nothing;
+    near none: the token lay ready before the thread asked).
+    ``placement_s`` is their sum, ``place_s + prefill_s`` (claim→first
+    token), kept for the sum ``queue_s + placement_s + decode_s``, which
+    is the request's wall time (tests pin it within 5%). No field times
+    an enqueue: that wall is ``prefill_piece.dispatch_s`` and the
+    ``prefill_dispatch_s`` counter.
+
+    ``ttft_s`` is submit→first token; ``decode_s`` first token→terminal;
     ``decode_s_per_token`` is the mean inter-token gap; ``stall_steps``
     counts engine decode-stall steps observed during this request's
     lifetime (prefill-first dispatches that idled live decode)."""
 
     queue_s: float = 0.0
-    placement_s: float = 0.0
+    slot_wait_s: float = 0.0
+    loop_wait_s: float = 0.0
+    flush_s: float = 0.0
+    place_s: float = 0.0
     prefill_s: float = 0.0
+    read_blocked_s: float = 0.0
+    placement_s: float = 0.0
     ttft_s: float = 0.0
     decode_s: float = 0.0
     decode_s_per_token: float = 0.0
@@ -172,14 +204,20 @@ class _Open:
     the recorder — first-token time arrives at the terminal from the
     handle's own ``first_token_at`` stamp (same monotonic domain)."""
 
-    __slots__ = ("submitted", "claimed", "placed", "prefill_s",
-                 "stall_base", "span")
+    __slots__ = ("submitted", "freed", "passed", "claimed", "enqueued",
+                 "read_blocked_s", "placed", "stall_base", "span")
 
     def __init__(self, now: float, stall_base: int, span) -> None:
         self.submitted = now
+        # The two cuts inside the queue wait, already clipped into
+        # [submitted, claimed] in that order (note_claim).
+        self.freed = now
+        self.passed = now
         self.claimed: Optional[float] = None
+        # The placement's last piece on the device's queue (note_placement).
+        self.enqueued: Optional[float] = None
+        self.read_blocked_s = 0.0
         self.placed: Optional[float] = None
-        self.prefill_s = 0.0
         self.stall_base = stall_base
         self.span = span
 
@@ -267,30 +305,66 @@ class FlightRecorder:
             "n_prompt": n_prompt, "traced": span is not None,
         })
 
-    def note_claim(self, request_id: str) -> None:
-        wait = None
+    def note_claim(self, request_id: str, t_free: float = 0.0,
+                   t_pass: float = 0.0) -> Optional[dict]:
+        """Scheduler claimed the request. ``t_free`` is since when a slot
+        it could take has been free on the host's books and ``t_pass`` the
+        start of the scheduling pass that claims it (both on this
+        recorder's clock; the default, 0.0, reads as "before the
+        submit"). Returns the queue wait's three parts in seconds, which
+        are the event's attrs too, or None for a request the recorder
+        never saw."""
+        parts = None
         with self._lock:
             o = self._open.get(request_id)
             if o is not None:
                 o.claimed = self._clock()
-                wait = o.claimed - o.submitted
-        self._record("claim", request_id, {})
-        if wait is not None:
-            self.hist["queue_wait"].observe(wait)
+                o.freed = min(max(t_free, o.submitted), o.claimed)
+                o.passed = min(max(t_pass, o.freed), o.claimed)
+                parts = {
+                    "slot_wait_s": o.freed - o.submitted,
+                    "loop_wait_s": o.passed - o.freed,
+                    "flush_s": o.claimed - o.passed,
+                }
+        self._record("claim", request_id, dict(parts or {}))
+        if parts is not None:
+            self.hist["queue_wait"].observe(sum(parts.values()))
+        return parts
 
     def note_placement(self, request_id: str, slot: int, n_prompt: int,
                        reuse: int = 0, seeded: int = 0,
-                       prefill_s: float = 0.0, stalled: bool = False) -> None:
+                       stalled: bool = False,
+                       t_enq: Optional[float] = None,
+                       t_read0: float = 0.0,
+                       t_read: float = 0.0) -> Optional[dict]:
+        """The slot is active and its first token about to emit.
+        ``t_enq`` is when the placement's last prefill / extend / mixed
+        piece was on the device's queue, ``t_read0`` / ``t_read`` the
+        start and end of the first token's read-back (this recorder's
+        clock). Returns ``place_s``, ``prefill_s`` and ``read_blocked_s``
+        as the event carries them, or None for a request the recorder
+        never saw. The event is stamped as the emit begins, so its
+        ``prefill_s`` ends microseconds before the handle's own
+        first-token stamp, where the terminal's breakdown ends it."""
+        stages = None
         with self._lock:
             o = self._open.get(request_id)
             if o is not None:
                 o.placed = self._clock()
-                o.prefill_s += prefill_s
+                claimed = o.claimed if o.claimed is not None else o.placed
+                enq = o.placed if t_enq is None else t_enq
+                o.enqueued = min(max(enq, claimed), o.placed)
+                o.read_blocked_s = max(t_read - t_read0, 0.0)
+                stages = {
+                    "place_s": o.enqueued - claimed,
+                    "prefill_s": o.placed - o.enqueued,
+                    "read_blocked_s": o.read_blocked_s,
+                }
         self._record("placement", request_id, {
             "slot": slot, "n_prompt": n_prompt, "reuse": reuse,
-            "seeded": seeded, "prefill_s": prefill_s,
-            "stalled": stalled,
+            "seeded": seeded, "stalled": stalled, **(stages or {}),
         })
+        return stages
 
     def note_prefill_piece(self, request_id: str, take: int, bucket: int,
                            dispatch_s: float) -> None:
@@ -300,10 +374,6 @@ class FlightRecorder:
 
     def note_mixed_step(self, request_id: str, take: int, bucket: int,
                         dispatch_s: float) -> None:
-        with self._lock:
-            o = self._open.get(request_id)
-            if o is not None:
-                o.prefill_s += dispatch_s
         self._record("mixed_step", request_id, {
             "take": take, "bucket": bucket, "dispatch_s": dispatch_s,
         })
@@ -427,16 +497,29 @@ class FlightRecorder:
             if o is not None:
                 span = o.span
                 if o.claimed is not None:
+                    bd.slot_wait_s = o.freed - o.submitted
+                    bd.loop_wait_s = o.passed - o.freed
+                    bd.flush_s = o.claimed - o.passed
                     bd.queue_s = o.claimed - o.submitted
+                    # The first token's stamp ends the prefill; a request
+                    # that never emitted one (its first token a stop id, a
+                    # failed placement) ends it where the placement was
+                    # noted, else now.
                     end = o.placed if o.placed is not None else now
-                    bd.placement_s = max(end - o.claimed, 0.0)
+                    if first_token_at is not None:
+                        end = first_token_at
+                    enq = o.enqueued if o.enqueued is not None else end
+                    bd.place_s = max(min(enq, end) - o.claimed, 0.0)
+                    bd.prefill_s = max(end - enq, 0.0)
+                    bd.read_blocked_s = o.read_blocked_s
+                    bd.placement_s = bd.place_s + bd.prefill_s
                 else:
                     # Never claimed (queue-reaped deadline/cancel/drain
                     # shed): the WHOLE lifetime was queue wait — exactly
                     # the requests that prove queue pressure, so an
                     # all-zero breakdown here would blind the runbook.
+                    # Its parts stay zero: no slot, no pass, no claim.
                     bd.queue_s = max(now - o.submitted, 0.0)
-                bd.prefill_s = o.prefill_s
                 if first_token_at is not None:
                     bd.ttft_s = max(first_token_at - o.submitted, 0.0)
                     bd.decode_s = max(now - first_token_at, 0.0)
@@ -514,9 +597,11 @@ def to_chrome_trace(events: list,
 
     Layout: tid 0 is the engine's step row (decode chunks, mixed steps,
     prefill pieces, offload/restore, failover/resubmit markers); each
-    request gets its own named thread row with ``queue`` → ``placement``
-    → ``decode`` complete events reconstructed from its lifecycle
-    events, and an instant at the terminal carrying the breakdown."""
+    request gets its own named thread row with ``slot_wait`` →
+    ``loop_wait`` → ``flush`` → ``place`` → ``prefill`` → ``decode``
+    complete events (:class:`LatencyBreakdown`'s stages, from the parts
+    its ``claim`` and ``placement`` events carry), and an instant at the
+    terminal carrying the breakdown."""
     evs = [e.to_dict() if isinstance(e, FlightEvent) else dict(e)
            for e in events]
     evs.sort(key=lambda e: e["seq"])
@@ -533,6 +618,8 @@ def to_chrome_trace(events: list,
             # durations in any cold-start or scale-down dump, so the
             # base must account for them.
             return e["mono"] - attrs.get("seconds", 0.0)
+        if e["kind"] == "placement":  # its place and prefill rows end at it
+            return e["mono"] - attrs.get("place_s", 0.0) - attrs.get("prefill_s", 0.0)
         return e["mono"] - attrs.get("dispatch_s", 0.0) - attrs.get("sync_s", 0.0)
 
     if profiler_offset_s is None:
@@ -587,19 +674,29 @@ def to_chrome_trace(events: list,
         tid = tid_for(rid)
         sub, claim = stages.get("submit"), stages.get("claim")
         placed, term = stages.get("placement"), stages.get("terminal")
-        if sub is not None and claim is not None:
-            out.append({
-                "ph": "X", "pid": 1, "tid": tid, "name": "queue",
-                "ts": us(sub["mono"]),
-                "dur": round((claim["mono"] - sub["mono"]) * 1e6, 1),
-            })
-        if claim is not None and placed is not None:
-            out.append({
-                "ph": "X", "pid": 1, "tid": tid, "name": "placement",
-                "ts": us(claim["mono"]),
-                "dur": round((placed["mono"] - claim["mono"]) * 1e6, 1),
-                "args": placed.get("attrs", {}),
-            })
+        # The first token's life as LatencyBreakdown tiles it, from the
+        # parts the claim and the placement carry: the queue's three laid
+        # forward from the submit, place and prefill back from the
+        # placement event (stamped as the first token's emit begins).
+        parts = claim.get("attrs", {}) if claim is not None else {}
+        if sub is not None and "flush_s" in parts:
+            at = sub["mono"]
+            for name in ("slot_wait", "loop_wait", "flush"):
+                out.append({
+                    "ph": "X", "pid": 1, "tid": tid, "name": name,
+                    "ts": us(at), "dur": round(parts[name + "_s"] * 1e6, 1),
+                })
+                at += parts[name + "_s"]
+        attrs = placed.get("attrs", {}) if placed is not None else {}
+        if "prefill_s" in attrs:
+            at = placed["mono"] - attrs["prefill_s"] - attrs["place_s"]
+            for name in ("place", "prefill"):
+                out.append({
+                    "ph": "X", "pid": 1, "tid": tid, "name": name,
+                    "ts": us(at), "dur": round(attrs[name + "_s"] * 1e6, 1),
+                    "args": attrs,
+                })
+                at += attrs[name + "_s"]
         if placed is not None and term is not None:
             out.append({
                 "ph": "X", "pid": 1, "tid": tid, "name": "decode",

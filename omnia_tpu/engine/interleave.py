@@ -309,7 +309,8 @@ class _InterleaveMixin:
         else:
             (self._ck, self._cv, self._tokens, self._positions, self._active,
              self._budget, self._key_data, dtoks) = out
-        dispatch_s = time.monotonic() - t_dispatch
+        t_enq = time.monotonic()
+        dispatch_s = t_enq - t_dispatch
         self.metrics["decode_dispatch_s"] += dispatch_s
         self._count_decode_dispatch(1, active)
         self.metrics["mixed_steps"] += 1
@@ -343,12 +344,13 @@ class _InterleaveMixin:
             pf.sess.token_ids = list(pf.prompt[:pf.frontier])
             pf.sess.last_used = self.clock()
         if final:
-            self._complete_interleaved(pf, first_tok, new_pkd)
+            self._complete_interleaved(pf, first_tok, new_pkd, t_enq)
 
-    def _complete_interleaved(self, pf, first_tok, new_pkd) -> None:
+    def _complete_interleaved(self, pf, first_tok, new_pkd, t_enq) -> None:
         """The final piece sampled the first token: activate the slot —
         the back half of ``_place_request``, against the mixed program's
-        already-advanced decode state."""
+        already-advanced decode state. ``t_enq`` is when that piece was on
+        the device's queue."""
         slot_idx, prompt = pf.slot_idx, pf.prompt
         if pf.sess is not None:
             pf.sess.token_ids = list(prompt)
@@ -362,12 +364,11 @@ class _InterleaveMixin:
             self._placing -= 1
         # positions[slot_idx] already sits at n — the final piece's
         # frontier, where the first real decode write lands — and is
-        # written there again. prefill_s=0: the per-piece mixed-step
-        # dispatches already accumulated it.
+        # written there again.
         self._activate_slot(
             slot_idx, pf.request, pf.handle, first_tok, new_pkd,
-            dict(reuse=pf.reuse, seeded=pf.seeded, prefill_s=0.0,
-                 stalled=False),
+            dict(reuse=pf.reuse, seeded=pf.seeded, stalled=False,
+                 t_enq=t_enq),
         )
 
     # -- abort / failure ------------------------------------------------
@@ -397,7 +398,7 @@ class _InterleaveMixin:
             quiesce_row = len(pf.sess.token_ids)
         else:
             self._release_slot_seed(slot)
-        slot.clear()
+        self._free_slot(slot)
         # Paged pool: keep only the pages below the consumed frontier
         # (the session's reusable rows); everything else frees.
         self._trim_slot_pages(pf.slot_idx, quiesce_row)

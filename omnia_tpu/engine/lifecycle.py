@@ -295,7 +295,7 @@ class _LifecycleMixin:
                         first_token_at=slot.handle.first_token_at,
                     )
                 self._release_slot_seed(slot)
-                slot.clear()
+                self._free_slot(slot)
         # No request is live any more. One stale positive temperature
         # would hold the sampler's gate open (ops/sampling.py) for every
         # later step of an engine that is started again; written without

@@ -557,8 +557,10 @@ class MockEngine(_MockMirrorsMixin, _MockSessionsMixin):
         fault = self.fault_plan
         n_prompt = len(prompt_tokens)
         if self._flight is not None:
-            # Playback-thread start is the mock's "claim" seam.
-            self._flight.note_claim(rid)
+            # Playback-thread start is the mock's "claim" seam: a mock
+            # has a slot for everyone, so the whole wait was for the
+            # thread to come round (loop_wait_s).
+            self._flight.note_claim(rid, t_pass=time.monotonic())
         # Hung-dispatch parity: an injected hang past watchdog_s fails
         # the request at the watchdog bound (the engine's trip path),
         # never after the full hang — bounded client latency.
@@ -572,12 +574,15 @@ class MockEngine(_MockMirrorsMixin, _MockSessionsMixin):
                 error=f"dispatch hung > watchdog_s={self.watchdog_s}",
             )
             return
+        t_enq = time.monotonic() if self._flight is not None else 0.0
         time.sleep(hang + scenario.ttft_s)
         if self._flight is not None:
             # The post-ttft-sleep moment is the mock's "placement": the
-            # simulated prefill is done, tokens stream next.
+            # simulated prefill (prefill_s, all of it spent blocked) is
+            # done, tokens stream next.
             self._flight.note_placement(
-                rid, 0, n_prompt, prefill_s=scenario.ttft_s
+                rid, 0, n_prompt, t_enq=t_enq, t_read0=t_enq,
+                t_read=time.monotonic(),
             )
         # Stall-free batching mirror: this is the playback's "prefill"
         # moment. With a token budget the prompt books ceil(n/budget)

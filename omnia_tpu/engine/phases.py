@@ -63,6 +63,13 @@ _OFF = _Off()
 enabled = TraceAnnotation.is_enabled
 
 
+def as_ms(parts) -> dict:
+    """``{"flush_s": 0.012}`` → ``{"flush_ms": 12.0}``: the flight
+    recorder's stage seconds (engine/flight.py ``note_claim`` /
+    ``note_placement``) as span attributes; ``{}`` with no recorder."""
+    return {k[:-2] + "_ms": v * 1e3 for k, v in (parts or {}).items()}
+
+
 def phase(name: str):
     """A ``TraceAnnotation`` named ``name`` while a profiler session is
     on, else the shared no-op. Use as a context manager."""

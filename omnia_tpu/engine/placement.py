@@ -244,8 +244,11 @@ class _PlacementMixin:
         # the prefix pages, so only pad pages free). The next decode
         # write re-allocates its page in the pre-dispatch prealloc.
         self._trim_slot_pages(slot_idx, n)
-        prefill_s = time.monotonic() - t_prefill
-        self.metrics["prefill_dispatch_s"] += prefill_s
+        # Every piece of the placement is on the device's queue: the
+        # host's part of it ends here (LatencyBreakdown.place_s), and what
+        # follows to the first token is the device's (prefill_s).
+        t_enq = time.monotonic()
+        self.metrics["prefill_dispatch_s"] += t_enq - t_prefill
         self.metrics["prefix_reuse_tokens"] += reuse
         self.metrics["prefill_tokens"] += n - frontier
         self.metrics["prefill_steps"] += 1
@@ -253,8 +256,7 @@ class _PlacementMixin:
             sess.token_ids = list(prompt)
         deferred = self._activate_slot(
             slot_idx, request, handle, first_tok, new_kd,
-            dict(reuse=reuse, seeded=seeded, prefill_s=prefill_s,
-                 stalled=stalled),
+            dict(reuse=reuse, seeded=seeded, stalled=stalled, t_enq=t_enq),
         )
         if span:
             span.set_metadata(
@@ -323,7 +325,10 @@ class _PlacementMixin:
             self.metrics["placements_deferred"] += 1
             self._push_inflight(first_tok, [(slot_idx, rid)], 0.0, note)
             return True
+        t_read0 = time.monotonic() if self._flight is not None else 0.0
         first = int(first_tok)
+        if self._flight is not None:
+            note = dict(note, t_read0=t_read0, t_read=time.monotonic())
         self._attach_grammar(slot_idx, request, first)
         self._emit_first_token(slot_idx, rid, first, note)
         return False
@@ -341,20 +346,24 @@ class _PlacementMixin:
             setattr(self, name, vector)
 
     def _emit_first_token(self, slot_idx: int, rid: str, token: int,
-                          note: dict) -> None:
+                          note: dict) -> Optional[dict]:
         """A placed request's first token, on the host: its event, under
         the guard ``_emit_chunk`` applies (the slot still holds that
-        request)."""
+        request). Returns the placement's stages as the flight recorder
+        noted them (``note_placement``), or None without one."""
         slot = self._slots[slot_idx]
         if not slot.active or slot.request.request_id != rid:
-            return
+            return None
+        stages = None
         if self._flight is not None:
-            # Recorded just BEFORE the first token emits so the
-            # breakdown's stages tile the wall: queue (submit→claim) +
-            # placement (claim→here, prefill included) + decode (first
-            # token→terminal).
-            self._flight.note_placement(rid, **note)
+            # Recorded just BEFORE the first token emits, so that a
+            # request this token ends has its placement on the books at
+            # its terminal; the breakdown's stages tile the wall: queue
+            # (submit→claim) + place (claim→enqueued) + prefill (→first
+            # token) + decode (first token→terminal).
+            stages = self._flight.note_placement(rid, **note)
         self._emit_token(slot_idx, token)
+        return stages
 
     def _fresh_prefill(self, slot_idx: int, prompt: list[int],
                        sp: SamplingParams, request: Optional[Request] = None):
@@ -390,6 +399,7 @@ class _PlacementMixin:
                 span.set_metadata(
                     request_id=request.request_id if request else "",
                     take=n, bucket=bucket,
+                    seq=next(self._prefill_seq), last=True,
                 )
             # Host operands go in as numpy: the call transfers them in
             # one batch, where a ``jnp`` constructor each is a device put
@@ -453,7 +463,10 @@ class _PlacementMixin:
             t0 = time.monotonic()
             with phase(PREFILL_DISPATCH) as span:
                 if span:
-                    span.set_metadata(request_id=rid, take=take, bucket=b)
+                    span.set_metadata(
+                        request_id=rid, take=take, bucket=b,
+                        seq=next(self._prefill_seq), last=False,
+                    )
                 self._cache = self._extend_nosample_fn(
                     self.params, *self._cache, toks, pos, slot_arr,
                     np.int32(off),
@@ -469,7 +482,10 @@ class _PlacementMixin:
         t0 = time.monotonic()
         with phase(PREFILL_DISPATCH) as span:
             if span:
-                span.set_metadata(request_id=rid, take=take, bucket=b)
+                span.set_metadata(
+                    request_id=rid, take=take, bucket=b,
+                    seq=next(self._prefill_seq), last=True,
+                )
             *cache, first_tok, new_kd = self._extend_fn(
                 self.params, *self._cache, toks, pos, slot_arr,
                 np.int32(off), np.int32(take - 1), kd,

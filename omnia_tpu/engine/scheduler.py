@@ -120,6 +120,11 @@ class _SchedulerMixin:
         )
 
     def _schedule(self) -> bool:
+        if self._flight is not None:
+            # The start of this pass, for the request it may claim: what
+            # it waited before is the loop's, what it waits from here the
+            # flush's (flight.py LatencyBreakdown).
+            self._pass_at = time.monotonic()
         if self._mixed_enabled():
             # Token-budget policy (engine/interleave.py): prefills split
             # into pieces fused with decode steps.
@@ -221,10 +226,17 @@ class _SchedulerMixin:
                     except ValueError:
                         pending = None  # reaped concurrently
             if pending is not None:
+                parts = None
                 if self._flight is not None:
-                    self._flight.note_claim(pending[0].request_id)
+                    parts = self._flight.note_claim(
+                        pending[0].request_id,
+                        self._free_since(pending[0], slot_idx), self._pass_at,
+                    )
                 if sp:
-                    sp.set_metadata(request_id=pending[0].request_id)
+                    sp.set_metadata(
+                        request_id=pending[0].request_id,
+                        **phases.as_ms(parts),
+                    )
         return pending, slot_idx
 
     def _place_pending(self, slot_idx, request, handle):
@@ -274,7 +286,7 @@ class _SchedulerMixin:
         self._drop_session(request.session_id)
         self._slots[slot_idx].session_id = None
         self._release_slot_seed(self._slots[slot_idx])
-        self._slots[slot_idx].clear()
+        self._free_slot(self._slots[slot_idx])
 
     # Admission fairness window: requests older than this keep strict
     # FIFO priority regardless of estimated prefill cost.
@@ -525,13 +537,17 @@ class _SchedulerMixin:
                 for i, s in enumerate(self._slots) if s.active
             ]
             chunk = 1 if single else self._pick_chunk()
+            seq = None
             if sp:
                 sampling, filtering = self._live_sampling(active)
+                # The count of decode dispatches so far names this one:
+                # the chunk carries it to the spans that read it back.
+                seq = self.metrics["decode_dispatches"]
                 sp.set_metadata(
                     chunk=chunk, active=len(active), single=single,
                     blocked=blocked, inflight=len(self._inflight),
                     kv_blocks=self._live_kv_blocks(active),
-                    sampling=sampling, filtering=filtering,
+                    sampling=sampling, filtering=filtering, seq=seq,
                 )
             # Paged pool: extend every active slot's pages past its write
             # frontier BEFORE the chunk dispatches (engine/paged.py) — a
@@ -545,14 +561,17 @@ class _SchedulerMixin:
             # The dispatch wall rides the in-flight entry so the flight
             # recorder can pair it with the (deferred) sync wall into one
             # per-chunk dispatch-vs-sync event.
-            self._push_inflight(toks, active, time.monotonic() - t_dispatch)
+            self._push_inflight(
+                toks, active, time.monotonic() - t_dispatch, seq=seq
+            )
 
-    def _push_inflight(self, toks, active, dispatch_s, placement=None):
+    def _push_inflight(self, toks, active, dispatch_s, placement=None,
+                       seq=None):
         """Append one dispatched chunk to the pipeline — the shared seam
         for plain decode chunks, mixed interleave steps and a
         placement's first token (``placement``, devloop.py)."""
         self._inflight.append(
-            _InflightChunk(toks, active, dispatch_s, placement)
+            _InflightChunk(toks, active, dispatch_s, placement, seq)
         )
 
     def _process_oldest_chunk(self):
@@ -563,7 +582,10 @@ class _SchedulerMixin:
         counters = getattr(self.model_module, "DECODE_COUNTERS", ())
         with phase(phases.CHUNK_SYNC) as sp:
             if sp:
-                sp.set_metadata(chunk=int(ch.toks.shape[0]) - len(counters))
+                sp.set_metadata(
+                    chunk=int(ch.toks.shape[0]) - len(counters),
+                    **ch.seq_attr,
+                )
             # [K, B] — ONE sync per chunk.
             host_tokens = self._sync_chunk_host(ch.toks)
         if counters:
@@ -579,17 +601,20 @@ class _SchedulerMixin:
                 int(host_tokens.shape[0]), ch.dispatch_s, sync_s,
                 len(ch.active),
             )
-        with self._emit_phase():
+        with self._emit_phase() as sp:
+            if sp:
+                sp.set_metadata(**ch.seq_attr)
             self._emit_chunk(ch, host_tokens)
 
     @contextlib.contextmanager
     def _emit_phase(self):
         """The ``emit`` span around what it covers, with the tokens and
-        the terminals that went out under it."""
+        the terminals that went out under it; yields the span, for what
+        the caller knows of it."""
         with phase(phases.EMIT) as sp:
             m = self.metrics
             tok0, fin0 = m["tokens_generated"], m["requests_finished"]
-            yield
+            yield sp
             if sp:
                 sp.set_metadata(
                     tokens=m["tokens_generated"] - tok0,
@@ -601,12 +626,18 @@ class _SchedulerMixin:
         waits for the prefill alone, whatever is queued behind it, under
         the watchdog like any chunk's (``chunk=0``: no decode step)."""
         (slot_idx, rid), = ch.active
+        note = ch.placement
         with phase(phases.CHUNK_SYNC) as sp:
             if sp:
-                sp.set_metadata(chunk=0)
+                sp.set_metadata(chunk=0, request_id=rid)
+            t_read0 = time.monotonic() if self._flight is not None else 0.0
             token = int(self._sync_chunk_host(ch.toks))
-        with self._emit_phase():
-            self._emit_first_token(slot_idx, rid, token, ch.placement)
+            if self._flight is not None:
+                note = dict(note, t_read0=t_read0, t_read=time.monotonic())
+        with self._emit_phase() as sp:
+            stages = self._emit_first_token(slot_idx, rid, token, note)
+            if sp:
+                sp.set_metadata(request_id=rid, **phases.as_ms(stages))
 
     def _first_token_unread(self) -> bool:
         """A placement's first token is in the pipeline: its prefill may
@@ -731,7 +762,7 @@ class _SchedulerMixin:
         elif sess is not None:
             self._drop_session(sid)
         self._release_slot_seed(slot)
-        slot.clear()
+        self._free_slot(slot)
         # Paged pool: pages past the quiesce frontier (all of them for
         # an unpinned slot) go back to the one free list; the frozen
         # row's garbage writes land in the kept partial page or the

@@ -45,6 +45,7 @@ class _Slot:
         "grammar",
         "gr_view",
         "gr_state",
+        "freed_at",
     )
 
     def __init__(self):
@@ -74,6 +75,11 @@ class _Slot:
         self.grammar = None
         self.gr_view = None
         self.gr_state = 0
+        # When the slot last became free on the host's books
+        # (time.monotonic()): stamped by ``_free_slot`` while a flight
+        # recorder is on, read at the next claim. 0.0, "since the engine's
+        # start", for a slot never used.
+        self.freed_at = 0.0
 
     def clear(self):
         self.request = None
@@ -178,6 +184,26 @@ class _SessionMixin:
         if idle_pinned:
             return min(idle_pinned)[1], True
         return None, False  # every slot is decoding
+
+    def _free_slot(self, slot: _Slot) -> None:
+        """``slot.clear()``: the slot is free on the host's books from
+        here, and with a flight recorder on it says since when, for the
+        request that takes it next (``LatencyBreakdown.slot_wait_s``)."""
+        slot.clear()
+        if self._flight is not None:
+            slot.freed_at = time.monotonic()
+
+    def _free_since(self, request: Request, slot_idx: int) -> float:
+        """Since when the host's books have shown a slot this request
+        could take (``t_free`` of its claim, with a flight recorder on):
+        its session's own slot if that is where it goes, else the
+        longest-free of the slots not decoding. Not the stamp of the slot
+        it takes: ``_choose_slot`` takes the lowest-numbered free one,
+        which is often the one freed last."""
+        sess = self._sessions.get(request.session_id) if request.session_id else None
+        if sess is not None and sess.slot == slot_idx:
+            return self._slots[slot_idx].freed_at
+        return min(s.freed_at for s in self._slots if not s.active)
 
     def _slot_for(self, request: Request) -> Optional[int]:
         """Claim the slot ``_choose_slot`` picks, evicting the idle

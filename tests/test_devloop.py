@@ -142,7 +142,11 @@ class TestDevLoopState:
         assert ch.active == [(0, "r0")]
         assert not hasattr(ch, "__dict__")  # __slots__: pipeline entry
         assert _InflightChunk.__slots__ == (
-            "toks", "active", "dispatch_s", "placement")
+            "toks", "active", "dispatch_s", "placement", "seq")
+        # No profiler session named its dispatch: its read-back spans
+        # carry no ``seq``; one that did hands it on.
+        assert ch.seq is None and ch.seq_attr == {}
+        assert _InflightChunk("t", [], 0.0, seq=7).seq_attr == {"seq": 7}
         # A decode chunk holds its buffer's rows as steps; a placement's
         # first token (any placement note) stands for one.
         assert ch.placement is None
@@ -170,6 +174,7 @@ def _stub_scheduler(waiting, inflight, useful, active=True, pipeline=2,
             self._waiting = list(waiting)
             self._inflight = collections.deque(f"c{i}" for i in range(inflight))
             self._slots = [SimpleNamespace(active=active)]
+            self._flight = None  # no recorder: the pass reads no clock
             self.calls = []
 
         def _mixed_enabled(self):

@@ -39,11 +39,16 @@ def _scripted_run(rec: FlightRecorder, clock: list, rid: str = "r1",
     """One full request lifecycle against an injected clock. The emit
     hot path never calls the recorder — the first-token stamp (taken by
     the handle) rides the terminal, exactly like the engine seams."""
+    t0 = clock[0]
     rec.note_submit(rid, 5)
     clock[0] += 1.0
-    rec.note_claim(rid)
+    # The slot came free 0.25 after the submit, the pass that claims
+    # began at 0.75: the wait is 0.25 slot + 0.5 loop + 0.25 flush.
+    rec.note_claim(rid, t_free=t0 + 0.25, t_pass=t0 + 0.75)
     clock[0] += 2.0
-    rec.note_placement(rid, 0, 5, reuse=1, seeded=2, prefill_s=1.5)
+    # Enqueued 0.5 after the claim; the read blocked for the last 1.0.
+    rec.note_placement(rid, 0, 5, reuse=1, seeded=2, t_enq=t0 + 1.5,
+                       t_read0=t0 + 2.0, t_read=t0 + 3.0)
     first_token_at = clock[0]  # first token lands AT placement
     clock[0] += float(tokens)  # decode: 1.0 per further token + finish
     rec.note_terminal(rid, "stop", tokens=tokens,
@@ -68,9 +73,23 @@ class TestRecorderUnit:
         term = rec.events("terminal")[0]
         bd = term.attrs["breakdown"]
         assert bd["queue_s"] == 1.0
-        assert bd["placement_s"] == 2.0
+        assert (bd["slot_wait_s"], bd["loop_wait_s"], bd["flush_s"]) == (
+            0.25, 0.5, 0.25)
+        assert bd["place_s"] == 0.5         # claim → enqueued
+        # Enqueued → first token: the device's prefill as the host sees
+        # it, never the enqueue's own wall (prefill_piece.dispatch_s).
         assert bd["prefill_s"] == 1.5
+        assert bd["read_blocked_s"] == 1.0
+        assert bd["placement_s"] == 2.0 == bd["place_s"] + bd["prefill_s"]
         assert bd["ttft_s"] == 3.0          # submit → first token
+        assert bd["queue_s"] + bd["place_s"] + bd["prefill_s"] == bd["ttft_s"]
+        # The same parts ride the events that exist.
+        claim = rec.events("claim")[0].attrs
+        assert claim == {"slot_wait_s": 0.25, "loop_wait_s": 0.5, "flush_s": 0.25}
+        placed = rec.events("placement")[0].attrs
+        assert (placed["place_s"], placed["prefill_s"],
+                placed["read_blocked_s"]) == (0.5, 1.5, 1.0)
+        assert placed["reuse"] == 1 and placed["seeded"] == 2
         assert bd["decode_s"] == 3.0        # first token → terminal
         assert bd["decode_s_per_token"] == 1.5
         assert bd["tokens"] == 3
@@ -131,6 +150,110 @@ class TestRecorderUnit:
         bd = rec.events("terminal")[0].attrs["breakdown"]
         assert bd["queue_s"] == 2.5
         assert bd["placement_s"] == 0.0 and bd["ttft_s"] == 0.0
+        # No slot, no pass, no claim: the parts say nothing.
+        assert bd["slot_wait_s"] == bd["loop_wait_s"] == bd["flush_s"] == 0.0
+        assert bd["place_s"] == bd["prefill_s"] == 0.0
+
+    @pytest.mark.parametrize("t_free,t_pass,parts", [
+        (-5.0, 0.5, (0.0, 0.5, 0.5)),   # the slot was free before the submit
+        (0.75, 0.5, (0.75, 0.0, 0.25)),  # freed inside the claiming pass
+        (0.25, -1.0, (0.25, 0.0, 0.75)),  # the pass began before the submit
+        (0.0, 0.0, (0.0, 0.0, 1.0)),    # a slot never used, no pass noted
+        (2.0, 3.0, (1.0, 0.0, 0.0)),    # stamps past the claim clip to it
+        (0.5, 1.0, (0.5, 0.5, 0.0)),    # claimed as its pass began
+    ], ids=["free-before-submit", "freed-inside-the-pass",
+            "pass-before-submit", "defaults", "past-the-claim",
+            "claimed-at-once"])
+    def test_queue_parts_are_clipped_in_order_and_tile(self, t_free, t_pass, parts):
+        """The two cuts are clipped into [submit, claim] in that order,
+        so whatever the stamps the three parts are non-negative and sum
+        to ``queue_s``."""
+        rec, clock = self._clocked()
+        clock[0] = 10.0
+        rec.note_submit("r", 4)
+        clock[0] = 11.0
+        got = rec.note_claim("r", t_free=10.0 + t_free, t_pass=10.0 + t_pass)
+        assert tuple(got.values()) == parts
+        rec.note_terminal("r", "stop")
+        bd = rec.events("terminal")[0].attrs["breakdown"]
+        assert (bd["slot_wait_s"], bd["loop_wait_s"], bd["flush_s"]) == parts
+        assert sum(parts) == bd["queue_s"] == 1.0
+
+    @pytest.mark.parametrize("seed", range(4))
+    def test_stages_tile_the_first_token_whatever_the_stamps(self, seed):
+        """Random boundaries, in and out of order: the queue's parts sum
+        to ``queue_s`` and ``queue_s + place_s + prefill_s == ttft_s``,
+        every stage non-negative."""
+        import random
+
+        rng = random.Random(seed)
+        rec, clock = self._clocked()
+        for i in range(50):
+            rid = f"r{i}"
+            clock[0] += rng.random()
+            t_sub = clock[0]
+            rec.note_submit(rid, 4)
+            clock[0] += rng.random()
+            rec.note_claim(rid, t_free=t_sub + rng.uniform(-1, 2),
+                           t_pass=t_sub + rng.uniform(-1, 2))
+            t_claim = clock[0]
+            clock[0] += rng.random()
+            rec.note_placement(rid, 0, 4, t_enq=t_claim + rng.uniform(-0.5, 1.5),
+                               t_read0=clock[0] - rng.random(), t_read=clock[0])
+            first = clock[0] + rng.random() * 1e-3
+            clock[0] += rng.random()
+            rec.note_terminal(rid, "stop", tokens=3, first_token_at=first)
+        for ev in rec.events("terminal"):
+            bd = ev.attrs["breakdown"]
+            stages = [bd[k] for k in ("slot_wait_s", "loop_wait_s", "flush_s",
+                                      "place_s", "prefill_s", "read_blocked_s")]
+            assert min(stages) >= 0.0
+            assert sum(stages[:3]) == pytest.approx(bd["queue_s"], abs=2e-6)
+            assert bd["queue_s"] + bd["place_s"] + bd["prefill_s"] == pytest.approx(
+                bd["ttft_s"], abs=2e-6)
+            assert bd["placement_s"] == pytest.approx(
+                bd["place_s"] + bd["prefill_s"], abs=2e-6)
+
+    def test_a_request_without_a_first_token_ends_its_prefill_at_the_note(self):
+        """A first token that is a stop id pushes no token: the prefill
+        ends where the placement was noted. A placement that failed was
+        never noted: all of claim → terminal is ``place_s``."""
+        rec, clock = self._clocked()
+        rec.note_submit("stop", 4)
+        rec.note_claim("stop")
+        clock[0] = 1.0
+        rec.note_placement("stop", 0, 4, t_enq=0.25)
+        clock[0] = 1.5
+        rec.note_terminal("stop", "stop")
+        rec.note_submit("failed", 4)
+        rec.note_claim("failed")
+        clock[0] = 2.0
+        rec.note_terminal("failed", "error", error="prefill failed")
+        stop, failed = (e.attrs["breakdown"] for e in rec.events("terminal"))
+        assert (stop["place_s"], stop["prefill_s"], stop["ttft_s"]) == (0.25, 0.75, 0.0)
+        assert (failed["place_s"], failed["prefill_s"]) == (0.5, 0.0)
+        assert failed["placement_s"] == 0.5
+
+    def test_chrome_rows_are_the_breakdowns_stages(self):
+        """A request's row reads slot_wait → loop_wait → flush → place →
+        prefill → decode, each as long as the field of that name, laid
+        end to end from the submit to the terminal."""
+        rec, clock = self._clocked()
+        _scripted_run(rec, clock)
+        rows = {e["name"]: e for e in to_chrome_trace(rec.events())["traceEvents"]
+                if e["ph"] == "X"}
+        assert list(rows) == ["slot_wait", "loop_wait", "flush", "place",
+                              "prefill", "decode"]
+        bd = rec.events("terminal")[0].attrs["breakdown"]
+        for name in ("slot_wait", "loop_wait", "flush", "place", "prefill"):
+            assert rows[name]["dur"] == pytest.approx(bd[name + "_s"] * 1e6)
+        assert rows["decode"]["dur"] == pytest.approx(bd["decode_s"] * 1e6)
+        at = 0.0
+        for row in rows.values():
+            assert row["ts"] == pytest.approx(at)
+            at += row["dur"]
+        assert "queue" not in rows and "placement" not in rows
+        assert rows["prefill"]["args"]["read_blocked_s"] == 1.0
 
     def test_chrome_trace_head_duration_event_stays_nonnegative(self):
         """Ring-overwrite head case: when the earliest retained event is
@@ -161,7 +284,7 @@ class TestRecorderUnit:
         rec.note_claim("r")
         offset = 2.25 - 100.0  # the profiler's 2.25 s is this clock's 100 s
         doc = to_chrome_trace(rec.events(), profiler_offset_s=offset)
-        queue = next(e for e in doc["traceEvents"] if e["name"] == "queue")
+        queue = next(e for e in doc["traceEvents"] if e["name"] == "flush")
         assert queue["ts"] == pytest.approx(2.25e6)
         assert queue["dur"] == pytest.approx(0.5e6)
         assert to_chrome_trace(rec.events())["traceEvents"][-1]["ts"] == 0.0
@@ -170,7 +293,7 @@ class TestRecorderUnit:
         assert flight_main([dump, "-o", out, "--profiler-offset-s", str(offset)]) == 0
         cli = json.load(open(out))
         assert next(e for e in cli["traceEvents"]
-                    if e["name"] == "queue")["ts"] == pytest.approx(2.25e6)
+                    if e["name"] == "flush")["ts"] == pytest.approx(2.25e6)
 
     def test_terminal_without_submit_is_tolerated(self):
         """A terminal for a request the recorder never saw (ring
@@ -205,7 +328,8 @@ class TestRecorderUnit:
                 assert e["dur"] >= 0
         names = {e["name"] for e in evs}
         # The per-request phase rows and the terminal marker.
-        assert {"queue", "placement", "decode"} <= names
+        assert {"slot_wait", "loop_wait", "flush", "place", "prefill",
+                "decode"} <= names
         assert any(n.startswith("finish:") for n in names)
         # Request rows are named via thread_name metadata.
         assert any(
@@ -250,6 +374,18 @@ class TestMockParity:
         assert bd["tokens"] == len(toks) == 5
         assert bd["ttft_s"] >= 0 and bd["queue_s"] >= 0
         assert m._flight.hist["ttft"].count == 1
+        # The same attrs as the engine's, from the mock's own seams: the
+        # wait for the playback thread is the loop's, the scripted ttft
+        # the prefill, all of it spent blocked.
+        claim = m._flight.events("claim")[0].attrs
+        placed = m._flight.events("placement")[0].attrs
+        assert set(claim) == {"slot_wait_s", "loop_wait_s", "flush_s"}
+        assert {"place_s", "prefill_s", "read_blocked_s"} <= set(placed)
+        assert "prefill_s" in placed and placed["prefill_s"] >= placed["read_blocked_s"] > 0
+        assert claim["slot_wait_s"] == 0.0 and bd["flush_s"] < 1e-3
+        assert sum(claim.values()) == pytest.approx(bd["queue_s"], abs=2e-6)
+        assert bd["queue_s"] + bd["place_s"] + bd["prefill_s"] == pytest.approx(
+            bd["ttft_s"], abs=2e-6)
         # Ledger exactness: one terminal per accepted submit.
         assert len(m._flight.events("terminal")) == m.metrics["requests_finished"]
         assert len(m._flight.events("submit")) == m.metrics["requests_submitted"]
@@ -521,8 +657,101 @@ class TestEngineLedger:
         # Chrome export of the real run keeps the schema.
         doc = to_chrome_trace(evs)
         names = {e["name"] for e in doc["traceEvents"]}
-        assert {"queue", "placement", "decode", "decode_chunk"} <= names
+        assert {"flush", "place", "prefill", "decode", "decode_chunk"} <= names
         root.end()
+
+    @pytest.mark.parametrize("path", ["deferred", "read-at-once", "extend",
+                                      "interleaved"])
+    def test_every_request_tiles_on_every_placement_path(self, path):
+        """Two slots, three requests (the third waits for a slot), on the
+        path whose first token rides the pipeline, the one that reads it
+        at once (a grammar), a chunked extend and an interleaved
+        placement: for every request the queue's parts sum to ``queue_s``
+        and ``queue_s + place_s + prefill_s == ttft_s``, to a microsecond
+        (each field is rounded to one)."""
+        from omnia_tpu.engine.tokenizer import ByteTokenizer
+
+        over = {
+            "deferred": {},
+            "read-at-once": {"grammar": True, "grammar_max_states": 256},
+            "extend": {},
+            "interleaved": {"prefill_chunk_tokens": 4},
+        }[path]
+        eng = _tiny_engine(max_sessions=0, prefill_buckets=(8,), **over)
+        sp = SamplingParams(temperature=0.0, max_tokens=6)
+        grammar = None
+        if path == "read-at-once":
+            from omnia_tpu.engine.grammar import compile_json_schema
+
+            grammar = compile_json_schema(
+                {"type": "object", "properties": {"a": {"type": "integer"}},
+                 "required": ["a"]}, ByteTokenizer())
+            sp = SamplingParams(temperature=0.0, max_tokens=24,
+                                stop_token_ids=(0,))
+        n = 20 if path in ("extend", "interleaved") else 4
+        handles = [
+            eng.submit(ByteTokenizer().encode("json") if grammar
+                       else list(range(i + 1, i + 1 + n)), sp, grammar=grammar)
+            for i in range(3)
+        ]
+        while eng.step():
+            pass
+        for h in handles:
+            h.collect_tokens(timeout=60)
+        m = eng.metrics
+        assert m["prefill_steps"] == 3
+        assert m["placements_deferred"] == (0 if grammar else 3)
+        assert (m["extend_steps"] > 0) == (path in ("extend", "interleaved"))
+        assert (m["mixed_steps"] > 0) == (path == "interleaved")
+        bds = {e.request_id: e.attrs["breakdown"]
+               for e in eng._flight.events("terminal")}
+        assert len(bds) == 3
+        for bd in bds.values():
+            parts = [bd[k] for k in ("slot_wait_s", "loop_wait_s", "flush_s")]
+            assert min(parts + [bd["place_s"], bd["prefill_s"]]) >= 0.0
+            assert sum(parts) == pytest.approx(bd["queue_s"], abs=2e-6)
+            assert bd["queue_s"] + bd["place_s"] + bd["prefill_s"] == (
+                pytest.approx(bd["ttft_s"], abs=2e-6))
+            assert bd["placement_s"] == pytest.approx(
+                bd["place_s"] + bd["prefill_s"], abs=2e-6)
+            # The thread blocked in the read for part of the prefill, and
+            # the enqueue came after the claim.
+            assert 0.0 < bd["read_blocked_s"] <= bd["prefill_s"]
+            assert bd["place_s"] > 0.0
+        # The third request had no slot until one of the first two ended.
+        assert bds[handles[2].request_id]["slot_wait_s"] > 0.0
+        assert bds[handles[0].request_id]["slot_wait_s"] == 0.0
+        # The events carry what the breakdown was made of.
+        for ev in eng._flight.events("claim"):
+            assert sum(ev.attrs.values()) == pytest.approx(
+                bds[ev.request_id]["queue_s"], abs=2e-6)
+        for ev in eng._flight.events("placement"):
+            bd = bds[ev.request_id]
+            assert ev.attrs["place_s"] == pytest.approx(bd["place_s"], abs=2e-6)
+            # Stamped as the emit begins, microseconds before the handle's.
+            assert 0.0 <= bd["prefill_s"] - ev.attrs["prefill_s"] < 5e-3
+
+    def test_slot_wait_ends_when_any_slot_it_could_take_was_free(self):
+        """Two slots. `a` ends inside the flush of the pass that claims
+        `c`, and `c` takes the slot `a` left (the lowest-numbered free
+        one), freed after `c` was submitted; the other slot was free all
+        along, so `c` never waited for a slot: its wait is the loop's and
+        the flush's."""
+        eng = _tiny_engine(max_sessions=0)
+        a = eng.submit([1, 2, 3], SamplingParams(temperature=0.0, max_tokens=2))
+        eng.step()  # a placed in slot 0, its second token in flight
+        assert eng._slots[0].active and len(eng._inflight) == 1
+        c = eng.submit([4, 5, 6], GREEDY)
+        eng.step()  # flushed for c: a ends; c claimed and placed
+        placed = {e.request_id: e.attrs for e in eng._flight.events("placement")}
+        claim = {e.request_id: e.attrs for e in eng._flight.events("claim")}
+        assert placed[c.request_id]["slot"] == 0 == placed[a.request_id]["slot"]
+        assert eng._slots[0].freed_at > 0.0 == eng._slots[1].freed_at
+        assert claim[c.request_id]["slot_wait_s"] == 0.0
+        assert claim[c.request_id]["flush_s"] > 0.0
+        while eng.step():
+            pass
+        assert len(c.collect_tokens(timeout=60)[0]) == 8
 
     def test_ledger_reconciles_with_terminal_counters(self):
         """Event-ledger exactness: submit events == requests_submitted,
@@ -613,6 +842,70 @@ def test_flight_off_is_true_noop():
     assert t_off == t_on == t_ctx
     assert tracer.spans(tr.SPAN_ENGINE) == []
     root.end()
+
+
+class _CountedTime:
+    """``time`` as a module under test sees it, with every
+    ``monotonic()`` call counted under the name of the function that
+    made it."""
+
+    def __init__(self):
+        import collections
+
+        self.calls = collections.Counter()
+
+    def monotonic(self):
+        import sys
+        import time
+
+        self.calls[sys._getframe(1).f_code.co_name] += 1
+        return time.monotonic()
+
+    def __getattr__(self, name):
+        import time
+
+        return getattr(time, name)
+
+
+@pytest.mark.parametrize("flight_events", [0, 512], ids=["off", "on"])
+def test_the_stage_stamps_are_taken_only_with_a_recorder(monkeypatch, flight_events):
+    """With ``flight_events=0`` and no profiler session the scheduling
+    pass, the claim, the placement, the first token's read and the
+    slot's release read the clock exactly where the parent did:
+    ``_place_request`` twice a placement (around the prefill's enqueue,
+    for ``prefill_dispatch_s``) and nowhere else. With a recorder on,
+    every boundary of ``LatencyBreakdown`` is one read more."""
+    pytest.importorskip("jax")
+    from omnia_tpu.engine import interleave, placement, scheduler, sessions
+
+    eng = _tiny_engine(flight_events=flight_events, max_sessions=0, num_slots=1)
+    eng.generate([1, 2, 3], GREEDY)  # compile first
+    clock = _CountedTime()
+    for mod in (scheduler, placement, interleave, sessions):
+        monkeypatch.setattr(mod, "time", clock)
+    handles = [eng.submit([i + 1, i + 2, i + 3], GREEDY) for i in range(2)]
+    passes = 0
+    while eng.step():
+        passes += 1
+    for h in handles:
+        assert len(h.collect_tokens(timeout=60)[0]) == 8
+    stamps = {name: clock.calls[name] for name in (
+        "_schedule", "_claim_pending", "_place_request", "_activate_slot",
+        "_process_first_token", "_emit_first_token", "_finish_slot",
+        "_free_slot")}
+    if flight_events == 0:
+        assert stamps == {
+            "_schedule": 0, "_claim_pending": 0, "_place_request": 2 * 2,
+            "_activate_slot": 0, "_process_first_token": 0,
+            "_emit_first_token": 0, "_finish_slot": 0, "_free_slot": 0,
+        }
+    else:
+        assert stamps == {
+            "_schedule": passes + 1, "_claim_pending": 0,
+            "_place_request": 2 * 2, "_activate_slot": 0,
+            "_process_first_token": 2 * 2, "_emit_first_token": 0,
+            "_finish_slot": 0, "_free_slot": 2,
+        }
 
 
 class TestConversationContinuity:

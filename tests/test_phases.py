@@ -400,6 +400,109 @@ def test_off_path_allocates_nothing_and_records_nothing():
     assert eng.step() is False
 
 
+def test_off_path_computes_no_attribute_of_the_readback_join(monkeypatch):
+    """No profiler session: no chunk carries a ``seq``, no
+    ``.prefill_dispatch`` draws one, and the stage seconds are never
+    turned into span attributes, recorder on or off."""
+    from omnia_tpu.engine import scheduler
+
+    made, turned = [], []
+
+    class Chunk(scheduler._InflightChunk):
+        def __init__(self, *a, **k):
+            super().__init__(*a, **k)
+            made.append(self.seq)
+
+    monkeypatch.setattr(scheduler, "_InflightChunk", Chunk)
+    monkeypatch.setattr(phases, "as_ms", lambda parts: turned.append(parts) or {})
+    for flight_events in (0, 64):
+        eng = _tiny_engine(flight_events=flight_events)
+        handles = [eng.submit([i + 1, i + 2, i + 3], GREEDY) for i in range(3)]
+        _drain(eng)
+        assert all(len(h.collect_tokens(timeout=60)[0]) == 8 for h in handles)
+        assert next(eng._prefill_seq) == 0
+    assert made and set(made) == {None}
+    assert turned == []
+
+
+@pytest.mark.parametrize("flight_events", [0, 256], ids=["recorder-off", "recorder-on"])
+def test_every_readback_names_the_dispatch_it_waited_for(tmp_path, flight_events):
+    """Under a session a ``.decode_dispatch`` says which dispatch it is
+    (``seq``), and the ``.chunk_sync`` and ``.emit`` that read that chunk
+    back say the same; a ``.prefill_dispatch`` has a ``seq`` of its own
+    series and says whether it is its placement's last piece, and the
+    first token's ``.chunk_sync`` and ``.emit`` name the request. With a
+    recorder on, the claim and the first token's emit carry
+    ``LatencyBreakdown``'s stages, in ms, so the trace alone tells a
+    placement's story; with none they carry no stage."""
+    eng = _tiny_engine(num_slots=2, prefill_buckets=(8,), flight_events=flight_events)
+    eng.generate([1, 2, 3], GREEDY)
+    eng.generate(list(range(1, 21)), GREEDY)  # the extend programs too
+    d0 = eng.metrics["decode_dispatches"]
+    with _profiled(tmp_path) as spans:
+        short = eng.submit([1, 2, 3], GREEDY)
+        long_ = eng.submit(list(range(1, 21)), GREEDY)  # three pieces of 8
+        late = eng.submit([7, 8, 9], GREEDY)            # waits for a slot
+        _drain(eng)
+    for h in (short, long_, late):
+        assert len(h.collect_tokens(timeout=60)[0]) == 8
+    (events,) = spans().values()
+
+    dispatches = _named(events, phases.DECODE_DISPATCH)
+    seqs = [d[3]["seq"] for d in dispatches]
+    assert seqs == list(range(d0, d0 + len(seqs)))  # the counter, one by one
+    syncs = [s for s in _named(events, phases.CHUNK_SYNC) if s[3]["chunk"] > 0]
+    emits = [e for e in _named(events, phases.EMIT) if "seq" in e[3]]
+    # Every chunk dispatched in the session was read in it, once, after
+    # its dispatch, and emitted right after its read.
+    assert sorted(s[3]["seq"] for s in syncs) == seqs
+    assert [e[3]["seq"] for e in emits] == [s[3]["seq"] for s in syncs]
+    start_of = {d[3]["seq"]: d[1] for d in dispatches}
+    for sync, emit in zip(syncs, emits):
+        assert start_of[sync[3]["seq"]] < sync[1] <= sync[2] <= emit[1]
+        assert "request_id" not in sync[3]
+
+    pieces = _named(events, phases.PREFILL_DISPATCH)
+    assert [p[3]["seq"] for p in pieces] == list(range(len(pieces)))
+    by_request = {}
+    for p in pieces:
+        by_request.setdefault(p[3]["request_id"], []).append(p[3]["last"])
+    assert by_request == {
+        short.request_id: [1], long_.request_id: [0, 0, 1], late.request_id: [1],
+    }
+    first_reads = [s for s in _named(events, phases.CHUNK_SYNC) if s[3]["chunk"] == 0]
+    assert [s[3]["request_id"] for s in first_reads] == [
+        short.request_id, long_.request_id, late.request_id]
+    assert all("seq" not in s[3] for s in first_reads)
+    first_emits = [e for e in _named(events, phases.EMIT) if "request_id" in e[3]]
+    assert [e[3]["request_id"] for e in first_emits] == [
+        s[3]["request_id"] for s in first_reads]
+    last_piece = {p[3]["request_id"]: p for p in pieces if p[3]["last"]}
+    for read in first_reads:
+        assert last_piece[read[3]["request_id"]][2] <= read[1]
+
+    claims = [c[3] for c in _named(events, phases.CLAIM) if c[3]]
+    stage_keys = {"place_ms", "prefill_ms", "read_blocked_ms"}
+    queue_keys = {"slot_wait_ms", "loop_wait_ms", "flush_ms"}
+    if not flight_events:
+        assert all(not queue_keys & set(c) for c in claims)
+        assert all(not stage_keys & set(e[3]) for e in first_emits)
+        return
+    bds = {e.request_id: e.attrs["breakdown"] for e in eng._flight.events("terminal")}
+    assert len(claims) == 3
+    for c in claims:
+        bd = bds[c["request_id"]]
+        for key in queue_keys:
+            assert c[key] == pytest.approx(bd[key[:-2] + "s"] * 1e3, abs=2e-3)
+    assert claims[2]["slot_wait_ms"] > 0 and claims[0]["slot_wait_ms"] == 0
+    for e in first_emits:
+        bd = bds[e[3]["request_id"]]
+        assert e[3]["place_ms"] == pytest.approx(bd["place_s"] * 1e3, abs=2e-3)
+        assert e[3]["read_blocked_ms"] == pytest.approx(
+            bd["read_blocked_s"] * 1e3, abs=2e-3)
+        assert 0 <= bd["prefill_s"] * 1e3 - e[3]["prefill_ms"] < 5.0
+
+
 def _decode_args(eng):
     return (eng.params, eng._ck, eng._cv, eng._tokens, eng._positions,
             eng._active, eng._budget, eng._stop_ids, eng._key_data,
