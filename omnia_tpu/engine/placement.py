@@ -387,9 +387,11 @@ class _PlacementMixin:
         ):
             # Ring path: the sp-sharded prefill stays its own program;
             # its KV chunk gathers into the slot via the insert step.
-            logits, *chunks = self._prefill_ring_fn(self.params, toks, pos)
+            last, *chunks = self._prefill_ring_fn(
+                self.params, toks, pos, np.int32(n - 1)
+            )
             self._cache, first_tok, new_kd = self._run_insert(
-                chunks, slot_idx, logits[:, n - 1], sp, request=request,
+                chunks, slot_idx, last, sp, request=request,
             )
             return first_tok, new_kd
         kd = self._sampling_key(slot_idx, sp)

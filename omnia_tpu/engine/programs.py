@@ -12,7 +12,8 @@ through):
   round trip that TTFT pays for; one program, one round trip).
 - ``prefill_ring`` — long-context prefill (sp > 1): ring attention
   splits the O(T²) attention of buckets ≥ long_prefill_threshold across
-  the sp mesh axis (SURVEY §5.7).
+  the sp mesh axis (SURVEY §5.7). Returns the last row's logits [1, V]
+  and the KV chunks: ``insert``'s operands.
 - ``insert`` — place a prefill KV chunk into a slot's rows + sample the
   first token (the gather step after a ring prefill).
 - ``decode_fns`` — chunked decode: `k` decode steps in one compiled
@@ -48,6 +49,13 @@ through):
   into a pool entry, seed-copy a pool entry into a fresh slot, and pull
   a pool entry to host RAM for the paged tier. All device↔device (store
   and seed never cross the host link) in fixed prefix-bucket shapes.
+
+First-token row: a program that samples a placement's first token hands
+the prompt's last real row (``last_idx``) to the model as ``row=``. The
+model takes that one row of the stream BEFORE its final norm and head and
+returns [1, V] logits, so no [1, T, V] value exists in these programs.
+Without a row the model returns every row: ``verify`` reads them all,
+``extend_nosample`` and the other mixed pieces drop them (and XLA the head).
 
 Model family and cache: the programs reach the model through
 ``models.model_module(cfg)`` and through nothing else, and a family's cache
@@ -192,11 +200,9 @@ def build_programs(
             return pkv.put_chunk(c, new, slot, write_start)
         return cache_put(c, view, (0, slot, 0))
 
-    def _sample_first(logits, last_idx, key_data, temp, top_p, top_k, g):
-        """A placed request's first token, sampled at its last prompt row."""
-        last = jax.lax.dynamic_slice(
-            logits, (0, last_idx, 0), (1, 1, logits.shape[-1])
-        )[:, 0]
+    def _sample_first(last, key_data, temp, top_p, top_k, g):
+        """A placed request's first token, sampled from the logits of its
+        last prompt row, ``last`` [1, V] (the header's "first-token row")."""
         tok, new_kd = sample_tokens_per_slot(
             last, key_data[None], temp[None], top_p[None], top_k[None],
             mask_bias=_first_bias(g),
@@ -208,21 +214,23 @@ def build_programs(
         temp, top_p, top_k, *g) -> (*cache, tok, new_key_data)."""
         cache, (tokens, positions, slot, last_idx, key_data, temp, top_p,
                 top_k, *g) = args[:n_cache], args[n_cache:]
-        logits, *chunks = model.forward_prefill(params, cfg, tokens, positions)
+        last, *chunks = model.forward_prefill(params, cfg, tokens, positions,
+                                              row=last_idx)
 
         # c: [L,B,S,...]; chunk: [L,1,T,...] — a quantized cache
         # quantizes the fresh rows inside cache_put (kv_quant mode).
         cache = tuple(_put(c, chunk, slot, 0) for c, chunk in zip(cache, chunks))
-        tok, new_kd = _sample_first(logits, last_idx, key_data, temp, top_p,
-                                    top_k, g)
+        tok, new_kd = _sample_first(last, key_data, temp, top_p, top_k, g)
         return (*cache, tok, new_kd)
 
     prefill_insert_fn = jax.jit(prefill_insert, donate_argnums=cache_args)
 
     prefill_ring_fn = None
     if ecfg.sp > 1:
-        def prefill_ring(params, tokens, positions):
-            return model.forward_prefill_ring(params, cfg, tokens, positions, mesh)
+        def prefill_ring(params, tokens, positions, last_idx):
+            """-> (last_logits [1, V], *chunks): ``insert``'s operands."""
+            return model.forward_prefill_ring(params, cfg, tokens, positions,
+                                              mesh, row=last_idx)
 
         prefill_ring_fn = jax.jit(prefill_ring)
 
@@ -234,11 +242,8 @@ def build_programs(
         # Place the prefill chunk into the slot's rows [slot, 0:T]
         # (chunk [L,1,T,...] floats — quantized on write in kv mode).
         cache = tuple(_put(c, chunk, slot, 0) for c, chunk in zip(cache, chunks))
-        tok, new_kd = sample_tokens_per_slot(
-            last_logits, key_data[None], temp[None], top_p[None], top_k[None],
-            mask_bias=_first_bias(g),
-        )
-        return (*cache, tok[0], new_kd[0])
+        return (*cache, *_sample_first(last_logits, key_data, temp, top_p,
+                                       top_k, g))
 
     insert_fn = jax.jit(insert, donate_argnums=tuple(range(n_cache)))
 
@@ -464,13 +469,15 @@ def build_programs(
     # full chunk. The scheduler's _pick_chunk chooses per dispatch.
     decode_fns = {k: make_decode(k) for k in ecfg.chunk_variants()}
 
-    def _extend_slot(params, cache, tokens, positions, slot, write_start):
+    def _extend_slot(params, cache, tokens, positions, slot, write_start,
+                     row=None):
         """The extend seam: one slot's view of every cache array, forward
-        over it with the slot's write offset, the view written back."""
+        over it with the slot's write offset, the view written back.
+        -> (logits: of ``row`` [1, V], of every row when it is None; cache)."""
         views = [_take_slot(c, slot) for c in cache]
         logits, *views = model.forward(
             params, cfg, tokens, positions, *views, write_start[None],
-            mesh=mesh,
+            mesh=mesh, row=row,
         )
         # forward kept the slice in cache representation (suffix rows
         # quantized inside _write_kv when kv_quant is on) — write back
@@ -484,10 +491,9 @@ def build_programs(
         key_data, temp, top_p, top_k, *g) -> (*cache, tok, new_key_data)."""
         cache, (tokens, positions, slot, write_start, last_idx, key_data,
                 temp, top_p, top_k, *g) = args[:n_cache], args[n_cache:]
-        logits, cache = _extend_slot(params, cache, tokens, positions, slot,
-                                     write_start)
-        tok, new_kd = _sample_first(logits, last_idx, key_data, temp, top_p,
-                                    top_k, g)
+        last, cache = _extend_slot(params, cache, tokens, positions, slot,
+                                   write_start, row=last_idx)
+        tok, new_kd = _sample_first(last, key_data, temp, top_p, top_k, g)
         return (*cache, tok, new_kd)
 
     extend_fn = jax.jit(extend, donate_argnums=cache_args)
@@ -539,25 +545,19 @@ def build_programs(
                     vtoks, vpos, vwstart, vmask = rest[:4]
                     del rest[:4]
                 # -- prefill piece via the extend seam ------------------
-                k_slot = _take_slot(ck, pslot)
-                v_slot = _take_slot(cv, pslot)
-                plogits, k_slot, v_slot = model.forward(
-                    params, cfg, ptoks, ppos, k_slot, v_slot, pwrite[None],
-                    mesh=mesh,
-                )
-                pt = ptoks.shape[1]
-                ck = _put_back(ck, k_slot, pslot, pwrite, pt)
-                cv = _put_back(cv, v_slot, pslot, pwrite, pt)
-                extra = ()
+                piece = (params, (ck, cv), ptoks, ppos, pslot, pwrite)
                 if sample:
                     # Final piece: sample the placed request's first
-                    # token (grammar start-state bias rides *pg, the
-                    # extend signature exactly).
-                    plast, pkd, ptemp, ptop_p, ptop_k = rest[:5]
-                    extra = _sample_first(
-                        plogits, plast, pkd, ptemp, ptop_p, ptop_k,
-                        tuple(rest[5:]),
-                    )
+                    # token at the one row the head runs over (grammar
+                    # start-state bias rides *pg, the extend signature
+                    # exactly).
+                    plast, pkd, ptemp, ptop_p, ptop_k, *pg = rest
+                    last, (ck, cv) = _extend_slot(*piece, row=plast)
+                    extra = _sample_first(last, pkd, ptemp, ptop_p, ptop_k, pg)
+                else:
+                    # Any other piece's logits are dropped.
+                    _, (ck, cv) = _extend_slot(*piece)
+                    extra = ()
                 if spec:
                     # Verify window AFTER the piece (its garbage rows
                     # for the placing slot park at the piece frontier,

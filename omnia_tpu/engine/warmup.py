@@ -222,13 +222,13 @@ class _WarmupMixin:
                         and b >= cfg.long_prefill_threshold
                         and b % cfg.sp == 0
                     ):
-                        logits, *chunks = self._prefill_ring_fn(
-                            self.params, toks, pos
+                        last, *chunks = self._prefill_ring_fn(
+                            self.params, toks, pos, np.int32(b - 1)
                         )
                         sp = SamplingParams()
                         out = self._insert_fn(
                             *st.cache, *chunks, 0,
-                            logits[:, -1], self._sampling_key(0, sp),
+                            last, self._sampling_key(0, sp),
                             jnp.float32(sp.temperature),
                             jnp.float32(sp.top_p), jnp.int32(sp.top_k),
                             *self._grammar_args(None, sp),

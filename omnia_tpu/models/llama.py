@@ -321,13 +321,16 @@ def _embed(params, cfg: ModelConfig, tokens, q_positions):
     return x, cos, sin
 
 
-def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None):
+def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None,
+                    row=None):
     """Fresh-sequence prefill: self-contained attention over the chunk,
     returning the per-layer KV chunk for the engine to place into a cache
     slot (so prefill never reads or writes other slots' cache).
 
     tokens, q_positions: int32 [B, T]
-    Returns (logits [B, T, V] f32, k_chunk, v_chunk [L, B, T, Hkv, D]).
+    Returns (logits [B, T, V] f32, k_chunk, v_chunk [L, B, T, Hkv, D]);
+    with ``row`` (int32 scalar) the logits are that row's alone, [B, V]:
+    the final norm and the head run over one row (``_logits_at``).
     attn_fn overrides the attention op (the ring-prefill path).
     """
     x, cos, sin = _embed(params, cfg, tokens, q_positions)
@@ -340,10 +343,11 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None)
 
     with jax.named_scope("layers"):
         x, (k_chunk, v_chunk) = jax.lax.scan(body, x, params["layers"])
-    return _logits(params, cfg, x), k_chunk, v_chunk
+    return _logits_at(params, cfg, x, row), k_chunk, v_chunk
 
 
-def forward_prefill_ring(params, cfg: ModelConfig, tokens, q_positions, mesh):
+def forward_prefill_ring(params, cfg: ModelConfig, tokens, q_positions, mesh,
+                         row=None):
     """Long-context prefill: identical contract to `forward_prefill`, but
     attention runs as causal ring attention with q/k/v sequence-sharded
     over the mesh's "sp" axis (parallel/ring_attention.py), so the O(T²)
@@ -361,7 +365,7 @@ def forward_prefill_ring(params, cfg: ModelConfig, tokens, q_positions, mesh):
     def ring(q, k, v, _q_positions):
         return ring_attention(q, k, v, mesh)
 
-    return forward_prefill(params, cfg, tokens, q_positions, attn_fn=ring)
+    return forward_prefill(params, cfg, tokens, q_positions, attn_fn=ring, row=row)
 
 
 # ---------------------------------------------------------------------------
@@ -377,8 +381,20 @@ def _logits(params, cfg: ModelConfig, x):
         return qdot(x, params["lm_head"]).astype(jnp.float32)
 
 
+def _logits_at(params, cfg: ModelConfig, x, row):
+    """The head over the rows its caller reads: every row of x [B, T, D]
+    (``row`` None → [B, T, V]), or row ``row`` alone (→ [B, V]), taken out
+    of x BEFORE the final norm and the head. A placement samples one row of
+    its prompt; the other T - 1 are the head's width in work nobody reads."""
+    if row is None:
+        return _logits(params, cfg, x)
+    with jax.named_scope("lm_head"):
+        x = jax.lax.dynamic_slice_in_dim(x, row, 1, axis=1)
+    return _logits(params, cfg, x)[:, 0]
+
+
 def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
-            write_start, mesh=None, live=None):
+            write_start, mesh=None, live=None, row=None):
     """Serving forward (prefill or decode — same code, different T).
 
     tokens, q_positions: int32 [B, T]; cache_k/v: [L, B, S, Hkv, D];
@@ -389,7 +405,10 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
     logits the caller will use. The decode kernel skips the others
     (no cache read, zero attention output); their rows are still
     written and their logits are garbage, as the caller expects.
-    Returns (logits [B, T, V] f32, new_cache_k, new_cache_v).
+    row: int32 scalar, or None for "every row": the one row of the T
+    whose logits the caller will use (a placement's last prompt row).
+    Returns (logits [B, T, V] f32, new_cache_k, new_cache_v); with ``row``
+    the logits are [B, V].
 
     The caches (plain, QuantKV or PagedKV alike) ride the layer scan
     whole, as its carry beside ``x``; what is scanned is each layer's
@@ -413,7 +432,7 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
         (x, new_k, new_v), _ = jax.lax.scan(
             body, (x, cache_k, cache_v), (params["layers"], layers)
         )
-    return _logits(params, cfg, x), new_k, new_v
+    return _logits_at(params, cfg, x, row), new_k, new_v
 
 
 def forward_embed(params, cfg: ModelConfig, tokens, mask):

@@ -451,6 +451,17 @@ def _logits(params, cfg: ModelConfig, x):
         return jnp.dot(x, params["lm_head"]).astype(jnp.float32)
 
 
+def _logits_at(params, cfg: ModelConfig, x, row):
+    """The head over the rows its caller reads (models/llama.py::_logits_at):
+    every row of the stream x [B, T, n·D] (``row`` None → [B, T, V]), or row
+    ``row`` alone (→ [B, V]), taken BEFORE the fold, the norm and the head."""
+    if row is None:
+        return _logits(params, cfg, x)
+    with jax.named_scope("lm_head"):
+        x = jax.lax.dynamic_slice_in_dim(x, row, 1, axis=1)
+    return _logits(params, cfg, x)[:, 0]
+
+
 def _scans(params, cfg: ModelConfig):
     """The layer scans a forward pass makes, in the model's order: (scope,
     what the scan runs over: the layers' parameters, sliced a layer at a time,
@@ -477,12 +488,13 @@ def _scans(params, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def forward_prefill(params, cfg: ModelConfig, tokens, q_positions):
+def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, row=None):
     """Fresh-sequence prefill: attention over the chunk's own rows, which
     come back for the engine to place into a cache slot.
 
     tokens, q_positions: int32 [B, T]. Returns (logits [B, T, V] f32,
-    chunk [L, B, T, W])."""
+    chunk [L, B, T, W]); with ``row`` (int32 scalar) the logits are that
+    row's alone, [B, V]: the head runs over one row (``_logits_at``)."""
     x, cos, sin, q_scale = _embed(params, cfg, tokens, q_positions)
     chunks = []
     for scope, layers, experts, first in _scans(params, cfg):
@@ -497,19 +509,20 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions):
             x, chunk = jax.lax.scan(body, x, layers)
         chunks.append(chunk)
     chunk = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, axis=0)
-    return _logits(params, cfg, x), chunk
+    return _logits_at(params, cfg, x, row), chunk
 
 
 def forward(params, cfg: ModelConfig, tokens, q_positions, cache, write_start,
-            mesh=None, live=None, counters=False):
+            mesh=None, live=None, counters=False, row=None):
     """Serving forward (prefill or decode: same code, different T).
 
     tokens, q_positions: int32 [B, T]; cache: [L, B, S, W]; write_start:
     int32 [B], the row where this chunk's rows land. ``live``: bool [B]
     or None, the slots whose logits the caller will use; the decode
-    kernel skips the others. Returns (logits [B, T, V] f32, cache), and
-    with ``counters`` a third: int32 [len(DECODE_COUNTERS)], summed over
-    the layers that have a router.
+    kernel skips the others. ``row``: int32 scalar or None, the one row of
+    the T whose logits the caller will use; the logits are then [B, V].
+    Returns (logits [B, T, V] f32, cache), and with ``counters`` a third:
+    int32 [len(DECODE_COUNTERS)], summed over the layers that have a router.
     """
     del mesh  # one chip a replica: nothing here is sharded
     x, cos, sin, q_scale = _embed(params, cfg, tokens, q_positions)
@@ -526,5 +539,5 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache, write_start,
 
         with jax.named_scope(scope):
             (x, cache, counts), _ = jax.lax.scan(body, (x, cache, counts), layers)
-    logits = _logits(params, cfg, x)
+    logits = _logits_at(params, cfg, x, row)
     return (logits, cache, counts) if counters else (logits, cache)

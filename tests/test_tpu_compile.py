@@ -304,6 +304,40 @@ def test_cell_decode_programs_keep_the_cache_in_place(one_chip, kernel_route_on,
     assert temp < 0.5e9 or chunk == 8, temp
 
 
+def test_cell_prefill_insert_runs_its_head_over_one_row(one_chip):
+    """The fresh-prefill program at the eval-batch cell's shape and its
+    largest bucket: the chip's compiler holds no value of T rows by V columns.
+    Until the row was taken before the head it held the product over every
+    prompt row (``bf16[1024,32768]``) and sliced the sampled row out of it:
+    the TPU's compiler does not push a dynamic slice through a product."""
+    cfg = _cell_model()
+    T = 1024
+    ecfg = EngineConfig(
+        num_slots=CELL_SLOTS, max_seq=CELL_SEQ, max_sessions=0,
+        prefill_buckets=(384, 512, 640, 768, 896, T),
+    )
+    params, ck, cv = _model_operands(
+        cfg, lambda _spec: one_chip, CELL_SLOTS, CELL_SEQ
+    )
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = build_programs(cfg, ecfg, None).prefill_insert.lower(
+        params, ck, cv, arg(jnp.int32, 1, T), arg(jnp.int32, 1, T),
+        arg(jnp.int32), arg(jnp.int32), arg(jnp.uint32, 2),
+        arg(jnp.float32), arg(jnp.float32), arg(jnp.int32),
+    ).compile().as_text()
+    V = cfg.vocab_size
+    assert re.search(rf"f32\[1,{V}\]", text)  # the one row's logits
+    wide = {
+        m.group(0) for m in re.finditer(r"\w+\[([\d,]+)\]", text)
+        if (dims := [int(d) for d in m.group(1).split(",")])[-1] == V
+        and int(np.prod(dims)) == T * V
+    }
+    assert not wide, wide
+
+
 def test_tp4_engine_decode_chunk_compiles_sharded(tp4_mesh, kernel_route_on):
     """The engine's real decode program (the scan of decode_chunk steps)
     on a dp=1 × tp=4 mesh of described devices, operands sharded by the
