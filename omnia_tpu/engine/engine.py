@@ -360,7 +360,7 @@ class InferenceEngine(
             # ran a step ahead); decode_slot_steps sums live slots x
             # steps at dispatch (occupancy where the batch is formed) and
             # decode_kv_blocks the decode kernel's blocks their contexts
-            # span x steps (the share of the cache's blocks it visits);
+            # span x steps (decode_window_rows: a window layer's ring rows);
             # decode_steps_sampling / _filtering the steps in which a live
             # request sampled / also filtered (ops/sampling.py's gates);
             # pipeline_flushes counts the flushes forced by a waiting
@@ -373,7 +373,7 @@ class InferenceEngine(
             "decode_dispatches_single": 0,
             "decode_dispatches_blocked": 0,
             "decode_slot_steps": 0,
-            "decode_kv_blocks": 0,
+            "decode_kv_blocks": 0, "decode_window_rows": 0,
             "decode_steps_sampling": 0,
             "decode_steps_filtering": 0,
             "pipeline_flushes": 0,
@@ -640,7 +640,7 @@ class InferenceEngine(
             return mc.num_layers * self.model_module.row_width(mc) * itemsize
         scale_bytes = 4 if self._kv_quant else 0
         return (
-            mc.num_layers * mc.num_kv_heads
+            mc.attention_kinds.count("full") * mc.num_kv_heads  # rings do not grow
             * (mc.head_dim * itemsize + scale_bytes) * 2
         )
 

@@ -2,7 +2,8 @@
 
 The engine holds a family's cache as ``_cache``, the tuple of arrays its
 model module makes (``models.model_module(cfg).init_kv_cache``): Llama's K
-and V, the latent family's one array. The programs and mixins that every
+and V (with window layers, their rings' K and V besides), the latent
+family's one array. The programs and mixins that every
 family has (prefill, extend, decode, warmup) take and return it whole.
 What exists for the pair family alone names the pair's two arrays, and is
 refused for another family when the engine is built."""
@@ -10,14 +11,44 @@ refused for another family when the engine is built."""
 from __future__ import annotations
 
 from omnia_tpu.engine.types import EngineConfig
-from omnia_tpu.models import ModelConfig
+from omnia_tpu.models import ModelConfig, llama
 
 
 def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
-    """Raise, naming the feature, where ``cfg`` asks a model of the latent
-    family (models/mla.py) for something only the pair family's programs
-    and mixins do: nothing falls through to a (K, V) pair silently."""
-    if not model_cfg.is_latent:
+    """Raise, naming the feature, where ``cfg`` asks a model for something
+    only the programs and mixins of the pair family's plain shape do (one
+    tree of layers all alike, a K and a V whose row s is position s):
+    nothing falls through to a (K, V) pair silently. Two kinds of model are
+    refused so: one of the latent family (models/mla.py), and one of the
+    pair family whose layers are of several kinds (models/llama.py's stacks:
+    window layers, a share of the routed experts, leading dense layers)."""
+    if model_cfg.is_latent:
+        family, why = "the latent-attention family (models/mla.py", {}
+    elif llama.is_stacked(model_cfg):
+        family = "a model of several kinds of layers (models/llama.py's stacks"
+        rings = ("its window layers' cache is a ring, whose row is not a position"
+                 if model_cfg.has_window_layers else "its cache is written by the stacks' "
+                 "own layer index")
+        why = {
+            "kv_quant": f"{rings}: the int8 rows and scales are not made for it",
+            "kv_pages": f"{rings}: a page table maps positions to rows",
+            "max_sessions": f"{rings}: a session's rows are offloaded and restored by position",
+            "prefix_cache_slots": f"{rings}: a shared prefix is copied by position, and a "
+                                  "ring holds the end of a prompt, not its start",
+            "spec_decode": f"{rings}: a rejected proposal's rows have overwritten rows "
+                           "still inside the window, and the model's multi-token-prediction "
+                           "module is not built",
+            "prefill_chunk_tokens": "the mixed step takes the pair's two arrays, and a dead "
+                                    "slot's decode write between a placement's pieces is "
+                                    "what a ring must not get",
+            "quant": "the stacks' projections and experts are plain matmuls, not qdot",
+            "sp": "ring-attention prefill returns rows by position for `insert`",
+            "tp": "the stacks and the expert share are replicated whole; the exchange "
+                  "between expert-parallel ranks is not built",
+            "dp": "the stacks and the expert share are replicated whole, and the decode "
+                  "kernels run without a mesh",
+        }
+    else:
         return
     asked = {
         "kv_quant": cfg.kv_quant, "kv_pages": cfg.kv_pages > 0,
@@ -29,9 +60,10 @@ def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
     }
     for name, on in asked.items():
         if on:
+            reason = f": {why[name]}" if name in why else ""
             raise NotImplementedError(
-                f"EngineConfig.{name}={getattr(cfg, name)!r} is not ported to the "
-                f"latent-attention family (models/mla.py; model {model_cfg.name!r})"
+                f"EngineConfig.{name}={getattr(cfg, name)!r} is not ported to "
+                f"{family}; model {model_cfg.name!r}){reason}"
             )
 
 

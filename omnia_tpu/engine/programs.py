@@ -65,7 +65,9 @@ programs take and return whole, right behind ``params``: each array of it
 is donated and moved by the same row helpers. A module that names
 ``DECODE_COUNTERS`` has them summed on the device over a chunk's steps and
 layers and appended to the chunk's token buffer, one row a counter, so
-they are read back with the tokens and by nothing else. What is not
+they are read back with the tokens and by nothing else. A slot's view of
+an array is its row axis whole, whatever its length (K and V of window
+layers are rings: which rows mean what is models/stacks.py's). What is not
 ported to a family keeps the pair's signature below and is refused for
 the others at engine construction (family.py::refuse_unported).
 
@@ -92,7 +94,7 @@ import jax
 import jax.numpy as jnp
 
 from omnia_tpu.engine.types import EngineConfig
-from omnia_tpu.models import ModelConfig, model_module
+from omnia_tpu.models import ModelConfig, cache_arrays, decode_counters, model_module
 from omnia_tpu.models.kv_quant import cache_put, cache_take, kv_map
 from omnia_tpu.models import paged_kv as pkv
 from omnia_tpu.ops.sampling import _NEG_INF, sample_tokens_per_slot
@@ -151,9 +153,9 @@ def build_programs(
     model = model_module(cfg)
     # Arrays of the family's cache tuple: the operands right behind
     # ``params`` of every program that takes the cache whole.
-    n_cache = len(model.kv_cache_specs(ecfg.kv_quant))
+    n_cache = cache_arrays(cfg, ecfg.kv_quant)
     cache_args = tuple(range(1, 1 + n_cache))
-    counters = len(getattr(model, "DECODE_COUNTERS", ()))
+    counters = len(decode_counters(cfg))
 
     # Grammar-constrained decoding: when the engine is built with
     # ecfg.grammar, every first-token sampler (prefill_insert / insert /

@@ -48,6 +48,7 @@ from omnia_tpu.engine.faults import WatchdogTimeout
 from omnia_tpu.engine.phases import phase
 from omnia_tpu.engine.placement import RELEASED
 from omnia_tpu.engine.types import FinishReason, SamplingParams, StreamEvent
+from omnia_tpu.models import decode_counters
 from omnia_tpu.ops.attention import decode_block_rows
 
 
@@ -472,7 +473,9 @@ class _SchedulerMixin:
         without reckoning it from tokens. ``decode_kv_blocks`` is
         ``_live_kv_blocks`` at dispatch times the steps asked, so over
         ``decode_steps * num_slots * max_seq / block`` it is the share
-        of all (slot, block) pairs the decode kernel visits.
+        of all (slot, block) pairs the decode kernel visits;
+        ``decode_window_rows`` the ring rows a window layer's kernel spans
+        (the model module's ``decode_window_rows``, where it has rings).
         ``decode_steps_sampling`` / ``decode_steps_filtering`` are the
         steps asked times ``_live_sampling``: over ``decode_steps``, the
         shares of steps in which the sampler did more than the argmax,
@@ -482,6 +485,10 @@ class _SchedulerMixin:
         m["decode_dispatches"] += 1
         m["decode_slot_steps"] += len(live) * steps
         m["decode_kv_blocks"] += self._live_kv_blocks(live) * steps
+        window_rows = getattr(self.model_module, "decode_window_rows", None)
+        if window_rows:
+            m["decode_window_rows"] += steps * window_rows(
+                self.model_cfg, [self._slots[i].length for i, _rid in live])
         sampling, filtering = self._live_sampling(live)
         m["decode_steps_sampling"] += steps * sampling
         m["decode_steps_filtering"] += steps * filtering
@@ -579,7 +586,7 @@ class _SchedulerMixin:
         if ch.placement is not None:
             return self._process_first_token(ch)
         t_sync = time.monotonic()
-        counters = getattr(self.model_module, "DECODE_COUNTERS", ())
+        counters = decode_counters(self.model_cfg)
         with phase(phases.CHUNK_SYNC) as sp:
             if sp:
                 sp.set_metadata(

@@ -279,7 +279,10 @@ class _WarmupMixin:
         for b in cfg.mixed_prefill_buckets():
             add("mixed", f"bucket{b}", mixed_task(b))
 
-        if sessions:
+        if sessions and cfg.max_sessions > 0:
+            # (An engine that keeps no session never offloads or restores
+            # one: ``sessions`` then warms the extend family alone, for
+            # prompts placed in pieces.)
             def session_task(r):
                 def run(st):
                     # The operands eviction and restore dispatch with

@@ -14,4 +14,26 @@ def model_module(cfg: ModelConfig):
     return llama
 
 
-__all__ = ["ModelConfig", "PRESETS", "get_config", "model_module"]
+def cache_arrays(cfg: ModelConfig, kv_quant=None) -> int:
+    """How many arrays the cache tuple of ``cfg`` has (K and V; with window
+    layers their rings besides; the latent family's one), counted on the
+    module's own ``init_kv_cache``: the operands right behind ``params`` of
+    every program that takes the cache whole."""
+    import jax
+
+    module = model_module(cfg)
+    return len(jax.eval_shape(lambda: module.init_kv_cache(cfg, 1, 1, kv_quant=kv_quant)))
+
+
+def decode_counters(cfg: ModelConfig) -> tuple:
+    """The counters a decode step of ``cfg`` sums on the device over its
+    layers, in the order its module's ``forward(..., counters=True)``
+    returns them (engine.metrics keys): the module's ``decode_counters(cfg)``
+    where what it counts depends on the model, else its ``DECODE_COUNTERS``,
+    else none."""
+    module = model_module(cfg)
+    own = getattr(module, "decode_counters", None)
+    return tuple(own(cfg)) if own else tuple(getattr(module, "DECODE_COUNTERS", ()))
+
+
+__all__ = ["ModelConfig", "PRESETS", "cache_arrays", "decode_counters", "get_config", "model_module"]

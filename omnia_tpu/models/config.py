@@ -83,6 +83,27 @@ class ModelConfig:
     hc_eps: float = 1e-6
     hc_res_clamp_min: float = -30.0
     hc_res_clamp_max: float = 30.0
+    # Attention kinds by layer in the pair family (models/llama.py), the
+    # source's ``layer_types``: "sliding_attention" (a layer that sees the
+    # query's own row and the sliding_window - 1 before it, cached in a
+    # ring) or "full_attention" (every row before it, cached whole). The
+    # first num_layers entries count (a model cut in depth keeps its
+    # source's list whole); None = every layer full. With any window layer,
+    # with experts of moe_ffn_hidden_size or with num_dense_layers,
+    # params["layers"] is a sequence of stacks, one for each kind of layer
+    # the model has (models/llama.py::stack_kinds).
+    layer_types: Optional[tuple] = None
+    sliding_window: int = 0
+    # The kinds of those stacks where they are not what the layers
+    # themselves show: a model cut out of another keeps that one's stacks,
+    # some of them with no layer (llama.with_layer_order sets it).
+    layer_stacks: Optional[tuple] = None
+    # False: a full-attention layer applies no rotary position (window
+    # layers always rotate). True with no window layer is every model before.
+    rope_on_full_layers: bool = True
+    # RMSNorm over each query and key head's head_dim values, one gain for
+    # all heads, before any rotation.
+    qk_norm: bool = False
 
     @property
     def q_dim(self) -> int:
@@ -108,17 +129,45 @@ class ModelConfig:
     def router_bias(self) -> bool:
         return self.router_topk_method == "noaux_tc"
 
+    @property
+    def attention_kinds(self) -> tuple:
+        """"window" or "full" for layer 0 ... num_layers - 1."""
+        if self.layer_types is None:
+            return ("full",) * self.num_layers
+        if len(self.layer_types) < self.num_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers "
+                             f"of {self.num_layers}")
+        kinds = {"sliding_attention": "window", "full_attention": "full"}
+        return tuple(kinds[t] for t in self.layer_types[:self.num_layers])
+
+    @property
+    def has_window_layers(self) -> bool:
+        return "window" in self.attention_kinds or any(
+            "window" in kind for kind in self.layer_stacks or ())
+
     def num_params(self) -> int:
-        """Approximate parameter count (for memory planning)."""
+        """Parameters this chip holds (for memory planning): of a model
+        with a share of the routed experts (moe_ffn_hidden_size), the held
+        ones beside the shared expert and the router, in the layers behind
+        the num_dense_layers leading ones; a QK-norm's two gains a layer.
+        Exact for the pair family; the latent family's attention and
+        hyper-connection maps are not counted (models/mla.py::init_params
+        is their word)."""
         d, f, v = self.hidden_size, self.ffn_hidden_size, self.vocab_size
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-        if self.is_moe:
-            mlp = self.num_experts * 3 * d * f + d * self.num_experts
-        else:
-            mlp = 3 * d * f
-        per_layer = attn + mlp + 2 * d
+        attn += 2 * self.head_dim * self.qk_norm
+        dense_mlp, dense = 3 * d * f, self.num_layers
+        sparse_mlp = 0
+        if self.moe_ffn_hidden_size:
+            fe = self.moe_ffn_hidden_size
+            sparse_mlp = ((self.experts_held + self.num_shared_experts) * 3 * d * fe
+                          + d * self.num_experts + self.num_experts * self.router_bias)
+            dense = self.num_dense_layers
+        elif self.is_moe:
+            dense_mlp = self.num_experts * 3 * d * f + d * self.num_experts
         embed = v * d * (1 if self.tie_embeddings else 2)
-        return self.num_layers * per_layer + embed + d
+        return (self.num_layers * (attn + 2 * d) + dense * dense_mlp
+                + (self.num_layers - dense) * sparse_mlp + embed + d)
 
 
 PRESETS: dict[str, ModelConfig] = {
@@ -261,6 +310,37 @@ PRESETS: dict[str, ModelConfig] = {
         router_topk_method="noaux_tc",
         num_dense_layers=1,
         residual_copies=4,
+    ),
+    # The pair family with layers of several kinds: a leading dense layer,
+    # window layers (8 rows) around one full layer that applies no rotary
+    # position, QK-norm, and behind the dense layer a sigmoid router with a
+    # selection bias over 8 experts of which rank 1 of 2 holds 4, beside a
+    # shared expert: (dense, window), (sparse, window), (sparse, full).
+    "test-tiny-window": ModelConfig(
+        name="test-tiny-window",
+        vocab_size=256,
+        hidden_size=64,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        ffn_hidden_size=128,
+        rope_theta=10000.0,
+        num_experts=8,
+        num_experts_per_tok=2,
+        max_seq_len=512,
+        moe_ffn_hidden_size=32,
+        num_shared_experts=1,
+        num_experts_held=4,
+        expert_rank=1,
+        routed_scaling_factor=2.5,
+        router_scoring="sigmoid",
+        router_topk_method="noaux_tc",
+        num_dense_layers=1,
+        layer_types=("sliding_attention", "sliding_attention", "full_attention"),
+        sliding_window=8,
+        rope_on_full_layers=False,
+        qk_norm=True,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

@@ -22,6 +22,15 @@ models; see SURVEY.md §0):
   FFN hidden dim, expert dim, vocab). Activations shard batch over "dp". XLA
   GSPMD inserts the collectives; there are no explicit psums here.
 - Compute dtype bf16 (MXU native), logits and softmax statistics f32.
+
+**Layers of several kinds** (``is_stacked(cfg)``: window layers, a share of
+the routed experts, leading dense layers) are models/stacks.py's, which this
+module dispatches to at the top of each entry point: ``params["layers"]`` is
+then a sequence of stacks, the cache of a model with window layers four
+arrays (whole contexts beside rings), and ``layer_order`` /
+``with_layer_order`` state and cut the order. Everything above is the model
+whose layers are all alike, which traces none of that and compiles to the
+programs it always had.
 """
 
 from __future__ import annotations
@@ -42,6 +51,17 @@ from omnia_tpu.models.kv_quant import (
 )
 from omnia_tpu.models.paged_kv import PagedKV, is_paged, write_rows
 from omnia_tpu.models.quant import qdot
+from omnia_tpu.models.stacks import (  # noqa: F401  (the module contract's names)
+    _init_stacks,
+    _run_stacks,
+    decode_counters,
+    decode_window_rows,
+    is_stacked,
+    layer_order,
+    ring_rows,
+    stack_kinds,
+    with_layer_order,
+)
 from omnia_tpu.ops.attention import gqa_attention
 from omnia_tpu.ops.moe import moe_mlp
 from omnia_tpu.ops.norms import rms_norm
@@ -54,7 +74,10 @@ from omnia_tpu.ops.rope import apply_rope, rope_cos_sin
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
-    """Random-initialized parameter pytree (layers stacked on axis 0)."""
+    """Random-initialized parameter pytree (layers stacked on axis 0; a
+    model of several kinds of layers: a stack each, ``_init_stacks``)."""
+    if is_stacked(cfg):
+        return _init_stacks(cfg, key, dtype)
     L, D, F, V = cfg.num_layers, cfg.hidden_size, cfg.ffn_hidden_size, cfg.vocab_size
     keys = iter(jax.random.split(key, 16))
 
@@ -97,7 +120,12 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
 
 
 def param_specs(cfg: ModelConfig):
-    """PartitionSpec pytree matching init_params (tensor parallel on "tp")."""
+    """PartitionSpec pytree matching init_params (tensor parallel on "tp");
+    a model of several kinds of layers is replicated whole (the engine
+    refuses tp/dp/sp > 1 for it)."""
+    if is_stacked(cfg):
+        return jax.tree_util.tree_map(
+            lambda _: P(), jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))))
     attn = {
         "wq": P(None, None, "tp"),
         "wk": P(None, None, "tp"),
@@ -154,7 +182,8 @@ def kv_cache_specs(kv_quant=None) -> tuple:
     """(k, v) PartitionSpecs for [L, B, S, Hkv, D] caches: batch over "dp",
     KV heads over "tp". With kv_quant the spec tree mirrors the QuantKV
     pytree (the scale drops the trailing head-dim axis but keeps the
-    "tp"-sharded head axis)."""
+    "tp"-sharded head axis). (A model with window layers has four arrays
+    and runs unsharded: nobody lays these over its cache.)"""
     spec = P(None, "dp", None, "tp", None)
     if validate_kv_quant(kv_quant):
         qspec = QuantKV(spec, P(None, "dp", None, "tp"))
@@ -175,8 +204,17 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloat16,
                   kv_quant=None):
     """Zeroed (k, v) caches: plain [L, B, S, Hkv, D] arrays, or QuantKV
     pairs (int8 rows + per-row-per-head f32 scales) when kv_quant is
-    set. kv_quant=None allocates no scale tensors at all."""
+    set. kv_quant=None allocates no scale tensors at all. A model with
+    window layers: (k, v) of its full layers at ``seq`` rows, then (k, v) of
+    its window layers at the ring's (``ring_rows``), whatever ``seq``."""
     shape = (cfg.num_layers, batch, seq, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.has_window_layers:
+        if kv_quant:
+            raise NotImplementedError("kv_quant is not ported to a cache with rings")
+        kinds = cfg.attention_kinds
+        full = (kinds.count("full"), *shape[1:])
+        ring = (kinds.count("window"), batch, ring_rows(cfg), *shape[3:])
+        return tuple(jnp.zeros(shape, dtype=dtype) for shape in (full, full, ring, ring))
     if validate_kv_quant(kv_quant):
         def one():
             return QuantKV(
@@ -331,9 +369,16 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None,
     Returns (logits [B, T, V] f32, k_chunk, v_chunk [L, B, T, Hkv, D]);
     with ``row`` (int32 scalar) the logits are that row's alone, [B, V]:
     the final norm and the head run over one row (``_logits_at``).
-    attn_fn overrides the attention op (the ring-prefill path).
+    attn_fn overrides the attention op (the ring-prefill path). A model
+    with window layers returns four chunks, the window layers' already in
+    the ring's shape [Lw, B, R, Hkv, D]: the last R real rows where the
+    ring holds them.
     """
     x, cos, sin = _embed(params, cfg, tokens, q_positions)
+    if is_stacked(cfg):
+        x, chunks, _ = _run_stacks(params, cfg, x, cos, sin, q_positions, None, None,
+                                   row, None, None)
+        return (_logits_at(params, cfg, x, row), *chunks)
 
     def body(x, p):
         x, k, v = _layer(
@@ -368,6 +413,7 @@ def forward_prefill_ring(params, cfg: ModelConfig, tokens, q_positions, mesh,
     return forward_prefill(params, cfg, tokens, q_positions, attn_fn=ring, row=row)
 
 
+
 # ---------------------------------------------------------------------------
 # Forward passes
 # ---------------------------------------------------------------------------
@@ -393,12 +439,14 @@ def _logits_at(params, cfg: ModelConfig, x, row):
     return _logits(params, cfg, x)[:, 0]
 
 
-def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
-            write_start, mesh=None, live=None, row=None):
+def forward(params, cfg: ModelConfig, tokens, q_positions, *cache_and_start,
+            mesh=None, live=None, row=None, counters=False):
     """Serving forward (prefill or decode — same code, different T).
 
-    tokens, q_positions: int32 [B, T]; cache_k/v: [L, B, S, Hkv, D];
-    write_start: int32 [B] row offset where this chunk's KV lands.
+    tokens, q_positions: int32 [B, T]; then the cache's arrays (cache_k/v:
+    [L, B, S, Hkv, D]; four for a model with window layers, the module
+    docstring) and last write_start:
+    int32 [B] row offset where this chunk's KV lands.
     mesh: the mesh params and caches are sharded over, if any — the
     decode kernel needs it named (ops/attention.py).
     live: bool [B], or None for "every slot live": the slots whose
@@ -408,7 +456,9 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
     row: int32 scalar, or None for "every row": the one row of the T
     whose logits the caller will use (a placement's last prompt row).
     Returns (logits [B, T, V] f32, new_cache_k, new_cache_v); with ``row``
-    the logits are [B, V].
+    the logits are [B, V]. Rows of the T past ``row`` are pad: a ring is
+    not written by them. With ``counters`` (a model that names
+    ``decode_counters``) a last result more: int32 [len(decode_counters(cfg))].
 
     The caches (plain, QuantKV or PagedKV alike) ride the layer scan
     whole, as its carry beside ``x``; what is scanned is each layer's
@@ -417,7 +467,14 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, cache_k, cache_v,
     donated the returned ones are the same buffers and the bytes that
     move are the new rows.
     """
+    *cache, write_start = cache_and_start
     x, cos, sin = _embed(params, cfg, tokens, q_positions)  # x [B,T,D]
+    if is_stacked(cfg):
+        x, cache, counts = _run_stacks(params, cfg, x, cos, sin, q_positions,
+                                       tuple(cache), write_start, row, mesh, live)
+        out = (_logits_at(params, cfg, x, row), *cache)
+        return (*out, counts) if counters else out
+    cache_k, cache_v = cache
 
     def body(carry, scanned):
         x, ck, cv = carry
@@ -450,7 +507,11 @@ def forward_embed(params, cfg: ModelConfig, tokens, mask):
         x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None)
         return x, None
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    if is_stacked(cfg):
+        x = _run_stacks(params, cfg, x, cos, sin, q_positions, None, None, None,
+                        None, None)[0]
+    else:
+        x, _ = jax.lax.scan(body, x, params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps).astype(jnp.float32)
     m = mask.astype(jnp.float32)[:, :, None]
     pooled = (x * m).sum(axis=1) / jnp.maximum(m.sum(axis=1), 1.0)
@@ -465,6 +526,9 @@ def forward_train(params, cfg: ModelConfig, tokens):
     B, T = tokens.shape
     q_positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
     x, cos, sin = _embed(params, cfg, tokens, q_positions)
+    if is_stacked(cfg):
+        return _logits(params, cfg, _run_stacks(
+            params, cfg, x, cos, sin, q_positions, None, None, None, None, None)[0])
 
     def body(x, p):
         x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None)

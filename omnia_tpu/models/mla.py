@@ -88,7 +88,9 @@ from omnia_tpu.models.config import ModelConfig
 from omnia_tpu.ops import hyper_connections as hc
 from omnia_tpu.ops.attention import _kernel_on, _pallas_decode_mode
 from omnia_tpu.ops.decode_mla_attention import block_rows, decode_mla_attention
-from omnia_tpu.ops.moe import moe_dropless
+from omnia_tpu.ops.moe import EXPERT_COUNTERS
+from omnia_tpu.ops.moe import expert_ffn as _experts
+from omnia_tpu.ops.moe import unstack_experts as _unstack_experts
 from omnia_tpu.ops.norms import rms_norm
 from omnia_tpu.ops.rope import (
     apply_rope,
@@ -102,7 +104,7 @@ _NEG_INF = -1e30
 
 #: Counters a decode step sums on the device over its layers, in the
 #: order ``forward(..., counters=True)`` returns them (engine.metrics keys).
-DECODE_COUNTERS = ("moe_assignments_held", "moe_experts_hit")
+DECODE_COUNTERS = EXPERT_COUNTERS
 
 
 #: Cache rows in one block of this family's decode kernel, by cache length:
@@ -339,47 +341,6 @@ def _absorbed_attention(q_nope, q_rope, cache, wkvb, cfg: ModelConfig, q_positio
     with jax.named_scope("attn.absorb"):
         out = jnp.einsum("bhr,rhd->bhd", o_lat, wkvb[..., dn:])
     return out.reshape(B, 1, H * dv)
-
-
-_EXPERT_STACKS = ("wg", "wu", "wd")
-
-
-def _unstack_experts(layers):
-    """(what the layer scan slices a layer at a time, the routed experts'
-    three stacks [L, Eh, …] whole): the experts are nine tenths of a
-    layer's bytes and a step needs only those a token chose, so the scan
-    must not slice a layer's out (ops/moe.py::_grouped_matmul). Of two
-    stacks this is the sparse one's; a dense layer's FFN is read whole."""
-    mlp = layers["mlp"]
-    scanned = {**layers, "mlp": {k: v for k, v in mlp.items() if k not in _EXPERT_STACKS}}
-    return scanned, {k: mlp[k] for k in _EXPERT_STACKS}
-
-
-def _swiglu(h, p):
-    return jnp.dot(jax.nn.silu(jnp.dot(h, p["wg"])) * jnp.dot(h, p["wu"]), p["wd"])
-
-
-def _experts(h2, p, experts, layer, cfg: ModelConfig):
-    """The routed experts held here (``experts``: their stacks over the
-    sparse layers, of which ``layer``'s are used) and the shared expert. h2
-    [B, T, D] → (y [B, T, D], counts int32 [2] as DECODE_COUNTERS). A dense
-    layer (``experts`` None) is one SwiGLU and counts nothing."""
-    if experts is None:
-        return _swiglu(h2, p), jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
-    B, T, D = h2.shape
-    router = {k: p[k] for k in ("router", "bias") if k in p}
-    y, held, hit = moe_dropless(
-        h2.reshape(B * T, D), {**router, **experts},
-        cfg.num_experts_per_tok,
-        first_expert=cfg.expert_rank * cfg.experts_held,
-        routed_scaling_factor=cfg.routed_scaling_factor, layer=layer,
-        scoring=cfg.router_scoring,
-    )
-    y = y.reshape(B, T, D)
-    if "shared" in p:
-        with jax.named_scope("moe.shared"):
-            y = y + _swiglu(h2, p["shared"])
-    return y, jnp.stack([held, hit])
 
 
 def _layer(x, p, experts, at, cfg: ModelConfig, cos, sin, q_scale, q_positions,

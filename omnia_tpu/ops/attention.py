@@ -274,3 +274,84 @@ def gqa_attention(
         probs = probs.astype(v_cache.dtype)
         out = jnp.einsum("bhgts,bshd->bthgd", probs, v_cache)
     return out.reshape(B, T, H, D)
+
+
+# ---------------------------------------------------------------------------
+# Window layers (models/llama.py's stacks): a band over [the rows before the
+# chunk | the chunk] on the prefill / extend path, a ring on the decode path.
+# ---------------------------------------------------------------------------
+
+
+def band_attention(q, k, v, prev_k, prev_v, first, window: int):
+    """Causal attention in which a query sees its own row and the ``window``
+    - 1 before it, over a chunk and the rows that precede it.
+
+    q [B, T, H, D]; k, v [B, T, Hkv, D], the chunk's own rows at positions
+    ``first[b] + t``; prev_k, prev_v [B, window, Hkv, D], the rows at
+    positions ``first[b] - window + j`` (one before ``first`` is the last;
+    those before position 0 are masked whatever they hold), or None for a
+    chunk with nothing before it. → [B, T, H, D].
+
+    The score tensor is the band, never [H, T, S] masked: the queries go in
+    blocks of Q = ``window`` rows (one block of T where ``window`` does not
+    divide T), and block n meets rows n·Q … n·Q + Q + window - 1 of [prev |
+    chunk], the only ones any of its queries sees: [H, T, Q + window]
+    scores in float32, O(T × (2 × window)) whatever the context."""
+    B, T, H, D = q.shape
+    Hkv, W = k.shape[2], window
+    G = H // Hkv
+    Q = W if T % W == 0 else T
+    nb = T // Q
+    if prev_k is None:
+        prev_k = prev_v = jnp.zeros((B, W, Hkv, D), k.dtype)
+    at = (jnp.arange(nb, dtype=jnp.int32)[:, None] * Q
+          + jnp.arange(Q + W, dtype=jnp.int32)[None, :])              # [nb, Q + W]
+    kb = jnp.concatenate([prev_k.astype(k.dtype), k], axis=1)[:, at]   # [B, nb, Q+W, Hkv, D]
+    vb = jnp.concatenate([prev_v.astype(v.dtype), v], axis=1)[:, at]
+    scores = jnp.einsum("bnqhgd,bnkhd->bnhgqk", q.reshape(B, nb, Q, Hkv, G, D), kb,
+                        preferred_element_type=jnp.float32) * (D**-0.5)
+    # Query i of a block lies at row W + n·Q + i of [prev | chunk], key j at
+    # n·Q + j: causal j <= W + i, inside the window j > i.
+    i = jnp.arange(Q, dtype=jnp.int32)[:, None]
+    j = jnp.arange(Q + W, dtype=jnp.int32)[None, :]
+    seen = ((j > i) & (j <= i + W))[None, None]                        # [1, 1, Q, Q+W]
+    if first is None:
+        real = (at >= W)[None, :, None, :]                             # [1, nb, 1, Q+W]
+    else:
+        real = (first[:, None, None] - W + at[None] >= 0)[:, :, None, :]  # [B, nb, 1, Q+W]
+    scores = jnp.where((seen & real)[:, :, None, None], scores, _NEG_INF)
+    probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+    probs = (probs / probs.sum(axis=-1, keepdims=True)).astype(v.dtype)
+    out = jnp.einsum("bnhgqk,bnkhd->bnqhgd", probs, vb)
+    return out.reshape(B, T, H, D)
+
+
+def ring_decode_attention(q, ring_k, ring_v, q_positions, layer, live, window: int):
+    """One decode step of a window layer over layer ``layer`` of its rings
+    [L, B, R, Hkv, D] (R a power of two): row r of a slot holds the newest
+    position ≡ r (mod R) at or before the slot's, its own included. q [B,
+    1, H, D] → [B, 1, H, D]. The Pallas kernel where it is routed on
+    (ops/decode_attention.py::decode_window_attention: a dead slot reads
+    nothing), else the same mask over the ring by einsum."""
+    B, _, H, D = q.shape
+    R, Hkv = ring_k.shape[2:4]
+    if _kernel_on():
+        from omnia_tpu.ops import decode_attention as dk
+
+        if live is None:
+            live = jnp.ones((B,), jnp.int32)
+        return dk.decode_window_attention(
+            q[:, 0], ring_k, ring_v, q_positions[:, 0], jnp.asarray(layer, jnp.int32),
+            live=live.astype(jnp.int32), window=window,
+            block_s=decode_block_rows(R), interpret=_pallas_decode_mode() == "interpret",
+        )[:, None]
+    k = jax.lax.dynamic_index_in_dim(ring_k, layer, 0, keepdims=False)   # [B, R, Hkv, D]
+    v = jax.lax.dynamic_index_in_dim(ring_v, layer, 0, keepdims=False)
+    scores = jnp.einsum("bhgd,bshd->bhgs", q[:, 0].reshape(B, Hkv, H // Hkv, D), k,
+                        preferred_element_type=jnp.float32) * (D**-0.5)
+    back = (q_positions - jnp.arange(R, dtype=jnp.int32)[None, :]) & (R - 1)  # [B, R]
+    seen = (back < window) & (back <= q_positions)
+    scores = jnp.where(seen[:, None, None, :], scores, _NEG_INF)
+    probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+    probs = (probs / probs.sum(axis=-1, keepdims=True)).astype(v.dtype)
+    return jnp.einsum("bhgs,bshd->bhgd", probs, v).reshape(B, 1, H, D)

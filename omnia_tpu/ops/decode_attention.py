@@ -69,11 +69,12 @@ def _decode_kernel(
     scale: float,
     quantized: bool = False,
     paged: bool = False,
+    window: int = 0,
 ):
     """One grid step a live (slot, block) pair; see the module docstring.
     The editions share every line: ``paged`` only adds the table the
     index maps read, ``quantized`` the two [1, BLOCK_S, Hkv] f32 blocks
-    of row scales."""
+    of row scales, ``window`` the mask of a ring (``decode_window_attention``)."""
     del layer_ref
     rest = rest[2:] if paged else rest[1:]  # the table, the aliased zeros
     # q_ref [1, Hkv, G, D]; k_ref, v_ref [1, BLOCK_S, Hkv, D] (bf16, or
@@ -112,7 +113,15 @@ def _decode_kernel(
     key_idx = s * block_s + jax.lax.broadcasted_iota(
         jnp.int32, scores.shape, dimension=2
     )
-    scores = jnp.where(key_idx <= pos, scores, _NEG_INF)
+    if window:
+        # The cache is a ring of num_s * block_s rows (a power of two):
+        # row r holds the newest position ≡ r at or before ``pos``, which
+        # lies ``back`` rows behind the query; rows that position has not
+        # reached yet hold another tenant's and lie "before position 0".
+        back = (pos - key_idx) & (num_s * block_s - 1)
+        scores = jnp.where((back < window) & (back <= pos), scores, _NEG_INF)
+    else:
+        scores = jnp.where(key_idx <= pos, scores, _NEG_INF)
 
     m_prev, l_prev = m_ref[:], l_ref[:]
     m_new = jnp.maximum(m_prev, scores.max(axis=-1))
@@ -176,10 +185,11 @@ def _pair(work_ref, w, num_s: int):
 
 
 def _attend(name, q, k, v, scales, table, positions, live, layer, block_s,
-            num_s, interpret):
-    """The one ``pallas_call`` behind both entry points: ``table`` None
+            num_s, interpret, window: int = 0):
+    """The one ``pallas_call`` behind every entry point: ``table`` None
     is the contiguous cache [L, B, S, Hkv, D], else the pool
-    [L, P, PAGE_S, Hkv, D] with ``block_s == PAGE_S``."""
+    [L, P, PAGE_S, Hkv, D] with ``block_s == PAGE_S``; ``window`` > 0 reads
+    the contiguous cache as a ring."""
     B, H, D = q.shape
     Hkv = k.shape[3]
     G = H // Hkv
@@ -223,6 +233,7 @@ def _attend(name, q, k, v, scales, table, positions, live, layer, block_s,
         functools.partial(
             _decode_kernel, block_s=block_s, num_s=num_s, scale=D**-0.5,
             quantized=bool(scales), paged=table is not None,
+            **({"window": window} if window else {}),
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         grid_spec=grid_spec,
@@ -293,3 +304,35 @@ def decode_gqa_attention(
     scales = () if k_scale is None else (k_scale, v_scale)
     return _attend("decode_gqa_attention", q, k_cache, v_cache, scales, None,
                    positions, live, layer, block_s, S // block_s, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "block_s", "interpret"))
+def decode_window_attention(
+    q: jnp.ndarray,          # [B, H, D] (rotary already applied)
+    k_ring: jnp.ndarray,     # [L, B, R, Hkv, D]
+    v_ring: jnp.ndarray,     # [L, B, R, Hkv, D]
+    positions: jnp.ndarray,  # int32 [B] — current decode position per slot
+    layer: jnp.ndarray,      # int32 [] — the layer of the rings to attend over
+    live: jnp.ndarray = None,     # int32/bool [B]; None = every slot live
+    window: int = 0,
+    block_s: int = DEFAULT_BLOCK_S,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """→ [B, H, D], a window layer's decode attention over layer ``layer``
+    of its rings: row ``r`` of a slot holds the newest position ``p ≡ r (mod
+    R)`` at or before the slot's ``positions`` entry (its own row included,
+    already written), and the query sees the ``window`` positions up to its
+    own. The body, the work list and the pipeline are
+    ``decode_gqa_attention``'s; what differs is the mask, by the position a
+    row holds and not by its index, and that a live slot's blocks are the
+    ring's (``min(position // block_s + 1, R // block_s)``: at most R rows
+    whatever the context). R is a power of two and a multiple of
+    ``block_s``. Neither kernel knows of rotary position: q and the rows
+    come rotated or not, as the layer's kind says."""
+    R = k_ring.shape[2]
+    if R % block_s or R & (R - 1) or not 0 < window <= R:
+        raise ValueError(f"ring of {R} rows, block {block_s}, window {window}: R "
+                         f"is a power of two, a multiple of the block, and holds the window")
+    return _attend("decode_window_attention", q, k_ring, v_ring, (), None,
+                   positions, live, layer, block_s, R // block_s, interpret,
+                   window=window)

@@ -214,3 +214,51 @@ def moe_dropless(h, p, num_experts_per_tok: int, first_expert: int = 0,
         ys = ys[jnp.argsort(order)].reshape(N, K, d)     # back to token order
         out = jnp.sum(ys * top_w[:, :, None], axis=1).astype(h.dtype)
     return out, held.sum(dtype=jnp.int32), (sizes > 0).sum(dtype=jnp.int32)
+
+
+#: What ``expert_ffn`` counts a layer, in the order it returns them (the
+#: engine.metrics keys a decode step's sums land under).
+EXPERT_COUNTERS = ("moe_assignments_held", "moe_experts_hit")
+
+_EXPERT_STACKS = ("wg", "wu", "wd")
+
+
+def unstack_experts(layers):
+    """(what the layer scan slices a layer at a time, the routed experts'
+    three stacks [L, Eh, …] whole): the experts are nine tenths of a
+    layer's bytes and a step needs only those a token chose, so the scan
+    must not slice a layer's out (``_grouped_matmul``). Of several stacks
+    this is a sparse one's; a dense layer's FFN is read whole."""
+    mlp = layers["mlp"]
+    scanned = {**layers, "mlp": {k: v for k, v in mlp.items() if k not in _EXPERT_STACKS}}
+    return scanned, {k: mlp[k] for k in _EXPERT_STACKS}
+
+
+def swiglu(h, p):
+    return jnp.dot(jax.nn.silu(jnp.dot(h, p["wg"])) * jnp.dot(h, p["wu"]), p["wd"])
+
+
+def expert_ffn(h2, p, experts, layer, cfg):
+    """The FFN of one layer of a model that holds a share of the routed
+    experts (models/mla.py, and models/llama.py's stacks): the routed
+    experts held here (``experts``: their stacks over the stack's layers,
+    of which ``layer``'s are used; ``cfg``: a ModelConfig) and the shared
+    expert. h2 [B, T, D] → (y [B, T, D], counts int32 [2] as
+    EXPERT_COUNTERS). A dense layer (``experts`` None) is one SwiGLU and
+    counts nothing."""
+    if experts is None:
+        return swiglu(h2, p), jnp.zeros((len(EXPERT_COUNTERS),), jnp.int32)
+    B, T, D = h2.shape
+    router = {k: p[k] for k in ("router", "bias") if k in p}
+    y, held, hit = moe_dropless(
+        h2.reshape(B * T, D), {**router, **experts},
+        cfg.num_experts_per_tok,
+        first_expert=cfg.expert_rank * cfg.experts_held,
+        routed_scaling_factor=cfg.routed_scaling_factor, layer=layer,
+        scoring=cfg.router_scoring,
+    )
+    y = y.reshape(B, T, D)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + swiglu(h2, p["shared"])
+    return y, jnp.stack([held, hit])

@@ -1,0 +1,30 @@
+"""The benchmark's own tests of `k-exaone-236b-a23b` (benchmark/tests/
+test_kexaone.py: the configuration file's promises, the flat copies the
+reference reads, the byte counts against the parameter trees, the check at
+the rehearsal's widths with a window one row too wide, and the seven readers
+the cell brings), run by tier-1 as `tests/test_bench_harness_stacks.py` runs
+the stacked family's. The program's side of the same model is
+`tests/test_kexaone.py`."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+for path in (os.path.join(BENCH, "tests"), BENCH):  # the case file; harness
+    if path not in sys.path:
+        sys.path.insert(0, path)
+
+import importlib.util  # noqa: E402
+
+# (Loaded by path: `tests/test_kexaone.py` has the same module name.)
+_spec = importlib.util.spec_from_file_location(
+    "bench_test_kexaone", os.path.join(BENCH, "tests", "test_kexaone.py"))
+cases = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(cases)
+globals().update({name: value for name, value in vars(cases).items()
+                  if name.startswith("test_") or name in ("cell", "traced")})
+
+
+def test_tier_1_runs_the_seven_new_readers_cases():
+    assert len(cases.NEW_READERS) == 7
+    assert test_the_new_readers_read_the_cell is cases.test_the_new_readers_read_the_cell  # noqa: F821

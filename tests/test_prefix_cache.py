@@ -465,6 +465,7 @@ class TestMetricsKeyStability:
         "prefill_steps", "decode_steps", "extend_steps", "prefill_tokens",
         "decode_dispatches", "decode_dispatches_single",
         "decode_dispatches_blocked", "decode_slot_steps", "decode_kv_blocks",
+        "decode_window_rows",
         "decode_steps_sampling", "decode_steps_filtering",
         "moe_assignments_held", "moe_experts_hit",
         "pipeline_flushes", "placements_deferred",

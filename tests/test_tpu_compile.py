@@ -167,6 +167,31 @@ def test_latent_decode_kernel_compiles(one_chip, slots, rows, rank, lanes):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("slots,dtype", [(32, jnp.bfloat16), (1, jnp.bfloat16),
+                                         (1, jnp.float32)],
+                         ids=["longdoc-batch", "the-check", "the-long-check-in-float32"])
+def test_window_decode_kernel_compiles(one_chip, slots, dtype):
+    """`decode_window_attention` at K-EXAONE's published widths (64 query
+    heads on 8 KV heads of 128, a window of 128 rows in a ring of 128: one
+    block a live slot whatever its context), over the four window layers'
+    rings at the cell's 32 slots and at the one slot the checks run; the
+    full layer's `decode_gqa_attention` beside it at the cell's 8960 rows."""
+    def arr(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, pos = arr((slots, 64, 128), dtype), arr((slots,), jnp.int32)
+    ring = arr((4, slots, 128, 8, 128), dtype)
+    compiled = jax.jit(
+        lambda q, k, v, pos, layer, live: dk.decode_window_attention(
+            q, k, v, pos, layer, live=live, window=128, block_s=128)
+    ).lower(q, ring, ring, pos, arr((), jnp.int32), pos).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    whole = arr((1, slots, 8960, 8, 128), dtype)
+    compiled = dk.decode_gqa_attention.lower(q, whole, whole, pos, arr((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_one_chip_decode_step_holds_the_mosaic_call(one_chip, kernel_route_on):
     cfg = get_config("llama3-1b")
     params, ck, cv = _model_operands(cfg, lambda _spec: one_chip)
