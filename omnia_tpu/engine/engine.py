@@ -84,6 +84,7 @@ from omnia_tpu.models import quant
 from omnia_tpu.models.kv_quant import cache_bytes, validate_kv_quant
 from omnia_tpu.ops.sampling import make_slot_key_data
 from omnia_tpu.ops.attention import check_decode_kernel, pallas_decode_mode
+from omnia_tpu.ops.moe import GROUPED_MATMUL_MIN_ROWS
 from omnia_tpu.parallel import init_sharded, make_mesh, shard_pytree
 from omnia_tpu.utils.compile_cache import enable_compilation_cache, enabled_dir
 
@@ -507,11 +508,10 @@ class InferenceEngine(
         # metrics dict existed — fold the tracker's view in now.
         self._sync_coldstart_metrics()
         logger.info(
-            "engine built: backend=%s pallas_decode=%s slots=%d max_seq=%d "
-            "chunks=%s quant=%s kv_quant=%s",
-            jax.default_backend(), pallas_decode_mode(), B, engine_cfg.max_seq,
-            self.cfg.chunk_variants(), qmode, self._kv_quant,
-        )
+            "engine built: backend=%s pallas_decode=%s grouped_matmul_from_rows=%d "
+            "slots=%d max_seq=%d chunks=%s quant=%s kv_quant=%s",
+            jax.default_backend(), pallas_decode_mode(), GROUPED_MATMUL_MIN_ROWS,
+            B, engine_cfg.max_seq, self.cfg.chunk_variants(), qmode, self._kv_quant)
 
     def _alloc_kv_state(self):
         """Fresh KV arrays at the engine's exact layout, representation,

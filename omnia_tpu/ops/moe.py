@@ -22,8 +22,14 @@ A third, ``moe_dropless``, is the expert layer of a chip that holds a
 SHARE of the experts (models/mla.py): it routes over all of them, sorts the
 (token, expert) assignments, and runs one grouped matmul over the
 assignments that landed on the experts it holds. No capacity, so nothing
-drops, and no token count at which the implementation changes. Mixtral's
-``moe_mlp`` moves onto it, and the two above go, in a later PR (ROADMAP).
+drops: at every token count every assignment on a held expert is
+multiplied by that expert's matrices, accumulated in float32, none capped
+or approximated. What changes with the count is the tile the grouped
+matmul is computed in: a call of ``GROUPED_MATMUL_MIN_ROWS`` rows or
+more (a prompt's) runs through the Pallas kernel of ops/grouped_matmul.py
+where the kernels are routed on, a shorter one (a decode step's) through
+``jax.lax.ragged_dot`` (``_grouped_matmul``). Mixtral's ``moe_mlp`` moves
+onto it, and the two above go, in a later PR (ROADMAP).
 
 Sharding: expert-leading weights [E, d, f] shard E over the "tp" axis
 (expert parallelism). The [E, C, d] buffer shards over E, each device runs
@@ -37,6 +43,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from omnia_tpu.ops.attention import _kernel_on, _pallas_decode_mode
+from omnia_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
 
 
 def top_k_weights(logits, k: int, scoring: str = "softmax", bias=None):
@@ -150,21 +159,42 @@ def moe_mlp(h, p, num_experts_per_tok: int, capacity_factor: float = 2.0):
     return moe_dispatch(h, p, num_experts_per_tok, capacity_factor)
 
 
-def _grouped_matmul(xs, w, sizes, layer):
-    """Rows of ``xs`` in runs of ``sizes`` against each run's own matrix of
-    ``w`` [Eh, k, n]. With ``layer``, ``w`` is the whole stack [L, Eh, k, n]
-    and the runs meet layer ``layer``'s matrices where they lie: the stack
-    is one group axis of L·Eh (a reshape) in which every other layer's
-    groups are empty. Slicing the layer out in front of ``ragged_dot``
-    instead copies all its experts, hit or not, once a call (1.6 GB a layer
-    a step at Mistral-Small-4's widths: 62 % of a decode step, PERF.md
-    section 6, PR 32)."""
+#: A grouped matmul of this many row tiles or more goes through the Pallas
+#: kernel. The served models' decode steps call with 192–384 rows (slots ×
+#: k), their shortest prompt buckets with 2,048 and more. (A decode step's
+#: call would gain from the kernel too, 1.2–1.7 × by the microbenchmark of
+#: PERF.md section 6, PR 42: ROADMAP Speed 2, an issue of its own.)
+GROUPED_MATMUL_MIN_ROW_TILES = 4
+#: The same in rows (tokens × k); the engine's start-up line says it
+#: beside ``pallas_decode_mode()``.
+GROUPED_MATMUL_MIN_ROWS = GROUPED_MATMUL_MIN_ROW_TILES * ROW_TILE
+
+
+def _ragged_matmul(xs, w, sizes, layer):
+    """``_grouped_matmul`` by ``jax.lax.ragged_dot``: with ``layer`` the
+    stack is one group axis of L·Eh (a reshape) in which every other
+    layer's groups are empty."""
     if layer is None:
         return jax.lax.ragged_dot(xs, w, sizes)
     L, Eh = w.shape[:2]
     stack_sizes = jax.lax.dynamic_update_slice(
         jnp.zeros((L * Eh,), sizes.dtype), sizes, (layer * Eh,))
     return jax.lax.ragged_dot(xs, w.reshape(L * Eh, *w.shape[2:]), stack_sizes)
+
+
+def _grouped_matmul(xs, w, sizes, layer):
+    """Rows of ``xs`` in runs of ``sizes`` against each run's own matrix of
+    ``w`` [Eh, k, n]. With ``layer``, ``w`` is the whole stack [L, Eh, k, n]
+    and the runs meet layer ``layer``'s matrices where they lie. Slicing
+    the layer out in front instead copies all its experts, hit or not,
+    once a call (1.6 GB a layer a step at Mistral-Small-4's widths: 62 % of
+    a decode step, PERF.md section 6, PR 32). The route is chosen by the
+    call's static row count, so a compiled program holds exactly one; both
+    give a held row the same product, accumulated in float32."""
+    if _kernel_on() and xs.shape[0] >= GROUPED_MATMUL_MIN_ROWS:
+        return grouped_matmul(xs, w, sizes, layer,
+                              interpret=_pallas_decode_mode() == "interpret")
+    return _ragged_matmul(xs, w, sizes, layer)
 
 
 def moe_dropless(h, p, num_experts_per_tok: int, first_expert: int = 0,
@@ -187,10 +217,11 @@ def moe_dropless(h, p, num_experts_per_tok: int, first_expert: int = 0,
     expert, and how many held experts got at least one.
 
     The N·k assignments are sorted by expert with those on absent
-    experts last; ``ragged_dot`` multiplies each held expert's run of
-    rows by that expert's weights and visits no expert whose run is
-    empty; the rows return to token order by the inverse permutation
-    and sum over k. Every shape is static; the group sizes are data."""
+    experts last; the grouped matmul (``_grouped_matmul``) multiplies each
+    held expert's run of rows by that expert's weights and visits no
+    expert whose run is empty; the rows return to token order by the
+    inverse permutation and sum over k. Every shape is static; the group
+    sizes are data."""
     N, d = h.shape
     Eh, K = p["wg"].shape[-3], num_experts_per_tok
     with jax.named_scope("moe.route"):
@@ -209,7 +240,7 @@ def moe_dropless(h, p, num_experts_per_tok: int, first_expert: int = 0,
         up = _grouped_matmul(xs, p["wu"], sizes, layer)
         ys = _grouped_matmul(jax.nn.silu(gate) * up, p["wd"], sizes, layer)
     with jax.named_scope("moe.combine"):
-        # Rows past the held runs are whatever ragged_dot left there.
+        # Rows past the held runs are whatever the grouped matmul left there.
         ys = jnp.where(held[order][:, None], ys, 0).astype(jnp.float32)
         ys = ys[jnp.argsort(order)].reshape(N, K, d)     # back to token order
         out = jnp.sum(ys * top_w[:, :, None], axis=1).astype(h.dtype)

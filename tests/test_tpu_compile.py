@@ -26,6 +26,7 @@ from omnia_tpu.engine.programs import build_programs
 from omnia_tpu.engine.types import MAX_DEVICE_STOP_IDS, EngineConfig
 from omnia_tpu.models import get_config, llama
 from omnia_tpu.ops import attention as attn
+from omnia_tpu.ops import moe
 from omnia_tpu.ops import decode_attention as dk
 from omnia_tpu.parallel import make_mesh
 
@@ -302,8 +303,10 @@ def test_cell_decode_programs_keep_the_cache_in_place(one_chip, kernel_route_on,
     # batch runs no sort over the vocabulary (PR 33).
     assert " conditional(" in text and re.search(r"\bsort\(", text)
     assert _sorts_outside_conditionals(text) == []
-    # One layer body, one Mosaic call in it.
+    # One layer body, one Mosaic call in it; a dense model reaches neither
+    # route of the experts' grouped matmul.
     assert text.count("tpu_custom_call") == 1
+    assert "ragged-dot" not in text and "grouped_matmul" not in text
     # Nothing produces a second cache, a layer of it, or a re-laid-out one:
     # the only instructions as large as a layer of K are the row writes,
     # fusions whose root updates the carried buffer in place.
@@ -355,12 +358,159 @@ def test_cell_prefill_insert_runs_its_head_over_one_row(one_chip):
     ).compile().as_text()
     V = cfg.vocab_size
     assert re.search(rf"f32\[1,{V}\]", text)  # the one row's logits
+    assert "ragged-dot" not in text and "grouped_matmul" not in text  # a dense model
     wide = {
         m.group(0) for m in re.finditer(r"\w+\[([\d,]+)\]", text)
         if (dims := [int(d) for d in m.group(1).split(",")])[-1] == V
         and int(np.prod(dims)) == T * V
     }
     assert not wide, wide
+
+
+# The experts' grouped matmuls (ops/moe.py::_grouped_matmul): a prompt's row
+# counts go through the Pallas kernel of ops/grouped_matmul.py, a decode
+# step's through ``ragged_dot`` (``ragged-dot-none*`` custom calls on the
+# chip). The two sparse cells whose prompt side is most of the device's time,
+# at their own sizes (benchmark/cells/, benchmark/configs/).
+SPARSE_CELLS = {
+    "judge-batch": ("xing4-29b-a4b.judge-batch", "prefill_insert", 1536),
+    "longdoc-batch": ("k-exaone-236b-a23b.longdoc-batch", "extend_nosample", 1024),
+}
+
+
+def _sparse_cell(one_chip, name):
+    """(cfg, ecfg, programs, params, cache) of a benchmark cell: the
+    program's configs as the harness builds them, shapes on the described
+    chip."""
+    import os
+    import sys
+
+    from omnia_tpu.models import model_module
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                         "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import manifest
+
+    cell = manifest.Cell(name)
+    cfg, ecfg = cell.model_config(), cell.engine_config()
+    model = model_module(cfg)
+
+    def shapes(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make))
+
+    params = shapes(lambda: model.init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16))
+    cache = shapes(lambda: model.init_kv_cache(cfg, ecfg.num_slots, ecfg.max_seq,
+                                               dtype=jnp.bfloat16))
+    return cfg, ecfg, build_programs(cfg, ecfg, None), params, cache
+
+
+# rows (tokens × k), layers of the cut stack, experts held, d, f: the three
+# sparse cells' grouped matmuls at a prompt's rows (chip_grouped_matmul.py).
+GROUPED_SHAPES = {
+    "judge-batch": (6144, 4, 64, 3584, 1024),
+    "longdoc-batch": (8192, 4, 16, 6144, 2048),
+    "reason-batch": (4096, 5, 32, 4096, 2048),
+}
+
+
+@pytest.mark.parametrize("matmul", ["gate-up", "down"])
+@pytest.mark.parametrize("cell", sorted(GROUPED_SHAPES))
+def test_grouped_matmul_kernel_compiles(one_chip, cell, matmul):
+    """The kernel alone at each cell's widths, the tiles it picks itself
+    (a whole [K, N] matrix a block, twice in VMEM: 15–50 MB, past the
+    compiler's default scope): Mosaic takes the blocks, the stack goes in
+    whole, and what comes out has the rows' type."""
+    from omnia_tpu.ops.grouped_matmul import grouped_matmul, tiles
+
+    rows, L, Eh, d, f = GROUPED_SHAPES[cell]
+    K, N = (d, f) if matmul == "gate-up" else (f, d)
+    assert tiles(K, N, 2) == (K, N)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(grouped_matmul).lower(
+        arg(jnp.bfloat16, rows, K), arg(jnp.bfloat16, L, Eh, K, N),
+        arg(jnp.int32, Eh), arg(jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(_grouped_matmul_calls(text)) == 1
+    assert re.search(rf"ROOT \S+ = bf16\[{rows},{N}\]", text)
+    assert not re.search(rf"bf16\[{Eh},{K},{N}\]\S* (copy|dynamic-slice|fusion)\(", text)
+
+
+def _grouped_matmul_calls(text: str) -> list[str]:
+    return re.findall(r"%grouped_matmul[.\d]* = \S+ custom-call\(", text)
+
+
+def _ragged_dot_calls(text: str) -> list[str]:
+    return re.findall(r"%ragged-dot[\w.\-]* = \S+ custom-call\(", text)
+
+
+@pytest.mark.parametrize("cell", sorted(SPARSE_CELLS))
+def test_sparse_cells_prompt_programs_hold_the_grouped_matmul_kernel(
+        one_chip, kernel_route_on, cell):
+    """``prefill_insert`` at judge-batch's middle bucket (6,144 rows a call)
+    and ``extend_nosample`` at longdoc-batch's piece (8,192): the three
+    matmuls of each sparse layer body are the kernel's Mosaic calls, no
+    ``ragged_dot`` is left, and nothing as large as a layer's experts is
+    copied, sliced or re-laid out in front of them: the kernel's operand is
+    the scan's own stack (PR 32 measured that copy at 62 % of a decode
+    step)."""
+    name, program, T = SPARSE_CELLS[cell]
+    cfg, ecfg, programs, params, cache = _sparse_cell(one_chip, name)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = arg(jnp.int32)
+    tokens = (arg(jnp.int32, 1, T), arg(jnp.int32, 1, T))
+    if program == "prefill_insert":
+        lowered = programs.prefill_insert.lower(
+            params, *cache, *tokens, i32, i32, arg(jnp.uint32, 2),
+            arg(jnp.float32), arg(jnp.float32), i32)
+    else:
+        lowered = programs.extend_nosample.lower(params, *cache, *tokens, i32, i32)
+    text = lowered.compile().as_text()
+    assert T * cfg.num_experts_per_tok >= moe.GROUPED_MATMUL_MIN_ROWS
+    calls = _grouped_matmul_calls(text)
+    assert calls and len(calls) % 3 == 0, calls
+    assert _ragged_dot_calls(text) == []
+    experts = cfg.experts_held * cfg.hidden_size * cfg.moe_ffn_hidden_size
+    widths = {cfg.hidden_size, cfg.moe_ffn_hidden_size}
+    for ln in text.splitlines():
+        m = re.search(r"= \w+\[[\d,]+\]\S* (copy|copy-start|dynamic-slice|slice|"
+                      r"transpose|fusion|convert)\(", ln)
+        dims = result_dims(ln) if m else []
+        if len(dims) >= 3 and set(dims[-2:]) == widths:
+            assert int(np.prod(dims)) < experts, ln.strip()[:200]
+
+
+@pytest.mark.parametrize("cell", sorted(SPARSE_CELLS))
+def test_sparse_cells_decode_step_keeps_ragged_dot(one_chip, kernel_route_on, cell):
+    """The one-step decode program of the same models (192 and 256 rows a
+    call, under the constant): three ``ragged_dot`` calls a sparse layer
+    body and no call of the grouped-matmul kernel, so the decode step's
+    rooflines read the ops they read before PR 42."""
+    cfg, ecfg, programs, params, cache = _sparse_cell(one_chip, SPARSE_CELLS[cell][0])
+    rows = ecfg.num_slots * cfg.num_experts_per_tok
+    assert rows < moe.GROUPED_MATMUL_MIN_ROWS
+
+    def vec(dtype, *tail):
+        return jax.ShapeDtypeStruct((ecfg.num_slots, *tail), dtype, sharding=one_chip)
+
+    i32, f32 = (lambda *t: vec(jnp.int32, *t)), vec(jnp.float32)
+    text = programs.decode_fns[1].lower(
+        params, *cache, i32(), i32(), vec(jnp.bool_), i32(),
+        i32(MAX_DEVICE_STOP_IDS), vec(jnp.uint32, 2), f32, f32, i32(),
+    ).compile().as_text()
+    ragged = _ragged_dot_calls(text)
+    assert ragged and len(ragged) % 3 == 0, ragged
+    assert _grouped_matmul_calls(text) == []
+    assert any(f"[{rows}," in call for call in ragged), ragged
 
 
 def test_tp4_engine_decode_chunk_compiles_sharded(tp4_mesh, kernel_route_on):
