@@ -466,10 +466,10 @@ class InferenceEngine(
             # over a decode chunk's steps and layers and read back with
             # its tokens: the (token, expert) assignments that landed on
             # an expert held here, and the held experts that got at least
-            # one token, a layer a step. Over every row of the batch a
-            # step computes, live or not. Zero for a model without one.
-            "moe_assignments_held": 0,
-            "moe_experts_hit": 0,
+            # one token, a layer a step, over every row of the batch, live or
+            # not; decode_kda_slots the same way: the states linear-attention
+            # layers updated, live slots a layer a step. Zero without either.
+            "moe_assignments_held": 0, "moe_experts_hit": 0, "decode_kda_slots": 0,
             # Paged KV cache (engine/kv_pages.py) — pool gauges, live
             # while kv_pages > 0 and zero otherwise: usable pages total/
             # free, internal fragmentation of slot-referenced pages
@@ -636,8 +636,8 @@ class InferenceEngine(
         roofline at THIS engine's configured precision."""
         mc = self.model_cfg
         itemsize = 1 if self._kv_quant else jnp.dtype(self._dtype).itemsize
-        if mc.is_latent:  # one row a token a layer, as allocated
-            return mc.num_layers * self.model_module.row_width(mc) * itemsize
+        if mc.is_latent:  # a row a token a latent layer, as allocated (a state does not grow)
+            return mc.attention_kinds.count("full") * self.model_module.row_width(mc) * itemsize
         scale_bytes = 4 if self._kv_quant else 0
         return (
             mc.attention_kinds.count("full") * mc.num_kv_heads  # rings do not grow

@@ -3,10 +3,16 @@
 The engine holds a family's cache as ``_cache``, the tuple of arrays its
 model module makes (``models.model_module(cfg).init_kv_cache``): Llama's K
 and V (with window layers, their rings' K and V besides), the latent
-family's one array. The programs and mixins that every
-family has (prefill, extend, decode, warmup) take and return it whole.
-What exists for the pair family alone names the pair's two arrays, and is
-refused for another family when the engine is built."""
+family's one array of rows, or with linear-attention layers three: the
+latent layers' rows, a recurrent state a slot a layer (float32 matrices,
+no row axis at all: a slot's view takes its axis 2, the heads, whole) and
+the short convolution's tail. The programs and mixins that every
+family has (prefill, extend, decode, warmup) take and return it whole, and
+never index it: which rows and steps may touch a slot's state is the model
+module's word (models/mla.py::_kda_layer), since nothing masks a state by
+position afterwards. What exists for the pair family alone names the
+pair's two arrays, and is refused for another family when the engine is
+built."""
 
 from __future__ import annotations
 
@@ -24,6 +30,23 @@ def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
     window layers, a share of the routed experts, leading dense layers)."""
     if model_cfg.is_latent:
         family, why = "the latent-attention family (models/mla.py", {}
+        if model_cfg.has_state_layers:
+            state = ("its linear-attention layers keep a recurrent state a slot, a "
+                     "matrix a head that every token of the context is summed into, "
+                     "with no rows")
+            why = {
+                "max_sessions": f"{state}: a session's rows are offloaded and restored "
+                                "by position, and a state has no rows to offload",
+                "prefix_cache_slots": f"{state}: a shared prefix is seeded by copying its "
+                                      "rows, and the state at the prefix's end is kept nowhere",
+                "kv_pages": f"{state}: a page table maps positions to rows, and a state "
+                            "has none to page",
+                "spec_decode": f"{state}: a rejected proposal is rolled back by moving the "
+                               "frontier, and a state that has taken a token cannot give it back",
+                "prefill_chunk_tokens": "the mixed step takes the pair's two arrays, and "
+                                        "runs a decode step over a slot between its "
+                                        "placement's pieces, which a state must not get",
+            }
     elif llama.is_stacked(model_cfg):
         family = "a model of several kinds of layers (models/llama.py's stacks"
         rings = ("its window layers' cache is a ring, whose row is not a position"

@@ -59,15 +59,15 @@ Without a row the model returns every row: ``verify`` reads them all,
 
 Model family and cache: the programs reach the model through
 ``models.model_module(cfg)`` and through nothing else, and a family's cache
-is an opaque TUPLE of arrays (Llama's K and V, the latent family's one
-array) that ``prefill_insert``, ``insert``, ``extend`` and the decode
-programs take and return whole, right behind ``params``: each array of it
-is donated and moved by the same row helpers. A module that names
-``DECODE_COUNTERS`` has them summed on the device over a chunk's steps and
-layers and appended to the chunk's token buffer, one row a counter, so
-they are read back with the tokens and by nothing else. A slot's view of
-an array is its row axis whole, whatever its length (K and V of window
-layers are rings: which rows mean what is models/stacks.py's). What is not
+is an opaque TUPLE of arrays (Llama's K and V; the latent family's one, or its rows
+beside a recurrent state and a convolution's tail) that ``prefill_insert``, ``insert``,
+``extend`` and the decode programs take and return whole, right behind ``params``: each
+is donated and moved by the same row helpers. A module that names ``DECODE_COUNTERS``
+has them summed on the device over a chunk's steps and layers and appended to the
+chunk's token buffer, one row a counter, so they are read back with the tokens and by
+nothing else. A slot's view of an array is its axis 2 whole, whatever its length (window
+layers' K and V are rings: which rows mean what is models/stacks.py's; a recurrent state
+has heads there and no rows: which steps may touch it is models/mla.py's). What is not
 ported to a family keeps the pair's signature below and is refused for
 the others at engine construction (family.py::refuse_unported).
 

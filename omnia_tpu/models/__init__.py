@@ -16,9 +16,12 @@ def model_module(cfg: ModelConfig):
 
 def cache_arrays(cfg: ModelConfig, kv_quant=None) -> int:
     """How many arrays the cache tuple of ``cfg`` has (K and V; with window
-    layers their rings besides; the latent family's one), counted on the
-    module's own ``init_kv_cache``: the operands right behind ``params`` of
-    every program that takes the cache whole."""
+    layers their rings besides; the latent family's one, or with
+    linear-attention layers its rows, their recurrent states and their
+    convolutions' tails), counted on the module's own ``init_kv_cache``: the
+    operands right behind ``params`` of every program that takes the cache
+    whole. Not every array has a row axis: a state is ``[L, B, heads, dk,
+    dv]``, and a slot's view of it is the whole of axis 2 like any other's."""
     import jax
 
     module = model_module(cfg)
@@ -29,8 +32,9 @@ def decode_counters(cfg: ModelConfig) -> tuple:
     """The counters a decode step of ``cfg`` sums on the device over its
     layers, in the order its module's ``forward(..., counters=True)``
     returns them (engine.metrics keys): the module's ``decode_counters(cfg)``
-    where what it counts depends on the model, else its ``DECODE_COUNTERS``,
-    else none."""
+    where what it counts depends on the model (the expert layer's; the
+    states a step updates, ``decode_kda_slots``, for a model with
+    linear-attention layers), else its ``DECODE_COUNTERS``, else none."""
     module = model_module(cfg)
     own = getattr(module, "decode_counters", None)
     return tuple(own(cfg)) if own else tuple(getattr(module, "DECODE_COUNTERS", ()))

@@ -41,6 +41,7 @@ class ModelConfig:
     # by every head. The query passes through a rank of its own; a head's
     # query and key are qk_nope_head_dim values without position beside
     # qk_rope_head_dim rotated ones, its value v_head_dim.
+    # q_rank 0 with a kv_rank: no query rank, q = h·Wq straight to the heads.
     kv_rank: int = 0
     q_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -99,11 +100,25 @@ class ModelConfig:
     # some of them with no layer (llama.with_layer_order sets it).
     layer_stacks: Optional[tuple] = None
     # False: a full-attention layer applies no rotary position (window
-    # layers always rotate). True with no window layer is every model before.
+    # layers always rotate; in the latent family the latent layers are the
+    # full ones, and q_rope, k_rope are used as projected). True with no
+    # window layer is every model before.
     rope_on_full_layers: bool = True
     # RMSNorm over each query and key head's head_dim values, one gain for
     # all heads, before any rotation.
     qk_norm: bool = False
+    # Linear-attention layers in the latent family (models/mla.py;
+    # ``layer_types`` "linear_attention"): the gated delta rule with a
+    # channel-wise decay (KDA, ops/kda.py) over kda_num_heads heads whose
+    # keys and values are both kda_head_dim wide, behind a depthwise causal
+    # convolution of kda_conv_kernel taps on q, k and v; the decay and the
+    # output gate pass through a rank of kda_gate_rank. Such a layer's slot
+    # state is a float32 matrix a head and the convolution's last
+    # kda_conv_kernel - 1 input rows: no rows, no positions.
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_gate_rank: int = 0
 
     @property
     def q_dim(self) -> int:
@@ -131,19 +146,27 @@ class ModelConfig:
 
     @property
     def attention_kinds(self) -> tuple:
-        """"window" or "full" for layer 0 ... num_layers - 1."""
+        """"window", "full" or "kda" for layer 0 ... num_layers - 1."""
         if self.layer_types is None:
             return ("full",) * self.num_layers
         if len(self.layer_types) < self.num_layers:
             raise ValueError(f"layer_types names {len(self.layer_types)} layers "
                              f"of {self.num_layers}")
-        kinds = {"sliding_attention": "window", "full_attention": "full"}
+        kinds = {"sliding_attention": "window", "full_attention": "full",
+                 "linear_attention": "kda"}
         return tuple(kinds[t] for t in self.layer_types[:self.num_layers])
 
     @property
     def has_window_layers(self) -> bool:
         return "window" in self.attention_kinds or any(
             "window" in kind for kind in self.layer_stacks or ())
+
+    @property
+    def has_state_layers(self) -> bool:
+        """Whether a slot's cache holds a recurrent state (linear-attention
+        layers, or the stacks of a model cut out of one that has them)."""
+        return "kda" in self.attention_kinds or any(
+            "kda" in kind for kind in self.layer_stacks or ())
 
     def num_params(self) -> int:
         """Parameters this chip holds (for memory planning): of a model
@@ -341,6 +364,45 @@ PRESETS: dict[str, ModelConfig] = {
         sliding_window=8,
         rope_on_full_layers=False,
         qk_norm=True,
+    ),
+    # The latent family with layers of several kinds: a leading dense layer
+    # with linear attention (KDA), then K, M, K behind it with a sigmoid
+    # router and a selection bias over 8 experts of which rank 1 of 2 holds
+    # 4, beside a shared expert; the latent layer has no query rank and no
+    # rotary position: (dense, kda), (sparse, kda), (sparse, mla).
+    "test-tiny-kda": ModelConfig(
+        name="test-tiny-kda",
+        vocab_size=256,
+        hidden_size=64,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        ffn_hidden_size=128,
+        rope_theta=10000.0,
+        num_experts=8,
+        num_experts_per_tok=2,
+        max_seq_len=512,
+        kv_rank=32,
+        q_rank=0,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        moe_ffn_hidden_size=32,
+        num_shared_experts=1,
+        num_experts_held=4,
+        expert_rank=1,
+        routed_scaling_factor=2.446,
+        router_scoring="sigmoid",
+        router_topk_method="noaux_tc",
+        num_dense_layers=1,
+        layer_types=("linear_attention", "linear_attention", "full_attention",
+                     "linear_attention"),
+        rope_on_full_layers=False,
+        kda_num_heads=4,
+        kda_head_dim=16,
+        kda_conv_kernel=4,
+        kda_gate_rank=8,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

@@ -1,8 +1,9 @@
 """Latent-attention (MLA) transformer with a dropless expert layer that
 holds a share of the routed experts beside a shared expert, functional JAX;
 optionally with leading dense layers (two stacks), a residual stream of
-several copies mixed by hyper-connections, and a sigmoid router with a
-selection bias.
+several copies mixed by hyper-connections, a sigmoid router with a
+selection bias, and layers of linear attention (the gated delta rule with
+a channel-wise decay, KDA) beside the latent ones in any stated order.
 
 The block, for a layer with input ``x`` [T, D] (benchmark/reference/
 mla_moe_ref.py is the same mathematics in plain float32):
@@ -45,6 +46,22 @@ mathematics in plain float32):
   ``s`` at those k renormalised, times ``routed_scaling_factor``
   (ops/moe.py::top_k_weights).
 
+A fourth, for a model that states an attention kind a layer (``cfg.layer_types``;
+``has_kinds``; benchmark/reference/kimi_linear_ref.py is its mathematics):
+
+- **Layers of several kinds, in any order.** A layer is *latent* ("full_attention":
+  the block above; ``q = h·Wq`` straight to the heads where ``cfg.q_rank`` is 0; no
+  rotary position where ``cfg.rope_on_full_layers`` is false) or *linear*
+  ("linear_attention": ``_kda_layer``, ops/kda.py). ``params["layers"]`` is a stack for
+  each kind the model has, FFN kind × attention kind (``stack_kinds``), in any
+  stated order (models/kinds.py, which models/stacks.py goes through too), a scan
+  a run of layers of one kind under ``stack.<kind>``. The cache is then THREE
+  arrays: the latent layers' rows, the linear layers' float32 states ``[Lk, B, H,
+  dk, dv]`` and their convolutions' tails; a layer's index into them is its count
+  among the layers of its attention kind. Scopes ``attn.kda`` (inside it
+  ``kda.conv``, ``kda.gates``, ``kda.chunk``, ``kda.state``, ``kda.out``); counter
+  ``decode_kda_slots``.
+
 **The cache is one array** ``[L, B, S, W]``: a token's row is ``[c | k_rope |
 0]``, W the next multiple of 128 (ops/decode_mla_attention.py says why).
 ``forward`` takes and returns it as a tuple of one, the contract of
@@ -73,7 +90,8 @@ and that partial stream goes on to the next layer: on one chip the layer
 runs without its exchange (the `model-configs` guide, section 4). Not
 ported to this family, and refused by name at engine construction:
 kv_quant, kv_pages, sessions and the prefix pool, spec_decode, the mixed
-step, sp, tp/dp > 1.
+step, sp, tp/dp > 1 (engine/family.py says why a recurrent state cannot
+have its rows offloaded, seeded, paged or rolled back).
 """
 
 from __future__ import annotations
@@ -84,11 +102,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from omnia_tpu.models import kinds
 from omnia_tpu.models.config import ModelConfig
 from omnia_tpu.ops import hyper_connections as hc
 from omnia_tpu.ops.attention import _kernel_on, _pallas_decode_mode
 from omnia_tpu.ops.decode_mla_attention import block_rows, decode_mla_attention
-from omnia_tpu.ops.moe import EXPERT_COUNTERS
+from omnia_tpu.ops.kda import decode_kda_state, kda_chunked
+from omnia_tpu.ops.moe import EXPERT_COUNTERS, init_ffn
 from omnia_tpu.ops.moe import expert_ffn as _experts
 from omnia_tpu.ops.moe import unstack_experts as _unstack_experts
 from omnia_tpu.ops.norms import rms_norm
@@ -105,6 +125,28 @@ _NEG_INF = -1e30
 #: Counters a decode step sums on the device over its layers, in the
 #: order ``forward(..., counters=True)`` returns them (engine.metrics keys).
 DECODE_COUNTERS = EXPERT_COUNTERS
+
+#: Every kind a layer of this family can be (models/kinds.py), in its stacks'
+#: order, and this family's name for a full-attention layer: the latent one.
+_KINDS = ("dense_kda", "dense_mla", "sparse_kda", "sparse_mla")
+_NAMES = {"full": "mla"}
+
+
+def has_kinds(cfg: ModelConfig) -> bool:
+    """Whether the model states an attention kind a layer (``layer_types``, or the
+    stacks of one cut out of such a model): ``params["layers"]`` is a stack a kind."""
+    return cfg.layer_types is not None or cfg.layer_stacks is not None
+
+
+def stack_kinds(cfg: ModelConfig) -> tuple:
+    """The kind of each stack of a model that ``has_kinds``."""
+    return kinds.stack_kinds(cfg, _KINDS, _NAMES)
+
+
+def decode_counters(cfg: ModelConfig) -> tuple:
+    """``DECODE_COUNTERS`` and, for a model with linear-attention layers, the states a
+    decode step updates (live slots, a layer), as ``forward`` returns them."""
+    return EXPERT_COUNTERS + (("decode_kda_slots",) if cfg.has_state_layers else ())
 
 
 #: Cache rows in one block of this family's decode kernel, by cache length:
@@ -126,7 +168,10 @@ def layer_order(cfg: ModelConfig) -> tuple:
     """((stack, index), ...) for model layer 0, 1, ... of a model whose
     ``params["layers"]`` is two stacks: the leading dense layers are stack 0,
     the sparse ones behind them stack 1 (benchmark/README.md, "`layers`: one
-    tree, or stacks")."""
+    tree, or stacks"). A model that ``has_kinds``: a layer lies in the stack of
+    its kind, in whatever order the model states (models/kinds.py)."""
+    if has_kinds(cfg):
+        return kinds.layer_order(cfg, _KINDS, _NAMES)
     dense = cfg.num_dense_layers
     return (tuple((0, i) for i in range(dense))
             + tuple((1, i) for i in range(cfg.num_layers - dense)))
@@ -134,7 +179,9 @@ def layer_order(cfg: ModelConfig) -> tuple:
 
 def with_layer_order(cfg: ModelConfig, order) -> ModelConfig:
     """The same model with the layers ``order`` names: its own order over
-    the cut stacks, either of which may be left with none."""
+    the cut stacks, any of which may be left with none."""
+    if has_kinds(cfg):
+        return kinds.with_layer_order(cfg, order, _KINDS, _NAMES)
     dense = sum(stack == 0 for stack, _ in order)
     cut = dataclasses.replace(cfg, num_layers=len(order), num_dense_layers=dense)
     if tuple(map(tuple, order)) != layer_order(cut):
@@ -160,7 +207,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
     ~ N(0, 0.5), B_res = 1.5·I + N(0, 0.3): H_res leans to the identity
     (diagonal about 0.6 of 4 copies) without being it, and 20 Sinkhorn
     iterations bring its columns within 1e-4 of 1 (a sharper diagonal does
-    not converge that far)."""
+    not converge that far). A model that ``has_kinds``: ``_init_kinds``."""
+    if has_kinds(cfg):
+        return _init_kinds(cfg, key, dtype)
     L, D, V, H = cfg.num_layers, cfg.hidden_size, cfg.vocab_size, cfg.num_heads
     R, Rq = cfg.kv_rank, cfg.q_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -226,6 +275,65 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16):
     return {"embed": embed, "layers": stack, "final_norm": ones(D), "lm_head": lm_head}
 
 
+def _init_kinds(cfg: ModelConfig, key: jax.Array, dtype):
+    """``init_params`` of a model that ``has_kinds``: ``layers`` is a list, a stack
+    for each of ``stack_kinds(cfg)`` with its layers on axis 0 (a cut model's may
+    have none). A linear-attention layer: ``wqkv`` (q | k | v before the
+    convolution), ``conv`` [taps, 3·H·dk], the decay's ``wfa``, ``wfb``, ``dt_bias``, ``a_log``
+    (float32), ``wb``, the output gate's ``wga``, ``wgb``, the head norm's ``on``, ``wo``.
+    ``a_log`` = log U(1, 16) a head, ``dt_bias`` the inverse softplus of a step
+    log-uniform in 1e-3 … 0.1 a channel: a token's decay then lies in about 0.2 …
+    0.999 as a trained model's does (near 0 it would empty the state every token)."""
+    if cfg.residual_copies != 1:
+        raise ValueError("a model with layer_types in the latent family has one residual copy")
+    D, V, L, H = cfg.hidden_size, cfg.vocab_size, cfg.num_layers, cfg.num_heads
+    R, Rq, dn, dr, dv = (cfg.kv_rank, cfg.q_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+    Hk, dk, r, taps = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_gate_rank, cfg.kda_conv_kernel
+    out_std = 0.02 / (2 * max(L, 1)) ** 0.5
+
+    def stack_of(kind, c, key):
+        keys = iter(jax.random.split(key, 32))
+
+        def normal(shape, std=0.02, dtype=dtype):
+            return (jax.random.normal(next(keys), shape, dtype=jnp.float32) * std).astype(dtype)
+
+        def log_uniform(shape, lo, hi):
+            return jnp.exp(jax.random.uniform(next(keys), shape, jnp.float32,
+                                              jnp.log(lo), jnp.log(hi)))
+
+        if kind.endswith("kda"):
+            step = log_uniform((c, Hk * dk), 1e-3, 0.1)
+            attn = {"wqkv": normal((c, D, 3 * Hk * dk)),
+                    "conv": normal((c, taps, 3 * Hk * dk), std=taps ** -0.5),
+                    "wfa": normal((c, D, r)), "wfb": normal((c, r, Hk * dk)),
+                    "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                    "a_log": jnp.log(log_uniform((c, Hk), 1.0, 16.0)),
+                    "wb": normal((c, D, Hk)),
+                    "wga": normal((c, D, r)), "wgb": normal((c, r, Hk * dk)),
+                    "on": jnp.ones((c, dk), dtype),
+                    "wo": normal((c, Hk * dk, D), std=out_std)}
+        else:
+            query = ({"wqa": normal((c, D, Rq)), "qn": jnp.ones((c, Rq), dtype),
+                      "wqb": normal((c, Rq, H * (dn + dr)))} if Rq
+                     else {"wq": normal((c, D, H * (dn + dr)))})
+            attn = {**query, "wkva": normal((c, D, R + dr)), "kvn": jnp.ones((c, R), dtype),
+                    "wkvb": normal((c, R, H * (dn + dv))),
+                    "wo": normal((c, H * dv, D), std=out_std)}
+        mlp = init_ffn(cfg, c, kind.startswith("sparse"), normal, out_std, lambda: next(keys))
+        return {"ln1": jnp.ones((c, D), dtype), "ln2": jnp.ones((c, D), dtype),
+                "attn": attn, "mlp": mlp}
+
+    k_embed, k_head, k_layers = jax.random.split(key, 3)
+    return {
+        "embed": (jax.random.normal(k_embed, (V, D), jnp.float32) * 0.02).astype(dtype),
+        "layers": [stack_of(kind, c, jax.random.fold_in(k_layers, _KINDS.index(kind)))
+                   for kind, c in zip(stack_kinds(cfg), kinds.stack_counts(cfg, _KINDS, _NAMES))],
+        "final_norm": jnp.ones((D,), dtype),
+        "lm_head": (jax.random.normal(k_head, (D, V), jnp.float32) * 0.02).astype(dtype),
+    }
+
+
 def param_specs(cfg: ModelConfig):
     """Everything replicated: this family runs on one chip a replica (the
     engine refuses tp/dp/sp > 1 for it)."""
@@ -236,13 +344,23 @@ def param_specs(cfg: ModelConfig):
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloat16,
                   kv_quant=None):
-    """The zeroed latent cache, a tuple of one array [L, B, S, W]."""
+    """The zeroed latent cache, a tuple of one array [L, B, S, W]. A model that
+    ``has_kinds``: the latent layers' rows [Lm, B, S, W], the linear-attention layers'
+    states [Lk, B, H, dk, dv] (float32 whatever ``dtype``) and their tails [Lk, B, taps -
+    1, 3·H·dk]; a layer's index is its count among the layers of its attention kind."""
     if kv_quant:
         raise NotImplementedError("kv_quant is not ported to the latent cache")
-    return (jnp.zeros((cfg.num_layers, batch, seq, row_width(cfg)), dtype=dtype),)
+    if not has_kinds(cfg):
+        return (jnp.zeros((cfg.num_layers, batch, seq, row_width(cfg)), dtype=dtype),)
+    Lm, Lk = (cfg.attention_kinds.count(kind) for kind in ("full", "kda"))
+    H, d = cfg.kda_num_heads, cfg.kda_head_dim
+    return (jnp.zeros((Lm, batch, seq, row_width(cfg)), dtype=dtype),
+            jnp.zeros((Lk, batch, H, d, d), dtype=jnp.float32),
+            jnp.zeros((Lk, batch, cfg.kda_conv_kernel - 1, 3 * H * d), dtype=dtype))
 
 
 def kv_cache_specs(kv_quant=None) -> tuple:
+    """(One chip a replica, no mesh: nobody lays these over a cache of three arrays.)"""
     return (P(),)
 
 
@@ -267,15 +385,23 @@ def _queries_and_row(h, p, cfg: ModelConfig, cos, sin, q_scale):
     B, T, _ = h.shape
     dn, dr, R = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_rank
     with jax.named_scope("attn.q_lora"):
-        cq = rms_norm(jnp.dot(h, p["wqa"]), p["qn"], cfg.rms_norm_eps)
-        q = jnp.dot(cq, p["wqb"]).reshape(B, T, cfg.num_heads, dn + dr)
+        if cfg.q_rank:
+            cq = rms_norm(jnp.dot(h, p["wqa"]), p["qn"], cfg.rms_norm_eps)
+            q = jnp.dot(cq, p["wqb"]).reshape(B, T, cfg.num_heads, dn + dr)
+        else:  # no query rank: straight to the heads
+            q = jnp.dot(h, p["wq"]).reshape(B, T, cfg.num_heads, dn + dr)
         if q_scale is not None:
             q = (q * q_scale[:, :, None, None]).astype(q.dtype)
-        q_nope, q_rope = q[..., :dn], _rope(cfg, q[..., dn:], cos, sin)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+        if cos is not None:  # else no rotary position: q_rope as projected
+            q_rope = _rope(cfg, q_rope, cos, sin)
     with jax.named_scope("attn.kv_latent"):
         kva = jnp.dot(h, p["wkva"])
         c = rms_norm(kva[..., :R], p["kvn"], cfg.rms_norm_eps)
-        k_rope = _rope(cfg, kva[..., None, R:], cos, sin)[..., 0, :]
+        if cos is not None:
+            k_rope = _rope(cfg, kva[..., None, R:], cos, sin)[..., 0, :]
+        else:  # no rotary position: k_rope as projected
+            k_rope = kva[..., R:]
         pad = jnp.zeros((B, T, row_width(cfg) - R - dr), c.dtype)
         row = jnp.concatenate([c, k_rope, pad], axis=-1)
     return q_nope, q_rope, row
@@ -344,9 +470,10 @@ def _absorbed_attention(q_nope, q_rope, cache, wkvb, cfg: ModelConfig, q_positio
 
 
 def _layer(x, p, experts, at, cfg: ModelConfig, cos, sin, q_scale, q_positions,
-           cache, write_start, live=None, first=0):
+           cache, write_start, live=None, first=0, cache_layer=None):
     """One block, ``at`` its index in its stack and ``first + at`` in the
-    model and the cache (one tree: the same). With a cache, ``cache`` is
+    model and the cache (one tree: the same; a model that ``has_kinds``
+    names the cache's layer itself, ``cache_layer``). With a cache, ``cache`` is
     the WHOLE [L, B, S, W]: the new rows are written in place and attention
     reads that layer where it lies. Without one (fresh prefill) attention
     runs over the chunk's own rows, which are returned. ``experts`` are the
@@ -357,6 +484,8 @@ def _layer(x, p, experts, at, cfg: ModelConfig, cos, sin, q_scale, q_positions,
     B, T, _ = x.shape
     n = cfg.residual_copies
     layer = first + at if first else at
+    if cache_layer is not None:
+        layer = cache_layer
     u, mixes = hc.pre(x, p["hc"]["attn"], n, **_hc_constants(cfg)) if n > 1 else (x, None)
     with jax.named_scope("attn.qkv"):  # attn.q_lora and attn.kv_latent inside
         h = rms_norm(u, p["ln1"], cfg.rms_norm_eps)
@@ -394,7 +523,7 @@ def _embed(params, cfg: ModelConfig, tokens, q_positions):
         x = params["embed"][tokens]
         if cfg.residual_copies > 1:
             x = hc.expand(x, cfg.residual_copies, cfg.hidden_size)
-        cos, sin = _rotary(cfg, q_positions)
+        cos, sin = _rotary(cfg, q_positions) if cfg.rope_on_full_layers else (None, None)
         q_scale = None
         if cfg.q_scaling_beta:
             # 1 below the original context (rope_yarn's), growing with
@@ -444,6 +573,160 @@ def _scans(params, cfg: ModelConfig):
     return scans
 
 
+#: The ε inside the square root of a KDA head's key and query norms.
+_L2_EPS = 1e-6
+
+
+def _fresh(write_start):
+    """bool [B]: the slots whose chunk starts at position 0, a new tenant's first."""
+    return write_start == 0
+
+
+def _kda_layer(x, p, experts, at, cfg: ModelConfig, cache, cache_layer, write_start,
+               n_real, live):
+    """One block whose attention is the gated delta rule (ops/kda.py), ``at`` its
+    index in its stack (its experts' too), ``cache_layer`` its index among the
+    linear-attention layers. ``cache``: (states [Lk, B, H, dk, dv] float32, tails [Lk,
+    B, taps - 1, 3·H·dk]) whole, or None for a fresh chunk, which starts from zero and
+    gets its (state, tail) back instead. A state has no position to mask by
+    afterwards, so which rows and steps may touch it is said here alone:
+    - of a chunk's T rows the first ``n_real`` [B] count; the pad behind them gets
+      β = 0 and g = 0, which leaves S as it is, and the tail kept is the last REAL row's;
+    - a chunk (T > 1) at position 0 is a new tenant's first: it starts from S = 0
+      and a zero tail whatever the slot holds;
+    - a decode step (T == 1) leaves a slot that is not ``live`` as it is.
+
+    → (x, cache or (state, tail), counts int32 [3]: EXPERT_COUNTERS, states updated)."""
+    B, T, _ = x.shape
+    H, d, taps = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_conv_kernel
+    f32, a = jnp.float32, p["attn"]
+    step = T == 1 and cache is not None
+    with jax.named_scope("attn.kda"):  # kda.conv/gates/chunk/state/out inside
+        h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
+        pre = jnp.dot(h, a["wqkv"])                                # q | k | v  [B, T, 3·H·d]
+        with jax.named_scope("kda.conv"):
+            if cache is None:
+                tail = jnp.zeros((B, taps - 1, pre.shape[-1]), pre.dtype)
+            else:
+                states, tails = cache
+                tail = jax.lax.dynamic_index_in_dim(tails, cache_layer, 0, keepdims=False)
+                if not step:
+                    fresh = _fresh(write_start)
+                    tail = jnp.where(fresh[:, None, None], 0, tail)
+            rows = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
+            u = sum(a["conv"][j].astype(f32) * rows[:, j:j + T].astype(f32)
+                    for j in range(taps))
+            q, k, v = (t.reshape(B, T, H, d) for t in jnp.split(jax.nn.silu(u), 3, axis=-1))
+            q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + _L2_EPS) * d ** -0.5
+            k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + _L2_EPS)
+            # What the next chunk's first taps see: the rows before the last
+            # real one, never the pad's.
+            kept = jax.vmap(lambda r, n: jax.lax.dynamic_slice_in_dim(r, n, taps - 1, 0))(
+                rows, n_real)
+            if step and live is not None:
+                kept = jnp.where(live[:, None, None], kept, tail.astype(kept.dtype))
+        with jax.named_scope("kda.gates"):
+            decay = jnp.dot(jnp.dot(h, a["wfa"]), a["wfb"], preferred_element_type=f32)
+            g = -jnp.exp(a["a_log"].astype(f32))[:, None] * jax.nn.softplus(
+                (decay + a["dt_bias"].astype(f32)).reshape(B, T, H, d))
+            beta = jax.nn.sigmoid(jnp.dot(h, a["wb"], preferred_element_type=f32))
+            gate = jax.nn.sigmoid(jnp.dot(jnp.dot(h, a["wga"]), a["wgb"],
+                                          preferred_element_type=f32)).reshape(B, T, H, d)
+            real = jnp.arange(T, dtype=jnp.int32)[None, :] < n_real[:, None]
+            g = jnp.where(real[:, :, None, None], g, 0.0)
+            beta = jnp.where(real[:, :, None], beta, 0.0)
+        if step:
+            with jax.named_scope("kda.state"):
+                o, states = decode_kda_state(
+                    states, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], cache_layer, live,
+                    kernel=_kernel_on(), interpret=_pallas_decode_mode() == "interpret")
+                o = o[:, None]
+            updated = (jnp.sum(live, dtype=jnp.int32) if live is not None
+                       else jnp.int32(B))
+        else:
+            with jax.named_scope("kda.chunk"):
+                S = jnp.zeros((B, H, d, d), f32)
+                if cache is not None:
+                    S = jax.lax.dynamic_index_in_dim(states, cache_layer, 0, keepdims=False)
+                    S = jnp.where(fresh[:, None, None, None], 0.0, S)
+                o, S = kda_chunked(q, k, v, g, beta, S)
+                if cache is not None:
+                    states = jax.lax.dynamic_update_slice_in_dim(states, S[None], cache_layer, 0)
+            updated = jnp.int32(0)
+        if cache is not None:
+            with jax.named_scope("kda.conv"):
+                tails = jax.lax.dynamic_update_slice_in_dim(
+                    tails, kept.astype(tails.dtype)[None], cache_layer, 0)
+        with jax.named_scope("kda.out"):
+            y = rms_norm(o, a["on"], cfg.rms_norm_eps) * gate
+            x = x + jnp.dot(y.astype(x.dtype).reshape(B, T, H * d), a["wo"])
+    with jax.named_scope("mlp"):  # moe.route/sort/experts/combine/shared inside
+        y, counts = _experts(rms_norm(x, p["ln2"], cfg.rms_norm_eps), p["mlp"], experts, at, cfg)
+    counts = jnp.concatenate([counts, updated[None]])
+    return x + y, ((states, tails) if cache is not None else (S, kept)), counts
+
+
+def _run_kinds(params, cfg: ModelConfig, x, cos, sin, q_scale, q_positions, cache,
+               write_start, row, live):
+    """Every layer of a model that ``has_kinds``, a scan a run of consecutive layers
+    of one kind (models/kinds.py) under ``stack.<kind>``: the stack's leaves are read
+    a layer at a time where they lie, the routed experts' never sliced. With a
+    cache (rows, states, tails) it is the carry and comes back; without one the
+    chunk's own come back, an array for each. → (x, cache or chunks, counts)."""
+    B, T, _ = x.shape
+    n_real = jnp.broadcast_to(T if row is None else row + 1, (B,)).astype(jnp.int32)
+    n_counts = len(decode_counters(cfg))
+    counts = jnp.zeros((n_counts,), jnp.int32)
+    chunks = {"mla": [], "kda": []}
+    for stack, kind, first, length, cache_first in kinds.runs(cfg, _KINDS, _NAMES):
+        layers = params["layers"][stack]
+        scanned, experts = (_unstack_experts(layers) if kind.startswith("sparse")
+                            else (layers, None))
+        latent = kind.endswith("mla")
+
+        def body(carry, i, scanned=scanned, experts=experts, latent=latent, first=first,
+                 cache_first=cache_first):
+            x, cache, counts = carry
+            p = jax.tree_util.tree_map(
+                lambda leaf: jax.lax.dynamic_index_in_dim(leaf, i, 0, keepdims=False), scanned)
+            at = cache_first + i - first
+            if latent:
+                x, kept, c = _layer(x, p, experts, i, cfg, cos, sin, q_scale, q_positions,
+                                    None if cache is None else cache[0], write_start,
+                                    live=live, cache_layer=at)
+                c = jnp.pad(c, (0, n_counts - c.shape[0]))
+                if cache is not None:
+                    kept = (kept, *cache[1:])
+            else:
+                x, kept, c = _kda_layer(x, p, experts, i, cfg,
+                                        None if cache is None else cache[1:], at,
+                                        write_start, n_real, live)
+                if cache is not None:
+                    kept = (cache[0], *kept)
+            return ((x, kept, counts + c), None) if cache is not None else (
+                (x, None, counts + c), kept)
+
+        with jax.named_scope(f"stack.{kind}"):
+            (x, cache, counts), kept = jax.lax.scan(
+                body, (x, cache, counts), first + jnp.arange(length, dtype=jnp.int32))
+        if kept is not None:
+            chunks[kind.split("_")[1]].append(kept)
+    if cache is not None:
+        return x, cache, counts
+    def whole(kind):  # the runs' chunks, in the order of the kind's cache arrays
+        found = [each if isinstance(each, tuple) else (each,) for each in chunks[kind]]
+        if found:
+            return tuple(each[0] if len(each) == 1 else jnp.concatenate(each, axis=0)
+                         for each in zip(*found))
+        if kind == "mla":  # a cut model without a layer of the kind: arrays of no layers
+            return (jnp.zeros((0, B, T, row_width(cfg)), x.dtype),)
+        H, d = cfg.kda_num_heads, cfg.kda_head_dim
+        return (jnp.zeros((0, B, H, d, d), jnp.float32),
+                jnp.zeros((0, B, cfg.kda_conv_kernel - 1, 3 * H * d), x.dtype))
+
+    return x, (*whole("mla"), *whole("kda")), counts
+
+
 # ---------------------------------------------------------------------------
 # Forward passes
 # ---------------------------------------------------------------------------
@@ -455,8 +738,14 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, row=None):
 
     tokens, q_positions: int32 [B, T]. Returns (logits [B, T, V] f32,
     chunk [L, B, T, W]); with ``row`` (int32 scalar) the logits are that
-    row's alone, [B, V]: the head runs over one row (``_logits_at``)."""
+    row's alone, [B, V]: the head runs over one row (``_logits_at``). A model that
+    ``has_kinds`` returns what ``init_kv_cache`` makes, a slot's worth: (logits, rows,
+    states, tails); rows past ``row`` are pad and touch neither state nor tail."""
     x, cos, sin, q_scale = _embed(params, cfg, tokens, q_positions)
+    if has_kinds(cfg):
+        x, chunks, _ = _run_kinds(params, cfg, x, cos, sin, q_scale, q_positions, None,
+                                  None, row, None)
+        return (_logits_at(params, cfg, x, row), *chunks)
     chunks = []
     for scope, layers, experts, first in _scans(params, cfg):
 
@@ -473,20 +762,27 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, row=None):
     return _logits_at(params, cfg, x, row), chunk
 
 
-def forward(params, cfg: ModelConfig, tokens, q_positions, cache, write_start,
-            mesh=None, live=None, counters=False, row=None):
+def forward(params, cfg: ModelConfig, tokens, q_positions, *cache, mesh=None, live=None,
+            counters=False, row=None):
     """Serving forward (prefill or decode: same code, different T).
 
-    tokens, q_positions: int32 [B, T]; cache: [L, B, S, W]; write_start:
-    int32 [B], the row where this chunk's rows land. ``live``: bool [B]
-    or None, the slots whose logits the caller will use; the decode
-    kernel skips the others. ``row``: int32 scalar or None, the one row of
-    the T whose logits the caller will use; the logits are then [B, V].
-    Returns (logits [B, T, V] f32, cache), and with ``counters`` a third:
-    int32 [len(DECODE_COUNTERS)], summed over the layers that have a router.
-    """
+    tokens, q_positions: int32 [B, T]; ``*cache``: the cache's arrays (``init_kv_cache``)
+    and behind them write_start, int32 [B], the row where this chunk's rows land.
+    ``live``: bool [B] or None, the slots whose logits the caller will use; the decode
+    kernels skip the others, and a linear-attention layer leaves their state and
+    tail as they are. ``row``: int32 scalar or None, the one row of the T whose logits
+    the caller will use: the logits are then [B, V], the rows behind it pad.
+    Returns (logits [B, T, V] f32, *cache), and with ``counters`` one more: int32
+    [len(decode_counters(cfg))], summed over the layers."""
     del mesh  # one chip a replica: nothing here is sharded
+    *cache, write_start = cache
     x, cos, sin, q_scale = _embed(params, cfg, tokens, q_positions)
+    if has_kinds(cfg):
+        x, cache, counts = _run_kinds(params, cfg, x, cos, sin, q_scale, q_positions,
+                                      tuple(cache), write_start, row, live)
+        logits = _logits_at(params, cfg, x, row)
+        return (logits, *cache, counts) if counters else (logits, *cache)
+    cache, = cache
     scans = _scans(params, cfg)
     counts = jnp.zeros((len(DECODE_COUNTERS),), jnp.int32)
     for scope, layers, experts, first in scans:

@@ -10,7 +10,8 @@ or softmax router over all experts, the held share through ``moe_dropless``,
 the shared expert, as models/mla.py runs it). ``params["layers"]`` is a
 sequence of stacks, one for each kind the model has (``stack_kinds``), and
 ``layer_order`` / ``with_layer_order`` state and cut the order as
-benchmark/README.md sets out. A forward pass is one ``lax.scan`` for each run
+benchmark/README.md sets out (models/kinds.py, which the latent family's
+kinds go through too). A forward pass is one ``lax.scan`` for each run
 of consecutive layers of one kind, under the scope ``stack.<kind>``, over the
 run's indices into its stack: the stack's leaves are read a layer at a time
 where they lie, the routed experts' never sliced at all. With ``cfg.qk_norm``
@@ -53,15 +54,14 @@ pool, spec_decode, the mixed step, int8 weights, sp, tp/dp > 1.
 
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 
+from omnia_tpu.models import kinds
 from omnia_tpu.models.config import ModelConfig
 from omnia_tpu.ops import attention as _attention
 from omnia_tpu.ops.attention import decode_block_rows, gqa_attention
-from omnia_tpu.ops.moe import EXPERT_COUNTERS, expert_ffn, unstack_experts
+from omnia_tpu.ops.moe import EXPERT_COUNTERS, expert_ffn, init_ffn, unstack_experts
 from omnia_tpu.ops.norms import rms_norm
 from omnia_tpu.ops.rope import apply_rope
 
@@ -103,68 +103,26 @@ def decode_window_rows(cfg: ModelConfig, lengths) -> int:
     return sum(min(n // rows + 1, ring // rows) * rows for n in lengths)
 
 
-def _layer_kinds(cfg: ModelConfig) -> tuple:
-    sparse_from = cfg.num_dense_layers if cfg.moe_ffn_hidden_size else cfg.num_layers
-    return tuple(f"{'dense' if l < sparse_from else 'sparse'}_{kind}"
-                 for l, kind in enumerate(cfg.attention_kinds))
-
-
 def stack_kinds(cfg: ModelConfig) -> tuple:
     """The kind of each stack of ``params["layers"]``: those of ``_KINDS``
-    that the model has a layer of (a cut model: the stacks of the model it
-    was cut out of, ``cfg.layer_stacks``)."""
-    if cfg.layer_stacks is not None:
-        return cfg.layer_stacks
-    have = set(_layer_kinds(cfg))
-    return tuple(kind for kind in _KINDS if kind in have)
+    that the model has a layer of (models/kinds.py)."""
+    return kinds.stack_kinds(cfg, _KINDS)
 
 
 def layer_order(cfg: ModelConfig) -> tuple:
     """((stack, index), ...) for model layer 0, 1, ... (benchmark/README.md,
-    "`layers`: one tree, or stacks"): a layer lies in the stack of its kind,
-    behind the earlier layers of that kind."""
-    stacks = stack_kinds(cfg)
-    seen = [0] * len(stacks)
-    order = []
-    for kind in _layer_kinds(cfg):
-        stack = stacks.index(kind)
-        order.append((stack, seen[stack]))
-        seen[stack] += 1
-    return tuple(order)
+    "`layers`: one tree, or stacks")."""
+    return kinds.layer_order(cfg, _KINDS)
 
 
 def with_layer_order(cfg: ModelConfig, order) -> ModelConfig:
     """The same model with the layers ``order`` names: its own order over
     the cut stacks, any of which may be left with none."""
-    stacks = stack_kinds(cfg)
-    kinds = [stacks[stack] for stack, _ in order]
-    dense = sum(kind.startswith("dense") for kind in kinds) if cfg.moe_ffn_hidden_size else 0
-    cut = dataclasses.replace(
-        cfg, num_layers=len(order), num_dense_layers=dense, layer_stacks=stacks,
-        layer_types=tuple("sliding_attention" if kind.endswith("window") else "full_attention"
-                          for kind in kinds))
-    if tuple(map(tuple, order)) != layer_order(cut):
-        raise ValueError(f"{order}: this family runs its dense layers first, and a "
-                         f"stack's layers in the order of its axis")
-    return cut
+    return kinds.with_layer_order(cfg, order, _KINDS)
 
 
 def _runs(cfg: ModelConfig) -> list:
-    """The scans of a forward pass: (stack, kind, the run's first index in
-    its stack, its length, its first layer among the layers of its
-    attention kind: the index into that kind's cache arrays) for each run
-    of consecutive layers of one kind."""
-    stacks = stack_kinds(cfg)
-    runs, cached = [], {"window": 0, "full": 0}
-    for stack, index in layer_order(cfg):
-        kind = stacks[stack]
-        attention = kind.split("_")[1]
-        if runs and runs[-1][0] == stack:
-            runs[-1][3] += 1
-        else:
-            runs.append([stack, kind, index, 1, cached[attention]])
-        cached[attention] += 1
-    return [tuple(run) for run in runs]
+    return kinds.runs(cfg, _KINDS)
 
 
 def _init_stacks(cfg: ModelConfig, key: jax.Array, dtype):
@@ -176,11 +134,8 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array, dtype):
     large enough to change which experts are kept, and neither a rank's
     load nor the count of experts a step hits depends on the seed."""
     D, V, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
-    F, Eh = cfg.moe_ffn_hidden_size, cfg.experts_held
     out_std = 0.02 / (2 * max(L, 1)) ** 0.5
-    counts = [0] * len(stack_kinds(cfg))
-    for stack, _ in layer_order(cfg):
-        counts[stack] += 1
+    counts = kinds.stack_counts(cfg, _KINDS)
 
     def stack_of(kind, c, key):
         keys = iter(jax.random.split(key, 16))
@@ -188,34 +143,12 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array, dtype):
         def normal(shape, std=0.02, dtype=dtype):
             return (jax.random.normal(next(keys), shape, dtype=jnp.float32) * std).astype(dtype)
 
-        def swiglu(lead, width):
-            return {"wg": normal((*lead, D, width)), "wu": normal((*lead, D, width)),
-                    "wd": normal((*lead, width, D), std=out_std)}
-
         attn = {"wq": normal((c, D, cfg.q_dim)), "wk": normal((c, D, cfg.kv_dim)),
                 "wv": normal((c, D, cfg.kv_dim)), "wo": normal((c, cfg.q_dim, D), std=out_std)}
         if cfg.qk_norm:
             attn["qn"] = jnp.ones((c, cfg.head_dim), dtype)
             attn["kn"] = jnp.ones((c, cfg.head_dim), dtype)
-        if kind.startswith("dense"):
-            mlp = swiglu((c,), cfg.ffn_hidden_size)
-        else:
-            mlp = {"router": normal((c, D, cfg.num_experts)), **swiglu((c, Eh), F)}
-            if cfg.num_shared_experts:
-                mlp["shared"] = swiglu((c,), cfg.num_shared_experts * F)
-            if cfg.router_bias:
-                # One set of values whatever the seed, layer or rank (the
-                # midpoints of N(0, 0.05)'s equal shares, a rank's count of
-                # them), in an order the seed draws. Values drawn an expert
-                # favour one rank's experts over another's, and their shape
-                # says how many experts a step's tokens hit (the favoured
-                # are hit by every step, the rest seldom): this chip's load
-                # and a decode step's cost would follow the seed.
-                values = 0.05 * jax.scipy.special.ndtri((jnp.arange(Eh) + 0.5) / Eh)
-                order = jax.vmap(lambda k: jax.random.permutation(k, Eh))(
-                    jax.random.split(next(keys), c))
-                mlp["bias"] = jnp.tile(values.astype(jnp.float32)[order],
-                                       (1, cfg.num_experts // Eh))
+        mlp = init_ffn(cfg, c, kind.startswith("sparse"), normal, out_std, lambda: next(keys))
         return {"ln1": jnp.ones((c, D), dtype), "ln2": jnp.ones((c, D), dtype),
                 "attn": attn, "mlp": mlp}
 

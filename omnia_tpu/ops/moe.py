@@ -254,6 +254,42 @@ EXPERT_COUNTERS = ("moe_assignments_held", "moe_experts_hit")
 _EXPERT_STACKS = ("wg", "wu", "wd")
 
 
+def selection_bias(key, layers: int, held: int, experts: int):
+    """A seeded selection bias [layers, experts] float32 for a router that
+    picks by ``s + b``: one set of values whatever the seed, layer or rank
+    (the midpoints of N(0, 0.05)'s ``held`` equal shares), in an order the
+    seed draws for each layer, the ranks alike. Values drawn an expert favour
+    one rank's experts over another's, and their shape says how many experts
+    a step's tokens hit (the favoured are hit by every step, the rest
+    seldom): a chip's load and a decode step's cost would follow the seed."""
+    values = 0.05 * jax.scipy.special.ndtri((jnp.arange(held) + 0.5) / held)
+    order = jax.vmap(lambda k: jax.random.permutation(k, held))(jax.random.split(key, layers))
+    return jnp.tile(values.astype(jnp.float32)[order], (1, experts // held))
+
+
+def init_ffn(cfg, layers: int, sparse: bool, normal, out_std: float, next_key):
+    """The FFN's parameters of a stack of ``layers`` layers of one kind, for
+    either family's ``init_params``: a dense SwiGLU of ``ffn_hidden_size``, or
+    the router over all experts, the held share's three stacks, the shared
+    expert and the selection bias. ``normal(shape, std=)`` draws a leaf in
+    the caller's type from the caller's keys, ``next_key()`` the bias's."""
+    D, F = cfg.hidden_size, cfg.moe_ffn_hidden_size
+
+    def swiglu_of(lead, width):
+        return {"wg": normal((*lead, D, width)), "wu": normal((*lead, D, width)),
+                "wd": normal((*lead, width, D), std=out_std)}
+
+    if not sparse:
+        return swiglu_of((layers,), cfg.ffn_hidden_size)
+    mlp = {"router": normal((layers, D, cfg.num_experts)),
+           **swiglu_of((layers, cfg.experts_held), F)}
+    if cfg.num_shared_experts:
+        mlp["shared"] = swiglu_of((layers,), cfg.num_shared_experts * F)
+    if cfg.router_bias:
+        mlp["bias"] = selection_bias(next_key(), layers, cfg.experts_held, cfg.num_experts)
+    return mlp
+
+
 def unstack_experts(layers):
     """(what the layer scan slices a layer at a time, the routed experts'
     three stacks [L, Eh, …] whole): the experts are nine tenths of a

@@ -25,6 +25,23 @@ globals().update({name: value for name, value in vars(cases).items()
                   if name.startswith("test_") or name in ("cell", "traced")})
 
 
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("metric", cases.NEW_READERS)
+def test_a_new_readers_declarations_equal_its_entry(metric):  # noqa: F811
+    """The case file's own case, but for the list: it holds a reader's
+    `workloads` to this one cell, and a later cell may join behind it (PR 43's
+    joined the two extend readers' lists). The benchmark's file is left as it
+    is (PERF.md section 7 says which edit a `benchmark` PR owes it)."""
+    entry = next(m for m in cases.mf.benchmark_json()["per_layer"] if m["name"] == metric)
+    mod = cases.load_layer_metric(metric)
+    assert (mod.LAYER, mod.UNIT, mod.BETTER, mod.SOURCE, mod.MOVES) == (
+        entry["layer"], entry["unit"], entry["better"], entry["source"], entry["moves"])
+    assert entry["workloads"][0] == cases.CELL and mod.MOVES == "out_tokens_per_s_chip"
+    assert len(entry["workloads"]) == 1 or metric.startswith("step.extend_")
+
+
 def test_tier_1_runs_the_seven_new_readers_cases():
     assert len(cases.NEW_READERS) == 7
     assert test_the_new_readers_read_the_cell is cases.test_the_new_readers_read_the_cell  # noqa: F821

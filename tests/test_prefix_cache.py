@@ -467,7 +467,7 @@ class TestMetricsKeyStability:
         "decode_dispatches_blocked", "decode_slot_steps", "decode_kv_blocks",
         "decode_window_rows",
         "decode_steps_sampling", "decode_steps_filtering",
-        "moe_assignments_held", "moe_experts_hit",
+        "moe_assignments_held", "moe_experts_hit", "decode_kda_slots",
         "pipeline_flushes", "placements_deferred",
         "programs_compiled_serving",
         "prefix_reuse_tokens", "session_offloads", "session_restores",

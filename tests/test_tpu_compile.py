@@ -513,6 +513,87 @@ def test_sparse_cells_decode_step_keeps_ragged_dot(one_chip, kernel_route_on, ce
     assert any(f"[{rows}," in call for call in ragged), ragged
 
 
+# The latent family with linear-attention layers (models/mla.py, ops/kda.py): a
+# float32 state a slot a layer in the cache, updated in place by a Pallas
+# kernel in the decode step, at `kimi-linear-48b-a3b.longdoc-wide`'s sizes.
+
+
+def test_kda_state_kernel_compiles_in_place(one_chip):
+    """`decode_kda_state` at the published widths (32 heads of 128 x 128
+    float32, 64 slots, six layers): Mosaic takes the 16-head blocks and the
+    in-kernel transposes, the whole state goes in and comes out aliased, and
+    nothing state-sized is copied around the call."""
+    from omnia_tpu.ops import kda
+
+    L, Bk, H, d = 6, 64, 32, 128
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(state, q, k, v, g, beta, layer, live):
+        return kda.decode_kda_state(state, q, k, v, g, beta, layer, live, kernel=True)
+
+    vec = arg(jnp.float32, Bk, H, d)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        arg(jnp.float32, L, Bk, H, d, d), vec, vec, vec, vec, arg(jnp.float32, Bk, H),
+        arg(jnp.int32), arg(jnp.bool_, Bk)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%decode_kda_state[.\d]* = \(.*\) custom-call\(", text)) == 1
+    memory = compiled.memory_analysis()
+    state_bytes = L * Bk * H * d * d * 4
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 8
+
+
+@pytest.mark.parametrize("program", ["decode_chunk", "extend_nosample"])
+def test_linear_attention_cell_programs_fit_and_keep_the_state_in_place(
+        one_chip, kernel_route_on, program):
+    """The one-step decode program and a 1,024-token piece of
+    `kimi-linear-48b-a3b.longdoc-wide` at the cell's sizes: the decode step
+    holds the state kernel's and the latent kernel's Mosaic calls (a scan
+    body a run of layers), the cache's three arrays are aliased through, and
+    arguments and temporaries fit the chip; the piece holds neither kernel
+    (the chunk-wise rule is plain XLA) and the rule's pairwise decays are a
+    chunk's at a time, never a piece's 16 chunks at once."""
+    cfg, ecfg, programs, params, cache = _sparse_cell(
+        one_chip, "kimi-linear-48b-a3b.longdoc-wide")
+    assert [c.shape for c in cache] == [(2, 64, 9216, 640), (6, 64, 32, 128, 128),
+                                        (6, 64, 3, 12288)]
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def vec(dtype, *tail):
+        return arg(dtype, ecfg.num_slots, *tail)
+
+    if program == "decode_chunk":
+        lowered = programs.decode_fns[1].lower(
+            params, *cache, vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_), vec(jnp.int32),
+            vec(jnp.int32, MAX_DEVICE_STOP_IDS), vec(jnp.uint32, 2), vec(jnp.float32),
+            vec(jnp.float32), vec(jnp.int32))
+    else:
+        lowered = programs.extend_nosample.lower(
+            params, *cache, arg(jnp.int32, 1, 1024), arg(jnp.int32, 1, 1024), arg(jnp.int32),
+            arg(jnp.int32))
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    cache_bytes = sum(int(np.prod(c.shape)) * c.dtype.itemsize for c in cache)
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13e9
+    state_calls = re.findall(r"%decode_kda_state[.\d]* = \(.*\) custom-call\(", text)
+    latent_calls = re.findall(r"%decode_mla_attention[.\d]* = \S+ custom-call\(", text)
+    if program == "decode_chunk":
+        # K | K K | M | K K K | M: three runs of linear-attention layers, two latent
+        assert (len(state_calls), len(latent_calls)) == (3, 2)
+        assert memory.temp_size_in_bytes < 0.5e9
+    else:
+        assert not state_calls and not latent_calls
+        chunk = 32 * 64 * 64 * 128                       # heads x C x C x dk, one chunk
+        pairwise = [int(np.prod(dims)) for ln in text.splitlines()
+                    if (dims := result_dims(ln)) and dims[-3:] == [64, 64, 128]]
+        assert pairwise and max(pairwise) <= 2 * chunk   # [q | k] rows against k
+
+
 def test_tp4_engine_decode_chunk_compiles_sharded(tp4_mesh, kernel_route_on):
     """The engine's real decode program (the scan of decode_chunk steps)
     on a dp=1 × tp=4 mesh of described devices, operands sharded by the
