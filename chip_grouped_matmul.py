@@ -7,7 +7,8 @@ layers' stack [L, Eh, K, N] at the published widths, ``sizes`` as a seeded
 router of the cell's kind gives them: scores, selection bias and the share
 of the experts that is held) it times each of a sparse layer's three
 matmuls (gate, up, down) through ``ops/moe.py::_ragged_matmul`` (the call as
-it was until PR 42, and as a decode step still makes it) and through
+a prompt's programs made it until PR 42 and a decode step's until PR 44,
+and as a call shorter than one row tile still makes it) and through
 ``ops/grouped_matmul.py`` at a few tilings, at a prompt's rows and at a
 decode step's, and prints one JSON line a shape and route: milliseconds a
 call and GB/s of the hit experts' bytes.
@@ -86,6 +87,8 @@ def main() -> int:
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="tiny sizes, the kernel interpreted; no measurement")
     ap.add_argument("--cells", nargs="*", default=sorted(SHAPES))
+    ap.add_argument("--rows-of", nargs="*", default=["prompt", "decode"],
+                    choices=["prompt", "decode"], help="which of a cell's two row counts")
     ap.add_argument("--out", default="chiprun_out/grouped_matmul.jsonl",
                     help="the lines again, for a tool that shows only the output's end")
     args = ap.parse_args()
@@ -119,6 +122,8 @@ def main() -> int:
         key = jax.random.key(args.seed & 0x7FFFFFFF)
         kx, kw, ks = jax.random.split(key, 3)
         for kind, toks in (("prompt", tokens), ("decode", slots)):
+            if kind not in args.rows_of:
+                continue
             rows = toks * k
             sizes, held = router_sizes(jax.random.fold_in(ks, toks), toks, k, E, Eh, d,
                                        scoring, bias)

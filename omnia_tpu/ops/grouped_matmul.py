@@ -1,11 +1,13 @@
-"""Pallas grouped matmul for a prompt's row counts.
+"""Pallas grouped matmul for the experts' runs of rows, from one row tile up.
 
 Rows of ``xs`` [M, K] lie in runs, run ``g`` of ``sizes[g]`` rows, and each
 run meets its own matrix ``w[g]`` [K, N]: what ``jax.lax.ragged_dot``
 computes, and what ops/moe.py's dropless expert layer asks three times a
-sparse layer. At a prompt's rows (thousands, 30–100 an expert)
-``ragged_dot`` takes three times the floor of the hit experts' bytes; this
-kernel takes 1.3–1.7 times it (PERF.md section 6, PR 42):
+sparse layer, in a prompt's programs and in a decode step's. At a prompt's
+rows (thousands, 30–100 an expert) ``ragged_dot`` takes three times the
+floor of the hit experts' bytes and this kernel 1.3–1.7 times it; at a
+decode step's (192–512, a few an expert) 1.4–2 times against the kernel's
+1.15–1.2 (PERF.md section 6, PR 42 and PR 44):
 
 - the work is a list made on the device, as the decode kernels' is
   (ops/decode_attention.py): ``_visits`` turns ``sizes`` into the pairs

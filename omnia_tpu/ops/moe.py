@@ -25,11 +25,13 @@ assignments that landed on the experts it holds. No capacity, so nothing
 drops: at every token count every assignment on a held expert is
 multiplied by that expert's matrices, accumulated in float32, none capped
 or approximated. What changes with the count is the tile the grouped
-matmul is computed in: a call of ``GROUPED_MATMUL_MIN_ROWS`` rows or
-more (a prompt's) runs through the Pallas kernel of ops/grouped_matmul.py
-where the kernels are routed on, a shorter one (a decode step's) through
-``jax.lax.ragged_dot`` (``_grouped_matmul``). Mixtral's ``moe_mlp`` moves
-onto it, and the two above go, in a later PR (ROADMAP).
+matmul is computed in: a call of ``GROUPED_MATMUL_MIN_ROWS`` rows (one row
+tile) or more, a prompt's and a served decode step's alike, runs through
+the Pallas kernel of ops/grouped_matmul.py where the kernels are routed
+on; a call shorter than a tile (a one-slot engine's step, a tiny test
+model's) and every call off the TPU through ``jax.lax.ragged_dot``
+(``_grouped_matmul``). Mixtral's ``moe_mlp`` moves onto it, and the two
+above go, in a later PR (ROADMAP).
 
 Sharding: expert-leading weights [E, d, f] shard E over the "tp" axis
 (expert parallelism). The [E, C, d] buffer shards over E, each device runs
@@ -160,11 +162,12 @@ def moe_mlp(h, p, num_experts_per_tok: int, capacity_factor: float = 2.0):
 
 
 #: A grouped matmul of this many row tiles or more goes through the Pallas
-#: kernel. The served models' decode steps call with 192–384 rows (slots ×
-#: k), their shortest prompt buckets with 2,048 and more. (A decode step's
-#: call would gain from the kernel too, 1.2–1.7 × by the microbenchmark of
-#: PERF.md section 6, PR 42: ROADMAP Speed 2, an issue of its own.)
-GROUPED_MATMUL_MIN_ROW_TILES = 4
+#: kernel. The served models' decode steps call with 192–512 rows (slots ×
+#: k), their shortest prompt buckets with 2,048 and more; at a step's rows
+#: the kernel reads the hit experts' matrices 1.2–1.7 × faster than
+#: ``ragged_dot`` (PERF.md section 6, PR 42 and PR 44). Below one tile
+#: nothing is measured and no served shape gets there.
+GROUPED_MATMUL_MIN_ROW_TILES = 1
 #: The same in rows (tokens × k); the engine's start-up line says it
 #: beside ``pallas_decode_mode()``.
 GROUPED_MATMUL_MIN_ROWS = GROUPED_MATMUL_MIN_ROW_TILES * ROW_TILE
@@ -189,8 +192,11 @@ def _grouped_matmul(xs, w, sizes, layer):
     the layer out in front instead copies all its experts, hit or not,
     once a call (1.6 GB a layer a step at Mistral-Small-4's widths: 62 % of
     a decode step, PERF.md section 6, PR 32). The route is chosen by the
-    call's static row count, so a compiled program holds exactly one; both
-    give a held row the same product, accumulated in float32."""
+    call's static row count against ``GROUPED_MATMUL_MIN_ROWS`` (read from
+    the module at each call: one row tile, so the kernel from a served
+    decode step's rows up and ``ragged_dot`` below a tile), so a compiled
+    program holds exactly one; both give a held row the same product,
+    accumulated in float32."""
     if _kernel_on() and xs.shape[0] >= GROUPED_MATMUL_MIN_ROWS:
         return grouped_matmul(xs, w, sizes, layer,
                               interpret=_pallas_decode_mode() == "interpret")

@@ -1,4 +1,4 @@
-"""The Pallas grouped matmul of a prompt's row counts
+"""The Pallas grouped matmul of a prompt's and a decode step's row counts
 (ops/grouped_matmul.py), interpreted on the CPU: held against
 ``jax.lax.ragged_dot`` and against a plain loop over the runs in float32,
 and ``moe_dropless`` end to end through both routes of
@@ -15,6 +15,17 @@ from omnia_tpu.ops import moe
 
 TM = 16  # the row tile of these cases (ROW_TILE is 128)
 
+
+def step_runs(held, runs, empty, seed=0):
+    """``held`` rows over ``runs`` runs as a router leaves them: ``empty``
+    runs drawn to get none, every other at least one and the rest by lot."""
+    rng = np.random.default_rng(seed)
+    full = np.sort(rng.permutation(runs)[empty:])
+    sizes = np.zeros(runs, np.int64)
+    sizes[full] = 1 + rng.multinomial(held - len(full), np.ones(len(full)) / len(full))
+    return sizes.tolist()
+
+
 # name: (rows, the runs' sizes)
 RUNS = {
     "runs that end inside a row tile": (64, [5, 20, 3, 36]),
@@ -25,6 +36,13 @@ RUNS = {
     "rows that are no multiple of the tile": (70, [10, 0, 30, 5, 0, 7]),
     "every run a tile exactly": (64, [16, 16, 16, 16]),
     "one row a run": (16, [1, 1, 1, 1, 1]),
+    # The served decode steps' calls (slots × k rows; PERF.md section 6, PR 42's
+    # table): a few rows a run, most runs sharing a row tile with others.
+    "a decode step's 192 rows in 64 runs of which 7 are empty": (
+        192, step_runs(192, 64, empty=7)),
+    "a decode step's 256 rows of which 37 are held, in 16 runs of which 4 are empty": (
+        256, step_runs(37, 16, empty=4)),
+    "a decode step's 384 rows in 32 runs": (384, step_runs(384, 32, empty=0)),
 }
 # (K, N) in the cells' ratios of width to expert width: 3.5, 3 and 2 to 1
 # (xing4-29b-a4b 3584 : 1024, k-exaone-236b-a23b 6144 : 2048,
@@ -91,6 +109,17 @@ def test_the_cells_ratios_of_k_to_n_at_the_tiles_the_kernel_picks(K, N):
     xs, w, sizes = operands(384, sizes, K, N, jnp.bfloat16, seed=K)
     got = gmm.grouped_matmul(xs, w, sizes, interpret=True)
     held_rows_agree(got, xs, w, sizes, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("case", [c for c in RUNS if c.startswith("a decode step")])
+def test_a_decode_steps_runs_at_the_tiles_the_kernel_picks(case):
+    """``tiling`` left out, as the decode programs call it: 128-row tiles,
+    so dozens of runs share a tile and each visit stores its own rows only."""
+    rows, sizes = RUNS[case]
+    xs, w, sizes = operands(rows, sizes, 256, 128, jnp.bfloat16, layers=2, seed=rows)
+    assert int((sizes > 0).sum()) > 2 * -(-rows // gmm.ROW_TILE)  # runs that share tiles
+    got = gmm.grouped_matmul(xs, w, sizes, jnp.int32(1), interpret=True)
+    held_rows_agree(got, xs, w[1], sizes, jnp.bfloat16)
 
 
 def test_no_held_row_is_no_visit():
@@ -173,7 +202,7 @@ def both_routes(routes, fn):
     return ragged, kernel
 
 
-ROWS = 300  # tokens: 600 rows a call at k = 2, above the constant
+ROWS = 300  # tokens: 600 rows a call at k = 2, nearly five times the constant (one row tile)
 
 
 @pytest.mark.parametrize("ranks", [1, 2, 4])
@@ -225,13 +254,15 @@ def test_both_routes_meet_a_layer_of_the_stacks_in_place(routes, layer):
         assert (int(out[1]), int(out[2])) == (int(want[1]), int(want[2]))
 
 
-@pytest.mark.parametrize("tokens,kernel", [(8, False), (255, False), (256, True), (300, True)])
+@pytest.mark.parametrize("tokens,kernel", [(8, False), (63, False), (64, True), (300, True)])
 def test_the_route_is_the_calls_row_count_against_one_constant(routes, tokens, kernel):
-    """tokens × k against ``GROUPED_MATMUL_MIN_ROWS``: a decode step's
-    call keeps ``ragged_dot`` with the kernels routed on, and with them off
-    no call takes the kernel."""
+    """tokens × k against ``GROUPED_MATMUL_MIN_ROWS``, one row tile: a call
+    of a tile or more (a served decode step's, a prompt's) takes the kernel
+    with the kernels routed on, a shorter one keeps ``ragged_dot``, and with
+    them off no call takes the kernel."""
     through, calls = routes
-    assert moe.GROUPED_MATMUL_MIN_ROWS == moe.GROUPED_MATMUL_MIN_ROW_TILES * gmm.ROW_TILE == 512
+    assert moe.GROUPED_MATMUL_MIN_ROW_TILES == 1
+    assert moe.GROUPED_MATMUL_MIN_ROWS == gmm.ROW_TILE == 128
     p = share.layer_params(shared=False)
     h = jax.random.normal(jax.random.key(1), (tokens, share.D))
     through("interpret")

@@ -367,14 +367,20 @@ def test_cell_prefill_insert_runs_its_head_over_one_row(one_chip):
     assert not wide, wide
 
 
-# The experts' grouped matmuls (ops/moe.py::_grouped_matmul): a prompt's row
-# counts go through the Pallas kernel of ops/grouped_matmul.py, a decode
-# step's through ``ragged_dot`` (``ragged-dot-none*`` custom calls on the
-# chip). The two sparse cells whose prompt side is most of the device's time,
-# at their own sizes (benchmark/cells/, benchmark/configs/).
+# The experts' grouped matmuls (ops/moe.py::_grouped_matmul): every call of
+# one row tile (128 rows) or more goes through the Pallas kernel of
+# ops/grouped_matmul.py, so a served engine's prompt-side programs (2,048
+# rows and more a call) and its decode programs (slots × k: 192–512 rows)
+# hold the kernel's Mosaic calls and no ``ragged_dot`` (``ragged-dot-none*``
+# custom calls on the chip); only a call shorter than a tile keeps that.
+# The three sparse cells whose decode step called ``ragged_dot`` until PR 44,
+# at their own sizes (benchmark/cells/, benchmark/configs/): the cell and,
+# for the two whose prompt side is most of the device's time, a prompt-side
+# program and its tokens a call.
 SPARSE_CELLS = {
     "judge-batch": ("xing4-29b-a4b.judge-batch", "prefill_insert", 1536),
     "longdoc-batch": ("k-exaone-236b-a23b.longdoc-batch", "extend_nosample", 1024),
+    "reason-batch": ("mistral-small-4.reason-batch", None, None),
 }
 
 
@@ -450,7 +456,7 @@ def _ragged_dot_calls(text: str) -> list[str]:
     return re.findall(r"%ragged-dot[\w.\-]* = \S+ custom-call\(", text)
 
 
-@pytest.mark.parametrize("cell", sorted(SPARSE_CELLS))
+@pytest.mark.parametrize("cell", sorted(c for c, s in SPARSE_CELLS.items() if s[1]))
 def test_sparse_cells_prompt_programs_hold_the_grouped_matmul_kernel(
         one_chip, kernel_route_on, cell):
     """``prefill_insert`` at judge-batch's middle bucket (6,144 rows a call)
@@ -476,6 +482,13 @@ def test_sparse_cells_prompt_programs_hold_the_grouped_matmul_kernel(
         lowered = programs.extend_nosample.lower(params, *cache, *tokens, i32, i32)
     text = lowered.compile().as_text()
     assert T * cfg.num_experts_per_tok >= moe.GROUPED_MATMUL_MIN_ROWS
+    _holds_the_kernel_on_the_scans_own_stack(text, cfg)
+
+
+def _holds_the_kernel_on_the_scans_own_stack(text, cfg):
+    """Three ``grouped_matmul`` Mosaic calls a sparse layer body, no
+    ``ragged_dot``, and no copy, slice or re-layout as large as a layer's
+    experts in front of them."""
     calls = _grouped_matmul_calls(text)
     assert calls and len(calls) % 3 == 0, calls
     assert _ragged_dot_calls(text) == []
@@ -489,28 +502,30 @@ def test_sparse_cells_prompt_programs_hold_the_grouped_matmul_kernel(
             assert int(np.prod(dims)) < experts, ln.strip()[:200]
 
 
+@pytest.mark.parametrize("chunk", [1, 8])
 @pytest.mark.parametrize("cell", sorted(SPARSE_CELLS))
-def test_sparse_cells_decode_step_keeps_ragged_dot(one_chip, kernel_route_on, cell):
-    """The one-step decode program of the same models (192 and 256 rows a
-    call, under the constant): three ``ragged_dot`` calls a sparse layer
-    body and no call of the grouped-matmul kernel, so the decode step's
-    rooflines read the ops they read before PR 42."""
+def test_sparse_cells_decode_programs_hold_the_grouped_matmul_kernel(
+        one_chip, kernel_route_on, cell, chunk):
+    """The one-step and the chunk-of-8 decode programs of the same models
+    (192, 256 and 384 rows a call: one row tile and more): the three
+    matmuls of each sparse layer body are the kernel's Mosaic calls on the
+    scan's own stack, as on the prompt side, and no ``ragged_dot`` is left
+    (until PR 44 a step kept it; the step's rooflines read the
+    ``moe.experts`` scope, which the kernel keeps)."""
     cfg, ecfg, programs, params, cache = _sparse_cell(one_chip, SPARSE_CELLS[cell][0])
     rows = ecfg.num_slots * cfg.num_experts_per_tok
-    assert rows < moe.GROUPED_MATMUL_MIN_ROWS
+    assert rows >= moe.GROUPED_MATMUL_MIN_ROWS
 
     def vec(dtype, *tail):
         return jax.ShapeDtypeStruct((ecfg.num_slots, *tail), dtype, sharding=one_chip)
 
     i32, f32 = (lambda *t: vec(jnp.int32, *t)), vec(jnp.float32)
-    text = programs.decode_fns[1].lower(
+    text = programs.decode_fns[chunk].lower(
         params, *cache, i32(), i32(), vec(jnp.bool_), i32(),
         i32(MAX_DEVICE_STOP_IDS), vec(jnp.uint32, 2), f32, f32, i32(),
     ).compile().as_text()
-    ragged = _ragged_dot_calls(text)
-    assert ragged and len(ragged) % 3 == 0, ragged
-    assert _grouped_matmul_calls(text) == []
-    assert any(f"[{rows}," in call for call in ragged), ragged
+    _holds_the_kernel_on_the_scans_own_stack(text, cfg)
+    assert all(f"bf16[{rows}," in call for call in _grouped_matmul_calls(text))
 
 
 # The latent family with linear-attention layers (models/mla.py, ops/kda.py): a
