@@ -79,8 +79,7 @@ from omnia_tpu.engine.types import (
     StreamEvent,
     resolve_dtype,
 )
-from omnia_tpu.models import ModelConfig, model_module
-from omnia_tpu.models import quant
+from omnia_tpu.models import ModelConfig, model_module, quant
 from omnia_tpu.models.kv_quant import cache_bytes, validate_kv_quant
 from omnia_tpu.ops.sampling import make_slot_key_data
 from omnia_tpu.ops.attention import check_decode_kernel, pallas_decode_mode
@@ -381,7 +380,7 @@ class InferenceEngine(
             "placements_deferred": 0,
             "programs_compiled_serving": 0,
             "extend_steps": 0,
-            "prefill_tokens": 0,
+            "prefill_tokens": 0, "prefill_tokens_blocked": 0,
             "prefix_reuse_tokens": 0,
             "session_offloads": 0,
             "session_restores": 0,
@@ -509,8 +508,9 @@ class InferenceEngine(
         self._sync_coldstart_metrics()
         logger.info(
             "engine built: backend=%s pallas_decode=%s grouped_matmul_from_rows=%d "
-            "slots=%d max_seq=%d chunks=%s quant=%s kv_quant=%s",
+            "blocked_buckets=%s slots=%d max_seq=%d chunks=%s quant=%s kv_quant=%s",
             jax.default_backend(), pallas_decode_mode(), GROUPED_MATMUL_MIN_ROWS,
+            self._blocked_buckets(),
             B, engine_cfg.max_seq, self.cfg.chunk_variants(), qmode, self._kv_quant)
 
     def _alloc_kv_state(self):

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from omnia_tpu.engine.types import EngineConfig
 from omnia_tpu.models import ModelConfig, llama
+from omnia_tpu.ops.attention import prefill_kernel_on
 
 
 def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
@@ -88,6 +89,21 @@ def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
                 f"EngineConfig.{name}={getattr(cfg, name)!r} is not ported to "
                 f"{family}; model {model_cfg.name!r}){reason}"
             )
+
+
+def prefill_blocked(model_cfg: ModelConfig, cfg: EngineConfig, mesh, bucket: int,
+                    fresh: bool) -> bool:
+    """Whether the engine's program over ``bucket`` prompt rows runs its
+    attention through the blocked kernel (ops/prefill_attention.py): the
+    route's own function over what programs.py hands the model. A ``fresh``
+    prefill (``prefill_insert``) attends over its own chunk, plain rows
+    whatever the cache holds; an extend piece or a mixed step
+    (``_extend_slot``) over one slot's view of ``max_seq`` rows, int8 under
+    ``kv_quant`` and plain else (a paged pool's view is gathered); each
+    with the engine's mesh. ``prefill_tokens_blocked`` counts by it, and
+    tests/test_prefill_attention.py holds it to the traced programs."""
+    rows, plain = (bucket, True) if fresh else (cfg.max_seq, not cfg.kv_quant)
+    return prefill_kernel_on(bucket, rows, model_cfg.attn_value_width, plain, mesh)
 
 
 class _PairCacheMixin:

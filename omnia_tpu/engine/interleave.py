@@ -316,6 +316,8 @@ class _InterleaveMixin:
         self.metrics["mixed_steps"] += 1
         self.metrics["interleaved_prefill_tokens"] += take
         self.metrics["prefill_tokens"] += take
+        if self._blocked(bucket, False):
+            self.metrics["prefill_tokens_blocked"] += take
         if self._flight is not None:
             self._flight.note_mixed_step(
                 pf.request.request_id, take, bucket, dispatch_s

@@ -19,6 +19,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from omnia_tpu.engine.family import prefill_blocked
 from omnia_tpu.engine.phases import PREFILL_DISPATCH, phase
 from omnia_tpu.engine.sessions import _SessionKV
 from omnia_tpu.engine.types import (
@@ -365,6 +366,20 @@ class _PlacementMixin:
         self._emit_token(slot_idx, token)
         return stages
 
+    def _blocked(self, bucket: int, fresh: bool) -> bool:
+        """Whether the program of ``bucket`` prompt rows (``fresh``: the
+        fresh prefill; else an extend piece or a mixed step) runs its
+        attention through the blocked kernel (family.py::prefill_blocked):
+        ``prefill_tokens_blocked`` counts the prompt tokens dispatched
+        through such a program."""
+        return prefill_blocked(self.model_cfg, self.cfg, self._mesh, bucket, fresh)
+
+    def _blocked_buckets(self) -> dict:
+        """The buckets whose fresh prefill / extend piece takes the kernel."""
+        buckets = sorted(self.cfg.usable_buckets())
+        return {"prefill": [b for b in buckets if self._blocked(b, True)],
+                "extend": [b for b in buckets if self._blocked(b, False)]}
+
     def _fresh_prefill(self, slot_idx: int, prompt: list[int],
                        sp: SamplingParams, request: Optional[Request] = None):
         """``(first_tok, new_key_data)``, both still on the device."""
@@ -414,6 +429,8 @@ class _PlacementMixin:
                 *self._grammar_args(request, sp),
             )
             self._cache = tuple(cache)
+        if self._blocked(bucket, True):
+            self.metrics["prefill_tokens_blocked"] += n
         if self._flight is not None and request is not None:
             self._flight.note_prefill_piece(
                 request.request_id, n, bucket, time.monotonic() - t0
@@ -499,4 +516,6 @@ class _PlacementMixin:
         if self._flight is not None and rid:
             self._flight.note_prefill_piece(rid, take, b, time.monotonic() - t0)
         self.metrics["extend_steps"] += len(pieces)
+        self.metrics["prefill_tokens_blocked"] += sum(
+            take for _, take, b in pieces if self._blocked(b, False))
         return first_tok, new_kd

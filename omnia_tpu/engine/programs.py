@@ -217,7 +217,7 @@ def build_programs(
         cache, (tokens, positions, slot, last_idx, key_data, temp, top_p,
                 top_k, *g) = args[:n_cache], args[n_cache:]
         last, *chunks = model.forward_prefill(params, cfg, tokens, positions,
-                                              row=last_idx)
+                                              row=last_idx, mesh=mesh)
 
         # c: [L,B,S,...]; chunk: [L,1,T,...] — a quantized cache
         # quantizes the fresh rows inside cache_put (kv_quant mode).

@@ -79,7 +79,7 @@ class TpuEmbedder(Embedder):
         self._params = params
         self._cfg = cfg
         self.dim = cfg.hidden_size
-        self._fn = jax.jit(lambda tok, mask: llama.forward_embed(params, cfg, tok, mask))
+        self._fn = jax.jit(lambda tok, mask: llama.forward_embed(params, cfg, tok, mask, mesh))
 
     def _bucket(self, n: int, buckets) -> int:
         for b in buckets:

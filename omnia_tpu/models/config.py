@@ -137,6 +137,13 @@ class ModelConfig:
         return self.kv_rank > 0
 
     @property
+    def attn_value_width(self) -> int:
+        """Lanes of one head's values in attention: the width the blocked
+        prefill kernel's route asks to be whole 128s (a latent head's key
+        is padded; ops/attention.py::prefill_kernel_on)."""
+        return self.v_head_dim if self.is_latent else self.head_dim
+
+    @property
     def experts_held(self) -> int:
         return self.num_experts_held or self.num_experts
 

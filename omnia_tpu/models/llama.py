@@ -62,7 +62,7 @@ from omnia_tpu.models.stacks import (  # noqa: F401  (the module contract's name
     stack_kinds,
     with_layer_order,
 )
-from omnia_tpu.ops.attention import gqa_attention
+from omnia_tpu.ops.attention import einsum_attention, gqa_attention
 from omnia_tpu.ops.moe import moe_mlp
 from omnia_tpu.ops.norms import rms_norm
 from omnia_tpu.ops.rope import apply_rope, rope_cos_sin
@@ -360,7 +360,7 @@ def _embed(params, cfg: ModelConfig, tokens, q_positions):
 
 
 def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None,
-                    row=None):
+                    row=None, mesh=None):
     """Fresh-sequence prefill: self-contained attention over the chunk,
     returning the per-layer KV chunk for the engine to place into a cache
     slot (so prefill never reads or writes other slots' cache).
@@ -369,7 +369,9 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None,
     Returns (logits [B, T, V] f32, k_chunk, v_chunk [L, B, T, Hkv, D]);
     with ``row`` (int32 scalar) the logits are that row's alone, [B, V]:
     the final norm and the head run over one row (``_logits_at``).
-    attn_fn overrides the attention op (the ring-prefill path). A model
+    attn_fn overrides the attention op (the ring-prefill path); ``mesh`` as
+    ``forward`` takes it: a chunk whose heads a mesh shards keeps the einsums
+    (ops/attention.py::prefill_kernel_on). A model
     with window layers returns four chunks, the window layers' already in
     the ring's shape [Lw, B, R, Hkv, D]: the last R real rows where the
     ring holds them.
@@ -377,12 +379,13 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None,
     x, cos, sin = _embed(params, cfg, tokens, q_positions)
     if is_stacked(cfg):
         x, chunks, _ = _run_stacks(params, cfg, x, cos, sin, q_positions, None, None,
-                                   row, None, None)
+                                   row, mesh, None)
         return (_logits_at(params, cfg, x, row), *chunks)
 
     def body(x, p):
         x, k, v = _layer(
-            x, p, cfg, cos, sin, q_positions, None, None, None, attn_fn=attn_fn
+            x, p, cfg, cos, sin, q_positions, None, None, None, attn_fn=attn_fn,
+            mesh=mesh,
         )
         return x, (k, v)
 
@@ -492,24 +495,25 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, *cache_and_start,
     return _logits_at(params, cfg, x, row), new_k, new_v
 
 
-def forward_embed(params, cfg: ModelConfig, tokens, mask):
+def forward_embed(params, cfg: ModelConfig, tokens, mask, mesh=None):
     """Embedding-role forward (reference Provider role `embedding`,
     provider_types.go:40-63 — served remotely there, on-device here):
     masked mean-pool of the final hidden states, L2-normalized f32 [B, D].
 
-    tokens: int32 [B, T]; mask: [B, T] (1 = real token, 0 = pad).
+    tokens: int32 [B, T]; mask: [B, T] (1 = real token, 0 = pad); ``mesh``:
+    the one the params are sharded over, if any (as ``forward`` takes it).
     """
     B, T = tokens.shape
     q_positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
     x, cos, sin = _embed(params, cfg, tokens, q_positions)
 
     def body(x, p):
-        x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None)
+        x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None, mesh=mesh)
         return x, None
 
     if is_stacked(cfg):
         x = _run_stacks(params, cfg, x, cos, sin, q_positions, None, None, None,
-                        None, None)[0]
+                        mesh, None)[0]
     else:
         x, _ = jax.lax.scan(body, x, params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps).astype(jnp.float32)
@@ -526,12 +530,16 @@ def forward_train(params, cfg: ModelConfig, tokens):
     B, T = tokens.shape
     q_positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
     x, cos, sin = _embed(params, cfg, tokens, q_positions)
+    # Differentiated through: the einsums whatever the route (the blocked
+    # kernel of ops/prefill_attention.py is a Pallas call, which has no VJP).
     if is_stacked(cfg):
         return _logits(params, cfg, _run_stacks(
-            params, cfg, x, cos, sin, q_positions, None, None, None, None, None)[0])
+            params, cfg, x, cos, sin, q_positions, None, None, None, None, None,
+            attn_fn=einsum_attention)[0])
 
     def body(x, p):
-        x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None)
+        x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None,
+                         attn_fn=einsum_attention)
         return x, None
 
     x, _ = jax.lax.scan(body, x, params["layers"])

@@ -105,20 +105,15 @@ from jax.sharding import PartitionSpec as P
 from omnia_tpu.models import kinds
 from omnia_tpu.models.config import ModelConfig
 from omnia_tpu.ops import hyper_connections as hc
-from omnia_tpu.ops.attention import _kernel_on, _pallas_decode_mode
+from omnia_tpu.ops.attention import _kernel_on, _pallas_decode_mode, prefill_kernel_on
 from omnia_tpu.ops.decode_mla_attention import block_rows, decode_mla_attention
 from omnia_tpu.ops.kda import decode_kda_state, kda_chunked
 from omnia_tpu.ops.moe import EXPERT_COUNTERS, init_ffn
-from omnia_tpu.ops.moe import expert_ffn as _experts
-from omnia_tpu.ops.moe import unstack_experts as _unstack_experts
+from omnia_tpu.ops.moe import expert_ffn as _experts, unstack_experts as _unstack_experts
 from omnia_tpu.ops.norms import rms_norm
-from omnia_tpu.ops.rope import (
-    apply_rope,
-    apply_rope_interleaved,
-    rope_cos_sin,
-    yarn_cos_sin,
-    yarn_softmax_scale,
-)
+from omnia_tpu.ops.prefill_attention import latent_prefill_attention
+from omnia_tpu.ops.rope import (apply_rope, apply_rope_interleaved, rope_cos_sin, yarn_cos_sin,
+                                yarn_softmax_scale)
 
 _NEG_INF = -1e30
 
@@ -418,11 +413,16 @@ def _write_rows(cache, row, start, layer):
 
 
 def _expanded_attention(q_nope, q_rope, rows, wkvb, cfg: ModelConfig, q_positions):
-    """Prefill (T > 1): every head's keys and values from the rows. rows
-    [B, S, W] lie at positions 0 … S-1. → [B, T, H·dv]."""
+    """Prefill (T > 1): every head's keys and values from the rows [B, S, W] at positions
+    0 … S-1 → [B, T, H·dv], by the einsums below or the blocked kernel where routed on."""
     B, T, H, dn = q_nope.shape
     R, dr, dv = cfg.kv_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
     S = rows.shape[1]
+    if prefill_kernel_on(T, S, cfg.attn_value_width):
+        return latent_prefill_attention(
+            q_nope, q_rope, rows, wkvb, q_positions,
+            scale=yarn_softmax_scale(dn + dr, cfg.rope_yarn),
+            interpret=_pallas_decode_mode() == "interpret")
     kv = jnp.dot(rows[..., :R], wkvb).reshape(B, S, H, dn + dv)
     scores = jnp.einsum("bthd,bshd->bhts", q_nope, kv[..., :dn],
                         preferred_element_type=jnp.float32)
@@ -732,9 +732,9 @@ def _run_kinds(params, cfg: ModelConfig, x, cos, sin, q_scale, q_positions, cach
 # ---------------------------------------------------------------------------
 
 
-def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, row=None):
+def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, row=None, mesh=None):
     """Fresh-sequence prefill: attention over the chunk's own rows, which
-    come back for the engine to place into a cache slot.
+    come back for the engine to place into a cache slot (``mesh``: unused, as ``forward``'s).
 
     tokens, q_positions: int32 [B, T]. Returns (logits [B, T, V] f32,
     chunk [L, B, T, W]); with ``row`` (int32 scalar) the logits are that

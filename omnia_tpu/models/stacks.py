@@ -213,14 +213,15 @@ def _ring_rows_before(ring, start, window: int, layer):
 
 
 def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, cos, sin, q_positions,
-                 cache, cache_layer, write_start, n_real, mesh, live):
+                 cache, cache_layer, write_start, n_real, mesh, live, attn_fn=None):
     """One block of a model of several kinds: ``kind`` its stack's, ``at`` its
     index in the stack (its experts' too), ``cache_layer`` its index into the
     cache arrays of its attention kind. ``cache``: the whole tuple (the
     module docstring), or None for a chunk on its own (training, a fresh
     prefill), which gets its rows back instead: (k, v) [B, T, Hkv, D] of a
-    full layer, [B, R, Hkv, D] of a window layer. → (x, cache or rows,
-    counts int32 [2] as EXPERT_COUNTERS)."""
+    full layer, [B, R, Hkv, D] of a window layer. ``attn_fn`` overrides a full
+    layer's attention over a chunk on its own (training: the einsums). → (x,
+    cache or rows, counts int32 [2] as EXPERT_COUNTERS)."""
     B, T, _ = x.shape
     window = cfg.sliding_window if kind.endswith("window") else 0
     a = p["attn"]
@@ -266,7 +267,8 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, cos, sin, q_position
             kept = (*full, rk, rv)
         elif cache is None:
             with jax.named_scope("attn.full"):
-                attn = gqa_attention(q, k, v, q_positions)
+                attn = (attn_fn(q, k, v, q_positions) if attn_fn else
+                        gqa_attention(q, k, v, q_positions, mesh=mesh))
             kept = (k, v)
         else:
             from omnia_tpu.models.llama import _write_kv  # (it imports this module)
@@ -288,7 +290,7 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, cos, sin, q_position
 
 
 def _run_stacks(params, cfg: ModelConfig, x, cos, sin, q_positions, cache, write_start,
-                row, mesh, live):
+                row, mesh, live, attn_fn=None):
     """Every layer of a model of several kinds, a scan a run (``_runs``)
     under ``stack.<kind>``. With a cache (the whole tuple) it is the carry
     and comes back; without one the chunk's rows come back in its place, an
@@ -312,7 +314,7 @@ def _run_stacks(params, cfg: ModelConfig, x, cos, sin, q_positions, cache, write
                 lambda leaf: jax.lax.dynamic_index_in_dim(leaf, i, 0, keepdims=False), scanned)
             x, kept, c = _stack_layer(
                 x, p, experts, i, kind, cfg, cos, sin, q_positions, cache,
-                cache_first + i - first, write_start, n_real, mesh, live)
+                cache_first + i - first, write_start, n_real, mesh, live, attn_fn)
             return ((x, kept, counts + c), None) if cache is not None else (
                 (x, None, counts + c), kept)
 

@@ -12,6 +12,12 @@ Design notes (TPU-first):
 - GQA is expressed by reshaping Q to [B, T, Hkv, G, D] and batching the
   einsums over the KV-head axis — no materialized KV repeat (which would
   multiply HBM traffic by the group size).
+- A chunk of more than one query a slot (prefill, extend) runs through the
+  blocked Pallas kernel of ops/prefill_attention.py where ``prefill_kernel_on``
+  says so: a running softmax over tiles in VMEM, no ``[H, T, S]`` scores in
+  HBM, no key block past the queries' positions. The einsums below keep the
+  CPU, int8 and paged caches, a mesh, chunks that are no whole 128-row tiles
+  (speculation's verify steps) and training, and are the kernel's oracle.
 - Head axes are sharded over the "tp" mesh axis by the caller (weights carry
   the sharding; XLA propagates it through the einsum path with no collectives
   inside attention). The Pallas decode kernel cannot be partitioned by XLA, so
@@ -90,6 +96,21 @@ def check_decode_kernel(cache_len: int, num_kv_heads: int, paged: bool,
             "decode kernel: a page pool sharded over dp holds global page "
             "ids, so one slot's pages span devices; use dp=1 with kv_pages"
         )
+
+
+def prefill_kernel_on(T: int, S: int, width: int, plain: bool = True, mesh=None) -> bool:
+    """Whether a chunk of T queries (T > 1) over S rows of keys takes the
+    blocked Pallas kernel (ops/prefill_attention.py) and not the einsums,
+    from what the call can see: the switch the decode kernels have, T and S
+    whole numbers of 128-row tiles, heads ``width`` lanes wide in whole
+    128s, the rows plain arrays (``plain``: neither QuantKV nor paged) and
+    no mesh to shard them. The engine counts ``prefill_tokens_blocked`` by
+    it, over what its programs hand the model (engine/family.py::
+    prefill_blocked; the width: ``ModelConfig.attn_value_width``)."""
+    from omnia_tpu.ops.prefill_attention import QUERY_TILE
+
+    return (_kernel_on() and plain and mesh is None and T > 1
+            and T % QUERY_TILE == 0 and S % QUERY_TILE == 0 and width % 128 == 0)
 
 
 def _map_rows(fn, cache):
@@ -194,7 +215,9 @@ def gqa_attention(
         reads nothing for a slot marked dead and returns zeros for it;
         the einsum path (and any T > 1) ignores it — a dead slot's
         output is discarded by whoever marked it dead.
-    Returns [B, T, H, D].
+    Returns [B, T, H, D]. T == 1 goes to the decode kernel, T > 1 to the
+    blocked prefill kernel, each where it is routed on and serves the call
+    (``_decode_path``, ``prefill_kernel_on``); ``einsum_attention`` else.
     """
     B, T, H, D = q.shape
 
@@ -204,6 +227,27 @@ def gqa_attention(
         if fused is not None:
             return fused
 
+    plain = not (is_paged(k_cache) or is_quant_kv(k_cache))
+    if prefill_kernel_on(T, k_cache.shape[-3], D, plain, mesh):
+        from omnia_tpu.ops.prefill_attention import prefill_attention
+
+        # The heads side by side on the minor axis (a reshape): a KV head's
+        # columns are one block of it, in the chunk or in the whole cache.
+        Hkv = k_cache.shape[-2]
+        return prefill_attention(
+            q.reshape(B, T, H * D), k_cache.reshape(*k_cache.shape[:-2], Hkv * D),
+            v_cache.reshape(*v_cache.shape[:-2], Hkv * D), q_positions, layer,
+            kv_heads=Hkv, scale=D**-0.5, interpret=_pallas_decode_mode() == "interpret",
+        ).reshape(B, T, H, D)
+    return einsum_attention(q, k_cache, v_cache, q_positions, layer)
+
+
+def einsum_attention(q, k_cache, v_cache, q_positions, layer=None):
+    """``gqa_attention`` by einsums over all S rows, the masked scores ``[H,
+    T, S]`` in float32: every layout of the cache, every T, any backend, and
+    differentiable (models/llama.py::forward_train names it as its
+    ``attn_fn``: a Pallas call has no VJP)."""
+    B, T, H, D = q.shape
     if layer is not None:
         # One read of the layer; nothing is written back.
         def take(arr):

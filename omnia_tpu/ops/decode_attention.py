@@ -42,8 +42,8 @@ test_tpu_compile.py has all three shapes). Blocks that the pipeline
 fetches through a BlockSpec have no such limit, so the list serves every
 edition with one body.
 
-Used for T==1 (decode) steps on TPU; prefill keeps the XLA path (it is
-compute-bound and XLA fuses it well)."""
+Used for T==1 (decode) steps on TPU; a prompt's chunk (T > 1) has a kernel
+of its own, ops/prefill_attention.py."""
 
 from __future__ import annotations
 

@@ -50,9 +50,13 @@ from omnia_tpu.models.config import ModelConfig
 def _stage_scan(layers_local, x, cfg, cos, sin, qpos):
     """Run this stage's local layer shard over activations x [mb, T, D]."""
     from omnia_tpu.models.llama import _layer
+    from omnia_tpu.ops.attention import einsum_attention
 
     def body(x, p):
-        x, k, v = _layer(x, p, cfg, cos, sin, qpos, None, None, None)
+        # Under the "pp" mesh, and differentiated through in training: the
+        # einsums whatever the kernel route (ops/attention.py).
+        x, k, v = _layer(x, p, cfg, cos, sin, qpos, None, None, None,
+                         attn_fn=einsum_attention)
         return x, (k, v)
 
     return lax.scan(body, x, layers_local)

@@ -609,6 +609,95 @@ def test_linear_attention_cell_programs_fit_and_keep_the_state_in_place(
         assert pairwise and max(pairwise) <= 2 * chunk   # [q | k] rows against k
 
 
+# The blocked prefill attention (ops/prefill_attention.py) at the three cells
+# whose device time is mostly prompts: cell, prompt-side program, its tokens a
+# call, the rows of keys they meet, heads, KV heads, key and value lanes a head.
+BLOCKED_CELLS = {
+    "judge-batch": ("xing4-29b-a4b.judge-batch", "prefill_insert", 2048, 2048, 32, 32, 256, 128),
+    "longdoc-batch": ("k-exaone-236b-a23b.longdoc-batch", "extend_nosample", 1024, 8960,
+                      64, 8, 128, 128),
+    "longdoc-wide": ("kimi-linear-48b-a3b.longdoc-wide", "extend_nosample", 1024, 9216,
+                     32, 32, 256, 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(BLOCKED_CELLS))
+def test_prefill_attention_kernel_compiles(one_chip, cell):
+    """The kernel alone at each cell's shape and the tiles it picks itself: a
+    fresh chunk of 2,048 × 32 heads × 192 (256 lanes) / 128, a piece of 1,024
+    against layer ``layer`` of 8,960 rows × 64 / 8 heads (a last block of 768
+    rows) and against 9,216 rows × 32 heads."""
+    from omnia_tpu.ops.prefill_attention import prefill_attention
+
+    _, program, T, S, H, Hkv, dk, dv = BLOCKED_CELLS[cell]
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lead = () if program == "prefill_insert" else (2,)
+    layer = () if program == "prefill_insert" else (arg(jnp.int32),)
+    text = jax.jit(
+        lambda q, k, v, pos, *layer: prefill_attention(
+            q, k, v, pos, *layer, kv_heads=Hkv, scale=0.07)
+    ).lower(arg(jnp.bfloat16, 1, T, H * dk), arg(jnp.bfloat16, *lead, 1, S, Hkv * dk),
+            arg(jnp.bfloat16, *lead, 1, S, Hkv * dv), arg(jnp.int32, 1, T), *layer
+            ).compile().as_text()
+    assert len(re.findall(r"%prefill_attention[.\d]* = \S+ custom-call\(", text)) == 1
+
+
+@pytest.mark.parametrize("cell", sorted(BLOCKED_CELLS))
+def test_claimed_cells_prompt_programs_hold_no_score_tensor(one_chip, kernel_route_on,
+                                                            monkeypatch, cell):
+    """``prefill_insert`` at judge-batch's largest bucket and ``extend_nosample``
+    at the two long-document cells' piece, as the harness builds them: the
+    attention of every full / latent layer body is the kernel's Mosaic call, and
+    no instruction has a result of heads × queries × rows elements in float32,
+    or in any type with the queries and the rows among its dimensions (the
+    masked scores, their exponentials, the probabilities): with the route off
+    the same program holds them in float32, and its temporaries are larger by
+    about their size."""
+    name, program, T, S, H, *_ = BLOCKED_CELLS[cell]
+    cfg, ecfg, _, params, cache = _sparse_cell(one_chip, name)
+    assert S == (T if program == "prefill_insert" else ecfg.max_seq) and H == cfg.num_heads
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = arg(jnp.int32)
+    tokens = (arg(jnp.int32, 1, T), arg(jnp.int32, 1, T))
+
+    def compiled():
+        programs = build_programs(cfg, ecfg, None)
+        if program == "prefill_insert":
+            return programs.prefill_insert.lower(
+                params, *cache, *tokens, i32, i32, arg(jnp.uint32, 2),
+                arg(jnp.float32), arg(jnp.float32), i32).compile()
+        return programs.extend_nosample.lower(params, *cache, *tokens, i32, i32).compile()
+
+    def scores(text):
+        """Results of heads × queries × rows elements that are float32, or
+        have the queries and the rows among their dimensions."""
+        return {what[:40] for ln in text.splitlines()
+                if (dims := result_dims(ln)) and int(np.prod(dims)) == H * T * S
+                and ((what := ln.split(" = ")[1].lstrip("(")).startswith("f32[")
+                     or sorted(d for d in dims if d in (T, S)) == sorted((T, S)))}
+
+    blocked = compiled()
+    text = blocked.as_text()
+    assert attn.prefill_kernel_on(T, S, 128)
+    assert re.search(r"%prefill_attention[.\d]* = \S+ custom-call\(", text)
+    assert not scores(text), scores(text)
+    if cell != "judge-batch":
+        return  # one compile of the einsums is enough to show what the check finds
+    monkeypatch.setenv("OMNIA_PALLAS_DECODE", "0")
+    attn._pallas_decode_mode.cache_clear()
+    einsum = compiled()
+    assert any(found.startswith("f32[") for found in scores(einsum.as_text()))
+    saved = (einsum.memory_analysis().temp_size_in_bytes
+             - blocked.memory_analysis().temp_size_in_bytes)
+    assert saved > 0.5 * H * T * S * 4, saved
+
+
 def test_tp4_engine_decode_chunk_compiles_sharded(tp4_mesh, kernel_route_on):
     """The engine's real decode program (the scan of decode_chunk steps)
     on a dp=1 × tp=4 mesh of described devices, operands sharded by the
@@ -653,3 +742,43 @@ def test_tp4_engine_decode_chunk_compiles_sharded(tp4_mesh, kernel_route_on):
         for x in jax.tree.leaves((params, ck, cv))
     )
     assert mem.argument_size_in_bytes < 0.3 * whole
+
+
+def test_tp4_engine_prefill_insert_keeps_the_einsums(tp4_mesh, kernel_route_on):
+    """The fresh-prefill program of a tp=4 engine at a bucket of whole tiles
+    and heads 128 wide, the route on: `build_programs` hands the model its
+    mesh, so the chunk's attention stays the einsums that XLA partitions over
+    the heads (the blocked kernel is a Mosaic call, which it cannot: the
+    program would not compile, or would gather the heads onto every chip),
+    and `prefill_blocked` says so. One chip's program of the same shape holds
+    the kernel."""
+    import dataclasses
+
+    from omnia_tpu.engine.family import prefill_blocked
+
+    cfg, T = dataclasses.replace(get_config("llama3-8b"), num_layers=2), 256
+    rep = NamedSharding(tp4_mesh, P())
+
+    def lowered(ecfg, mesh, sharding_for, rep):
+        def arg(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+        params, ck, cv = _model_operands(cfg, sharding_for)
+        return build_programs(cfg, ecfg, mesh).prefill_insert.lower(
+            params, ck, cv, arg(jnp.int32, 1, T), arg(jnp.int32, 1, T),
+            arg(jnp.int32), arg(jnp.int32), arg(jnp.uint32, 2),
+            arg(jnp.float32), arg(jnp.float32), arg(jnp.int32))
+
+    kinds = dict(num_slots=B, max_seq=S, max_sessions=0, prefill_buckets=(T,))
+    ecfg = EngineConfig(tp=4, **kinds)
+    assert not prefill_blocked(cfg, ecfg, tp4_mesh, T, fresh=True)
+    assert not prefill_blocked(cfg, ecfg, tp4_mesh, T, fresh=False)
+    text = lowered(ecfg, tp4_mesh, lambda spec: NamedSharding(tp4_mesh, spec),
+                   rep).compile().as_text()
+    assert "prefill_attention" not in text and "tpu_custom_call" not in text
+    assert len(collective_lines(text).get("all-reduce", [])) >= 2
+
+    ecfg = EngineConfig(**kinds)
+    one = SingleDeviceSharding(tp4_mesh.devices.flat[0])
+    assert prefill_blocked(cfg, ecfg, None, T, fresh=True)
+    assert "prefill_attention" in lowered(ecfg, None, lambda _spec: one, one).as_text()
