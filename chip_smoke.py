@@ -250,7 +250,7 @@ def decode_program_text(engine) -> str:
 
 def collective_lines(text: str) -> dict[str, list[str]]:
     """Compiled-HLO lines of each collective, keyed by its op name (also
-    what tests/test_tpu_compile.py reads a described-chip compile with)."""
+    what tests/chipless/ reads a described-chip compile with)."""
     out: dict[str, list[str]] = {}
     for line in text.splitlines():
         m = re.search(

@@ -75,10 +75,12 @@ def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
 
     from_env = cache_dir is None and "JAX_COMPILATION_CACHE_DIR" in os.environ
     if cache_dir is None and not from_env and jax.default_backend() == "cpu":
-        # CPU runs (tests, dev) don't pay a meaningful compile bill, and
-        # XLA:CPU AOT cache entries are machine-feature-pinned — reloading
-        # them across feature-detection differences risks SIGILL. Name a
-        # directory to cache on CPU.
+        # A CPU backend gets no *default* directory: XLA:CPU cache entries
+        # are pinned to the machine's CPU features, and reading them back
+        # where feature detection differs risks SIGILL, so nothing is
+        # written where another machine may find it. Name a directory to
+        # cache on the CPU: the tests do (tests/conftest.py, a fresh one
+        # a run, since every engine a test recompiles the same programs).
         return None
     cache_dir = cache_dir or default_cache_dir()
     _require_writable(cache_dir)

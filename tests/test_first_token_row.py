@@ -44,7 +44,8 @@ def _family(name):
     cfg = get_config(preset)
     if name == "llama-tied":
         cfg = dataclasses.replace(cfg, tie_embeddings=True)
-    params = model_module(cfg).init_params(cfg, jax.random.key(3), dtype=jnp.float32)
+    params = jax.jit(lambda key: model_module(cfg).init_params(cfg, key, dtype=jnp.float32))(
+        jax.random.key(3))
     if name == "llama-int8-head":
         params = quantize_params(params, cfg, "int8")
     return cfg, params
@@ -68,7 +69,7 @@ def family(request):
         "forward": lambda **kw: model.forward(
             params, cfg, tokens, pos, *cache, start, **kw)[0],
     }
-    every_row = {k: np.asarray(f()) for k, f in entries.items()}
+    every_row = {k: np.asarray(jax.jit(f)()) for k, f in entries.items()}
     # The row is an operand: one compile an entry serves both rows.
     one_row = {k: jax.jit(lambda r, f=f: f(row=r)) for k, f in entries.items()}
     return cfg, one_row, every_row

@@ -12,6 +12,7 @@ is 1e-5 of the reference's logit range (readings here are 1e-7 to 5e-7), and
 every planted fault has to move the number named for it by a hundred times
 that."""
 import dataclasses
+import functools
 import importlib.util
 import os
 import sys
@@ -72,18 +73,18 @@ def file_of(cfg) -> dict:
     }
 
 
-def served_logits(params, cfg, tokens, placement, rows: int = 128, pad_is_real=False):
-    """The prompt placed into a fresh cache piece by piece (a padded piece
-    names its last real row, as engine/programs.py::extend does), then one
-    token a step through the cache: float32 [T, V]. Each piece gives the
-    logits of its real rows."""
-    cache = llama.init_kv_cache(cfg, 1, rows, dtype=params["embed"].dtype)
-
-    def step(p, c, toks, start):
+def _programs():
+    """`step`, `piece`, `train` and `fresh` under `jax.jit`, the configuration
+    a static argument: new functions a call, so traced anew. SOUND is the set
+    every case on the sound path shares (a configuration, a placement's
+    shapes and `pad_is_real` each compile once a module); a case that patches
+    a function of the model, or routes the kernels, makes its own, because
+    the shared set would hand it the trace of the sound path."""
+    def step(p, c, toks, start, *, cfg):
         pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
         return llama.forward(p, cfg, toks, pos, *c, jnp.reshape(start, (1,)))
 
-    def piece(p, c, toks, start, last):
+    def piece(p, c, toks, start, last, *, cfg, pad_is_real):
         """Every row's logits, the cache written as a placement writes it."""
         pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
         every, *_ = llama.forward(p, cfg, toks, pos, *c, jnp.reshape(start, (1,)))
@@ -91,24 +92,55 @@ def served_logits(params, cfg, tokens, placement, rows: int = 128, pad_is_real=F
                               row=None if pad_is_real else last)
         return every, *c
 
-    step, piece = jax.jit(step), jax.jit(piece)
+    def train(p, toks, *, cfg):
+        """The uncached forward over the whole sequence."""
+        return llama.forward_train(p, cfg, toks)
+
+    def fresh(p, toks, row, *, cfg):
+        """`forward_prefill` over a padded bucket whose last real row is `row`."""
+        return llama.forward_prefill(p, cfg, toks,
+                                     jnp.arange(toks.shape[1], dtype=jnp.int32)[None], row=row)
+
+    return {"step": jax.jit(step, static_argnames="cfg"),
+            "piece": jax.jit(piece, static_argnames=("cfg", "pad_is_real")),
+            "train": jax.jit(train, static_argnames="cfg"),
+            "fresh": jax.jit(fresh, static_argnames="cfg")}
+
+
+SOUND = _programs()
+
+
+def served_logits(params, cfg, tokens, placement, rows: int = 128, pad_is_real=False,
+                  programs=SOUND):
+    """The prompt placed into a fresh cache piece by piece (a padded piece
+    names its last real row, as engine/programs.py::extend does), then one
+    token a step through the cache: float32 [T, V]. Each piece gives the
+    logits of its real rows."""
+    cache = llama.init_kv_cache(cfg, 1, rows, dtype=params["embed"].dtype)
+    step, piece = programs["step"], programs["piece"]
     out, at = [], 0
     for take, bucket in placement:
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :take] = tokens[at:at + take]
         logits, *cache = piece(params, cache, jnp.asarray(toks), jnp.int32(at),
-                               jnp.int32(take - 1))
+                               jnp.int32(take - 1), cfg=cfg, pad_is_real=pad_is_real)
         out.append(np.asarray(logits[0, :take], np.float32))
         at += take
     for t in range(at, len(tokens)):
-        logits, *cache = step(params, cache, jnp.asarray(tokens[None, t:t + 1]), jnp.int32(t))
+        logits, *cache = step(params, cache, jnp.asarray(tokens[None, t:t + 1]), jnp.int32(t),
+                              cfg=cfg)
         out.append(np.asarray(logits[0], np.float32))
     return np.concatenate(out)
 
 
+@functools.partial(jax.jit, static_argnames="cfg")
+def seeded_params(key, *, cfg=CFG):
+    return llama.init_params(cfg, key, dtype=jnp.float32)
+
+
 @pytest.fixture(scope="module")
 def seeded():
-    params = llama.init_params(CFG, jax.random.key(0), dtype=jnp.float32)
+    params = seeded_params(jax.random.key(0))
     tokens = np.random.default_rng(0).integers(0, CFG.vocab_size, PREFILL + DECODE)
     tokens = tokens.astype(np.int32)
     sizes = reference_sizes(CFG, file_of(CFG))
@@ -121,15 +153,16 @@ def over_range(got, want):
 
 
 def numbers(seeded, cfg=CFG, params=None, placement="pieces, the last padded",
-            pad_is_real=False) -> dict:
+            pad_is_real=False, programs=SOUND) -> dict:
     """The three numbers a fault is caught by, each a largest |logit
     difference| as a share of the reference's logit range: the uncached
     forward (`train`), and the prompt's positions and the decode positions
     through the cache."""
     own, tokens, _, want = seeded
     params = own if params is None else params
-    got = served_logits(params, cfg, tokens, PLACEMENTS[placement], pad_is_real=pad_is_real)
-    train = np.asarray(llama.forward_train(params, cfg, jnp.asarray(tokens[None]))[0])
+    got = served_logits(params, cfg, tokens, PLACEMENTS[placement], pad_is_real=pad_is_real,
+                        programs=programs)
+    train = np.asarray(programs["train"](params, jnp.asarray(tokens[None]), cfg=cfg)[0])
     return {"train": over_range(train, want),
             "prefill": over_range(got[:PREFILL], want[:PREFILL]),
             "decode": over_range(got[PREFILL:], want[PREFILL:])}
@@ -161,7 +194,7 @@ def test_the_seeded_selection_bias_is_one_set_of_values_in_a_seeded_order():
     ranks, held = CFG.num_experts // CFG.experts_held, CFG.experts_held
     orders = []
     for seed in (0, 2965719344 & 0x7FFFFFFF):
-        params = llama.init_params(CFG, jax.random.key(seed), dtype=jnp.float32)
+        params = seeded_params(jax.random.key(seed))
         for stack in params["layers"]:
             if "bias" not in stack["mlp"]:
                 continue
@@ -196,18 +229,15 @@ def test_a_fresh_prefill_returns_the_rings_it_would_have_written(seeded):
     n, bucket = 21, 32
     toks = np.zeros((1, bucket), np.int32)
     toks[0, :n] = tokens[:n]
-    last, k, v, rk, rv = llama.forward_prefill(
-        params, CFG, jnp.asarray(toks), jnp.arange(bucket, dtype=jnp.int32)[None],
-        row=jnp.int32(n - 1))
+    last, k, v, rk, rv = SOUND["fresh"](params, jnp.asarray(toks), jnp.int32(n - 1), cfg=CFG)
     assert over_range(np.asarray(last[0]), want[n - 1]) <= TOL
     assert k.shape == (1, 1, bucket, 2, 16) and rk.shape == (2, 1, 8, 2, 16)
     cache = [jax.lax.dynamic_update_slice(c, chunk, (0,) * 5)
              for c, chunk in zip(llama.init_kv_cache(CFG, 1, 128, dtype=jnp.float32),
                                  (k, v, rk, rv))]
     for t in range(n, n + 12):
-        logits, *cache = llama.forward(
-            params, CFG, jnp.asarray(tokens[None, t:t + 1]), jnp.full((1, 1), t, jnp.int32),
-            *cache, jnp.asarray([t], jnp.int32))
+        logits, *cache = SOUND["step"](params, cache, jnp.asarray(tokens[None, t:t + 1]),
+                                       jnp.int32(t), cfg=CFG)
         assert over_range(np.asarray(logits[0, 0]), want[t]) <= TOL, t
 
 
@@ -278,7 +308,8 @@ def test_a_planted_fault_fails_by_a_hundred_tolerances(seeded, fault, monkeypatc
     if patch:
         monkeypatch.setattr(*patch)
     params = change(seeded[0]) if change else None
-    got = numbers(seeded, dataclasses.replace(CFG, **replace), params, pad_is_real=pad_is_real)
+    got = numbers(seeded, dataclasses.replace(CFG, **replace), params, pad_is_real=pad_is_real,
+                  programs=_programs() if patch else SOUND)
     assert got[number] >= 100 * TOL, (fault, got)
 
 
@@ -350,7 +381,7 @@ def test_the_window_kernel_equals_the_einsum_and_reads_no_other_tenants_row(inte
 def test_decode_through_the_kernels_agrees_with_the_reference(seeded, interpreted):
     """Both decode kernels interpreted (the full layer's
     `decode_gqa_attention`, the window layers' `decode_window_attention`)."""
-    got = numbers(seeded, placement="one bucket")
+    got = numbers(seeded, placement="one bucket", programs=_programs())
     assert got["decode"] <= TOL, got
 
 
@@ -366,7 +397,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_equal_the_uncut_laye
     expert once, is the layer of a chip that holds all 8, which is the
     reference's uncut layer."""
     whole = dataclasses.replace(CFG, num_experts_held=0, expert_rank=0)
-    params = llama.init_params(whole, jax.random.key(4), dtype=jnp.float32)
+    params = seeded_params(jax.random.key(4), cfg=whole)
     stack = params["layers"][1]
     scanned, experts = moe.unstack_experts(stack)
     mlp = jax.tree_util.tree_map(lambda a: a[0], scanned["mlp"])

@@ -14,6 +14,7 @@ and every planted fault has to move the number named for it by a hundred
 times that. A state or a decay rounded to bfloat16 is among the faults: it
 reads 1e-3 and more."""
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -95,8 +96,45 @@ def file_of(cfg) -> dict:
     }
 
 
+def _programs():
+    """`step`, `piece`, `whole` and `fresh` under `jax.jit`, the configuration
+    a static argument: new functions a call, so traced anew. SOUND is the set
+    every case on the sound path shares (a configuration, a placement's
+    shapes and `pad_is_real` each compile once a module); a case that patches
+    a function of the model, or routes the kernels, makes its own, because
+    the shared set would hand it the trace of the sound path."""
+    def step(p, c, toks, start, *, cfg):
+        pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
+        return mla.forward(p, cfg, toks, pos, *c, jnp.reshape(start, (1,)))
+
+    def piece(p, c, toks, start, last, *, cfg, pad_is_real):
+        """Every row's logits, the cache written as a placement writes it."""
+        pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
+        every, *_ = mla.forward(p, cfg, toks, pos, *c, jnp.reshape(start, (1,)))
+        _, *c = mla.forward(p, cfg, toks, pos, *c, jnp.reshape(start, (1,)),
+                            row=None if pad_is_real else last)
+        return every, *c
+
+    def whole(p, toks, *, cfg):
+        """The uncached forward over the whole sequence."""
+        return mla.forward_prefill(p, cfg, toks, jnp.arange(toks.shape[1], dtype=jnp.int32)[None])
+
+    def fresh(p, toks, row, *, cfg):
+        """`whole` over a padded bucket whose last real row is `row`."""
+        return mla.forward_prefill(p, cfg, toks, jnp.arange(toks.shape[1], dtype=jnp.int32)[None],
+                                   row=row)
+
+    return {"step": jax.jit(step, static_argnames="cfg"),
+            "piece": jax.jit(piece, static_argnames=("cfg", "pad_is_real")),
+            "whole": jax.jit(whole, static_argnames="cfg"),
+            "fresh": jax.jit(fresh, static_argnames="cfg")}
+
+
+SOUND = _programs()
+
+
 def served_logits(params, cfg, tokens, placement, rows: int = 128, pad_is_real=False,
-                  between=None, poison=False):
+                  between=None, poison=False, programs=SOUND):
     """The prompt placed into a cache piece by piece (a padded piece names
     its last real row, as engine/programs.py::extend does), then one token a
     step through the cache: float32 [T, V]. Each piece gives the logits of
@@ -105,40 +143,33 @@ def served_logits(params, cfg, tokens, placement, rows: int = 128, pad_is_real=F
     cache = mla.init_kv_cache(cfg, 1, rows, dtype=params["embed"].dtype)
     if poison:
         cache = tuple(c + 3.0 for c in cache)
-
-    def step(p, c, toks, start):
-        pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
-        return mla.forward(p, cfg, toks, pos, *c, jnp.reshape(start, (1,)))
-
-    def piece(p, c, toks, start, last):
-        """Every row's logits, the cache written as a placement writes it."""
-        pos = start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
-        every, *_ = mla.forward(p, cfg, toks, pos, *c, jnp.reshape(start, (1,)))
-        _, *c = mla.forward(p, cfg, toks, pos, *c, jnp.reshape(start, (1,)),
-                            row=None if pad_is_real else last)
-        return every, *c
-
-    step, piece = jax.jit(step), jax.jit(piece)
+    step, piece = programs["step"], programs["piece"]
     out, at = [], 0
     for take, bucket in placement:
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :take] = tokens[at:at + take]
         logits, *cache = piece(params, cache, jnp.asarray(toks), jnp.int32(at),
-                               jnp.int32(take - 1))
+                               jnp.int32(take - 1), cfg=cfg, pad_is_real=pad_is_real)
         out.append(np.asarray(logits[0, :take], np.float32))
         at += take
         if between:
             cache = between(cache)
     for t in range(at, len(tokens)):
-        logits, *cache = step(params, cache, jnp.asarray(tokens[None, t:t + 1]), jnp.int32(t))
+        logits, *cache = step(params, cache, jnp.asarray(tokens[None, t:t + 1]), jnp.int32(t),
+                              cfg=cfg)
         out.append(np.asarray(logits[0], np.float32))
         if between:
             cache = between(cache)
     return np.concatenate(out)
 
 
+@functools.partial(jax.jit, static_argnames="cfg")
+def seeded_params(key, *, cfg):
+    return mla.init_params(cfg, key, dtype=jnp.float32)
+
+
 def _seeded(cfg):
-    params = mla.init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    params = seeded_params(jax.random.key(0), cfg=cfg)
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, PREFILL + DECODE)
     tokens = tokens.astype(np.int32)
     sizes = reference_sizes(cfg, file_of(cfg))
@@ -160,19 +191,25 @@ def over_range(got, want):
     return float(np.abs(got - want).max() / (want.max() - want.min()))
 
 
-def numbers(seeded, cfg=CFG, params=None, placement="pieces, the last padded", **how) -> dict:
+def numbers(seeded, cfg=CFG, params=None, placement="pieces, the last padded", programs=SOUND,
+            only=("whole", "prefill", "decode"), **how) -> dict:
     """The three numbers a fault is caught by, each a largest |logit
     difference| as a share of the reference's logit range: the uncached
     forward (`whole`: `forward_prefill` over the whole sequence), and the
-    prompt's positions and the decode positions through the cache."""
+    prompt's positions and the decode positions through the cache. A case
+    that judges one of them names it in `only`, and the path of the others
+    is not traced for it."""
     own, tokens, _, want = seeded
     params = own if params is None else params
-    got = served_logits(params, cfg, tokens, PLACEMENTS[placement], **how)
-    whole = np.asarray(mla.forward_prefill(
-        params, cfg, jnp.asarray(tokens[None]), jnp.arange(len(tokens), dtype=jnp.int32)[None])[0][0])
-    return {"whole": over_range(whole, want),
-            "prefill": over_range(got[:PREFILL], want[:PREFILL]),
-            "decode": over_range(got[PREFILL:], want[PREFILL:])}
+    out = {}
+    if "whole" in only:
+        whole = np.asarray(programs["whole"](params, jnp.asarray(tokens[None]), cfg=cfg)[0][0])
+        out["whole"] = over_range(whole, want)
+    if "prefill" in only or "decode" in only:
+        got = served_logits(params, cfg, tokens, PLACEMENTS[placement], programs=programs, **how)
+        out["prefill"] = over_range(got[:PREFILL], want[:PREFILL])
+        out["decode"] = over_range(got[PREFILL:], want[PREFILL:])
+    return out
 
 
 # -- (a) the program against the reference ------------------------------------
@@ -224,7 +261,7 @@ def test_the_seeded_decay_lies_where_a_trained_models_does():
     log-uniform in 1e-3 to 0.1: a token's decay at a zero input is between
     exp(-1.6) = 0.2 and 0.999, never near 0 (which would empty the state
     every token)."""
-    params = mla.init_params(CFG, jax.random.key(5), dtype=jnp.float32)
+    params = seeded_params(jax.random.key(5), cfg=CFG)
     for stack in params["layers"][:2]:
         a = stack["attn"]
         rate = np.exp(np.asarray(a["a_log"]))
@@ -236,7 +273,7 @@ def test_the_seeded_decay_lies_where_a_trained_models_does():
 
 
 def test_the_uncached_forward_agrees_with_the_reference(seeded):
-    assert numbers(seeded)["whole"] <= TOL
+    assert numbers(seeded, only=("whole",))["whole"] <= TOL
 
 
 @pytest.mark.parametrize("placement", list(PLACEMENTS))
@@ -245,7 +282,7 @@ def test_prefill_then_decode_through_the_cache_agrees_with_the_reference(seeded,
     inside a chunk and whose last is padded: the state and the three-row tail
     handed from piece to piece and on to 24 decode steps equal one pass over
     the whole sequence."""
-    got = numbers(seeded, placement=placement)
+    got = numbers(seeded, placement=placement, only=("prefill", "decode"))
     assert got["prefill"] <= TOL and got["decode"] <= TOL, got
 
 
@@ -263,9 +300,7 @@ def test_a_fresh_prefill_returns_the_state_it_would_have_written(seeded):
     n, bucket = 21, 32
     toks = np.zeros((1, bucket), np.int32)
     toks[0, :n] = tokens[:n]
-    last, rows, states, tails = mla.forward_prefill(
-        params, CFG, jnp.asarray(toks), jnp.arange(bucket, dtype=jnp.int32)[None],
-        row=jnp.int32(n - 1))
+    last, rows, states, tails = SOUND["fresh"](params, jnp.asarray(toks), jnp.int32(n - 1), cfg=CFG)
     assert over_range(np.asarray(last[0]), want[n - 1]) <= TOL
     assert rows.shape == (1, 1, bucket, 128) and states.shape == (3, 1, 4, 16, 16)
     assert tails.shape == (3, 1, 3, 192)
@@ -273,9 +308,8 @@ def test_a_fresh_prefill_returns_the_state_it_would_have_written(seeded):
              for c, chunk in zip(mla.init_kv_cache(CFG, 1, 128, dtype=jnp.float32),
                                  (rows, states, tails))]
     for t in range(n, n + 12):
-        logits, *cache = mla.forward(
-            params, CFG, jnp.asarray(tokens[None, t:t + 1]), jnp.full((1, 1), t, jnp.int32),
-            *cache, jnp.asarray([t], jnp.int32))
+        logits, *cache = SOUND["step"](params, cache, jnp.asarray(tokens[None, t:t + 1]),
+                                       jnp.int32(t), cfg=CFG)
         assert over_range(np.asarray(logits[0, 0]), want[t]) <= TOL, t
 
 
@@ -401,7 +435,7 @@ def interpreted(monkeypatch):
 def test_decode_through_the_kernels_agrees_with_the_reference(seeded, interpreted):
     """Both decode kernels interpreted (the latent layer's
     `decode_mla_attention`, the linear-attention layers' `decode_kda_state`)."""
-    got = numbers(seeded, placement="one bucket")
+    got = numbers(seeded, placement="one bucket", programs=_programs(), only=("decode",))
     assert got["decode"] <= TOL, got
 
 
@@ -504,7 +538,7 @@ def test_a_planted_fault_fails_by_a_hundred_tolerances(seeded, seeded_kmkm, faul
     for patch in patches:
         monkeypatch.setattr(*patch)
     got = numbers(seeded_kmkm if cfg is CFG_KMKM else seeded, dataclasses.replace(cfg, **replace),
-                  **how)
+                  programs=_programs() if patches else SOUND, only=(number,), **how)
     assert got[number] >= 100 * TOL, (fault, got)
 
 
@@ -517,7 +551,7 @@ def test_a_state_or_a_decay_in_bfloat16_fails_the_tolerance(seeded, which, monke
     neither precision can pass for the other."""
     for patch in _patch_rule(which):
         monkeypatch.setattr(*patch)
-    got = numbers(seeded)
+    got = numbers(seeded, programs=_programs(), only=("prefill", "decode"))
     assert got["decode"] >= 10 * TOL and got["prefill"] >= 10 * TOL, (which, got)
 
 
@@ -537,6 +571,7 @@ def test_a_dead_slots_decode_step_leaves_its_state_and_tail_alone(seeded, monkey
     cache = tuple(c + 1.0 for c in mla.init_kv_cache(CFG, 2, 32, dtype=jnp.float32))
     live = jnp.asarray([True, False])
 
+    @jax.jit
     def step(live):
         return mla.forward(params, CFG, jnp.asarray(tokens[:2, None]),
                            jnp.full((2, 1), 11, jnp.int32), *cache,
@@ -596,8 +631,7 @@ def test_decode_steps_between_a_placements_pieces_do_not_reach_its_state(seeded)
     for a, b in zip(plain[1:], mixed[1:]):               # slot 0's states and tails
         assert np.array_equal(np.asarray(a[:, 0]), np.asarray(b[:, 0]))
     # and they are a zero-started pass's, not the poisoned slot's
-    _, _, states, tails = mla.forward_prefill(
-        params, CFG, jnp.asarray(tokens[None, :25]), jnp.arange(25, dtype=jnp.int32)[None])
+    _, _, states, tails = SOUND["whole"](params, jnp.asarray(tokens[None, :25]), cfg=CFG)
     np.testing.assert_allclose(np.asarray(plain[1][:, 0]), np.asarray(states[:, 0]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(plain[2][:, 0]), np.asarray(tails[:, 0]), atol=1e-5)
 
@@ -614,7 +648,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_equal_the_uncut_laye
     expert once, is the layer of a chip that holds all 8, which is the
     reference's uncut layer."""
     whole = dataclasses.replace(CFG, num_experts_held=0, expert_rank=0)
-    params = mla.init_params(whole, jax.random.key(4), dtype=jnp.float32)
+    params = seeded_params(jax.random.key(4), cfg=whole)
     stack = params["layers"][1]
     scanned, experts = moe.unstack_experts(stack)
     mlp = jax.tree_util.tree_map(lambda a: a[0], scanned["mlp"])

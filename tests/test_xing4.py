@@ -65,23 +65,41 @@ def reference_sizes(cfg) -> dict:
     }
 
 
-def served_logits(params, cfg, tokens, prefill: int, rows: int = 128):
+def _step():
+    """One call of `mla.forward` through the cache under `jax.jit`, the
+    configuration a static argument: a new function a call, so traced anew.
+    SOUND is the one every case on the sound path shares (a configuration
+    compiles once a module); a case that replaces a function of the model
+    makes its own, because SOUND would hand it the trace of the sound path."""
+    def step(p, c, toks, start, *, cfg):
+        return mla.forward(p, cfg, toks, start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None],
+                           *c, jnp.reshape(start, (1,)))
+    return jax.jit(step, static_argnames="cfg")
+
+
+SOUND = _step()
+
+
+def served_logits(params, cfg, tokens, prefill: int, rows: int = 128, step=SOUND):
     """Prefill of `prefill` tokens into a fresh cache, then one token a
     step through it: float32 [T, V]."""
     cache = mla.init_kv_cache(cfg, 1, rows, dtype=params["embed"].dtype)
-    step = jax.jit(lambda p, c, toks, start: mla.forward(
-        p, cfg, toks, start + jnp.arange(toks.shape[1], dtype=jnp.int32)[None], *c,
-        jnp.reshape(start, (1,))))
     out = []
     for lo, hi in [(0, prefill)] + [(t, t + 1) for t in range(prefill, len(tokens))]:
-        logits, *cache = step(params, cache, jnp.asarray(tokens[None, lo:hi]), jnp.int32(lo))
+        logits, *cache = step(params, cache, jnp.asarray(tokens[None, lo:hi]), jnp.int32(lo),
+                              cfg=cfg)
         out.append(np.asarray(logits[0], np.float32))
     return np.concatenate(out)
 
 
+@jax.jit
+def seeded_params(key):
+    return mla.init_params(CFG, key, dtype=jnp.float32)
+
+
 @pytest.fixture(scope="module")
 def seeded():
-    params = mla.init_params(CFG, jax.random.key(0), dtype=jnp.float32)
+    params = seeded_params(jax.random.key(0))
     tokens = np.random.default_rng(0).integers(0, CFG.vocab_size, PREFILL + DECODE)
     tokens = tokens.astype(np.int32)
     sizes = reference_sizes(CFG)
@@ -93,7 +111,7 @@ def over_range(got, want):
     return float(np.abs(got - want).max() / (want.max() - want.min()))
 
 
-def numbers(seeded, cfg=CFG, layers: bool = True) -> dict:
+def numbers(seeded, cfg=CFG, layers: bool = True, step=SOUND) -> dict:
     """The three numbers a fault is caught by, each a largest |logit
     difference| as a share of the reference's logit range: the whole model's
     prefill positions, its decode positions through the cache, and
@@ -102,7 +120,7 @@ def numbers(seeded, cfg=CFG, layers: bool = True) -> dict:
     from harness import correct
 
     params, tokens, sizes, want, residual, _ = seeded
-    got = served_logits(params, cfg, tokens, PREFILL)
+    got = served_logits(params, cfg, tokens, PREFILL, step=step)
     out = {"prefill": over_range(got[:PREFILL], want[:PREFILL]),
            "decode": over_range(got[PREFILL:], want[PREFILL:])}
     if not layers:
@@ -114,7 +132,7 @@ def numbers(seeded, cfg=CFG, layers: bool = True) -> dict:
         first, count, cut = correct.cut_layers(order, layer, 1)
         sub = correct._sub_model(params, residual[layer], first, count, jnp.float32)
         alone = np.asarray(ref.forward(sub, {**sizes, "layer_order": cut}, jnp.asarray(positions)))
-        one = served_logits(sub, mla.with_layer_order(cfg, cut), positions, PREFILL)
+        one = served_logits(sub, mla.with_layer_order(cfg, cut), positions, PREFILL, step=step)
         out["layers"] = max(out["layers"], over_range(one, alone))
     return out
 
@@ -151,7 +169,7 @@ def test_a_cut_model_may_leave_a_stack_with_no_layer():
     assert mla.layer_order(pair) == ((0, 0), (1, 0))
     with pytest.raises(ValueError, match="dense layers first"):
         mla.with_layer_order(CFG, ((1, 0), (0, 0)))
-    whole = mla.init_params(CFG, jax.random.key(1), dtype=jnp.float32)
+    whole = seeded_params(jax.random.key(1))
     for cut, kept in ((one_sparse, (0, 1)), (one_dense, (1, 0))):
         stacks = [jax.tree.map(lambda a, n=n: a[:n], stack)
                   for stack, n in zip(whole["layers"], kept)]
@@ -159,8 +177,8 @@ def test_a_cut_model_may_leave_a_stack_with_no_layer():
         assert stacks[kept.index(0)]["ln1"].shape == (0, 64)
         (cache,) = mla.init_kv_cache(cut, 1, 32, dtype=jnp.float32)
         tokens = jnp.arange(8, dtype=jnp.int32)[None]
-        logits, cache, counts = mla.forward(params, cut, tokens, tokens, cache,
-                                            jnp.zeros((1,), jnp.int32), counters=True)
+        logits, cache, counts = jax.jit(lambda p, c: mla.forward(
+            p, cut, tokens, tokens, c, jnp.zeros((1,), jnp.int32), counters=True))(params, cache)
         assert logits.shape == (1, 8, 256) and cache.shape[0] == 1
         assert (int(counts[0]) > 0) == (cut is one_sparse)   # only a sparse layer counts
 
@@ -173,9 +191,9 @@ def test_prefill_then_decode_equals_the_reference_float32(seeded):
     params, tokens, sizes, want, residual, (margin, sigma) = seeded
     sound = numbers(seeded)
     assert max(sound.values()) < TOL, sound
-    fresh, chunk = mla.forward_prefill(
-        params, CFG, jnp.asarray(tokens[None, :PREFILL]),
-        jnp.arange(PREFILL, dtype=jnp.int32)[None])
+    fresh, chunk = jax.jit(lambda p, t: mla.forward_prefill(
+        p, CFG, t, jnp.arange(PREFILL, dtype=jnp.int32)[None]))(
+            params, jnp.asarray(tokens[None, :PREFILL]))
     assert over_range(np.asarray(fresh[0]), want[:PREFILL]) < TOL
     assert chunk.shape == (CFG.num_layers, 1, PREFILL, 128)
     assert residual.shape == (4, len(tokens), 4 * CFG.hidden_size)
@@ -242,7 +260,7 @@ def test_each_planted_fault_fails_by_a_named_number(fault, seeded):
     layers = caught_by == "layers"
     if isinstance(plant, tuple):
         with replaced(*plant):
-            got = numbers(seeded, layers=layers)
+            got = numbers(seeded, layers=layers, step=_step())
     else:
         got = numbers(seeded, plant, layers=layers)
     assert got[caught_by] > 100 * TOL, (fault, got)
@@ -257,7 +275,8 @@ def test_the_counters_count_the_sparse_layers_only(seeded):
     (cache,) = mla.init_kv_cache(CFG, 3, 64, dtype=jnp.float32)
     tokens = jnp.asarray([[5], [9], [200]], jnp.int32)
     pos = jnp.zeros((3, 1), jnp.int32)
-    _, _, counts = mla.forward(params, CFG, tokens, pos, cache, pos[:, 0], counters=True)
+    _, _, counts = jax.jit(lambda p, c: mla.forward(
+        p, CFG, tokens, pos, c, pos[:, 0], counters=True))(params, cache)
     held, hit = (int(c) for c in counts)
     sparse = CFG.num_layers - CFG.num_dense_layers
     assert held == sparse * 3 * CFG.num_experts_per_tok     # every expert is held here
