@@ -379,7 +379,7 @@ class InferenceEngine(
             "pipeline_flushes": 0,
             "placements_deferred": 0,
             "programs_compiled_serving": 0,
-            "extend_steps": 0,
+            "extend_steps": 0, "extend_tokens": 0,  # (tokens placed in pieces)
             "prefill_tokens": 0, "prefill_tokens_blocked": 0,
             "prefix_reuse_tokens": 0,
             "session_offloads": 0,

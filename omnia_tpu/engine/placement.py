@@ -516,6 +516,7 @@ class _PlacementMixin:
         if self._flight is not None and rid:
             self._flight.note_prefill_piece(rid, take, b, time.monotonic() - t0)
         self.metrics["extend_steps"] += len(pieces)
+        self.metrics["extend_tokens"] += len(prompt) - reuse
         self.metrics["prefill_tokens_blocked"] += sum(
             take for _, take, b in pieces if self._blocked(b, False))
         return first_tok, new_kd

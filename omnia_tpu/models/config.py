@@ -102,8 +102,22 @@ class ModelConfig:
     # False: a full-attention layer applies no rotary position (window
     # layers always rotate; in the latent family the latent layers are the
     # full ones, and q_rope, k_rope are used as projected). True with no
-    # window layer is every model before.
+    # window layer is every model before. True with window layers: a full
+    # layer rotates too, by the window layers' table unless rope_full_yarn
+    # gives the full layers one of their own.
     rope_on_full_layers: bool = True
+    # A rotary table a kind of attention layer in the pair family's stacks
+    # (a source whose rope_parameters has a group for each of layer_types'
+    # words). Window layers rotate by plain RoPE at rope_theta. Full layers
+    # rotate at the same rope_theta by YaRN, where this is its hashable
+    # tuple (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, attention_factor): frequencies blended between kept and
+    # divided by factor (ops/rope.py::yarn_inv_freq), and cos and sin both
+    # times attention_factor, the transformers convention, so a full
+    # layer's q.k carries its square (the latent family's rope_yarn folds
+    # its magnitude into the softmax scale instead). None = the full
+    # layers take the window layers' table.
+    rope_full_yarn: Optional[tuple] = None
     # RMSNorm over each query and key head's head_dim values, one gain for
     # all heads, before any rotation.
     qk_norm: bool = False
@@ -410,6 +424,31 @@ PRESETS: dict[str, ModelConfig] = {
         kda_head_dim=16,
         kda_conv_kernel=4,
         kda_gate_rank=8,
+    ),
+    # The pair family with sparse stacks only and a rotary table a kind:
+    # S S S F, rings of 8 rows, every layer a softmax router's greedy top-2
+    # of 8 experts, all held, no shared expert and no dense layer; QK-norm;
+    # the full layer rotates by YaRN with cos and sin scaled.
+    "test-tiny-yarn": ModelConfig(
+        name="test-tiny-yarn",
+        vocab_size=256,
+        hidden_size=64,
+        num_layers=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        ffn_hidden_size=128,
+        rope_theta=10000.0,
+        rms_norm_eps=1e-6,
+        num_experts=8,
+        num_experts_per_tok=2,
+        max_seq_len=512,
+        moe_ffn_hidden_size=32,
+        layer_types=("sliding_attention", "sliding_attention", "sliding_attention",
+                     "full_attention"),
+        sliding_window=8,
+        qk_norm=True,
+        rope_full_yarn=(16.0, 64, 32.0, 1.0, 1.2772588722239782),
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

@@ -59,6 +59,7 @@ from omnia_tpu.models.stacks import (  # noqa: F401  (the module contract's name
     is_stacked,
     layer_order,
     ring_rows,
+    rope_tables,
     stack_kinds,
     with_layer_order,
 )
@@ -350,13 +351,18 @@ def _layer(x, p, cfg: ModelConfig, cos, sin, q_positions, ck, cv, write_start,
 
 
 def _embed(params, cfg: ModelConfig, tokens, q_positions):
-    """Token embeddings and the rotary tables of their positions."""
+    """Token embeddings and the rotary tables of their positions, made once
+    a program: → (x, rope), ``rope`` the pair (cos, sin), or for a model of
+    several kinds of layers a pair for each attention kind that rotates
+    (``rope_tables``)."""
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
+        if is_stacked(cfg):
+            return x, rope_tables(cfg, q_positions)
         cos, sin = rope_cos_sin(
             q_positions, cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
         )
-    return x, cos, sin
+    return x, (cos, sin)
 
 
 def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None,
@@ -376,11 +382,12 @@ def forward_prefill(params, cfg: ModelConfig, tokens, q_positions, attn_fn=None,
     the ring's shape [Lw, B, R, Hkv, D]: the last R real rows where the
     ring holds them.
     """
-    x, cos, sin = _embed(params, cfg, tokens, q_positions)
+    x, rope = _embed(params, cfg, tokens, q_positions)
     if is_stacked(cfg):
-        x, chunks, _ = _run_stacks(params, cfg, x, cos, sin, q_positions, None, None,
+        x, chunks, _ = _run_stacks(params, cfg, x, rope, q_positions, None, None,
                                    row, mesh, None)
         return (_logits_at(params, cfg, x, row), *chunks)
+    cos, sin = rope
 
     def body(x, p):
         x, k, v = _layer(
@@ -471,13 +478,14 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, *cache_and_start,
     move are the new rows.
     """
     *cache, write_start = cache_and_start
-    x, cos, sin = _embed(params, cfg, tokens, q_positions)  # x [B,T,D]
+    x, rope = _embed(params, cfg, tokens, q_positions)  # x [B,T,D]
     if is_stacked(cfg):
-        x, cache, counts = _run_stacks(params, cfg, x, cos, sin, q_positions,
+        x, cache, counts = _run_stacks(params, cfg, x, rope, q_positions,
                                        tuple(cache), write_start, row, mesh, live)
         out = (_logits_at(params, cfg, x, row), *cache)
         return (*out, counts) if counters else out
     cache_k, cache_v = cache
+    cos, sin = rope
 
     def body(carry, scanned):
         x, ck, cv = carry
@@ -505,14 +513,14 @@ def forward_embed(params, cfg: ModelConfig, tokens, mask, mesh=None):
     """
     B, T = tokens.shape
     q_positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-    x, cos, sin = _embed(params, cfg, tokens, q_positions)
+    x, rope = _embed(params, cfg, tokens, q_positions)
 
     def body(x, p):
-        x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None, mesh=mesh)
+        x, _, _ = _layer(x, p, cfg, *rope, q_positions, None, None, None, mesh=mesh)
         return x, None
 
     if is_stacked(cfg):
-        x = _run_stacks(params, cfg, x, cos, sin, q_positions, None, None, None,
+        x = _run_stacks(params, cfg, x, rope, q_positions, None, None, None,
                         mesh, None)[0]
     else:
         x, _ = jax.lax.scan(body, x, params["layers"])
@@ -529,16 +537,16 @@ def forward_train(params, cfg: ModelConfig, tokens):
     """
     B, T = tokens.shape
     q_positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
-    x, cos, sin = _embed(params, cfg, tokens, q_positions)
+    x, rope = _embed(params, cfg, tokens, q_positions)
     # Differentiated through: the einsums whatever the route (the blocked
     # kernel of ops/prefill_attention.py is a Pallas call, which has no VJP).
     if is_stacked(cfg):
         return _logits(params, cfg, _run_stacks(
-            params, cfg, x, cos, sin, q_positions, None, None, None, None, None,
+            params, cfg, x, rope, q_positions, None, None, None, None, None,
             attn_fn=einsum_attention)[0])
 
     def body(x, p):
-        x, _, _ = _layer(x, p, cfg, cos, sin, q_positions, None, None, None,
+        x, _, _ = _layer(x, p, cfg, *rope, q_positions, None, None, None,
                          attn_fn=einsum_attention)
         return x, None
 

@@ -1,23 +1,31 @@
 """Layers of several kinds in the pair family (models/llama.py dispatches
-here for a model with window layers, a share of the routed experts or
+here for a model with window layers, experts of ``moe_ffn_hidden_size`` or
 leading dense layers): stacks, runs and rings.
 
 A layer has an attention kind, *window*
 (``cfg.layer_types`` "sliding_attention": a query sees its own row and the
 ``sliding_window`` - 1 before it) or *full*, and an FFN kind, *dense* (SwiGLU
 of ``ffn_hidden_size``) or *sparse* (``ops/moe.py::expert_ffn``: the sigmoid
-or softmax router over all experts, the held share through ``moe_dropless``,
-the shared expert, as models/mla.py runs it). ``params["layers"]`` is a
-sequence of stacks, one for each kind the model has (``stack_kinds``), and
-``layer_order`` / ``with_layer_order`` state and cut the order as
-benchmark/README.md sets out (models/kinds.py, which the latent family's
-kinds go through too). A forward pass is one ``lax.scan`` for each run
+or softmax router over all experts, the experts held here, all of them or a
+rank's share, through ``moe_dropless``, and the shared expert where the model
+has one, as models/mla.py runs it). ``params["layers"]`` is a
+sequence of stacks, one for each kind the model has (``stack_kinds``): a
+model whose every layer is sparse has sparse stacks only, and nothing here
+asks for a dense one. ``layer_order`` / ``with_layer_order`` state and cut
+the order as benchmark/README.md sets out (models/kinds.py, which the latent
+family's kinds go through too). A forward pass is one ``lax.scan`` for each run
 of consecutive layers of one kind, under the scope ``stack.<kind>``, over the
 run's indices into its stack: the stack's leaves are read a layer at a time
 where they lie, the routed experts' never sliced at all. With ``cfg.qk_norm``
 every query and key head is RMS-normed (one gain ``[head_dim]``) before any
-rotation (``attn.qk_norm``); a full layer of a model with
-``rope_on_full_layers`` false is not rotated at all.
+rotation (``attn.qk_norm``).
+
+*A rotary table a kind of attention layer* (``rope_tables``, made once a
+program under ``rope.tables``; a layer turns its q and k by its kind's under
+``attn.rope``): window layers by plain RoPE at ``rope_theta``; full layers by
+the same table, by one of their own where ``cfg.rope_full_yarn`` states it
+(YaRN's blended frequencies, cos and sin times the attention factor), or,
+``rope_on_full_layers`` false, not at all.
 
 *The cache of a model with window layers is four arrays*: K and V of the
 full layers ``[Lf, B, S, Hkv, D]`` (row s = position s, as models/llama.py has it) and K and V
@@ -63,7 +71,7 @@ from omnia_tpu.ops import attention as _attention
 from omnia_tpu.ops.attention import decode_block_rows, gqa_attention
 from omnia_tpu.ops.moe import EXPERT_COUNTERS, expert_ffn, init_ffn, unstack_experts
 from omnia_tpu.ops.norms import rms_norm
-from omnia_tpu.ops.rope import apply_rope
+from omnia_tpu.ops.rope import apply_rope, rope_cos_sin, yarn_scaled_cos_sin
 
 #: Every kind a layer can be, in the order a model's stacks stand in.
 _KINDS = ("dense_window", "dense_full", "sparse_window", "sparse_full")
@@ -101,6 +109,22 @@ def decode_window_rows(cfg: ModelConfig, lengths) -> int:
     ring = ring_rows(cfg)
     rows = decode_block_rows(ring)
     return sum(min(n // rows + 1, ring // rows) * rows for n in lengths)
+
+
+def rope_tables(cfg: ModelConfig, positions) -> dict:
+    """(cos, sin) [..., head_dim // 2] of ``positions`` for each attention kind
+    that rotates, made once a program under ``rope.tables``: "window" by
+    plain RoPE; "full", where ``rope_on_full_layers``, the same pair unless
+    ``rope_full_yarn`` gives the full layers a table of their own (YaRN's
+    blended frequencies, cos and sin times the attention factor)."""
+    with jax.named_scope("rope.tables"):
+        tables = {"window": rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
+                                         cfg.rope_scaling)}
+        if cfg.rope_on_full_layers:
+            tables["full"] = tables["window"] if cfg.rope_full_yarn is None else (
+                yarn_scaled_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
+                                    cfg.rope_full_yarn))
+    return tables
 
 
 def stack_kinds(cfg: ModelConfig) -> tuple:
@@ -212,18 +236,20 @@ def _ring_rows_before(ring, start, window: int, layer):
     return jnp.take_along_axis(held, rows[:, :, None, None], axis=1)
 
 
-def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, cos, sin, q_positions,
+def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
                  cache, cache_layer, write_start, n_real, mesh, live, attn_fn=None):
     """One block of a model of several kinds: ``kind`` its stack's, ``at`` its
     index in the stack (its experts' too), ``cache_layer`` its index into the
-    cache arrays of its attention kind. ``cache``: the whole tuple (the
-    module docstring), or None for a chunk on its own (training, a fresh
+    cache arrays of its attention kind, ``rope`` the program's ``rope_tables``
+    (a kind that is not among them is not rotated). ``cache``: the whole
+    tuple (the module docstring), or None for a chunk on its own (training, a fresh
     prefill), which gets its rows back instead: (k, v) [B, T, Hkv, D] of a
     full layer, [B, R, Hkv, D] of a window layer. ``attn_fn`` overrides a full
     layer's attention over a chunk on its own (training: the einsums). → (x,
     cache or rows, counts int32 [2] as EXPERT_COUNTERS)."""
     B, T, _ = x.shape
-    window = cfg.sliding_window if kind.endswith("window") else 0
+    attention = kind.split("_")[1]
+    window = cfg.sliding_window if attention == "window" else 0
     a = p["attn"]
     with jax.named_scope("attn.qkv"):
         h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
@@ -234,7 +260,8 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, cos, sin, q_position
         with jax.named_scope("attn.qk_norm"):
             q = rms_norm(q, a["qn"], cfg.rms_norm_eps)
             k = rms_norm(k, a["kn"], cfg.rms_norm_eps)
-    if window or cfg.rope_on_full_layers:
+    if attention in rope:
+        cos, sin = rope[attention]
         with jax.named_scope("attn.rope"):
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
@@ -289,11 +316,12 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, cos, sin, q_position
     return x + y, kept, counts
 
 
-def _run_stacks(params, cfg: ModelConfig, x, cos, sin, q_positions, cache, write_start,
+def _run_stacks(params, cfg: ModelConfig, x, rope, q_positions, cache, write_start,
                 row, mesh, live, attn_fn=None):
     """Every layer of a model of several kinds, a scan a run (``_runs``)
-    under ``stack.<kind>``. With a cache (the whole tuple) it is the carry
-    and comes back; without one the chunk's rows come back in its place, an
+    under ``stack.<kind>``; ``rope`` is ``rope_tables``, of which a layer
+    takes its kind's. With a cache (the whole tuple) it is the carry and
+    comes back; without one the chunk's rows come back in its place, an
     array for each cache array ([L of the kind, B, T or R, Hkv, D]). →
     (x, cache or chunks, counts summed over the layers)."""
     B, T, _ = x.shape
@@ -313,7 +341,7 @@ def _run_stacks(params, cfg: ModelConfig, x, cos, sin, q_positions, cache, write
             p = jax.tree_util.tree_map(
                 lambda leaf: jax.lax.dynamic_index_in_dim(leaf, i, 0, keepdims=False), scanned)
             x, kept, c = _stack_layer(
-                x, p, experts, i, kind, cfg, cos, sin, q_positions, cache,
+                x, p, experts, i, kind, cfg, rope, q_positions, cache,
                 cache_first + i - first, write_start, n_real, mesh, live, attn_fn)
             return ((x, kept, counts + c), None) if cache is not None else (
                 (x, None, counts + c), kept)

@@ -1,6 +1,7 @@
 """Rotary position embeddings: half-split ("rotate-half") pairs with the
-Llama-3 long-context remap, and interleaved pairs with YaRN frequencies
-(models/mla.py).
+Llama-3 long-context remap or YaRN frequencies with cos and sin scaled
+(models/stacks.py's full layers), and interleaved pairs with YaRN
+frequencies (models/mla.py).
 
 Angles are computed in float32 from integer positions (not accumulated), so
 decode steps at large positions stay exact. Cos/sin are computed on the fly —
@@ -114,8 +115,24 @@ def yarn_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float, yarn: tupl
     factor, original_max, beta_fast, beta_slow, mscale, mscale_all_dim = yarn
     inv_freq = yarn_inv_freq(head_dim, theta, factor, original_max, beta_fast, beta_slow)
     ratio = _yarn_mscale(factor, mscale) / _yarn_mscale(factor, mscale_all_dim)
+    return _scaled_cos_sin(positions, inv_freq, ratio)
+
+
+def _scaled_cos_sin(positions, inv_freq, scale: float):
     angles = positions[..., None].astype(jnp.float32) * inv_freq
-    return jnp.cos(angles) * ratio, jnp.sin(angles) * ratio
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def yarn_scaled_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float, yarn: tuple):
+    """Cos/sin [..., head_dim // 2] in float32 under YaRN as transformers
+    applies it (``rope_type`` "yarn"); ``yarn`` is
+    ``ModelConfig.rope_full_yarn``: (factor, original_max_position_embeddings,
+    beta_fast, beta_slow, attention_factor). Both are times
+    ``attention_factor``, so q.k of two rows rotated by them carries its
+    square and no softmax scale knows of it."""
+    factor, original_max, beta_fast, beta_slow, attention_factor = yarn
+    inv_freq = yarn_inv_freq(head_dim, theta, factor, original_max, beta_fast, beta_slow)
+    return _scaled_cos_sin(positions, inv_freq, attention_factor)
 
 
 def yarn_softmax_scale(qk_head_dim: int, yarn: tuple | None) -> float:

@@ -1,0 +1,95 @@
+"""`mellum2-12b-a2p5b.code-mixed`'s programs as the harness builds them,
+compiled for the described chip at the real size (benchmark/README.md's third
+rehearsal): the decode program, the three fresh prefills and the three extend
+pieces each fit the chip beside the engine's weights and cache, hold the
+experts' grouped-matmul kernel and both decode kernels, and make both rotary
+tables once. And the programs of the seven cells that were there before lower
+to the text they lowered to without `ModelConfig.rope_full_yarn`."""
+
+import hashlib
+import json
+import re
+
+import pytest
+
+from omnia_tpu.engine.programs import build_programs
+
+from . import cells
+
+CELL = "mellum2-12b-a2p5b.code-mixed"
+BUCKETS = (256, 512, 1024)
+CHIP_BYTES = 16e9
+
+
+@pytest.mark.parametrize("program,size", [("decode", 8)]
+                         + [("prefill_insert", b) for b in BUCKETS]
+                         + [("extend_nosample", b) for b in BUCKETS])
+def test_the_cells_programs_fit_the_chip_at_the_real_size(cell_programs, kernel_route_on,
+                                                          program, size):
+    """Arguments (the weights, the cache of 48 slots x 5888 rows with its six
+    1,024-row rings, the step's operands) + temporaries under 16 GB, and the
+    engine at least half the chip (the driver's floor is a quarter)."""
+    cfg, ecfg, params, cache = cell_programs.cell(CELL)
+    assert (ecfg.num_slots, ecfg.max_seq, ecfg.prefill_buckets) == (48, 5888, BUCKETS)
+    compiled = cell_programs.compiled(CELL, program, size)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 0.5 * CHIP_BYTES < memory.argument_size_in_bytes and held < CHIP_BYTES, (
+        program, size, memory.argument_size_in_bytes, memory.temp_size_in_bytes)
+    text = compiled.as_text()
+    cells.holds_the_kernel_on_the_scans_own_stack(text, cfg)
+    if program == "decode":
+        for kernel in ("decode_gqa_attention", "decode_window_attention"):
+            assert re.search(rf"%{kernel}[.\d]* = \S+ custom-call\(", text), kernel
+    else:
+        # a full layer's prompt side is the blocked kernel; a window layer's the band
+        assert re.search(r"%prefill_attention[.\d]* = \S+ custom-call\(", text)
+    assert "rope.tables" in text and "attn.rope" in text
+
+
+# Each older cell's decode program (a chunk of 8 steps) and one prompt-side
+# program at its largest size, lowered for the described chip with the kernels
+# routed on: the lowered text's lines and a digest of it, as the parent commit
+# (35b5e03) gave them; a mismatch prints what this tree gives.
+OLDER_CELLS = {
+    "mistral-7b.chat-steady": "prefill_insert",
+    "mistral-7b.eval-batch": "prefill_insert",
+    "mistral-7b.longprompt-steady": "prefill_insert",
+    "mistral-small-4.reason-batch": "prefill_insert",
+    "xing4-29b-a4b.judge-batch": "prefill_insert",
+    "k-exaone-236b-a23b.longdoc-batch": "extend_nosample",
+    "kimi-linear-48b-a3b.longdoc-wide": "extend_nosample",
+}
+PARENT_PROGRAMS = {
+    "mistral-7b.chat-steady": {"decode": [2494, "11623e217650a845"], "prefill_insert": [1239, "39ddc8bf32640128"]},
+    "mistral-7b.eval-batch": {"decode": [2494, "11623e217650a845"], "prefill_insert": [1238, "a6e284ff5a7e377f"]},
+    "mistral-7b.longprompt-steady": {"decode": [1886, "88d4d4ab1a4d70e3"], "prefill_insert": [1239, "8792f2f464a6f54c"]},
+    "mistral-small-4.reason-batch": {"decode": [3534, "07b25d7fe97dc9b8"], "prefill_insert": [1737, "5e61a19ed46983b5"]},
+    "xing4-29b-a4b.judge-batch": {"decode": [15215, "dcedcd3c3a11519a"], "prefill_insert": [13422, "a659dcff38151baa"]},
+    "k-exaone-236b-a23b.longdoc-batch": {"decode": [11512, "f866f9795b95fd13"], "extend_nosample": [2698, "eb771a4d84f09057"]},
+    "kimi-linear-48b-a3b.longdoc-wide": {"decode": [6101, "8af547ae1889551b"], "extend_nosample": [3132, "2b06a5d865166331"]},
+}
+
+
+def _digest(text: str) -> list:
+    """Lines, and a digest of the text without the Mosaic kernels' serialized
+    bodies: those embed the checkout's path in their source locations."""
+    text = re.sub(r'(@tpu_custom_call\(.*?backend_config = )"[^"]*"', r"\1<kernel>", text)
+    return [len(text.splitlines()), hashlib.sha256(text.encode()).hexdigest()[:16]]
+
+
+@pytest.mark.parametrize("name", list(OLDER_CELLS))
+def test_an_older_cells_programs_lower_to_the_parents_text(cell_programs, kernel_route_on, name):
+    cfg, ecfg, params, cache = cell_programs.cell(name)
+    assert getattr(cfg, "rope_full_yarn", None) is None
+    programs = build_programs(cfg, ecfg, None)
+    prompt_side = OLDER_CELLS[name]
+    got = {
+        "decode": _digest(cells.lower_program(
+            programs, "decode", 8, params, cache, ecfg.num_slots,
+            cell_programs.one_chip).as_text()),
+        prompt_side: _digest(cells.lower_program(
+            programs, prompt_side, max(ecfg.prefill_buckets), params, cache, ecfg.num_slots,
+            cell_programs.one_chip).as_text()),
+    }
+    assert got == PARENT_PROGRAMS.get(name), json.dumps({name: got})

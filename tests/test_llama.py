@@ -242,7 +242,7 @@ def _loop_forward(params, cfg, tokens, qpos, ck, cv, start):
         rows = kv_map(lambda a: a[l:l + 1], pool(c))
         return PagedKV(rows, c.table) if is_paged(c) else rows
 
-    x, cos, sin = llama._embed(params, cfg, tokens, qpos)
+    x, (cos, sin) = llama._embed(params, cfg, tokens, qpos)
     ks, vs = [], []
     for l in range(cfg.num_layers):
         p = jax.tree.map(lambda a: a[l], params["layers"])

@@ -463,7 +463,7 @@ class TestMetricsKeyStability:
     EXPECTED = {
         "requests_submitted", "requests_finished", "tokens_generated",
         "prefill_steps", "decode_steps", "extend_steps", "prefill_tokens",
-        "prefill_tokens_blocked",
+        "prefill_tokens_blocked", "extend_tokens",
         "decode_dispatches", "decode_dispatches_single",
         "decode_dispatches_blocked", "decode_slot_steps", "decode_kv_blocks",
         "decode_window_rows",
