@@ -3,7 +3,12 @@
 tensor against the blocked Pallas kernel, at the shapes of the cells whose
 device time is mostly prompts.
 
-One process on one chip. For each cell's shape (a fresh chunk, or a piece of
+One process on one chip. For a window layer's shapes (``WINDOW_SHAPES``: a
+fresh chunk, or a piece behind the ``window`` rows before it) it times the
+einsum band (``ops/attention.py::band_attention``, the route-off side) against
+the kernel with its lower bound at a few query tiles and key blocks; the floor
+there is over the rows a query can see, at most ``window`` of them. For each
+other cell's shape (a fresh chunk, or a piece of
 T rows at offset ``first`` over a slot of S rows in a cache of a few layers)
 it times one layer's attention as the model calls it: the pair family through
 ``ops/attention.py`` (``einsum_attention`` against ``prefill_attention`` at a
@@ -23,6 +28,7 @@ A number of the rehearsal is no measurement. No TPU and no
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -42,6 +48,13 @@ SHAPES = {
     "mistral-7b.longprompt-steady": ("pair", 2048, 2048, 32, 8, (128, 0), 128, (0,)),
     "mistral-small-4.reason-batch": ("latent", 1024, 1024, 32, 32, (64, 64), 128, (0,)),
 }
+# cell: (chunk lengths T, window, heads, KV heads, head width, offsets ``first`` of
+# the piece; None: a fresh chunk, which meets its own rows alone).
+WINDOW_SHAPES = {
+    "mellum2-12b-a2p5b.code-mixed": ((256, 512, 1024), 1024, 32, 4, 128, (None, 1024, 4096)),
+    "k-exaone-236b-a23b.longdoc-batch": ((1024,), 128, 64, 8, 128, (None, 1024, 4096)),
+}
+TINY_WINDOW = {name: ((256,), 128, 4, 2, 128, (None, 64, 256)) for name in WINDOW_SHAPES}
 TINY = {name: (s[0], 256, 512 if s[2] > s[1] else 256, 4, 4 if s[3] == s[4] else 2,
                s[5], 128, (0, 256) if s[2] > s[1] else (0,)) for name, s in SHAPES.items()}
 
@@ -61,6 +74,16 @@ def timed(fn, args, iters):
     return (time.perf_counter() - t0) / iters * 1e3
 
 
+def chained(fn, calls: int):
+    """``calls`` calls of ``fn`` in one program, each one's result the next
+    one's queries (an attention's result has its queries' shape): a call of
+    under half a millisecond is otherwise timed by the host's dispatch."""
+    import jax
+
+    return jax.jit(lambda q, *rest: jax.lax.fori_loop(
+        0, calls, lambda _, q: fn(q, *rest).reshape(q.shape).astype(q.dtype), q))
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
@@ -68,6 +91,16 @@ def main() -> int:
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="tiny sizes, the kernel interpreted; no measurement")
     ap.add_argument("--cells", nargs="*", default=sorted(SHAPES))
+    ap.add_argument("--window-cells", nargs="*", default=sorted(WINDOW_SHAPES),
+                    help="the cells whose window layers' shapes to time (none: leave them out)")
+    ap.add_argument("--window", type=int, default=0,
+                    help="time the window cells' shapes at this window, not their own "
+                         "(where the band and the kernel cross)")
+    ap.add_argument("--chain", type=int, default=8,
+                    help="calls of a window shape's attention in one program")
+    ap.add_argument("--check", action="store_true",
+                    help="no timing: the window shapes in float32, the compiled kernel's largest "
+                         "distance from the band, beside that of a window one row too wide")
     ap.add_argument("--sweep", action="store_true",
                     help="query tiles 256–1024 × key blocks 512–2048, not the default alone")
     ap.add_argument("--kernel-only", action="store_true",
@@ -107,6 +140,69 @@ def main() -> int:
         attention._pallas_decode_mode.cache_clear()
 
     kernel_mode = "1" if on_tpu else "interpret"
+    for cell in args.window_cells:
+        Ts, W, H, Hkv, D, firsts = (WINDOW_SHAPES if on_tpu else TINY_WINDOW)[cell]
+        W, G, calls = args.window or W, H // Hkv, args.chain if on_tpu else 1
+        if args.check:  # and a piece whose slot holds less than a window: the lowest row binds
+            dtype, firsts = jnp.float32, (*firsts, W // 2 + 7)
+        keys = jax.random.split(jax.random.key(args.seed & 0x7FFFFFFF), 5)
+        for T in Ts:
+            q = jax.random.normal(keys[0], (1, T, H, D), dtype)
+            k, v = (jax.random.normal(key, (1, T, Hkv, D), dtype) for key in keys[1:3])
+            for first in firsts:
+                fresh = first is None
+                prev = () if fresh else tuple(
+                    jax.random.normal(key, (1, W, Hkv, D), dtype) for key in keys[3:])
+                S = T if fresh else W + T
+                # Where the window never binds the route makes the causal call.
+                window = W if not fresh or T > W else 0
+                # A query sees its own row and at most W - 1 before it, none before 0.
+                pairs = sum(min((first or 0) + t + 1, W) for t in range(T))
+                floor_ms = 2 * pairs * H * 2 * D / PEAK_FLOPS * 1e3
+                tilings = [tiles(T, S, G, window)]
+                if args.sweep and window:
+                    tilings += [(tq, tk) for tq in (256, 512, 1024) for tk in (256, 512, 1024)
+                                if T % tq == 0 and tk <= S and G * tq <= 4096]
+                at = jnp.full((1,), first or 0, jnp.int32)
+
+                def band(q, k, v, at, *prev, fresh=fresh):
+                    pk, pv = prev or (None, None)
+                    return attention.band_attention(q, k, v, pk, pv, None if fresh else at, W)
+
+                def kernel(q, k, v, at, *prev, fresh=fresh, S=S, T=T, window=window, tiling=None):
+                    if prev:
+                        k, v = (jnp.concatenate([p, x], axis=1) for p, x in zip(prev, (k, v)))
+                    pos = (S - T + jnp.arange(T, dtype=jnp.int32))[None]
+                    return prefill_attention(
+                        q.reshape(1, T, H * D), k.reshape(1, S, Hkv * D), v.reshape(1, S, Hkv * D),
+                        pos, None, None if fresh else jnp.maximum(W - at, 0), kv_heads=Hkv,
+                        scale=D ** -0.5, window=window, tiling=tiling, interpret=not on_tpu)
+
+                if args.check:
+                    with jax.default_matmul_precision("highest"):
+                        want = jax.jit(band)(q, k, v, at, *prev)
+                    far = {name: float(jnp.abs(jax.jit(functools.partial(kernel, window=w))(
+                        q, k, v, at, *prev).reshape(want.shape) - want).max())
+                        for name, w in (("kernel", window), ("one_row_too_wide", window + 1))
+                        if window or name == "kernel"}
+                    line(cell=cell, family="window", T=T, S=S, window=W, first=first,
+                         check="float32, largest distance from the band", **far)
+                    continue
+                routes = [] if args.kernel_only else [("band", chained(band, calls))]
+                routes += [("prefill_attention window=%d tq=%d tk=%d" % (window, *tiling),
+                            chained(functools.partial(kernel, tiling=tiling), calls))
+                           for tiling in dict.fromkeys(tilings)]
+                base = None
+                for name, fn in routes:
+                    try:
+                        ms = timed(fn, (q, k, v, at, *prev), iters) / calls
+                    except Exception as e:  # a tiling the compiler refuses is a line too
+                        line(cell=cell, T=T, window=W, first=first, route=name, error=str(e)[:200])
+                        continue
+                    base = base or ms
+                    line(cell=cell, family="window", T=T, S=S, window=W, heads=H, kv_heads=Hkv,
+                         first=first, route=name, ms=round(ms, 4), floor_ms=round(floor_ms, 4),
+                         peak_share=round(floor_ms / ms, 4), vs_band=round(base / ms, 3))
     for cell in args.cells:
         family, T, S, H, Hkv, (dn, dr), dv, firsts = shapes[cell]
         G = H // Hkv

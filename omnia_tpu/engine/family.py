@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from omnia_tpu.engine.types import EngineConfig
 from omnia_tpu.models import ModelConfig, llama
-from omnia_tpu.ops.attention import prefill_kernel_on
+from omnia_tpu.ops.attention import prefill_kernel_on, window_kernel_on
 
 
 def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
@@ -100,10 +100,17 @@ def prefill_blocked(model_cfg: ModelConfig, cfg: EngineConfig, mesh, bucket: int
     whatever the cache holds; an extend piece or a mixed step
     (``_extend_slot``) over one slot's view of ``max_seq`` rows, int8 under
     ``kv_quant`` and plain else (a paged pool's view is gathered); each
-    with the engine's mesh. ``prefill_tokens_blocked`` counts by it, and
+    with the engine's mesh. A model with rings runs it through the kernel
+    only if its window layers' chunk takes it too (the window route's own
+    function): the attention layers of every kind, or the program does not
+    count. ``prefill_tokens_blocked`` counts by it, and
     tests/test_prefill_attention.py holds it to the traced programs."""
     rows, plain = (bucket, True) if fresh else (cfg.max_seq, not cfg.kv_quant)
-    return prefill_kernel_on(bucket, rows, model_cfg.attn_value_width, plain, mesh)
+    width = model_cfg.attn_value_width
+    return prefill_kernel_on(bucket, rows, width, plain, mesh) and (
+        not model_cfg.has_window_layers
+        or window_kernel_on(bucket, model_cfg.sliding_window, model_cfg.num_heads, width,
+                            fresh, mesh))
 
 
 class _PairCacheMixin:

@@ -43,7 +43,10 @@ seam), and which rows of it mean what is this module's word alone:
   frontier, would here wrap onto rows still inside the window. A decode
   step writes a slot's row only where the slot is live.
 - *A chunk attends before it writes*: over ``[the window - 1 rows before it,
-  out of the ring | its own k, v]`` (``ops/attention.py::band_attention``).
+  out of the ring | its own k, v]`` (``ops/attention.py::window_attention``:
+  the blocked kernel of ops/prefill_attention.py with a lower bound on the
+  key blocks where the route takes it, the einsums of ``band_attention``
+  else, and in training).
   The ring therefore never has to hold a chunk and the window before it at
   once, R = window serves a chunk of any length, and the cost is O(T × 2 ×
   window) whatever the context. Sizing R for the largest chunk instead would
@@ -228,8 +231,8 @@ def _ring_put_step(ring, new, position, live, layer):
 def _ring_rows_before(ring, start, window: int, layer):
     """The ``window`` rows at positions ``start[b] - window … start[b] - 1``
     out of layer ``layer`` of a ring [L, B, R, Hkv, D] → [B, window, Hkv, D]
-    (what lies before position 0 is whatever the ring holds: ``band_attention``
-    masks it)."""
+    (what lies before position 0 is whatever the ring holds:
+    ``window_attention`` masks it)."""
     R = ring.shape[2]
     rows = (start[:, None] - window + jnp.arange(window, dtype=jnp.int32)[None, :]) & (R - 1)
     held = jax.lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
@@ -245,8 +248,9 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
     tuple (the module docstring), or None for a chunk on its own (training, a fresh
     prefill), which gets its rows back instead: (k, v) [B, T, Hkv, D] of a
     full layer, [B, R, Hkv, D] of a window layer. ``attn_fn`` overrides a full
-    layer's attention over a chunk on its own (training: the einsums). → (x,
-    cache or rows, counts int32 [2] as EXPERT_COUNTERS)."""
+    layer's attention over a chunk on its own (training: the einsums), and
+    with one a window layer takes the einsum band. → (x, cache or rows,
+    counts int32 [2] as EXPERT_COUNTERS)."""
     B, T, _ = x.shape
     attention = kind.split("_")[1]
     window = cfg.sliding_window if attention == "window" else 0
@@ -268,7 +272,9 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
     with jax.named_scope("attn.decode" if T == 1 else "attn.prefill"):
         if window and cache is None:
             with jax.named_scope("attn.window"):
-                attn = _attention.band_attention(q, k, v, None, None, None, window)
+                attn = (_attention.band_attention(q, k, v, None, None, None, window)
+                        if attn_fn else
+                        _attention.window_attention(q, k, v, None, None, None, window, mesh))
             # A fresh chunk starts at position 0: its rows as a ring that
             # held nothing takes them.
             kept = tuple(_ring_image(rows, jnp.zeros((B,), jnp.int32), n_real,
@@ -284,10 +290,10 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
         elif window:
             *full, rk, rv = cache
             with jax.named_scope("attn.window"):
-                attn = _attention.band_attention(
+                attn = _attention.window_attention(
                     q, k, v, _ring_rows_before(rk, write_start, window, cache_layer),
                     _ring_rows_before(rv, write_start, window, cache_layer),
-                    write_start, window)
+                    write_start, window, mesh)
             with jax.named_scope("kv.update"):
                 rk = _ring_put(rk, k, write_start, n_real, cache_layer)
                 rv = _ring_put(rv, v, write_start, n_real, cache_layer)

@@ -17,7 +17,10 @@ Design notes (TPU-first):
   says so: a running softmax over tiles in VMEM, no ``[H, T, S]`` scores in
   HBM, no key block past the queries' positions. The einsums below keep the
   CPU, int8 and paged caches, a mesh, chunks that are no whole 128-row tiles
-  (speculation's verify steps) and training, and are the kernel's oracle.
+  (speculation's verify steps) and training, and are the kernel's oracle. A
+  window layer's chunk (``window_attention``) takes the same kernel with a
+  lower bound on the key blocks where ``window_kernel_on`` says so, and the
+  einsum band (``band_attention``) else.
 - Head axes are sharded over the "tp" mesh axis by the caller (weights carry
   the sharding; XLA propagates it through the einsum path with no collectives
   inside attention). The Pallas decode kernel cannot be partitioned by XLA, so
@@ -111,6 +114,40 @@ def prefill_kernel_on(T: int, S: int, width: int, plain: bool = True, mesh=None)
 
     return (_kernel_on() and plain and mesh is None and T > 1
             and T % QUERY_TILE == 0 and S % QUERY_TILE == 0 and width % 128 == 0)
+
+
+# A window layer's chunk keeps the einsum band (``band_attention``) while a
+# slot's float32 band scores ``[H, T, Q + window]`` are at most this many
+# bytes: up to 96 MiB they never leave the chip's fast memory and the band
+# costs 0.03–0.15 ms a layer, half to a third of the blocked kernel, whose
+# tiles execute T × (tq + window) products for the band's T × window; from
+# 128 MiB they go through HBM three times, 0.6–1.3 ms against the kernel's
+# 0.2–0.45 (PERF.md section 6, PR 48: 13 shapes, windows of 128 to 1,024).
+_BAND_SCORES_MOST = 96 << 20
+
+
+def _band_queries(T: int, window: int) -> int:
+    """Rows of a block of ``band_attention``'s queries: the window, or all T
+    where the window does not divide them."""
+    return window if T % window == 0 else T
+
+
+def window_kernel_on(T: int, window: int, heads: int, width: int, fresh: bool,
+                     mesh=None) -> bool:
+    """Whether a window layer's chunk of T queries a slot takes the blocked
+    kernel (``window_attention``) and not the einsum band, from what the
+    call can see: ``prefill_kernel_on`` over the rows of keys the call hands
+    it (a ``fresh`` chunk its own T, a piece ``[the window rows before it |
+    its own]``), and then either a fresh chunk no longer than the window (the
+    window never binds: the causal call a full layer makes, faster than the
+    band at every size) or band scores of more than ``_BAND_SCORES_MOST``
+    bytes. The engine asks it for a model with rings as it asks
+    ``prefill_kernel_on`` for the full layers (engine/family.py::
+    prefill_blocked)."""
+    if not prefill_kernel_on(T, T if fresh else window + T, width, True, mesh):
+        return False
+    return (fresh and T <= window) or (
+        heads * T * (_band_queries(T, window) + window) * 4 > _BAND_SCORES_MOST)
 
 
 def _map_rows(fn, cache):
@@ -322,7 +359,8 @@ def einsum_attention(q, k_cache, v_cache, q_positions, layer=None):
 
 # ---------------------------------------------------------------------------
 # Window layers (models/llama.py's stacks): a band over [the rows before the
-# chunk | the chunk] on the prefill / extend path, a ring on the decode path.
+# chunk | the chunk] on the prefill / extend path (``window_attention``: the
+# blocked kernel or ``band_attention``'s einsums), a ring on the decode path.
 # ---------------------------------------------------------------------------
 
 
@@ -344,7 +382,7 @@ def band_attention(q, k, v, prev_k, prev_v, first, window: int):
     B, T, H, D = q.shape
     Hkv, W = k.shape[2], window
     G = H // Hkv
-    Q = W if T % W == 0 else T
+    Q = _band_queries(T, W)
     nb = T // Q
     if prev_k is None:
         prev_k = prev_v = jnp.zeros((B, W, Hkv, D), k.dtype)
@@ -368,6 +406,37 @@ def band_attention(q, k, v, prev_k, prev_v, first, window: int):
     probs = (probs / probs.sum(axis=-1, keepdims=True)).astype(v.dtype)
     out = jnp.einsum("bnhgqk,bnkhd->bnqhgd", probs, vb)
     return out.reshape(B, T, H, D)
+
+
+def window_attention(q, k, v, prev_k, prev_v, first, window: int, mesh=None):
+    """``band_attention`` (its operands and its result) by the route: the
+    blocked kernel where ``window_kernel_on`` says so, the einsum band else
+    (the CPU, a mesh, heads off 128 lanes, chunks and windows in no whole
+    128-row tiles, a band whose scores are small). The kernel meets the keys
+    as one array of rows: a fresh chunk's own, queries at rows 0 … T − 1
+    (where T ≤ ``window`` the window never binds: the causal call a full
+    layer makes); a piece's ``[prev | chunk]``, queries at rows ``window +
+    t``, of which the rows before ``window − first[b]`` lie before position
+    0 and are below the slot's lowest row, whatever they hold."""
+    B, T, H, D = q.shape
+    fresh = prev_k is None
+    if not window_kernel_on(T, window, H, D, fresh, mesh):
+        return band_attention(q, k, v, prev_k, prev_v, first, window)
+    from omnia_tpu.ops.prefill_attention import prefill_attention
+
+    lowest, at = None, 0
+    if not fresh:
+        k = jnp.concatenate([prev_k.astype(k.dtype), k], axis=1)
+        v = jnp.concatenate([prev_v.astype(v.dtype), v], axis=1)
+        lowest, at = jnp.maximum(window - first, 0), window
+    Hkv = k.shape[2]
+    positions = jnp.broadcast_to(at + jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
+    return prefill_attention(
+        q.reshape(B, T, H * D), k.reshape(B, -1, Hkv * D), v.reshape(B, -1, Hkv * D),
+        positions, None, lowest, kv_heads=Hkv, scale=D**-0.5,
+        window=0 if fresh and T <= window else window,   # it never binds: the causal call
+        interpret=_pallas_decode_mode() == "interpret",
+    ).reshape(B, T, H, D)
 
 
 def ring_decode_attention(q, ring_k, ring_v, q_positions, layer, live, window: int):

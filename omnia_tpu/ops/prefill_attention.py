@@ -31,6 +31,17 @@ query of the tile can see (PERF.md section 6, PR 45):
   with ``layer`` in the block index map, so no layer is sliced out in front.
   A KV head's columns are one block of the minor axis: the widths are
   multiples of 128 lanes, the key's and the value's may differ.
+- with a ``window`` W (a window layer's chunk, PERF.md section 6, PR 48) a
+  query at key row p sees rows ``max(p - W + 1, lowest[b]) … p``, and a tile
+  has a *first* visited block beside its last: the block that holds its
+  smallest lower bound (from the tile's smallest position and the slot's
+  ``lowest``, both scalar-prefetched). The index map and the guard count
+  from it, so a tile visits ``⌈(tq + W - 1) / tk⌉ + 1`` blocks at most
+  whatever the keys' length, and the grid's last axis is that static number.
+  A block wholly inside every query's band takes the unmasked body; rows of
+  a visited block before the tile's smallest bound are zeroed in the values
+  like those past its largest position. ``window`` 0 is the causal call:
+  its program holds no operand, step or compare of the window's.
 """
 
 from __future__ import annotations
@@ -57,23 +68,39 @@ _NEG_INF = -1e30
 _QUERY_TILE_MOST = 1024
 _STEP_ROWS = 4096
 _KEY_BLOCK = 1024
+# Under a window a tile visits the blocks that hold its tq + window − 1 rows,
+# and a narrower block wastes fewer rows at their two ends: 512 is 3–10 %
+# faster than 1,024 at windows of 512 and 1,024 and level with it at 128 and
+# 256; 256 is 20–70 % slower everywhere (PERF.md section 6, PR 48).
+_WINDOW_KEY_BLOCK = 512
 
 
-def tiles(T: int, S: int, G: int) -> tuple[int, int]:
+def tiles(T: int, S: int, G: int, window: int = 0) -> tuple[int, int]:
     """(tq, tk): query tiles of even size, as few as stay within
     ``_QUERY_TILE_MOST`` and ``_STEP_ROWS``, in whole 128s (the last one is
     short where T is no multiple: 1,152 rows are tiles of 640 and 512); key
-    blocks of ``_KEY_BLOCK`` rows, the last one short likewise (S in whole
-    128s where that is less)."""
+    blocks of ``_KEY_BLOCK`` rows (``_WINDOW_KEY_BLOCK`` under a ``window``),
+    the last one short likewise (S in whole 128s where that is less)."""
     most = min(_QUERY_TILE_MOST, _STEP_ROWS // G)
     tq = pl.cdiv(pl.cdiv(T, pl.cdiv(T, most)), QUERY_TILE) * QUERY_TILE
-    return tq, min(_KEY_BLOCK, pl.cdiv(S, _LANES) * _LANES)
+    return tq, min(_WINDOW_KEY_BLOCK if window else _KEY_BLOCK, pl.cdiv(S, _LANES) * _LANES)
 
 
 def _visits(top, tk: int, blocks: int):
     """Key blocks a tile whose largest position is ``top`` visits: those
     that hold a row at or before it (one at least, the cache's at most)."""
     return jnp.clip(top // tk + 1, 1, blocks)
+
+
+def _band(top, low, lowest, window: int, tk: int, blocks: int, most: int):
+    """(bound, first, visits) of a tile under a window: its smallest lower
+    bound (that of its smallest position ``low``, no lower than the slot's
+    ``lowest``), the block that holds it, and the blocks from there to the
+    one that holds its largest position ``top`` (one at least, the grid's
+    ``most`` at most)."""
+    bound = jnp.maximum(low - (window - 1), lowest)
+    first = jnp.clip(bound // tk, 0, blocks - 1)
+    return bound, first, jnp.clip(top // tk + 1 - first, 1, most)
 
 
 def _each_head(G: int, head) -> None:
@@ -92,22 +119,35 @@ def _lanes_of(g, width: int):
     return pl.ds(g * width if isinstance(g, int) else pl.multiple_of(g * width, _LANES), width)
 
 
-def _kernel(layer_ref, top_ref, low_ref, pos_ref, q_ref, k_ref, v_ref, out_ref,
-            m_ref, l_ref, acc_ref, *, G: int, dk: int, dv: int, tk: int,
-            tiles_q: int, blocks: int, rows: int, scale: float):
-    """One grid step a (slot, KV head, query tile, key block). pos_ref [tq,
-    128] int32 (a row's position in every lane); q_ref [tq, G·dk]; k_ref
-    [tk, dk]; v_ref [tk, dv]; out_ref [tq, G·dv]; m_ref [G, tq, 1], l_ref
-    [G, tq, 128] (a row's sum is its lanes' sum: a step adds lane to lane,
-    and the reduction across them waits for the tile's last), acc_ref [G,
-    tq, dv], float32 all three. ``rows`` = S: the last block may hold
-    fewer, and what lies behind them is masked like any row past ``top``."""
+def _kernel(layer_ref, top_ref, low_ref, *refs, G: int, dk: int, dv: int, tk: int,
+            tiles_q: int, blocks: int, rows: int, scale: float, window: int, most: int):
+    """One grid step a (slot, KV head, query tile, key block). ``refs``:
+    with a window first lowest_ref [B] (scalar-prefetched like the three in
+    front), then pos_ref [tq, 128] int32 (a row's position in every lane);
+    q_ref [tq, G·dk]; k_ref [tk, dk]; v_ref [tk, dv]; out_ref [tq, G·dv];
+    m_ref [G, tq, 1], l_ref [G, tq, 128] (a row's sum is its lanes' sum: a
+    step adds lane to lane, and the reduction across them waits for the
+    tile's last), acc_ref [G, tq, dv], float32 all three. ``rows`` = S: the
+    last block may hold fewer, and what lies behind them is masked like any
+    row past ``top``. ``most``: the grid's last axis under a window."""
     del layer_ref
+    pos_ref, q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref = refs[-8:]
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     top = jnp.minimum(top_ref[b * tiles_q + i], rows - 1)
-    visits = _visits(top, tk, blocks)
-    # Blocks every query of the tile sees whole: no mask to compute.
-    whole = jnp.clip((low_ref[b * tiles_q + i] + 1) // tk, 0, visits)
+    if window:
+        # The tile's smallest lower bound names its first block; a block is
+        # ``whole`` (every query sees all of it) between the tile's largest
+        # bound and its smallest position.
+        low, lowest = low_ref[b * tiles_q + i], refs[0][b]
+        bound, first, visits = _band(top, low, lowest, window, tk, blocks, most)
+        at = first + j
+        whole = ((at >= (jnp.maximum(top - (window - 1), lowest) + tk - 1) // tk)
+                 & (at < (low + 1) // tk))
+    else:
+        visits = _visits(top, tk, blocks)
+        # Blocks every query of the tile sees whole: no mask to compute.
+        whole = jnp.clip((low_ref[b * tiles_q + i] + 1) // tk, 0, visits)
+        at = j
 
     @pl.when(j == 0)
     def _init():
@@ -119,11 +159,16 @@ def _kernel(layer_ref, top_ref, low_ref, pos_ref, q_ref, k_ref, v_ref, out_ref,
         k, v = k_ref[...], v_ref[...]
         if masked:
             pos = jnp.minimum(pos_ref[:, :1], rows - 1)               # [tq, 1]
-            seen = j * tk + jax.lax.broadcasted_iota(jnp.int32, (pos.shape[0], tk), 1) <= pos
+            col = at * tk + jax.lax.broadcasted_iota(jnp.int32, (pos.shape[0], tk), 1)
+            seen = col <= pos
             # Rows no query of the tile sees: out of the second product
             # whatever they hold (0 × NaN is NaN).
-            row = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tk, 1), 0)
-            v = jnp.where(row <= top, v, jnp.zeros_like(v))
+            row = at * tk + jax.lax.broadcasted_iota(jnp.int32, (tk, 1), 0)
+            kept = row <= top
+            if window:
+                seen &= col >= jnp.maximum(pos - (window - 1), lowest)
+                kept &= row >= bound
+            v = jnp.where(kept, v, jnp.zeros_like(v))
         def head(g, carry=None):
             s = jax.lax.dot_general(
                 q_ref[:, _lanes_of(g, dk)], k, (((1,), (1,)), ((), ())),
@@ -142,8 +187,12 @@ def _kernel(layer_ref, top_ref, low_ref, pos_ref, q_ref, k_ref, v_ref, out_ref,
 
         _each_head(G, head)
 
-    pl.when(j < whole)(lambda: block(False))
-    pl.when((j >= whole) & (j < visits))(lambda: block(True))
+    if window:
+        pl.when(whole & (j < visits))(lambda: block(False))
+        pl.when(jnp.logical_not(whole) & (j < visits))(lambda: block(True))
+    else:
+        pl.when(j < whole)(lambda: block(False))
+        pl.when((j >= whole) & (j < visits))(lambda: block(True))
 
     @pl.when(j == visits - 1)
     def _finish():
@@ -154,25 +203,30 @@ def _kernel(layer_ref, top_ref, low_ref, pos_ref, q_ref, k_ref, v_ref, out_ref,
         _each_head(G, head)
 
 
-@functools.partial(jax.jit, static_argnames=("kv_heads", "scale", "tiling", "interpret"))
-def prefill_attention(q, k, v, q_positions, layer=None, *, kv_heads: int,
-                      scale: float, tiling=None, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("kv_heads", "scale", "window", "tiling",
+                                             "interpret"))
+def prefill_attention(q, k, v, q_positions, layer=None, lowest=None, *, kv_heads: int,
+                      scale: float, window: int = 0, tiling=None, interpret: bool = False):
     """q [B, T, H·dk] × keys k [B, S, Hkv·dk] and values v [B, S, Hkv·dv] at
     rows 0 … S − 1 (with ``layer`` an int32 index: [L, B, S, ·], of which
     layer ``layer``) → [B, T, H·dv] in q's type: softmax over the keys whose
     row is at or before ``q_positions`` [B, T] of ``scale`` × q·k, head h with
-    KV head ``h // (H // Hkv)``. dk and dv are multiples of 128, the query tile
-    of 8, the key block of 128 (a row's sum is kept lane by lane, a block's
-    columns added 128 at a time); ``tiling`` (tq, tk) overrides
-    ``tiles``. A last tile or block that T or S does not fill reads what lies
-    behind the array: such keys are masked, such queries' rows not written."""
+    KV head ``h // (H // Hkv)``. With ``window`` W > 0 a query at row p sees
+    rows ``max(p − W + 1, lowest[b]) … p`` (``lowest`` int32 [B], None for
+    0), and a tile's positions must lie within ``tq`` rows of each other (a
+    chunk's rising ones do): the grid's last axis is sized from that. dk and
+    dv are multiples of 128, the query tile of 8, the key block of 128 (a
+    row's sum is kept lane by lane, a block's columns added 128 at a time);
+    ``tiling`` (tq, tk) overrides ``tiles``. A last tile or block that T or
+    S does not fill reads what lies behind the array: such keys are masked,
+    such queries' rows not written."""
     B, T, _ = q.shape
     if k.ndim == 3:
         k, v, layer = k[None], v[None], 0
     S = k.shape[2]
     dk, dv = k.shape[3] // kv_heads, v.shape[3] // kv_heads
     G = q.shape[2] // (kv_heads * dk)
-    tq, tk = tiling or tiles(T, S, G)
+    tq, tk = tiling or tiles(T, S, G, window)
     if tq % 8 or tk % _LANES or dk % _LANES or dv % _LANES:
         raise ValueError(f"prefill_attention: query tile {tq}, key block {tk}, widths {dk}, "
                          f"{dv}: a multiple of 8, and the rest multiples of {_LANES}")
@@ -184,13 +238,22 @@ def prefill_attention(q, k, v, q_positions, layer=None, *, kv_heads: int,
     # A tile's largest and smallest position: what it visits, what it masks.
     prefetch = [jnp.asarray(layer, jnp.int32).reshape(1), by_tile.max(axis=-1),
                 by_tile.min(axis=-1)]
+    most = blocks
+    if window:
+        most = min(blocks, pl.cdiv(tq + window - 1, tk) + 1)
+        prefetch.append(jnp.zeros((B,), jnp.int32) if lowest is None
+                        else lowest.astype(jnp.int32))
 
     def q_index(b, h, i, j, *_):
         return (b, i, h)
 
-    def kv_index(b, h, i, j, layer_ref, top_ref, low_ref):
+    def kv_index(b, h, i, j, layer_ref, top_ref, low_ref, *lowest_ref):
         top = jnp.minimum(top_ref[b * tiles_q + i], S - 1)
-        return (layer_ref[0], b, jnp.minimum(j, _visits(top, tk, blocks) - 1), h)
+        if not window:
+            return (layer_ref[0], b, jnp.minimum(j, _visits(top, tk, blocks) - 1), h)
+        _, first, visits = _band(top, low_ref[b * tiles_q + i], lowest_ref[0][b], window, tk,
+                                 blocks, most)
+        return (layer_ref[0], b, first + jnp.minimum(j, visits - 1), h)
 
     itemsize = max(q.dtype.itemsize, k.dtype.itemsize)
     vmem = (2 * (tq * _LANES * 4 + (tq * G + tk) * (dk + dv) * itemsize)  # the blocks, twice
@@ -198,7 +261,7 @@ def prefill_attention(q, k, v, q_positions, layer=None, *, kv_heads: int,
             + 4 * tq * tk * 4)                                          # a tile of scores
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B, kv_heads, tiles_q, blocks),
+        grid=(B, kv_heads, tiles_q, most),
         in_specs=[
             pl.BlockSpec((None, tq, _LANES), lambda b, h, i, j, *_: (b, i, 0)),
             pl.BlockSpec((None, tq, G * dk), q_index),
@@ -213,8 +276,8 @@ def prefill_attention(q, k, v, q_positions, layer=None, *, kv_heads: int,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, G=G, dk=dk, dv=dv, tk=tk, tiles_q=tiles_q,
-                          blocks=blocks, rows=S, scale=scale),
+        functools.partial(_kernel, G=G, dk=dk, dv=dv, tk=tk, tiles_q=tiles_q, blocks=blocks,
+                          rows=S, scale=scale, window=window, most=most),
         out_shape=jax.ShapeDtypeStruct((B, T, kv_heads * G * dv), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(

@@ -2,17 +2,24 @@
 compiled for the described chip at the real size (benchmark/README.md's third
 rehearsal): the decode program, the three fresh prefills and the three extend
 pieces each fit the chip beside the engine's weights and cache, hold the
-experts' grouped-matmul kernel and both decode kernels, and make both rotary
-tables once. And the programs of the seven cells that were there before lower
+experts' grouped-matmul kernel and both decode kernels, make both rotary
+tables once, and on the prompt side hold the blocked attention kernel a run of
+attention layers of either kind wherever the window route takes it (every
+fresh prefill, and the piece of 1,024 rows, whose band would be 256 MiB of
+float32 scores). And the programs of the seven cells that were there before lower
 to the text they lowered to without `ModelConfig.rope_full_yarn`."""
 
 import hashlib
 import json
 import re
 
+import numpy as np
 import pytest
 
+from chip_smoke import result_dims
 from omnia_tpu.engine.programs import build_programs
+from omnia_tpu.models import stacks
+from omnia_tpu.ops import attention as attn
 
 from . import cells
 
@@ -42,8 +49,26 @@ def test_the_cells_programs_fit_the_chip_at_the_real_size(cell_programs, kernel_
         for kernel in ("decode_gqa_attention", "decode_window_attention"):
             assert re.search(rf"%{kernel}[.\d]* = \S+ custom-call\(", text), kernel
     else:
-        # a full layer's prompt side is the blocked kernel; a window layer's the band
-        assert re.search(r"%prefill_attention[.\d]* = \S+ custom-call\(", text)
+        # A full layer's prompt side is the blocked kernel, and a window layer's
+        # where its route says so: a call a run of layers (a scan's body), and
+        # then no float32 result as large as the band's scores [H, T, Q +
+        # window] (2,048 wide at the piece of 1,024). The shorter pieces keep
+        # the band, whose scores are 40 and 96 MiB.
+        window, fresh = cfg.sliding_window, program == "prefill_insert"
+        blocked = attn.window_kernel_on(size, window, cfg.num_heads, cfg.head_dim, fresh)
+        assert blocked == (fresh or size == 1024)
+        runs = [kind.split("_")[1] for _, kind, *_ in stacks._runs(cfg)]
+        assert runs == ["window", "full"] * 2
+        # (the compiler may merge two runs' equal bodies into one computation:
+        # more calls than the full runs alone could make are the window runs')
+        calls = re.findall(r"%prefill_attention[.\d]* = \S+ custom-call\(", text)
+        assert (2 < len(calls) <= 4) if blocked else (1 <= len(calls) <= 2), calls
+        if blocked:
+            wide = (window if size % window == 0 else size) + window
+            scores = [ln.strip()[:120] for ln in text.splitlines()
+                      if " = f32[" in ln and (dims := result_dims(ln)) and dims[-1] == wide
+                      and int(np.prod(dims)) >= cfg.num_heads * size * wide]
+            assert not scores, scores
     assert "rope.tables" in text and "attn.rope" in text
 
 
