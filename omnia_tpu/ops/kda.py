@@ -21,13 +21,29 @@ over a whole context, and the published kernels keep it so.
   transform); then ``O = Q̃·S₀ + tril(B)·U`` with ``B`` the same sum over
   ``q_i, k_j`` for j ≤ i, and ``S_C = Diag(exp(G_C))·S₀ + K̂ᵀ·U``, where ``K̃_i =
   k_i ⊙ exp(G_i)``, ``Q̃_i = q_i ⊙ exp(G_i)``, ``K̂_j = k_j ⊙ exp(G_C − G_j)``.
-  *Every exponent is a difference G_i − G_j with j ≤ i, never positive*: the
-  factorised form ``(q ⊙ exp(G))·(k ⊙ exp(−G))`` overflows float32 inside one
-  chunk at the decays this model has (1.6 a token is e^102 over 64). The
-  pairwise tensor ``[C, C, dk]`` exists a chunk at a time, inside the scan,
-  and feeds ONE reduction (A and B together), so that XLA fuses it away.
+  *No exponent is ever positive*: the factorised form ``(q ⊙ exp(G))·(k ⊙
+  exp(−G))`` over a whole chunk overflows float32 at the decays this model
+  has (1.6 a token is e^102 over 64). So a chunk's rows are cut into
+  sub-blocks of ``SUB`` (``_chunk_blocks``). Only the diagonal ``[SUB, SUB]``
+  blocks of A and B are pairwise over dk, ``exp(G_i − G_j)`` masked to j ≤ i
+  on ``[SUB, SUB, dk]``, summed on the vector unit. Under them a block row I
+  meets every earlier row j through G at the block's first row ``s``:
+  ``(k_i ⊙ exp(G_i − G_s))·(k_j ⊙ exp(G_s − G_j))``, a matmul, and both
+  exponents are ≤ 0 because G falls along a chunk and j < s ≤ i. The system
+  is not solved row by row either: ``N = Diag(β)·A`` is strictly lower
+  triangular and ``T = (I + N)⁻¹`` is taken by halves, ``[[L, 0], [R, D]]⁻¹ =
+  [[L⁻¹, 0], [−D⁻¹·R·L⁻¹, D⁻¹]]`` from two rows up (``_unit_lower_inverse``:
+  two matmuls a level, five levels a chunk), then ``U = T·rhs``.
+  *What is rounded:* the four einsums that read or write the carried state
+  (``K̃·S``, ``Q̃·S``, ``tril(B)·U``, ``K̂ᵀ·U``) run at the default precision,
+  float32 operands through bfloat16 in one pass, as a configuration states
+  (``assumed.extend_matmul_precision``); A, B, T and ``T·rhs`` are
+  ``Precision.HIGHEST``, as the sums and the float32 solve were that they
+  replace. All of a chunk stays inside the scan: on the chip that is faster
+  than the state-free part done for a piece's 16 chunks at once.
   A row with ``β = 0`` and ``g = 0`` leaves the state as it was: that is how
-  a piece's pad rows and a length that is no multiple of the chunk are held.
+  a piece's pad rows, a length that is no multiple of the chunk and a chunk
+  that is no multiple of ``SUB`` are held.
 - ``decode_kda_state`` (T == 1): one step of every live slot over layer
   ``layer`` of the whole state ``[L, B, H, dk, dv]``, in place. On a TPU a
   Pallas kernel whose grid is (live slot × group of ``HEAD_BLOCK`` heads)
@@ -50,6 +66,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 #: Tokens of one chunk of ``kda_chunked``.
 CHUNK = 64
+#: Rows of a sub-block of a chunk: only the diagonal [SUB, SUB] blocks are
+#: pairwise over dk. The published kernels' choice; two float32 tiles' sublanes.
+SUB = 16
 #: Heads of one block of the decode kernel: 16 blocks of [128, 128] float32
 #: are 1 MB, so a grid step moves 2 MB against its fixed cost of about a
 #: third of a microsecond (one head a step would be 128 KB: the fixed cost
@@ -85,57 +104,118 @@ def kda_recurrent(q, k, v, g, beta, S0):
     return jnp.moveaxis(o, 0, 1), S
 
 
+def _mm(a, b):
+    """a [..., m, n] · b [..., n, p] in float32 with nothing rounded."""
+    return jnp.matmul(a, b, precision=_HIGHEST)
+
+
+def _unit_lower_inverse(N):
+    """(I + N)⁻¹ for N [..., n, n] strictly lower triangular, by halves:
+    [[L, 0], [R, D]]⁻¹ = [[L⁻¹, 0], [−D⁻¹·R·L⁻¹, D⁻¹]], both halves' inverses
+    in one batch, down to two rows (N² = 0 there: I − N). That is forward
+    substitution by blocks, two matmuls a level, and as stable as the
+    row-by-row solve. The closed product (I − N)(I + N²)(I + N⁴)… over a
+    whole chunk is not: keys that share a direction under a weak decay give
+    powers of N with entries of 1e7 and more that cancel to an inverse of
+    order 1 (the benchmark's ``correct`` caught it on the chip at eight times
+    the parent's distance; ``tests/test_kimi_linear.py`` has the case)."""
+    n = N.shape[-1]
+    if n <= 2:
+        return jnp.eye(n, dtype=N.dtype) - N
+    if n % 2:  # a row and a column of the identity, taken off again
+        grown = jnp.pad(N, [(0, 0)] * (N.ndim - 2) + [(0, 1), (0, 1)])
+        return _unit_lower_inverse(grown)[..., :n, :n]
+    h = n // 2
+    halves = _unit_lower_inverse(jnp.stack([N[..., :h, :h], N[..., h:, h:]], axis=-3))
+    L, D = halves[..., 0, :, :], halves[..., 1, :, :]
+    return jnp.concatenate([
+        jnp.concatenate([L, jnp.zeros_like(L)], axis=-1),
+        jnp.concatenate([-_mm(D, _mm(N[..., h:, :h], L)), D], axis=-1)], axis=-2)
+
+
+def _chunk_blocks(q, k, g, beta):
+    """What a chunk's rule needs of its inputs alone (nothing here reads the
+    carried state): q, k, g [..., C, dk], beta [..., C], C a whole number of
+    sub-blocks → K̃, Q̃, K̂ [..., C, dk], exp(G_C) [..., dk], T = (I +
+    Diag(β)·A)⁻¹ and tril(B) [..., C, C]. The module docstring has the block
+    form. All of it is float32 and its matmuls are ``HIGHEST``: A and B were
+    sums on the vector unit and the system a float32 solve before they were
+    matmuls, and nothing is rounded now that was not then."""
+    *lead, C, dk = q.shape
+    sub = min(SUB, C)
+    nb = C // sub
+    G = jnp.cumsum(g, axis=-2)
+    Gb, qb, kb = (a.reshape(*lead, nb, sub, dk) for a in (G, q, k))
+    first = Gb[..., :1, :]                                      # G at a block's first row
+    # The diagonal blocks, pairwise over dk: exponents G_i - G_j for j <= i
+    # and -inf elsewhere. A sum for B and a sum for A: each fuses with its
+    # products and the decays; one sum over stacked rows [q | k] leaves the
+    # decays' products in memory between two fusions (the chip reads 0.32 ms
+    # a piece against 0.86).
+    i = jnp.arange(sub)
+    decay = jnp.exp(jnp.where((i[:, None] >= i[None, :])[:, :, None],
+                              Gb[..., :, None, :] - Gb[..., None, :, :], -jnp.inf))
+    diag = jnp.stack([jnp.sum(r[..., :, None, :] * kb[..., None, :, :] * decay, axis=-1)
+                      for r in (qb, kb)], axis=-4)              # [..., 2, nb, sub, sub]
+    # Under them, block row I against every row j before its first, through
+    # G at that first row: both exponents are <= 0 (G falls along a chunk),
+    # and a later j has -inf.
+    before = (jnp.arange(C)[None, :] < jnp.arange(nb)[:, None] * sub)[:, :, None]
+    cols = k[..., None, :, :] * jnp.exp(
+        jnp.where(before, first - G[..., None, :, :], -jnp.inf))   # [..., nb, C, dk]
+    rows = jnp.stack([qb, kb], axis=-4) * jnp.exp(Gb - first)[..., None, :, :, :]
+    under = jnp.einsum("...xnid,...njd->...xnij", rows, cols,
+                       precision=_HIGHEST)                      # [..., 2, nb, sub, C]
+    on_diag = jnp.eye(nb, dtype=bool)[:, None, :, None]         # block I of block row I
+    AB = (under.reshape(*lead, 2, nb, sub, nb, sub)
+          + jnp.where(on_diag, diag[..., None, :], 0.0)).reshape(*lead, 2, C, C)
+    r = jnp.arange(C)
+    T = _unit_lower_inverse(
+        jnp.where(r[:, None] > r[None, :], beta[..., None] * AB[..., 1, :, :], 0.0))
+    eG, last = jnp.exp(G), G[..., -1:, :]
+    return k * eG, q * eG, k * jnp.exp(last - G), jnp.exp(last[..., 0, :]), T, AB[..., 0, :, :]
+
+
 def _chunk(S, x):
-    """One chunk of C tokens for every slot and head: S [B, H, dk, dv]; q,
-    k, g [B, H, C, dk]; v [B, H, C, dv]; beta [B, H, C] → (S_C, o [B, H, C,
-    dv]). The module docstring has the mathematics. Its matmuls (and the
-    triangular system) carry no ``precision``: on a TPU float32 operands are
+    """One chunk of C tokens for every slot and head: S [B, H, dk, dv]; q, k,
+    g [B, H, C, dk]; v [B, H, C, dv]; beta [B, H, C] → (S_C, o [B, H, C, dv]).
+    The module docstring has the mathematics. The four einsums that read or
+    write the state carry no ``precision``: on a TPU float32 operands are
     rounded to bfloat16 in one pass and summed in float32, so a piece reads
     the float32 state through bfloat16 once a chunk, where ``kda_step`` and
     the decode kernel are exact. A configuration states that beside its
-    state's type (``assumed.extend_matmul_precision``)."""
+    state's type (``assumed.extend_matmul_precision``). ``T·rhs`` is
+    ``HIGHEST`` as the solve it replaces was float32: at the default the
+    state a piece leaves stands 5.3e-3 from the recurrence's, not 3.7e-3."""
     q, k, v, g, beta = x
-    C = q.shape[2]
-    G = jnp.cumsum(g, axis=2)                                   # [B, H, C, dk]
-    # A and B in one reduction over the pairwise decays, rows [q | k]
-    # against k: exponents are G_i - G_j for j <= i and -inf elsewhere.
-    i = jnp.arange(C)
-    lower = i[:, None] >= i[None, :]                            # j <= i
-    diff = G[:, :, :, None, :] - G[:, :, None, :, :]            # [B, H, C, C, dk]
-    decay = jnp.exp(jnp.where(lower[:, :, None], diff, -jnp.inf))
-    both = jnp.stack([q, k], axis=2)                            # [B, H, 2, C, dk]
-    AB = jnp.sum(both[:, :, :, :, None, :] * k[:, :, None, None, :, :]
-                 * decay[:, :, None], axis=-1)                  # [B, H, 2, C, C]
-    Bq, A = AB[:, :, 0], AB[:, :, 1]
-    strict = i[:, None] > i[None, :]
-    M = jnp.eye(C, dtype=jnp.float32) + jnp.where(strict, beta[..., None] * A, 0.0)
-    eG = jnp.exp(G)
-    rhs = beta[..., None] * (v - jnp.einsum("bhck,bhkv->bhcv", k * eG, S))
-    U = jax.scipy.linalg.solve_triangular(M, rhs, lower=True, unit_diagonal=True)
-    o = jnp.einsum("bhck,bhkv->bhcv", q * eG, S) + jnp.einsum("bhij,bhjv->bhiv", Bq, U)
-    last = G[:, :, -1:, :]                                      # G_C
-    S = (jnp.exp(last[:, :, 0, :, None]) * S
-         + jnp.einsum("bhck,bhcv->bhkv", k * jnp.exp(last - G), U))
+    Kt, Qt, Kh, eGC, T, Bq = _chunk_blocks(q, k, g, beta)
+    rhs = beta[..., None] * (v - jnp.einsum("bhck,bhkv->bhcv", Kt, S))
+    U = _mm(T, rhs)
+    o = jnp.einsum("bhck,bhkv->bhcv", Qt, S) + jnp.einsum("bhij,bhjv->bhiv", Bq, U)
+    S = eGC[..., None] * S + jnp.einsum("bhck,bhcv->bhkv", Kh, U)
     return S, o
 
 
 def kda_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
     """The rule over T tokens in chunks. Shapes as ``kda_recurrent``. T need
-    be no multiple of ``chunk``: the rows that fill the last chunk have β = 0
-    and g = 0 and leave the state as it is."""
+    be no multiple of ``chunk``, nor a chunk of ``SUB``: the rows that fill
+    the last chunk, and every chunk to whole sub-blocks, have β = 0 and g = 0
+    and leave the state as it is."""
     B, T, H, _ = q.shape
     f32 = jnp.float32
     C = min(chunk, T)
     pad = -T % C
     N = (T + pad) // C
+    fill = -C % min(SUB, C)
 
-    def chunks(a):  # [B, T, H, ...] → [N, B, H, C, ...]
+    def chunks(a):  # [B, T, H, ...] → [N, B, H, C + fill, ...]
         a = jnp.pad(a.astype(f32), ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
         a = a.reshape(B, N, C, *a.shape[2:])
+        a = jnp.pad(a, ((0, 0), (0, 0), (0, fill)) + ((0, 0),) * (a.ndim - 3))
         return jnp.moveaxis(jnp.moveaxis(a, 1, 0), 2, 3)
 
     S, o = jax.lax.scan(_chunk, S0.astype(f32), tuple(map(chunks, (q, k, v, g, beta))))
-    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1).reshape(B, N * C, H, -1)
+    o = jnp.moveaxis(jnp.moveaxis(o[:, :, :, :C], 2, 3), 0, 1).reshape(B, N * C, H, -1)
     return o[:, :T], S
 
 

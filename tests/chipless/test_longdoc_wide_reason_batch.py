@@ -26,8 +26,10 @@ def test_linear_attention_cell_programs_fit_and_keep_the_state_in_place(
     holds the state kernel's and the latent kernel's Mosaic calls (a scan
     body a run of layers), the cache's three arrays are aliased through, and
     arguments and temporaries fit the chip; the piece holds neither kernel
-    (the chunk-wise rule is plain XLA) and the rule's pairwise decays are a
-    chunk's at a time, never a piece's 16 chunks at once."""
+    (the chunk-wise rule is plain XLA), and of that rule no `[64, 64, 128]`
+    pairwise tensor and no triangular solve: what is pairwise over dk is a
+    chunk's four diagonal 16-row blocks, and the unit-triangular system is
+    matmuls."""
     name = "kimi-linear-48b-a3b.longdoc-wide"
     cache = cell_programs.cell(name)[3]
     assert [c.shape for c in cache] == [(2, 64, 9216, 640), (6, 64, 32, 128, 128),
@@ -48,10 +50,24 @@ def test_linear_attention_cell_programs_fit_and_keep_the_state_in_place(
         assert memory.temp_size_in_bytes < 0.5e9
     else:
         assert not state_calls and not latent_calls
-        chunk = 32 * 64 * 64 * 128                       # heads x C x C x dk, one chunk
-        pairwise = [int(np.prod(dims)) for ln in text.splitlines()
-                    if (dims := result_dims(ln)) and dims[-3:] == [64, 64, 128]]
-        assert pairwise and max(pairwise) <= 2 * chunk   # [q | k] rows against k
+        dims = [d for ln in text.splitlines() if (d := result_dims(ln))]
+        assert not [d for d in dims if d[-3:] == [64, 64, 128]]
+        # pairwise over dk: two equal dims before the 128 lanes. One chunk's
+        # four diagonal blocks for its 32 heads, a sum for A and a sum for B.
+        pairwise = [d for d in dims if len(d) >= 3 and d[-1] == 128 and d[-2] == d[-3]]
+        assert pairwise and {tuple(d[-3:]) for d in pairwise} == {(16, 16, 128)}
+        assert max(int(np.prod(d)) for d in pairwise) == 32 * 4 * 16 * 16 * 128
+        # The parent's `solve_triangular` was this call, 64 rows one after
+        # another. The Mosaic kernels left are the experts' and the latent
+        # attention's, and under `kda.chunk` no call but the compiler's own
+        # buffers.
+        assert "InvertDiagBlocksLowerTriangular" not in text
+        assert "triangular_solve" not in text
+        kernels = set(re.findall(r"%([a-z_]+)[.\d]* = \S+ custom-call\(.*tpu_custom_call", text))
+        assert kernels == {"grouped_matmul", "prefill_attention"}, kernels
+        in_rule = {re.search(r'custom_call_target="([^"]+)"', ln).group(1)
+                   for ln in text.splitlines() if " custom-call(" in ln and "kda.chunk" in ln}
+        assert in_rule <= {"AllocateBuffer", "ConcatBitcast"}, in_rule
 
 
 @pytest.mark.parametrize("cell", ["longdoc-wide"])
