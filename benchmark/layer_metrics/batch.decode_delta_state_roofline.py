@@ -1,0 +1,32 @@
+"""The scalar-gated delta rule's state kernel's (`decode_delta_state`) share
+of its roofline, bound by HBM: the states it updates (the program's counter
+`decode_delta_slots`: live slots, a linear-attention layer a step, over its
+`decode_steps`), each read once and written once in float32
+(`decode_bytes/olmo_hybrid_bytes.py::state_bytes`), over the chips' HBM
+bandwidth, over the kernel's device seconds a step. The steps are the
+configuration's (`program.decode_kernel`'s calls over the layers that call
+it). Over 100 % is a wrong count, not a fast kernel. A program without the
+counter or the kernel (the parent of PR 50) reads None."""
+from harness.layer_common import DECODE_MODULE, decode_steps_in_trace
+from harness.manifest import load_decode_bytes
+
+LAYER, UNIT, BETTER = "kernels", "%", "higher"
+SOURCE, MOVES = "device_trace", "out_tokens_per_s_chip"
+
+KERNEL = "decode_delta_state"
+
+
+def read(ctx):
+    tr, steps = ctx.get("trace"), decode_steps_in_trace(ctx)
+    counters = (ctx.get("traced") or {}).get("counters", {})
+    slots, dispatched = counters.get("decode_delta_slots"), counters.get("decode_steps")
+    state_bytes = getattr(load_decode_bytes(ctx["model"]), "state_bytes", None)
+    if not tr or not steps or not slots or not dispatched or state_bytes is None:
+        return None
+    seconds = sum(s for name, (_n, s) in tr["ops_in_module"].get(DECODE_MODULE, {}).items()
+                  if name.split(".")[0] == KERNEL)
+    if not seconds:
+        return None
+    bytes_a_step = slots / dispatched * state_bytes(ctx["model"])
+    floor = bytes_a_step / (ctx["chips"] * ctx["peaks"]["hbm_bytes_per_s"])
+    return 100.0 * floor / (seconds / steps)

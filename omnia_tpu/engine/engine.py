@@ -460,15 +460,15 @@ class InferenceEngine(
             "kv_quant_device_bytes": cache_bytes(
                 *self._cache, self._pk, self._pv
             ),
-            # The expert layer of a chip that holds a share of the
-            # experts (ops/moe.py::moe_dropless), summed on the device
-            # over a decode chunk's steps and layers and read back with
-            # its tokens: the (token, expert) assignments that landed on
-            # an expert held here, and the held experts that got at least
-            # one token, a layer a step, over every row of the batch, live or
-            # not; decode_kda_slots the same way: the states linear-attention
-            # layers updated, live slots a layer a step. Zero without either.
-            "moe_assignments_held": 0, "moe_experts_hit": 0, "decode_kda_slots": 0,
+            # The expert layer of a chip that holds a share of the experts
+            # (ops/moe.py::moe_dropless), summed on the device over a decode chunk's
+            # steps and layers and read back with its tokens: the (token, expert)
+            # assignments that landed on an expert held here, and the held experts
+            # that got a token, a layer a step, over every row of the batch, live or
+            # not; decode_kda_slots (the latent family's) and decode_delta_slots (the
+            # pair family's): the states linear-attention layers updated, the same way.
+            "moe_assignments_held": 0, "moe_experts_hit": 0,
+            "decode_kda_slots": 0, "decode_delta_slots": 0,
             # Paged KV cache (engine/kv_pages.py) — pool gauges, live
             # while kv_pages > 0 and zero otherwise: usable pages total/
             # free, internal fragmentation of slot-referenced pages

@@ -9,7 +9,9 @@ no row axis at all: a slot's view takes its axis 2, the heads, whole) and
 the short convolution's tail. The programs and mixins that every
 family has (prefill, extend, decode, warmup) take and return it whole, and
 never index it: which rows and steps may touch a slot's state is the model
-module's word (models/mla.py::_kda_layer), since nothing masks a state by
+module's word (models/mla.py::_kda_layer, models/stacks.py::_delta_mixer for
+the pair family's linear-attention layers, whose cache is K and V of the
+full layers beside such states and tails), since nothing masks a state by
 position afterwards. What exists for the pair family alone names the
 pair's two arrays, and is refused for another family when the engine is
 built."""
@@ -28,26 +30,17 @@ def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
     nothing falls through to a (K, V) pair silently. Two kinds of model are
     refused so: one of the latent family (models/mla.py), and one of the
     pair family whose layers are of several kinds (models/llama.py's stacks:
-    window layers, a share of the routed experts, leading dense layers)."""
+    window layers, linear-attention layers, a share of the routed experts,
+    leading dense layers). What a recurrent state rules out is said for a
+    model of either family that has one."""
     if model_cfg.is_latent:
         family, why = "the latent-attention family (models/mla.py", {}
-        if model_cfg.has_state_layers:
-            state = ("its linear-attention layers keep a recurrent state a slot, a "
-                     "matrix a head that every token of the context is summed into, "
-                     "with no rows")
-            why = {
-                "max_sessions": f"{state}: a session's rows are offloaded and restored "
-                                "by position, and a state has no rows to offload",
-                "prefix_cache_slots": f"{state}: a shared prefix is seeded by copying its "
-                                      "rows, and the state at the prefix's end is kept nowhere",
-                "kv_pages": f"{state}: a page table maps positions to rows, and a state "
-                            "has none to page",
-                "spec_decode": f"{state}: a rejected proposal is rolled back by moving the "
-                               "frontier, and a state that has taken a token cannot give it back",
-                "prefill_chunk_tokens": "the mixed step takes the pair's two arrays, and "
-                                        "runs a decode step over a slot between its "
-                                        "placement's pieces, which a state must not get",
-            }
+        # (its blocks norm a sublayer's input, and no q or k has a whole width)
+        for name, plain in (("norm_placement", "pre"), ("qk_norm_whole", False)):
+            if getattr(model_cfg, name) != plain:
+                raise NotImplementedError(
+                    f"ModelConfig.{name}={getattr(model_cfg, name)!r} is not ported to "
+                    f"{family}; model {model_cfg.name!r})")
     elif llama.is_stacked(model_cfg):
         family = "a model of several kinds of layers (models/llama.py's stacks"
         rings = ("its window layers' cache is a ring, whose row is not a position"
@@ -74,6 +67,24 @@ def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
         }
     else:
         return
+    if model_cfg.has_state_layers:  # of either family: these reasons come first
+        state = ("its linear-attention layers keep a recurrent state a slot, a "
+                 "matrix a head that every token of the context is summed into, "
+                 "with no rows")
+        why = {
+            **why,
+            "max_sessions": f"{state}: a session's rows are offloaded and restored "
+                            "by position, and a state has no rows to offload",
+            "prefix_cache_slots": f"{state}: a shared prefix is seeded by copying its "
+                                  "rows, and the state at the prefix's end is kept nowhere",
+            "kv_pages": f"{state}: a page table maps positions to rows, and a state "
+                        "has none to page",
+            "spec_decode": f"{state}: a rejected proposal is rolled back by moving the "
+                           "frontier, and a state that has taken a token cannot give it back",
+            "prefill_chunk_tokens": "the mixed step takes the pair's two arrays, and "
+                                    "runs a decode step over a slot between its "
+                                    "placement's pieces, which a state must not get",
+        }
     asked = {
         "kv_quant": cfg.kv_quant, "kv_pages": cfg.kv_pages > 0,
         "max_sessions": cfg.max_sessions > 0,

@@ -607,6 +607,10 @@ class _WarmupMixin:
             })
 
         self._warmup_slot_programs()
+        # The warmup states' arrays go before the restore allocates their
+        # successors: a cache that fills most of the chip (a 7.6 GB one
+        # beside 4.9 GB of weights) cannot stand beside a second one.
+        del states, st
 
         cs.begin_phase("warmup_restore")
         self.metrics["warmup_phase"] = PHASE_CODES["warmup_restore"]

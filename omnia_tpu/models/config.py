@@ -133,6 +133,31 @@ class ModelConfig:
     kda_head_dim: int = 0
     kda_conv_kernel: int = 4
     kda_gate_rank: int = 0
+    # Linear-attention layers in the pair family (models/stacks.py; the
+    # same ``layer_types`` word): the gated delta rule with ONE decay a head
+    # (ops/delta.py) over linear_num_heads heads whose keys are
+    # linear_key_head_dim wide and whose values linear_value_head_dim,
+    # behind a depthwise causal convolution of linear_conv_kernel taps on
+    # q, k and v each; the write strength β is a sigmoid, times 2 where
+    # linear_allow_neg_eigval (I − β k kᵀ then has eigenvalues down to −1);
+    # the output gate is SiLU of a full-rank projection. A slot's state is
+    # a float32 [key width, value width] matrix a head and the
+    # convolutions' last linear_conv_kernel - 1 input rows.
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    linear_allow_neg_eigval: bool = False
+    # Where a block's RMSNorms stand in the pair family's stacks: "pre", in
+    # front of each sublayer (x + f(norm(x)): every model before), or
+    # "post", on each sublayer's output with nothing in front
+    # (x + norm(f(x)): the OLMo 2/3 block).
+    norm_placement: str = "pre"
+    # With qk_norm, the width one RMSNorm spans: False, each head's
+    # head_dim values with one gain [head_dim] for all heads; True, the
+    # whole projected width before the heads are split, gains [q_dim] and
+    # [kv_dim].
+    qk_norm_whole: bool = False
 
     @property
     def q_dim(self) -> int:
@@ -167,14 +192,15 @@ class ModelConfig:
 
     @property
     def attention_kinds(self) -> tuple:
-        """"window", "full" or "kda" for layer 0 ... num_layers - 1."""
+        """"window", "full" or the family's linear kind ("kda" in the latent
+        family, "delta" in the pair family) for layer 0 ... num_layers - 1."""
         if self.layer_types is None:
             return ("full",) * self.num_layers
         if len(self.layer_types) < self.num_layers:
             raise ValueError(f"layer_types names {len(self.layer_types)} layers "
                              f"of {self.num_layers}")
         kinds = {"sliding_attention": "window", "full_attention": "full",
-                 "linear_attention": "kda"}
+                 "linear_attention": "kda" if self.is_latent else "delta"}
         return tuple(kinds[t] for t in self.layer_types[:self.num_layers])
 
     @property
@@ -186,20 +212,27 @@ class ModelConfig:
     def has_state_layers(self) -> bool:
         """Whether a slot's cache holds a recurrent state (linear-attention
         layers, or the stacks of a model cut out of one that has them)."""
-        return "kda" in self.attention_kinds or any(
-            "kda" in kind for kind in self.layer_stacks or ())
+        return any(kind.endswith(("kda", "delta"))
+                   for kind in self.attention_kinds + (self.layer_stacks or ()))
 
     def num_params(self) -> int:
         """Parameters this chip holds (for memory planning): of a model
         with a share of the routed experts (moe_ffn_hidden_size), the held
         ones beside the shared expert and the router, in the layers behind
-        the num_dense_layers leading ones; a QK-norm's two gains a layer.
+        the num_dense_layers leading ones; a QK-norm's two gains a layer; a
+        linear-attention layer's projections, taps, gates and head norm.
         Exact for the pair family; the latent family's attention and
         hyper-connection maps are not counted (models/mla.py::init_params
         is their word)."""
         d, f, v = self.hidden_size, self.ffn_hidden_size, self.vocab_size
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-        attn += 2 * self.head_dim * self.qk_norm
+        attn += ((self.q_dim + self.kv_dim if self.qk_norm_whole else 2 * self.head_dim)
+                 * self.qk_norm)
+        hl, dv = self.linear_num_heads, self.linear_value_head_dim
+        width = hl * (2 * self.linear_key_head_dim + dv)       # q | k | v
+        linear = ((d + self.linear_conv_kernel) * width + 2 * d * hl + 2 * hl
+                  + 2 * d * hl * dv + dv)
+        n_linear = self.attention_kinds.count("delta")
         dense_mlp, dense = 3 * d * f, self.num_layers
         sparse_mlp = 0
         if self.moe_ffn_hidden_size:
@@ -210,7 +243,8 @@ class ModelConfig:
         elif self.is_moe:
             dense_mlp = self.num_experts * 3 * d * f + d * self.num_experts
         embed = v * d * (1 if self.tie_embeddings else 2)
-        return (self.num_layers * (attn + 2 * d) + dense * dense_mlp
+        return (self.num_layers * (attn + 2 * d) + n_linear * (linear - attn)
+                + dense * dense_mlp
                 + (self.num_layers - dense) * sparse_mlp + embed + d)
 
 
@@ -449,6 +483,34 @@ PRESETS: dict[str, ModelConfig] = {
         sliding_window=8,
         qk_norm=True,
         rope_full_yarn=(16.0, 64, 32.0, 1.0, 1.2772588722239782),
+    ),
+    # The pair family with linear-attention layers: L L L F, six heads whose
+    # keys are 8 wide and values 16 (no multiple of a kernel's head block, and
+    # dk != dv), one decay a head, β in (0, 2); the full layer has six
+    # un-grouped heads, no rotary position and a QK-norm over the whole
+    # width; both norms of a block stand on the sublayers' outputs.
+    "test-tiny-delta": ModelConfig(
+        name="test-tiny-delta",
+        vocab_size=256,
+        hidden_size=96,
+        num_layers=4,
+        num_heads=6,
+        num_kv_heads=6,
+        head_dim=16,
+        ffn_hidden_size=128,
+        rms_norm_eps=1e-6,
+        max_seq_len=512,
+        layer_types=("linear_attention", "linear_attention", "linear_attention",
+                     "full_attention"),
+        rope_on_full_layers=False,
+        qk_norm=True,
+        qk_norm_whole=True,
+        norm_placement="post",
+        linear_num_heads=6,
+        linear_key_head_dim=8,
+        linear_value_head_dim=16,
+        linear_conv_kernel=4,
+        linear_allow_neg_eigval=True,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

@@ -23,11 +23,14 @@ models; see SURVEY.md §0):
   GSPMD inserts the collectives; there are no explicit psums here.
 - Compute dtype bf16 (MXU native), logits and softmax statistics f32.
 
-**Layers of several kinds** (``is_stacked(cfg)``: window layers, a share of
-the routed experts, leading dense layers) are models/stacks.py's, which this
+**Layers of several kinds** (``is_stacked(cfg)``: window layers,
+linear-attention layers, a share of the routed experts, leading dense
+layers, norms on the sublayers' outputs) are models/stacks.py's, which this
 module dispatches to at the top of each entry point: ``params["layers"]`` is
 then a sequence of stacks, the cache of a model with window layers four
-arrays (whole contexts beside rings), and ``layer_order`` /
+arrays (whole contexts beside rings), that of a model with linear-attention
+layers four too (whole contexts, float32 states, convolution tails), and
+``layer_order`` /
 ``with_layer_order`` state and cut the order. Everything above is the model
 whose layers are all alike, which traces none of that and compiles to the
 programs it always had.
@@ -54,6 +57,8 @@ from omnia_tpu.models.quant import qdot
 from omnia_tpu.models.stacks import (  # noqa: F401  (the module contract's names)
     _init_stacks,
     _run_stacks,
+    cache_kv_heads,
+    conv_width,
     decode_counters,
     decode_window_rows,
     is_stacked,
@@ -207,14 +212,31 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloat16,
     pairs (int8 rows + per-row-per-head f32 scales) when kv_quant is
     set. kv_quant=None allocates no scale tensors at all. A model with
     window layers: (k, v) of its full layers at ``seq`` rows, then (k, v) of
-    its window layers at the ring's (``ring_rows``), whatever ``seq``."""
+    its window layers at the ring's (``ring_rows``), whatever ``seq``. A
+    model with linear-attention layers: (k, v) of its full layers, then the
+    delta layers' states [Ld, B, H, dk, dv] (float32 whatever ``dtype``) and
+    their convolutions' tails [Ld, B, taps - 1, 2·H·dk + H·dv]. Either
+    model's rows hold ``cache_kv_heads`` heads (more than ``num_kv_heads``
+    where that is over eight and no whole number of eights)."""
     shape = (cfg.num_layers, batch, seq, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.has_state_layers:
+        if kv_quant or cfg.has_window_layers:
+            raise NotImplementedError("kv_quant and window layers are not ported to a "
+                                      "cache with recurrent states")
+        kinds = cfg.attention_kinds
+        full = (kinds.count("full"), batch, seq, cache_kv_heads(cfg), cfg.head_dim)
+        H, dk, dv = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        Ld = kinds.count("delta")
+        return (jnp.zeros(full, dtype=dtype), jnp.zeros(full, dtype=dtype),
+                jnp.zeros((Ld, batch, H, dk, dv), dtype=jnp.float32),
+                jnp.zeros((Ld, batch, cfg.linear_conv_kernel - 1, conv_width(cfg)), dtype=dtype))
     if cfg.has_window_layers:
         if kv_quant:
             raise NotImplementedError("kv_quant is not ported to a cache with rings")
         kinds = cfg.attention_kinds
-        full = (kinds.count("full"), *shape[1:])
-        ring = (kinds.count("window"), batch, ring_rows(cfg), *shape[3:])
+        heads = (cache_kv_heads(cfg), cfg.head_dim)
+        full = (kinds.count("full"), batch, seq, *heads)
+        ring = (kinds.count("window"), batch, ring_rows(cfg), *heads)
         return tuple(jnp.zeros(shape, dtype=dtype) for shape in (full, full, ring, ring))
     if validate_kv_quant(kv_quant):
         def one():
@@ -483,7 +505,10 @@ def forward(params, cfg: ModelConfig, tokens, q_positions, *cache_and_start,
         x, cache, counts = _run_stacks(params, cfg, x, rope, q_positions,
                                        tuple(cache), write_start, row, mesh, live)
         out = (_logits_at(params, cfg, x, row), *cache)
-        return (*out, counts) if counters else out
+        # (a model without experts counts nothing for them: the counters it
+        # names are the last of ``_run_stacks``', none of them if it names none)
+        named = len(decode_counters(cfg))
+        return (*out, counts[counts.shape[0] - named:]) if counters else out
     cache_k, cache_v = cache
     cos, sin = rope
 

@@ -1,10 +1,12 @@
 """Layers of several kinds in the pair family (models/llama.py dispatches
-here for a model with window layers, experts of ``moe_ffn_hidden_size`` or
-leading dense layers): stacks, runs and rings.
+here for a model with window layers, linear-attention layers, experts of
+``moe_ffn_hidden_size`` or leading dense layers): stacks, runs, rings and
+states.
 
 A layer has an attention kind, *window*
 (``cfg.layer_types`` "sliding_attention": a query sees its own row and the
-``sliding_window`` - 1 before it) or *full*, and an FFN kind, *dense* (SwiGLU
+``sliding_window`` - 1 before it), *full* or *delta* ("linear_attention": the
+gated delta rule with one decay a head, below), and an FFN kind, *dense* (SwiGLU
 of ``ffn_hidden_size``) or *sparse* (``ops/moe.py::expert_ffn``: the sigmoid
 or softmax router over all experts, the experts held here, all of them or a
 rank's share, through ``moe_dropless``, and the shared expert where the model
@@ -18,7 +20,30 @@ of consecutive layers of one kind, under the scope ``stack.<kind>``, over the
 run's indices into its stack: the stack's leaves are read a layer at a time
 where they lie, the routed experts' never sliced at all. With ``cfg.qk_norm``
 every query and key head is RMS-normed (one gain ``[head_dim]``) before any
-rotation (``attn.qk_norm``).
+rotation (``attn.qk_norm``); with ``cfg.qk_norm_whole`` besides, q and k are
+normed over their whole projected width (gains ``[q_dim]``, ``[kv_dim]``)
+before the heads are split. ``cfg.norm_placement`` "post" puts a block's two
+RMSNorms on the sublayers' outputs, ``x + norm(f(x))``, with nothing in front.
+
+*A delta layer* (``_delta_mixer``; ops/delta.py is the rule, three ways):
+``[q~ | k~ | v~] = h·Wqkv`` (H heads of dk, dk and dv), a depthwise causal
+convolution of ``linear_conv_kernel`` taps and SiLU on each, q and k
+L2-normed a head (q times dk^-½), ``β = sigmoid(h·Wb)`` (times 2 where
+``linear_allow_neg_eigval``), ``g = −exp(A_log)·softplus(h·Wa + dt_bias)`` one
+number a head, the rule over a float32 state ``[dk, dv]`` a head, then
+``RMSNorm_dv(o)·SiLU(h·Wg)`` through ``Wo``. Scope ``attn.delta`` from the
+sublayer's input to the residual, inside it ``delta.conv``, ``delta.gates``,
+``delta.chunk`` (T > 1), ``delta.state`` (a decode step), ``delta.out``;
+counter ``decode_delta_slots`` (the states a decode step updates: live slots
+a delta layer). Such a model's cache is four arrays: K and V of the full
+layers, the delta layers' states ``[Ld, B, H, dk, dv]`` float32 whatever the
+stream's type, and their convolutions' tails ``[Ld, B, taps - 1, 2·H·dk +
+H·dv]``. A state has no position to mask by afterwards, so which rows and
+steps may touch it is ``_delta_mixer``'s word, and it is models/mla.py::
+``_kda_layer``'s: a piece's pad rows get β = 0 and g = 0 and the tail kept
+is the last REAL row's; a piece at position 0 starts from S = 0 and a zero
+tail whatever the slot holds; a decode step leaves a dead slot's state and
+tail as they are. (Window layers beside delta layers are not built.)
 
 *A rotary table a kind of attention layer* (``rope_tables``, made once a
 program under ``rope.tables``; a layer turns its q and k by its kind's under
@@ -60,7 +85,9 @@ seam), and which rows of it mean what is this module's word alone:
 
 Not ported to a model of several kinds, and refused by name at engine
 construction (engine/family.py): kv_quant, kv_pages, sessions, the prefix
-pool, spec_decode, the mixed step, int8 weights, sp, tp/dp > 1.
+pool, spec_decode, the mixed step, int8 weights, sp, tp/dp > 1 (and for a
+model with delta layers it says why a recurrent state cannot have its rows
+offloaded, seeded, paged or rolled back).
 """
 
 from __future__ import annotations
@@ -72,12 +99,17 @@ from omnia_tpu.models import kinds
 from omnia_tpu.models.config import ModelConfig
 from omnia_tpu.ops import attention as _attention
 from omnia_tpu.ops.attention import decode_block_rows, gqa_attention
+from omnia_tpu.ops.delta import decode_delta_state, delta_chunked
 from omnia_tpu.ops.moe import EXPERT_COUNTERS, expert_ffn, init_ffn, unstack_experts
 from omnia_tpu.ops.norms import rms_norm
 from omnia_tpu.ops.rope import apply_rope, rope_cos_sin, yarn_scaled_cos_sin
 
-#: Every kind a layer can be, in the order a model's stacks stand in.
-_KINDS = ("dense_window", "dense_full", "sparse_window", "sparse_full")
+#: Every kind a layer can be, in the order a model's stacks stand in (a
+#: stack's seed is its place here: a new kind goes behind the others).
+_KINDS = ("dense_window", "dense_full", "sparse_window", "sparse_full", "dense_delta")
+
+#: The ε inside the square root of a delta head's key and query norms.
+_L2_EPS = 1e-6
 
 
 def is_stacked(cfg: ModelConfig) -> bool:
@@ -85,14 +117,37 @@ def is_stacked(cfg: ModelConfig) -> bool:
     window layers, a share of the routed experts or leading dense layers
     (or one cut out of such a model)."""
     return bool(cfg.layer_types is not None or cfg.layer_stacks is not None
-                or cfg.moe_ffn_hidden_size or cfg.num_dense_layers)
+                or cfg.moe_ffn_hidden_size or cfg.num_dense_layers
+                or cfg.norm_placement != "pre")
 
 
 def decode_counters(cfg: ModelConfig) -> tuple:
     """Counters a decode step sums on the device over its layers, in the
     order ``forward(..., counters=True)`` returns them (engine.metrics keys):
-    the expert layer's, for a model that has one."""
-    return EXPERT_COUNTERS if cfg.moe_ffn_hidden_size else ()
+    the expert layer's, for a model that has one, and the states a step
+    updates (live slots a delta layer), for a model with delta layers."""
+    return ((EXPERT_COUNTERS if cfg.moe_ffn_hidden_size else ())
+            + (("decode_delta_slots",) if cfg.has_state_layers else ()))
+
+
+def cache_kv_heads(cfg: ModelConfig) -> int:
+    """KV heads of a cached row as a model of several kinds allocates it:
+    ``num_kv_heads``, rounded up to whole eights where there are more than
+    eight. The chip lays an array [.., rows, heads, head_dim] out with the
+    rows INSIDE the heads where the heads are no whole number of sublane
+    tiles (30: its default layout for [3072, 30, 128] is heads-major), and
+    every program would then copy the whole cache in and out to hand its
+    kernels rows (the chipless compile of a 30-head cache showed two copies
+    of K and V a decode chunk). The heads behind ``num_kv_heads`` hold zeros:
+    a layer pads its k and v, and its q by as many groups, and drops the
+    padded heads' output (``_attention_sublayer``)."""
+    H = cfg.num_kv_heads
+    return H if H <= 8 else -(-H // 8) * 8
+
+
+def conv_width(cfg: ModelConfig) -> int:
+    """Columns of a delta layer's ``wqkv``, its taps and its tail: q | k | v."""
+    return cfg.linear_num_heads * (2 * cfg.linear_key_head_dim + cfg.linear_value_head_dim)
 
 
 def ring_rows(cfg: ModelConfig) -> int:
@@ -159,7 +214,18 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array, dtype):
     exists; a selection bias is ``mlp/bias`` [E] float32, the midpoints of
     N(0, 0.05)'s equal shares in a seeded order, the same on every rank:
     large enough to change which experts are kept, and neither a rank's
-    load nor the count of experts a step hits depends on the seed."""
+    load nor the count of experts a step hits depends on the seed. A delta
+    layer: ``wqkv`` (q | k | v before the convolution), ``conv`` [taps, that
+    width] normal(0, taps^-½), ``wa``, ``wb``, the gate's ``wg``, the head norm's
+    ``on``, ``wo``, and in float32 ``a_log`` = log U(1, 16) and ``dt_bias`` the
+    inverse softplus of a step log-uniform in 1e-3 … 0.1, a head each: a
+    token's decay then lies in about 0.2 … 0.999 as a trained model's does
+    (near 0 it would empty the state every token)."""
+    if cfg.has_window_layers and cfg.has_state_layers:
+        raise NotImplementedError("window layers beside linear-attention layers in one "
+                                  "model are not built (models/stacks.py)")
+    if cfg.norm_placement not in ("pre", "post"):
+        raise ValueError(f"norm_placement {cfg.norm_placement!r}: \"pre\" or \"post\"")
     D, V, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
     out_std = 0.02 / (2 * max(L, 1)) ** 0.5
     counts = kinds.stack_counts(cfg, _KINDS)
@@ -170,11 +236,29 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array, dtype):
         def normal(shape, std=0.02, dtype=dtype):
             return (jax.random.normal(next(keys), shape, dtype=jnp.float32) * std).astype(dtype)
 
-        attn = {"wq": normal((c, D, cfg.q_dim)), "wk": normal((c, D, cfg.kv_dim)),
-                "wv": normal((c, D, cfg.kv_dim)), "wo": normal((c, cfg.q_dim, D), std=out_std)}
-        if cfg.qk_norm:
-            attn["qn"] = jnp.ones((c, cfg.head_dim), dtype)
-            attn["kn"] = jnp.ones((c, cfg.head_dim), dtype)
+        def log_uniform(shape, lo, hi):
+            return jnp.exp(jax.random.uniform(next(keys), shape, jnp.float32,
+                                              jnp.log(lo), jnp.log(hi)))
+
+        if kind.endswith("delta"):
+            H, dv, taps = cfg.linear_num_heads, cfg.linear_value_head_dim, cfg.linear_conv_kernel
+            step = log_uniform((c, H), 1e-3, 0.1)
+            attn = {"wqkv": normal((c, D, conv_width(cfg))),
+                    "conv": normal((c, taps, conv_width(cfg)), std=taps ** -0.5),
+                    "wa": normal((c, D, H)), "wb": normal((c, D, H)),
+                    "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                    "a_log": jnp.log(log_uniform((c, H), 1.0, 16.0)),
+                    "wg": normal((c, D, H * dv)), "on": jnp.ones((c, dv), dtype),
+                    "wo": normal((c, H * dv, D), std=out_std)}
+        else:
+            attn = {"wq": normal((c, D, cfg.q_dim)), "wk": normal((c, D, cfg.kv_dim)),
+                    "wv": normal((c, D, cfg.kv_dim)),
+                    "wo": normal((c, cfg.q_dim, D), std=out_std)}
+            if cfg.qk_norm:
+                q_gain, k_gain = ((cfg.q_dim, cfg.kv_dim) if cfg.qk_norm_whole
+                                  else (cfg.head_dim, cfg.head_dim))
+                attn["qn"] = jnp.ones((c, q_gain), dtype)
+                attn["kn"] = jnp.ones((c, k_gain), dtype)
         mlp = init_ffn(cfg, c, kind.startswith("sparse"), normal, out_std, lambda: next(keys))
         return {"ln1": jnp.ones((c, D), dtype), "ln2": jnp.ones((c, D), dtype),
                 "attn": attn, "mlp": mlp}
@@ -239,6 +323,86 @@ def _ring_rows_before(ring, start, window: int, layer):
     return jnp.take_along_axis(held, rows[:, :, None, None], axis=1)
 
 
+def _delta_mixer(h, a, cfg: ModelConfig, cache, cache_layer, write_start, n_real, live):
+    """A delta layer between its input ``h`` [B, T, D] and what it adds to the
+    residual (the module docstring has the mathematics), ``cache_layer`` its
+    index among the delta layers. ``cache``: (states [Ld, B, H, dk, dv]
+    float32, tails [Ld, B, taps - 1, ``conv_width``]) whole, or None for a
+    fresh chunk, which starts from zero and gets its (state, tail) back
+    instead. Which rows and steps may touch a state is said here alone:
+    - of a chunk's T rows the first ``n_real`` [B] count; the pad behind them
+      gets β = 0 and g = 0, which leaves S as it is, and the tail kept is the
+      last REAL row's;
+    - a chunk (T > 1) at position 0 is a new tenant's first: it starts from
+      S = 0 and a zero tail whatever the slot holds;
+    - a decode step (T == 1) leaves a slot that is not ``live`` as it is.
+
+    → (out [B, T, D], (states, tails) or (state, tail), states updated int32)."""
+    B, T, _ = h.shape
+    H, dk, dv, taps = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                       cfg.linear_value_head_dim, cfg.linear_conv_kernel)
+    f32 = jnp.float32
+    step = T == 1 and cache is not None
+    pre = jnp.dot(h, a["wqkv"])                                    # q | k | v
+    with jax.named_scope("delta.conv"):
+        if cache is None:
+            tail = jnp.zeros((B, taps - 1, pre.shape[-1]), pre.dtype)
+        else:
+            states, tails = cache
+            tail = jax.lax.dynamic_index_in_dim(tails, cache_layer, 0, keepdims=False)
+            if not step:
+                fresh = write_start == 0
+                tail = jnp.where(fresh[:, None, None], 0, tail)
+        rows = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
+        u = jax.nn.silu(sum(a["conv"][j].astype(f32) * rows[:, j:j + T].astype(f32)
+                            for j in range(taps)))
+        q, k, v = (t.reshape(B, T, H, -1) for t in jnp.split(u, (H * dk, 2 * H * dk), axis=-1))
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + _L2_EPS) * dk ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + _L2_EPS)
+        # What the next chunk's first taps see: the rows before the last
+        # real one, never the pad's.
+        kept = jax.vmap(lambda r, n: jax.lax.dynamic_slice_in_dim(r, n, taps - 1, 0))(
+            rows, n_real)
+        if step and live is not None:
+            kept = jnp.where(live[:, None, None], kept, tail.astype(kept.dtype))
+    with jax.named_scope("delta.gates"):
+        g = -jnp.exp(a["a_log"].astype(f32)) * jax.nn.softplus(
+            jnp.dot(h, a["wa"], preferred_element_type=f32) + a["dt_bias"].astype(f32))
+        beta = jax.nn.sigmoid(jnp.dot(h, a["wb"], preferred_element_type=f32))
+        if cfg.linear_allow_neg_eigval:
+            beta = 2.0 * beta
+        gate = jax.nn.silu(jnp.dot(h, a["wg"], preferred_element_type=f32)).reshape(B, T, H, dv)
+        real = jnp.arange(T, dtype=jnp.int32)[None, :] < n_real[:, None]
+        g = jnp.where(real[:, :, None], g, 0.0)
+        beta = jnp.where(real[:, :, None], beta, 0.0)
+    if step:
+        with jax.named_scope("delta.state"):
+            o, states = decode_delta_state(
+                states, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], cache_layer, live,
+                kernel=_attention._kernel_on(),
+                interpret=_attention._pallas_decode_mode() == "interpret")
+            o = o[:, None]
+        updated = jnp.sum(live, dtype=jnp.int32) if live is not None else jnp.int32(B)
+    else:
+        with jax.named_scope("delta.chunk"):
+            S = jnp.zeros((B, H, dk, dv), f32)
+            if cache is not None:
+                S = jax.lax.dynamic_index_in_dim(states, cache_layer, 0, keepdims=False)
+                S = jnp.where(fresh[:, None, None, None], 0.0, S)
+            o, S = delta_chunked(q, k, v, g, beta, S)
+            if cache is not None:
+                states = jax.lax.dynamic_update_slice_in_dim(states, S[None], cache_layer, 0)
+        updated = jnp.int32(0)
+    if cache is not None:
+        with jax.named_scope("delta.conv"):
+            tails = jax.lax.dynamic_update_slice_in_dim(
+                tails, kept.astype(tails.dtype)[None], cache_layer, 0)
+    with jax.named_scope("delta.out"):
+        y = rms_norm(o, a["on"], cfg.rms_norm_eps) * gate
+        out = jnp.dot(y.astype(h.dtype).reshape(B, T, H * dv), a["wo"])
+    return out, ((states, tails) if cache is not None else (S, kept)), updated
+
+
 def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
                  cache, cache_layer, write_start, n_real, mesh, live, attn_fn=None):
     """One block of a model of several kinds: ``kind`` its stack's, ``at`` its
@@ -247,20 +411,61 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
     (a kind that is not among them is not rotated). ``cache``: the whole
     tuple (the module docstring), or None for a chunk on its own (training, a fresh
     prefill), which gets its rows back instead: (k, v) [B, T, Hkv, D] of a
-    full layer, [B, R, Hkv, D] of a window layer. ``attn_fn`` overrides a full
+    full layer, [B, R, Hkv, D] of a window layer, (state, tail) of a delta
+    layer. ``attn_fn`` overrides a full
     layer's attention over a chunk on its own (training: the einsums), and
-    with one a window layer takes the einsum band. → (x, cache or rows,
-    counts int32 [2] as EXPERT_COUNTERS)."""
+    with one a window layer takes the einsum band. ``cfg.norm_placement``:
+    ``ln1`` and ``ln2`` in front of the sublayers ("pre") or on their outputs.
+    → (x, cache or rows, counts int32 as EXPERT_COUNTERS, and behind them the
+    states updated for a model with delta layers)."""
     B, T, _ = x.shape
     attention = kind.split("_")[1]
+    pre = cfg.norm_placement == "pre"
+    eps = cfg.rms_norm_eps
+    if attention == "delta":
+        with jax.named_scope("attn.delta"):  # delta.conv/gates/chunk/state/out inside
+            out, kept, updated = _delta_mixer(
+                rms_norm(x, p["ln1"], eps) if pre else x, p["attn"], cfg,
+                None if cache is None else cache[-2:], cache_layer, write_start, n_real, live)
+            x = x + (out if pre else rms_norm(out, p["ln1"], eps))
+        if cache is not None:
+            kept = (*cache[:-2], *kept)
+    else:
+        updated = jnp.int32(0)
+        x, kept = _attention_sublayer(x, p, attention, cfg, rope, q_positions, cache,
+                                      cache_layer, write_start, n_real, mesh, live, attn_fn)
+    with jax.named_scope("mlp"):  # moe.route/sort/experts/combine/shared inside
+        y, counts = expert_ffn(rms_norm(x, p["ln2"], eps) if pre else x, p["mlp"],
+                               experts, at, cfg)
+        if not pre:
+            y = rms_norm(y, p["ln2"], eps)
+    if cfg.has_state_layers:
+        counts = jnp.concatenate([counts, updated[None]])
+    return x + y, kept, counts
+
+
+def _attention_sublayer(x, p, attention, cfg: ModelConfig, rope, q_positions, cache,
+                        cache_layer, write_start, n_real, mesh, live, attn_fn):
+    """A window or full layer's attention with its residual: → (x, cache or
+    the chunk's rows), as ``_stack_layer`` sets out."""
+    B, T, _ = x.shape
     window = cfg.sliding_window if attention == "window" else 0
+    pre = cfg.norm_placement == "pre"
     a = p["attn"]
     with jax.named_scope("attn.qkv"):
-        h = rms_norm(x, p["ln1"], cfg.rms_norm_eps)
-        q = jnp.dot(h, a["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-        k = jnp.dot(h, a["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-        v = jnp.dot(h, a["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
+        h = rms_norm(x, p["ln1"], cfg.rms_norm_eps) if pre else x
+        whole = cfg.qk_norm and cfg.qk_norm_whole
+
+        def heads(t, n, gain=None):  # a whole-width norm comes before the split
+            if whole and gain:
+                with jax.named_scope("attn.qk_norm"):
+                    t = rms_norm(t, a[gain], cfg.rms_norm_eps)
+            return t.reshape(B, T, n, cfg.head_dim)
+
+        q = heads(jnp.dot(h, a["wq"]), cfg.num_heads, "qn")
+        k = heads(jnp.dot(h, a["wk"]), cfg.num_kv_heads, "kn")
+        v = heads(jnp.dot(h, a["wv"]), cfg.num_kv_heads)
+    if cfg.qk_norm and not whole:
         with jax.named_scope("attn.qk_norm"):
             q = rms_norm(q, a["qn"], cfg.rms_norm_eps)
             k = rms_norm(k, a["kn"], cfg.rms_norm_eps)
@@ -268,6 +473,13 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
         cos, sin = rope[attention]
         with jax.named_scope("attn.rope"):
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    spare = cache_kv_heads(cfg) - cfg.num_kv_heads
+    if spare:  # zero heads up to the cache's count, a whole group of queries each
+        def more(t, n):
+            return jnp.pad(t, ((0, 0), (0, 0), (0, n), (0, 0)))
+
+        q = more(q, spare * (cfg.num_heads // cfg.num_kv_heads))
+        k, v = more(k, spare), more(v, spare)
 
     with jax.named_scope("attn.decode" if T == 1 else "attn.prefill"):
         if window and cache is None:
@@ -306,20 +518,19 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
         else:
             from omnia_tpu.models.llama import _write_kv  # (it imports this module)
 
-            ck, cv, *rings = cache
+            ck, cv, *others = cache
             with jax.named_scope("kv.update"):
                 ck = _write_kv(ck, k, write_start, cache_layer)
                 cv = _write_kv(cv, v, write_start, cache_layer)
             with jax.named_scope("attn.full"):
                 attn = gqa_attention(q, ck, cv, q_positions, mesh=mesh,
                                      layer=cache_layer, live=live)
-            kept = (ck, cv, *rings)
+            kept = (ck, cv, *others)
     with jax.named_scope("attn.out"):
-        x = x + jnp.dot(attn.reshape(B, T, -1), a["wo"])
-    with jax.named_scope("mlp"):  # moe.route/sort/experts/combine/shared inside
-        y, counts = expert_ffn(rms_norm(x, p["ln2"], cfg.rms_norm_eps), p["mlp"],
-                               experts, at, cfg)
-    return x + y, kept, counts
+        out = jnp.dot(attn[:, :, :cfg.num_heads].reshape(B, T, -1) if spare
+                      else attn.reshape(B, T, -1), a["wo"])
+        x = x + (out if pre else rms_norm(out, p["ln1"], cfg.rms_norm_eps))
+    return x, kept
 
 
 def _run_stacks(params, cfg: ModelConfig, x, rope, q_positions, cache, write_start,
@@ -328,12 +539,13 @@ def _run_stacks(params, cfg: ModelConfig, x, rope, q_positions, cache, write_sta
     under ``stack.<kind>``; ``rope`` is ``rope_tables``, of which a layer
     takes its kind's. With a cache (the whole tuple) it is the carry and
     comes back; without one the chunk's rows come back in its place, an
-    array for each cache array ([L of the kind, B, T or R, Hkv, D]). →
-    (x, cache or chunks, counts summed over the layers)."""
+    array for each cache array ([L of the kind, B, T or R, Hkv, D]; a delta
+    layer's state and tail). → (x, cache or chunks, counts summed over the
+    layers: ``_stack_layer``'s)."""
     B, T, _ = x.shape
     n_real = jnp.broadcast_to(T if row is None else row + 1, (B,)).astype(jnp.int32)
-    counts = jnp.zeros((len(EXPERT_COUNTERS),), jnp.int32)
-    chunks = {"full": [], "window": []}
+    counts = jnp.zeros((len(EXPERT_COUNTERS) + cfg.has_state_layers,), jnp.int32)
+    chunks = {"full": [], "window": [], "delta": []}
     for stack, kind, first, length, cache_first in _runs(cfg):
         layers = params["layers"][stack]
         scanned, experts = (unstack_experts(layers) if kind.startswith("sparse")
@@ -363,14 +575,17 @@ def _run_stacks(params, cfg: ModelConfig, x, rope, q_positions, cache, write_sta
     def whole(kind, rows):  # the runs' rows, in the order of the kind's cache
         if len(rows) == 1:
             return rows[0]
-        if not rows:
-            shape = (0, B, ring_rows(cfg) if kind == "window" else T,
-                     cfg.num_kv_heads, cfg.head_dim)
-            return jnp.zeros(shape, x.dtype), jnp.zeros(shape, x.dtype)
-        return tuple(jnp.concatenate(each, axis=0) for each in zip(*rows))
+        if rows:
+            return tuple(jnp.concatenate(each, axis=0) for each in zip(*rows))
+        if kind == "delta":  # a cut model without a layer of the kind: arrays of no layers
+            return (jnp.zeros((0, B, cfg.linear_num_heads, cfg.linear_key_head_dim,
+                               cfg.linear_value_head_dim), jnp.float32),
+                    jnp.zeros((0, B, cfg.linear_conv_kernel - 1, conv_width(cfg)), x.dtype))
+        shape = (0, B, ring_rows(cfg) if kind == "window" else T,
+                 cache_kv_heads(cfg), cfg.head_dim)
+        return jnp.zeros(shape, x.dtype), jnp.zeros(shape, x.dtype)
 
-    if not cfg.has_window_layers:
+    have = ["full"] + ["window"] * cfg.has_window_layers + ["delta"] * cfg.has_state_layers
+    if have == ["full"]:
         return x, whole("full", chunks["full"]), counts
-    return x, (*whole("full", chunks["full"]), *whole("window", chunks["window"])), counts
-
-
+    return x, tuple(a for kind in have for a in whole(kind, chunks[kind])), counts

@@ -1,0 +1,87 @@
+"""`olmo-hybrid-7b.think-batch`'s programs as the harness builds them,
+compiled for the described chip at the real size (benchmark/README.md's third
+rehearsal), one compile a program: the decode program, the three fresh
+prefills and the three extend pieces each fit the chip beside the engine's
+weights and cache (K and V of two full layers at 64 slots x 3,072 rows, six
+layers' float32 states of 30 x 96 x 192 a slot, their tails), the decode
+program holds the state kernel and the full layers' kernel at a group of one
+query head, and the prompt side holds the blocked attention kernel and no
+kernel for the chunk-wise rule. And the state kernel alone at the published
+widths: Mosaic takes blocks whose lanes (192) and sublanes (96) are no whole
+128, 15 heads a block, in place."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from omnia_tpu.models import stacks
+
+CELL = "olmo-hybrid-7b.think-batch"
+BUCKETS = (256, 512, 1024)
+CHIP_BYTES = 16e9
+
+
+@pytest.mark.parametrize("program,size", [("decode", 8)]
+                         + [("prefill_insert", b) for b in BUCKETS]
+                         + [("extend_nosample", b) for b in BUCKETS])
+def test_the_cells_programs_fit_the_chip_at_the_real_size(cell_programs, kernel_route_on,
+                                                          program, size):
+    """Arguments (4.87 GB of weights, the cache, the step's operands) +
+    temporaries under 16 GB, and the engine more than 70 % of the chip (the
+    issue reckons 74 %; the driver's floor is a quarter)."""
+    cfg, ecfg, params, cache = cell_programs.cell(CELL)
+    assert (ecfg.num_slots, ecfg.max_seq, ecfg.prefill_buckets) == (64, 3072, BUCKETS)
+    assert [tuple(c.shape) for c in cache] == [
+        (2, 64, 3072, 32, 128), (2, 64, 3072, 32, 128), (6, 64, 30, 96, 192), (6, 64, 3, 11520)]
+    assert cache[2].dtype == jnp.float32
+    runs = [(kind, length) for _, kind, _, length, _ in stacks._runs(cfg)]
+    assert runs == [("dense_delta", 3), ("dense_full", 1)] * 2
+    compiled = cell_programs.compiled(CELL, program, size)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print(f"\n{CELL} {program} {size}: arguments {memory.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB")
+    assert 0.7 * CHIP_BYTES < memory.argument_size_in_bytes and held < CHIP_BYTES, (
+        program, size, memory.argument_size_in_bytes, memory.temp_size_in_bytes)
+    text = compiled.as_text()
+
+    def calls(kernel):
+        return re.findall(rf"%{kernel}[.\d]* = .*? custom-call\(", text)
+
+    if program == "decode":
+        assert calls("decode_delta_state") and calls("decode_gqa_attention")
+        assert not calls("prefill_attention")
+    else:
+        assert calls("prefill_attention") and not calls("decode_delta_state")
+    assert "attn.delta" in text and "delta.conv" in text
+    assert ("delta.state" if program == "decode" else "delta.chunk") in text
+
+
+def test_the_state_kernel_compiles_in_place_at_the_published_widths(one_chip):
+    """`decode_delta_state` at 30 heads of 96 x 192 float32, 64 slots, six
+    layers: 15 heads a block (no multiple of 16 divides 30), the whole state
+    goes in and comes out aliased, and nothing state-sized is copied around
+    the call."""
+    from omnia_tpu.ops import delta
+
+    L, Bk, H, dk, dv = 6, 64, 30, 96, 192
+    assert delta.head_block(H, dk, dv) == 15
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(state, q, k, v, g, beta, layer, live):
+        return delta.decode_delta_state(state, q, k, v, g, beta, layer, live, kernel=True)
+
+    f32 = jnp.float32
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        arg(f32, L, Bk, H, dk, dv), arg(f32, Bk, H, dk), arg(f32, Bk, H, dk), arg(f32, Bk, H, dv),
+        arg(f32, Bk, H), arg(f32, Bk, H), arg(jnp.int32), arg(jnp.bool_, Bk)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%decode_delta_state[.\d]* = \(.*\) custom-call\(", text)) == 1
+    memory = compiled.memory_analysis()
+    state_bytes = L * Bk * H * dk * dv * 4
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 8
