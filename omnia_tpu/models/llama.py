@@ -66,6 +66,7 @@ from omnia_tpu.models.stacks import (  # noqa: F401  (the module contract's name
     ring_rows,
     rope_tables,
     stack_kinds,
+    state_shape,
     with_layer_order,
 )
 from omnia_tpu.ops.attention import einsum_attention, gqa_attention
@@ -214,7 +215,8 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloat16,
     window layers: (k, v) of its full layers at ``seq`` rows, then (k, v) of
     its window layers at the ring's (``ring_rows``), whatever ``seq``. A
     model with linear-attention layers: (k, v) of its full layers, then the
-    delta layers' states [Ld, B, H, dk, dv] (float32 whatever ``dtype``) and
+    delta layers' states [Ld, B, H/p, dk, p·dv] (float32 whatever ``dtype``;
+    ``stacks.state_heads_a_row`` heads side by side along the lanes) and
     their convolutions' tails [Ld, B, taps - 1, 2·H·dk + H·dv]. Either
     model's rows hold ``cache_kv_heads`` heads (more than ``num_kv_heads``
     where that is over eight and no whole number of eights)."""
@@ -225,10 +227,9 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloat16,
                                       "cache with recurrent states")
         kinds = cfg.attention_kinds
         full = (kinds.count("full"), batch, seq, cache_kv_heads(cfg), cfg.head_dim)
-        H, dk, dv = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
         Ld = kinds.count("delta")
         return (jnp.zeros(full, dtype=dtype), jnp.zeros(full, dtype=dtype),
-                jnp.zeros((Ld, batch, H, dk, dv), dtype=jnp.float32),
+                jnp.zeros((Ld, batch, *state_shape(cfg)), dtype=jnp.float32),
                 jnp.zeros((Ld, batch, cfg.linear_conv_kernel - 1, conv_width(cfg)), dtype=dtype))
     if cfg.has_window_layers:
         if kv_quant:

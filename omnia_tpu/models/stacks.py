@@ -36,14 +36,16 @@ sublayer's input to the residual, inside it ``delta.conv``, ``delta.gates``,
 ``delta.chunk`` (T > 1), ``delta.state`` (a decode step), ``delta.out``;
 counter ``decode_delta_slots`` (the states a decode step updates: live slots
 a delta layer). Such a model's cache is four arrays: K and V of the full
-layers, the delta layers' states ``[Ld, B, H, dk, dv]`` float32 whatever the
-stream's type, and their convolutions' tails ``[Ld, B, taps - 1, 2·H·dk +
-H·dv]``. A state has no position to mask by afterwards, so which rows and
-steps may touch it is ``_delta_mixer``'s word, and it is models/mla.py::
-``_kda_layer``'s: a piece's pad rows get β = 0 and g = 0 and the tail kept
-is the last REAL row's; a piece at position 0 starts from S = 0 and a zero
-tail whatever the slot holds; a decode step leaves a dead slot's state and
-tail as they are. (Window layers beside delta layers are not built.)
+layers, the delta layers' states ``[Ld, B, H/p, dk, p·dv]`` float32 whatever
+the stream's type (p = ``state_heads_a_row`` heads side by side, so that a
+row of lanes is whole 128s; the decode kernel works on it as it lies, a
+chunk unpacks its slot's layer in front of the rule and packs it behind),
+and their convolutions' tails ``[Ld, B, taps - 1, 2·H·dk + H·dv]``. A state
+has no position to mask by afterwards, so which rows and steps may touch it
+is ``_delta_mixer``'s word, and it is models/mla.py::``_kda_layer``'s: a
+piece's pad rows get β = 0 and g = 0 and the tail kept is the last REAL
+row's; a piece at position 0 starts from S = 0 and a zero tail whatever the
+slot holds; a decode step leaves a dead slot's state and tail as they are. (Window layers beside delta layers are not built.)
 
 *A rotary table a kind of attention layer* (``rope_tables``, made once a
 program under ``rope.tables``; a layer turns its q and k by its kind's under
@@ -99,7 +101,7 @@ from omnia_tpu.models import kinds
 from omnia_tpu.models.config import ModelConfig
 from omnia_tpu.ops import attention as _attention
 from omnia_tpu.ops.attention import decode_block_rows, gqa_attention
-from omnia_tpu.ops.delta import decode_delta_state, delta_chunked
+from omnia_tpu.ops.delta import decode_delta_state, delta_chunked, pack_state, unpack_state
 from omnia_tpu.ops.moe import EXPERT_COUNTERS, expert_ffn, init_ffn, unstack_experts
 from omnia_tpu.ops.norms import rms_norm
 from omnia_tpu.ops.rope import apply_rope, rope_cos_sin, yarn_scaled_cos_sin
@@ -143,6 +145,28 @@ def cache_kv_heads(cfg: ModelConfig) -> int:
     padded heads' output (``_attention_sublayer``)."""
     H = cfg.num_kv_heads
     return H if H <= 8 else -(-H // 8) * 8
+
+
+def state_heads_a_row(cfg: ModelConfig) -> int:
+    """Delta heads that share a row of lanes in the cache's states, ``p``: the
+    smallest divisor of ``linear_num_heads`` for which p heads' values are a
+    whole number of 128 lanes; 1 where the value width already is one, or no
+    divisor gives one. The chip stores an array's last axis in whole 128-lane
+    tiles: a float32 state [96, 192] sits in rows of 256 lanes, and the
+    decode kernel's every read and write of it moves a third more than the
+    state holds (30 heads of 192: p = 2, [15, 96, 384], three whole tiles).
+    From shapes alone; ops/delta.py has the layout and reads p off its
+    operands."""
+    H, dv = cfg.linear_num_heads, cfg.linear_value_head_dim
+    return next((p for p in range(1, H + 1) if H % p == 0 and p * dv % 128 == 0), 1)
+
+
+def state_shape(cfg: ModelConfig) -> tuple:
+    """A slot's state of one delta layer as the cache holds it: (H/p, dk,
+    p·dv), ``state_heads_a_row`` heads side by side."""
+    p = state_heads_a_row(cfg)
+    return (cfg.linear_num_heads // p, cfg.linear_key_head_dim,
+            p * cfg.linear_value_head_dim)
 
 
 def conv_width(cfg: ModelConfig) -> int:
@@ -326,10 +350,11 @@ def _ring_rows_before(ring, start, window: int, layer):
 def _delta_mixer(h, a, cfg: ModelConfig, cache, cache_layer, write_start, n_real, live):
     """A delta layer between its input ``h`` [B, T, D] and what it adds to the
     residual (the module docstring has the mathematics), ``cache_layer`` its
-    index among the delta layers. ``cache``: (states [Ld, B, H, dk, dv]
+    index among the delta layers. ``cache``: (states [Ld, B, *``state_shape``]
     float32, tails [Ld, B, taps - 1, ``conv_width``]) whole, or None for a
     fresh chunk, which starts from zero and gets its (state, tail) back
-    instead. Which rows and steps may touch a state is said here alone:
+    instead, the state packed as the cache holds it. Which rows and steps
+    may touch a state is said here alone:
     - of a chunk's T rows the first ``n_real`` [B] count; the pad behind them
       gets β = 0 and g = 0, which leaves S as it is, and the tail kept is the
       last REAL row's;
@@ -385,11 +410,16 @@ def _delta_mixer(h, a, cfg: ModelConfig, cache, cache_layer, write_start, n_real
         updated = jnp.sum(live, dtype=jnp.int32) if live is not None else jnp.int32(B)
     else:
         with jax.named_scope("delta.chunk"):
+            # The rule works on [B, H, dk, dv]: the cache's packed layout is
+            # undone in front of it and made behind it, a copy each way.
+            p = state_heads_a_row(cfg)
             S = jnp.zeros((B, H, dk, dv), f32)
             if cache is not None:
-                S = jax.lax.dynamic_index_in_dim(states, cache_layer, 0, keepdims=False)
+                S = unpack_state(
+                    jax.lax.dynamic_index_in_dim(states, cache_layer, 0, keepdims=False), p)
                 S = jnp.where(fresh[:, None, None, None], 0.0, S)
             o, S = delta_chunked(q, k, v, g, beta, S)
+            S = pack_state(S, p)
             if cache is not None:
                 states = jax.lax.dynamic_update_slice_in_dim(states, S[None], cache_layer, 0)
         updated = jnp.int32(0)
@@ -578,8 +608,7 @@ def _run_stacks(params, cfg: ModelConfig, x, rope, q_positions, cache, write_sta
         if rows:
             return tuple(jnp.concatenate(each, axis=0) for each in zip(*rows))
         if kind == "delta":  # a cut model without a layer of the kind: arrays of no layers
-            return (jnp.zeros((0, B, cfg.linear_num_heads, cfg.linear_key_head_dim,
-                               cfg.linear_value_head_dim), jnp.float32),
+            return (jnp.zeros((0, B, *state_shape(cfg)), jnp.float32),
                     jnp.zeros((0, B, cfg.linear_conv_kernel - 1, conv_width(cfg)), x.dtype))
         shape = (0, B, ring_rows(cfg) if kind == "window" else T,
                  cache_kv_heads(cfg), cfg.head_dim)

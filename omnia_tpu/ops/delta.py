@@ -33,15 +33,28 @@ this is a module beside it and not a case of it:
   A row with ``β = 0`` and ``g = 0`` leaves the state as it was: a piece's pad
   rows and a length that is no multiple of the chunk.
 - ``decode_delta_state`` (T == 1): one step of every live slot over layer
-  ``layer`` of the whole state ``[L, B, H, dk, dv]``, in place. On a TPU a
-  Pallas kernel whose grid is (live slot × group of heads) from a
+  ``layer`` of the whole state, in place. The state is held PACKED, ``p``
+  heads side by side along the lanes: ``[L, B, H/p, dk, p·dv]``
+  (``pack_state`` / ``unpack_state``; packed head j holds heads j·p … j·p +
+  p − 1, head c of them in lanes c·dv … (c + 1)·dv − 1). The chip stores an
+  array's last axis in whole 128-lane tiles, so a 192-wide value would sit
+  in 256 lanes and every read and write of a state would move a third more
+  than the state holds; two heads side by side are 384 lanes, three whole
+  tiles. ``p`` is the caller's word (models/stacks.py::``state_heads_a_row``,
+  from the model's shapes) and is read here off the operands: H of ``q`` over
+  the state's heads. p = 1 is the plain ``[L, B, H, dk, dv]``. On a TPU a
+  Pallas kernel whose grid is (live slot × group of packed heads) from a
   scalar-prefetched list, each block read once and written once, exact
   float32 on the vector unit; the state is aliased in and out and a dead
-  slot's blocks are not visited. dk and dv need be no whole 128: a block is
-  the state's own last two axes whole (dk a multiple of 8 sublanes), and
-  the heads of a block are the largest divisor of H whose block stays
-  under ``BLOCK_BYTES`` (30 heads of 96 × 192: 15, 1.1 MB a block).
-  Elsewhere ``delta_step``.
+  slot's blocks are not visited. Inside a packed row a head's key and query
+  column reach that head's dv lanes only (a select by lane); v, α and β
+  ride as rows of p·dv lanes, and ``S'ᵀk`` and ``Sᵀq`` are sums down the
+  sublanes, so every element sees the float32 operations it would see alone,
+  in the same order. dk and p·dv need be no whole 128: a block is the
+  state's own last two axes whole (dk a multiple of 8 sublanes), and the
+  packed heads of a block are the largest divisor of H/p whose block stays
+  under ``BLOCK_BYTES`` (15 packed heads of 96 × 384: 5, 0.74 MB a block).
+  Elsewhere ``delta_step`` on the layer unpacked.
 """
 
 from __future__ import annotations
@@ -60,11 +73,11 @@ CHUNK = 64
 #: The most bytes of state one grid step of the decode kernel moves in (and
 #: out): a step's fixed cost is about a third of a microsecond, this much
 #: takes a microsecond and a half, and in and out double-buffered it is a
-#: third of the scoped VMEM.
+#: third of the scoped VMEM. A larger block buys nothing: a slot's 15 packed
+#: heads as one block of 2.2 MB read 455 µs a layer's call for 457 in three
+#: of five (chip_delta_state.py, PR 51: both move what they move at 620 GB/s,
+#: a read and a write stream's share of HBM), at three times the VMEM.
 BLOCK_BYTES = 5 << 18
-#: Rows of a head's tile of step vectors (k, q, v, α, β; a float32 tile's
-#: eight sublanes) and the lanes of a row: whole 128s that hold dk and dv.
-_VECTORS = 8
 
 
 def delta_step(S, q, k, v, g, beta):
@@ -123,42 +136,106 @@ def delta_chunked(q, k, v, g, beta, S0, chunk: int = CHUNK):
     return o[:, :T], S
 
 
+def pack_state(S, p: int):
+    """[..., H, dk, dv] → [..., H/p, dk, p·dv]: ``p`` heads side by side along
+    the lanes (the module docstring). One transposing copy; p = 1 is S."""
+    if p == 1:
+        return S
+    *lead, H, dk, dv = S.shape
+    S = jnp.swapaxes(S.reshape(*lead, H // p, p, dk, dv), -3, -2)
+    return S.reshape(*lead, H // p, dk, p * dv)
+
+
+def unpack_state(S, p: int):
+    """``pack_state``'s inverse: [..., H/p, dk, p·dv] → [..., H, dk, dv]."""
+    if p == 1:
+        return S
+    *lead, packed, dk, lanes = S.shape
+    S = jnp.swapaxes(S.reshape(*lead, packed, dk, p, lanes // p), -3, -2)
+    return S.reshape(*lead, packed * p, dk, lanes // p)
+
+
 def head_block(H: int, dk: int, dv: int) -> int:
-    """Heads of one block of the decode kernel: the largest divisor of H
-    whose [dk, dv] float32 states stay under ``BLOCK_BYTES`` (at least one)."""
+    """(Packed) heads of one block of the decode kernel: the largest divisor
+    of H whose [dk, dv] float32 states stay under ``BLOCK_BYTES`` (at least
+    one). For a packed state H and dv are the packed counts, H/p and p·dv:
+    the bytes are the block's real ones."""
     fit = [h for h in range(1, H + 1) if H % h == 0 and h * dk * dv * 4 <= BLOCK_BYTES]
     return max(fit, default=1)
 
 
-def _state_kernel(layer_ref, work_ref, zeros_ref, vec_ref, s_ref, o_ref, s_out_ref, *,
-                  heads: int):
-    """One grid step a (live slot, group of ``heads`` heads). vec_ref [1,
-    heads, 8, W]: rows k, q (dk lanes), v (dv lanes), α, β (every lane) of
-    each head; s_ref, s_out_ref [1, heads, dk, dv] (k down the sublanes, v
-    along the lanes); o_ref [1, heads, 1, dv]."""
-    del layer_ref, work_ref, zeros_ref
-    dk, dv = s_ref.shape[-2:]
-    W = vec_ref.shape[-1]
+def _columns(vec, n: int, dk: int):
+    """The first ``n`` rows of vec [R, W], dk lanes of each, as columns [dk,
+    ≥ n]: a tile of 128 rows (zeros behind the n) transposed, 128 lanes of
+    keys at a time."""
+    rows = -n // 8 * -8
+    wide = -dk // 128 * -128
+    tile = jnp.concatenate([vec[:rows, :wide], jnp.zeros((128 - rows, wide), vec.dtype)])
+    return tile.T[:dk]
 
-    def column(row):  # [1, W] → [dk, 1], entry [i] = row[i]
-        return jnp.broadcast_to(row, (W, W)).T[:dk, :1]
+
+def _state_kernel(layer_ref, work_ref, zeros_ref, vec_ref, s_ref, o_ref, s_out_ref, *,
+                  heads: int, p: int):
+    """One grid step a (live slot, group of ``heads`` packed heads). vec_ref
+    [1, heads, R, W]: rows k of each of a packed head's ``p`` heads, then q of
+    each (dk lanes), then v, α, β (p·dv lanes, a head's dv lanes its own);
+    s_ref, s_out_ref [1, heads, dk, p·dv] (k down the sublanes, the heads'
+    values along the lanes); o_ref [1, heads, 1, p·dv]."""
+    del layer_ref, work_ref, zeros_ref
+    dk, lanes = s_ref.shape[-2:]
+    dv = lanes // p
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+
+    def by_head(cols):  # p columns [dk, 1] → [dk, lanes]: head c's over its dv lanes
+        out = cols[-1]
+        for c in range(p - 2, -1, -1):
+            out = jnp.where(lane < (c + 1) * dv, cols[c], out)
+        return out
 
     for h in range(heads):
-        vec = vec_ref[0, h]                                     # [8, W]
-        k_col, q_col = column(vec[0:1]), column(vec[1:2])
-        S = s_ref[0, h] * vec[3:4, :dv]                         # α·S
-        r = jnp.sum(S * k_col, axis=0, keepdims=True)           # S'ᵀk  [1, dv]
-        S = S + k_col * (vec[4:5, :dv] * (vec[2:3, :dv] - r))
+        vec = vec_ref[0, h]                                     # [R, W]
+        # The 2p key and query rows as columns, one transpose for all of them:
+        # cols[i, r] = vec[r, i].
+        cols = _columns(vec, 2 * p, dk)
+        k_col = by_head([cols[:, c:c + 1] for c in range(p)])
+        q_col = by_head([cols[:, p + c:p + c + 1] for c in range(p)])
+        v, alpha, beta = (vec[2 * p + i:2 * p + i + 1, :lanes] for i in range(3))
+        S = s_ref[0, h] * alpha                                 # α·S
+        r = jnp.sum(S * k_col, axis=0, keepdims=True)           # S'ᵀk  [1, p·dv]
+        S = S + k_col * (beta * (v - r))
         o_ref[0, h] = jnp.sum(S * q_col, axis=0, keepdims=True)
         s_out_ref[0, h] = S
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _state_call(state, vectors, layer, live, interpret: bool = False):
-    """state [L, B, H, dk, dv] f32, vectors [B, H, 8, W] f32 → (o [B, H, 1,
-    dv], state): the Pallas call, over the live slots' blocks only."""
+def _step_vectors(q, k, v, g, beta, p: int):
+    """A step's operands as the kernel reads them, a tile a packed head: q, k
+    [B, H, dk]; v [B, H, dv]; g, beta [B, H] → [B, H/p, R, W] float32, rows k
+    of each of the p heads, q of each, then v, α = exp(g) and β with the
+    heads side by side (α and β over their head's dv lanes); W whole 128s
+    that hold dk and p·dv, R = 2p + 3 in whole tiles of eight sublanes."""
+    f32 = jnp.float32
+    B, H, dk = q.shape
+    dv = v.shape[-1]
+    W = -max(dk, p * dv) // 128 * -128
+
+    def rows(a):  # [B, H, dk] → [B, H/p, p, W]: a head a row
+        return jnp.pad(a.astype(f32).reshape(B, H // p, p, dk), ((0, 0),) * 3 + ((0, W - dk),))
+
+    def lanes(a):  # [B, H, dv] or [B, H] → [B, H/p, 1, W]: the heads side by side
+        a = jnp.broadcast_to(a.astype(f32).reshape(B, H, -1), (B, H, dv))
+        return jnp.pad(a.reshape(B, H // p, 1, p * dv), ((0, 0),) * 3 + ((0, W - p * dv),))
+
+    spare = jnp.zeros((B, H // p, -(2 * p + 3) % 8, W), f32)
+    return jnp.concatenate(
+        [rows(k), rows(q), lanes(v), lanes(jnp.exp(g.astype(f32))), lanes(beta), spare], axis=2)
+
+
+@functools.partial(jax.jit, static_argnames=("p", "interpret"))
+def _state_call(state, vectors, layer, live, p: int, interpret: bool = False):
+    """state [L, B, H/p, dk, p·dv] f32, vectors [B, H/p, R, W] f32 → (o [B,
+    H/p, 1, p·dv], state): the Pallas call, over the live slots' blocks only."""
     L, B, H, dk, dv = state.shape
-    W = vectors.shape[-1]
+    R, W = vectors.shape[-2:]
     hb = head_block(H, dk, dv)
     groups = H // hb
     live = jnp.ones((B,), bool) if live is None else live.astype(bool)
@@ -179,7 +256,7 @@ def _state_call(state, vectors, layer, live, interpret: bool = False):
         grid=(n_work,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, hb, _VECTORS, W), vec_index, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, hb, R, W), vec_index, memory_space=pltpu.VMEM),
             state_spec,
         ],
         out_specs=[
@@ -188,7 +265,7 @@ def _state_call(state, vectors, layer, live, interpret: bool = False):
         ],
     )
     return pl.pallas_call(
-        functools.partial(_state_kernel, heads=hb),
+        functools.partial(_state_kernel, heads=hb, p=p),
         out_shape=[jax.ShapeDtypeStruct((B, H, 1, dv), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         grid_spec=grid_spec,
@@ -203,28 +280,23 @@ def _state_call(state, vectors, layer, live, interpret: bool = False):
 
 def decode_delta_state(state, q, k, v, g, beta, layer, live=None, *, kernel: bool = False,
                        interpret: bool = False):
-    """One decode step of layer ``layer`` of the whole state [L, B, H, dk,
-    dv] float32, in place: q, k [B, H, dk]; v [B, H, dv]; g, beta [B, H];
-    ``live`` bool [B] or None (every slot). → (o [B, H, dv] f32, state). A
-    dead slot's state is left as it is and its output row is not to be
-    used. ``kernel``: the Pallas call, else ``delta_step`` on the layer taken
-    out and put back."""
-    f32 = jnp.float32
+    """One decode step of layer ``layer`` of the whole packed state [L, B,
+    H/p, dk, p·dv] float32, in place: q, k [B, H, dk]; v [B, H, dv]; g, beta
+    [B, H]; ``live`` bool [B] or None (every slot); p is H over the state's
+    heads. → (o [B, H, dv] f32, state). A dead slot's state is left as it is
+    and its output row is not to be used. ``kernel``: the Pallas call, else
+    ``delta_step`` on the layer taken out, unpacked, and put back."""
+    B, H, _ = q.shape
+    p = H // state.shape[2]
+    if state.shape[2:] != (H // p, k.shape[-1], p * v.shape[-1]):
+        raise ValueError(f"a state {state.shape} is no packing of {H} heads of "
+                         f"{k.shape[-1]} x {v.shape[-1]}")
     if kernel:
-        B, H, dk = q.shape
-        W = -max(dk, v.shape[-1]) // 128 * -128
-
-        def row(a):  # [B, H, n] or [B, H] → [B, H, W]
-            a = a.astype(f32)
-            return (jnp.broadcast_to(a[..., None], (B, H, W)) if a.ndim == 2
-                    else jnp.pad(a, ((0, 0), (0, 0), (0, W - a.shape[-1]))))
-
-        rows = [row(a) for a in (k, q, v, jnp.exp(g.astype(f32)), beta)]
-        vectors = jnp.stack(rows + [jnp.zeros((B, H, W), f32)] * (_VECTORS - len(rows)), axis=2)
-        o, state = _state_call(state, vectors, layer, live, interpret=interpret)
-        return o[:, :, 0], state
-    S = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+        vectors = _step_vectors(q, k, v, g, beta, p)
+        o, state = _state_call(state, vectors, layer, live, p=p, interpret=interpret)
+        return o.reshape(B, H, -1), state
+    S = unpack_state(jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False), p)
     o, new = delta_step(S, q, k, v, g, beta)
     if live is not None:
         new = jnp.where(live[:, None, None, None], new, S)
-    return o, jax.lax.dynamic_update_slice_in_dim(state, new[None], layer, axis=0)
+    return o, jax.lax.dynamic_update_slice_in_dim(state, pack_state(new, p)[None], layer, axis=0)

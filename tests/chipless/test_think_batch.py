@@ -3,12 +3,14 @@ compiled for the described chip at the real size (benchmark/README.md's third
 rehearsal), one compile a program: the decode program, the three fresh
 prefills and the three extend pieces each fit the chip beside the engine's
 weights and cache (K and V of two full layers at 64 slots x 3,072 rows, six
-layers' float32 states of 30 x 96 x 192 a slot, their tails), the decode
+layers' float32 states of 30 x 96 x 192 a slot held two heads side by side,
+15 x 96 x 384, so that the chip's 128-lane tiles hold nothing but state, their
+tails), the decode
 program holds the state kernel and the full layers' kernel at a group of one
 query head, and the prompt side holds the blocked attention kernel and no
 kernel for the chunk-wise rule. And the state kernel alone at the published
-widths: Mosaic takes blocks whose lanes (192) and sublanes (96) are no whole
-128, 15 heads a block, in place."""
+widths: Mosaic takes blocks of five packed heads whose sublanes (96) are no
+whole 128, in place, and the state operand is the states' own bytes."""
 
 import re
 
@@ -34,7 +36,7 @@ def test_the_cells_programs_fit_the_chip_at_the_real_size(cell_programs, kernel_
     cfg, ecfg, params, cache = cell_programs.cell(CELL)
     assert (ecfg.num_slots, ecfg.max_seq, ecfg.prefill_buckets) == (64, 3072, BUCKETS)
     assert [tuple(c.shape) for c in cache] == [
-        (2, 64, 3072, 32, 128), (2, 64, 3072, 32, 128), (6, 64, 30, 96, 192), (6, 64, 3, 11520)]
+        (2, 64, 3072, 32, 128), (2, 64, 3072, 32, 128), (6, 64, 15, 96, 384), (6, 64, 3, 11520)]
     assert cache[2].dtype == jnp.float32
     runs = [(kind, length) for _, kind, _, length, _ in stacks._runs(cfg)]
     assert runs == [("dense_delta", 3), ("dense_full", 1)] * 2
@@ -53,6 +55,15 @@ def test_the_cells_programs_fit_the_chip_at_the_real_size(cell_programs, kernel_
     if program == "decode":
         assert calls("decode_delta_state") and calls("decode_gqa_attention")
         assert not calls("prefill_attention")
+        # The states come in as their own bytes, whole (8, 128) tiles with no
+        # lane of padding (a head a row was 4/3 of them), and go out in place.
+        state = re.search(r"f32\[6,64,15,96,384\]\{4,3,2,1,0:T\((\d+),(\d+)\)\} parameter\((\d+)\)",
+                          text)
+        assert state, "no states parameter in whole tiles"
+        sublanes, lanes, index = map(int, state.groups())
+        assert 96 % sublanes == 0 and 384 % lanes == 0, state.group(0)
+        assert re.search(rf"input_output_alias=\{{.*\({index}, \{{\}}, may-alias\)", text)
+        assert memory.temp_size_in_bytes < 6 * 64 * 30 * 96 * 192 * 4
     else:
         assert calls("prefill_attention") and not calls("decode_delta_state")
     assert "attn.delta" in text and "delta.conv" in text
@@ -60,14 +71,18 @@ def test_the_cells_programs_fit_the_chip_at_the_real_size(cell_programs, kernel_
 
 
 def test_the_state_kernel_compiles_in_place_at_the_published_widths(one_chip):
-    """`decode_delta_state` at 30 heads of 96 x 192 float32, 64 slots, six
-    layers: 15 heads a block (no multiple of 16 divides 30), the whole state
-    goes in and comes out aliased, and nothing state-sized is copied around
-    the call."""
+    """`decode_delta_state` at 30 heads of 96 x 192 float32 held two a row, 64
+    slots, six layers: five packed heads a block (the choice the chip made:
+    457 us a layer's call against 455 with all fifteen in one block of 2.2 MB,
+    `chip_delta_state.py`, PR 51), the whole state goes in and comes out
+    aliased, the operands are the states' own bytes and a few MB of step
+    vectors (a head a row: 4/3 of the states), and nothing state-sized is
+    copied around the call."""
     from omnia_tpu.ops import delta
 
     L, Bk, H, dk, dv = 6, 64, 30, 96, 192
-    assert delta.head_block(H, dk, dv) == 15
+    p = 2
+    assert delta.head_block(H // p, dk, p * dv) == 5
 
     def arg(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -77,11 +92,13 @@ def test_the_state_kernel_compiles_in_place_at_the_published_widths(one_chip):
 
     f32 = jnp.float32
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
-        arg(f32, L, Bk, H, dk, dv), arg(f32, Bk, H, dk), arg(f32, Bk, H, dk), arg(f32, Bk, H, dv),
-        arg(f32, Bk, H), arg(f32, Bk, H), arg(jnp.int32), arg(jnp.bool_, Bk)).compile()
+        arg(f32, L, Bk, H // p, dk, p * dv), arg(f32, Bk, H, dk), arg(f32, Bk, H, dk),
+        arg(f32, Bk, H, dv), arg(f32, Bk, H), arg(f32, Bk, H), arg(jnp.int32),
+        arg(jnp.bool_, Bk)).compile()
     text = compiled.as_text()
     assert len(re.findall(r"%decode_delta_state[.\d]* = \(.*\) custom-call\(", text)) == 1
     memory = compiled.memory_analysis()
     state_bytes = L * Bk * H * dk * dv * 4
+    assert state_bytes <= memory.argument_size_in_bytes < state_bytes + (8 << 20)
     assert memory.alias_size_in_bytes >= state_bytes
     assert memory.temp_size_in_bytes < state_bytes // 8

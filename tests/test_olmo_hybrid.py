@@ -47,6 +47,9 @@ CFG = get_config("test-tiny-delta")
 # The same model with ten un-grouped heads: a cached row then holds sixteen
 # (`stacks.cache_kv_heads`), six of them zeros.
 CFG_TEN = dataclasses.replace(CFG, hidden_size=160, num_heads=10, num_kv_heads=10)
+# The same model with values 64 wide: two heads' are a whole 128 lanes, so the
+# cache holds its states two heads side by side (`stacks.state_heads_a_row`).
+CFG_PACKED = dataclasses.replace(CFG, linear_value_head_dim=64)
 PREFILL, DECODE = 40, 24
 TOL = 1e-5
 # How a prompt of PREFILL tokens is placed: (real rows, bucket) a piece. The
@@ -55,6 +58,7 @@ PLACEMENTS = {
     "one bucket": [(PREFILL, PREFILL)],
     "pieces of unequal length, the last padded": [(12, 12), (20, 20), (8, 16)],
     "one padded piece": [(PREFILL, 64)],
+    "two pieces, the second padded": [(24, 24), (16, 32)],
 }
 CHUNK = 8
 
@@ -203,6 +207,7 @@ def test_the_preset_is_the_shape_the_issue_names():
     assert [(c.shape, c.dtype.name) for c in cache] == [
         ((1, 2, 32, 6, 16), "bfloat16"), ((1, 2, 32, 6, 16), "bfloat16"),
         ((3, 2, 6, 8, 16), "float32"), ((3, 2, 3, 6 * 32), "bfloat16")]
+    assert stacks.state_heads_a_row(CFG) == 1 and stacks.state_shape(CFG) == (6, 8, 16)
     assert stacks.cache_kv_heads(CFG) == 6 and stacks.cache_kv_heads(CFG_TEN) == 16
     assert stacks.cache_kv_heads(dataclasses.replace(CFG, num_kv_heads=30)) == 32
     # the latent family's linear kind keeps its name
@@ -234,6 +239,65 @@ def test_ten_heads_are_cached_as_sixteen_and_agree_with_the_reference():
     assert cache[0].shape == (1, 1, 8, 16, 16)
     got = numbers(seeded, CFG_TEN)
     assert max(got.values()) <= TOL, got
+
+
+def _with_heads(H, dk, dv):
+    return dataclasses.replace(CFG, linear_num_heads=H, linear_key_head_dim=dk,
+                               linear_value_head_dim=dv)
+
+
+@pytest.mark.parametrize("H,dv,p", [
+    (30, 192, 2),     # the served heads: 384 lanes, three whole tiles
+    (6, 64, 2), (8, 32, 4), (3, 128, 1), (4, 256, 1),
+    (6, 16, 1),       # no divisor of 6 makes 16s a whole 128
+    (5, 192, 1),      # 2 does not divide 5, and 5 x 192 is no whole 128
+    (7, 96, 1),
+    (12, 96, 4),      # the smallest that does, not the largest
+])
+def test_heads_share_a_row_of_lanes_where_that_fills_whole_tiles(H, dv, p):
+    """`state_heads_a_row` from shapes alone, and the cache's states in it:
+    the same elements, slots on axis 1."""
+    cfg = _with_heads(H, 8, dv)
+    assert stacks.state_heads_a_row(cfg) == p
+    assert stacks.state_shape(cfg) == (H // p, 8, p * dv)
+    states = jax.eval_shape(lambda: llama.init_kv_cache(cfg, 2, 16))[2]
+    assert states.shape == (3, 2, H // p, 8, p * dv) and states.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("p", [1, 2, 3])
+def test_pack_then_unpack_is_the_identity(p):
+    """... and packed head j holds heads j p ... j p + p - 1 side by side."""
+    S = jax.random.normal(jax.random.key(p), (2, 3, 6, 8, 16))
+    packed = delta.pack_state(S, p)
+    assert packed.shape == (2, 3, 6 // p, 8, p * 16)
+    assert np.array_equal(np.asarray(delta.unpack_state(packed, p)), np.asarray(S))
+    for c in range(p):
+        assert np.array_equal(np.asarray(packed[..., 1, :, c * 16:(c + 1) * 16]),
+                              np.asarray(S[..., p + c, :, :]))
+
+
+@pytest.mark.parametrize("route", ["jnp", "kernels"])
+def test_two_heads_a_row_through_pieces_and_decode_agree_with_the_reference(route, request):
+    """`CFG_PACKED` (states [3, B, 3, 8, 128]): the prompt in two pieces, the
+    second padded (each unpacks its slot's state in front of the chunk-wise
+    rule and packs it behind), then decode through the packed cache, by
+    `delta_step` and by the kernel interpreted, against the reference's
+    recurrence; and a fresh prefill returns the state packed, the one the
+    pieces leave."""
+    assert stacks.state_heads_a_row(CFG_PACKED) == 2
+    if route == "kernels":
+        request.getfixturevalue("interpreted")
+    seeded, programs, caches = _seeded(CFG_PACKED), _programs(), []
+    got = numbers(seeded, CFG_PACKED, programs=programs, only=("prefill", "decode"),
+                  placement="two pieces, the second padded",
+                  between=lambda cache: caches.append(cache) or cache)
+    assert max(got.values()) <= TOL, got
+    placed = caches[1][2]                 # the states behind the second piece
+    params, tokens, _, _ = seeded
+    _, _, _, states, _ = programs["whole"](params, jnp.asarray(tokens[None, :PREFILL]),
+                                           cfg=CFG_PACKED)
+    assert states.shape == placed.shape == (3, 1, 3, 8, 128)
+    np.testing.assert_allclose(np.asarray(states), np.asarray(placed), atol=1e-5)
 
 
 def test_a_fresh_prefill_returns_the_state_it_would_have_written(seeded):
@@ -306,16 +370,24 @@ def test_a_row_with_no_strength_and_no_decay_leaves_the_state_alone():
 
 
 @pytest.mark.parametrize("route", ["jnp", "kernel"])
-def test_a_decode_step_equals_the_rule_and_skips_dead_slots(route):
+@pytest.mark.parametrize("H,dk,dv,p", [
+    (30, 96, 192, 2),   # the served heads, two a row: 15 packed heads, three blocks of 5
+    (4, 16, 128, 1),    # a 128-wide value: a head a row, the plain [L, B, H, dk, dv]
+    (5, 8, 192, 1),     # no divisor of 5 fills whole tiles: a head a row
+    (8, 8, 32, 4),      # four a row (eleven rows of step vectors: two tiles)
+])
+def test_a_decode_step_equals_the_rule_and_skips_dead_slots(route, H, dk, dv, p):
     """`decode_delta_state`, by `delta_step` and by the Pallas kernel
-    interpreted, at the served head shape (30 heads of 96 x 192: two groups
-    of 15), a middle layer of three: equal to one step of the recurrence for
-    the live slots; a dead slot's state and every other layer's are bit for
-    bit what they were."""
-    B, H, dk, dv = 5, 30, 96, 192
-    assert delta.head_block(H, dk, dv) == 15 and delta.head_block(6, 8, 16) == 6
+    interpreted, over the state in the layout the cache holds for those
+    shapes (`stacks.state_heads_a_row`), a middle layer of three: equal to
+    one step of the recurrence for the live slots; a dead slot's packed
+    blocks and every other layer's are bit for bit what they were."""
+    B = 5
+    assert stacks.state_heads_a_row(_with_heads(H, dk, dv)) == p
+    assert delta.head_block(15, 96, 384) == 5 and delta.head_block(6, 8, 16) == 6
     q, k, v, g, beta, S0 = _rule_inputs(B, 1, H, dk, dv, seed=3)
-    state = jnp.stack([S0 * 0.5, S0, S0 * 2])
+    state = delta.pack_state(jnp.stack([S0 * 0.5, S0, S0 * 2]), p)
+    assert state.shape == (3, B, H // p, dk, p * dv)
     live = jnp.asarray([True, False, True, True, False])
     want_o, want_S = delta.delta_recurrent(q, k, v, g, beta, S0)
     o, new = delta.decode_delta_state(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
@@ -323,7 +395,8 @@ def test_a_decode_step_equals_the_rule_and_skips_dead_slots(route):
                                       interpret=True)
     alive = np.asarray(live)
     np.testing.assert_allclose(np.asarray(o)[alive], np.asarray(want_o[:, 0])[alive], atol=2e-6)
-    np.testing.assert_allclose(np.asarray(new[1])[alive], np.asarray(want_S)[alive], atol=5e-6)
+    np.testing.assert_allclose(np.asarray(delta.unpack_state(new[1], p))[alive],
+                               np.asarray(want_S)[alive], atol=5e-6)
     assert np.array_equal(np.asarray(new[1])[~alive], np.asarray(state[1])[~alive])
     assert np.array_equal(np.asarray(new[0]), np.asarray(state[0]))
     assert np.array_equal(np.asarray(new[2]), np.asarray(state[2]))
@@ -511,9 +584,12 @@ def test_a_dead_slots_decode_step_leaves_its_state_and_tail_alone(seeded):
 # -- (d) through the engine ----------------------------------------------------
 
 
-def test_the_engine_serves_it_through_pieces_states_and_reused_slots():
-    """`InferenceEngine` on the normal path: prompts longer than the largest
-    bucket (placed through `extend` in pieces of unequal length, the state
+@pytest.mark.parametrize("cfg", [CFG, CFG_PACKED], ids=["a head a row", "two heads a row"])
+def test_the_engine_serves_it_through_pieces_states_and_reused_slots(cfg):
+    """`InferenceEngine` on the normal path, the states a head a row and two
+    heads side by side (a fresh prefill's packed state put into its slot,
+    pieces through the slot's view, decode over the whole array): prompts
+    longer than the largest bucket (placed through `extend` in pieces of unequal length, the state
     and the tail handed from piece to piece), one that fits a bucket
     (`prefill_insert`), 24 decode steps each, and two more rounds of requests
     into the same two slots: a state must not leak the previous tenant's.
@@ -521,13 +597,14 @@ def test_the_engine_serves_it_through_pieces_states_and_reused_slots():
     over the tokens before it, to within the two paths' rounding."""
     ecfg = EngineConfig(num_slots=2, max_seq=256, prefill_buckets=(16, 32), max_sessions=0,
                         decode_chunk=4, dtype="float32")
-    engine = InferenceEngine(CFG, ecfg, seed=3)
+    engine = InferenceEngine(cfg, ecfg, seed=3)
     assert engine.model_module is llama and len(engine._cache) == 4
+    assert engine._cache[2].shape == (3, 2, *stacks.state_shape(cfg))
     assert engine.kv_bytes_per_token() == 1 * 2 * 6 * 16 * 4   # the full layer's rows alone
     engine.warmup()
     engine.start()
     rng = np.random.default_rng(0)
-    sizes = reference_sizes(CFG, file_of(CFG))
+    sizes = reference_sizes(cfg, file_of(cfg))
     forward = jax.jit(lambda p, t: ref.forward(p, sizes, t))
     try:
         for _ in range(3):
