@@ -407,6 +407,9 @@ def one_chip(smoke: Smoke, args, jax, counter: CompileCounter, on_tpu: bool):
                 f"warmup_compile={phases.get('warmup_compile', 0.0):.1f} "
                 f"warmup_tasks={engine.metrics['warmup_programs_total']} "
                 f"(smoke observation, not a benchmark)")
+            say("warmup stage seconds: " + " ".join(
+                f"{k.partition('.')[2]}={v:.2f}" for k, v in phases.items()
+                if k.startswith("programs.")))
             if on_tpu:
                 smoke.check(compile_cache.enabled_dir() is not None,
                             "persistent compile cache is not enabled")

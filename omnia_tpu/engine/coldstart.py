@@ -1,9 +1,11 @@
-"""Cold-start instrumentation: submit-to-ready phases + the warmup manifest.
+"""Cold-start instrumentation: submit-to-ready phases, a record a
+warm-up program, and the warmup manifest.
 
 Cold start is the repo's worst number (BENCH_r01: 97.5 s of warmup against
 a 99 ms TTFT) and the direct blocker for scale-to-zero — a pod is useless
-until every serving shape is compiled, and until this module existed the
-whole bring-up was one opaque wall-clock gap. Two jax-free pieces:
+until every serving shape is compiled. Phases say which part of bring-up
+the seconds fall in; inside the longest one, ``warmup_compile``, a record
+a program says which of five costs they are. Jax-free pieces:
 
 - :class:`ColdStartTracker` — a thread-safe record of the bring-up
   phases (``backend_init`` → ``weights_load`` → ``warmup_compile`` →
@@ -16,6 +18,19 @@ whole bring-up was one opaque wall-clock gap. Two jax-free pieces:
   the server reports "initializing", and the operator capability gate
   turns it into a status condition — the next r02-style hang is
   attributed to a phase, not a 390 s timeout.
+
+- **A record a warm-up program** (``begin_program`` / ``end_program``,
+  :func:`record_stages`): ``{family, key, thread, t0, t1, trace_s,
+  lower_s, compile_s, cache_load_s, run_s, cache}`` on the tracker's
+  clock. The five stages are cut from the intervals that JAX's own
+  duration events cover (engine/warmup.py owns the ``jax.monitoring``
+  listener and hands each event to the record open on its thread), so
+  they equal ``t1 - t0`` exactly and a nested ``jit``'s trace is counted
+  once. Their sums ride in ``phase_seconds()`` / ``snapshot()["phases_s"]``
+  under ``programs.*`` keys — sums, not phases — beside
+  ``programs_cache_hits`` / ``programs_cache_misses``: what the
+  persistent compile cache did, where the manifest's counts below say
+  only what the last start listed.
 
 - :class:`WarmupManifest` — a persisted list of every (program family,
   shape) the engine compiled on first start, keyed by a content hash of
@@ -54,6 +69,120 @@ PHASES = (
     "ready",           # 5: submit-to-ready complete
 )
 PHASE_CODES = {name: i for i, name in enumerate(PHASES)}
+
+#: The ``jax.monitoring`` names a program's record is built from
+#: (engine/warmup.py's listener hands them over; nothing here imports
+#: jax). Three report a duration as the stage ends, on the thread that
+#: asked; the two cache events arrive inside a backend interval.
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+#: Spans ``compile_or_get_cached``: the backend's compile on a miss, the
+#: read and load of the executable on a hit.
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+STAGE_EVENTS = frozenset({
+    TRACE_EVENT, LOWER_EVENT, BACKEND_EVENT, CACHE_HIT_EVENT, CACHE_MISS_EVENT,
+})
+
+#: A record's stages, in the order they tile its wall.
+STAGES = ("trace", "lower", "compile", "cache_load", "run")
+#: The sums' keys in ``phase_seconds()`` / ``snapshot()["phases_s"]``.
+#: Sums, not phases, and none may start with ``warmup``: the benchmark's
+#: ``programs.warmup_s`` adds every key with that prefix. All but the
+#: last tile the ``warmup_compile`` span of a serial warm-up (under
+#: ``warmup_threads`` the stages are thread-seconds): ``drain`` is the
+#: closing wait for the device, ``other`` what stage events covered
+#: inside the span with no record open on their thread. ``after`` is the
+#: same between the span's end and ``warmup()``'s return (the slot
+#: programs, the restore's allocations), outside the tile.
+PROGRAM_SUMS = tuple(
+    f"programs.{name}" for name in STAGES + ("drain", "other", "after")
+)
+
+
+def record_stages(t0: float, t1: float, events: list) -> dict:
+    """The five stages of one program record ``[t0, t1]`` from the
+    ``(event, t, d)`` it was fed: ``{trace_s, lower_s, compile_s,
+    cache_load_s, run_s, cache}``. Pure.
+
+    Built from intervals, not by adding durations (an inner ``jit``'s
+    trace is reported inside its caller's). A duration event that
+    arrives at ``t`` covers ``[t - d, t]``. A backend interval with a
+    cache hit inside it is a load, else a compile; lowering intervals
+    are ``lower_s``; the wall up to the end of the last interval that
+    neither covers is ``trace_s`` (tracing and the operands' Python),
+    and what follows to ``t1`` is ``run_s``. So the five add up to
+    ``t1 - t0``, whatever a task compiles on the way (eager operands
+    are programs too; their intervals add into the same record).
+    ``cache``: ``miss`` if anything was compiled, else ``hit`` if
+    anything was loaded, else ``none`` (no compile asked: the process
+    already held the program)."""
+    hits = [t for event, t, _d in events if event == CACHE_HIT_EVENT]
+    cut = t0
+    cover = []  # (start, end, stage)
+    for event, t, d in events:
+        if event not in (TRACE_EVENT, LOWER_EVENT, BACKEND_EVENT):
+            continue
+        a, b = max(t0, t - d), min(t1, t)
+        if b <= a:
+            continue
+        cut = max(cut, b)
+        if event == BACKEND_EVENT:
+            hit = any(t - d <= h <= t for h in hits)
+            cover.append((a, b, "cache_load" if hit else "compile"))
+        elif event == LOWER_EVENT:
+            cover.append((a, b, "lower"))
+    # Where two overlap (they should not), the backend's interval wins.
+    cover.sort(key=lambda c: c[2] == "lower")
+    out = dict.fromkeys(STAGES, 0.0)
+    edges = sorted({t0, cut, *(x for a, b, _s in cover for x in (a, b))})
+    for a, b in zip(edges, edges[1:]):
+        stage = next((s for ca, cb, s in cover if ca <= a and b <= cb), "trace")
+        out[stage] += b - a
+    out["run"] = t1 - cut
+    stages = {s for _a, _b, s in cover}
+    cache = ("miss" if "compile" in stages
+             else "hit" if "cache_load" in stages else "none")
+    return {**{f"{name}_s": out[name] for name in STAGES}, "cache": cache}
+
+
+def _union_s(intervals: list) -> float:
+    """Seconds that some interval of ``[(start, end)]`` covers."""
+    total, hi = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > hi:
+            total += b - max(a, hi)
+            hi = b
+    return total
+
+
+def _phases_s(now: float, spans: dict, stage_sums: Optional[dict],
+              unowned: dict) -> dict:
+    """phase -> wall seconds (a running phase up to ``now``), then the
+    :data:`PROGRAM_SUMS` where a warm-up kept records (pure; caller
+    holds the lock; tens of intervals to union)."""
+    out = {
+        name: round((span[1] if span[1] is not None else now) - span[0], 6)
+        for name, span in spans.items()
+    }
+    if stage_sums is not None:
+        sums = {**stage_sums, **{k: _union_s(v) for k, v in unowned.items()}}
+        out.update(
+            (key, round(sums[key.partition(".")[2]], 6)) for key in PROGRAM_SUMS
+        )
+    return out
+
+
+class _OpenProgram:
+    """A program record while its task runs. Only the thread that opened
+    it feeds it, so ``events`` needs no lock."""
+
+    __slots__ = ("family", "key", "thread", "t0", "events")
+
+    def __init__(self, family: str, key: str, thread: str, t0: float):
+        self.family, self.key, self.thread, self.t0 = family, key, thread, t0
+        self.events: list = []
 
 
 def _pick_phase(ready: bool, spans: dict) -> str:
@@ -94,6 +223,15 @@ class ColdStartTracker:
         self._manifest_hits = 0  # guarded-by: _lock
         self._manifest_misses = 0  # guarded-by: _lock
         self._ready = False  # guarded-by: _lock
+        # Program records (one warm-up's; begin_programs() starts over).
+        # None until a warm-up keeps records: the mock's never does, and
+        # its phases_s carry no ``programs.*`` key.
+        self._stage_sums: Optional[dict] = None  # guarded-by: _lock
+        self._records: list[dict] = []  # guarded-by: _lock
+        self._unowned: dict[str, list] = {"other": [], "after": []}  # guarded-by: _lock
+        self._programs_open = False  # guarded-by: _lock
+        self._cache_hits = 0  # guarded-by: _lock
+        self._cache_misses = 0  # guarded-by: _lock
 
     # -- writers ---------------------------------------------------------
 
@@ -148,6 +286,79 @@ class ColdStartTracker:
         with self._lock:
             self._ready = True
 
+    # -- writers: program records ----------------------------------------
+
+    def now(self) -> float:
+        """The tracker's clock (``time.monotonic``: the flight
+        recorder's, so records lie on its axis)."""
+        return self._clock()
+
+    def begin_programs(self) -> None:
+        """A warm-up starts keeping records (after it began
+        ``warmup_compile``). Until ``end_programs()`` a stage event with
+        no record open counts under ``other`` or ``after``."""
+        with self._lock:
+            self._stage_sums = dict.fromkeys(STAGES + ("drain",), 0.0)
+            self._records = []
+            self._unowned = {"other": [], "after": []}
+            self._cache_hits = self._cache_misses = 0
+            self._programs_open = True
+
+    def end_programs(self) -> None:
+        with self._lock:
+            self._programs_open = False
+
+    def begin_program(self, family: str, key: str) -> _OpenProgram:
+        """Open the record of one warm-up task on the calling thread."""
+        return _OpenProgram(
+            family, key, threading.current_thread().name, self._clock()
+        )
+
+    def note_stage_event(self, rec: Optional[_OpenProgram], event: str,
+                         d: float = 0.0) -> None:
+        """One of :data:`STAGE_EVENTS` as it arrives (``d`` its duration,
+        0 for a cache event), for the record open on the thread or, with
+        none, for the warm-up's ``other`` / ``after``."""
+        t = self._clock()
+        if rec is not None:
+            rec.events.append((event, t, d))
+            return
+        with self._lock:
+            if not self._programs_open:
+                return
+            if event == CACHE_HIT_EVENT:
+                self._cache_hits += 1
+            elif event == CACHE_MISS_EVENT:
+                self._cache_misses += 1
+            else:
+                span = self._spans.get("warmup_compile")
+                inside = span is not None and span[1] is None
+                self._unowned["other" if inside else "after"].append((t - d, t))
+
+    def end_program(self, rec: _OpenProgram) -> dict:
+        """Close ``rec``: cut its stages, keep it, add it to the sums."""
+        t1 = self._clock()
+        record = {
+            "family": rec.family, "key": rec.key, "thread": rec.thread,
+            "t0": rec.t0, "t1": t1, **record_stages(rec.t0, t1, rec.events),
+        }
+        hits = sum(1 for event, _t, _d in rec.events if event == CACHE_HIT_EVENT)
+        misses = sum(1 for event, _t, _d in rec.events if event == CACHE_MISS_EVENT)
+        with self._lock:
+            self._records.append(record)
+            self._cache_hits += hits
+            self._cache_misses += misses
+            if self._stage_sums is not None:
+                for name in STAGES:
+                    self._stage_sums[name] += record[f"{name}_s"]
+        return record
+
+    def note_drain(self, seconds: float) -> None:
+        """The closing wait for the device over the warm-up's states."""
+        with self._lock:
+            if self._stage_sums is not None:
+                self._stage_sums["drain"] += seconds
+
     # -- readers ---------------------------------------------------------
 
     def current_phase(self) -> str:
@@ -155,19 +366,28 @@ class ColdStartTracker:
             return _pick_phase(self._ready, self._spans)
 
     def phase_seconds(self) -> dict:
-        """phase -> wall seconds (running phases measured up to now)."""
+        """phase -> wall seconds (running phases measured up to now),
+        then the :data:`PROGRAM_SUMS` once a warm-up kept records."""
         with self._lock:
-            now = self._clock()
-            return {
-                name: round((span[1] if span[1] is not None else now) - span[0], 6)
-                for name, span in self._spans.items()
-            }
+            return _phases_s(
+                self._clock(), self._spans, self._stage_sums, self._unowned
+            )
+
+    def program_records(self) -> list[dict]:
+        """The closed records of the last warm-up, in closing order."""
+        with self._lock:
+            return [dict(r) for r in self._records]
+
+    def slowest_programs(self, n: int = 5) -> list[dict]:
+        """The ``n`` records with the longest wall, longest first."""
+        return sorted(
+            self.program_records(), key=lambda r: r["t0"] - r["t1"]
+        )[:n]
 
     def snapshot(self) -> dict:
         """One consistent progress view — the shape the Health wire, the
         engine metrics mirror, and bench ``aux.coldstart`` all read."""
         with self._lock:
-            now = self._clock()
             phase = _pick_phase(self._ready, self._spans)
             return {
                 "phase": phase,
@@ -178,12 +398,11 @@ class ColdStartTracker:
                 "programs_done": self._programs_done,
                 "manifest_hits": self._manifest_hits,
                 "manifest_misses": self._manifest_misses,
-                "phases_s": {
-                    name: round(
-                        (span[1] if span[1] is not None else now) - span[0], 6
-                    )
-                    for name, span in self._spans.items()
-                },
+                "programs_cache_hits": self._cache_hits,
+                "programs_cache_misses": self._cache_misses,
+                "phases_s": _phases_s(
+                    self._clock(), self._spans, self._stage_sums, self._unowned
+                ),
             }
 
 

@@ -68,7 +68,7 @@ from omnia_tpu.engine.programs import build_programs
 from omnia_tpu.engine.scheduler import _SchedulerMixin
 from omnia_tpu.engine.sessions import _SessionKV, _SessionMixin, _Slot
 from omnia_tpu.engine.spec_decode import _SpecDecodeMixin, validate_spec_config
-from omnia_tpu.engine.warmup import _WarmupMixin
+from omnia_tpu.engine.warmup import _WarmupMixin, listen_to_jax
 from omnia_tpu.engine.types import (
     MAX_DEVICE_STOP_IDS,
     EngineConfig,
@@ -119,10 +119,11 @@ class InferenceEngine(
         # server) pass their own tracker with the backend_init phase
         # already begun; construction here closes it.
         self._coldstart = coldstart or ColdStartTracker()
-        # Every serving path compiles through the persistent cache: restart
-        # after the first start deserializes instead of recompiling (cold
-        # warmup ~100 s → seconds; the scale-to-zero enabler).
+        # Every serving path compiles through the persistent cache (a
+        # restart deserializes: cold warmup ~100 s → seconds), under the
+        # process's one pair of jax.monitoring listeners (warmup.py).
         enable_compilation_cache()
+        listen_to_jax()
         if engine_cfg.max_seq > model_cfg.max_seq_len:
             raise ValueError("engine max_seq exceeds model max_seq_len")
         if engine_cfg.num_slots % max(engine_cfg.dp, 1) != 0:
@@ -488,10 +489,9 @@ class InferenceEngine(
             # persistent-compile-cache switch and the submit-to-ready
             # progress surface. warmup_phase is the PHASE_CODES index
             # (0 idle → 5 ready); programs/bytes counters fill in DURING
-            # bring-up, so a probe mid-warmup reads real progress
-            # instead of an opaque "initializing". manifest hits/misses
-            # say whether this start found a prior start's program list
-            # (warm restore) or is discovering the set cold.
+            # bring-up, so a probe mid-warmup reads real progress.
+            # manifest hits/misses compare this start's program list with
+            # the last's; warmup_cache_hits/_misses join at the sync below.
             "compile_cache_enabled": 1 if enabled_dir() else 0,
             "warmup_phase": PHASE_CODES[self._coldstart.current_phase()],
             "warmup_programs_total": 0,

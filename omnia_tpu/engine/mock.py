@@ -267,6 +267,9 @@ class MockEngine(_MockMirrorsMixin, _MockSessionsMixin):
             "warmup_programs_done": 0,
             "warmup_manifest_hits": 0,
             "warmup_manifest_misses": 0,
+            # The mock compiles nothing, so its warm-up asks no cache.
+            "warmup_cache_hits": 0,
+            "warmup_cache_misses": 0,
             "weights_bytes_total": 0,
             "weights_bytes_loaded": 0,
         }

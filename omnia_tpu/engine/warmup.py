@@ -29,6 +29,15 @@ warmup pipeline:
   shape) or a cold compile, and the ``warmup_*`` metrics mirror the
   tracker so readiness progress is observable mid-warmup.
 
+- **A record a task.** Each task runs under a program record on the
+  cold-start tracker (``_run_warmup_task``): this module owns the
+  process's one pair of ``jax.monitoring`` listeners and a thread-local
+  that says which warm-up, and which record, is at work on the calling
+  thread, and hands every trace, lowering, backend-compile and cache
+  event JAX reports there to :mod:`~omnia_tpu.engine.coldstart`, which
+  cuts the record's five stages from them. After ``warmup()`` returns
+  the same listener counts ``programs_compiled_serving``.
+
 - **Param-free overlap.** ``_warmup_paramfree`` warms the families that
   take no model params (session offload/restore, prefix-pool transfers,
   page-run programs) — the engine runs it on a side thread while the
@@ -43,6 +52,7 @@ phase), so warmup cannot perturb request sampling.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import threading
@@ -56,6 +66,9 @@ import numpy as np
 
 from omnia_tpu.engine.coldstart import (
     PHASE_CODES,
+    PROGRAM_SUMS,
+    STAGE_EVENTS,
+    STAGES,
     WarmupManifest,
     manifest_bookkeeping,
     manifest_dir,
@@ -72,14 +85,27 @@ logger = logging.getLogger(__name__)
 #: warm-up did not cover.
 _COMPILE_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 # JAX's listeners are process-wide and cannot be taken off again, so the
-# process registers ONE, the first time an engine finishes warming, and
-# the engines it serves are held weakly.
+# process registers ONE pair (bare events, durations), when its first
+# engine is built, and the engines it serves are held weakly.
 _warmed: "weakref.WeakSet" = weakref.WeakSet()  # guarded-by: _warmed_lock
 _warmed_lock = threading.Lock()
 _listening = False  # guarded-by: _warmed_lock
+# The warm-up at work on this thread: ``cur = (tracker, record)`` inside a
+# task, ``(tracker, None)`` on warmup()'s own thread between tasks, unset
+# otherwise. JAX reports a stage on the thread that asked for it, so
+# parallel warm-up attributes rightly.
+_at_work = threading.local()
 
 
-def _on_jax_event(event: str, **_kw) -> None:
+def _on_jax_event(event: str, duration: float = 0.0, **_kw) -> None:
+    """Both listeners. A stage event goes to the warm-up at work on the
+    calling thread (coldstart.py keeps the books); a compile request on
+    a thread with none is a warmed engine's serving compile."""
+    cur = getattr(_at_work, "cur", None)
+    if cur is not None:
+        if event in STAGE_EVENTS:
+            cur[0].note_stage_event(cur[1], event, duration)
+        return
     if event != _COMPILE_EVENT:
         return
     with _warmed_lock:
@@ -93,14 +119,42 @@ def _on_jax_event(event: str, **_kw) -> None:
             eng.metrics["programs_compiled_serving"] += 1
 
 
-def _watch_serving_compiles(engine) -> None:
-    """From now on ``programs_compiled_serving`` counts for ``engine``."""
+def listen_to_jax() -> None:
+    """Register the process's one pair of listeners (idempotent; engine
+    construction calls it)."""
     global _listening
     with _warmed_lock:
         if not _listening:
             jax.monitoring.register_event_listener(_on_jax_event)
+            jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
             _listening = True
+
+
+def _watch_serving_compiles(engine) -> None:
+    """From now on ``programs_compiled_serving`` counts for ``engine``."""
+    with _warmed_lock:
         _warmed.add(engine)
+
+
+@contextlib.contextmanager
+def _warming(cs):
+    """``cs`` keeps program records until the block ends: what JAX
+    reports on this thread outside a task's record meanwhile is this
+    warm-up's ``other`` / ``after``."""
+    cs.begin_programs()
+    _at_work.cur = (cs, None)
+    try:
+        yield
+    finally:
+        _at_work.cur = None
+        cs.end_programs()
+
+
+def _program_line(r: dict) -> str:
+    """One record for the log: its wall and its stages."""
+    stages = " ".join(f"{name}={r[name + '_s']:.2f}" for name in STAGES)
+    return (f"{r['family']}:{r['key']} {r['t1'] - r['t0']:.2f}s "
+            f"({stages}, cache {r['cache']})")
 
 
 #: Families whose programs take no model params — compilable while the
@@ -421,10 +475,23 @@ class _WarmupMixin:
         closing restore reallocates them regardless)."""
         return _WarmupState(*self._alloc_kv_state())
 
+    def _run_warmup_task(self, task, st: _WarmupState) -> None:
+        """One task under its record (coldstart.py): the stage events
+        JAX reports on this thread meanwhile are the record's."""
+        family, key, fn = task
+        cs = self._coldstart
+        rec = cs.begin_program(family, key)
+        _at_work.cur = (cs, rec)
+        try:
+            fn(st)
+        finally:
+            _at_work.cur = (cs, None)
+            cs.end_program(rec)
+
     def _run_warmup_serial(self, tasks) -> list[_WarmupState]:
         st = _WarmupState(self._cache, self._pk, self._pv)
-        for _family, _key, fn in tasks:
-            fn(st)
+        for task in tasks:
+            self._run_warmup_task(task, st)
             self.metrics["warmup_programs_done"] = self._coldstart.note_program()
         return [st]
 
@@ -444,17 +511,21 @@ class _WarmupMixin:
         states_lock = threading.Lock()
 
         def run(task):
-            _family, _key, fn = task
+            # A fresh state's allocation is this warm-up's too (`other`).
+            _at_work.cur = (self._coldstart, None)
+            st = None
             try:
-                st = idle.get_nowait()
-            except queue_mod.Empty:
-                st = self._alloc_warmup_state()
-                with states_lock:
-                    states.append(st)
-            try:
-                fn(st)
+                try:
+                    st = idle.get_nowait()
+                except queue_mod.Empty:
+                    st = self._alloc_warmup_state()
+                    with states_lock:
+                        states.append(st)
+                self._run_warmup_task(task, st)
             finally:
-                idle.put(st)
+                if st is not None:
+                    idle.put(st)
+                _at_work.cur = None
             self.metrics["warmup_programs_done"] = self._coldstart.note_program()
 
         with ThreadPoolExecutor(
@@ -590,46 +661,61 @@ class _WarmupMixin:
         self.metrics["warmup_manifest_misses"] = misses
 
         threads = max(int(self.cfg.warmup_threads), 0)
-        if threads <= 0:
-            states = self._run_warmup_serial(tasks)
-        else:
-            states = self._run_warmup_parallel(tasks, threads)
-        for st in states:
-            # Donated chains may still be executing asynchronously;
-            # the compile phase ends when the device is quiesced.
-            jax.block_until_ready(st.cache)
-        compile_s = cs.end_phase("warmup_compile")
-        if self._flight is not None:
-            self._flight.note_init_phase("warmup_compile", {
-                "seconds": compile_s, "programs": len(tasks),
-                "threads": threads, "manifest_hits": hits,
-                "manifest_misses": misses,
-            })
+        with _warming(cs):
+            if threads <= 0:
+                states = self._run_warmup_serial(tasks)
+            else:
+                states = self._run_warmup_parallel(tasks, threads)
+            t_drain = cs.now()
+            for st in states:
+                # Donated chains may still be executing asynchronously;
+                # the compile phase ends when the device is quiesced.
+                jax.block_until_ready(st.cache)
+            cs.note_drain(cs.now() - t_drain)
+            compile_s = cs.end_phase("warmup_compile")
+            if self._flight is not None:
+                phases_s = cs.phase_seconds()
+                self._flight.note_init_phase("warmup_compile", {
+                    "seconds": compile_s, "programs": len(tasks),
+                    "threads": threads, "manifest_hits": hits,
+                    "manifest_misses": misses,
+                    # `after` is still filling: the closing log line has it.
+                    "stages_s": {k: phases_s[k] for k in PROGRAM_SUMS[:-1]},
+                    "slowest": cs.slowest_programs(),
+                })
 
-        self._warmup_slot_programs()
-        # The warmup states' arrays go before the restore allocates their
-        # successors: a cache that fills most of the chip (a 7.6 GB one
-        # beside 4.9 GB of weights) cannot stand beside a second one.
-        del states, st
+            self._warmup_slot_programs()
+            # The warmup states' arrays go before the restore allocates
+            # their successors: a cache that fills most of the chip (a
+            # 7.6 GB one beside 4.9 GB of weights) cannot stand beside a
+            # second one.
+            del states, st
 
-        cs.begin_phase("warmup_restore")
-        self.metrics["warmup_phase"] = PHASE_CODES["warmup_restore"]
-        # Restore everything warmup wrote (cache contents, PRNG streams,
-        # positions, metrics) so warmup cannot perturb request sampling.
-        self._init_device_state()
-        self.metrics.update(metrics_before)
-        restore_s = cs.end_phase("warmup_restore")
+            cs.begin_phase("warmup_restore")
+            self.metrics["warmup_phase"] = PHASE_CODES["warmup_restore"]
+            # Restore everything warmup wrote (cache contents, PRNG
+            # streams, positions, metrics) so warmup cannot perturb
+            # request sampling.
+            self._init_device_state()
+            self.metrics.update(metrics_before)
+            restore_s = cs.end_phase("warmup_restore")
         cs.mark_ready()
         self._sync_coldstart_metrics()
         if self._flight is not None:
             self._flight.note_init_phase(
                 "warmup_restore", {"seconds": restore_s}
             )
+        snap = cs.snapshot()
         logger.info(
             "engine warmup done in %.1fs (%d programs, %d decode variants, "
-            "threads=%d, manifest %d hit / %d miss, sessions=%s)",
+            "threads=%d, manifest %d hit / %d miss, sessions=%s); stage "
+            "seconds %s; compile cache %d hit / %d miss; slowest: %s",
             time.monotonic() - t0, len(tasks), len(self._decode_fns),
             threads, hits, misses, sessions,
+            " ".join(f"{k.partition('.')[2]}={snap['phases_s'][k]:.2f}"
+                     for k in PROGRAM_SUMS),
+            snap["programs_cache_hits"], snap["programs_cache_misses"],
+            "; ".join(_program_line(r) for r in cs.slowest_programs()),
         )
         _watch_serving_compiles(self)
 
@@ -676,5 +762,7 @@ class _WarmupMixin:
         self.metrics["warmup_programs_done"] = snap["programs_done"]
         self.metrics["warmup_manifest_hits"] = snap["manifest_hits"]
         self.metrics["warmup_manifest_misses"] = snap["manifest_misses"]
+        self.metrics["warmup_cache_hits"] = snap["programs_cache_hits"]
+        self.metrics["warmup_cache_misses"] = snap["programs_cache_misses"]
         self.metrics["weights_bytes_total"] = snap["weights_bytes_total"]
         self.metrics["weights_bytes_loaded"] = snap["weights_bytes_loaded"]

@@ -16,12 +16,20 @@ import os
 import pytest
 
 from omnia_tpu.engine.coldstart import (
+    BACKEND_EVENT,
+    CACHE_HIT_EVENT,
+    CACHE_MISS_EVENT,
+    LOWER_EVENT,
     PHASE_CODES,
     PHASES,
+    PROGRAM_SUMS,
+    STAGES,
+    TRACE_EVENT,
     ColdStartTracker,
     WarmupManifest,
     manifest_bookkeeping,
     manifest_dir,
+    record_stages,
 )
 
 pytestmark = pytest.mark.coldstart
@@ -114,6 +122,202 @@ class TestColdStartTracker:
         assert cs.note_program() == 1
         snap = cs.snapshot()
         assert (snap["programs_done"], snap["programs_total"]) == (1, 3)
+
+
+# ---------------------------------------------------------------------------
+# Program records (jax-free): synthetic (event, t, d) on a fake clock
+# ---------------------------------------------------------------------------
+
+
+def _stage_sum(record):
+    return sum(record[f"{name}_s"] for name in STAGES)
+
+
+#: One program as JAX reports it: an inner jit traced inside the outer
+#: trace, lowering, then the backend. Times are the events' arrivals.
+_ONE_PROGRAM = [
+    (TRACE_EVENT, 12.0, 1.0),    # inner jit: [11, 12]
+    (TRACE_EVENT, 13.0, 3.0),    # its caller: [10, 13], holds the inner one
+    (LOWER_EVENT, 15.0, 2.0),    # [13, 15]
+    (BACKEND_EVENT, 19.0, 4.0),  # [15, 19]
+]
+
+
+class TestRecordStages:
+    def test_stages_equal_the_wall(self):
+        r = record_stages(10.0, 19.5, _ONE_PROGRAM)
+        assert r == {"trace_s": 3.0, "lower_s": 2.0, "compile_s": 4.0,
+                     "cache_load_s": 0.0, "run_s": 0.5, "cache": "miss"}
+        assert _stage_sum(r) == 19.5 - 10.0
+
+    def test_a_nested_trace_is_not_counted_twice(self):
+        """Adding the two trace durations would say 4 s of a 3 s trace."""
+        assert record_stages(10.0, 19.5, _ONE_PROGRAM)["trace_s"] == 3.0
+        assert record_stages(10.0, 19.5, _ONE_PROGRAM[1:])["trace_s"] == 3.0
+
+    def test_a_backend_interval_with_a_hit_inside_is_a_load(self):
+        hit = _ONE_PROGRAM[:3] + [(CACHE_HIT_EVENT, 18.9, 0.0), _ONE_PROGRAM[3]]
+        r = record_stages(10.0, 19.5, hit)
+        assert (r["cache_load_s"], r["compile_s"], r["cache"]) == (4.0, 0.0, "hit")
+        # A miss is reported as its entry is written: still a compile.
+        miss = _ONE_PROGRAM[:3] + [(CACHE_MISS_EVENT, 18.9, 0.0), _ONE_PROGRAM[3]]
+        r = record_stages(10.0, 19.5, miss)
+        assert (r["cache_load_s"], r["compile_s"], r["cache"]) == (0.0, 4.0, "miss")
+
+    def test_a_hit_belongs_to_the_interval_it_lies_in(self):
+        """A task that loads an operand's eager program and then
+        compiles its step program: one load, one compile, one record."""
+        events = [
+            (LOWER_EVENT, 1.5, 0.5), (CACHE_HIT_EVENT, 1.9, 0.0),
+            (BACKEND_EVENT, 2.0, 0.5),
+            (TRACE_EVENT, 4.0, 1.0), (LOWER_EVENT, 5.0, 1.0),
+            (BACKEND_EVENT, 8.0, 3.0),
+        ]
+        r = record_stages(0.0, 9.0, events)
+        assert r == {"trace_s": 3.0, "lower_s": 1.5, "compile_s": 3.0,
+                     "cache_load_s": 0.5, "run_s": 1.0, "cache": "miss"}
+        assert _stage_sum(r) == 9.0
+
+    def test_no_compile_asked_is_all_run(self):
+        """The process already held the program: no event arrives."""
+        r = record_stages(3.0, 3.25, [])
+        assert (r["run_s"], r["cache"]) == (0.25, "none")
+        assert _stage_sum(r) == 0.25
+
+    def test_intervals_are_cut_to_the_record(self):
+        """An event that began before the record (it cannot, on one
+        thread; a clock's slack can say so) never makes a stage longer
+        than the wall."""
+        r = record_stages(10.0, 12.0, [(BACKEND_EVENT, 11.0, 5.0)])
+        assert (r["compile_s"], r["run_s"]) == (1.0, 1.0)
+        assert _stage_sum(r) == 2.0
+
+
+class _FakeClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+def _feed(cs, clock, rec, events):
+    for event, t, d in events:
+        clock.t = t
+        cs.note_stage_event(rec, event, d)
+
+
+class TestProgramRecords:
+    def _warming(self):
+        clock = _FakeClock()
+        cs = ColdStartTracker(clock=clock)
+        clock.t = 9.0
+        cs.begin_phase("warmup_compile")
+        cs.begin_programs()
+        return cs, clock
+
+    def test_a_record_is_its_task_and_its_stages(self):
+        cs, clock = self._warming()
+        clock.t = 10.0
+        rec = cs.begin_program("decode", "chunk8")
+        _feed(cs, clock, rec, _ONE_PROGRAM)
+        clock.t = 19.5
+        record = cs.end_program(rec)
+        assert set(record) == {
+            "family", "key", "thread", "t0", "t1", "trace_s", "lower_s",
+            "compile_s", "cache_load_s", "run_s", "cache",
+        }
+        assert (record["family"], record["key"]) == ("decode", "chunk8")
+        assert (record["t0"], record["t1"]) == (10.0, 19.5)
+        assert _stage_sum(record) == record["t1"] - record["t0"]
+        assert cs.program_records() == [record]
+
+    def test_sums_ride_in_both_phase_readers_and_tile_the_span(self):
+        cs, clock = self._warming()
+        clock.t = 10.0
+        rec = cs.begin_program("decode", "chunk8")
+        _feed(cs, clock, rec, _ONE_PROGRAM)
+        clock.t = 19.5
+        cs.end_program(rec)
+        cs.note_drain(0.5)
+        clock.t = 20.0
+        cs.end_phase("warmup_compile")
+        phases = cs.phase_seconds()
+        assert phases == cs.snapshot()["phases_s"]
+        assert [k for k in phases if k.startswith("programs.")] == list(PROGRAM_SUMS)
+        assert phases["programs.trace"] == 3.0
+        assert phases["programs.run"] == 0.5
+        assert phases["programs.drain"] == 0.5
+        tile = sum(phases[k] for k in PROGRAM_SUMS if k != "programs.after")
+        # The record began a second into the span: nothing claims it.
+        assert tile == phases["warmup_compile"] - 1.0
+
+    def test_no_new_key_starts_with_warmup(self):
+        """benchmark/layer_metrics/programs.warmup_s.py adds every key
+        with that prefix."""
+        assert not [k for k in PROGRAM_SUMS if k.startswith("warmup")]
+        assert set(PROGRAM_SUMS) & set(PHASES) == set()
+        cs, _clock = self._warming()
+        assert [k for k in cs.phase_seconds() if k.startswith("warmup")] == [
+            "warmup_compile"
+        ]
+
+    def test_no_records_no_keys(self):
+        """The mock's warm-up keeps no records: phases only."""
+        cs = ColdStartTracker()
+        cs.begin_phase("warmup_compile")
+        cs.end_phase("warmup_compile")
+        assert list(cs.phase_seconds()) == ["warmup_compile"]
+        snap = cs.snapshot()
+        assert (snap["programs_cache_hits"], snap["programs_cache_misses"]) == (0, 0)
+
+    def test_two_open_records_do_not_cross(self):
+        """Two workers' records are open at once; each is fed on its own
+        thread, and neither's events reach the other."""
+        cs, clock = self._warming()
+        clock.t = 10.0
+        a = cs.begin_program("decode", "chunk8")
+        b = cs.begin_program("prefill", "bucket64")
+        _feed(cs, clock, a, [(LOWER_EVENT, 12.0, 2.0)])
+        _feed(cs, clock, b, [(CACHE_HIT_EVENT, 13.5, 0.0), (BACKEND_EVENT, 14.0, 3.0)])
+        ra, rb = cs.end_program(a), cs.end_program(b)
+        assert (ra["lower_s"], ra["cache_load_s"], ra["cache"]) == (2.0, 0.0, "none")
+        assert (rb["lower_s"], rb["cache_load_s"], rb["cache"]) == (0.0, 3.0, "hit")
+        snap = cs.snapshot()
+        assert (snap["programs_cache_hits"], snap["programs_cache_misses"]) == (1, 0)
+
+    def test_an_event_with_no_record_is_other_then_after_then_nobodys(self):
+        cs, clock = self._warming()
+        _feed(cs, clock, None, [(TRACE_EVENT, 11.0, 1.0), (BACKEND_EVENT, 12.0, 1.5),
+                                (CACHE_MISS_EVENT, 12.0, 0.0)])
+        clock.t = 13.0
+        cs.end_phase("warmup_compile")
+        # The slot programs and the restore: outside the span, inside warmup().
+        _feed(cs, clock, None, [(BACKEND_EVENT, 14.0, 0.25)])
+        cs.end_programs()
+        _feed(cs, clock, None, [(BACKEND_EVENT, 20.0, 5.0), (CACHE_HIT_EVENT, 20.0, 0.0)])
+        phases = cs.phase_seconds()
+        assert phases["programs.other"] == 2.0  # [10, 11] and [10.5, 12] as one
+        assert phases["programs.after"] == 0.25
+        snap = cs.snapshot()
+        assert (snap["programs_cache_hits"], snap["programs_cache_misses"]) == (0, 1)
+
+    def test_a_second_warmup_starts_its_records_over(self):
+        cs, clock = self._warming()
+        rec = cs.begin_program("decode", "chunk8")
+        clock.t = 10.0
+        cs.end_program(rec)
+        cs.begin_programs()
+        assert cs.program_records() == []
+        assert cs.phase_seconds()["programs.run"] == 0.0
+
+    def test_slowest_programs_longest_first(self):
+        cs, clock = self._warming()
+        for key, wall in (("a", 1.0), ("b", 3.0), ("c", 2.0)):
+            rec = cs.begin_program("prefill", key)
+            clock.t += wall
+            cs.end_program(rec)
+        assert [r["key"] for r in cs.slowest_programs(2)] == ["b", "c"]
 
 
 # ---------------------------------------------------------------------------
@@ -691,3 +895,169 @@ def test_runtime_forwards_warmup_threads(monkeypatch):
                  "dtype": "float32", "warmup_threads": 3},
     ))
     assert tpu.cfg.warmup_threads == 3
+
+
+# -- program records on a real warm-up --------------------------------------
+
+
+def _warmup_s_reader():
+    """The accepted reader of set-up's one per-layer number, by its file."""
+    import importlib.util
+
+    path = os.path.join(REPO, "benchmark", "layer_metrics", "programs.warmup_s.py")
+    spec = importlib.util.spec_from_file_location("programs_warmup_s", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.fixture(scope="module")
+def warmed(tmp_path_factory):
+    """One serial warm-up of the tiny engine, recorder on."""
+    pytest.importorskip("jax")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("OMNIA_WARMUP_MANIFEST_DIR", str(tmp_path_factory.mktemp("manifest")))
+        eng = _engine(flight_events=128)
+        tasks = [(f, k) for f, k, _fn in eng._warmup_tasks(sessions=True)]
+        eng.warmup()
+    return eng, tasks
+
+
+def test_one_record_a_task_and_its_stages_equal_its_wall(warmed):
+    eng, tasks = warmed
+    records = eng._coldstart.program_records()
+    assert [(r["family"], r["key"]) for r in records] == tasks
+    for r in records:
+        assert _stage_sum(r) == pytest.approx(
+            r["t1"] - r["t0"], abs=1e-9)
+        assert min(r[f"{s}_s"] for s in STAGES) >= 0.0
+        # A first start of a shape in this process: something was asked
+        # of the compiler, and a serial warm-up runs on the caller's thread.
+        assert r["cache"] in ("hit", "miss")
+        assert r["trace_s"] > 0 and r["lower_s"] > 0
+    assert len({r["thread"] for r in records}) == 1
+    assert all(a["t1"] <= b["t0"] for a, b in zip(records, records[1:]))
+
+
+def test_the_seven_sums_tile_warmup_compile(warmed):
+    eng, _tasks = warmed
+    phases = eng._coldstart.phase_seconds()
+    assert phases == eng._coldstart.snapshot()["phases_s"]
+    tile = sum(phases[k] for k in PROGRAM_SUMS if k != "programs.after")
+    assert tile == pytest.approx(phases["warmup_compile"], rel=0.01)
+    # The slot programs and the restore's allocations compile after the
+    # span: counted, and outside the tile.
+    assert phases["programs.after"] > 0
+    assert phases["programs.other"] == 0.0
+
+
+def test_the_accepted_warmup_reader_reads_what_it_read(warmed):
+    """`programs.warmup_s` adds every phase key that starts with
+    `warmup`: the stage sums must not reach it."""
+    eng, _tasks = warmed
+    phases = eng._coldstart.phase_seconds()
+    assert _warmup_s_reader()({"setup": {"phases": phases}}) == pytest.approx(
+        phases["warmup_compile"] + phases["warmup_restore"])
+    assert [k for k in phases if k.startswith("warmup")] == [
+        "warmup_compile", "warmup_restore"]
+
+
+def test_cache_counts_are_mirrored_and_the_flight_event_carries_the_stages(warmed):
+    eng, tasks = warmed
+    snap = eng._coldstart.snapshot()
+    m = eng.metrics
+    assert m["warmup_cache_hits"] == snap["programs_cache_hits"]
+    assert m["warmup_cache_misses"] == snap["programs_cache_misses"]
+    # tests/conftest.py names a cache directory: every program asked it.
+    assert m["warmup_cache_hits"] + m["warmup_cache_misses"] >= len(tasks)
+    attrs = eng._flight.events("warmup_compile")[0].attrs
+    assert list(attrs["stages_s"]) == [k for k in PROGRAM_SUMS if k != "programs.after"]
+    assert sum(attrs["stages_s"].values()) == pytest.approx(attrs["seconds"], rel=0.01)
+    walls = [r["t1"] - r["t0"] for r in attrs["slowest"]]
+    assert len(walls) == min(5, len(tasks)) and walls == sorted(walls, reverse=True)
+    json.dumps(attrs)  # the recorder's dump and the Chrome export carry it
+
+
+def test_parallel_warmup_records_on_its_workers_threads(tmp_path, monkeypatch):
+    """Under `warmup_threads` JAX still reports a stage on the thread
+    that asked, so every record's stages equal its wall; the sums are
+    thread-seconds."""
+    monkeypatch.setenv("OMNIA_WARMUP_MANIFEST_DIR", str(tmp_path))
+    eng = _engine(warmup_threads=2)
+    eng.warmup()
+    records = eng._coldstart.program_records()
+    assert len(records) == eng.metrics["warmup_programs_total"]
+    assert all(r["thread"].startswith("omnia-warmup") for r in records)
+    for r in records:
+        assert _stage_sum(r) == pytest.approx(
+            r["t1"] - r["t0"], abs=1e-9)
+
+
+def test_one_pair_of_jax_listeners_a_process(warmed):
+    pytest.importorskip("jax")
+    from jax._src import monitoring
+
+    from omnia_tpu.engine import warmup
+
+    _engine()  # a second engine registers nothing more
+    assert monitoring.get_event_listeners().count(warmup._on_jax_event) == 1
+    assert monitoring.get_event_duration_listeners().count(warmup._on_jax_event) == 1
+
+
+def test_the_listener_routes_by_the_calling_thread():
+    """A stage event goes to the record open on the thread it arrives
+    on; with none, to the warm-up's `other` on warmup()'s own thread;
+    and a compile request counts as a serving compile only on a thread
+    with no warm-up at work."""
+    pytest.importorskip("jax")
+    import threading
+
+    from omnia_tpu.engine import warmup
+
+    class Served:  # what _on_jax_event touches of a warmed engine
+        _thread = None
+
+        def __init__(self):
+            self.metrics = {"programs_compiled_serving": 0}
+
+    served = Served()
+    warmup._watch_serving_compiles(served)
+    cs = ColdStartTracker()
+    cs.begin_phase("warmup_compile")
+    both_open = threading.Barrier(2, timeout=30)
+    both_fed = threading.Barrier(2, timeout=30)
+
+    def worker(key, event):
+        rec = cs.begin_program("decode", key)
+        warmup._at_work.cur = (cs, rec)
+        try:
+            both_open.wait()
+            warmup._on_jax_event(event, duration=0.0)
+            warmup._on_jax_event(warmup._COMPILE_EVENT)
+            both_fed.wait()
+        finally:
+            warmup._at_work.cur = None
+            cs.end_program(rec)
+
+    with warmup._warming(cs):
+        threads = [
+            threading.Thread(target=worker, args=("a", CACHE_HIT_EVENT)),
+            threading.Thread(target=worker, args=("b", CACHE_MISS_EVENT)),
+        ]
+        for t in threads:
+            t.start()
+        warmup._on_jax_event(BACKEND_EVENT, duration=0.5)  # no record here
+        warmup._on_jax_event(warmup._COMPILE_EVENT)
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        assert served.metrics["programs_compiled_serving"] == 0
+        assert cs.phase_seconds()["programs.other"] == pytest.approx(0.5)
+    snap = cs.snapshot()
+    assert (snap["programs_cache_hits"], snap["programs_cache_misses"]) == (1, 1)
+    assert sorted(r["key"] for r in cs.program_records()) == ["a", "b"]
+    assert getattr(warmup._at_work, "cur", None) is None
+    warmup._on_jax_event(BACKEND_EVENT, duration=1.0)  # after warmup(): nobody's
+    warmup._on_jax_event(warmup._COMPILE_EVENT)
+    assert served.metrics["programs_compiled_serving"] == 1
+    assert cs.phase_seconds()["programs.after"] == 0.0

@@ -493,6 +493,7 @@ class TestMetricsKeyStability:
         "compile_cache_enabled", "warmup_phase",
         "warmup_programs_total", "warmup_programs_done",
         "warmup_manifest_hits", "warmup_manifest_misses",
+        "warmup_cache_hits", "warmup_cache_misses",
         "weights_bytes_total", "weights_bytes_loaded",
     }
 
