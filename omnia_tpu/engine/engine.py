@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from omnia_tpu.engine import phases
 from omnia_tpu.engine.coldstart import PHASE_CODES, ColdStartTracker
 from omnia_tpu.engine.devloop import DevLoopState
-from omnia_tpu.engine.family import _PairCacheMixin, refuse_unported
+from omnia_tpu.engine.family import _PairCacheMixin, decode_blocks, refuse_unported
 from omnia_tpu.engine.faults import FaultPlan
 from omnia_tpu.engine.flight import FlightRecorder
 from omnia_tpu.engine.interleave import _InflightPrefill, _InterleaveMixin
@@ -507,11 +507,11 @@ class InferenceEngine(
         # metrics dict existed — fold the tracker's view in now.
         self._sync_coldstart_metrics()
         logger.info(
-            "engine built: backend=%s pallas_decode=%s grouped_matmul_from_rows=%d "
-            "blocked_buckets=%s slots=%d max_seq=%d chunks=%s quant=%s kv_quant=%s",
+            "engine built: backend=%s pallas_decode=%s grouped_matmul_from_rows=%d decode_blocks=%s"
+            " blocked_buckets=%s slots=%d max_seq=%d chunks=%s quant=%s kv_quant=%s",
             jax.default_backend(), pallas_decode_mode(), GROUPED_MATMUL_MIN_ROWS,
-            self._blocked_buckets(),
-            B, engine_cfg.max_seq, self.cfg.chunk_variants(), qmode, self._kv_quant)
+            decode_blocks(model_cfg, self.cfg, self._dtype), self._blocked_buckets(), B,
+            engine_cfg.max_seq, self.cfg.chunk_variants(), qmode, self._kv_quant)
 
     def _alloc_kv_state(self):
         """Fresh KV arrays at the engine's exact layout, representation,

@@ -20,7 +20,10 @@ from __future__ import annotations
 
 from omnia_tpu.engine.types import EngineConfig
 from omnia_tpu.models import ModelConfig, llama
-from omnia_tpu.ops.attention import prefill_kernel_on, window_kernel_on
+from omnia_tpu.ops.attention import (
+    _kernel_on, decode_block_rows, prefill_kernel_on, window_kernel_on,
+)
+from omnia_tpu.ops.decode_attention import flat_rows
 
 
 def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
@@ -122,6 +125,27 @@ def prefill_blocked(model_cfg: ModelConfig, cfg: EngineConfig, mesh, bucket: int
         not model_cfg.has_window_layers
         or window_kernel_on(bucket, model_cfg.sliding_window, model_cfg.num_heads, width,
                             fresh, mesh))
+
+
+def decode_blocks(model_cfg: ModelConfig, cfg: EngineConfig, dtype) -> str:
+    """The K and V block a grid step of the pair family's decode kernels
+    takes, a cache kind, for the start-up line: "full=1024x128" where the
+    heads lie among the rows (``flat_rows``: rows · a device's KV heads x
+    lanes), "full=256x32x128" where they keep an axis, a ring's after
+    "window=". "" with the kernels routed off, and for the latent family
+    (ops/decode_mla_attention.py has one block)."""
+    if not _kernel_on() or model_cfg.is_latent:
+        return ""
+    stacked = llama.is_stacked(model_cfg)
+    heads = (llama.cache_kv_heads(model_cfg) if stacked else model_cfg.num_kv_heads) // cfg.tp
+    D = model_cfg.head_dim
+    dtype = "int8" if cfg.kv_quant else dtype
+    caches = {"full": decode_block_rows(cfg.max_seq, cfg.kv_page_tokens if cfg.kv_pages else 0)}
+    if model_cfg.has_window_layers:
+        caches["window"] = decode_block_rows(llama.ring_rows(model_cfg))
+    return ",".join(
+        f"{kind}={rows * heads}x{D}" if flat_rows(heads, D, dtype, rows)
+        else f"{kind}={rows}x{heads}x{D}" for kind, rows in caches.items())
 
 
 class _PairCacheMixin:

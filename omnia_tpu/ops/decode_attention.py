@@ -28,10 +28,37 @@ context spans, and none for anything else:
   (a TPU grid runs in order on one core), reset at the slot's block 0
   and written out at its last block.
 - GQA without KV repeat: q reshapes to [Hkv, G, D] and both matmuls
-  batch over the KV-head axis (MXU), accumulating in f32.
+  run a KV head over its G query heads (MXU), accumulating in f32.
 - ONE body for every edition: the paged edition differs only in the
   index map (block ``s`` of slot ``b`` is pool page ``table[b, s]``), the
   int8 edition only in two extra blocks of row scales.
+- the block of K and of V has one of TWO SHAPES, by ``flat_rows`` (shapes
+  and the cache's type, nothing else). ``[BLOCK_S, Hkv, D]``, the cache's
+  own axes: the minor two are a tile, so where the KV heads (those a
+  device holds) are fewer than a tile's sublanes (16 bfloat16, 32 int8, 8
+  float32) every cache row is one padded tile of K and one of V in VMEM,
+  and the copy in, the ``swapaxes`` and the cast all work on 16 sublanes
+  of which 4 or 8 hold anything: the kernel then costs 5.5 ns a row
+  whatever the row holds (PERF.md section 6, PR 53). So there the kernel
+  meets the cache as ``[L, B, S · Hkv, D]``, the heads among the rows:
+  row s's head h is sublane ``s · Hkv + h``, every tile whole. That is a
+  reshape of the operand in ``_attend`` and the SAME BYTES on the chip
+  (it holds ``[.., S, 4, 128]`` in tiles of (4, 128) and ``[.., S · 4,
+  128]`` in tiles of (8, 128), both row after row), a bitcast in the
+  compiled program; ``[.., S, Hkv · D]``, a head's columns a lane block,
+  is another order of bytes and cost a copy of the whole cache a call.
+  The body takes a head's rows out of the block at a stride of ``Hkv``
+  sublanes (``_heads_of_flat_rows``: the chip strides 32-bit words only,
+  so two bfloat16 or four int8 heads come in a word and shifts part
+  them, which for bfloat16 IS the cast to float32) and scores its G
+  queries by a product that contracts D of both operands: no transpose.
+  A short unrolled loop over the heads and not one batched product,
+  because a batch axis in front wants the heads major, [Hkv, BLOCK_S,
+  D], the very relayout the strided loads replace; the heads' [G,
+  BLOCK_S] scores are stacked on a leading axis, which moves nothing, so
+  the mask, ``m``, ``l``, ``acc`` and the int8 scales are one code for
+  both shapes. Heads of 64 lanes, 16 or more bfloat16 heads, an odd
+  number of them: the first shape.
 
 Why a list and not a loop inside one grid step a slot (manual DMA out of
 ``pl.ANY`` into a two-deep VMEM buffer): Mosaic (libtpu 0.0.34) pads an
@@ -52,6 +79,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_S = 256
@@ -70,15 +98,17 @@ def _decode_kernel(
     quantized: bool = False,
     paged: bool = False,
     window: int = 0,
+    flat: bool = False,
 ):
     """One grid step a live (slot, block) pair; see the module docstring.
-    The editions share every line: ``paged`` only adds the table the
-    index maps read, ``quantized`` the two [1, BLOCK_S, Hkv] f32 blocks
-    of row scales, ``window`` the mask of a ring (``decode_window_attention``)."""
+    The editions share every line but the two products: ``paged`` only adds
+    the table the index maps read, ``quantized`` the two [1, BLOCK_S, Hkv]
+    f32 blocks of row scales, ``window`` the mask of a ring
+    (``decode_window_attention``), ``flat`` the block's shape (``flat_rows``)."""
     del layer_ref
     rest = rest[2:] if paged else rest[1:]  # the table, the aliased zeros
-    # q_ref [1, Hkv, G, D]; k_ref, v_ref [1, BLOCK_S, Hkv, D] (bf16, or
-    # int8 when quantized).
+    # q_ref [1, Hkv, G, D]; k_ref, v_ref [1, BLOCK_S, Hkv, D], or [1, 1,
+    # BLOCK_S · Hkv, D] when flat (bf16, or int8 when quantized).
     if quantized:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -93,15 +123,21 @@ def _decode_kernel(
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0].astype(jnp.float32)           # [Hkv, G, D]
-    k = k_ref[0]                               # [BLOCK_S, Hkv, D]
-    v = v_ref[0]
-    # scores [Hkv, G, BLOCK_S] — batch over the KV-head axis.
-    scores = jax.lax.dot_general(
-        q,
-        jnp.swapaxes(k, 0, 1).astype(jnp.float32),  # [Hkv, BLOCK_S, D]
-        dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    # scores [Hkv, G, BLOCK_S]
+    if flat:  # a head at a time: a product a head's [BLOCK_S, D], D against D
+        scores = jnp.stack([
+            jax.lax.dot_general(q[h], k_h, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            for h, k_h in enumerate(_heads_of_flat_rows(k_ref, q.shape[0]))])
+    else:  # batch over the KV-head axis
+        k, v = k_ref[0], v_ref[0]              # [BLOCK_S, Hkv, D]
+        scores = jax.lax.dot_general(
+            q,
+            jnp.swapaxes(k, 0, 1).astype(jnp.float32),  # [Hkv, BLOCK_S, D]
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+    scores = scores * scale
     if quantized:
         # int8-KV edition (EngineConfig.kv_quant): the HBM read streams
         # int8 rows (half the bf16 bytes — the whole point of the mode);
@@ -136,12 +172,17 @@ def _decode_kernel(
     else:
         pv_p = p
     # pv [Hkv, G, D]
-    pv = jax.lax.dot_general(
-        pv_p,
-        jnp.swapaxes(v, 0, 1).astype(jnp.float32),  # [Hkv, BLOCK_S, D]
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
+    if flat:
+        pv = jnp.stack([
+            jnp.dot(pv_p[h], v_h, preferred_element_type=jnp.float32)
+            for h, v_h in enumerate(_heads_of_flat_rows(v_ref, q.shape[0]))])
+    else:
+        pv = jax.lax.dot_general(
+            pv_p,
+            jnp.swapaxes(v, 0, 1).astype(jnp.float32),  # [Hkv, BLOCK_S, D]
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
     acc_ref[:] = acc_ref[:] * alpha[:, :, None] + pv
     l_ref[:] = l_prev * alpha + p.sum(axis=-1)
     m_ref[:] = m_new
@@ -151,6 +192,49 @@ def _decode_kernel(
         out_ref[0] = (
             acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)[:, :, None]
         ).astype(out_ref.dtype)
+
+
+def flat_rows(kv_heads: int, head_dim: int, dtype, block_s: int) -> bool:
+    """Whether a grid step's K and V rows lie in VMEM as ``[block_s · Hkv, D]``
+    (row s's head h at sublane ``s · Hkv + h``: whole tiles) and not as
+    ``[block_s, Hkv, D]``, from what the call can see: heads of whole 128-lane
+    tiles; fewer KV heads (those a device holds) than the sublanes of a tile
+    of the cache's type (8 float32, 16 bfloat16, 32 int8: a row's ``(Hkv, D)``
+    is one tile, padded); one head, or whole 32-bit words of heads (two
+    bfloat16, four int8: ``_heads_of_flat_rows`` takes a word's heads apart);
+    and a block of whole tiles."""
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.float32, jnp.bfloat16, jnp.int8):
+        return False
+    sublanes, a_word = 32 // dtype.itemsize, 4 // dtype.itemsize
+    return (head_dim % 128 == 0 and kv_heads < sublanes
+            and (kv_heads == 1 or kv_heads % a_word == 0)
+            and block_s * kv_heads % sublanes == 0)
+
+
+def _heads_of_flat_rows(ref, kv_heads: int):
+    """The block ``ref`` [1, 1, BLOCK_S · Hkv, D] a KV head at a time, in
+    float32: Hkv arrays [BLOCK_S, D], head h the sublanes h, h + Hkv, …. The
+    chip loads sublanes at a stride in 32-bit words only, and a word of a
+    bfloat16 or int8 tile holds two or four consecutive sublanes of one
+    lane, which here are consecutive heads of one row: the words of a group
+    of heads come at the stride, and shifts take them apart. A bfloat16 is
+    the upper half of its float32, so this is the cast, exactly."""
+    dtype, a_word = ref.dtype, 4 // ref.dtype.itemsize
+    if kv_heads == 1:
+        return [ref[0, 0].astype(jnp.float32)]
+    words = ref if a_word == 1 else ref.bitcast(jnp.int32)  # [1, 1, BLOCK_S · Hkv / a_word, D]
+    groups, heads = kv_heads // a_word, []
+    for g in range(groups):
+        w = words[0, 0, pl.ds(g, ref.shape[2] // kv_heads, stride=groups), :]
+        if dtype == jnp.float32:
+            heads.append(w)
+        elif dtype == jnp.bfloat16:            # the lower half is the even head
+            heads += [jax.lax.bitcast_convert_type(w << 16, jnp.float32),
+                      jax.lax.bitcast_convert_type(w & -65536, jnp.float32)]
+        else:                                  # int8: byte i, its sign carried up
+            heads += [((w << (24 - 8 * i)) >> 24).astype(jnp.float32) for i in range(4)]
+    return heads
 
 
 def _work_list(positions, live, block_s: int, num_s: int):
@@ -189,10 +273,22 @@ def _attend(name, q, k, v, scales, table, positions, live, layer, block_s,
     """The one ``pallas_call`` behind every entry point: ``table`` None
     is the contiguous cache [L, B, S, Hkv, D], else the pool
     [L, P, PAGE_S, Hkv, D] with ``block_s == PAGE_S``; ``window`` > 0 reads
-    the contiguous cache as a ring."""
+    the contiguous cache as a ring. Where ``flat_rows`` holds the kernel
+    meets either with the heads among the rows, [L, B, S · Hkv, D]: the same
+    bytes in the chip's layout, so the reshape moves nothing (under a mesh it
+    is of each device's own heads)."""
     B, H, D = q.shape
     Hkv = k.shape[3]
     G = H // Hkv
+    flat = flat_rows(Hkv, D, k.dtype, block_s)
+    if flat:
+        # Row-major stated, or the compiler is free to lay a cache that no
+        # kernel constrains any more slots-minor for its updates, and copies
+        # it whole in front of every call (the chipless compile of
+        # code-mixed's decode chunk: +1 GB of temporaries).
+        row_major = Layout(major_to_minor=tuple(range(k.ndim)))
+        k, v = (with_layout_constraint(x, row_major).reshape(*x.shape[:2], -1, D)
+                for x in (k, v))
     positions = positions.astype(jnp.int32)
     work, n_work = _work_list(positions, live, block_s, num_s)
     prefetch = [jnp.asarray(layer, jnp.int32).reshape(1), positions, work]
@@ -209,10 +305,14 @@ def _attend(name, q, k, v, scales, table, positions, live, layer, block_s,
         return (layer_ref[0], slot, s, 0)
 
     slot_spec = pl.BlockSpec((1, Hkv, G, D), slot_index, memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec(
-        (None, 1, block_s, Hkv, D), lambda *a: kv_index(*a) + (0,),
-        memory_space=pltpu.VMEM,
-    )
+    if flat:  # (the layer's axis kept: a block that squeezes one has no view in words)
+        kv_spec = pl.BlockSpec((1, 1, block_s * Hkv, D), kv_index,
+                               memory_space=pltpu.VMEM)
+    else:
+        kv_spec = pl.BlockSpec(
+            (None, 1, block_s, Hkv, D), lambda *a: kv_index(*a) + (0,),
+            memory_space=pltpu.VMEM,
+        )
     scale_spec = pl.BlockSpec(
         (None, 1, block_s, Hkv), kv_index, memory_space=pltpu.VMEM,
     )
@@ -233,7 +333,10 @@ def _attend(name, q, k, v, scales, table, positions, live, layer, block_s,
         functools.partial(
             _decode_kernel, block_s=block_s, num_s=num_s, scale=D**-0.5,
             quantized=bool(scales), paged=table is not None,
+            # (each named only where it is on: a call that keeps the first
+            # shape lowers to the text it lowered to before there was a second)
             **({"window": window} if window else {}),
+            **({"flat": True} if flat else {}),
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         grid_spec=grid_spec,

@@ -3,6 +3,7 @@ the described chip, a module's memo of compiled programs, the readers of
 compiled text, and the bodies of the cases that more than one cell runs
 (each cell's file parametrises them over its own cells)."""
 
+import hashlib
 import os
 import re
 import sys
@@ -74,6 +75,14 @@ def lower_program(programs, program, size, params, cache, slots, sharding):
             params, *cache, *tokens, i32, i32, arg(jnp.uint32, 2), f32, f32, i32)
     assert program == "extend_nosample", program
     return programs.extend_nosample.lower(params, *cache, *tokens, i32, i32)
+
+
+def program_digest(text: str) -> list:
+    """A lowered program's lines, and a digest of the text without the Mosaic
+    kernels' serialized bodies: those embed the checkout's path in their
+    source locations."""
+    text = re.sub(r'(@tpu_custom_call\(.*?backend_config = )"[^"]*"', r"\1<kernel>", text)
+    return [len(text.splitlines()), hashlib.sha256(text.encode()).hexdigest()[:16]]
 
 
 class CellPrograms:

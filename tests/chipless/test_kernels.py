@@ -15,7 +15,11 @@ from .cells import (
 )
 
 PAGE_S = 64
-WIDTHS = {"llama3-1b": (32, 8, 64), "llama3-8b": (32, 8, 128)}  # H, Hkv, D
+# H, Hkv, D: heads of 64 keep the block [rows, Hkv, D]; at 128 lanes eight
+# and four KV heads take the block [rows · Hkv, D] (``dk.flat_rows``), bfloat16
+# and int8, contiguous and paged.
+WIDTHS = {"llama3-1b": (32, 8, 64), "llama3-8b": (32, 8, 128),
+          "mellum2-12b-a2p5b": (32, 4, 128)}
 
 
 @pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16", "int8kv"])
@@ -43,8 +47,11 @@ def test_decode_kernel_compiles(one_chip, paged, model, kv_int8):
         fn = dk.decode_gqa_attention
     if kv_int8:
         args += (scale, scale)
+    assert dk.flat_rows(Hkv, D, kv_dtype, PAGE_S if paged else dk.DEFAULT_BLOCK_S) == (D == 128)
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if D == 128:  # the heads among the rows are the cache's own bytes: nothing is copied
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("slots,rows,rank,lanes", [
@@ -73,29 +80,39 @@ def test_latent_decode_kernel_compiles(one_chip, slots, rows, rank, lanes):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("slots,dtype", [(32, jnp.bfloat16), (1, jnp.bfloat16),
-                                         (1, jnp.float32)],
-                         ids=["longdoc-batch", "the-check", "the-long-check-in-float32"])
-def test_window_decode_kernel_compiles(one_chip, slots, dtype):
+@pytest.mark.parametrize("slots,dtype,heads,kv_heads,layers,ring,block,rows", [
+    (32, jnp.bfloat16, 64, 8, 4, 128, 128, 8960), (1, jnp.bfloat16, 64, 8, 4, 128, 128, 8960),
+    (1, jnp.float32, 64, 8, 4, 128, 128, 8960),
+    (48, jnp.bfloat16, 32, 4, 6, 1024, 256, 5888), (1, jnp.float32, 32, 4, 6, 1024, 256, 5888),
+], ids=["longdoc-batch", "the-check", "the-long-check-in-float32",
+        "code-mixed", "code-mixed-the-long-check-in-float32"])
+def test_window_decode_kernel_compiles(one_chip, slots, dtype, heads, kv_heads, layers, ring,
+                                       block, rows):
     """`decode_window_attention` at K-EXAONE's published widths (64 query
     heads on 8 KV heads of 128, a window of 128 rows in a ring of 128: one
     block a live slot whatever its context), over the four window layers'
-    rings at the cell's 32 slots and at the one slot the checks run; the
-    full layer's `decode_gqa_attention` beside it at the cell's 8960 rows."""
+    rings at the cell's 32 slots and at the one slot the checks run, and at
+    Mellum 2's (32 on 4 heads of 128, six rings of 1,024 rows in four blocks,
+    48 slots); the full layers' `decode_gqa_attention` beside it at the
+    cell's rows. Eight float32 heads fill a tile and keep the block [rows,
+    Hkv, D]; every other case takes [rows · Hkv, D]."""
     def arr(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    q, pos = arr((slots, 64, 128), dtype), arr((slots,), jnp.int32)
-    ring = arr((4, slots, 128, 8, 128), dtype)
+    assert dk.flat_rows(kv_heads, 128, dtype, block) == (
+        (kv_heads, dtype) != (8, jnp.float32))
+    q, pos = arr((slots, heads, 128), dtype), arr((slots,), jnp.int32)
+    rings = arr((layers, slots, ring, kv_heads, 128), dtype)
     compiled = jax.jit(
         lambda q, k, v, pos, layer, live: dk.decode_window_attention(
-            q, k, v, pos, layer, live=live, window=128, block_s=128)
-    ).lower(q, ring, ring, pos, arr((), jnp.int32), pos).compile()
+            q, k, v, pos, layer, live=live, window=ring, block_s=block)
+    ).lower(q, rings, rings, pos, arr((), jnp.int32), pos).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
-    whole = arr((1, slots, 8960, 8, 128), dtype)
+    whole = arr((1, slots, rows, kv_heads, 128), dtype)
     compiled = dk.decode_gqa_attention.lower(q, whole, whole, pos, arr((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_one_chip_decode_step_holds_the_mosaic_call(one_chip, kernel_route_on):

@@ -70,6 +70,24 @@ def test_the_cells_programs_fit_the_chip_at_the_real_size(cell_programs, kernel_
     assert ("delta.state" if program == "decode" else "delta.chunk") in text
 
 
+def test_32_held_heads_keep_the_parents_decode_program(cell_programs, kernel_route_on):
+    """The cell's decode program (a chunk of 8 steps) lowers to the text it
+    lowered to at f1e7612, before the decode kernels had a second block shape
+    (PR 53): 32 held KV heads leave no sublane of a tile empty, `flat_rows`
+    says so, and nothing in front of the call or in its arguments moved."""
+    from omnia_tpu.engine.programs import build_programs
+    from omnia_tpu.ops.decode_attention import flat_rows
+
+    from . import cells
+
+    cfg, ecfg, params, cache = cell_programs.cell(CELL)
+    assert not flat_rows(cache[0].shape[3], cfg.head_dim, cache[0].dtype, 256)
+    got = cells.program_digest(cells.lower_program(
+        build_programs(cfg, ecfg, None), "decode", 8, params, cache, ecfg.num_slots,
+        cell_programs.one_chip).as_text())
+    assert got == [7339, "7b39056eda5b0cb2"], got
+
+
 def test_the_state_kernel_compiles_in_place_at_the_published_widths(one_chip):
     """`decode_delta_state` at 30 heads of 96 x 192 float32 held two a row, 64
     slots, six layers: five packed heads a block (the choice the chip made:

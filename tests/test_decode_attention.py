@@ -12,6 +12,7 @@ from omnia_tpu.ops.attention import gqa_attention
 from omnia_tpu.ops.decode_attention import (
     decode_gqa_attention,
     decode_gqa_attention_paged,
+    flat_rows,
 )
 
 
@@ -25,6 +26,9 @@ def _setup(B=4, S=512, H=8, Hkv=2, D=128, seed=0, dtype=jnp.float32):
 
 L = 3  # layers of the whole caches the kernel is handed
 LAYERS = pytest.mark.parametrize("layer", [0, L - 1])
+# KV heads of 128 lanes: the float32 caches of these cases take the block
+# [rows · Hkv, D] under 8 heads (``flat_rows``) and [rows, Hkv, D] at 8.
+KV_HEADS = pytest.mark.parametrize("kv_heads", [1, 2, 4, 8])
 
 
 def _whole(x, layer):
@@ -88,10 +92,12 @@ class TestDecodeAttention:
         out = _kernel(q[:, 0], k, v, pos, layer, block_s=128)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
-    def test_rows_past_position_do_not_influence(self):
+    @KV_HEADS
+    def test_rows_past_position_do_not_influence(self, kv_heads):
         """Poison cache rows beyond each position with huge values — the
         kernel must produce identical output (those blocks are skipped)."""
-        q, k, v = _setup(B=2, S=256, H=4, Hkv=2, D=128)
+        q, k, v = _setup(B=2, S=256, H=2 * kv_heads, Hkv=kv_heads, D=128)
+        assert flat_rows(kv_heads, 128, k.dtype, 64) == (kv_heads < 8)
         pos = jnp.asarray([63, 190], dtype=jnp.int32)
         out_clean = _kernel(q[:, 0], k, v, pos, block_s=64)
         k_poison, v_poison = np.asarray(k).copy(), np.asarray(v).copy()
@@ -101,8 +107,13 @@ class TestDecodeAttention:
         out_poison = _kernel(q[:, 0], k_poison, v_poison, pos, block_s=64)
         np.testing.assert_allclose(np.asarray(out_clean), np.asarray(out_poison))
 
-    def test_bf16_inputs(self):
-        q, k, v = _setup(B=2, S=256, H=8, Hkv=4, D=128, dtype=jnp.bfloat16)
+    @pytest.mark.parametrize("kv_heads", [1, 2, 4, 8, 16])
+    def test_bf16_inputs(self, kv_heads):
+        """A bfloat16 tile has 16 sublanes: up to 8 heads (the served 4 and 8
+        among them) the block is [rows · Hkv, D] and a word of two heads is
+        taken apart by a shift and a mask, which is the cast to float32."""
+        q, k, v = _setup(B=2, S=256, H=2 * kv_heads, Hkv=kv_heads, D=128, dtype=jnp.bfloat16)
+        assert flat_rows(kv_heads, 128, k.dtype, 128) == (kv_heads < 16)
         pos = jnp.asarray([100, 200], dtype=jnp.int32)
         ref = gqa_attention(q, k, v, pos[:, None])[:, 0]
         out = _kernel(q[:, 0], k, v, pos, block_s=128)
@@ -137,13 +148,16 @@ class TestDecodeAttention:
         )
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
-    def test_quantized_rows_past_position_do_not_influence(self):
+    @KV_HEADS
+    def test_quantized_rows_past_position_do_not_influence(self, kv_heads):
         """Scale blocks ride the same clamped index map as the KV
         blocks: poisoned rows AND poisoned scales beyond each position
-        must not change the output."""
+        must not change the output. (int8: four heads a word, so one head
+        and whole fours take the block [rows · Hkv, D]; two keep [rows, Hkv, D].)"""
         from omnia_tpu.models import kv_quant as kvq
 
-        q, k, v = _setup(B=2, S=256, H=4, Hkv=2, D=128)
+        q, k, v = _setup(B=2, S=256, H=2 * kv_heads, Hkv=kv_heads, D=128)
+        assert flat_rows(kv_heads, 128, jnp.int8, 64) == (kv_heads != 2)
         pos = jnp.asarray([63, 190], dtype=jnp.int32)
         qk, qv = kvq.quantize_rows(k), kvq.quantize_rows(v)
         clean = _kernel(
@@ -358,8 +372,9 @@ class TestLiveSlotsOnly:
     reads nothing and its output row is zeros."""
 
     @EDITIONS
-    def test_dead_slot_reads_nothing_and_returns_zeros(self, edition):
-        q, k, v = _setup(B=4, S=512, H=4, Hkv=2, D=128)
+    @KV_HEADS
+    def test_dead_slot_reads_nothing_and_returns_zeros(self, edition, kv_heads):
+        q, k, v = _setup(B=4, S=512, H=2 * kv_heads, Hkv=kv_heads, D=128)
         pos = jnp.asarray([300, 0, 17, 511], jnp.int32)
         live = [1, 0, 1, 0]
         clean = _edition_run(edition, q[:, 0], k, v, pos)
@@ -379,7 +394,7 @@ class TestLiveSlotsOnly:
         last row, under every pattern of live and dead neighbours: a
         slot's first block follows another slot's last, or nothing."""
         S = 512
-        q, k, v = _setup(B=4, S=S, H=4, Hkv=2, D=128)
+        q, k, v = _setup(B=4, S=S, H=8, Hkv=4, D=128)
         pos = jnp.asarray([0, 255, 256, S - 1], jnp.int32)
         ref = np.array(_edition_ref(edition, q, k, v, pos))
         ref[~np.asarray(live, bool)] = 0.0
@@ -432,7 +447,7 @@ def route(monkeypatch):
 
 
 class TestKernelUnderMesh:
-    @LAYERS
+    @pytest.mark.parametrize("layer,D", [(0, 64), (L - 1, 64), (L - 1, 128)])
     @pytest.mark.parametrize(
         "dp,tp,layout",
         [(dp, tp, layout)
@@ -441,11 +456,13 @@ class TestKernelUnderMesh:
          if not (layout == "paged" and dp > 1)],  # refused: TestKernelRefusals
     )
     def test_sharded_kernel_matches_einsum(self, devices8, route, dp, tp,
-                                           layout, layer):
+                                           layout, layer, D):
         """Whole caches sharded the way the engine shards them (layers
         whole, slots over dp, KV heads over tp): the shard_mapped kernel
         on layer ``layer`` must give what the GSPMD-partitioned einsum
-        path gives on that layer's slice (the other layers are NaN)."""
+        path gives on that layer's slice (the other layers are NaN). At
+        128 lanes a device's own two heads or one take the block [rows ·
+        Hkv, D] inside the shard_map (int8 at two heads keeps [rows, Hkv, D])."""
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
@@ -454,7 +471,7 @@ class TestKernelUnderMesh:
         from omnia_tpu.parallel import make_mesh
 
         mesh = make_mesh(dp, tp, devices=devices8)
-        q, k, v = _setup(B=4, S=512, H=8, Hkv=4, D=64)
+        q, k, v = _setup(B=4, S=512, H=8, Hkv=4, D=D)
         pos = jnp.asarray([3, 255, 256, 510], dtype=jnp.int32)
 
         def put(x, *spec):
@@ -490,12 +507,13 @@ class TestKernelUnderMesh:
             np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
         )
 
+    @pytest.mark.parametrize("D", [64, 128])
     @pytest.mark.parametrize(
         "dp,tp,layout",
         [(2, 2, "plain"), (2, 2, "int8"), (1, 2, "paged")],
     )
     def test_live_mask_is_sliced_with_the_slots(self, devices8, route, dp, tp,
-                                                layout):
+                                                layout, D):
         """The mask goes into the shard_map sliced over "dp" like the
         positions: each shard skips its own dead slots (their rows and
         scales are NaN) and gives the einsum's answer for its live ones."""
@@ -507,7 +525,7 @@ class TestKernelUnderMesh:
         from omnia_tpu.parallel import make_mesh
 
         mesh = make_mesh(dp, tp, devices=devices8)
-        q, k, v = _setup(B=4, S=512, H=8, Hkv=4, D=64)
+        q, k, v = _setup(B=4, S=512, H=8, Hkv=4, D=D)
         pos = jnp.asarray([3, 255, 256, 510], dtype=jnp.int32)
         live = np.asarray([True, False, False, True])  # one a dp shard
         route("0")
@@ -622,3 +640,86 @@ class TestKernelRefusals:
         route("interpret")
         with pytest.raises(ValueError, match="cache length 300"):
             gqa_attention(q, k, v, pos)
+
+
+class TestWhichBlockACallTakes:
+    """``flat_rows``: the one rule for the shape of a grid step's K and V
+    block, from KV heads (a device's own), head width, the cache's type and
+    the rows a block."""
+
+    @pytest.mark.parametrize("kv_heads,head_dim,dtype,block_s,flat", [
+        (4, 128, jnp.bfloat16, 256, True),     # mellum2-12b-a2p5b
+        (8, 128, jnp.bfloat16, 256, True),     # mistral-7b, k-exaone-236b-a23b
+        (8, 128, jnp.bfloat16, 128, True),     # k-exaone's ring of 128 rows
+        (2, 128, jnp.bfloat16, 256, True),     # mistral-7b at tp=4
+        (1, 128, jnp.bfloat16, 256, True),
+        (32, 128, jnp.bfloat16, 256, False),   # olmo-hybrid-7b: no sublane empty
+        (16, 128, jnp.bfloat16, 256, False),
+        (3, 128, jnp.bfloat16, 256, False),    # no whole words of two heads
+        (8, 64, jnp.bfloat16, 256, False),     # llama3-1b: heads of half a tile
+        (2, 16, jnp.float32, 256, False),      # the tiny presets
+        (4, 128, jnp.float32, 256, True),      # the long checks in float32
+        (8, 128, jnp.float32, 256, False),     # eight float32 heads fill a tile
+        (4, 128, jnp.int8, 64, True),
+        (8, 128, jnp.int8, 64, True),
+        (16, 128, jnp.int8, 64, True),
+        (2, 128, jnp.int8, 64, False),         # half a word of four heads
+        (32, 128, jnp.int8, 64, False),
+        (4, 128, jnp.float16, 256, False),     # no rule for taking its words apart
+        (1, 128, jnp.bfloat16, 8, False),      # a block of half a tile of rows
+        (4, 128, jnp.bfloat16, 4, True),       # four rows of four heads: one tile
+    ])
+    def test_the_predicate(self, kv_heads, head_dim, dtype, block_s, flat):
+        assert flat_rows(kv_heads, head_dim, dtype, block_s) is flat
+        assert flat_rows(kv_heads, head_dim, jnp.dtype(dtype), block_s) is flat
+
+    @pytest.mark.parametrize("preset,engine,want", [
+        ("llama3-8b", dict(), "full=2048x128"),              # 8 heads: 256 rows x 8
+        ("llama3-8b", dict(tp=4), "full=512x128"),           # a device's own two
+        ("llama3-8b", dict(kv_quant="int8"), "full=2048x128"),
+        ("llama3-8b", dict(kv_pages=64, kv_page_tokens=64), "full=512x128"),
+        ("llama3-1b", dict(), "full=256x8x64"),              # heads of 64 lanes
+        ("test-tiny-window", dict(), "full=256x2x16,window=8x2x16"),
+    ])
+    def test_the_engines_start_up_line_names_the_block(self, route, preset, engine, want):
+        """``engine/family.py::decode_blocks``, what "engine built:" logs
+        beside the kernels' route: rows · heads x lanes where the heads lie
+        among the rows, rows x heads x lanes where they keep an axis, a kind
+        of cache; nothing with the kernels off."""
+        from omnia_tpu.engine.family import decode_blocks
+        from omnia_tpu.engine.types import EngineConfig
+        from omnia_tpu.models import get_config
+
+        cfg = get_config(preset)
+        ecfg = EngineConfig(num_slots=4, max_seq=2048, **engine)
+        route("interpret")
+        assert decode_blocks(cfg, ecfg, jnp.bfloat16) == want
+        route("0")
+        assert decode_blocks(cfg, ecfg, jnp.bfloat16) == ""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+    @KV_HEADS
+    def test_both_blocks_give_the_same_numbers(self, monkeypatch, kv_heads, dtype):
+        """The same call with the rule switched off takes the block [rows,
+        Hkv, D]: the same float32 products and sums a head, so the two agree
+        to float32 rounding whatever the cache's type."""
+        import omnia_tpu.ops.decode_attention as dk
+        from omnia_tpu.models import kv_quant as kvq
+
+        q, k, v = _setup(B=3, S=256, H=2 * kv_heads, Hkv=kv_heads, D=128,
+                         dtype=jnp.float32 if dtype == jnp.int8 else dtype)
+        pos = jnp.asarray([0, 70, 255], jnp.int32)
+        kw = {}
+        if dtype == jnp.int8:
+            qk, qv = kvq.quantize_rows(k), kvq.quantize_rows(v)
+            k, v, kw = qk.q, qv.q, dict(k_scale=qk.s, v_scale=qv.s)
+
+        def run():
+            jax.clear_caches()  # the rule is read while tracing
+            return np.asarray(_kernel(q[:, 0], k, v, pos, block_s=64, **kw), np.float32)
+
+        got = run()
+        monkeypatch.setattr(dk, "flat_rows", lambda *a: False)
+        want = run()
+        np.testing.assert_allclose(got, want, atol=2e-6 if dtype != jnp.bfloat16 else 8e-3,
+                                   rtol=1e-5)

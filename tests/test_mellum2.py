@@ -443,15 +443,21 @@ def interpreted(monkeypatch):
     attn._pallas_decode_mode.cache_clear()
 
 
-def test_the_window_kernel_over_a_ring_of_four_blocks(interpreted, monkeypatch):
+@pytest.mark.parametrize("H,Hkv,D,flat", [(4, 2, 16, False), (32, 4, 128, True)],
+                         ids=["2x16", "the-served-4x128"])
+def test_the_window_kernel_over_a_ring_of_four_blocks(interpreted, monkeypatch, H, Hkv, D, flat):
     """`decode_window_attention` (interpreted) at the served ring: 1,024 rows
-    in four blocks of 256, the window the whole ring. Slots that have not
+    in four blocks of 256, the window the whole ring, at the tiny widths (a
+    block `[256, 2, 16]`) and at the served 32 heads on 4 of 128 (a block
+    `[1024, 128]`, the heads among the rows). Slots that have not
     reached the ring's end (positions 3, 300 and 1,022: the kernel spans one,
     two and four blocks) beside slots that have wrapped it once and five
     times, and a dead one; the rows a slot's positions have not reached are
     poisoned, in the blocks it reads and in those it must not."""
-    B, H, Hkv, D, R = 6, 4, 2, 16, 1024
-    assert attn.decode_block_rows(R) == 256
+    from omnia_tpu.ops.decode_attention import flat_rows
+
+    B, R = 6, 1024
+    assert attn.decode_block_rows(R) == 256 and flat_rows(Hkv, D, jnp.float32, 256) == flat
     keys = jax.random.split(jax.random.key(3), 3)
     q = jax.random.normal(keys[0], (B, 1, H, D))
     rk = jax.random.normal(keys[1], (2, B, R, Hkv, D))
