@@ -466,10 +466,10 @@ class InferenceEngine(
             # steps and layers and read back with its tokens: the (token, expert)
             # assignments that landed on an expert held here, and the held experts
             # that got a token, a layer a step, over every row of the batch, live or
-            # not; decode_kda_slots (the latent family's) and decode_delta_slots (the
-            # pair family's): the states linear-attention layers updated, the same way.
+            # not; decode_kda_slots (the latent family's), decode_delta_slots, decode_mamba_slots
+            # (the pair family's): the states linear-attention / state-space layers updated.
             "moe_assignments_held": 0, "moe_experts_hit": 0,
-            "decode_kda_slots": 0, "decode_delta_slots": 0,
+            "decode_kda_slots": 0, "decode_delta_slots": 0, "decode_mamba_slots": 0,
             # Paged KV cache (engine/kv_pages.py) — pool gauges, live
             # while kv_pages > 0 and zero otherwise: usable pages total/
             # free, internal fragmentation of slot-referenced pages

@@ -9,10 +9,10 @@ no row axis at all: a slot's view takes its axis 2, the heads, whole) and
 the short convolution's tail. The programs and mixins that every
 family has (prefill, extend, decode, warmup) take and return it whole, and
 never index it: which rows and steps may touch a slot's state is the model
-module's word (models/mla.py::_kda_layer, models/stacks.py::_delta_mixer for
-the pair family's linear-attention layers, whose cache is K and V of the
-full layers beside such states and tails), since nothing masks a state by
-position afterwards. What exists for the pair family alone names the
+module's word (models/mla.py::_kda_layer, models/stacks.py::_delta_mixer and
+_mamba_mixer for the pair family's linear-attention and state-space layers,
+whose cache is K and V of the full layers beside such states and tails), since
+nothing masks a state by position afterwards. What exists for the pair family alone names the
 pair's two arrays, and is refused for another family when the engine is
 built."""
 
@@ -33,8 +33,9 @@ def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
     nothing falls through to a (K, V) pair silently. Two kinds of model are
     refused so: one of the latent family (models/mla.py), and one of the
     pair family whose layers are of several kinds (models/llama.py's stacks:
-    window layers, linear-attention layers, a share of the routed experts,
-    leading dense layers). What a recurrent state rules out is said for a
+    window layers, linear-attention layers, state-space layers, a share of the
+    routed experts, leading dense layers). What a recurrent state rules out is
+    said for a
     model of either family that has one."""
     if model_cfg.is_latent:
         family, why = "the latent-attention family (models/mla.py", {}
@@ -71,9 +72,10 @@ def refuse_unported(model_cfg: ModelConfig, cfg: EngineConfig) -> None:
     else:
         return
     if model_cfg.has_state_layers:  # of either family: these reasons come first
-        state = ("its linear-attention layers keep a recurrent state a slot, a "
-                 "matrix a head that every token of the context is summed into, "
-                 "with no rows")
+        layers, what = (("state-space", "some numbers a channel") if "mamba" in
+                        model_cfg.attention_kinds else ("linear-attention", "a matrix a head"))
+        state = (f"its {layers} layers keep a recurrent state a slot, {what} "
+                 "that every token of the context is summed into, with no rows")
         why = {
             **why,
             "max_sessions": f"{state}: a session's rows are offloaded and restored "

@@ -16,8 +16,8 @@ def model_module(cfg: ModelConfig):
 
 def cache_arrays(cfg: ModelConfig, kv_quant=None) -> int:
     """How many arrays the cache tuple of ``cfg`` has (K and V; with window
-    layers their rings besides, with linear-attention layers their float32
-    states and convolution tails; the latent family's one, or with
+    layers their rings besides, with linear-attention or state-space layers
+    their float32 states and convolution tails; the latent family's one, or with
     linear-attention layers its rows, their recurrent states and their
     convolutions' tails), counted on the module's own ``init_kv_cache``: the
     operands right behind ``params`` of every program that takes the cache
@@ -35,8 +35,9 @@ def decode_counters(cfg: ModelConfig) -> tuple:
     returns them (engine.metrics keys): the module's ``decode_counters(cfg)``
     where what it counts depends on the model (the expert layer's; the
     states a step updates, ``decode_kda_slots`` in the latent family and
-    ``decode_delta_slots`` in the pair family, for a model with
-    linear-attention layers), else its ``DECODE_COUNTERS``, else none."""
+    ``decode_delta_slots`` or ``decode_mamba_slots`` in the pair family, for
+    a model with linear-attention or state-space layers), else its
+    ``DECODE_COUNTERS``, else none."""
     module = model_module(cfg)
     own = getattr(module, "decode_counters", None)
     return tuple(own(cfg)) if own else tuple(getattr(module, "DECODE_COUNTERS", ()))

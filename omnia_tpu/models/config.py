@@ -153,6 +153,22 @@ class ModelConfig:
     # "post", on each sublayer's output with nothing in front
     # (x + norm(f(x)): the OLMo 2/3 block).
     norm_placement: str = "pre"
+    # State-space layers in the pair family (models/stacks.py; ``layer_types``
+    # "mamba"): the Mamba-1 selective scan (ops/mamba.py) over mamba_expand x
+    # hidden_size channels with mamba_d_state state numbers each, behind a
+    # depthwise causal convolution of mamba_d_conv taps (with a bias where
+    # mamba_conv_bias); the step Δ passes through a rank of mamba_dt_rank;
+    # mamba_proj_bias puts a bias on the in and out projections (not built);
+    # mamba_inner_norms RMS-norms Δ's rank, B and C before they are used (a
+    # gain each). A slot's state is a float32 [mamba_d_state, channels]
+    # matrix a layer and the convolution's last mamba_d_conv - 1 input rows.
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 0
+    mamba_expand: int = 2
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    mamba_inner_norms: bool = False
     # With qk_norm, the width one RMSNorm spans: False, each head's
     # head_dim values with one gain [head_dim] for all heads; True, the
     # whole projected width before the heads are split, gains [q_dim] and
@@ -176,6 +192,11 @@ class ModelConfig:
         return self.kv_rank > 0
 
     @property
+    def mamba_channels(self) -> int:
+        """E: the channels of a state-space layer, mamba_expand x hidden_size."""
+        return self.mamba_expand * self.hidden_size
+
+    @property
     def attn_value_width(self) -> int:
         """Lanes of one head's values in attention: the width the blocked
         prefill kernel's route asks to be whole 128s (a latent head's key
@@ -192,15 +213,17 @@ class ModelConfig:
 
     @property
     def attention_kinds(self) -> tuple:
-        """"window", "full" or the family's linear kind ("kda" in the latent
-        family, "delta" in the pair family) for layer 0 ... num_layers - 1."""
+        """"window", "full", the family's linear kind ("kda" in the latent
+        family, "delta" in the pair family) or "mamba" for layer 0 ...
+        num_layers - 1."""
         if self.layer_types is None:
             return ("full",) * self.num_layers
         if len(self.layer_types) < self.num_layers:
             raise ValueError(f"layer_types names {len(self.layer_types)} layers "
                              f"of {self.num_layers}")
         kinds = {"sliding_attention": "window", "full_attention": "full",
-                 "linear_attention": "kda" if self.is_latent else "delta"}
+                 "linear_attention": "kda" if self.is_latent else "delta",
+                 "mamba": "mamba"}
         return tuple(kinds[t] for t in self.layer_types[:self.num_layers])
 
     @property
@@ -211,8 +234,9 @@ class ModelConfig:
     @property
     def has_state_layers(self) -> bool:
         """Whether a slot's cache holds a recurrent state (linear-attention
-        layers, or the stacks of a model cut out of one that has them)."""
-        return any(kind.endswith(("kda", "delta"))
+        or state-space layers, or the stacks of a model cut out of one that
+        has them)."""
+        return any(kind.endswith(("kda", "delta", "mamba"))
                    for kind in self.attention_kinds + (self.layer_stacks or ()))
 
     def num_params(self) -> int:
@@ -220,7 +244,8 @@ class ModelConfig:
         with a share of the routed experts (moe_ffn_hidden_size), the held
         ones beside the shared expert and the router, in the layers behind
         the num_dense_layers leading ones; a QK-norm's two gains a layer; a
-        linear-attention layer's projections, taps, gates and head norm.
+        linear-attention layer's projections, taps, gates and head norm; a
+        state-space layer's projections, taps, A, D and inner norms.
         Exact for the pair family; the latent family's attention and
         hyper-connection maps are not counted (models/mla.py::init_params
         is their word)."""
@@ -233,6 +258,11 @@ class ModelConfig:
         linear = ((d + self.linear_conv_kernel) * width + 2 * d * hl + 2 * hl
                   + 2 * d * hl * dv + dv)
         n_linear = self.attention_kinds.count("delta")
+        e, n, r = self.mamba_channels, self.mamba_d_state, self.mamba_dt_rank
+        mamba = (2 * d * e + (self.mamba_d_conv + self.mamba_conv_bias) * e
+                 + e * (r + 2 * n) + (r + 2 * n) * self.mamba_inner_norms
+                 + r * e + e + n * e + e + e * d)    # in, conv, x, norms, dt, A, D, out
+        n_mamba = self.attention_kinds.count("mamba")
         dense_mlp, dense = 3 * d * f, self.num_layers
         sparse_mlp = 0
         if self.moe_ffn_hidden_size:
@@ -244,6 +274,7 @@ class ModelConfig:
             dense_mlp = self.num_experts * 3 * d * f + d * self.num_experts
         embed = v * d * (1 if self.tie_embeddings else 2)
         return (self.num_layers * (attn + 2 * d) + n_linear * (linear - attn)
+                + n_mamba * (mamba - attn)
                 + dense * dense_mlp
                 + (self.num_layers - dense) * sparse_mlp + embed + d)
 
@@ -511,6 +542,30 @@ PRESETS: dict[str, ModelConfig] = {
         linear_value_head_dim=16,
         linear_conv_kernel=4,
         linear_allow_neg_eigval=True,
+    ),
+    # The pair family with state-space layers in the published order's shape,
+    # M M A M M: 128 channels of 16 state numbers, a step rank of 8, the three
+    # inner norms, a convolution bias; the attention layer has four query
+    # heads on ONE KV head and no rotary position; the head is the table's.
+    "test-tiny-mamba": ModelConfig(
+        name="test-tiny-mamba",
+        vocab_size=256,
+        hidden_size=64,
+        num_layers=5,
+        num_heads=4,
+        num_kv_heads=1,
+        head_dim=16,
+        ffn_hidden_size=128,
+        rms_norm_eps=1e-6,
+        max_seq_len=512,
+        tie_embeddings=True,
+        layer_types=("mamba", "mamba", "full_attention", "mamba", "mamba"),
+        rope_on_full_layers=False,
+        mamba_d_state=16,
+        mamba_d_conv=4,
+        mamba_dt_rank=8,
+        mamba_expand=2,
+        mamba_inner_norms=True,
     ),
     "test-tiny-moe": ModelConfig(
         name="test-tiny-moe",

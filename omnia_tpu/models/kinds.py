@@ -10,8 +10,8 @@ family's ``kinds``; benchmark/README.md ("`layers`: one tree, or stacks")
 sets out ``layer_order`` / ``with_layer_order``. A family passes its own
 ``kinds`` (every kind a layer of it can be) and ``names`` (its name for
 each of ``cfg.attention_kinds``' words, where they differ): models/stacks.py
-the pair family's window, full and linear-attention ("delta") layers,
-models/mla.py the latent family's linear-attention ("kda") and latent ones.
+the pair family's window, full, linear-attention ("delta") and state-space
+("mamba") layers, models/mla.py the latent family's linear-attention ("kda") and latent ones.
 """
 
 from __future__ import annotations
@@ -22,7 +22,7 @@ from omnia_tpu.models.config import ModelConfig
 
 #: ``cfg.attention_kinds``' word for each of ``cfg.layer_types``'.
 _TYPES = {"window": "sliding_attention", "full": "full_attention", "kda": "linear_attention",
-          "delta": "linear_attention"}
+          "delta": "linear_attention", "mamba": "mamba"}
 
 
 def layer_kinds(cfg: ModelConfig, names=None) -> tuple:

@@ -24,12 +24,14 @@ models; see SURVEY.md §0):
 - Compute dtype bf16 (MXU native), logits and softmax statistics f32.
 
 **Layers of several kinds** (``is_stacked(cfg)``: window layers,
-linear-attention layers, a share of the routed experts, leading dense
-layers, norms on the sublayers' outputs) are models/stacks.py's, which this
-module dispatches to at the top of each entry point: ``params["layers"]`` is
+linear-attention layers, state-space layers, a share of the routed experts,
+leading dense layers, norms on the sublayers' outputs) are models/stacks.py's,
+which this module dispatches to at the top of each entry point:
+``params["layers"]`` is
 then a sequence of stacks, the cache of a model with window layers four
 arrays (whole contexts beside rings), that of a model with linear-attention
-layers four too (whole contexts, float32 states, convolution tails), and
+or state-space layers four too (whole contexts, float32 states, convolution
+tails), and
 ``layer_order`` /
 ``with_layer_order`` state and cut the order. Everything above is the model
 whose layers are all alike, which traces none of that and compiles to the
@@ -67,6 +69,7 @@ from omnia_tpu.models.stacks import (  # noqa: F401  (the module contract's name
     rope_tables,
     stack_kinds,
     state_shape,
+    tail_shape,
     with_layer_order,
 )
 from omnia_tpu.ops.attention import einsum_attention, gqa_attention
@@ -217,7 +220,10 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloat16,
     model with linear-attention layers: (k, v) of its full layers, then the
     delta layers' states [Ld, B, H/p, dk, p·dv] (float32 whatever ``dtype``;
     ``stacks.state_heads_a_row`` heads side by side along the lanes) and
-    their convolutions' tails [Ld, B, taps - 1, 2·H·dk + H·dv]. Either
+    their convolutions' tails [Ld, B, taps - 1, 2·H·dk + H·dv]; with
+    state-space layers in their place the Mamba layers' states [Lm, B, N, E]
+    float32 and tails [Lm, B, (K - 1)·E] (``stacks.state_shape``,
+    ``stacks.tail_shape``). Either
     model's rows hold ``cache_kv_heads`` heads (more than ``num_kv_heads``
     where that is over eight and no whole number of eights)."""
     shape = (cfg.num_layers, batch, seq, cfg.num_kv_heads, cfg.head_dim)
@@ -227,10 +233,10 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloat16,
                                       "cache with recurrent states")
         kinds = cfg.attention_kinds
         full = (kinds.count("full"), batch, seq, cache_kv_heads(cfg), cfg.head_dim)
-        Ld = kinds.count("delta")
+        Ld = kinds.count("delta") + kinds.count("mamba")
         return (jnp.zeros(full, dtype=dtype), jnp.zeros(full, dtype=dtype),
                 jnp.zeros((Ld, batch, *state_shape(cfg)), dtype=jnp.float32),
-                jnp.zeros((Ld, batch, cfg.linear_conv_kernel - 1, conv_width(cfg)), dtype=dtype))
+                jnp.zeros((Ld, batch, *tail_shape(cfg)), dtype=dtype))
     if cfg.has_window_layers:
         if kv_quant:
             raise NotImplementedError("kv_quant is not ported to a cache with rings")

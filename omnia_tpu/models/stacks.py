@@ -1,12 +1,13 @@
 """Layers of several kinds in the pair family (models/llama.py dispatches
-here for a model with window layers, linear-attention layers, experts of
-``moe_ffn_hidden_size`` or leading dense layers): stacks, runs, rings and
-states.
+here for a model with window layers, linear-attention layers, state-space
+layers, experts of ``moe_ffn_hidden_size`` or leading dense layers): stacks,
+runs, rings and states.
 
 A layer has an attention kind, *window*
 (``cfg.layer_types`` "sliding_attention": a query sees its own row and the
-``sliding_window`` - 1 before it), *full* or *delta* ("linear_attention": the
-gated delta rule with one decay a head, below), and an FFN kind, *dense* (SwiGLU
+``sliding_window`` - 1 before it), *full*, *delta* ("linear_attention": the
+gated delta rule with one decay a head, below) or *mamba* ("mamba": the
+Mamba-1 selective scan, below), and an FFN kind, *dense* (SwiGLU
 of ``ffn_hidden_size``) or *sparse* (``ops/moe.py::expert_ffn``: the sigmoid
 or softmax router over all experts, the experts held here, all of them or a
 rank's share, through ``moe_dropless``, and the shared expert where the model
@@ -46,6 +47,31 @@ is ``_delta_mixer``'s word, and it is models/mla.py::``_kda_layer``'s: a
 piece's pad rows get β = 0 and g = 0 and the tail kept is the last REAL
 row's; a piece at position 0 starts from S = 0 and a zero tail whatever the
 slot holds; a decode step leaves a dead slot's state and tail as they are. (Window layers beside delta layers are not built.)
+
+*A Mamba layer* (``_mamba_mixer``; ops/mamba.py is the rule, three ways), E =
+``mamba_expand``·D channels of N = ``mamba_d_state`` state numbers, a step rank
+R = ``mamba_dt_rank``, K = ``mamba_d_conv`` taps: ``[u | z] = h·W_in``; ``u' =
+SiLU(conv_K(u) + b_c)`` depthwise and causal; ``[δ | B | C] = u'·W_x``, each
+RMS-normed with a gain of its own where ``mamba_inner_norms``; ``Δ =
+softplus(δ·W_dt + b_dt)``; ``A = −exp(A_log)``; ``S[n, c] ← exp(Δ[c]·A[n, c])·S[n,
+c] + Δ[c]·B[n]·u'[c]``, ``y[c] = Σ_n S[n, c]·C[n] + D[c]·u'[c]``; ``(y ⊙ SiLU(z))·
+W_out``. No heads, keys or values, no matmul form: a scan on the vector unit,
+float32 (Δ, the exponent, the sum over n and the state) whatever the stream's
+type. Scope ``attn.mamba`` from the sublayer's input to the residual, inside
+it ``mamba.in``, ``mamba.conv``, ``mamba.gates`` (W_x, the norms, W_dt, the
+softplus), ``mamba.scan`` (T > 1: the Pallas kernel ``mamba_scan`` where the
+piece is whole blocks of 128 tokens, ``mamba_chunked`` else), ``mamba.state`` (a
+decode step's kernel), ``mamba.out``; counter ``decode_mamba_slots`` (live slots a Mamba layer). Such
+a model's cache is four arrays: K and V of the full layers, the Mamba layers'
+states ``[Lm, B, N, E]`` float32 (the channels along the lanes: 16 sublanes x
+5120 lanes are whole tiles, where ``[.., E, N]`` would store its 16 lanes as
+128) and their convolutions' tails ``[Lm, B, (K − 1)·E]`` (the K − 1 rows side by
+side along the lanes: as ``[.., K − 1, E]`` their three rows would be stored as
+a tile's sixteen). ``A_log`` is held as ``[N, E]``, the state's own order. The
+three rules for pad rows, a new tenant's first piece and dead slots are
+``_delta_mixer``'s, in ``_mamba_mixer``'s words: a pad row gets Δ = 0, which is
+decay 1 and input 0. (Window or delta layers or a sparse FFN beside Mamba
+layers are not built.)
 
 *A rotary table a kind of attention layer* (``rope_tables``, made once a
 program under ``rope.tables``; a layer turns its q and k by its kind's under
@@ -102,13 +128,15 @@ from omnia_tpu.models.config import ModelConfig
 from omnia_tpu.ops import attention as _attention
 from omnia_tpu.ops.attention import decode_block_rows, gqa_attention
 from omnia_tpu.ops.delta import decode_delta_state, delta_chunked, pack_state, unpack_state
+from omnia_tpu.ops.mamba import decode_mamba_state, mamba_chunked, mamba_scan, scan_takes
 from omnia_tpu.ops.moe import EXPERT_COUNTERS, expert_ffn, init_ffn, unstack_experts
 from omnia_tpu.ops.norms import rms_norm
 from omnia_tpu.ops.rope import apply_rope, rope_cos_sin, yarn_scaled_cos_sin
 
 #: Every kind a layer can be, in the order a model's stacks stand in (a
 #: stack's seed is its place here: a new kind goes behind the others).
-_KINDS = ("dense_window", "dense_full", "sparse_window", "sparse_full", "dense_delta")
+_KINDS = ("dense_window", "dense_full", "sparse_window", "sparse_full", "dense_delta",
+          "dense_mamba")
 
 #: The ε inside the square root of a delta head's key and query norms.
 _L2_EPS = 1e-6
@@ -127,9 +155,15 @@ def decode_counters(cfg: ModelConfig) -> tuple:
     """Counters a decode step sums on the device over its layers, in the
     order ``forward(..., counters=True)`` returns them (engine.metrics keys):
     the expert layer's, for a model that has one, and the states a step
-    updates (live slots a delta layer), for a model with delta layers."""
+    updates (live slots a delta or Mamba layer), for a model with either."""
     return ((EXPERT_COUNTERS if cfg.moe_ffn_hidden_size else ())
-            + (("decode_delta_slots",) if cfg.has_state_layers else ()))
+            + ((f"decode_{_state_kind(cfg)}_slots",) if cfg.has_state_layers else ()))
+
+
+def _state_kind(cfg: ModelConfig) -> str:
+    """"mamba" or "delta": the kind of a model's layers that keep a state."""
+    return "mamba" if any(kind.endswith("mamba") for kind in
+                          cfg.attention_kinds + (cfg.layer_stacks or ())) else "delta"
 
 
 def cache_kv_heads(cfg: ModelConfig) -> int:
@@ -163,7 +197,10 @@ def state_heads_a_row(cfg: ModelConfig) -> int:
 
 def state_shape(cfg: ModelConfig) -> tuple:
     """A slot's state of one delta layer as the cache holds it: (H/p, dk,
-    p·dv), ``state_heads_a_row`` heads side by side."""
+    p·dv), ``state_heads_a_row`` heads side by side; of one Mamba layer: (N,
+    E), the channels along the lanes."""
+    if _state_kind(cfg) == "mamba":
+        return (cfg.mamba_d_state, cfg.mamba_channels)
     p = state_heads_a_row(cfg)
     return (cfg.linear_num_heads // p, cfg.linear_key_head_dim,
             p * cfg.linear_value_head_dim)
@@ -172,6 +209,16 @@ def state_shape(cfg: ModelConfig) -> tuple:
 def conv_width(cfg: ModelConfig) -> int:
     """Columns of a delta layer's ``wqkv``, its taps and its tail: q | k | v."""
     return cfg.linear_num_heads * (2 * cfg.linear_key_head_dim + cfg.linear_value_head_dim)
+
+
+def tail_shape(cfg: ModelConfig) -> tuple:
+    """A slot's convolution tail of one state layer as the cache holds it:
+    (taps - 1, ``conv_width``) of a delta layer; of a Mamba layer its K - 1 rows
+    of E channels side by side, ((K - 1)·E,): three rows would be stored as a
+    tile's sixteen (1.09 GB for 0.20 at 26 layers x 256 slots x 5120)."""
+    if _state_kind(cfg) == "mamba":
+        return ((cfg.mamba_d_conv - 1) * cfg.mamba_channels,)
+    return (cfg.linear_conv_kernel - 1, conv_width(cfg))
 
 
 def ring_rows(cfg: ModelConfig) -> int:
@@ -244,10 +291,25 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array, dtype):
     ``on``, ``wo``, and in float32 ``a_log`` = log U(1, 16) and ``dt_bias`` the
     inverse softplus of a step log-uniform in 1e-3 … 0.1, a head each: a
     token's decay then lies in about 0.2 … 0.999 as a trained model's does
-    (near 0 it would empty the state every token)."""
+    (near 0 it would empty the state every token). A Mamba layer: ``win`` (u | z),
+    ``conv`` [K, E] normal(0, K^-½) and its bias ``conv_b`` uniform in ±K^-½ (the
+    published module's), ``wx`` (δ | B | C), the inner norms' gains ``dtn``, ``bn``,
+    ``cn``, ``wdt``, ``wo``, and in float32 the published initialisation where a
+    normal draw would empty or freeze the state: ``a_log`` [N, E] = log(1 … N) a
+    channel, ``dt_bias`` the inverse softplus of a step log-uniform in 1e-3 …
+    0.1, ``d`` = 1."""
     if cfg.has_window_layers and cfg.has_state_layers:
-        raise NotImplementedError("window layers beside linear-attention layers in one "
-                                  "model are not built (models/stacks.py)")
+        raise NotImplementedError("window layers beside linear-attention or state-space "
+                                  "layers in one model are not built (models/stacks.py)")
+    if "mamba" in cfg.attention_kinds:
+        beside = [what for what, there in (
+            ("linear-attention layers", "delta" in cfg.attention_kinds),
+            ("a sparse FFN", bool(cfg.moe_ffn_hidden_size)),
+            ("a bias on the in and out projections (mamba_proj_bias)", cfg.mamba_proj_bias),
+        ) if there]
+        if beside:
+            raise NotImplementedError(f"{beside[0]} beside state-space layers in one model "
+                                      "are not built (models/stacks.py)")
     if cfg.norm_placement not in ("pre", "post"):
         raise ValueError(f"norm_placement {cfg.norm_placement!r}: \"pre\" or \"post\"")
     D, V, L = cfg.hidden_size, cfg.vocab_size, cfg.num_layers
@@ -264,7 +326,25 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array, dtype):
             return jnp.exp(jax.random.uniform(next(keys), shape, jnp.float32,
                                               jnp.log(lo), jnp.log(hi)))
 
-        if kind.endswith("delta"):
+        if kind.endswith("mamba"):
+            E, N, R, K = (cfg.mamba_channels, cfg.mamba_d_state, cfg.mamba_dt_rank,
+                          cfg.mamba_d_conv)
+            step = log_uniform((c, E), 1e-3, 0.1)
+            attn = {"win": normal((c, D, 2 * E)),
+                    "conv": normal((c, K, E), std=K ** -0.5),
+                    "wx": normal((c, E, R + 2 * N)), "wdt": normal((c, R, E)),
+                    "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                    "a_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                        1, N + 1, dtype=jnp.float32))[None, :, None], (c, N, E)),
+                    "d": jnp.ones((c, E), jnp.float32),
+                    "wo": normal((c, E, D), std=out_std)}
+            if cfg.mamba_conv_bias:
+                attn["conv_b"] = jax.random.uniform(
+                    next(keys), (c, E), jnp.float32, -K ** -0.5, K ** -0.5).astype(dtype)
+            if cfg.mamba_inner_norms:
+                attn.update(dtn=jnp.ones((c, R), dtype), bn=jnp.ones((c, N), dtype),
+                            cn=jnp.ones((c, N), dtype))
+        elif kind.endswith("delta"):
             H, dv, taps = cfg.linear_num_heads, cfg.linear_value_head_dim, cfg.linear_conv_kernel
             step = log_uniform((c, H), 1e-3, 0.1)
             attn = {"wqkv": normal((c, D, conv_width(cfg))),
@@ -433,6 +513,100 @@ def _delta_mixer(h, a, cfg: ModelConfig, cache, cache_layer, write_start, n_real
     return out, ((states, tails) if cache is not None else (S, kept)), updated
 
 
+def _mamba_mixer(h, a, cfg: ModelConfig, cache, cache_layer, write_start, n_real, live):
+    """A Mamba layer between its input ``h`` [B, T, D] and what it adds to the
+    residual (the module docstring has the mathematics), ``cache_layer`` its
+    index among the Mamba layers. ``cache``: (states [Lm, B, N, E] float32,
+    tails [Lm, B, (K - 1)·E]) whole, or None for a fresh chunk, which starts
+    from zero and gets its (state, tail) back instead. Which rows and steps
+    may touch a state is said here alone, in ``_delta_mixer``'s words:
+    - of a chunk's T rows the first ``n_real`` [B] count; the pad behind them
+      gets Δ = 0 (decay 1, input 0), which leaves S as it is, and the tail
+      kept is the last REAL row's;
+    - a chunk (T > 1) at position 0 is a new tenant's first: it starts from
+      S = 0 and a zero tail whatever the slot holds;
+    - a decode step (T == 1) leaves a slot that is not ``live`` as it is.
+
+    → (out [B, T, D], (states, tails) or (state, tail), states updated int32)."""
+    B, T, _ = h.shape
+    E, N, R, K = cfg.mamba_channels, cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.mamba_d_conv
+    f32 = jnp.float32
+    step = T == 1 and cache is not None
+    with jax.named_scope("mamba.in"):
+        u, z = jnp.split(jnp.dot(h, a["win"]), 2, axis=-1)          # [B, T, E] each
+    with jax.named_scope("mamba.conv"):
+        taps = a["conv"].astype(f32)
+        if step:
+            # One row a slot: the taps meet the tail's rows where they lie,
+            # side by side along the lanes, and the tail moves up by one.
+            states, tails = cache
+            tail = jax.lax.dynamic_index_in_dim(tails, cache_layer, 0, keepdims=False)
+            pre = taps[K - 1] * u[:, 0].astype(f32) + sum(
+                taps[j] * tail[:, j * E:(j + 1) * E].astype(f32) for j in range(K - 1))
+            pre = pre[:, None]
+            kept = jnp.concatenate([tail[:, E:], u[:, 0].astype(tail.dtype)], axis=1)
+            if live is not None:
+                kept = jnp.where(live[:, None], kept, tail)
+        else:
+            if cache is None:
+                tail = jnp.zeros((B, K - 1, E), u.dtype)
+            else:
+                states, tails = cache
+                tail = jax.lax.dynamic_index_in_dim(
+                    tails, cache_layer, 0, keepdims=False).reshape(B, K - 1, E)
+                fresh = write_start == 0
+                tail = jnp.where(fresh[:, None, None], 0, tail)
+            rows = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+            pre = sum(taps[j] * rows[:, j:j + T].astype(f32) for j in range(K))
+            # What the next chunk's first taps see: the rows before the last
+            # real one, never the pad's.
+            kept = jax.vmap(lambda r, n: jax.lax.dynamic_slice_in_dim(r, n, K - 1, 0))(
+                rows, n_real).reshape(B, (K - 1) * E)
+        if cfg.mamba_conv_bias:
+            pre = pre + a["conv_b"].astype(f32)
+        u = jax.nn.silu(pre)                                         # u' [B, T, E] f32
+    with jax.named_scope("mamba.gates"):
+        dbc = jnp.dot(u.astype(h.dtype), a["wx"], preferred_element_type=f32)
+        dl, Bv, Cv = jnp.split(dbc, (R, R + N), axis=-1)
+        if cfg.mamba_inner_norms:
+            dl, Bv, Cv = (rms_norm(t, a[gain], cfg.rms_norm_eps)
+                          for t, gain in ((dl, "dtn"), (Bv, "bn"), (Cv, "cn")))
+        dt = jax.nn.softplus(jnp.dot(dl.astype(h.dtype), a["wdt"], preferred_element_type=f32)
+                             + a["dt_bias"].astype(f32))
+        real = jnp.arange(T, dtype=jnp.int32)[None, :] < n_real[:, None]
+        dt = jnp.where(real[:, :, None], dt, 0.0)
+        A = -jnp.exp(a["a_log"].astype(f32))
+    if step:
+        with jax.named_scope("mamba.state"):
+            y, states = decode_mamba_state(
+                states, u[:, 0], dt[:, 0], Bv[:, 0], Cv[:, 0], A, a["d"], cache_layer, live,
+                kernel=_attention._kernel_on(),
+                interpret=_attention._pallas_decode_mode() == "interpret")
+            y = y[:, None]
+        updated = jnp.sum(live, dtype=jnp.int32) if live is not None else jnp.int32(B)
+    else:
+        with jax.named_scope("mamba.scan"):
+            S = jnp.zeros((B, N, E), f32)
+            if cache is not None:
+                S = jax.lax.dynamic_index_in_dim(states, cache_layer, 0, keepdims=False)
+                S = jnp.where(fresh[:, None, None], 0.0, S)
+            if _attention._kernel_on() and scan_takes(T, N, E):  # whole blocks of tokens
+                y, S = mamba_scan(u, dt, Bv, Cv, A, a["d"], S,
+                                  interpret=_attention._pallas_decode_mode() == "interpret")
+            else:
+                y, S = mamba_chunked(u, dt, Bv, Cv, A, a["d"], S)
+            if cache is not None:
+                states = jax.lax.dynamic_update_slice_in_dim(states, S[None], cache_layer, 0)
+        updated = jnp.int32(0)
+    if cache is not None:
+        with jax.named_scope("mamba.conv"):
+            tails = jax.lax.dynamic_update_slice_in_dim(
+                tails, kept.astype(tails.dtype)[None], cache_layer, 0)
+    with jax.named_scope("mamba.out"):
+        out = jnp.dot((y * jax.nn.silu(z.astype(f32))).astype(h.dtype), a["wo"])
+    return out, ((states, tails) if cache is not None else (S, kept)), updated
+
+
 def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
                  cache, cache_layer, write_start, n_real, mesh, live, attn_fn=None):
     """One block of a model of several kinds: ``kind`` its stack's, ``at`` its
@@ -442,7 +616,7 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
     tuple (the module docstring), or None for a chunk on its own (training, a fresh
     prefill), which gets its rows back instead: (k, v) [B, T, Hkv, D] of a
     full layer, [B, R, Hkv, D] of a window layer, (state, tail) of a delta
-    layer. ``attn_fn`` overrides a full
+    or Mamba layer. ``attn_fn`` overrides a full
     layer's attention over a chunk on its own (training: the einsums), and
     with one a window layer takes the einsum band. ``cfg.norm_placement``:
     ``ln1`` and ``ln2`` in front of the sublayers ("pre") or on their outputs.
@@ -452,9 +626,11 @@ def _stack_layer(x, p, experts, at, kind, cfg: ModelConfig, rope, q_positions,
     attention = kind.split("_")[1]
     pre = cfg.norm_placement == "pre"
     eps = cfg.rms_norm_eps
-    if attention == "delta":
-        with jax.named_scope("attn.delta"):  # delta.conv/gates/chunk/state/out inside
-            out, kept, updated = _delta_mixer(
+    if attention in ("delta", "mamba"):
+        mixer = _delta_mixer if attention == "delta" else _mamba_mixer
+        # delta.conv/gates/chunk/state/out, mamba.in/conv/gates/scan/state/out inside
+        with jax.named_scope(f"attn.{attention}"):
+            out, kept, updated = mixer(
                 rms_norm(x, p["ln1"], eps) if pre else x, p["attn"], cfg,
                 None if cache is None else cache[-2:], cache_layer, write_start, n_real, live)
             x = x + (out if pre else rms_norm(out, p["ln1"], eps))
@@ -570,12 +746,12 @@ def _run_stacks(params, cfg: ModelConfig, x, rope, q_positions, cache, write_sta
     takes its kind's. With a cache (the whole tuple) it is the carry and
     comes back; without one the chunk's rows come back in its place, an
     array for each cache array ([L of the kind, B, T or R, Hkv, D]; a delta
-    layer's state and tail). → (x, cache or chunks, counts summed over the
+    or Mamba layer's state and tail). → (x, cache or chunks, counts summed over the
     layers: ``_stack_layer``'s)."""
     B, T, _ = x.shape
     n_real = jnp.broadcast_to(T if row is None else row + 1, (B,)).astype(jnp.int32)
     counts = jnp.zeros((len(EXPERT_COUNTERS) + cfg.has_state_layers,), jnp.int32)
-    chunks = {"full": [], "window": [], "delta": []}
+    chunks = {"full": [], "window": [], "delta": [], "mamba": []}
     for stack, kind, first, length, cache_first in _runs(cfg):
         layers = params["layers"][stack]
         scanned, experts = (unstack_experts(layers) if kind.startswith("sparse")
@@ -607,14 +783,15 @@ def _run_stacks(params, cfg: ModelConfig, x, rope, q_positions, cache, write_sta
             return rows[0]
         if rows:
             return tuple(jnp.concatenate(each, axis=0) for each in zip(*rows))
-        if kind == "delta":  # a cut model without a layer of the kind: arrays of no layers
+        if kind in ("delta", "mamba"):  # a cut model without a layer of the kind: no layers
             return (jnp.zeros((0, B, *state_shape(cfg)), jnp.float32),
-                    jnp.zeros((0, B, cfg.linear_conv_kernel - 1, conv_width(cfg)), x.dtype))
+                    jnp.zeros((0, B, *tail_shape(cfg)), x.dtype))
         shape = (0, B, ring_rows(cfg) if kind == "window" else T,
                  cache_kv_heads(cfg), cfg.head_dim)
         return jnp.zeros(shape, x.dtype), jnp.zeros(shape, x.dtype)
 
-    have = ["full"] + ["window"] * cfg.has_window_layers + ["delta"] * cfg.has_state_layers
+    have = (["full"] + ["window"] * cfg.has_window_layers
+            + [_state_kind(cfg)] * cfg.has_state_layers)
     if have == ["full"]:
         return x, whole("full", chunks["full"]), counts
     return x, tuple(a for kind in have for a in whole(kind, chunks[kind])), counts

@@ -286,9 +286,13 @@ def _attend(name, q, k, v, scales, table, positions, live, layer, block_s,
         # kernel constrains any more slots-minor for its updates, and copies
         # it whole in front of every call (the chipless compile of
         # code-mixed's decode chunk: +1 GB of temporaries).
-        row_major = Layout(major_to_minor=tuple(range(k.ndim)))
-        k, v = (with_layout_constraint(x, row_major).reshape(*x.shape[:2], -1, D)
-                for x in (k, v))
+        # (ONE head: the chip's own layout of [.., S, 1, D] has the rows
+        # minor to the head's axis of one, whole tiles of rows, which is
+        # [.., S, D] byte for byte; rows-major as stated for more heads it
+        # would pad every row to a tile of two and copy the cache to and fro.)
+        order = (0, 1, 3, 2, 4) if Hkv == 1 else tuple(range(k.ndim))
+        k, v = (with_layout_constraint(x, Layout(major_to_minor=order))
+                .reshape(*x.shape[:2], -1, D) for x in (k, v))
     positions = positions.astype(jnp.int32)
     work, n_work = _work_list(positions, live, block_s, num_s)
     prefetch = [jnp.asarray(layer, jnp.int32).reshape(1), positions, work]

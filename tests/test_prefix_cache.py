@@ -469,7 +469,7 @@ class TestMetricsKeyStability:
         "decode_window_rows",
         "decode_steps_sampling", "decode_steps_filtering",
         "moe_assignments_held", "moe_experts_hit", "decode_kda_slots",
-        "decode_delta_slots",
+        "decode_delta_slots", "decode_mamba_slots",
         "pipeline_flushes", "placements_deferred",
         "programs_compiled_serving",
         "prefix_reuse_tokens", "session_offloads", "session_restores",
